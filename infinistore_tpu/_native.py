@@ -65,11 +65,16 @@ def _build_native():
         raise RuntimeError(
             f"native library missing at {_LIB_PATH} and no source tree found"
         )
-    subprocess.run(
-        ["make", "-C", os.path.abspath(_NATIVE_SRC)],
-        check=True,
-        capture_output=True,
-    )
+    cmd = ["make", "-C", os.path.abspath(_NATIVE_SRC)]
+    try:
+        proc = subprocess.run(cmd, capture_output=True, text=True)
+    except FileNotFoundError as e:
+        raise RuntimeError(f"cannot build native library: {e}") from e
+    if proc.returncode != 0:
+        raise RuntimeError(
+            f"{' '.join(cmd)} failed with exit code {proc.returncode}:\n"
+            f"{proc.stderr[-8000:]}"
+        )
 
 
 def _decls(lib):

@@ -32,10 +32,12 @@ import numpy as np
 from infinistore_tpu import ClientConfig, InfinityConnection
 from infinistore_tpu.models import llama
 from infinistore_tpu.serving import Request, ServingConfig, ServingEngine
-from infinistore_tpu.tpu import TpuKVStore
+from infinistore_tpu.tpu import TpuKVStore, enable_compile_cache
 
 
 def run(host, port, http_port=None, http_demo_requests=False):
+    dev = jax.devices()[0]
+    print(f"device: {dev.platform} {dev.device_kind!r} x{len(jax.devices())}")
     cfg = llama.LlamaConfig(
         vocab_size=256, d_model=128, n_layers=4, n_heads=4, n_kv_heads=2,
         d_ff=256, max_seq=256, page_size=16,
@@ -160,5 +162,6 @@ if __name__ == "__main__":
                    help="with --http-port: fire one demo request and "
                         "exit instead of serving forever")
     args = p.parse_args()
+    print(f"compile cache: {enable_compile_cache()}")
     run(args.host, args.service_port, http_port=args.http_port,
         http_demo_requests=args.http_demo)

@@ -394,8 +394,8 @@ def _forward_stack(params, cfg: LlamaConfig, tokens, prefix_kvs=None,
             pk, pv = prefix_kvs[li]
             k_full = jnp.concatenate([pk.astype(k.dtype), k], axis=1)
             v_full = jnp.concatenate([pv.astype(v.dtype), v], axis=1)
-        # Pallas flash kernel on TPU (O(S) memory, ~4x faster than the
-        # XLA path at S=4096 on v5e), XLA path elsewhere. kv may be
+        # Pallas flash kernel on TPU (O(S) memory; speed against the
+        # XLA path not measured), XLA path elsewhere. kv may be
         # longer than q — the causal diagonal shifts by the prefix.
         attn = flash_prefill(q, k_full, v_full, causal=True,
                              window=cfg.window)
@@ -617,10 +617,9 @@ def restore_prefix_pages(store, cfg: LlamaConfig, key_fn, n_pages,
     store.get_kv_pages_quantized for int8 pages).
 
     ONE batched store call covers every (layer, kind): 2L small
-    fetches would pay 2L pin/transfer/completion-proof round trips
-    (~4.5 s for a 32-layer model on a 70 ms/call link) where the batch
-    pays one, and one large DMA beats 2L small ones on any host. The
-    device-side split back into per-layer stacks is free slicing.
+    fetches would pay 2L pin/transfer round trips where the batch pays
+    one, and one large DMA beats 2L small ones. The device-side split
+    back into per-layer stacks is free slicing.
     Returns (k_pages, v_pages) [n_layers, n_pages, page, n_kv, hd]."""
     get = getter if getter is not None else store.get_kv_pages
     keys = []

@@ -292,10 +292,9 @@ def paged_flash_decode_quantized(q, k_q, k_s, v_q, v_s, page_table,
     (ops/kv_quant.py format): pages stay int8 in HBM — the decode cache
     holds 2x the tokens — and each page's DMA moves ~0.53x the bf16
     bytes, with dequantization fused into the kernel right after the
-    load. Same contract as paged_flash_decode otherwise. Measured on
-    v5e at 1024-token sequences (batch 8, 8 heads, hd 128): 2266 us vs
-    the bf16 kernel's 3099 us — 1.37x from the halved page traffic;
-    accuracy is the quantizer's (~0.4% rel).
+    load. Same contract as paged_flash_decode otherwise. Speed against
+    the bf16 kernel: not measured; accuracy is the quantizer's
+    (~0.4% rel).
 
     k_q/v_q: int8 [n_pages, page, n_kv, hd];
     k_s/v_s: f32 [n_pages, page, n_kv] (per-token-per-head scales).
@@ -534,7 +533,6 @@ def decode_attention_tp(mesh, q, k_pages, v_pages, page_table, seq_lens,
 
     Requires n_kv_heads % mesh.shape[axis] == 0.
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     if interpret is None:
@@ -548,7 +546,7 @@ def decode_attention_tp(mesh, q, k_pages, v_pages, page_table, seq_lens,
         return paged_flash_decode(q, kp, vp, pt, sl, interpret=interpret,
                                   window=window)
 
-    return shard_map(
+    return jax.shard_map(
         local, mesh=mesh,
         in_specs=(
             P(None, axis, None),        # q: heads sharded
@@ -558,7 +556,7 @@ def decode_attention_tp(mesh, q, k_pages, v_pages, page_table, seq_lens,
             P(None),                    # seq_lens: replicated
         ),
         out_specs=P(None, axis, None),
-        check_rep=False,
+        check_vma=False,
     )(q, k_pages, v_pages, page_table, seq_lens)
 
 
@@ -568,7 +566,6 @@ def decode_attention_quantized_tp(mesh, q, k_q, k_s, v_q, v_s, page_table,
     """Int8 variant of :func:`decode_attention_tp`: quantized pages and
     their per-token-per-head scales both shard on the kv-head dim; the
     fused dequant-in-kernel path runs per device on local heads."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     if interpret is None:
@@ -584,7 +581,7 @@ def decode_attention_quantized_tp(mesh, q, k_q, k_s, v_q, v_s, page_table,
             q, kq, ks, vq, vs, pt, sl, interpret=interpret, window=window
         )
 
-    return shard_map(
+    return jax.shard_map(
         local, mesh=mesh,
         in_specs=(
             P(None, axis, None),        # q
@@ -596,7 +593,7 @@ def decode_attention_quantized_tp(mesh, q, k_q, k_s, v_q, v_s, page_table,
             P(None),
         ),
         out_specs=P(None, axis, None),
-        check_rep=False,
+        check_vma=False,
     )(q, k_q, k_s, v_q, v_s, page_table, seq_lens)
 
 

@@ -61,11 +61,8 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-try:  # jax >= 0.8
-    from jax import shard_map
-except ImportError:  # pragma: no cover — older jax
-    from jax.experimental.shard_map import shard_map
 
 
 def make_pool_mesh(n_devices, axis="pool", devices=None):

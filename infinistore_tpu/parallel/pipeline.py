@@ -22,16 +22,8 @@ reversed permute), so pipelined training needs no extra machinery.
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-try:  # jax >= 0.8
-    from jax import shard_map
-
-    _CHECK_KW = {"check_vma": False}
-except ImportError:  # pragma: no cover — older jax (kwarg is check_rep)
-    from jax.experimental.shard_map import shard_map
-
-    _CHECK_KW = {"check_rep": False}
 
 
 def make_pp_mesh(n_stages, devices=None, axis="pp"):
@@ -125,7 +117,7 @@ def pipeline_apply(stage_fn, stacked_params, x_micro, mesh, axis="pp"):
         mesh=mesh,
         in_specs=(P(axis), P()),
         out_specs=P(),
-        **_CHECK_KW,  # masked psum IS the replication proof
+        check_vma=False,  # masked psum IS the replication proof
     )
     return smapped(stacked_params, x_micro)
 

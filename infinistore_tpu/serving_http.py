@@ -33,6 +33,7 @@ batching), which is the entire point of the engine.
 """
 
 import json
+import logging
 import queue
 import threading
 import time
@@ -289,6 +290,9 @@ class ServingHTTPServer:
                 try:
                     decoded = eng.step()
                 except Exception:
+                    logging.getLogger("infinistore_tpu.serving").exception(
+                        "engine step failed; the engine goes down"
+                    )
                     # A failed device step leaves the engine's pools in
                     # an undefined state (donated buffers): go DOWN
                     # cleanly — fail every waiting client instead of

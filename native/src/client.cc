@@ -6,6 +6,7 @@
 #include <netdb.h>
 #include <netinet/in.h>
 #include <netinet/tcp.h>
+#include <poll.h>
 #include <sched.h>
 #include <sys/epoll.h>
 #include <sys/eventfd.h>
@@ -40,6 +41,21 @@ int connect_tcp(const std::string& host, uint16_t port, int timeout_ms) {
     setsockopt(fd, SOL_SOCKET, SO_RCVTIMEO, &tv, sizeof(tv));
     int rc = connect(fd, res->ai_addr, res->ai_addrlen);
     freeaddrinfo(res);
+    if (rc != 0 && errno == EINTR) {
+        // A signal (e.g. io_uring task work of a server in this same
+        // process) interrupted the call; the handshake carries on in
+        // the kernel. Wait for it and read its outcome.
+        pollfd pfd{fd, POLLOUT, 0};
+        int pr;
+        do {
+            pr = poll(&pfd, 1, timeout_ms);
+        } while (pr < 0 && errno == EINTR);
+        int err = pr == 0 ? ETIMEDOUT : errno;
+        socklen_t elen = sizeof(err);
+        if (pr > 0) getsockopt(fd, SOL_SOCKET, SO_ERROR, &err, &elen);
+        rc = err == 0 ? 0 : -1;
+        errno = err;
+    }
     if (rc != 0) {
         close(fd);
         return -1;

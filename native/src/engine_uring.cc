@@ -617,16 +617,19 @@ bool EngineUring::init() {
     // Op support probe (IORING_REGISTER_PROBE, 5.6+). A kernel too old
     // to probe is also too old for any of the optional ops.
     {
-        struct {
-            io_uring_probe p;
-            io_uring_probe_op ops[256];
-        } pr;
-        memset(&pr, 0, sizeof(pr));
-        if (sys_uring_register(r_.fd, IORING_REGISTER_PROBE, &pr, 256) ==
-            0) {
+        // io_uring_probe ends in a flexible ops[] array, so it cannot
+        // be a member followed by storage: carve both out of one
+        // aligned byte buffer.
+        constexpr unsigned kProbeOps = 256;
+        alignas(io_uring_probe) unsigned char
+            buf[sizeof(io_uring_probe) +
+                kProbeOps * sizeof(io_uring_probe_op)] = {};
+        auto* pr = reinterpret_cast<io_uring_probe*>(buf);
+        if (sys_uring_register(r_.fd, IORING_REGISTER_PROBE, pr,
+                               kProbeOps) == 0) {
             auto supported = [&](uint8_t op) {
-                return pr.p.last_op >= op &&
-                       (pr.ops[op].flags & IO_URING_OP_SUPPORTED) != 0;
+                return pr->last_op >= op &&
+                       (pr->ops[op].flags & IO_URING_OP_SUPPORTED) != 0;
             };
             zc_ok_ = supported(kOpSendZc);
             zc_msg_ok_ = supported(kOpSendmsgZc);

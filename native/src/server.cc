@@ -174,6 +174,18 @@ std::string json_escape(const std::string& in) {
     return out;
 }
 
+// Stop listening BEFORE dropping our descriptor: the uring engine's
+// standing accept/poll holds its own reference to the socket until the
+// kernel finishes tearing the ring down, which happens asynchronously
+// after close(ring_fd) — a restart on the same port would meet the
+// still-listening socket and fail with EADDRINUSE. shutdown() takes
+// the socket out of LISTEN at once; SO_REUSEADDR then lets the next
+// bind through whoever still references it.
+void close_listener(int fd) {
+    shutdown(fd, SHUT_RDWR);
+    close(fd);
+}
+
 }  // namespace
 
 Server::Server(const ServerConfig& cfg) : cfg_(cfg) {
@@ -683,10 +695,10 @@ void Server::stop() {
         // Per-worker SO_REUSEPORT listeners (worker 0 aliases
         // listen_fd_, closed below).
         if (w->listen_fd >= 0 && w->listen_fd != listen_fd_) {
-            close(w->listen_fd);
+            close_listener(w->listen_fd);
         }
     }
-    if (listen_fd_ >= 0) close(listen_fd_);
+    if (listen_fd_ >= 0) close_listener(listen_fd_);
     listen_fd_ = -1;
     {
         // Control-plane threads may still be inside kvmap_len/stats or a

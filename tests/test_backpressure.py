@@ -5,8 +5,7 @@ write another client's in-flight allocations).
 Reference discipline being matched: the reference bounds its push path
 with signal/32 and a 4096-WR window (libinfinistore.cpp:898-987) and keys
 inflight write state per client (infinistore.cpp:63,361-371). Round-1
-review found both missing here (VERDICT.md items 3-4); these tests pin
-the fixes.
+review found both missing here; these tests pin the fixes.
 """
 
 import socket

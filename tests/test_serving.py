@@ -122,6 +122,51 @@ def test_multiturn_prefix_hit_through_store(params, cfg, shm_conn):
     assert out2["t2"] == ref["x"]
 
 
+def test_engine_lives_where_its_weights_live(params, cfg, shm_conn):
+    """A replica whose weights sit on device 1 keeps its pool, its
+    restores and its steps there — also when another thread drives it
+    (the HTTP engine thread; jax.default_device is thread-local) — and
+    hits the prefix an engine on device 0 offloaded."""
+    import threading
+
+    from infinistore_tpu.tpu import TpuKVStore
+
+    class Recording(TpuKVStore):
+        def get_kv_pages(self, *a, **kw):
+            out = super().get_kv_pages(*a, **kw)
+            self.restored_on = set(out.devices())
+            return out
+
+    dev0, dev1 = jax.devices()[:2]
+    rng = np.random.default_rng(21)
+    turn1 = _prompt(rng, cfg, 16)
+    sc = ServingConfig(model_id="replicas")
+    eng0 = ServingEngine(params, cfg, sc, store=TpuKVStore(shm_conn))
+    out1 = eng0.run([Request("t1", turn1, max_new_tokens=8)])
+    assert eng0.device == dev0
+
+    store1 = Recording(shm_conn)
+    eng1 = ServingEngine(jax.device_put(params, dev1), cfg, sc, store=store1)
+    turn2 = turn1 + out1["t1"] + _prompt(rng, cfg, 5)  # 3 full pages + 5
+    out2 = {}
+    t = threading.Thread(
+        target=lambda: out2.update(
+            eng1.run([Request("t2", turn2, max_new_tokens=6)])
+        )
+    )
+    t.start()
+    t.join(timeout=120)
+    assert not t.is_alive()
+    assert eng1.stats["prefix_hit_pages"] > 0
+    assert store1.restored_on == {dev1}
+    assert set(eng1.k_pages.devices()) == {dev1}
+    assert set(eng0.k_pages.devices()) == {dev0}
+    ref = ServingEngine(params, cfg).run(
+        [Request("x", turn2, max_new_tokens=6)]
+    )
+    assert out2["t2"] == ref["x"]
+
+
 def test_identical_prompts_share_pages(params, cfg, shm_conn):
     """Two requests with the same prompt: the second admission hits the
     first's offloaded pages (content addressing needs no seq ids)."""

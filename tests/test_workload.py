@@ -488,6 +488,12 @@ def test_thrash_verdict_fires_once_with_workload_bundle(tmp_path):
                 trips = srv.stats()["watchdog"]["thrash_trips"]
                 if trips:
                     break
+            # The trip is counted before its bundle is written: wait for
+            # the capture to finish before reading the bundle below.
+            deadline = time.time() + 10
+            while (srv.stats()["watchdog"]["bundles"] < 1
+                   and time.time() < deadline):
+                time.sleep(0.02)
             st = srv.stats()
             assert st["watchdog"]["thrash_trips"] == 1, st["watchdog"]
             assert st["workload"]["premature_evictions"] > 0
