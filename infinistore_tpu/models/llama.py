@@ -305,21 +305,35 @@ def _proj(h, layer, w, b_, shape=None):
     return out if shape is None else out.reshape(shape)
 
 
+# Stage names (jax.named_scope): every device operation's metadata
+# carries the stage it came from — one name a stage and none a layer
+# index, the same in every model family — so a trace reduction finds a
+# stage's operations whatever the compiler calls its fusions:
+#   embed, attn.qkv, attn.rope, attn.kernel, attn.out, mlp (moe.route,
+#   moe.dispatch, moe.experts, moe.combine in models/moe.py),
+#   pool.update, lm_head.
+
+
 def _qkv(layer, x, cfg, positions):
     b = x.shape[0]
     s = x.shape[1]
-    h = rms_norm(x, layer["ln1"], cfg.norm_eps, cfg.norm_plus_one)
-    q = _proj(h, layer, "wq", "bq", (b, s, cfg.n_heads, cfg.head_dim))
-    k = _proj(h, layer, "wk", "bk", (b, s, cfg.n_kv_heads, cfg.head_dim))
-    v = _proj(h, layer, "wv", "bv", (b, s, cfg.n_kv_heads, cfg.head_dim))
-    q = rope(q, positions, cfg.rope_theta, cfg.rope_scaling)
-    k = rope(k, positions, cfg.rope_theta, cfg.rope_scaling)
+    with jax.named_scope("attn.qkv"):
+        h = rms_norm(x, layer["ln1"], cfg.norm_eps, cfg.norm_plus_one)
+        q = _proj(h, layer, "wq", "bq", (b, s, cfg.n_heads, cfg.head_dim))
+        k = _proj(h, layer, "wk", "bk",
+                  (b, s, cfg.n_kv_heads, cfg.head_dim))
+        v = _proj(h, layer, "wv", "bv",
+                  (b, s, cfg.n_kv_heads, cfg.head_dim))
+    with jax.named_scope("attn.rope"):
+        q = rope(q, positions, cfg.rope_theta, cfg.rope_scaling)
+        k = rope(k, positions, cfg.rope_theta, cfg.rope_scaling)
     return q, k, v
 
 
 def _attn_out(layer, attn_flat):
     """attn @ Wo (+ optional bo) — the attention output projection."""
-    return _proj(attn_flat, layer, "wo", "bo")
+    with jax.named_scope("attn.out"):
+        return _proj(attn_flat, layer, "wo", "bo")
 
 
 def _act(cfg):
@@ -334,11 +348,12 @@ def _act(cfg):
 
 
 def _mlp(layer, x, cfg):
-    h = rms_norm(x, layer["ln2"], cfg.norm_eps, cfg.norm_plus_one)
-    gated = _act(cfg)(_matmul(h, layer["w_gate"])) * _matmul(
-        h, layer["w_up"]
-    )
-    return _matmul(gated, layer["w_down"])
+    with jax.named_scope("mlp"):
+        h = rms_norm(x, layer["ln2"], cfg.norm_eps, cfg.norm_plus_one)
+        gated = _act(cfg)(_matmul(h, layer["w_gate"])) * _matmul(
+            h, layer["w_up"]
+        )
+        return _matmul(gated, layer["w_down"])
 
 
 def _embed(params, tokens, cfg=None):
@@ -348,20 +363,22 @@ def _embed(params, tokens, cfg=None):
     carries the model's compute dtype (quantize_params stores it as
     cfg.jdtype), so the result matches the dense path."""
     e = params["embed"]
-    if isinstance(e, dict):
-        rows = jnp.take(e["int8"], tokens, axis=0)
-        row_scale = jnp.take(e["scale"], tokens, axis=0)
-        out = rows.astype(row_scale.dtype) * row_scale[..., None]
-    else:
-        out = jnp.take(e, tokens, axis=0)
-    if cfg is not None and cfg.embed_scale != 1.0:
-        out = out * jnp.asarray(cfg.embed_scale, out.dtype)
+    with jax.named_scope("embed"):
+        if isinstance(e, dict):
+            rows = jnp.take(e["int8"], tokens, axis=0)
+            row_scale = jnp.take(e["scale"], tokens, axis=0)
+            out = rows.astype(row_scale.dtype) * row_scale[..., None]
+        else:
+            out = jnp.take(e, tokens, axis=0)
+        if cfg is not None and cfg.embed_scale != 1.0:
+            out = out * jnp.asarray(cfg.embed_scale, out.dtype)
     return out
 
 
 def _logits(params, x):
     """Final projection to vocab, fp32 output."""
-    return _matmul(x, params["lm_head"]).astype(jnp.float32)
+    with jax.named_scope("lm_head"):
+        return _matmul(x, params["lm_head"]).astype(jnp.float32)
 
 
 def _forward_stack(params, cfg: LlamaConfig, tokens, prefix_kvs=None,
@@ -397,8 +414,9 @@ def _forward_stack(params, cfg: LlamaConfig, tokens, prefix_kvs=None,
         # Pallas flash kernel on TPU (O(S) memory; speed against the
         # XLA path not measured), XLA path elsewhere. kv may be
         # longer than q — the causal diagonal shifts by the prefix.
-        attn = flash_prefill(q, k_full, v_full, causal=True,
-                             window=cfg.window)
+        with jax.named_scope("attn.kernel"):
+            attn = flash_prefill(q, k_full, v_full, causal=True,
+                                 window=cfg.window)
         x = x + _attn_out(layer, attn.reshape(b, s, -1))
         x = x + _mlp(layer, x, cfg)
         kvs.append((k, v))
@@ -471,18 +489,22 @@ def decode_step(params, cfg: LlamaConfig, token, seq_lens, k_pages, v_pages,
     new_k_pages, new_v_pages = [], []
     for li, layer in enumerate(params["layers"]):
         q, k, v = _qkv(layer, x, cfg, positions)
-        kp = scatter_kv_to_pages(k_pages[li], k, target_page, slot)
-        vp = scatter_kv_to_pages(v_pages[li], v, target_page, slot)
-        attn = paged_decode_attention(
-            q[:, 0], kp, vp, page_table, seq_lens + 1, window=cfg.window
-        )
+        with jax.named_scope("pool.update"):
+            kp = scatter_kv_to_pages(k_pages[li], k, target_page, slot)
+            vp = scatter_kv_to_pages(v_pages[li], v, target_page, slot)
+        with jax.named_scope("attn.kernel"):
+            attn = paged_decode_attention(
+                q[:, 0], kp, vp, page_table, seq_lens + 1,
+                window=cfg.window
+            )
         x = x + _attn_out(layer, attn.reshape(b, 1, -1))
         x = x + _mlp(layer, x, cfg)
         new_k_pages.append(kp)
         new_v_pages.append(vp)
     x = rms_norm(x, params["final_ln"], cfg.norm_eps, cfg.norm_plus_one)
     logits = _logits(params, x[:, 0])
-    return logits, jnp.stack(new_k_pages), jnp.stack(new_v_pages)
+    with jax.named_scope("pool.update"):
+        return logits, jnp.stack(new_k_pages), jnp.stack(new_v_pages)
 
 
 @partial(jax.jit, static_argnames=("cfg",))
@@ -528,19 +550,22 @@ def verify_step(params, cfg: LlamaConfig, tokens, seq_lens, k_pages,
     new_k_pages, new_v_pages = [], []
     for li, layer in enumerate(params["layers"]):
         q, k, v = _qkv(layer, x, cfg, positions)
-        kp = scatter_kv_multi(k_pages[li], k, target_page, slot)
-        vp = scatter_kv_multi(v_pages[li], v, target_page, slot)
+        with jax.named_scope("pool.update"):
+            kp = scatter_kv_multi(k_pages[li], k, target_page, slot)
+            vp = scatter_kv_multi(v_pages[li], v, target_page, slot)
         # Pallas streaming kernel on TPU (pages HBM->VMEM, nothing
         # gathered), XLA gather path elsewhere.
-        attn = paged_verify_attention(q, kp, vp, page_table, seq_lens,
-                                      window=cfg.window)
+        with jax.named_scope("attn.kernel"):
+            attn = paged_verify_attention(q, kp, vp, page_table, seq_lens,
+                                          window=cfg.window)
         x = x + _attn_out(layer, attn.reshape(b, m, -1))
         x = x + _mlp(layer, x, cfg)
         new_k_pages.append(kp)
         new_v_pages.append(vp)
     x = rms_norm(x, params["final_ln"], cfg.norm_eps, cfg.norm_plus_one)
     logits = _logits(params, x)
-    return logits, jnp.stack(new_k_pages), jnp.stack(new_v_pages)
+    with jax.named_scope("pool.update"):
+        return logits, jnp.stack(new_k_pages), jnp.stack(new_v_pages)
 
 
 def token_nll(logits, targets):

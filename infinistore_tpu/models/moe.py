@@ -166,18 +166,23 @@ def _moe_mlp(layer, x, cfg: MoEConfig, valid=None):
     the layer's aux loss. `valid` ([B, S] bool or None) masks tokens
     out of routing (see _route)."""
     b, s, d = x.shape
-    h = rms_norm(x, layer["ln2"], cfg.norm_eps,
-                 cfg.norm_plus_one).reshape(b * s, d)
-    vflat = None if valid is None else valid.reshape(b * s)
-    dispatch, combine, aux = _route(layer, h, cfg, vflat)
+    # Stage names as in models/llama.py (one a stage, no layer index).
+    with jax.named_scope("moe.route"):
+        h = rms_norm(x, layer["ln2"], cfg.norm_eps,
+                     cfg.norm_plus_one).reshape(b * s, d)
+        vflat = None if valid is None else valid.reshape(b * s)
+        dispatch, combine, aux = _route(layer, h, cfg, vflat)
     # Scatter to per-expert slots: ONE einsum, [E, C, d] activations.
-    xe = jnp.einsum("tec,td->ecd", dispatch.astype(h.dtype), h)
+    with jax.named_scope("moe.dispatch"):
+        xe = jnp.einsum("tec,td->ecd", dispatch.astype(h.dtype), h)
     # Batched expert SwiGLU on the MXU (E stacked matmuls; sharded over
     # the ep axis when the params carry P("ep", ...) shardings).
-    a = jax.nn.silu(jnp.einsum("ecd,edf->ecf", xe, layer["e_gate"]))
-    a = a * jnp.einsum("ecd,edf->ecf", xe, layer["e_up"])
-    oe = jnp.einsum("ecf,efd->ecd", a, layer["e_down"])
-    out = jnp.einsum("tec,ecd->td", combine.astype(oe.dtype), oe)
+    with jax.named_scope("moe.experts"):
+        a = jax.nn.silu(jnp.einsum("ecd,edf->ecf", xe, layer["e_gate"]))
+        a = a * jnp.einsum("ecd,edf->ecf", xe, layer["e_up"])
+        oe = jnp.einsum("ecf,efd->ecd", a, layer["e_down"])
+    with jax.named_scope("moe.combine"):
+        out = jnp.einsum("tec,ecd->td", combine.astype(oe.dtype), oe)
     return out.reshape(b, s, d), aux
 
 
@@ -204,8 +209,9 @@ def _forward_stack(params, cfg: MoEConfig, tokens, prefix_kvs=None,
             pk, pv = prefix_kvs[li]
             k_full = jnp.concatenate([pk.astype(k.dtype), k], axis=1)
             v_full = jnp.concatenate([pv.astype(v.dtype), v], axis=1)
-        attn = _llama.flash_prefill(q, k_full, v_full, causal=True,
-                                    window=cfg.window)
+        with jax.named_scope("attn.kernel"):
+            attn = _llama.flash_prefill(q, k_full, v_full, causal=True,
+                                        window=cfg.window)
         x = x + _llama._attn_out(layer, attn.reshape(b, s, -1))
         moe_out, aux = _moe_mlp(layer, x, cfg)
         x = x + moe_out
@@ -267,11 +273,16 @@ def decode_step(params, cfg: MoEConfig, token, seq_lens, k_pages, v_pages,
     new_k_pages, new_v_pages = [], []
     for li, layer in enumerate(params["layers"]):
         q, k, v = _llama._qkv(layer, x, cfg, positions)
-        kp = _llama.scatter_kv_to_pages(k_pages[li], k, target_page, slot)
-        vp = _llama.scatter_kv_to_pages(v_pages[li], v, target_page, slot)
-        attn = _llama.paged_decode_attention(
-            q[:, 0], kp, vp, page_table, seq_lens + 1, window=cfg.window
-        )
+        with jax.named_scope("pool.update"):
+            kp = _llama.scatter_kv_to_pages(k_pages[li], k, target_page,
+                                            slot)
+            vp = _llama.scatter_kv_to_pages(v_pages[li], v, target_page,
+                                            slot)
+        with jax.named_scope("attn.kernel"):
+            attn = _llama.paged_decode_attention(
+                q[:, 0], kp, vp, page_table, seq_lens + 1,
+                window=cfg.window
+            )
         x = x + _llama._attn_out(layer, attn.reshape(b, 1, -1))
         moe_out, _aux = _moe_mlp(layer, x, cfg, valid)
         x = x + moe_out
@@ -279,7 +290,8 @@ def decode_step(params, cfg: MoEConfig, token, seq_lens, k_pages, v_pages,
         new_v_pages.append(vp)
     x = rms_norm(x, params["final_ln"], cfg.norm_eps, cfg.norm_plus_one)
     logits = _llama._logits(params, x[:, 0])
-    return logits, jnp.stack(new_k_pages), jnp.stack(new_v_pages)
+    with jax.named_scope("pool.update"):
+        return logits, jnp.stack(new_k_pages), jnp.stack(new_v_pages)
 
 
 @partial(jax.jit, static_argnames=("cfg",))
@@ -303,11 +315,13 @@ def verify_step(params, cfg: MoEConfig, tokens, seq_lens, k_pages,
     new_k_pages, new_v_pages = [], []
     for li, layer in enumerate(params["layers"]):
         q, k, v = _llama._qkv(layer, x, cfg, positions)
-        kp = _llama.scatter_kv_multi(k_pages[li], k, target_page, slot)
-        vp = _llama.scatter_kv_multi(v_pages[li], v, target_page, slot)
-        attn = _llama.paged_verify_attention(
-            q, kp, vp, page_table, seq_lens, window=cfg.window
-        )
+        with jax.named_scope("pool.update"):
+            kp = _llama.scatter_kv_multi(k_pages[li], k, target_page, slot)
+            vp = _llama.scatter_kv_multi(v_pages[li], v, target_page, slot)
+        with jax.named_scope("attn.kernel"):
+            attn = _llama.paged_verify_attention(
+                q, kp, vp, page_table, seq_lens, window=cfg.window
+            )
         x = x + _llama._attn_out(layer, attn.reshape(b, m, -1))
         # Ragged padding + inactive rows stay out of expert capacity.
         moe_out, _aux = _moe_mlp(layer, x, cfg, ok)
@@ -316,7 +330,8 @@ def verify_step(params, cfg: MoEConfig, tokens, seq_lens, k_pages,
         new_v_pages.append(vp)
     x = rms_norm(x, params["final_ln"], cfg.norm_eps, cfg.norm_plus_one)
     logits = _llama._logits(params, x)
-    return logits, jnp.stack(new_k_pages), jnp.stack(new_v_pages)
+    with jax.named_scope("pool.update"):
+        return logits, jnp.stack(new_k_pages), jnp.stack(new_v_pages)
 
 
 def loss_fn(params, cfg: MoEConfig, tokens):

@@ -49,6 +49,7 @@ out-of-range page ids dropped — no recompilation as counts vary.
 
 import hashlib
 import logging
+import time
 from dataclasses import dataclass, field
 from functools import partial
 
@@ -58,6 +59,7 @@ import numpy as np
 
 from .lib import InfiniStoreKeyNotFound
 from .models import llama
+from .utils import profiling
 
 
 def content_page_digests(tokens, page_size, n_pages, namespace=""):
@@ -168,6 +170,11 @@ class Request:
     #                           generated token as it is produced (incl.
     #                           across preemptions; a mid-draft EOS
     #                           truncation emits only the kept tokens)
+    arrived_ns: int = field(default_factory=time.time_ns)
+    #                         # unix ns at which the request reached the
+    #                           system (the HTTP edge stamps it before it
+    #                           reads the body): the origin of its
+    #                           istpu.sched.queue_wait span
 
 
 @dataclass
@@ -192,10 +199,15 @@ class _Work:
     #                       would throttle the running slots' decode
     #                       (invalidated whenever prompt changes:
     #                       preemption)
+    queued_ns: int = 0    # unix ns it entered the queue (the request's
+    #                       arrival; after a preemption, the swap-out)
+    queue_len: int = 0    # requests that were ahead of it then
 
     def __post_init__(self):
         if self.req.temperature > 0 and self.rng is None:
             self.rng = np.random.default_rng(self.req.seed)
+        if not self.queued_ns:
+            self.queued_ns = self.req.arrived_ns
 
 
 class _AdmitPagesRefunded(Exception):
@@ -316,14 +328,16 @@ def _admit_fused(params, cfg, tokens, k_pages, v_pages, ids, s_real,
     logits, kvs = model.prefill(params, cfg, tokens)
     page = cfg.page_size
     n = tokens.shape[1] // page
-    k_sfx = jnp.stack([k[0] for k, _ in kvs])  # [L, s_pad, kv, hd]
-    v_sfx = jnp.stack([v[0] for _, v in kvs])
-    kp = k_sfx.reshape(cfg.n_layers, n, page, cfg.n_kv_heads, cfg.head_dim)
-    vp = v_sfx.reshape(cfg.n_layers, n, page, cfg.n_kv_heads, cfg.head_dim)
-    m = ids.shape[0]
-    pad = ((0, 0), (0, m - n), (0, 0), (0, 0), (0, 0))
-    k_pages = k_pages.at[:, ids].set(jnp.pad(kp, pad), mode="drop")
-    v_pages = v_pages.at[:, ids].set(jnp.pad(vp, pad), mode="drop")
+    with jax.named_scope("pool.update"):  # stage names: models/llama.py
+        k_sfx = jnp.stack([k[0] for k, _ in kvs])  # [L, s_pad, kv, hd]
+        v_sfx = jnp.stack([v[0] for _, v in kvs])
+        shape = (cfg.n_layers, n, page, cfg.n_kv_heads, cfg.head_dim)
+        kp = k_sfx.reshape(shape)
+        vp = v_sfx.reshape(shape)
+        m = ids.shape[0]
+        pad = ((0, 0), (0, m - n), (0, 0), (0, 0), (0, 0))
+        k_pages = k_pages.at[:, ids].set(jnp.pad(kp, pad), mode="drop")
+        v_pages = v_pages.at[:, ids].set(jnp.pad(vp, pad), mode="drop")
     return logits[0, s_real - 1], k_pages, v_pages
 
 
@@ -352,8 +366,9 @@ def _write_pages(k_pool, v_pool, ids, k_new, v_new):
     """Scatter per-layer pages into the pool at `ids` ([m] int32; entries
     == total_pages are out of range and dropped — fixed arity, no
     recompiles as counts vary). k_new/v_new: [L, m, page, n_kv, hd]."""
-    k_pool = k_pool.at[:, ids].set(k_new, mode="drop")
-    v_pool = v_pool.at[:, ids].set(v_new, mode="drop")
+    with jax.named_scope("pool.update"):
+        k_pool = k_pool.at[:, ids].set(k_new, mode="drop")
+        v_pool = v_pool.at[:, ids].set(v_new, mode="drop")
     return k_pool, v_pool
 
 
@@ -431,7 +446,17 @@ class ServingEngine:
             "offloaded_pages": 0, "preemptions": 0, "store_errors": 0,
             "restore_misses": 0, "spec_proposed": 0, "spec_accepted": 0,
             "chunk_steps": 0, "burst_steps": 0, "prefetched_pages": 0,
+            # admissions that returned for want of pool pages
+            "admit_retries": 0,
+            # XLA programs built (or read from the persistent cache)
+            # inside a step: 0 once every shape is warm
+            "compilations": 0,
         }
+        self.engine_id = profiling.next_engine_id()
+        # One sequence page over every layer and both kinds, in bytes.
+        self._page_bytes = (
+            2 * L * int(np.prod(cfg.kv_page_shape())) * cfg.jdtype.itemsize
+        )
         # The store is an accelerator, never a dependency: after the
         # first store failure the engine downgrades itself to store-less
         # serving (full prefills, no offload) instead of failing
@@ -477,6 +502,11 @@ class ServingEngine:
     def _to_device(self, host):
         """Host array -> the engine's device (None: jax's default)."""
         return jax.device_put(host, self.device)
+
+    def _span(self, name, request=None, **fields):
+        """A span of this engine in the program's ring
+        (utils/profiling.py); docs/serving.md lists the names."""
+        return profiling.span(name, request, self.engine_id, **fields)
 
     def _weights_fingerprint(self):
         """Cheap checkpoint identity for the store-key namespace: sha256
@@ -558,7 +588,8 @@ class ServingEngine:
                 f"request needs {need} pages > max_pages_per_seq "
                 f"{self.sc.max_pages_per_seq}"
             )
-        self.queue.append(_Work(req=req, prompt=list(req.prompt)))
+        self.queue.append(_Work(req=req, prompt=list(req.prompt),
+                                queue_len=len(self.queue)))
         self.stats["requests"] += 1
 
     def _alloc(self, n):
@@ -611,18 +642,21 @@ class ServingEngine:
         cap = (len(work.prompt) - 1) // self.cfg.page_size
         if cap == 0:
             return 0, []
-        digests = self._digests(work.prompt, cap)
-        try:
-            hit = self.store.cached_prefix_len(
-                content_page_keys(work.prompt, self.cfg.page_size, cap, 0,
-                                  "k", digests=digests)
-            )
-        except Exception as e:
-            self._store_failed("probe", e)
-            return 0, []
-        hit = min(hit, cap)
-        if hit > 0:
-            self._prefetch_chain(work.prompt, hit, digests[:hit])
+        with self._span("istpu.cache.probe", work.req.request_id,
+                        pages=cap) as f:
+            digests = self._digests(work.prompt, cap)
+            try:
+                hit = self.store.cached_prefix_len(
+                    content_page_keys(work.prompt, self.cfg.page_size,
+                                      cap, 0, "k", digests=digests)
+                )
+            except Exception as e:
+                self._store_failed("probe", e)
+                return 0, []
+            hit = min(hit, cap)
+            f["hit_pages"] = hit
+            if hit > 0:
+                self._prefetch_chain(work.prompt, hit, digests[:hit])
         return hit, digests[:hit]
 
     def _prefetch_chain(self, prompt, hit, digests):
@@ -655,9 +689,24 @@ class ServingEngine:
     def _admit(self, slot_idx, work):
         n_prompt = len(work.prompt)
         n_pages = -(-n_prompt // self.cfg.page_size)
-        return self._do_admit(slot_idx, work, n_prompt, n_pages)
+        rid = work.req.request_id
+        t0_ns = time.time_ns()
+        with self._span("istpu.sched.admit", rid, slot=slot_idx,
+                        prompt_tokens=n_prompt, hit_pages=0) as f:
+            admitted = self._do_admit(slot_idx, work, n_prompt, n_pages, f)
+        if admitted:
+            # Known only now that an admission went through: arrival
+            # (or swap-out) to the start of that admission.
+            profiling.record(
+                "istpu.sched.queue_wait", work.queued_ns,
+                t0_ns - work.queued_ns, rid, self.engine_id,
+                slot=slot_idx, queue_len=work.queue_len,
+            )
+        return admitted
 
-    def _do_admit(self, slot_idx, work, n_prompt, n_pages):
+    def _do_admit(self, slot_idx, work, n_prompt, n_pages, f):
+        """`f`: the fields of the admission's span; every way out
+        leaves its `outcome` there."""
         cfg = self.cfg
         page = cfg.page_size
         window = cfg.window
@@ -719,14 +768,16 @@ class ServingEngine:
         # probe, never on the restore.
         ids = self._alloc(n_pages - skip)
         if ids is None:
+            self.stats["admit_retries"] += 1
+            f["outcome"] = "no_pages"
             return False  # pool pressure: stay queued
         return self._admit_with_pages(
             slot_idx, work, ids, n_prompt, n_pages, hit, digests,
-            skip, first_live,
+            skip, first_live, f,
         )
 
     def _admit_with_pages(self, slot_idx, work, ids, n_prompt, n_pages,
-                          hit, digests, skip, first_live):
+                          hit, digests, skip, first_live, f):
         """Everything after a successful allocation, wrapped so that
         ANY escaping exception (restore-side OOM building prefix_kvs,
         prefill failure, connection loss) refunds the pages — `ids`
@@ -735,36 +786,63 @@ class ServingEngine:
         try:
             return self._admit_restore_and_prefill(
                 slot_idx, work, ids, n_prompt, n_pages, hit, digests,
-                skip, first_live,
+                skip, first_live, f,
             )
         except _AdmitPagesRefunded:
+            self.stats["admit_retries"] += 1
+            f["outcome"] = "refunded"
             return False
         except BaseException:
             self.free_pages.extend(self._admit_ids_view)
             raise
 
+    def _restore(self, prompt, hit, digests, first_live=0):
+        """Pages [first_live, hit) of `prompt`'s chain, store -> HBM in
+        pool form: (k, v) [n_layers, hit - first_live, page, n_kv, hd].
+        Digests are layer/kind-independent and come from the probe — the
+        prompt is hashed ONCE per admission."""
+        n = hit - first_live
+
+        def get(keys, page_shape, dtype):
+            # The span times the store call alone — the interval a
+            # span around get_kv_pages from outside times too; building
+            # the keys and splitting the result are the admission's own.
+            with self._span("istpu.cache.restore", pages=n,
+                            bytes=n * self._page_bytes):
+                return self._get_pages(keys, page_shape, dtype)
+
+        return llama.restore_prefix_pages(
+            self.store, self.cfg,
+            lambda li, kind: content_page_keys(
+                prompt, self.cfg.page_size, hit, li, kind, digests=digests
+            )[first_live:],
+            n, getter=get,
+        )
+
+    def _to_kv(self, kp, vp):
+        """Restored pages in the contiguous per-layer form the one-shot
+        suffix prefill attends over."""
+        n_tokens = kp.shape[1] * self.cfg.page_size
+        with self._span("istpu.cache.to_kv", tokens=n_tokens):
+            return [
+                llama.pages_to_kv(self.cfg, kp[li][None], vp[li][None],
+                                  n_tokens)
+                for li in range(self.cfg.n_layers)
+            ]
+
     def _admit_restore_and_prefill(self, slot_idx, work, ids, n_prompt,
                                    n_pages, hit, digests, skip,
-                                   first_live):
+                                   first_live, f):
         cfg = self.cfg
-        page = cfg.page_size
         self._admit_ids_view = ids
         prefix_kvs = None
         kp = vp = None
         if hit > 0:
             # Restore the in-window hit pages once (into HBM tensors;
-            # pool placement follows in _do_admit_paged). Digests are
-            # layer/kind-independent and come from the probe — the
-            # prompt is hashed ONCE per admission.
+            # pool placement follows in _do_admit_paged).
             try:
-                kp, vp = llama.restore_prefix_pages(
-                    self.store, cfg,
-                    lambda li, kind: content_page_keys(
-                        work.prompt, page, hit, li, kind, digests=digests
-                    )[first_live:],
-                    hit - first_live,
-                    getter=self._get_pages,
-                )
+                kp, vp = self._restore(work.prompt, hit, digests,
+                                       first_live)
             except InfiniStoreKeyNotFound:
                 # Routine eviction race: the page was LRU-dropped
                 # between probe and restore. A cache MISS for this
@@ -779,11 +857,7 @@ class ServingEngine:
                 if self.sc.prefill_chunk == 0:
                     # Contiguous form for the one-shot suffix prefill;
                     # the chunked path attends straight over the pages.
-                    prefix_kvs = [
-                        llama.pages_to_kv(cfg, kp[li][None], vp[li][None],
-                                          (hit - first_live) * page)
-                        for li in range(cfg.n_layers)
-                    ]
+                    prefix_kvs = self._to_kv(kp, vp)
                 self.stats["prefix_hit_pages"] += hit
                 self.stats["restored_pages"] += (
                     (hit - first_live) * cfg.n_layers * 2
@@ -800,11 +874,13 @@ class ServingEngine:
                 self._admit_ids_view = ids
                 first_live = 0
                 skip = 0
+        f["hit_pages"] = hit
         self._do_admit_paged(
             slot_idx, work, ids, n_prompt, n_pages, hit, skip,
             first_live, prefix_kvs, kp, vp,
         )
         work.probe = None  # consumed; a future re-admission re-probes
+        f["outcome"] = "admitted"
         return True
 
     def _do_admit_paged(self, slot_idx, work, ids, n_prompt, n_pages,
@@ -822,11 +898,13 @@ class ServingEngine:
             # restored tensors ([first_live, hit)) and the pool targets
             # ([skip, hit)) line up exactly.
             assert skip == first_live, (skip, first_live)
-            self._pool_write(
-                ids[: hit - skip],
-                kp[:, : hit - first_live],
-                vp[:, : hit - first_live],
-            )
+            with self._span("istpu.cache.pool_write", what="restored",
+                            pages=hit - skip):
+                self._pool_write(
+                    ids[: hit - skip],
+                    kp[:, : hit - first_live],
+                    vp[:, : hit - first_live],
+                )
 
         row = np.zeros(self.sc.max_pages_per_seq, dtype=np.int32)
         row[skip:n_pages] = ids
@@ -846,50 +924,22 @@ class ServingEngine:
             self._release_windowed(self.slots[slot_idx])
             return
 
-        # Suffix prefill, bucketed to a page multiple (causal attention
-        # makes tail padding inert for the positions we read).
         suffix = work.prompt[hit * page:]
-        s_real = len(suffix)
-        s_pad = -(-s_real // page) * page
-        toks = np.zeros((1, s_pad), dtype=np.int32)
-        toks[0, :s_real] = suffix
-        toks = self._to_device(toks)
         if prefix_kvs is None:
-            # Cold admission (hit == 0): one fused device program does
-            # prefill + page-out + pool scatter + logits-row slice.
-            # Dead prompt pages [0, skip) scatter to the drop sentinel:
-            # no pool page was allocated for them.
-            row_dev, self.k_pages, self.v_pages = _admit_fused(
-                self.params, cfg, toks, self.k_pages, self.v_pages,
-                self._to_device(self._pad_ids(ids, offset=skip)),
-                self._to_device(np.int32(s_real)),
-                model=self.model,
-            )
-            row_host = np.asarray(row_dev)
+            # Cold admission (hit == 0). Dead prompt pages [0, skip)
+            # scatter to the drop sentinel: no pool page was allocated
+            # for them.
+            row_host = self._prefill_cold(
+                suffix, self._pad_ids(ids, offset=skip))
         else:
-            # pos0 anchors the trimmed prefix's absolute rope
-            # positions; the band mask is relative, so local indices
-            # inside the kernel stay correct (llama._forward_stack).
-            logits, kvs = self._prefill_px(
-                toks, prefix_kvs, self._to_device(np.int32(first_live * page))
-            )
-            # Page out the suffix KV into the pool (real tokens
-            # only). A hit implies skip = first_live <= hit, so every
-            # suffix page has a pool id; sub-floor suffix pages (if
-            # any are below the post-admission floor) are materialized
-            # here and freed by the _release_windowed below, AFTER
+            # A hit implies skip = first_live <= hit, so every suffix
+            # page has a pool id; sub-floor suffix pages (if any are
+            # below the post-admission floor) are materialized here
+            # and freed by the _release_windowed below, AFTER
             # offloading — keeping the prefix chain gap-free.
-            k_sfx = jnp.stack([k[:, :s_real] for k, _ in kvs])
-            v_sfx = jnp.stack([v[:, :s_real] for _, v in kvs])
-            kp_s, vp_s = [], []
-            for li in range(cfg.n_layers):
-                a, b = llama.kv_to_pages(cfg, k_sfx[li], v_sfx[li])
-                kp_s.append(a[0])
-                vp_s.append(b[0])
-            self._pool_write(ids[hit - skip:], jnp.stack(kp_s),
-                             jnp.stack(vp_s))
-            row_host = np.asarray(logits[0, s_real - 1])
-        self.stats["prefill_tokens"] += s_real
+            row_host = self._prefill_hit(
+                suffix, prefix_kvs, first_live * page, ids[hit - skip:])
+        self.stats["prefill_tokens"] += len(suffix)
 
         self.page_table[slot_idx] = row
 
@@ -906,6 +956,90 @@ class ServingEngine:
         # already trimmed to [first_live, hit) — only the PROBE's key
         # list stays O(prompt), it is hash-only).
         self._release_windowed(slot)
+
+    def _pad_tokens(self, tokens):
+        """Prompt tokens as the [1, s_pad] device array the prefill
+        programs take: bucketed to a page multiple (causal attention
+        makes tail padding inert for the positions we read)."""
+        page = self.cfg.page_size
+        toks = np.zeros((1, -(-len(tokens) // page) * page), dtype=np.int32)
+        toks[0, :len(tokens)] = tokens
+        return self._to_device(toks)
+
+    def _prefill_cold(self, tokens, ids_padded):
+        """The cold program: ONE fused device program does prefill +
+        page-out + pool scatter at `ids_padded` (_pad_ids form) +
+        logits-row slice. Returns the last real position's logits row,
+        on the host."""
+        toks = self._pad_tokens(tokens)
+        with self._span("istpu.model.prefill", program="cold",
+                        tokens=len(tokens), padded_tokens=toks.shape[1]):
+            row_dev, self.k_pages, self.v_pages = _admit_fused(
+                self.params, self.cfg, toks, self.k_pages, self.v_pages,
+                self._to_device(ids_padded),
+                self._to_device(np.int32(len(tokens))),
+                model=self.model,
+            )
+            return np.asarray(row_dev)
+
+    def _prefill_hit(self, suffix, prefix_kvs, pos0, ids=None):
+        """The prefix program: the suffix attends over the restored
+        `prefix_kvs`. pos0 anchors the trimmed prefix's absolute rope
+        positions; the band mask is relative, so local indices inside
+        the kernel stay correct (llama._forward_stack). With `ids`,
+        the suffix KV (real tokens only) is paged out into the pool
+        there — dispatched while the program runs on the device, so
+        that span lies inside this one. Returns the last real
+        position's logits row, on the host."""
+        cfg = self.cfg
+        s_real = len(suffix)
+        toks = self._pad_tokens(suffix)
+        with self._span("istpu.model.prefill", program="prefix",
+                        tokens=s_real, padded_tokens=toks.shape[1]):
+            logits, kvs = self._prefill_px(
+                toks, prefix_kvs, self._to_device(np.int32(pos0))
+            )
+            if ids is not None:
+                with self._span("istpu.cache.pool_write", what="suffix",
+                                pages=len(ids)):
+                    k_sfx = jnp.stack([k[:, :s_real] for k, _ in kvs])
+                    v_sfx = jnp.stack([v[:, :s_real] for _, v in kvs])
+                    kp_s, vp_s = [], []
+                    for li in range(cfg.n_layers):
+                        a, b = llama.kv_to_pages(cfg, k_sfx[li], v_sfx[li])
+                        kp_s.append(a[0])
+                        vp_s.append(b[0])
+                    self._pool_write(ids, jnp.stack(kp_s), jnp.stack(vp_s))
+            return np.asarray(logits[0, s_real - 1])
+
+    def first_token_logits(self, prompt):
+        """First-token logits of `prompt` through the programs an
+        admission dispatches, without admitting anything: on a miss
+        the cold program with every page id at the drop sentinel (the
+        pool is untouched), on a hit probe + restore + the prefix
+        program. Returns (float32 row [vocab], hit pages). The engine
+        must be idle, and the caller on the thread that steps it."""
+        if self.queue or any(s is not None for s in self.slots):
+            raise RuntimeError("first_token_logits needs an idle engine")
+        prompt = [int(t) for t in prompt]
+        page = self.cfg.page_size
+        window = getattr(self.cfg, "window", 0)
+        work = _Work(req=Request("first-token-logits", prompt),
+                     prompt=prompt)
+        hit, digests = self._probe_hit(work)
+        if hit > 0:
+            first_live = max(0, hit * page - window + 1) // page \
+                if window else 0
+            try:
+                kp, vp = self._restore(prompt, hit, digests, first_live)
+            except InfiniStoreKeyNotFound:
+                hit = 0  # evicted between probe and restore
+        if hit > 0:
+            row = self._prefill_hit(prompt[hit * page:],
+                                    self._to_kv(kp, vp), first_live * page)
+        else:
+            row = self._prefill_cold(prompt, self._pad_ids([]))
+        return np.asarray(row, np.float32), hit
 
     # ---- decode --------------------------------------------------------
 
@@ -967,7 +1101,7 @@ class ServingEngine:
         """The KV being appended this step lands at position seq_len."""
         return self._ensure_pages(slot_idx, slot, slot.seq_len)
 
-    def _offload_full_pages(self, slot, hi=None):
+    def _offload_full_pages(self, slot, hi=None, reason="finish"):
         """Persist the slot's NEW full pages [lo, hi) to the store
         (shared by finish, preemption and windowed release). Offloads
         FULL pages only — partial tail pages would poison page-granular
@@ -994,28 +1128,32 @@ class ServingEngine:
         # loopback RTT per released page — page contents must be
         # durable in the store BEFORE the pool page is freed for
         # reuse.)
-        new_digests = self._slot_digests(slot, n_full)[lo:]
-        try:
-            for li in range(self.cfg.n_layers):
-                sel = self._to_device(
-                    np.asarray(slot.page_ids[lo:n_full], np.int32)
-                )
-                self._put_pages(
-                    content_page_keys([], 0, 0, li, "k",
-                                      digests=new_digests),
-                    jnp.take(self.k_pages[li], sel, axis=0),
-                )
-                self._put_pages(
-                    content_page_keys([], 0, 0, li, "v",
-                                      digests=new_digests),
-                    jnp.take(self.v_pages[li], sel, axis=0),
-                )
-            self.store.conn.sync()
-        except Exception as e:
-            # The sequence's OUTPUT does not depend on the offload;
-            # losing it only costs future cache hits.
-            self._store_failed("offload", e)
-            return
+        with self._span("istpu.cache.offload", slot.work.req.request_id,
+                        reason=reason, pages=n_full - lo,
+                        bytes=(n_full - lo) * self._page_bytes):
+            new_digests = self._slot_digests(slot, n_full)[lo:]
+            try:
+                for li in range(self.cfg.n_layers):
+                    sel = self._to_device(
+                        np.asarray(slot.page_ids[lo:n_full], np.int32)
+                    )
+                    self._put_pages(
+                        content_page_keys([], 0, 0, li, "k",
+                                          digests=new_digests),
+                        jnp.take(self.k_pages[li], sel, axis=0),
+                    )
+                    self._put_pages(
+                        content_page_keys([], 0, 0, li, "v",
+                                          digests=new_digests),
+                        jnp.take(self.v_pages[li], sel, axis=0),
+                    )
+                with self._span("istpu.cache.offload_sync"):
+                    self.store.conn.sync()
+            except Exception as e:
+                # The sequence's OUTPUT does not depend on the offload;
+                # losing it only costs future cache hits.
+                self._store_failed("offload", e)
+                return
         self.stats["offloaded_pages"] += n_full - lo
 
     def _release(self, slot_idx, slot):
@@ -1046,7 +1184,7 @@ class ServingEngine:
         dead = (slot.seq_len - window) // self.cfg.page_size
         if dead <= slot.released:
             return
-        self._offload_full_pages(slot, hi=dead)  # best-effort
+        self._offload_full_pages(slot, hi=dead, reason="window")
         self.free_pages.extend(slot.page_ids[slot.released:dead])
         slot.released = dead
 
@@ -1064,18 +1202,31 @@ class ServingEngine:
         re-admission travels the normal prefix-HIT path — restore the
         cached pages, recompute only the partial tail page — and decoding
         resumes exactly where it left off."""
-        self._offload_full_pages(slot)
+        self._offload_full_pages(slot, reason="preempt")
         work = slot.work
         work.done.extend(slot.generated)
         work.prompt = list(work.prompt) + slot.generated
         work.probe = None  # prompt changed: stale probe
         self._release(slot_idx, slot)
+        work.queued_ns, work.queue_len = time.time_ns(), 0
         self.queue.insert(0, work)
         self.stats["preemptions"] += 1
 
     def step(self):
         """One engine iteration: admit into free slots, then decode one
         token for every active slot. Returns #active slots decoded."""
+        with self._span("istpu.engine.step", kind="idle", active=0,
+                        k=0) as f:
+            c0 = profiling.compilations()
+            try:
+                return self._step(f)
+            finally:
+                f["compiled"] = profiling.compilations() - c0
+                self.stats["compilations"] += f["compiled"]
+
+    def _step(self, f):
+        """step() proper; `f` holds the step span's fields (kind,
+        active slots, k)."""
         for i in range(self.sc.max_slots):
             if self.slots[i] is None and self.queue:
                 if self._admit(i, self.queue[0]):
@@ -1103,6 +1254,8 @@ class ServingEngine:
             return 0
 
         if any(s.pending for _, s in active):
+            f.update(kind="unified", active=len(active),
+                     k=self.sc.prefill_chunk)
             return self._unified_step(active)
 
         if self.sc.spec_k > 0:
@@ -1115,6 +1268,8 @@ class ServingEngine:
                 # A buggy/hostile proposer must not index out of vocab.
                 proposals[i] = [int(t) % self.cfg.vocab_size for t in p]
             if any(proposals.values()):
+                f.update(kind="spec", active=len(active),
+                         k=self.sc.spec_k + 1)
                 return self._spec_decode(active, proposals)
             # Every draft is empty: the plain single-token path below is
             # strictly cheaper (pallas decode kernel, no (k+1)-wide
@@ -1170,6 +1325,8 @@ class ServingEngine:
         # they were pure per-step waste (built, then discarded for the
         # cached device copies) — measured as part of the ~140 us/step
         # scheduler overhead the sched bench leg isolates.
+        f.update(kind="burst" if k > 1 else "decode", active=len(active),
+                 k=k)
         key = (tuple(i for i, _ in active), self._pages_rev)
         if (self._steady is not None and greedy
                 and self._steady[0] == key):
@@ -1187,12 +1344,14 @@ class ServingEngine:
             rows_dev = self._to_device(rows)
 
         if k > 1:
-            toks_dev, lens_next, self.k_pages, self.v_pages = _decode_scan(
-                self.params, self.cfg, token_dev, lens_dev,
-                self.k_pages, self.v_pages, rows_dev, k,
-                model=self.model,
-            )
-            toks = np.asarray(toks_dev)  # [B, k] — the one D2H
+            with self._span("istpu.model.decode", program="decode_scan"):
+                (toks_dev, lens_next, self.k_pages,
+                 self.v_pages) = _decode_scan(
+                    self.params, self.cfg, token_dev, lens_dev,
+                    self.k_pages, self.v_pages, rows_dev, k,
+                    model=self.model,
+                )
+                toks = np.asarray(toks_dev)  # [B, k] — the one D2H
             trimmed = False
             for i, s in active:
                 burst = [int(t) for t in toks[i]]
@@ -1217,13 +1376,14 @@ class ServingEngine:
             )
             return len(active)
 
-        logits, nxt_dev, lens_next, self.k_pages, self.v_pages = (
-            _decode_fused(
-                self.params, self.cfg, token_dev, lens_dev,
-                self.k_pages, self.v_pages, rows_dev, model=self.model,
+        with self._span("istpu.model.decode", program="decode_fused"):
+            logits, nxt_dev, lens_next, self.k_pages, self.v_pages = (
+                _decode_fused(
+                    self.params, self.cfg, token_dev, lens_dev,
+                    self.k_pages, self.v_pages, rows_dev, model=self.model,
+                )
             )
-        )
-        nxt = np.asarray(nxt_dev)
+            nxt = np.asarray(nxt_dev)
         # Reusable next step iff every emitted token is the device's
         # argmax (greedy) — samplers/spec/finishes invalidate via key.
         self._steady = (
@@ -1265,13 +1425,15 @@ class ServingEngine:
         ]
         if not active:
             return [], None, None
-        logits, self.k_pages, self.v_pages = self.model.verify_step(
-            self.params, self.cfg,
-            self._to_device(token), self._to_device(seq_lens),
-            self.k_pages, self.v_pages, self._to_device(rows),
-            self._to_device(valid),
-        )
-        return active, np.asarray(jnp.argmax(logits, axis=-1)), logits
+        with self._span("istpu.model.decode", program="verify"):
+            logits, self.k_pages, self.v_pages = self.model.verify_step(
+                self.params, self.cfg,
+                self._to_device(token), self._to_device(seq_lens),
+                self.k_pages, self.v_pages, self._to_device(rows),
+                self._to_device(valid),
+            )
+            nxt = np.asarray(jnp.argmax(logits, axis=-1))
+        return active, nxt, logits
 
     def _unified_step(self, active):
         """Mixed chunked-prefill + decode batch (vLLM-style): slots

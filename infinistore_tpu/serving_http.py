@@ -23,6 +23,11 @@ API:
   full output and the same timings.
 - ``GET /stats`` — engine counters plus per-request serving metrics:
   requests served, mean/max TTFT ms, mean tok/s, in-flight count.
+  Every timing counts from the request's ARRIVAL — the top of the POST
+  handler, before the body is read — the same stamp the engine's
+  ``istpu.sched.queue_wait`` span starts from.
+- ``GET /trace`` — the program's span ring (utils/profiling.py) as
+  Chrome trace-event JSON, the form the store's ``/trace`` answers in.
 - ``GET /health`` — liveness.
 
 Concurrency model: the engine is single-threaded by design (one jitted
@@ -41,17 +46,21 @@ import uuid
 from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 
 from .serving import Request
+from .utils import profiling
 
 _DONE = object()
 
 
 class _ReqState:
-    __slots__ = ("queue", "submit_t", "first_t", "done_t", "n_tokens",
-                 "tokens")
+    __slots__ = ("queue", "arrived_ns", "submit_t", "first_t", "done_t",
+                 "n_tokens", "tokens")
 
-    def __init__(self):
+    def __init__(self, arrived=None):
+        """`arrived`: the (time.time_ns(), time.perf_counter()) pair
+        taken when the request reached the server; now if None."""
         self.queue = queue.Queue()
-        self.submit_t = time.perf_counter()
+        self.arrived_ns, self.submit_t = arrived or (
+            time.time_ns(), time.perf_counter())
         self.first_t = None
         self.done_t = None
         self.n_tokens = 0
@@ -93,10 +102,15 @@ class ServingHTTPServer:
                     self._json(200, {"status": "ok"})
                 elif self.path == "/stats":
                     self._json(200, outer.stats())
+                elif self.path == "/trace":
+                    self._json(200, profiling.chrome_trace())
                 else:
                     self._json(404, {"error": "not found"})
 
             def do_POST(self):
+                # The arrival stamp, before the body is read: the one
+                # origin of ttft_ms, tok_s and the engine's queue wait.
+                arrived = (time.time_ns(), time.perf_counter())
                 if self.path != "/generate":
                     self._json(404, {"error": "not found"})
                     return
@@ -110,7 +124,7 @@ class ServingHTTPServer:
                 stream = bool(req.get("stream", True))
                 try:
                     rid, st = outer.submit_request(
-                        prompt,
+                        prompt, arrived=arrived,
                         max_new_tokens=int(req.get("max_new_tokens", 16)),
                         temperature=float(req.get("temperature", 0.0)),
                         top_k=int(req.get("top_k", 0)),
@@ -119,6 +133,22 @@ class ServingHTTPServer:
                 except ValueError as e:
                     self._json(400, {"error": str(e)})
                     return
+                try:
+                    self._respond(rid, st, stream)
+                finally:
+                    # Arrival to the last token delivered (or to the
+                    # client going away).
+                    first = st.first_t
+                    profiling.record(
+                        "istpu.http.request", st.arrived_ns,
+                        (time.perf_counter() - st.submit_t) * 1e9,
+                        rid, outer.engine.engine_id,
+                        prompt_tokens=len(prompt), tokens_out=st.n_tokens,
+                        first_token_ns=None if first is None
+                        else int((first - st.submit_t) * 1e9),
+                    )
+
+            def _respond(self, rid, st, stream):
                 if not stream:
                     while True:
                         item = outer._next_item(rid, st)
@@ -154,7 +184,7 @@ class ServingHTTPServer:
 
     # -- engine side ---------------------------------------------------
 
-    def submit_request(self, prompt, **kw):
+    def submit_request(self, prompt, arrived=None, **kw):
         # Validate BEFORE registering: a rejected request must not leave
         # an orphaned _ReqState inflating the in-flight count forever.
         # (These mirror engine.submit's cheap checks so the HTTP client
@@ -164,7 +194,7 @@ class ServingHTTPServer:
         if kw.get("max_new_tokens", 16) < 1:
             raise ValueError("max_new_tokens must be >= 1")
         rid = uuid.uuid4().hex[:16]
-        st = _ReqState()
+        st = _ReqState(arrived)
 
         def on_token(_rid, tok):
             if st.first_t is None:
@@ -172,7 +202,8 @@ class ServingHTTPServer:
             st.n_tokens += 1
             st.queue.put(int(tok))
 
-        req = Request(rid, prompt, on_token=on_token, **kw)
+        req = Request(rid, prompt, on_token=on_token,
+                      arrived_ns=st.arrived_ns, **kw)
         # Register and enqueue under ONE lock hold, with the _broken
         # check inside it: the engine's failure path flips _broken and
         # snapshots _reqs under the same lock, so every request is

@@ -37,6 +37,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from .lib import InfinityConnection
+from .utils import profiling
 
 
 # Published per-chip peaks for utilization accounting, keyed by jax's
@@ -135,7 +136,9 @@ def _to_host(arr):
         return np.ascontiguousarray(arr)
     shape = arr.shape
     flat = _flatten_on_device(arr)
-    host = np.asarray(flat)
+    # Also waits for whatever produces `arr` (the engine's page gather).
+    with profiling.span("istpu.xfer.d2h", bytes=arr.nbytes):
+        host = np.asarray(flat)
     copy_counters["d2h_copies"] += 1
     copy_counters["d2h_bytes"] += host.nbytes
     if not host.flags["C_CONTIGUOUS"]:  # defensive: 1-D should be flat
@@ -156,9 +159,10 @@ def _device_put_owned(view, device):
     targets PJRT may alias an aligned contiguous host buffer
     (kImmutableZeroCopy) — force a private copy there."""
     platform = device.platform if device is not None else jax.default_backend()
-    if platform == "cpu":
-        view = np.array(view, copy=True)
-    return jax.block_until_ready(jax.device_put(view, device))
+    with profiling.span("istpu.xfer.h2d", bytes=view.nbytes):
+        if platform == "cpu":
+            view = np.array(view, copy=True)
+        return jax.block_until_ready(jax.device_put(view, device))
 
 
 def _abort_uncommitted(conn, blocks, keys=None):

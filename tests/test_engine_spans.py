@@ -1,0 +1,611 @@
+"""The program's own spans (utils/profiling.py): the tree an admission,
+a hit and a finish leave in the ring, the counters beside them, the
+ring's bound, and the ring's clock against the jax profiler's.
+
+CPU, tiny widths, the in-process loop-back store of conftest.py.
+"""
+
+import glob
+import gzip
+import json
+import os
+import sys
+import threading
+import time
+import types
+import urllib.request
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from infinistore_tpu.models import llama, moe
+from infinistore_tpu.serving import (
+    Request, ServingConfig, ServingEngine, _Work,
+)
+from infinistore_tpu.serving_http import ServingHTTPServer
+from infinistore_tpu.tpu import TpuKVStore
+from infinistore_tpu.utils import profiling
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+PAGE = 8
+# time_ns() stamps a start and perf_counter_ns() measures a duration:
+# two clocks, read a few hundred ns apart. Containment holds to this.
+SLACK_NS = 1_000_000
+
+
+@pytest.fixture(scope="module")
+def cfg():
+    return llama.LlamaConfig(
+        vocab_size=128, d_model=64, n_layers=2, n_heads=4, n_kv_heads=2,
+        d_ff=128, max_seq=128, page_size=PAGE, dtype="float32",
+    )
+
+
+@pytest.fixture(scope="module")
+def params(cfg):
+    return llama.init_params(jax.random.PRNGKey(0), cfg)
+
+
+def _prompt(seed, n, vocab=128):
+    rng = np.random.default_rng(seed)
+    return [int(t) for t in rng.integers(0, vocab, n)]
+
+
+def _engine(params, cfg, conn, model_id, **sc):
+    sc.setdefault("max_slots", 2)
+    sc.setdefault("total_pages", 64)
+    return ServingEngine(
+        params, cfg, ServingConfig(model_id=model_id, **sc),
+        store=None if conn is None else TpuKVStore(conn),
+    )
+
+
+def _run(eng, *reqs):
+    """Drive `reqs` to completion; returns the spans recorded since
+    the first of them arrived."""
+    t0 = min(r.arrived_ns for r in reqs)
+    eng.run(list(reqs))
+    return profiling.spans(since_ns=t0)
+
+
+def _named(spans, name, **fields):
+    return [s for s in spans if s.name == name
+            and all(s.fields.get(k) == v for k, v in fields.items())]
+
+
+def _children(spans, parent):
+    return sorted((s for s in spans if s.parent == parent.id),
+                  key=lambda s: s.t0_ns)
+
+
+def _inside(child, parent):
+    """A child never outlasts its parent, nor starts before it — but
+    for a queue wait, which began long before the step that ended it."""
+    return ((child.t0_ns >= parent.t0_ns - SLACK_NS
+             or child.name == "istpu.sched.queue_wait")
+            and child.t0_ns + child.dur_ns
+            <= parent.t0_ns + parent.dur_ns + SLACK_NS)
+
+
+def test_miss_span_tree(params, cfg, shm_conn):
+    eng = _engine(params, cfg, shm_conn, "spans-miss")
+    spans = _run(eng, Request("m1", _prompt(1, 3 * PAGE + 2),
+                              max_new_tokens=3))
+    (admit,) = _named(spans, "istpu.sched.admit")
+    assert admit.request == "m1" and admit.engine == eng.engine_id
+    assert admit.fields["outcome"] == "admitted"
+    assert admit.fields["hit_pages"] == 0
+    assert admit.fields["prompt_tokens"] == 3 * PAGE + 2
+    step = next(s for s in spans if s.id == admit.parent)
+    assert step.name == "istpu.engine.step"
+    kids = _children(spans, admit)
+    assert [k.name for k in kids] == ["istpu.cache.probe",
+                                      "istpu.model.prefill"]
+    assert all(k.request == "m1" for k in kids)
+    assert kids[0].fields == {"pages": 3, "hit_pages": 0}
+    assert kids[1].fields == {"program": "cold", "tokens": 3 * PAGE + 2,
+                              "padded_tokens": 4 * PAGE}
+    # The cold program writes the pool itself: no separate pool write.
+    assert not _named(spans, "istpu.cache.pool_write")
+    # Queue wait: recorded after the fact, from the request's arrival
+    # to the start of the admission, under the step that admitted it.
+    (wait,) = _named(spans, "istpu.sched.queue_wait")
+    assert wait.request == "m1" and wait.parent == step.id
+    assert wait.fields == {"slot": 0, "queue_len": 0}
+    assert abs(wait.t0_ns + wait.dur_ns - admit.t0_ns) < SLACK_NS
+
+
+def test_hit_span_tree(params, cfg, shm_conn):
+    eng = _engine(params, cfg, shm_conn, "spans-hit")
+    first = _prompt(2, 4 * PAGE)
+    out = eng.run([Request("h0", first, max_new_tokens=PAGE)])["h0"]
+    follow = first + out + _prompt(3, 5)
+    spans = _run(eng, Request("h1", follow, max_new_tokens=2))
+    (admit,) = _named(spans, "istpu.sched.admit")
+    hit = admit.fields["hit_pages"]
+    # 4 prompt pages + the full pages the first answer completed.
+    assert hit == (len(first) + len(out) - 1) // PAGE == 4
+    kids = _children(spans, admit)
+    assert [k.name for k in kids] == [
+        "istpu.cache.probe", "istpu.cache.restore", "istpu.cache.to_kv",
+        "istpu.cache.pool_write", "istpu.model.prefill"]
+    assert all(k.request == "h1" for k in kids)
+    probe, restore, to_kv, pool, prefill = kids
+    assert probe.fields["hit_pages"] == hit
+    # restore: bytes are pages x the bytes of one page over every layer
+    # and both kinds, and its transfer is one h2d of as many bytes.
+    page_bytes = 2 * cfg.n_layers * cfg.kv_page_bytes()
+    assert restore.fields == {"pages": hit, "bytes": hit * page_bytes}
+    (h2d,) = _children(spans, restore)
+    assert h2d.name == "istpu.xfer.h2d"
+    assert h2d.fields["bytes"] == hit * page_bytes
+    assert to_kv.fields == {"tokens": hit * PAGE}
+    assert pool.fields == {"what": "restored", "pages": hit}
+    n_sfx = len(follow) - hit * PAGE
+    assert prefill.fields == {"program": "prefix", "tokens": n_sfx,
+                              "padded_tokens": -(-n_sfx // PAGE) * PAGE}
+    # The suffix's pool write is dispatched while the prefix program
+    # runs, so it lies inside that span.
+    (sfx,) = _children(spans, prefill)
+    assert sfx.name == "istpu.cache.pool_write"
+    assert sfx.fields == {"what": "suffix", "pages": -(-n_sfx // PAGE)}
+
+
+def test_finish_span_tree(params, cfg, shm_conn):
+    eng = _engine(params, cfg, shm_conn, "spans-finish")
+    spans = _run(eng, Request("f1", _prompt(4, 2 * PAGE + 1),
+                              max_new_tokens=PAGE))
+    (off,) = _named(spans, "istpu.cache.offload")
+    # 2 * PAGE + 1 prompt tokens + PAGE - 1 decoded ones have KV.
+    assert off.request == "f1" and off.fields["reason"] == "finish"
+    assert off.fields["pages"] == 3
+    assert off.fields["bytes"] == 3 * 2 * cfg.n_layers * cfg.kv_page_bytes()
+    assert eng.stats["offloaded_pages"] == 3
+    kids = _children(spans, off)
+    assert [k.name for k in kids] == (
+        ["istpu.xfer.d2h"] * (2 * cfg.n_layers)
+        + ["istpu.cache.offload_sync"])
+    assert sum(k.fields["bytes"] for k in kids[:-1]) == off.fields["bytes"]
+    assert all(k.request == "f1" for k in kids)
+    step = next(s for s in spans if s.id == off.parent)
+    assert step.name == "istpu.engine.step"
+
+
+def test_children_inside_parents_and_steps_hold_their_sum(
+        params, cfg, shm_conn):
+    eng = _engine(params, cfg, shm_conn, "spans-sum", max_slots=3)
+    base = _prompt(5, 3 * PAGE)
+    spans = _run(eng, *[
+        Request(f"s{i}", base + _prompt(10 + i, 3 + i), max_new_tokens=6)
+        for i in range(5)])
+    by_id = {s.id: s for s in spans}
+    for s in spans:
+        if s.parent:
+            assert _inside(s, by_id[s.parent]), (s, by_id[s.parent])
+    steps = _named(spans, "istpu.engine.step")
+    assert len(steps) > 6
+    for st in steps:
+        kids = _children(spans, st)
+        # queue_wait is a wait that ended inside the step, not work
+        # done in it.
+        work = sum(k.dur_ns for k in kids
+                   if k.name != "istpu.sched.queue_wait")
+        assert work <= st.dur_ns + SLACK_NS
+    # A step that only finishes what is left runs no program.
+    assert {st.fields["kind"] for st in steps} == {"decode", "idle"}
+    assert steps[-1].fields["kind"] == "idle"
+    decodes = _named(spans, "istpu.model.decode", program="decode_fused")
+    assert len(decodes) == eng.stats["decode_steps"]
+    assert {by_id[d.parent].name for d in decodes} == {"istpu.engine.step"}
+    assert max(st.fields["active"] for st in steps) == 3
+
+
+def test_step_kinds_burst_unified_spec(params, cfg):
+    def kinds(**sc):
+        eng = _engine(params, cfg, None, "spans-kinds", **sc)
+        eng.proposer = lambda ctx, k: [1] * k
+        prompt = _prompt(6, 2 * PAGE + 3)
+        spans = _run(eng, Request("k", prompt + prompt, max_new_tokens=10))
+        return ({s.fields["kind"] for s in _named(spans, "istpu.engine.step")}
+                - {"idle"},
+                {s.fields["program"]
+                 for s in _named(spans, "istpu.model.decode")})
+
+    assert kinds(host_steps=4) == ({"burst", "decode"},
+                                   {"decode_scan", "decode_fused"})
+    assert kinds(prefill_chunk=PAGE) == ({"unified", "decode"},
+                                         {"verify", "decode_fused"})
+    k, p = kinds(spec_k=3)
+    assert "spec" in k and "verify" in p
+
+
+def test_one_request_id_from_http_to_offload(params, cfg, shm_conn):
+    eng = _engine(params, cfg, shm_conn, "spans-http")
+    srv = ServingHTTPServer(eng, port=0)
+    port = srv.start()
+    t0 = time.time_ns()
+    try:
+        req = urllib.request.Request(
+            f"http://127.0.0.1:{port}/generate",
+            data=json.dumps({"prompt": _prompt(7, 2 * PAGE + 2),
+                             "max_new_tokens": PAGE,
+                             "stream": False}).encode(), method="POST")
+        with urllib.request.urlopen(req, timeout=120) as r:
+            res = json.loads(r.read())
+        deadline = time.time() + 30
+        while time.time() < deadline and not _named(
+                profiling.spans(since_ns=t0), "istpu.http.request"):
+            time.sleep(0.01)  # recorded after the response is written
+    finally:
+        srv.shutdown()
+    rid = res["request_id"]
+    mine = [s for s in profiling.spans(since_ns=t0) if s.request == rid]
+    names = {s.name for s in mine}
+    assert {"istpu.http.request", "istpu.sched.queue_wait",
+            "istpu.sched.admit", "istpu.cache.probe",
+            "istpu.model.prefill", "istpu.cache.offload",
+            "istpu.xfer.d2h", "istpu.cache.offload_sync"} <= names
+    (http,) = _named(mine, "istpu.http.request")
+    (wait,) = _named(mine, "istpu.sched.queue_wait")
+    (admit,) = _named(mine, "istpu.sched.admit")
+    (off,) = _named(mine, "istpu.cache.offload")
+    # One origin: the queue wait starts at the stamp the handler took
+    # before it read the body, and so does the server's TTFT.
+    assert wait.t0_ns == http.t0_ns
+    assert http.fields["prompt_tokens"] == 2 * PAGE + 2
+    assert http.fields["tokens_out"] == PAGE
+    first_ns = http.fields["first_token_ns"]
+    assert abs(first_ns / 1e6 - res["ttft_ms"]) < 0.01
+    assert wait.dur_ns + admit.dur_ns <= first_ns + SLACK_NS
+    assert _inside(admit, http) and _inside(off, http)
+    assert {s.engine for s in mine} == {eng.engine_id}
+
+
+def test_admit_retries_and_the_second_queue_wait(params, cfg, shm_conn):
+    # 10 usable pages: the second request (4 + growth) cannot be
+    # admitted beside the first until that one is done; the first
+    # outgrows the pool's rest beside the second and is preempted.
+    eng = _engine(params, cfg, shm_conn, "spans-retry", total_pages=11,
+                  max_pages_per_seq=10)
+    t0 = time.time_ns()
+    eng.submit(Request("a", _prompt(8, 5 * PAGE), max_new_tokens=3 * PAGE))
+    eng.step()
+    eng.submit(Request("b", _prompt(9, 4 * PAGE), max_new_tokens=PAGE))
+    eng.run()
+    spans = profiling.spans(since_ns=t0)
+    refused = _named(spans, "istpu.sched.admit", outcome="no_pages")
+    assert refused and eng.stats["admit_retries"] == len(refused)
+    assert {s.request for s in refused} <= {"a", "b"}
+    assert all([k.name for k in _children(spans, s)]
+               in ([], ["istpu.cache.probe"]) for s in refused)
+    assert eng.stats["preemptions"] >= 1
+    swapped = _named(spans, "istpu.cache.offload", reason="preempt")
+    assert len(swapped) == eng.stats["preemptions"]
+    victim = swapped[0].request
+    waits = _named(spans, "istpu.sched.queue_wait")
+    # One wait an admission: the victim's second one starts at its
+    # swap-out, not at its arrival.
+    assert len(waits) == len(_named(spans, "istpu.sched.admit",
+                                    outcome="admitted"))
+    mine = sorted((w for w in waits if w.request == victim),
+                  key=lambda w: w.t0_ns)
+    assert len(mine) == 1 + sum(1 for s in swapped if s.request == victim)
+    assert mine[1].t0_ns >= swapped[0].t0_ns
+
+
+def test_compilations_are_counted_in_the_step_that_paid(cfg):
+    # A width no other test of this process uses: its programs are new.
+    import dataclasses
+
+    cfg = dataclasses.replace(cfg, vocab_size=136)
+    params = llama.init_params(jax.random.PRNGKey(1), cfg)
+    eng = _engine(params, cfg, None, "spans-compile")
+    spans = _run(eng, Request("c", _prompt(11, PAGE + 1, 136),
+                              max_new_tokens=4))
+    steps = _named(spans, "istpu.engine.step")
+    built = [s.fields["compiled"] for s in steps]
+    assert built[0] >= 1                      # the cold program
+    assert sum(built) == eng.stats["compilations"] >= 2
+    assert built[-1] == 0                     # a warm decode step
+    before = eng.stats["compilations"]
+    _run(eng, Request("c2", _prompt(12, PAGE + 1, 136), max_new_tokens=4))
+    assert eng.stats["compilations"] == before
+
+
+def test_the_ring_holds_at_most_its_bound():
+    keep = profiling.spans()
+    try:
+        for i in range(profiling.RING_SPANS + 10):
+            profiling.record("istpu.test.fill", i, 1)
+        ring = profiling.spans()
+        assert len(ring) == profiling.RING_SPANS
+        assert ring[0].t0_ns == 10 and ring[-1].name == "istpu.test.fill"
+        assert profiling.spans(since_ns=profiling.RING_SPANS) == ring[-10:]
+    finally:
+        profiling._ring.clear()
+        profiling._ring.extend(keep)
+
+
+def test_spans_nest_per_thread_and_inherit_request_and_engine():
+    t0 = time.time_ns()
+    seen = {}
+
+    def other():
+        with profiling.span("istpu.test.other") as f:
+            f["x"] = 1
+        seen["done"] = True
+
+    with profiling.span("istpu.test.outer", "req-9", 42, a=1):
+        th = threading.Thread(target=other)
+        th.start()
+        th.join(timeout=30)
+        with profiling.span("istpu.test.inner"):
+            profiling.record("istpu.test.after", 5, 6, n=2)
+    assert seen["done"]
+    got = {s.name: s for s in profiling.spans(since_ns=0)
+           if s.name.startswith("istpu.test.") and (s.t0_ns >= t0
+                                                    or s.t0_ns == 5)}
+    outer, inner = got["istpu.test.outer"], got["istpu.test.inner"]
+    after, oth = got["istpu.test.after"], got["istpu.test.other"]
+    assert (outer.parent, outer.request, outer.engine) == (0, "req-9", 42)
+    assert (inner.parent, inner.request, inner.engine) == (
+        outer.id, "req-9", 42)
+    assert (after.parent, after.request, after.fields) == (
+        inner.id, "req-9", {"n": 2})
+    # Another thread's span is nobody's child here.
+    assert (oth.parent, oth.request, oth.fields) == (0, None, {"x": 1})
+    assert oth.tid != outer.tid
+
+
+def test_chrome_trace_is_the_trace_event_form():
+    t0 = time.time_ns()
+    with profiling.span("istpu.test.chrome", "r1", 7, pages=3):
+        pass
+    out = profiling.chrome_trace()
+    json.dumps(out)
+    (ev,) = [e for e in out["traceEvents"]
+             if e["name"] == "istpu.test.chrome"]
+    assert ev["ph"] == "X" and ev["pid"] == os.getpid()
+    assert abs(ev["ts"] * 1e3 - t0) < 1e9 and ev["dur"] >= 0
+    assert ev["args"]["request_id"] == "r1" and ev["args"]["engine"] == 7
+    assert ev["args"]["pages"] == 3 and ev["args"]["parent"] == 0
+    meta = out["metadata"]
+    assert abs(meta["clock_realtime_ns"] - time.time_ns()) < 5e9
+    assert abs(meta["clock_monotonic_ns"] - time.monotonic_ns()) < 5e9
+
+
+def _xplane_events(trace_dir, prefix="istpu."):
+    from jax.profiler import ProfileData
+
+    (path,) = glob.glob(os.path.join(
+        trace_dir, "plugins", "profile", "*", "*.xplane.pb"))
+    out = []
+    for plane in ProfileData.from_file(path).planes:
+        for ln in plane.lines:
+            for ev in ln.events:
+                if ev.name.startswith(prefix):
+                    out.append((ev.name, ev.start_ns, ev.duration_ns,
+                                plane.name))
+    return out
+
+
+def test_ring_and_profiler_record_the_same_spans(params, cfg, tmp_path):
+    """Under a jax.profiler session every istpu.* span is also a host
+    event of the xplane. The profiler's clock counts from the start of
+    its session (neither unix nor monotonic time), so the ring's starts
+    match the trace's after ONE offset, measured from the matched
+    pairs: within 1 ms at the quartiles here (tens of microseconds on
+    an idle host; PERF.md has the chip's figure)."""
+    eng = _engine(params, cfg, None, "spans-xplane")
+    eng.run([Request("warm", _prompt(13, PAGE + 3), max_new_tokens=3)])
+    with profiling.profile_window(trace_dir=tmp_path) as w:
+        eng.run([Request("x", _prompt(14, PAGE + 3), max_new_tokens=5)])
+    events = _xplane_events(str(tmp_path))
+    assert {p for *_, p in events} == {"/host:CPU"}
+    names = {n for n, *_ in events}
+    assert {profiling.WINDOW_SPAN, "istpu.engine.step",
+            "istpu.sched.admit", "istpu.model.prefill",
+            "istpu.model.decode"} <= names
+    # A wait recorded after the fact was never an annotation.
+    ring = [s for s in w.engine_spans if s.name != "istpu.sched.queue_wait"]
+    assert len(ring) == len(w.engine_spans) - 1
+    assert sorted(n for n, *_ in events) == sorted(s.name for s in ring)
+    offset, spread, pairs = profiling.clock_offset_ns(
+        ring, [(n, s) for n, s, *_ in events])
+    assert pairs == len(ring) >= 10
+    # Not unix time: the session's first events start near zero.
+    assert min(s for _, s, *_ in events) < 60e9 < 1e18 < offset
+    assert spread < 1e6
+    # Durations are the same interval on either clock.
+    ring_dur = sorted(s.dur_ns for s in ring if s.name == "istpu.engine.step")
+    trace_dur = sorted(d for n, _, d, _ in events
+                       if n == "istpu.engine.step")
+    assert abs(np.median(ring_dur) - np.median(trace_dur)) < 1e6
+
+
+def test_merge_lands_store_and_engine_spans_on_the_jax_axis(tmp_path):
+    # A jax trace whose session began at unix second 1000: its events
+    # count microseconds from there.
+    session_ns = 1000 * 10 ** 9
+    prof = tmp_path / "plugins" / "profile" / "run"
+    prof.mkdir(parents=True)
+    jax_events = [
+        {"ph": "X", "pid": 701, "tid": 1, "name": "istpu.engine.step",
+         "ts": 50.0, "dur": 30.0},
+        {"ph": "X", "pid": 701, "tid": 1, "name": "istpu.engine.step",
+         "ts": 150.0, "dur": 30.0},
+        {"ph": "X", "pid": 3, "tid": 0, "name": "fusion.1", "ts": 60.0,
+         "dur": 5.0},
+    ]
+    with gzip.open(prof / "host.trace.json.gz", "wt") as f:
+        json.dump({"traceEvents": jax_events}, f)
+    ring = [
+        profiling.Span(1, 0, "istpu.engine.step", session_ns + 50_000,
+                       30_000, 9, None, 1, {"kind": "decode"}),
+        profiling.Span(2, 1, "istpu.model.decode", session_ns + 55_000,
+                       20_000, 9, None, 1, {}),
+        profiling.Span(3, 0, "istpu.engine.step", session_ns + 150_400,
+                       30_000, 9, "r", 1, {"kind": "decode"}),
+    ]
+    # CLOCK_MONOTONIC read 7 s when CLOCK_REALTIME read 1000 s; a store
+    # span at monotonic 7 s + 70 us is jax time 70 us.
+    clocks = (session_ns, 7 * 10 ** 9)
+    store = [{"ph": "M", "pid": 1, "tid": 0, "name": "thread_name",
+              "args": {"name": "worker0"}},
+             {"ph": "X", "pid": 1, "tid": 0, "name": "GET",
+              "ts": 7e6 + 70.0, "dur": 4.0}]
+    path, offset = profiling._merge_perfetto(
+        str(tmp_path), store, ring, clocks)
+    assert offset == (session_ns + 200, 600, 2)
+    with gzip.open(path, "rt") as f:
+        merged = json.load(f)["traceEvents"]
+    assert jax_events == merged[:3]
+    get = next(e for e in merged if e.get("name") == "GET")
+    assert get["ts"] == pytest.approx(70.0 - 0.2) and get["dur"] == 4.0
+    mine = [e for e in merged if e.get("pid") == profiling._RING_PID
+            and e["ph"] == "X"]
+    assert [e["ts"] for e in mine] == pytest.approx([49.8, 54.8, 150.2])
+    assert mine[2]["args"] == {"id": 3, "parent": 0, "request_id": "r",
+                               "engine": 1, "kind": "decode"}
+    # Without a jax timeline the axis is the store's own.
+    alone = tmp_path / "alone"
+    alone.mkdir()
+    path, offset = profiling._merge_perfetto(str(alone), store, ring, clocks)
+    assert offset is None
+    with gzip.open(path, "rt") as f:
+        merged = json.load(f)["traceEvents"]
+    assert next(e for e in merged if e.get("name") == "GET")["ts"] == \
+        7e6 + 70.0
+    assert next(e for e in merged if e.get("name") ==
+                "istpu.model.decode")["ts"] == pytest.approx(7e6 + 55.0)
+
+
+@pytest.mark.parametrize("hit", [False, True], ids=["miss", "hit"])
+def test_first_token_logits_equals_the_private_pieces(params, cfg,
+                                                      shm_conn, hit):
+    """ServingEngine.first_token_logits against what the benchmark's
+    correct.py builds from _admit_fused / _probe_hit /
+    restore_prefix_pages / pages_to_kv / _prefill_px_jit today."""
+    if ROOT not in sys.path:
+        sys.path.insert(0, ROOT)
+    from benchmark.lib import correct
+
+    store = TpuKVStore(shm_conn)
+    eng = ServingEngine(params, cfg, ServingConfig(
+        max_slots=2, total_pages=64, model_id=f"spans-ftl-{hit}"),
+        store=store)
+    prompt = _prompt(15, 5 * PAGE + 3)
+    if hit:
+        eng.run([Request("seed", prompt[:4 * PAGE + 1], max_new_tokens=1)])
+    free, pool = list(eng.free_pages), np.asarray(eng.k_pages)
+    replica = types.SimpleNamespace(engine=eng, inner_store=store)
+    want, want_hit = correct.program_first_logits(
+        replica, llama, cfg, prompt, hit)
+    row, hit_pages = eng.first_token_logits(prompt)
+    assert hit_pages == want_hit == (4 if hit else 0)
+    assert row.dtype == np.float32 and row.shape == (cfg.vocab_size,)
+    np.testing.assert_array_equal(row, want)
+    # ... and against the dense forward, whichever program ran.
+    ref, _ = llama.forward_dense(params, cfg, jnp.asarray([prompt]))
+    np.testing.assert_allclose(row, np.asarray(ref[0, -1]), atol=2e-4)
+    # Nothing was admitted and no pool page written.
+    assert eng.free_pages == free and not eng.queue
+    np.testing.assert_array_equal(np.asarray(eng.k_pages), pool)
+    # An engine with work in it refuses.
+    eng.submit(Request("busy", prompt, max_new_tokens=2))
+    with pytest.raises(RuntimeError, match="idle"):
+        eng.first_token_logits(prompt)
+    eng.run()
+    assert isinstance(_Work(req=Request("w", [1]), prompt=[1]).queued_ns, int)
+
+
+STAGES = {
+    llama: {"embed", "attn.qkv", "attn.rope", "attn.kernel", "attn.out",
+            "mlp", "pool.update", "lm_head"},
+    moe: {"embed", "attn.qkv", "attn.rope", "attn.kernel", "attn.out",
+          "moe.route", "moe.dispatch", "moe.experts", "moe.combine",
+          "pool.update", "lm_head"},
+}
+
+
+@pytest.mark.parametrize("model", [llama, moe], ids=["llama", "moe"])
+def test_every_stage_is_named_in_the_step_programs(model):
+    """jax.named_scope: the decode and the prefill program's operations
+    carry their stage, and no layer index, in their metadata."""
+    import re
+
+    kw = dict(vocab_size=64, d_model=32, n_layers=2, n_heads=2,
+              n_kv_heads=1, d_ff=64, page_size=PAGE, dtype="float32")
+    cfg = (moe.MoEConfig(n_experts=4, top_k=2, **kw) if model is moe
+           else llama.LlamaConfig(**kw))
+    params = model.init_params(jax.random.PRNGKey(0), cfg)
+    pool = jnp.zeros((cfg.n_layers, 8, PAGE, cfg.n_kv_heads, cfg.head_dim))
+    decode = model.decode_step.lower(
+        params, cfg, jnp.zeros(2, jnp.int32), jnp.ones(2, jnp.int32),
+        pool, pool, jnp.zeros((2, 4), jnp.int32)).as_text(debug_info=True)
+    prefill = jax.jit(model.prefill, static_argnums=1).lower(
+        params, cfg, jnp.zeros((1, 2 * PAGE), jnp.int32)
+    ).as_text(debug_info=True)
+    found = set(re.findall(r"/((?:attn|moe|pool)\.\w+|embed|mlp|lm_head)/",
+                           decode))
+    assert found == STAGES[model]
+    found = set(re.findall(r"/((?:attn|moe|pool)\.\w+|embed|mlp|lm_head)/",
+                           prefill))
+    assert found == STAGES[model] - {"pool.update"}
+
+
+def test_threads_record_while_the_ring_is_read():
+    """More recording threads than cores, a short switch interval and a
+    reader snapshotting throughout: no snapshot fails, ids stay unique
+    and every thread's spans arrive whole and in order."""
+    n_threads, n_each = 2 * (os.cpu_count() or 4), 400
+    t0 = time.time_ns()
+    errors, stop = [], threading.Event()
+
+    def writer(k):
+        try:
+            for i in range(n_each):
+                with profiling.span("istpu.test.stress", f"w{k}", k, i=i):
+                    profiling.record("istpu.test.stress.after", t0, 1, i=i)
+        except Exception as e:  # surfaced below
+            errors.append(e)
+
+    def reader():
+        try:
+            while not stop.is_set():
+                profiling.spans(since_ns=t0)
+                profiling.chrome_trace()
+        except Exception as e:
+            errors.append(e)
+
+    old = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        rd = threading.Thread(target=reader)
+        rd.start()
+        ws = [threading.Thread(target=writer, args=(k,))
+              for k in range(n_threads)]
+        for w in ws:
+            w.start()
+        for w in ws:
+            w.join(timeout=120)
+        stop.set()
+        rd.join(timeout=120)
+    finally:
+        sys.setswitchinterval(old)
+    assert not errors and not rd.is_alive()
+    assert not any(w.is_alive() for w in ws)
+    mine = [s for s in profiling.spans(since_ns=t0)
+            if s.name.startswith("istpu.test.stress")]
+    assert len(mine) == 2 * n_threads * n_each
+    assert len({s.id for s in mine}) == len(mine)
+    for k in range(n_threads):
+        outer = [s for s in mine if s.engine == k
+                 and s.name == "istpu.test.stress"]
+        inner = {s.parent: s for s in mine if s.engine == k
+                 and s.name == "istpu.test.stress.after"}
+        assert [s.fields["i"] for s in outer] == list(range(n_each))
+        assert all(inner[s.id].fields["i"] == s.fields["i"]
+                   and inner[s.id].request == f"w{k}" for s in outer)

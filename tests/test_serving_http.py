@@ -154,3 +154,39 @@ def test_health(server):
     assert json.loads(
         urllib.request.urlopen(f"{server}/health", timeout=10).read()
     )["status"] == "ok"
+
+
+def test_trace_answers_the_span_ring_and_ttft_counts_from_arrival(
+        server, cfg):
+    """GET /trace: the program's span ring as Chrome trace-event JSON
+    (the form the store's /trace answers in), holding this request's
+    spans from the HTTP edge down; the server's ttft_ms counts from the
+    arrival stamp the engine's queue wait starts at."""
+    rng = np.random.default_rng(4)
+    prompt = [int(t) for t in rng.integers(0, cfg.vocab_size, 10)]
+    res = _post(server, {"prompt": prompt, "max_new_tokens": 5,
+                         "stream": False}, stream=False)
+    rid = res["request_id"]
+    mine = []
+    for _ in range(200):  # http.request lands after the response
+        trace = json.loads(
+            urllib.request.urlopen(f"{server}/trace", timeout=30).read())
+        mine = [e for e in trace["traceEvents"]
+                if e.get("args", {}).get("request_id") == rid]
+        if any(e["name"] == "istpu.http.request" for e in mine):
+            break
+    by_name = {e["name"]: e for e in mine}
+    assert {"istpu.http.request", "istpu.sched.queue_wait",
+            "istpu.sched.admit", "istpu.model.prefill"} <= set(by_name)
+    assert all(e["ph"] == "X" and e["dur"] >= 0 for e in mine)
+    assert {"clock_realtime_ns", "clock_monotonic_ns"} <= set(
+        trace["metadata"])
+    http, wait = by_name["istpu.http.request"], by_name[
+        "istpu.sched.queue_wait"]
+    assert http["ts"] == wait["ts"]  # one origin
+    assert http["args"]["tokens_out"] == 5
+    assert http["args"]["first_token_ns"] / 1e6 == pytest.approx(
+        res["ttft_ms"], abs=0.01)
+    # TTFT holds the wait and the admission it follows.
+    assert res["ttft_ms"] * 1e3 >= wait["dur"] + by_name[
+        "istpu.sched.admit"]["dur"] - 1e3
