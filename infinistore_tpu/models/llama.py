@@ -474,8 +474,13 @@ def decode_step(params, cfg: LlamaConfig, token, seq_lens, k_pages, v_pages,
     k_pages/v_pages: [n_layers, n_pages, page, n_kv, hd]
     page_table: [batch, max_pages] int32
 
-    Returns (logits [batch, vocab] fp32, new k_pages, new v_pages). The
-    new token's KV is scattered into the page at seq_lens position.
+    Returns (logits [batch, vocab] fp32, k_pages, v_pages): the pools
+    it was given with, per layer, the new token's K and V rows written
+    at (layer, page of position seq_lens, seq_lens % page_size). The
+    pools stay the 5-D arrays they arrive as — every layer's scatter and
+    every layer's attention address `li` inside them, and nothing the
+    size of a layer is sliced out or stacked back — so a caller that
+    donates them (the engine's fused programs) updates them in place.
     """
     b = token.shape[0]
     x = _embed(params, token[:, None], cfg)  # [b, 1, d]
@@ -486,25 +491,22 @@ def decode_step(params, cfg: LlamaConfig, token, seq_lens, k_pages, v_pages,
     )[:, 0]
     slot = seq_lens % cfg.page_size
 
-    new_k_pages, new_v_pages = [], []
     for li, layer in enumerate(params["layers"]):
         q, k, v = _qkv(layer, x, cfg, positions)
         with jax.named_scope("pool.update"):
-            kp = scatter_kv_to_pages(k_pages[li], k, target_page, slot)
-            vp = scatter_kv_to_pages(v_pages[li], v, target_page, slot)
+            k_pages = scatter_kv_to_pages(k_pages, k, target_page, slot,
+                                          layer=li)
+            v_pages = scatter_kv_to_pages(v_pages, v, target_page, slot,
+                                          layer=li)
         with jax.named_scope("attn.kernel"):
             attn = paged_decode_attention(
-                q[:, 0], kp, vp, page_table, seq_lens + 1,
-                window=cfg.window
+                q[:, 0], k_pages, v_pages, page_table, seq_lens + 1,
+                window=cfg.window, layer=li
             )
         x = x + _attn_out(layer, attn.reshape(b, 1, -1))
         x = x + _mlp(layer, x, cfg)
-        new_k_pages.append(kp)
-        new_v_pages.append(vp)
     x = rms_norm(x, params["final_ln"], cfg.norm_eps, cfg.norm_plus_one)
-    logits = _logits(params, x[:, 0])
-    with jax.named_scope("pool.update"):
-        return logits, jnp.stack(new_k_pages), jnp.stack(new_v_pages)
+    return _logits(params, x[:, 0]), k_pages, v_pages
 
 
 @partial(jax.jit, static_argnames=("cfg",))
@@ -531,7 +533,9 @@ def verify_step(params, cfg: LlamaConfig, tokens, seq_lens, k_pages,
                 appears in no sequence's page table). None means all m
                 are valid.
 
-    Returns (logits [batch, m, vocab] fp32, new k_pages, new v_pages).
+    Returns (logits [batch, m, vocab] fp32, k_pages, v_pages): the
+    pools it was given with the m tokens' rows written per layer, 5-D
+    throughout as in `decode_step`.
     A rejected speculative tail needs no rollback: its KV sits at
     positions >= the accepted seq_len, which later steps overwrite
     before attending (attention is masked by per-token length).
@@ -547,25 +551,24 @@ def verify_step(params, cfg: LlamaConfig, tokens, seq_lens, k_pages,
         target_page = jnp.where(ok, target_page, 0)
         slot = jnp.where(ok, slot, jnp.arange(m)[None, :] % cfg.page_size)
 
-    new_k_pages, new_v_pages = [], []
     for li, layer in enumerate(params["layers"]):
         q, k, v = _qkv(layer, x, cfg, positions)
         with jax.named_scope("pool.update"):
-            kp = scatter_kv_multi(k_pages[li], k, target_page, slot)
-            vp = scatter_kv_multi(v_pages[li], v, target_page, slot)
+            k_pages = scatter_kv_multi(k_pages, k, target_page, slot,
+                                       layer=li)
+            v_pages = scatter_kv_multi(v_pages, v, target_page, slot,
+                                       layer=li)
         # Pallas streaming kernel on TPU (pages HBM->VMEM, nothing
         # gathered), XLA gather path elsewhere.
         with jax.named_scope("attn.kernel"):
-            attn = paged_verify_attention(q, kp, vp, page_table, seq_lens,
-                                          window=cfg.window)
+            attn = paged_verify_attention(
+                q, k_pages, v_pages, page_table, seq_lens,
+                window=cfg.window, layer=li
+            )
         x = x + _attn_out(layer, attn.reshape(b, m, -1))
         x = x + _mlp(layer, x, cfg)
-        new_k_pages.append(kp)
-        new_v_pages.append(vp)
     x = rms_norm(x, params["final_ln"], cfg.norm_eps, cfg.norm_plus_one)
-    logits = _logits(params, x)
-    with jax.named_scope("pool.update"):
-        return logits, jnp.stack(new_k_pages), jnp.stack(new_v_pages)
+    return _logits(params, x), k_pages, v_pages
 
 
 def token_nll(logits, targets):

@@ -270,28 +270,23 @@ def decode_step(params, cfg: MoEConfig, token, seq_lens, k_pages, v_pages,
     # tokens unless the router is badly imbalanced).
     valid = (seq_lens > 0)[:, None]  # [b, 1]
 
-    new_k_pages, new_v_pages = [], []
     for li, layer in enumerate(params["layers"]):
         q, k, v = _llama._qkv(layer, x, cfg, positions)
         with jax.named_scope("pool.update"):
-            kp = _llama.scatter_kv_to_pages(k_pages[li], k, target_page,
-                                            slot)
-            vp = _llama.scatter_kv_to_pages(v_pages[li], v, target_page,
-                                            slot)
+            k_pages = _llama.scatter_kv_to_pages(k_pages, k, target_page,
+                                                 slot, layer=li)
+            v_pages = _llama.scatter_kv_to_pages(v_pages, v, target_page,
+                                                 slot, layer=li)
         with jax.named_scope("attn.kernel"):
             attn = _llama.paged_decode_attention(
-                q[:, 0], kp, vp, page_table, seq_lens + 1,
-                window=cfg.window
+                q[:, 0], k_pages, v_pages, page_table, seq_lens + 1,
+                window=cfg.window, layer=li
             )
         x = x + _llama._attn_out(layer, attn.reshape(b, 1, -1))
         moe_out, _aux = _moe_mlp(layer, x, cfg, valid)
         x = x + moe_out
-        new_k_pages.append(kp)
-        new_v_pages.append(vp)
     x = rms_norm(x, params["final_ln"], cfg.norm_eps, cfg.norm_plus_one)
-    logits = _llama._logits(params, x[:, 0])
-    with jax.named_scope("pool.update"):
-        return logits, jnp.stack(new_k_pages), jnp.stack(new_v_pages)
+    return _llama._logits(params, x[:, 0]), k_pages, v_pages
 
 
 @partial(jax.jit, static_argnames=("cfg",))
@@ -312,26 +307,24 @@ def verify_step(params, cfg: MoEConfig, tokens, seq_lens, k_pages,
         target_page = jnp.where(ok, target_page, 0)
         slot = jnp.where(ok, slot, jnp.arange(m)[None, :] % cfg.page_size)
 
-    new_k_pages, new_v_pages = [], []
     for li, layer in enumerate(params["layers"]):
         q, k, v = _llama._qkv(layer, x, cfg, positions)
         with jax.named_scope("pool.update"):
-            kp = _llama.scatter_kv_multi(k_pages[li], k, target_page, slot)
-            vp = _llama.scatter_kv_multi(v_pages[li], v, target_page, slot)
+            k_pages = _llama.scatter_kv_multi(k_pages, k, target_page, slot,
+                                              layer=li)
+            v_pages = _llama.scatter_kv_multi(v_pages, v, target_page, slot,
+                                              layer=li)
         with jax.named_scope("attn.kernel"):
             attn = _llama.paged_verify_attention(
-                q, kp, vp, page_table, seq_lens, window=cfg.window
+                q, k_pages, v_pages, page_table, seq_lens,
+                window=cfg.window, layer=li
             )
         x = x + _llama._attn_out(layer, attn.reshape(b, m, -1))
         # Ragged padding + inactive rows stay out of expert capacity.
         moe_out, _aux = _moe_mlp(layer, x, cfg, ok)
         x = x + moe_out
-        new_k_pages.append(kp)
-        new_v_pages.append(vp)
     x = rms_norm(x, params["final_ln"], cfg.norm_eps, cfg.norm_plus_one)
-    logits = _llama._logits(params, x)
-    with jax.named_scope("pool.update"):
-        return logits, jnp.stack(new_k_pages), jnp.stack(new_v_pages)
+    return _llama._logits(params, x), k_pages, v_pages
 
 
 def loss_fn(params, cfg: MoEConfig, tokens):

@@ -17,13 +17,21 @@ import jax
 import jax.numpy as jnp
 
 
-def gather_pages(pages, page_indices):
+def gather_pages(pages, page_indices, layer=None):
     """pages: [n_pages, page, ...]; page_indices: [batch, pages_per_seq]
-    → [batch, pages_per_seq, page, ...]."""
-    return jnp.take(pages, page_indices, axis=0)
+    → [batch, pages_per_seq, page, ...]. With `layer` (static int),
+    pages is the whole pool [n_layers, n_pages, page, ...] and the
+    gather reads that layer's pages straight out of it — no layer-sized
+    slice is produced on the way."""
+    if layer is None:
+        return jnp.take(pages, page_indices, axis=0)
+    # jnp.take's out-of-range semantics (fill), so both forms agree on
+    # every table however it is padded.
+    return pages.at[layer, page_indices].get(mode="fill")
 
 
-def scatter_kv_to_pages(pages, new_kv, page_indices, start_in_page):
+def scatter_kv_to_pages(pages, new_kv, page_indices, start_in_page,
+                        layer=None):
     """Write `new_kv` [batch, 1, n_kv, hd] (one decode step per sequence)
     into `pages` at (page_indices[b], start_in_page[b]).
 
@@ -31,23 +39,25 @@ def scatter_kv_to_pages(pages, new_kv, page_indices, start_in_page):
     entries may target distinct pages; duplicate targets are undefined
     (callers allocate one page per sequence tail, as vLLM does).
     """
-    b = new_kv.shape[0]
-    flat_idx = page_indices  # [batch]
-    updated = pages.at[flat_idx, start_in_page].set(
-        new_kv[:, 0], mode="drop", unique_indices=False
-    )
-    del b
-    return updated
+    return scatter_kv_multi(pages, new_kv[:, 0], page_indices,
+                            start_in_page, layer=layer)
 
 
-def scatter_kv_multi(pages, new_kv, page_indices, start_in_page):
+def scatter_kv_multi(pages, new_kv, page_indices, start_in_page,
+                     layer=None):
     """Multi-token variant: write `new_kv` [batch, m, n_kv, hd] at
     (page_indices[b, j], start_in_page[b, j]) — the m tokens of a
-    speculative-verify or chunked-prefill step. Same scatter semantics
-    as `scatter_kv_to_pages`."""
-    return pages.at[page_indices, start_in_page].set(
-        new_kv, mode="drop", unique_indices=False
-    )
+    speculative-verify or chunked-prefill step. Out-of-range page ids
+    are dropped.
+
+    pages: one layer [n_pages, page, n_kv, hd], or with `layer` (static
+    int) the whole pool [n_layers, n_pages, page, n_kv, hd]: the rows go
+    into that layer of the pool itself, which inside a program that
+    donates the pool is an update in place and touches nothing else."""
+    where = (page_indices, start_in_page)
+    if layer is not None:
+        where = (layer, *where)
+    return pages.at[where].set(new_kv, mode="drop", unique_indices=False)
 
 
 def matmul_precision(dtype):
@@ -110,14 +120,15 @@ def prefill_attention(q, k, v, causal=True, window=0):
 
 
 def multi_token_paged_attention(q, k_pages, v_pages, page_table, seq_lens,
-                                window=0):
+                                window=0, layer=None):
     """m-token decode attention over paged KV — the verify step of
     speculative decoding and the inner op of chunked prefill.
 
     q:          [batch, m, n_heads, hd] — m new tokens per sequence,
                 whose KV has ALREADY been scattered into the pages at
                 positions seq_lens[b] + j.
-    k_pages/v_pages: [n_pages, page, n_kv, hd]
+    k_pages/v_pages: [n_pages, page, n_kv, hd], or the whole pool
+                [n_layers, n_pages, page, n_kv, hd] plus `layer`
     page_table: [batch, max_pages] int32
     seq_lens:   [batch] int32 — tokens in cache BEFORE these m (so
                 token j attends to positions < seq_lens[b] + j + 1:
@@ -126,15 +137,14 @@ def multi_token_paged_attention(q, k_pages, v_pages, page_table, seq_lens,
     Returns [batch, m, n_heads, hd]. Static shapes; per-batch lengths
     are arithmetic masks (no dynamic control flow)."""
     batch, m, n_heads, hd = q.shape
-    page = k_pages.shape[1]
-    n_kv = k_pages.shape[2]
+    page, n_kv = k_pages.shape[-3:-1]
     max_pages = page_table.shape[1]
     n_rep = n_heads // n_kv
 
-    k = gather_pages(k_pages, page_table).reshape(
+    k = gather_pages(k_pages, page_table, layer).reshape(
         batch, max_pages * page, n_kv, hd
     )
-    v = gather_pages(v_pages, page_table).reshape(
+    v = gather_pages(v_pages, page_table, layer).reshape(
         batch, max_pages * page, n_kv, hd
     )
     k = _repeat_kv(k, n_rep)
@@ -157,11 +167,13 @@ def multi_token_paged_attention(q, k_pages, v_pages, page_table, seq_lens,
 
 
 def paged_decode_attention(q, k_pages, v_pages, page_table, seq_lens,
-                           window=0):
+                           window=0, layer=None):
     """Single-token decode attention over paged KV.
 
     q:            [batch, n_heads, hd] (current-step queries)
-    k_pages/v_pages: [n_pages, page, n_kv, hd] (the store's page unit)
+    k_pages/v_pages: [n_pages, page, n_kv, hd] (the store's page unit),
+                  or the whole pool [n_layers, n_pages, page, n_kv, hd]
+                  plus `layer` (static int)
     page_table:   [batch, max_pages] int32 page ids (padded arbitrarily)
     seq_lens:     [batch] int32 — valid tokens per sequence (incl. current)
 
@@ -169,13 +181,12 @@ def paged_decode_attention(q, k_pages, v_pages, page_table, seq_lens,
     the compile-time budget; invalid positions are masked arithmetically.
     """
     batch, n_heads, hd = q.shape
-    page = k_pages.shape[1]
-    n_kv = k_pages.shape[2]
+    page, n_kv = k_pages.shape[-3:-1]
     max_pages = page_table.shape[1]
     n_rep = n_heads // n_kv
 
-    k = gather_pages(k_pages, page_table)  # [b, mp, page, n_kv, hd]
-    v = gather_pages(v_pages, page_table)
+    k = gather_pages(k_pages, page_table, layer)  # [b, mp, page, n_kv, hd]
+    v = gather_pages(v_pages, page_table, layer)
     k = k.reshape(batch, max_pages * page, n_kv, hd)
     v = v.reshape(batch, max_pages * page, n_kv, hd)
     k = _repeat_kv(k, n_rep)  # [b, T, n_heads, hd]

@@ -10,11 +10,26 @@ the MXU, and folds it into an online-softmax accumulator held in VMEM
 scratch. HBM traffic is exactly one pass over the pages a sequence
 actually uses; nothing is materialized.
 
-Layout notes (pallas guide: min tile (8,128) f32 / (16,128) bf16): the
-wrapper pads head_dim to a lane multiple of 128 and n_heads to a sublane
-multiple of 8, and flattens pages to [n_pages, page, n_kv * hd] so the
-last two dims tile cleanly. Padding contributes zeros to logits and is
-sliced off the output.
+Operand layout. The bf16 decode and verify kernels take K and V in one
+of two forms, chosen by the operand's shape alone (`_kv_operand`):
+
+- the WHOLE pool [n_layers, n_pages, page, n_kv, hd] plus a static
+  `layer`, when head_dim is a lane multiple (128) and the kv heads
+  already make the query rows a sublane multiple. The pool goes to the
+  call as it lies in HBM, the block is one page (None, 1, page, n_kv,
+  hd) and the index map leads with the layer, so the serving step never
+  produces a layer-sized array. It is NOT viewed as [..., page,
+  n_kv * hd]: on the TPU's tiled layout (the last two dims in (8,128)
+  tiles) that reshape is a relayout, and XLA materialised the whole pool
+  for it, once per layer and kind (seen in the AOT-compiled decode
+  program of PR 25). The kernel body is the same either way: a page
+  block reads as [page, n_kv, hd].
+- one layer [n_pages, page, n_kv, hd] (or a pool that needs padding,
+  which is sliced to its layer first): the wrapper pads head_dim to a
+  lane multiple of 128 and the kv heads to the sublane multiple, and
+  flattens pages to [n_pages, page, n_kv * hd]. That costs a copy of
+  the layer per call. Padding contributes zeros to logits and is sliced
+  off the output.
 
 `decode_attention` picks this kernel on TPU backends and falls back to
 the XLA gather path elsewhere (tests run the kernel in interpret mode so
@@ -198,64 +213,104 @@ def _decode_dims(q_dtype, n_kv, group):
     return sublane, ((n_kv + kv_mult - 1) // kv_mult) * kv_mult
 
 
-def _make_page_idx(page_size, n_pages, tok_offset=0):
+def _make_page_idx(page_size, n_pages, tok_offset=0, layer=None):
     """Shared page index map: clamp against the table contract ("padded
     arbitrarily" — the XLA path's jnp.take clamps OOB ids) AND freeze j
     at the sequence's last used page, so pages past seq_len cost no HBM
     traffic (pallas elides same-index re-fetches). `tok_offset` extends
     the used range by the m new tokens a verify step appends (decode:
-    0)."""
+    0). With `layer` the operand is the whole 5-D pool and the map
+    leads with that (static) layer coordinate."""
 
     def _page_idx(b, j, pt, sl):
         last_used = jnp.maximum(sl[b] + tok_offset - 1, 0) // page_size
         jj = jnp.minimum(j, last_used)
-        return (jnp.clip(pt[b, jj], 0, n_pages - 1), 0, 0)
+        page = jnp.clip(pt[b, jj], 0, n_pages - 1)
+        return (page, 0, 0) if layer is None else (layer, page, 0, 0, 0)
 
     return _page_idx
 
 
-@functools.partial(jax.jit, static_argnames=("interpret", "window"))
+def _kv_operand(pages, layer, n_kv_p):
+    """The K or V operand of the bf16 paged kernels, from a layer
+    [n_pages, page, n_kv, hd] or from the whole pool [n_layers, n_pages,
+    page, n_kv, hd] plus a static `layer`.
+
+    A pool whose lanes and kv heads are already tile-aligned
+    (hd % 128 == 0, n_kv == n_kv_p) goes to the kernel WHOLE and as it
+    is, 5-D (see the module docstring for why it is not flattened).
+    Anything else is sliced to its layer first and that slice is padded
+    and flattened to [n_pages, page, n_kv_p * hd_p] — padding the pool
+    itself would copy every layer on every layer's call. The shape
+    decides, nothing else does."""
+    if pages.ndim == 5:
+        n_kv, hd = pages.shape[3:]
+        if hd % 128 == 0 and n_kv == n_kv_p:
+            return pages
+        pages = pages[layer]
+    pages, _ = _pad_to(pages, 3, 128)
+    n_pages, page_size, n_kv, hd_p = pages.shape
+    if n_kv_p != n_kv:
+        pages = jnp.pad(pages, ((0, 0), (0, 0), (0, n_kv_p - n_kv), (0, 0)))
+    return pages.reshape(n_pages, page_size, n_kv_p * hd_p)
+
+
+def _kv_spec(operand, layer, tok_offset=0):
+    """BlockSpec of a `_kv_operand`: one page a grid step, picked by
+    `_make_page_idx` — [1, page, n_kv_p * hd_p] of a flattened layer, or
+    [1, page, n_kv, hd] of the whole pool with the layer dimension
+    squeezed and led by `layer`. Either way the kernel reads the block
+    as [page, n_kv, hd]."""
+    if operand.ndim == 5:
+        _, n_pages, page_size = operand.shape[:3]
+        block = (None, 1, *operand.shape[2:])
+    else:
+        n_pages, page_size = operand.shape[:2]
+        block, layer = (1, *operand.shape[1:]), None
+    return pl.BlockSpec(
+        block, _make_page_idx(page_size, n_pages, tok_offset, layer))
+
+
+@functools.partial(jax.jit,
+                   static_argnames=("interpret", "window", "layer"))
 def paged_flash_decode(q, k_pages, v_pages, page_table, seq_lens,
-                       interpret=False, window=0):
+                       interpret=False, window=0, layer=None):
     """Flash-decode attention over paged KV (same contract as
     paged_attention.paged_decode_attention).
 
-    q: [batch, n_heads, hd]; k_pages/v_pages: [n_pages, page, n_kv, hd];
+    q: [batch, n_heads, hd]; k_pages/v_pages: one layer
+    [n_pages, page, n_kv, hd], or the whole pool
+    [n_layers, n_pages, page, n_kv, hd] with a static `layer` (see
+    `_kv_operand`: the kernel then indexes the layer itself);
     page_table: [batch, max_pages] int32; seq_lens: [batch] int32.
     Returns [batch, n_heads, hd].
     """
     batch, n_heads, hd = q.shape
-    n_pages, page_size, n_kv, _ = k_pages.shape
+    page_size, n_kv = k_pages.shape[-3:-1]
     max_pages = page_table.shape[1]
 
     # Pad to TPU tile boundaries: lanes (last dim) 128; sublane multiple
     # is dtype-dependent (8 for f32, 16 for bf16 — pallas guide tiling
     # table).
     q_p, _ = _pad_to(q, 2, 128)
-    k_p, _ = _pad_to(k_pages, 3, 128)
-    v_p, _ = _pad_to(v_pages, 3, 128)
     hd_p = q_p.shape[2]
     group = n_heads // n_kv
     _, n_kv_p = _decode_dims(q.dtype, n_kv, group)
     if n_kv_p != n_kv:
-        k_p = jnp.pad(k_p, ((0, 0), (0, 0), (0, n_kv_p - n_kv), (0, 0)))
-        v_p = jnp.pad(v_p, ((0, 0), (0, 0), (0, n_kv_p - n_kv), (0, 0)))
         q_p = jnp.pad(q_p, ((0, 0), (0, (n_kv_p - n_kv) * group), (0, 0)))
     n_heads_p = n_kv_p * group
 
-    # Flatten pages for clean 2D tiling: [n_pages, page, n_kv_p * hd_p].
-    k_f = k_p.reshape(n_pages, page_size, n_kv_p * hd_p)
-    v_f = v_p.reshape(n_pages, page_size, n_kv_p * hd_p)
-
-    _page_idx = _make_page_idx(page_size, n_pages)
+    k_f = _kv_operand(k_pages, layer, n_kv_p)
+    v_f = _kv_operand(v_pages, layer, n_kv_p)
+    kv_spec = _kv_spec(k_f, layer)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,  # page_table, seq_lens
         grid=(batch, max_pages),
         in_specs=[
             pl.BlockSpec((1, n_heads_p, hd_p), lambda b, j, pt, sl: (b, 0, 0)),
-            pl.BlockSpec((1, page_size, n_kv_p * hd_p), _page_idx),
-            pl.BlockSpec((1, page_size, n_kv_p * hd_p), _page_idx),
+            kv_spec,
+            kv_spec,
         ],
         out_specs=pl.BlockSpec(
             (1, n_heads_p, hd_p), lambda b, j, pt, sl: (b, 0, 0)
@@ -413,30 +468,29 @@ def _kernel_multi(page_tbl_ref, seq_lens_ref, q_ref, k_ref, v_ref, o_ref,
         o_ref[0] = (acc_ref[...] / l_ref[...]).astype(o_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("interpret", "window"))
+@functools.partial(jax.jit,
+                   static_argnames=("interpret", "window", "layer"))
 def paged_flash_verify(q, k_pages, v_pages, page_table, seq_lens,
-                       interpret=False, window=0):
+                       interpret=False, window=0, layer=None):
     """m-token flash verify over paged KV (same contract as
     paged_attention.multi_token_paged_attention): q [batch, m, n_heads,
     hd]; token j's KV must already be scattered at position
     seq_lens[b] + j. Streams pages HBM → VMEM like the decode kernel —
     nothing is gathered or materialized — with the causal limit applied
-    per token row. Returns [batch, m, n_heads, hd]."""
+    per token row. k_pages/v_pages: one layer or the whole pool plus a
+    static `layer`, as in paged_flash_decode. Returns
+    [batch, m, n_heads, hd]."""
     batch, m_tok, n_heads, hd = q.shape
-    n_pages, page_size, n_kv, _ = k_pages.shape
+    page_size, n_kv = k_pages.shape[-3:-1]
     max_pages = page_table.shape[1]
     group = n_heads // n_kv
 
     q_p, _ = _pad_to(q, 3, 128)
-    k_p, _ = _pad_to(k_pages, 3, 128)
-    v_p, _ = _pad_to(v_pages, 3, 128)
     hd_p = q_p.shape[3]
     # Pad kv heads so n_kv_p * (m_tok * group) rows hit a sublane
     # multiple (same math as decode, with the m-fold group).
     _, n_kv_p = _decode_dims(q.dtype, n_kv, m_tok * group)
     if n_kv_p != n_kv:
-        k_p = jnp.pad(k_p, ((0, 0), (0, 0), (0, n_kv_p - n_kv), (0, 0)))
-        v_p = jnp.pad(v_p, ((0, 0), (0, 0), (0, n_kv_p - n_kv), (0, 0)))
         q_p = jnp.pad(
             q_p, ((0, 0), (0, 0), (0, (n_kv_p - n_kv) * group), (0, 0))
         )
@@ -446,18 +500,17 @@ def paged_flash_verify(q, k_pages, v_pages, page_table, seq_lens,
     q_r = q_p.reshape(batch, m_tok, n_kv_p, group, hd_p)
     q_r = q_r.transpose(0, 2, 1, 3, 4).reshape(batch, rows, hd_p)
 
-    k_f = k_p.reshape(n_pages, page_size, n_kv_p * hd_p)
-    v_f = v_p.reshape(n_pages, page_size, n_kv_p * hd_p)
-
-    _page_idx = _make_page_idx(page_size, n_pages, tok_offset=m_tok)
+    k_f = _kv_operand(k_pages, layer, n_kv_p)
+    v_f = _kv_operand(v_pages, layer, n_kv_p)
+    kv_spec = _kv_spec(k_f, layer, tok_offset=m_tok)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(batch, max_pages),
         in_specs=[
             pl.BlockSpec((1, rows, hd_p), lambda b, j, pt, sl: (b, 0, 0)),
-            pl.BlockSpec((1, page_size, n_kv_p * hd_p), _page_idx),
-            pl.BlockSpec((1, page_size, n_kv_p * hd_p), _page_idx),
+            kv_spec,
+            kv_spec,
         ],
         out_specs=pl.BlockSpec(
             (1, rows, hd_p), lambda b, j, pt, sl: (b, 0, 0)
@@ -492,25 +545,31 @@ def paged_flash_verify(q, k_pages, v_pages, page_table, seq_lens,
     return out[:, :, :n_heads, :hd]
 
 
-def verify_attention(q, k_pages, v_pages, page_table, seq_lens, window=0):
+def verify_attention(q, k_pages, v_pages, page_table, seq_lens, window=0,
+                     layer=None):
     """m-token paged verify attention with automatic backend choice:
-    the pallas streaming kernel on TPU, the XLA gather path elsewhere."""
+    the pallas streaming kernel on TPU, the XLA gather path elsewhere.
+    k_pages/v_pages: one layer, or the whole pool plus `layer`."""
     if jax.default_backend() == "tpu":
         return paged_flash_verify(q, k_pages, v_pages, page_table, seq_lens,
-                                  window=window)
+                                  window=window, layer=layer)
     return xla_ref.multi_token_paged_attention(
-        q, k_pages, v_pages, page_table, seq_lens, window=window
+        q, k_pages, v_pages, page_table, seq_lens, window=window,
+        layer=layer
     )
 
 
-def decode_attention(q, k_pages, v_pages, page_table, seq_lens, window=0):
+def decode_attention(q, k_pages, v_pages, page_table, seq_lens, window=0,
+                     layer=None):
     """Paged decode attention with automatic backend choice: the pallas
-    flash kernel on TPU, the XLA gather path elsewhere."""
+    flash kernel on TPU, the XLA gather path elsewhere.
+    k_pages/v_pages: one layer, or the whole pool plus `layer`."""
     if jax.default_backend() == "tpu":
         return paged_flash_decode(q, k_pages, v_pages, page_table, seq_lens,
-                                  window=window)
+                                  window=window, layer=layer)
     return xla_ref.paged_decode_attention(
-        q, k_pages, v_pages, page_table, seq_lens, window=window
+        q, k_pages, v_pages, page_table, seq_lens, window=window,
+        layer=layer
     )
 
 

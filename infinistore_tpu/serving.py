@@ -345,14 +345,18 @@ def _admit_fused(params, cfg, tokens, k_pages, v_pages, ids, s_real,
 def _decode_fused(params, cfg, token, seq_lens, k_pages, v_pages, rows,
                   model=llama):
     """One fused device program per decode step: model forward + argmax
-    + seq_lens advance, with the KV pools DONATED (the functional
-    .at[].set() update aliases in place instead of copying the whole
-    pool every step — at 1B scale the pool copy would halve decode
-    throughput). Host pulls only `nxt` (4 bytes/slot) in the greedy
-    steady state; `logits` stays device-resident unless a sampling slot
-    needs it. Fusing matters twice: it keeps the pool update in-place,
-    and it collapses ~6 host API calls per step into one dispatch + one
-    tiny D2H."""
+    + seq_lens advance, with the KV pools DONATED. Donation alone does
+    not keep the pool in place: the step must also never slice a layer
+    out of the pool or stack layers back (a Pallas operand is a buffer
+    of its own, so a sliced layer is a copied layer). `decode_step`
+    therefore scatters each layer's new rows into the 5-D pool itself
+    and hands the kernel the whole pool plus the layer to read, and the
+    compiled program's temporaries stay under one layer of the pool
+    (tests/test_model.py holds that). Host pulls only `nxt`
+    (4 bytes/slot) in the greedy steady state; `logits` stays
+    device-resident unless a sampling slot needs it. Fusing also
+    collapses ~6 host API calls per step into one dispatch + one tiny
+    D2H."""
     logits, k_pages, v_pages = model.decode_step(
         params, cfg, token, seq_lens, k_pages, v_pages, rows
     )

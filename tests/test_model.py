@@ -275,6 +275,159 @@ def test_scatter_kv_to_pages():
     assert float(out.sum()) == 16.0
 
 
+# ---------------------------------------------------------------------------
+# The pool stays one 5-D array through a step: updated in place, never
+# sliced to a layer or stacked back.
+# ---------------------------------------------------------------------------
+
+def _pool_cfgs():
+    from infinistore_tpu.models import moe
+
+    kw = dict(vocab_size=64, d_model=32, n_layers=3, n_heads=4,
+              n_kv_heads=2, d_ff=64, max_seq=64, page_size=8,
+              dtype="float32")
+    return {
+        "llama": (llama, llama.LlamaConfig(**kw)),
+        "moe": (moe, moe.MoEConfig(n_experts=4, top_k=2,
+                                   capacity_factor=4.0, **kw)),
+    }
+
+
+def _fused_step(model, step):
+    """The donated device program the engine runs for `step` (verify_step
+    has none of its own there, so the test donates the model's)."""
+    from infinistore_tpu import serving
+
+    if step == "decode_step":
+        return lambda p, cfg, tok, lens, kp, vp, rows: (
+            serving._decode_fused.lower(p, cfg, tok, lens, kp, vp, rows,
+                                        model=model))
+    if step == "_decode_scan":
+        return lambda p, cfg, tok, lens, kp, vp, rows: (
+            serving._decode_scan.lower(p, cfg, tok, lens, kp, vp, rows,
+                                       n_steps=4, model=model))
+    verify = jax.jit(model.verify_step.__wrapped__,
+                     static_argnames=("cfg",), donate_argnums=(4, 5))
+    return lambda p, cfg, tok, lens, kp, vp, rows: verify.lower(
+        p, cfg, jnp.zeros((tok.shape[0], 3), jnp.int32), lens, kp, vp, rows)
+
+
+@pytest.mark.parametrize("step", ["decode_step", "verify_step",
+                                  "_decode_scan"])
+@pytest.mark.parametrize("family", ["llama", "moe"])
+def test_step_program_holds_no_layer_of_the_pool(family, step):
+    """The counter that says the in-place mechanism engages, read off
+    the compiled program: with a pool far larger than everything else,
+    the donated step's temporaries stay under ONE layer-and-kind of it.
+    Slicing `k_pages[li]` per layer and stacking the slices back (the
+    formulation before) held more than the whole pool."""
+    model, cfg = _pool_cfgs()[family]
+    params = model.init_params(jax.random.PRNGKey(0), cfg)
+    batch, max_pages, n_pages = 2, 4, 8192
+    pool = jnp.zeros((cfg.n_layers, n_pages, *cfg.kv_page_shape()),
+                     cfg.jdtype)
+    one_layer_and_kind = pool.nbytes // cfg.n_layers
+    weights = sum(x.nbytes for x in jax.tree_util.tree_leaves(params))
+    assert one_layer_and_kind > 8 * weights
+    lens = jnp.zeros((batch,), jnp.int32)
+    rows = jnp.zeros((batch, max_pages), jnp.int32)
+    compiled = _fused_step(model, step)(
+        params, cfg, lens, lens, pool, pool, rows).compile()
+    ma = compiled.memory_analysis()
+    assert ma.alias_size_in_bytes >= 2 * pool.nbytes  # donated, aliased
+    assert ma.temp_size_in_bytes < one_layer_and_kind, (
+        ma.temp_size_in_bytes, one_layer_and_kind)
+
+
+def _step_sliced(model, params, cfg, tokens, seq_lens, k_pages, v_pages,
+                 page_table, valid_len=None):
+    """The formulation decode_step / verify_step had before the pool
+    stayed whole, kept here as the reference: slice a layer out, scatter
+    the tokens' rows into the slice, attend over the slice, stack the
+    slices back. tokens [batch, m]; m == 1 is a decode step."""
+    b, m = tokens.shape
+    x = llama._embed(params, tokens, cfg)
+    positions = seq_lens[:, None] + jnp.arange(m)[None, :]
+    target_page = jnp.take_along_axis(
+        page_table, positions // cfg.page_size, axis=1)
+    slot = positions % cfg.page_size
+    ok = None
+    if valid_len is not None:
+        ok = jnp.arange(m)[None, :] < valid_len[:, None]
+        target_page = jnp.where(ok, target_page, 0)
+        slot = jnp.where(ok, slot, jnp.arange(m)[None, :] % cfg.page_size)
+    new_k, new_v = [], []
+    for li, layer in enumerate(params["layers"]):
+        q, k, v = llama._qkv(layer, x, cfg, positions)
+        kp = k_pages[li].at[target_page, slot].set(k, mode="drop")
+        vp = v_pages[li].at[target_page, slot].set(v, mode="drop")
+        attn = pa.multi_token_paged_attention(q, kp, vp, page_table,
+                                              seq_lens, window=cfg.window)
+        x = x + llama._attn_out(layer, attn.reshape(b, m, -1))
+        if model is llama:
+            x = x + llama._mlp(layer, x, cfg)
+        else:
+            valid = (seq_lens > 0)[:, None] if valid_len is None else ok
+            x = x + model._moe_mlp(layer, x, cfg, valid)[0]
+        new_k.append(kp)
+        new_v.append(vp)
+    return jnp.stack(new_k), jnp.stack(new_v)
+
+
+@pytest.mark.parametrize("step", ["decode_step", "verify_step"])
+@pytest.mark.parametrize("family", ["llama", "moe"])
+def test_pool_after_a_step_equals_slice_scatter_stack(family, step):
+    """After a step the WHOLE pool is bit-identical to the sliced
+    formulation's: the tokens' rows written in every layer, every other
+    byte of every layer untouched, inactive slots' rows in scratch page
+    0, and a page id equal to total_pages dropped."""
+    model, cfg = _pool_cfgs()[family]
+    params = model.init_params(jax.random.PRNGKey(1), cfg)
+    total_pages = 12
+    rng = np.random.default_rng(7)
+    shape = (cfg.n_layers, total_pages, *cfg.kv_page_shape())
+    k_pages = jnp.asarray(rng.standard_normal(shape), cfg.jdtype)
+    v_pages = jnp.asarray(rng.standard_normal(shape), cfg.jdtype)
+    # slot 0: mid-page; slot 1: inactive (length 0, table of zeros ->
+    # scratch page 0); slot 2: its next page is not allocated yet (the
+    # table pads with total_pages -> dropped); slot 3: last slot of a page.
+    seq_lens = jnp.asarray([11, 0, 16, 7], jnp.int32)
+    page_table = jnp.asarray([[3, 4, 9, total_pages],
+                              [0, 0, 0, 0],
+                              [5, 6, total_pages, total_pages],
+                              [7, 8, total_pages, total_pages]], jnp.int32)
+    if step == "decode_step":
+        valid_len = None
+        tokens = jnp.asarray([[5], [0], [9], [2]], jnp.int32)
+        _, k_new, v_new = model.decode_step(
+            params, cfg, tokens[:, 0], seq_lens, k_pages, v_pages,
+            page_table)
+        # position -> (page, slot) of the rows a step must have written
+        written = {(3 + 1, 3), (0, 0), (8 - 1, 7)}
+    else:
+        valid_len = jnp.asarray([3, 0, 2, 3], jnp.int32)
+        tokens = jnp.asarray(rng.integers(0, cfg.vocab_size, (4, 3)),
+                             jnp.int32)
+        _, k_new, v_new = model.verify_step(
+            params, cfg, tokens, seq_lens, k_pages, v_pages, page_table,
+            valid_len)
+        written = {(4, 3), (4, 4), (4, 5),       # slot 0: 11, 12, 13
+                   (0, 0), (0, 1), (0, 2),       # inactive + padding
+                   (7, 7), (8, 0), (8, 1)}       # slot 3 crosses a page
+        # slot 2's two real tokens (16, 17) sit on a dropped page id;
+        # its padded third column goes to scratch slot 2 (listed above).
+    k_ref, v_ref = jax.jit(_step_sliced, static_argnums=(0, 2))(
+        model, params, cfg, tokens, seq_lens, k_pages, v_pages, page_table,
+        valid_len)
+    np.testing.assert_array_equal(np.asarray(k_new), np.asarray(k_ref))
+    np.testing.assert_array_equal(np.asarray(v_new), np.asarray(v_ref))
+    for before, after in ((k_pages, k_new), (v_pages, v_new)):
+        diff = np.any(np.asarray(before) != np.asarray(after), axis=(3, 4))
+        for li in range(cfg.n_layers):  # [total_pages, page] per layer
+            assert {(int(p), int(s)) for p, s in zip(*np.nonzero(diff[li]))
+                    } == written, li
+
+
 def test_train_step_sharded_mesh(cfg):
     """Full training step jitted over the 8-device (dp=2, tp=4) mesh."""
     import optax

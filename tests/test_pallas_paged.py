@@ -491,3 +491,102 @@ def test_tp_decode_kernel_sliding_window():
     out = decode_attention_tp(mesh, q, k_pages, v_pages, pt, sl, window=9)
     err = float(jnp.max(jnp.abs(out - ref)))
     assert err < 1e-4, err
+
+
+# ---------------------------------------------------------------------------
+# Whole-pool operand: [n_layers, n_pages, page, n_kv, hd] plus a static
+# layer. Aligned shapes go to the kernel whole (its index map leads with
+# the layer); anything that needs padding is sliced to its layer first.
+# ---------------------------------------------------------------------------
+
+def _as_pool(pages, n_layers, layer, seed):
+    """A pool whose `layer` is `pages` and whose other layers are other
+    random numbers: a kernel that read the wrong layer cannot match."""
+    rng = np.random.default_rng(1000 + seed)
+    pool = jnp.asarray(
+        rng.standard_normal((n_layers, *pages.shape)), dtype=pages.dtype
+    )
+    return pool.at[layer].set(pages)
+
+
+def _eqns(jaxpr):
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for val in eqn.params.values():
+            sub = getattr(val, "jaxpr", val)
+            if hasattr(sub, "eqns"):
+                yield from _eqns(sub)
+
+
+def _kernel_operand_ranks(fn, *args):
+    """(ranks of the pallas_call's K and V operands, ranks of every
+    `pad` operand) in fn's jaxpr."""
+    import jax
+
+    eqns = list(_eqns(jax.make_jaxpr(fn)(*args).jaxpr))
+    (call,) = [e for e in eqns if e.primitive.name == "pallas_call"]
+    pads = [e.invars[0].aval.ndim for e in eqns if e.primitive.name == "pad"]
+    return [v.aval.ndim for v in call.invars[-2:]], pads
+
+
+def _pool_case(kind, n_heads, n_kv, hd, seed, dtype):
+    from infinistore_tpu.ops.pallas_paged_attention import paged_flash_verify
+
+    if kind == "decode":
+        q, k, v, pt, sl = _mk(2, n_heads, n_kv, hd, 16, 16, 3, seed=seed,
+                              dtype=dtype)
+        return paged_flash_decode, q, k, v, pt, sl
+    q, k, v, pt, sl = _mk_multi(2, 3, n_heads, n_kv, hd, 16, 16, 3,
+                                seed=seed, dtype=dtype)
+    return paged_flash_verify, q, k, v, pt, sl
+
+
+@pytest.mark.parametrize("window", [0, 20])
+@pytest.mark.parametrize("layer", [0, 1, 2])
+@pytest.mark.parametrize("kind", ["decode", "verify"])
+def test_pool_operand_equals_layer_slice_bit_for_bit(kind, layer, window):
+    """Aligned widths (bf16, hd 128, kv heads a tile multiple): the 5-D
+    pool goes to the kernel whole — no pad, no slice — and the result
+    is the 4-D call on pool[layer], bit for bit."""
+    fn, q, k, v, pt, sl = _pool_case(kind, 16, 4, 128, 11 + layer,
+                                     jnp.bfloat16)
+    k_pool = _as_pool(k, 3, layer, 1)
+    v_pool = _as_pool(v, 3, layer, 2)
+    want = fn(q, k_pool[layer], v_pool[layer], pt, sl, interpret=True,
+              window=window)
+    got = fn(q, k_pool, v_pool, pt, sl, interpret=True, window=window,
+             layer=layer)
+    np.testing.assert_array_equal(
+        np.asarray(got, dtype=np.float32), np.asarray(want, dtype=np.float32)
+    )
+    ranks, pads = _kernel_operand_ranks(
+        lambda *a: fn(*a, interpret=True, window=window, layer=layer),
+        q, k_pool, v_pool, pt, sl,
+    )
+    assert ranks == [5, 5] and pads == []
+
+
+@pytest.mark.parametrize("n_heads,n_kv,hd", [
+    (8, 2, 64),    # Llama-3.2-1B's head_dim: lanes need padding
+    (4, 2, 128),   # group 2 in bf16: kv heads need padding
+])
+@pytest.mark.parametrize("kind", ["decode", "verify"])
+def test_pool_operand_that_needs_padding_is_sliced_first(kind, n_heads,
+                                                         n_kv, hd):
+    """Padding the pool would copy every layer on every layer's call:
+    the wrapper slices the layer out and pads that, as the 4-D form
+    does. Same result bit for bit, and no `pad` of a 5-D array."""
+    fn, q, k, v, pt, sl = _pool_case(kind, n_heads, n_kv, hd, 21,
+                                     jnp.bfloat16)
+    k_pool = _as_pool(k, 3, 1, 3)
+    v_pool = _as_pool(v, 3, 1, 4)
+    want = fn(q, k, v, pt, sl, interpret=True)
+    got = fn(q, k_pool, v_pool, pt, sl, interpret=True, layer=1)
+    np.testing.assert_array_equal(
+        np.asarray(got, dtype=np.float32), np.asarray(want, dtype=np.float32)
+    )
+    ranks, pads = _kernel_operand_ranks(
+        lambda *a: fn(*a, interpret=True, layer=1), q, k_pool, v_pool, pt, sl
+    )
+    assert ranks == [3, 3]
+    assert pads and all(r < 5 for r in pads)
