@@ -61,6 +61,14 @@ from .lib import InfiniStoreKeyNotFound
 from .models import llama
 from .utils import profiling
 
+# Digests of the pages an engine itself offloaded, kept to tell a hit
+# on its own pages from one on another engine's (stats
+# "foreign_hit_pages"), oldest first out. Four replicas near their knee
+# write 130 pages a second between them (PERF.md, PR 26), so the 40 s
+# of writes a store pool is sized for are some 1,300 digests an
+# engine; 64 Ki outlast any pool one host holds, at 8 MB at most.
+OWN_DIGESTS = 65536
+
 
 def content_page_digests(tokens, page_size, n_pages, namespace=""):
     """Per-page content digests, vLLM-style: digest i is the hash CHAIN
@@ -419,6 +427,9 @@ class ServingEngine:
                 "(weights on one device) instead."
             )
         self.params = params
+        # What maps an engine's spans to its replica: the index of its
+        # chip in the process (None over a mesh).
+        self._device_index = getattr(self.device, "id", None)
         self.cfg = cfg
         # The model family: any module exposing the llama serving
         # surface (prefill, prefill_with_prefix, decode_step,
@@ -449,6 +460,9 @@ class ServingEngine:
             "prefill_tokens": 0, "decode_steps": 0, "decoded_tokens": 0,
             "offloaded_pages": 0, "preemptions": 0, "store_errors": 0,
             "restore_misses": 0, "spec_proposed": 0, "spec_accepted": 0,
+            # hit pages this engine did not itself offload: another
+            # engine over the same store wrote them
+            "foreign_hit_pages": 0,
             "chunk_steps": 0, "burst_steps": 0, "prefetched_pages": 0,
             # admissions that returned for want of pool pages
             "admit_retries": 0,
@@ -457,6 +471,7 @@ class ServingEngine:
             "compilations": 0,
         }
         self.engine_id = profiling.next_engine_id()
+        self._own_digests = {}  # insertion-ordered, at most OWN_DIGESTS
         # One sequence page over every layer and both kinds, in bytes.
         self._page_bytes = (
             2 * L * int(np.prod(cfg.kv_page_shape())) * cfg.jdtype.itemsize
@@ -696,7 +711,8 @@ class ServingEngine:
         rid = work.req.request_id
         t0_ns = time.time_ns()
         with self._span("istpu.sched.admit", rid, slot=slot_idx,
-                        prompt_tokens=n_prompt, hit_pages=0) as f:
+                        prompt_tokens=n_prompt, hit_pages=0,
+                        foreign_pages=0) as f:
             admitted = self._do_admit(slot_idx, work, n_prompt, n_pages, f)
         if admitted:
             # Known only now that an admission went through: arrival
@@ -800,7 +816,7 @@ class ServingEngine:
             self.free_pages.extend(self._admit_ids_view)
             raise
 
-    def _restore(self, prompt, hit, digests, first_live=0):
+    def _restore(self, prompt, hit, digests, first_live=0, foreign=0):
         """Pages [first_live, hit) of `prompt`'s chain, store -> HBM in
         pool form: (k, v) [n_layers, hit - first_live, page, n_kv, hd].
         Digests are layer/kind-independent and come from the probe — the
@@ -812,7 +828,8 @@ class ServingEngine:
             # span around get_kv_pages from outside times too; building
             # the keys and splitting the result are the admission's own.
             with self._span("istpu.cache.restore", pages=n,
-                            bytes=n * self._page_bytes):
+                            bytes=n * self._page_bytes,
+                            foreign_pages=foreign):
                 return self._get_pages(keys, page_shape, dtype)
 
         return llama.restore_prefix_pages(
@@ -844,9 +861,11 @@ class ServingEngine:
         if hit > 0:
             # Restore the in-window hit pages once (into HBM tensors;
             # pool placement follows in _do_admit_paged).
+            # hit pages this engine did not itself offload
+            foreign = sum(d not in self._own_digests for d in digests[:hit])
             try:
                 kp, vp = self._restore(work.prompt, hit, digests,
-                                       first_live)
+                                       first_live, foreign)
             except InfiniStoreKeyNotFound:
                 # Routine eviction race: the page was LRU-dropped
                 # between probe and restore. A cache MISS for this
@@ -863,6 +882,8 @@ class ServingEngine:
                     # the chunked path attends straight over the pages.
                     prefix_kvs = self._to_kv(kp, vp)
                 self.stats["prefix_hit_pages"] += hit
+                self.stats["foreign_hit_pages"] += foreign
+                f["foreign_pages"] = foreign
                 self.stats["restored_pages"] += (
                     (hit - first_live) * cfg.n_layers * 2
                 )
@@ -1159,6 +1180,10 @@ class ServingEngine:
                 self._store_failed("offload", e)
                 return
         self.stats["offloaded_pages"] += n_full - lo
+        own = self._own_digests
+        own.update(dict.fromkeys(new_digests))
+        while len(own) > OWN_DIGESTS:
+            del own[next(iter(own))]
 
     def _release(self, slot_idx, slot):
         # [0:released) already went back to the pool when those pages
@@ -1220,7 +1245,7 @@ class ServingEngine:
         """One engine iteration: admit into free slots, then decode one
         token for every active slot. Returns #active slots decoded."""
         with self._span("istpu.engine.step", kind="idle", active=0,
-                        k=0) as f:
+                        k=0, device=self._device_index) as f:
             c0 = profiling.compilations()
             try:
                 return self._step(f)

@@ -137,7 +137,11 @@ def test_hit_span_tree(params, cfg, shm_conn):
     # restore: bytes are pages x the bytes of one page over every layer
     # and both kinds, and its transfer is one h2d of as many bytes.
     page_bytes = 2 * cfg.n_layers * cfg.kv_page_bytes()
-    assert restore.fields == {"pages": hit, "bytes": hit * page_bytes}
+    # the engine offloaded these pages itself: none is foreign
+    assert restore.fields == {"pages": hit, "bytes": hit * page_bytes,
+                              "foreign_pages": 0}
+    assert admit.fields["foreign_pages"] == 0
+    assert eng.stats["foreign_hit_pages"] == 0
     (h2d,) = _children(spans, restore)
     assert h2d.name == "istpu.xfer.h2d"
     assert h2d.fields["bytes"] == hit * page_bytes
