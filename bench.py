@@ -3732,7 +3732,7 @@ def bench_tpu(port):
             # memcpy into the mlocked shm pool. The control performs the
             # IDENTICAL sequence into an equally mlocked buffer — the r4
             # control's np.asarray of the 4-D array paid the tiled-
-            # layout host assembly _to_host exists to avoid, and its
+            # layout host assembly to_host exists to avoid, and its
             # np.asarray target was ordinary heap, not the pool's memory
             # class, so offload_vs_ctrl (1.38) bounded nothing. With the
             # control matched, the ratio again measures pure store
@@ -3765,7 +3765,7 @@ def bench_tpu(port):
             def _d2h_pass(_it):
                 pages_ctrl = jax.block_until_ready(pages + 0)
                 t0 = time.perf_counter()
-                # Same sequence as tpu._to_host + the native pool write:
+                # Same sequence as tpu.to_host + the native pool write:
                 # device-side flatten, 1-D D2H, one memcpy into mlocked
                 # shm. (reshape(-1) matches _flatten_on_device.)
                 host = np.asarray(pages_ctrl.reshape(-1))

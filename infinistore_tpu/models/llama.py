@@ -646,17 +646,20 @@ def restore_prefix_pages(store, cfg: LlamaConfig, key_fn, n_pages,
 
     ONE batched store call covers every (layer, kind): 2L small
     fetches would pay 2L pin/transfer round trips where the batch pays
-    one, and one large DMA beats 2L small ones. The device-side split
-    back into per-layer stacks is free slicing.
+    one, and one large DMA beats 2L small ones. The keys go page-major
+    (page, layer, k then v), the order the serving engine's offload
+    allocates them in (serving.content_page_keys_by_page): pages that
+    one offload wrote then lie in the store's pool in the order asked
+    for, and the SHM read is one zero-copy view of the pool and not a
+    view a block plus a stacking copy. The split back into per-layer
+    stacks is one device transpose, then slicing.
     Returns (k_pages, v_pages) [n_layers, n_pages, page, n_kv, hd]."""
     get = getter if getter is not None else store.get_kv_pages
-    keys = []
-    for li in range(cfg.n_layers):
-        keys.extend(key_fn(li, "k"))
-        keys.extend(key_fn(li, "v"))
+    per = [key_fn(li, kind) for li in range(cfg.n_layers) for kind in "kv"]
+    keys = [ks[p] for p in range(n_pages) for ks in per]
     flat = get(keys, cfg.kv_page_shape(), cfg.jdtype)
-    both = flat.reshape(
-        cfg.n_layers, 2, n_pages, *cfg.kv_page_shape()
+    both = jnp.moveaxis(
+        flat.reshape(n_pages, cfg.n_layers, 2, *cfg.kv_page_shape()), 0, 2
     )
     return both[:, 0], both[:, 1]
 

@@ -23,7 +23,8 @@ the store's primitives into end-to-end serving:
 - **Offload on finish**: completed sequences' full pages go back to the
   store (first-writer-wins dedup makes repeats free), so the next request
   sharing the prompt — e.g. the next turn of the same conversation —
-  hits.
+  hits. One gather program, one device-to-host transfer and one store
+  batch per chunk of OFFLOAD_CHUNK_BYTES, closed by one sync.
 - **Quantized wire (opt-in)**: `ServingConfig(quantized_store=True)`
   moves pages to/from the store int8-packed (per-token-per-head scales,
   ops/kv_quant.py) — half the restore/offload bytes and store capacity
@@ -59,6 +60,7 @@ import numpy as np
 
 from .lib import InfiniStoreKeyNotFound
 from .models import llama
+from .tpu import to_host
 from .utils import profiling
 
 # Digests of the pages an engine itself offloaded, kept to tell a hit
@@ -114,6 +116,14 @@ def content_page_keys(tokens, page_size, n_pages, layer, kind,
         digests = content_page_digests(tokens, page_size, n_pages,
                                        namespace)
     return [f"cp/{d}/L{layer}/{kind}" for d in digests]
+
+
+def content_page_keys_by_page(digests, n_layers):
+    """The same keys for every layer and kind of each page, page-major
+    (page, layer, k then v): the row order of `_gather_pages`, so one
+    offload is one key list over one array."""
+    return [f"cp/{d}/L{layer}/{kind}" for d in digests
+            for layer in range(n_layers) for kind in "kv"]
 
 
 @dataclass(frozen=True)
@@ -382,6 +392,47 @@ def _write_pages(k_pool, v_pool, ids, k_new, v_new):
         k_pool = k_pool.at[:, ids].set(k_new, mode="drop")
         v_pool = v_pool.at[:, ids].set(v_new, mode="drop")
     return k_pool, v_pool
+
+
+# The most bytes one device-to-host transfer of an offload brings over
+# (and one store batch holds). Measured on a v5e host (PERF.md, PR 27):
+# a transfer lands in a host buffer PJRT allocates anew, and above
+# glibc's largest mmap threshold (32 MiB) every such buffer is fresh
+# pages, faulted in one by one: 0.7-0.9 GB/s against 5.6 GB/s below it.
+# An offload larger than this goes in chunks of it, each transferred
+# while the one before is copied into the store's pool.
+OFFLOAD_CHUNK_BYTES = 16 << 20
+
+
+@jax.jit
+def _gather_pages(k_pool, v_pool, ids):
+    """Every (layer, kind) row of the pool pages `ids` as ONE flat
+    array, page-major: element order [len(ids), L, 2 (k, v), page,
+    n_kv, hd] — the offload's device program. The pools are read where
+    they lie (not donated, no layer sliced out: a sliced layer is a
+    copied layer) and the only temporaries are the gathered rows
+    themselves, at most the output's size (tests/test_offload_batch.py
+    holds that). Page-major, so that the rows of a padded tail of `ids`
+    are a suffix the host drops as a view; flat, because PJRT hands a
+    multi-dimensional array to the host in its tiled device layout
+    (tpu.to_host) — here the flattening is part of the program, not a
+    second dispatch."""
+    with jax.named_scope("pool.gather"):
+        k = k_pool.at[:, ids].get(mode="promise_in_bounds")
+        v = v_pool.at[:, ids].get(mode="promise_in_bounds")
+        rows = jnp.stack([k, v], axis=2)  # [L, len(ids), 2, page, kv, hd]
+        return jnp.swapaxes(rows, 0, 1).reshape(-1)
+
+
+def _offload_bucket(n, cap):
+    """The page count a gather of `n` pages runs at: `n` rounded up to
+    a grid of four steps an octave (1..8, 10, 12, 14, 16, 20, 24, ...),
+    at most `cap`. Padding is under a fifth of the bytes moved, and the
+    gather programs are as many as the grid's points up to `cap` (12 up
+    to 16, 26 up to 192) however many distinct counts traffic
+    produces."""
+    step = 1 << max(0, (n - 1).bit_length() - 3)
+    return min(cap, -(-n // step) * step)
 
 
 class ServingEngine:
@@ -1149,29 +1200,49 @@ class ServingEngine:
         # Digests come from the slot's incremental chain and only the
         # [lo, n_full) keys are ever formatted — windowed release calls
         # this every page_size tokens, so per-call work must stay
-        # O(pages released), not O(seq). (The sync below is one
-        # loopback RTT per released page — page contents must be
-        # durable in the store BEFORE the pool page is freed for
-        # reuse.)
+        # O(pages released), not O(seq). The pages go in chunks of at
+        # most OFFLOAD_CHUNK_BYTES: each is one gather program, one
+        # device-to-host transfer and one store batch, and chunk i+1 is
+        # gathered and on its way to the host while chunk i is copied
+        # into the store's pool. One sync closes them, on this thread:
+        # page contents must be durable in the store BEFORE the pool
+        # page is freed for reuse (and before the caller hears `done`).
+        n = n_full - lo
+        L = self.cfg.n_layers
+        page_ids = slot.page_ids[lo:n_full]
+        c = min(self.sc.max_pages_per_seq,
+                max(1, OFFLOAD_CHUNK_BYTES // self._page_bytes))
         with self._span("istpu.cache.offload", slot.work.req.request_id,
-                        reason=reason, pages=n_full - lo,
-                        bytes=(n_full - lo) * self._page_bytes):
+                        reason=reason, pages=n, bytes=n * self._page_bytes,
+                        padded_pages=0, puts=0) as f:
+
+            def gather(a):
+                # The chunk's ids, padded to a bucket with the scratch
+                # page 0: those rows are the tail of the array and never
+                # reach the store.
+                part = page_ids[a:a + c]
+                ids = np.zeros(_offload_bucket(len(part), c), np.int32)
+                ids[:len(part)] = part
+                f["padded_pages"] += len(ids)
+                flat = _gather_pages(self.k_pages, self.v_pages,
+                                     self._to_device(ids))
+                if not self.sc.quantized_store:
+                    flat.copy_to_host_async()
+                return flat
+
             new_digests = self._slot_digests(slot, n_full)[lo:]
             try:
-                for li in range(self.cfg.n_layers):
-                    sel = self._to_device(
-                        np.asarray(slot.page_ids[lo:n_full], np.int32)
-                    )
-                    self._put_pages(
-                        content_page_keys([], 0, 0, li, "k",
-                                          digests=new_digests),
-                        jnp.take(self.k_pages[li], sel, axis=0),
-                    )
-                    self._put_pages(
-                        content_page_keys([], 0, 0, li, "v",
-                                          digests=new_digests),
-                        jnp.take(self.v_pages[li], sel, axis=0),
-                    )
+                flat = gather(0)
+                for a in range(0, n, c):
+                    ahead = gather(a + c) if a + c < n else None
+                    keys = content_page_keys_by_page(new_digests[a:a + c], L)
+                    # Quantized pages stay on the device: the store call
+                    # quantizes there, so only packed int8 crosses over.
+                    pages = flat if self.sc.quantized_store else to_host(flat)
+                    pages = pages.reshape(-1, *self.cfg.kv_page_shape())
+                    self._put_pages(keys, pages[:len(keys)])
+                    f["puts"] += 1
+                    flat = ahead
                 with self._span("istpu.cache.offload_sync"):
                     self.store.conn.sync()
             except Exception as e:
@@ -1179,7 +1250,7 @@ class ServingEngine:
                 # losing it only costs future cache hits.
                 self._store_failed("offload", e)
                 return
-        self.stats["offloaded_pages"] += n_full - lo
+        self.stats["offloaded_pages"] += n
         own = self._own_digests
         own.update(dict.fromkeys(new_digests))
         while len(own) > OWN_DIGESTS:

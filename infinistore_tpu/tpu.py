@@ -103,7 +103,7 @@ def reset_copy_counters():
 
 def _flatten_on_device(arr):
     """Device-side flatten of a multi-dim jax.Array (no-op otherwise):
-    the prefetch sites and _to_host must flatten the SAME way or the
+    the prefetch sites and to_host must flatten the SAME way or the
     async D2H and the blocking one hit different arrays (a wasted
     double transfer)."""
     if not isinstance(arr, np.ndarray) and getattr(arr, "ndim", 1) > 1:
@@ -111,7 +111,7 @@ def _flatten_on_device(arr):
     return arr
 
 
-def _to_host(arr):
+def to_host(arr):
     """Device → host as a C-contiguous numpy array, counting copies.
 
     jax.Array: the transfer is issued on a device-side FLATTENED view.
@@ -237,7 +237,7 @@ class TpuKVStore:
             return
         host = []
         for k, a in items:
-            h = _to_host(a)
+            h = to_host(a)
             if not sync and h is a:
                 h = h.copy()  # caller-owned numpy buffer: detach from it
             host.append((k, h))
@@ -299,7 +299,7 @@ class TpuKVStore:
         :meth:`InfinityConnection.sync`, the same post-until-sync
         contract as ``write_cache``.
         """
-        host = _to_host(pages)
+        host = to_host(pages)
         n = host.shape[0]
         if n != len(keys):
             raise ValueError("len(keys) must equal pages.shape[0]")
@@ -391,7 +391,7 @@ class TpuKVStore:
             raise ValueError("len(keys) must equal pages.shape[0]")
         page_shape = tuple(pages.shape[1:])
         q, scales = kv_quant.quantize_kv_pages(pages)
-        packed = kv_quant.pack_pages_host(_to_host(q), _to_host(scales))
+        packed = kv_quant.pack_pages_host(to_host(q), to_host(scales))
         block = kv_quant.packed_page_bytes(page_shape)
         blocks = self.conn.allocate(keys, block)
         try:
@@ -533,8 +533,8 @@ class LayerStreamer:
     def submit(self, key, array):
         """Queue one array (one page) for upload under ``key``."""
         # Flatten ON DEVICE before the async D2H so the prefetch and
-        # _to_host hit the SAME (contiguous-landing) array — see
-        # _to_host for the device-layout story.
+        # to_host hit the SAME (contiguous-landing) array — see
+        # to_host for the device-layout story.
         array = _flatten_on_device(array)
         if hasattr(array, "copy_to_host_async"):
             array.copy_to_host_async()  # start D2H now; thread reaps it
@@ -561,7 +561,7 @@ class LayerStreamer:
                     return
                 key, arr, batched = item
                 try:
-                    host = _to_host(arr)  # waits only for the async D2H
+                    host = to_host(arr)  # waits only for the async D2H
                     if batched:
                         # Device inputs arrive pre-flattened (submit_pages);
                         # numpy inputs keep their [n, ...] shape — derive

@@ -167,12 +167,14 @@ def test_finish_span_tree(params, cfg, shm_conn):
     assert off.fields["pages"] == 3
     assert off.fields["bytes"] == 3 * 2 * cfg.n_layers * cfg.kv_page_bytes()
     assert eng.stats["offloaded_pages"] == 3
-    kids = _children(spans, off)
-    assert [k.name for k in kids] == (
-        ["istpu.xfer.d2h"] * (2 * cfg.n_layers)
-        + ["istpu.cache.offload_sync"])
-    assert sum(k.fields["bytes"] for k in kids[:-1]) == off.fields["bytes"]
-    assert all(k.request == "f1" for k in kids)
+    # One gather program, one transfer of the bucket's rows (3 pages
+    # are a bucket of their own), one store batch, one sync.
+    assert off.fields["padded_pages"] == 3 and off.fields["puts"] == 1
+    d2h, sync = _children(spans, off)
+    assert (d2h.name, sync.name) == ("istpu.xfer.d2h",
+                                     "istpu.cache.offload_sync")
+    assert d2h.fields == {"bytes": off.fields["bytes"]}
+    assert d2h.request == sync.request == "f1"
     step = next(s for s in spans if s.id == off.parent)
     assert step.name == "istpu.engine.step"
 
