@@ -4,7 +4,7 @@ Parity target: reference ``infinistore/benchmark.py`` — put/get throughput
 in MB/s with ``--size`` MB split into ``--block-size`` KB blocks written in
 ``--steps`` batches simulating model layers, uuid keys, and a final
 data-equality assert (benchmark.py:112-210). Extended with path selection
-(SHM/STREAM) and a ``--json`` machine-readable output used by bench.py.
+(SHM/STREAM) and a ``--json`` machine-readable output.
 """
 
 import argparse

@@ -233,8 +233,8 @@ class _ClientTelemetry:
     decomposes client-visible latency into client+wire vs server time.
 
     ``ISTPU_CLIENT_STATS=0`` (read at connection construction) disables
-    recording — the kill switch exists ONLY as the bench --obs-leg
-    overhead denominator (client_telemetry_overhead_p50_ratio <= 1.02).
+    recording — the kill switch exists ONLY as the denominator of an
+    overhead measurement (nothing in the tree takes one now).
 
     When the connection traces (``ClientConfig.trace``), each recorded
     op also lands in a bounded span ring (CLOCK_MONOTONIC timebase via

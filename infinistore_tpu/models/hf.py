@@ -40,7 +40,7 @@ def config_from_hf(hf_cfg, page_size=16, dtype="float32"):
         rope_type = scaling.get("rope_type", scaling.get("type", ""))
         if rope_type == "llama3":
             # Llama-3.1/3.2 long-context checkpoints; applied in
-            # llama.rope via _llama3_scale_freqs, parity-pinned
+            # decoder.rope via _llama3_scale_freqs, parity-pinned
             # against transformers in tests/test_hf_bridge.py.
             rope_scaling = (
                 float(scaling["factor"]),
@@ -228,18 +228,20 @@ def moe_config_from_hf(hf_cfg, page_size=16, dtype="float32"):
             "Mixtral sliding_window set: the MoE family does not route "
             "windowed attention configs yet"
         )
-    # Never silently diverge (the dense bridge's contract): the MoE
-    # attention stack has no rope-scaling slot at all, so ANY scaling —
-    # including 'llama3', which the dense bridge wires through — would
-    # load and then produce wrong logits at every position.
+    # Never silently diverge (the dense bridge's contract): MoEConfig
+    # has LlamaConfig's rope_scaling slot and the shared stack
+    # (models/decoder.py) applies it, but no test pins a scaled Mixtral
+    # against transformers, so ANY scaling — including 'llama3', which
+    # the dense bridge wires through with such a test — is refused and
+    # not passed on unverified.
     scaling = getattr(hf_cfg, "rope_scaling", None)
     if scaling:
         rope_type = scaling.get("rope_type", scaling.get("type", ""))
         if rope_type != "default":
             raise NotImplementedError(
                 f"rope_scaling type {rope_type!r} is not supported by "
-                "the MoE bridge (the MoE attention stack applies "
-                "unscaled RoPE only)"
+                "the MoE bridge (no parity test against transformers "
+                "for a scaled Mixtral)"
             )
     if getattr(hf_cfg, "hidden_act", "silu") not in ("silu", "swish"):
         raise NotImplementedError(

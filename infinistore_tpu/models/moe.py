@@ -4,9 +4,10 @@ expert-parallel (ep) consumer of the store.
 The reference ships no models (its scope is the KV pool; SURVEY.md §2);
 this family exists so the TPU engine side of the stack exercises expert
 parallelism end-to-end: MoE KV pages are identical store blocks (the
-attention stack is the same GQA+RoPE design as models/llama.py and pages
-out through the same kv_to_pages/page_keys helpers), while the FFN is a
-top-k routed expert layer whose experts shard over a mesh "ep" axis.
+attention stack and the layer loops are models/decoder.py's, shared with
+models/llama.py, and pages go out through its kv_to_pages/page_keys
+helpers), while the FFN is a top-k routed expert layer whose experts
+shard over a mesh "ep" axis.
 
 TPU-first routing (GShard dense-dispatch formulation): routing is
 expressed entirely as static-shape einsums — a [tokens, experts,
@@ -22,50 +23,26 @@ a load-balance auxiliary loss keeps the router spread.
 """
 
 from dataclasses import dataclass
-from functools import partial
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from . import llama as _llama
-from .llama import rms_norm
+
+from . import decoder, llama
 
 
 @dataclass(frozen=True)
-class MoEConfig:
-    vocab_size: int = 512
-    d_model: int = 128
-    n_layers: int = 2
-    n_heads: int = 4
-    n_kv_heads: int = 2
-    d_ff: int = 256          # per-expert hidden size
+class MoEConfig(llama.LlamaConfig):
+    """LlamaConfig plus the routed feed-forward's own fields. `d_ff` is
+    the per-expert hidden size; `act` is not read (the expert FFN is
+    SwiGLU, and the HF bridge refuses anything else)."""
+
     n_experts: int = 4
     top_k: int = 2
     capacity_factor: float = 1.5
-    max_seq: int = 256
-    page_size: int = 16
-    rope_theta: float = 10000.0
-    rope_scaling: tuple = ()  # see LlamaConfig.rope_scaling
-    window: int = 0           # see LlamaConfig.window
-    norm_plus_one: bool = False  # mirror of LlamaConfig's family knobs
-    embed_scale: float = 1.0     # (the expert FFN itself stays SwiGLU)
-    head_dim_override: int = 0
-    norm_eps: float = 1e-5
-    dtype: str = "bfloat16"
     aux_loss_weight: float = 0.01
-
-    @property
-    def head_dim(self):
-        return self.head_dim_override or self.d_model // self.n_heads
-
-    @property
-    def jdtype(self):
-        return jnp.dtype(self.dtype)
-
-    def kv_page_shape(self):
-        return (self.page_size, self.n_kv_heads, self.head_dim)
 
     def capacity(self, n_tokens):
         """Per-expert token slots: ceil(top_k * T / E * factor), rounded
@@ -161,14 +138,15 @@ def _route(layer, h, cfg: MoEConfig, valid=None):
     return dispatch, combine, aux
 
 
-def _moe_mlp(layer, x, cfg: MoEConfig, valid=None):
-    """[B, S, d] → [B, S, d] through the routed expert FFN; also returns
-    the layer's aux loss. `valid` ([B, S] bool or None) masks tokens
-    out of routing (see _route)."""
+def _moe_mlp(layer, x, cfg: MoEConfig, valid):
+    """The family's feed-forward block (decoder.py's `block` contract):
+    [B, S, d] → [B, S, d] through the routed expert FFN, and the
+    layer's aux loss. `valid` ([B, S] bool or None) masks tokens out of
+    routing (see _route)."""
     b, s, d = x.shape
-    # Stage names as in models/llama.py (one a stage, no layer index).
+    # Stage names as in models/decoder.py (one a stage, no layer index).
     with jax.named_scope("moe.route"):
-        h = rms_norm(x, layer["ln2"], cfg.norm_eps,
+        h = decoder.rms_norm(x, layer["ln2"], cfg.norm_eps,
                      cfg.norm_plus_one).reshape(b * s, d)
         vflat = None if valid is None else valid.reshape(b * s)
         dispatch, combine, aux = _route(layer, h, cfg, vflat)
@@ -185,51 +163,18 @@ def _moe_mlp(layer, x, cfg: MoEConfig, valid=None):
         out = jnp.einsum("tec,ecd->td", combine.astype(oe.dtype), oe)
     return out.reshape(b, s, d), aux
 
-
-def _forward_stack(params, cfg: MoEConfig, tokens, prefix_kvs=None,
-                   pos0=0):
-    """The decoder-stack loop shared by dense forward and prefix-cached
-    prefill (mirrors llama._forward_stack — same attention, routed
-    FFN): with `prefix_kvs` the positions shift by the prefix length
-    and each layer attends over prefix + suffix KV through the
-    rectangular flash kernel."""
-    b, s = tokens.shape
-    prefix_len = 0 if prefix_kvs is None else prefix_kvs[0][0].shape[1]
-    x = _llama._embed(params, tokens, cfg)
-    positions = jnp.broadcast_to(
-        pos0 + prefix_len + jnp.arange(s)[None], (b, s)
-    )
-    kvs = []
-    aux_total = jnp.float32(0)
-    for li, layer in enumerate(params["layers"]):
-        q, k, v = _llama._qkv(layer, x, cfg, positions)
-        if prefix_kvs is None:
-            k_full, v_full = k, v
-        else:
-            pk, pv = prefix_kvs[li]
-            k_full = jnp.concatenate([pk.astype(k.dtype), k], axis=1)
-            v_full = jnp.concatenate([pv.astype(v.dtype), v], axis=1)
-        with jax.named_scope("attn.kernel"):
-            attn = _llama.flash_prefill(q, k_full, v_full, causal=True,
-                                        window=cfg.window)
-        x = x + _llama._attn_out(layer, attn.reshape(b, s, -1))
-        moe_out, aux = _moe_mlp(layer, x, cfg)
-        x = x + moe_out
-        kvs.append((k, v))
-        aux_total = aux_total + aux
-    x = rms_norm(x, params["final_ln"], cfg.norm_eps, cfg.norm_plus_one)
-    logits = _llama._logits(params, x)
-    return logits, kvs, aux_total
+_forward_stack, decode_step, verify_step = decoder.bind(_moe_mlp)
 
 
 def forward_dense(params, cfg: MoEConfig, tokens):
     """Dense causal forward. tokens: [B, S] int32 → (logits [B, S, V]
     fp32, per-layer (k, v), total aux loss)."""
-    return _forward_stack(params, cfg, tokens)
+    logits, kvs, auxes = _forward_stack(params, cfg, tokens)
+    return logits, kvs, sum(auxes, jnp.float32(0))
 
 
 def prefill(params, cfg: MoEConfig, tokens):
-    logits, kvs, _ = forward_dense(params, cfg, tokens)
+    logits, kvs, _ = _forward_stack(params, cfg, tokens)
     return logits, kvs
 
 
@@ -243,99 +188,15 @@ def prefill_with_prefix(params, cfg: MoEConfig, tokens, prefix_kvs,
     return logits, kvs
 
 
-@partial(jax.jit, static_argnames=("cfg",))
-def decode_step(params, cfg: MoEConfig, token, seq_lens, k_pages, v_pages,
-                page_table):
-    """One paged decode step — llama.decode_step with the routed expert
-    FFN in place of the dense MLP (same KV page contract, so the store,
-    the pallas decode kernels and the serving engine work unchanged).
-
-    MIRROR CONTRACT: the paging/scatter/attention plumbing here and in
-    verify_step is a deliberate mirror of models/llama.py (the FFN call
-    is the only divergence) — any fix to llama's paging, scratch-page
-    or rollback logic MUST be applied here too; the MoE serving parity
-    suite (tests/test_moe.py) is the drift alarm."""
-    b = token.shape[0]
-    x = _llama._embed(params, token[:, None], cfg)  # [b, 1, d]
-    positions = seq_lens[:, None]
-    page_idx_in_seq = seq_lens // cfg.page_size
-    target_page = jnp.take_along_axis(
-        page_table, page_idx_in_seq[:, None], axis=1
-    )[:, 0]
-    slot = seq_lens % cfg.page_size
-    # Slots with an empty cache are the engine's inactive rows: keep
-    # their garbage tokens out of expert routing/capacity (best-effort
-    # — a previously-active slot's stale row may still route, but
-    # capacity() is sized for the full batch so it cannot evict real
-    # tokens unless the router is badly imbalanced).
-    valid = (seq_lens > 0)[:, None]  # [b, 1]
-
-    for li, layer in enumerate(params["layers"]):
-        q, k, v = _llama._qkv(layer, x, cfg, positions)
-        with jax.named_scope("pool.update"):
-            k_pages = _llama.scatter_kv_to_pages(k_pages, k, target_page,
-                                                 slot, layer=li)
-            v_pages = _llama.scatter_kv_to_pages(v_pages, v, target_page,
-                                                 slot, layer=li)
-        with jax.named_scope("attn.kernel"):
-            attn = _llama.paged_decode_attention(
-                q[:, 0], k_pages, v_pages, page_table, seq_lens + 1,
-                window=cfg.window, layer=li
-            )
-        x = x + _llama._attn_out(layer, attn.reshape(b, 1, -1))
-        moe_out, _aux = _moe_mlp(layer, x, cfg, valid)
-        x = x + moe_out
-    x = rms_norm(x, params["final_ln"], cfg.norm_eps, cfg.norm_plus_one)
-    return _llama._logits(params, x[:, 0]), k_pages, v_pages
-
-
-@partial(jax.jit, static_argnames=("cfg",))
-def verify_step(params, cfg: MoEConfig, tokens, seq_lens, k_pages,
-                v_pages, page_table, valid_len=None):
-    """m-token paged step (speculative verify / chunked prefill) —
-    llama.verify_step with the routed FFN; see that docstring for the
-    scratch-page and rollback contracts."""
-    b, m = tokens.shape
-    x = _llama._embed(params, tokens, cfg)  # [b, m, d]
-    positions = seq_lens[:, None] + jnp.arange(m)[None, :]
-    page_idx_in_seq = positions // cfg.page_size
-    target_page = jnp.take_along_axis(page_table, page_idx_in_seq, axis=1)
-    slot = positions % cfg.page_size
-    ok = None
-    if valid_len is not None:
-        ok = jnp.arange(m)[None, :] < valid_len[:, None]
-        target_page = jnp.where(ok, target_page, 0)
-        slot = jnp.where(ok, slot, jnp.arange(m)[None, :] % cfg.page_size)
-
-    for li, layer in enumerate(params["layers"]):
-        q, k, v = _llama._qkv(layer, x, cfg, positions)
-        with jax.named_scope("pool.update"):
-            k_pages = _llama.scatter_kv_multi(k_pages, k, target_page, slot,
-                                              layer=li)
-            v_pages = _llama.scatter_kv_multi(v_pages, v, target_page, slot,
-                                              layer=li)
-        with jax.named_scope("attn.kernel"):
-            attn = _llama.paged_verify_attention(
-                q, k_pages, v_pages, page_table, seq_lens,
-                window=cfg.window, layer=li
-            )
-        x = x + _llama._attn_out(layer, attn.reshape(b, m, -1))
-        # Ragged padding + inactive rows stay out of expert capacity.
-        moe_out, _aux = _moe_mlp(layer, x, cfg, ok)
-        x = x + moe_out
-    x = rms_norm(x, params["final_ln"], cfg.norm_eps, cfg.norm_plus_one)
-    return _llama._logits(params, x), k_pages, v_pages
-
-
 def loss_fn(params, cfg: MoEConfig, tokens):
     logits, _, aux = forward_dense(params, cfg, tokens[:, :-1])
-    return (_llama.token_nll(logits, tokens[:, 1:])
+    return (llama.token_nll(logits, tokens[:, 1:])
             + cfg.aux_loss_weight * aux)
 
 
 def train_step(params, opt_state, cfg: MoEConfig, tokens, optimizer):
     # The shared optimizer step with this family's loss plugged in.
-    return _llama.train_step(
+    return llama.train_step(
         params, opt_state, cfg, tokens, optimizer, loss=loss_fn
     )
 

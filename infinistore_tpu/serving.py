@@ -59,7 +59,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from .lib import InfiniStoreKeyNotFound
-from .models import llama
+from .models import decoder, llama
 from .tpu import to_host
 from .utils import profiling
 
@@ -346,7 +346,7 @@ def _admit_fused(params, cfg, tokens, k_pages, v_pages, ids, s_real,
     logits, kvs = model.prefill(params, cfg, tokens)
     page = cfg.page_size
     n = tokens.shape[1] // page
-    with jax.named_scope("pool.update"):  # stage names: models/llama.py
+    with jax.named_scope("pool.update"):  # stage names: models/decoder.py
         k_sfx = jnp.stack([k[0] for k, _ in kvs])  # [L, s_pad, kv, hd]
         v_sfx = jnp.stack([v[0] for _, v in kvs])
         shape = (cfg.n_layers, n, page, cfg.n_kv_heads, cfg.head_dim)
@@ -883,7 +883,7 @@ class ServingEngine:
                             foreign_pages=foreign):
                 return self._get_pages(keys, page_shape, dtype)
 
-        return llama.restore_prefix_pages(
+        return decoder.restore_prefix_pages(
             self.store, self.cfg,
             lambda li, kind: content_page_keys(
                 prompt, self.cfg.page_size, hit, li, kind, digests=digests
@@ -897,7 +897,7 @@ class ServingEngine:
         n_tokens = kp.shape[1] * self.cfg.page_size
         with self._span("istpu.cache.to_kv", tokens=n_tokens):
             return [
-                llama.pages_to_kv(self.cfg, kp[li][None], vp[li][None],
+                decoder.pages_to_kv(self.cfg, kp[li][None], vp[li][None],
                                   n_tokens)
                 for li in range(self.cfg.n_layers)
             ]
@@ -1062,7 +1062,7 @@ class ServingEngine:
         """The prefix program: the suffix attends over the restored
         `prefix_kvs`. pos0 anchors the trimmed prefix's absolute rope
         positions; the band mask is relative, so local indices inside
-        the kernel stay correct (llama._forward_stack). With `ids`,
+        the kernel stay correct (decoder.forward_stack). With `ids`,
         the suffix KV (real tokens only) is paged out into the pool
         there — dispatched while the program runs on the device, so
         that span lies inside this one. Returns the last real
@@ -1082,7 +1082,7 @@ class ServingEngine:
                     v_sfx = jnp.stack([v[:, :s_real] for _, v in kvs])
                     kp_s, vp_s = [], []
                     for li in range(cfg.n_layers):
-                        a, b = llama.kv_to_pages(cfg, k_sfx[li], v_sfx[li])
+                        a, b = decoder.kv_to_pages(cfg, k_sfx[li], v_sfx[li])
                         kp_s.append(a[0])
                         vp_s.append(b[0])
                     self._pool_write(ids, jnp.stack(kp_s), jnp.stack(vp_s))
@@ -1423,8 +1423,7 @@ class ServingEngine:
         # D2H per decode step (or per k-step burst). The host-side
         # input arrays are built ONLY on a cache miss: on the hit path
         # they were pure per-step waste (built, then discarded for the
-        # cached device copies) — measured as part of the ~140 us/step
-        # scheduler overhead the sched bench leg isolates.
+        # cached device copies).
         f.update(kind="burst" if k > 1 else "decode", active=len(active),
                  k=k)
         key = (tuple(i for i, _ in active), self._pages_rev)

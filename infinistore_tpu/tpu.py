@@ -40,32 +40,11 @@ from .lib import InfinityConnection
 from .utils import profiling
 
 
-# Published per-chip peaks for utilization accounting, keyed by jax's
-# device_kind (Google Cloud documentation, "TPU v5e": 197 TFLOP/s bf16,
-# 819 GB/s HBM). A device that is not in the table is an error, not a
-# default: a share of the wrong chip's peak is not a measurement.
-DEVICE_PEAKS = {
-    "TPU v5 lite": {"bf16_flops": 197e12, "hbm_bps": 819e9},
-}
-
-
-def device_peaks(device):
-    """The DEVICE_PEAKS row for `device` (a jax.Device)."""
-    try:
-        return DEVICE_PEAKS[device.device_kind]
-    except KeyError:
-        raise RuntimeError(
-            f"no published peaks for device kind {device.device_kind!r} "
-            f"(known: {sorted(DEVICE_PEAKS)}); add its row to "
-            "infinistore_tpu.tpu.DEVICE_PEAKS with a source"
-        ) from None
-
-
 def enable_compile_cache():
     """Persistent XLA compilation cache for every entry point that
-    compiles for the chip (chip_smoke.py, bench.py's device children,
-    example/serve.py, docs/prefill_sweep.py), so the processes of one
-    command and successive runs on one machine share executables. Where
+    compiles for the chip (chip_smoke.py, example/serve.py,
+    benchmark/run.py through benchmark/lib/serve.py), so the processes of
+    one command and successive runs on one machine share executables. Where
     JAX_COMPILATION_CACHE_DIR is set the environment owns the location —
     jax reads the variable itself and no directory is set in code;
     otherwise the cache lives at the fixed path <checkout>/.xla_cache
@@ -81,26 +60,6 @@ def enable_compile_cache():
     return jax.config.jax_compilation_cache_dir
 
 
-# Offload-path copy accounting (VERDICT r3 item 2). The reference lands
-# D2H bytes directly in pool blocks (cudaMemcpyAsync into mm->allocate'd
-# memory, reference infinistore.cpp:728-748). PJRT exposes no D2H
-# destination control from Python (probed: np.asarray of a pinned_host-
-# resident array still transfers; dlpack export is unimplemented), so
-# the achievable floor here is: ONE device->host DMA into jax's host
-# buffer, then ONE native memcpy into the pool — no further staging
-# copies. These counters prove the floor is met: `staging` must stay 0
-# on the offload path (bench.py publishes them).
-copy_counters = {
-    "d2h_copies": 0, "d2h_bytes": 0,       # device->host DMAs
-    "staging_copies": 0, "staging_bytes": 0,  # extra host->host copies
-}
-
-
-def reset_copy_counters():
-    for k in copy_counters:
-        copy_counters[k] = 0
-
-
 def _flatten_on_device(arr):
     """Device-side flatten of a multi-dim jax.Array (no-op otherwise):
     the prefetch sites and to_host must flatten the SAME way or the
@@ -112,7 +71,15 @@ def _flatten_on_device(arr):
 
 
 def to_host(arr):
-    """Device → host as a C-contiguous numpy array, counting copies.
+    """Device → host as a C-contiguous numpy array.
+
+    The reference lands D2H bytes directly in pool blocks
+    (cudaMemcpyAsync into mm->allocate'd memory, reference
+    infinistore.cpp:728-748). PJRT exposes no D2H destination control
+    from Python (probed: np.asarray of a pinned_host-resident array
+    still transfers; dlpack export is unimplemented), so the floor here
+    is ONE device->host DMA into jax's host buffer, then ONE native
+    memcpy into the pool, and no staging copy between them.
 
     jax.Array: the transfer is issued on a device-side FLATTENED view.
     PJRT hands multi-dim TPU arrays to the host in their device (tiled)
@@ -125,13 +92,9 @@ def to_host(arr):
     caller's shape is a free view — so the bytes go from the D2H buffer
     straight into the pool via the native client's memcpy. A
     non-contiguous numpy input is the only case that still pays a
-    staging copy, and the counter records it."""
+    staging copy."""
     if isinstance(arr, np.ndarray):
-        if arr.flags["C_CONTIGUOUS"]:
-            return arr
-        copy_counters["staging_copies"] += 1
-        copy_counters["staging_bytes"] += arr.nbytes
-        return np.ascontiguousarray(arr)
+        return arr if arr.flags["C_CONTIGUOUS"] else np.ascontiguousarray(arr)
     if not hasattr(arr, "shape"):  # plain array-likes (lists, scalars)
         return np.ascontiguousarray(arr)
     shape = arr.shape
@@ -139,11 +102,7 @@ def to_host(arr):
     # Also waits for whatever produces `arr` (the engine's page gather).
     with profiling.span("istpu.xfer.d2h", bytes=arr.nbytes):
         host = np.asarray(flat)
-    copy_counters["d2h_copies"] += 1
-    copy_counters["d2h_bytes"] += host.nbytes
     if not host.flags["C_CONTIGUOUS"]:  # defensive: 1-D should be flat
-        copy_counters["staging_copies"] += 1
-        copy_counters["staging_bytes"] += host.nbytes
         host = np.ascontiguousarray(host)
     return host.reshape(shape)
 

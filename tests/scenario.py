@@ -1,14 +1,15 @@
-"""Phase-shifting workload scenario (ISSUE 17 satellite).
+"""Phase-shifting workload scenario (ISSUE 17 satellite), and the two
+test oracles it is built on (a seeded Zipfian trace, an exact LRU
+simulator).
 
-One deterministic op sequence shared by ``bench.py --iosched-leg`` and
-the iosched tests, modeling the traffic shape the background-IO
-scheduler exists for:
+One deterministic op sequence for the iosched tests, modeling the
+traffic shape the background-IO scheduler exists for:
 
   1. ``bulk_load``   — every key written once in insertion order: the
      pool overfills past reclaim_high, so the spill/reclaim machinery
      is saturated when phase 2 starts.
-  2. ``interactive`` — a Zipfian read trace (bench.zipf_trace, same
-     seeded generator as the workload-observability oracle): hot-key
+  2. ``interactive`` — a Zipfian read trace (zipf_trace, the seeded
+     generator the workload-observability tests replay too): hot-key
      gets that demand-promote against the spill backlog. This is the
      phase whose p99 the scheduler protects.
   3. ``scan``        — one sequential sweep over the whole key space:
@@ -16,34 +17,50 @@ scheduler exists for:
      hands the closed-loop controller something to throttle.
 
 The sequence is a pure function of (nkeys, interactive_len, alpha,
-seed), so two servers replaying it see byte-identical traffic —
-bench A/B legs and the deterministic starvation test replay EXACTLY
-the same ops.
+seed), so two servers replaying it see byte-identical traffic — the
+A/B arms of the deterministic starvation test replay EXACTLY the same
+ops.
 """
 
-import importlib.util
-import os
 import time
-
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 PHASES = ("bulk_load", "interactive", "scan")
 
-_bench = None
+
+def zipf_trace(nkeys, length, alpha=0.9, seed=1234):
+    """Deterministic Zipfian reference trace: key INDICES drawn from a
+    rank-frequency power law (rank r with weight r^-alpha) by a seeded
+    generator, with the rank->key mapping shuffled by the same seed so
+    popularity is not correlated with insertion order. The scenario
+    below and the workload tests' exact stack-distance simulator replay
+    EXACTLY this sequence."""
+    import numpy as np
+
+    rng = np.random.default_rng(seed)
+    weights = 1.0 / np.arange(1, nkeys + 1, dtype=np.float64) ** alpha
+    weights /= weights.sum()
+    ranks = rng.choice(nkeys, size=length, p=weights)
+    perm = rng.permutation(nkeys)
+    return [int(perm[r]) for r in ranks]
 
 
-def _bench_module():
-    """Load bench.py by path (tests/ is not a package and bench.py is
-    not importable as a module name) — the scenario is BUILT ON its
-    zipf_trace so both replay the identical seeded trace."""
-    global _bench
-    if _bench is None:
-        spec = importlib.util.spec_from_file_location(
-            "bench_for_scenario", os.path.join(REPO, "bench.py"))
-        mod = importlib.util.module_from_spec(spec)
-        spec.loader.exec_module(mod)
-        _bench = mod
-    return _bench
+def exact_lru_miss_ratio(trace, capacity_keys):
+    """Exact stack-distance (LRU) simulation over a key-index trace at
+    a fixed capacity in KEYS (uniform object size): the oracle the
+    sampler's predicted miss ratio is pinned against."""
+    from collections import OrderedDict
+
+    lru = OrderedDict()
+    misses = 0
+    for k in trace:
+        if k in lru:
+            lru.move_to_end(k)
+        else:
+            misses += 1
+            if len(lru) >= capacity_keys:
+                lru.popitem(last=False)
+            lru[k] = True
+    return misses / len(trace) if trace else 0.0
 
 
 def build_scenario(nkeys, interactive_len=None, alpha=0.9, seed=4242):
@@ -52,8 +69,7 @@ def build_scenario(nkeys, interactive_len=None, alpha=0.9, seed=4242):
     if interactive_len is None:
         interactive_len = 4 * nkeys
     ops = [("bulk_load", "put", i) for i in range(nkeys)]
-    trace = _bench_module().zipf_trace(
-        nkeys, interactive_len, alpha=alpha, seed=seed)
+    trace = zipf_trace(nkeys, interactive_len, alpha=alpha, seed=seed)
     ops.extend(("interactive", "get", k) for k in trace)
     ops.extend(("scan", "get", i) for i in range(nkeys))
     return ops

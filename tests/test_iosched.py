@@ -20,8 +20,8 @@ Covers the tentpole end to end, deterministically:
     when the section/keys are present and degrades silently on
     pre-v17 blobs that lack them.
 
-All scenario traffic shapes come from tests/scenario.py — the same
-deterministic phase trace bench.py --iosched-leg replays.
+All scenario traffic shapes come from tests/scenario.py, one
+deterministic phase trace.
 """
 
 import importlib.util
@@ -366,8 +366,7 @@ def test_autotune_decisions_are_events(tmp_path, monkeypatch):
 
 
 def test_scenario_trace_is_deterministic():
-    """The shared phase driver (bench --iosched-leg replays the same
-    object): pure function of its seed, phases in order, puts only in
+    """The shared phase driver: pure function of its seed, phases in order, puts only in
     bulk_load."""
     a = scenario.build_scenario(64, interactive_len=128)
     b = scenario.build_scenario(64, interactive_len=128)

@@ -226,7 +226,7 @@ def test_embed_quantization_is_per_row():
     import jax.numpy as jnp
     import numpy as np
 
-    from infinistore_tpu.models import llama
+    from infinistore_tpu.models import decoder, llama
 
     cfg = llama.LlamaConfig(
         vocab_size=64, d_model=32, n_layers=1, n_heads=2, n_kv_heads=2,
@@ -237,7 +237,7 @@ def test_embed_quantization_is_per_row():
     q = llama.quantize_params(params, cfg)
     assert q["embed"]["scale"].shape == (cfg.vocab_size,)
     toks = jnp.asarray([[7]], jnp.int32)
-    ef = np.asarray(llama._embed(params, toks))
-    eq = np.asarray(llama._embed(q, toks))
+    ef = np.asarray(decoder.embed(params, toks))
+    eq = np.asarray(decoder.embed(q, toks))
     rel = np.abs(eq - ef).max() / (np.abs(ef).max() + 1e-12)
     assert rel < 0.02, rel

@@ -1,78 +1,137 @@
-"""Flagship paged-KV model tests: decode-vs-dense equivalence, store
-round-trip of KV pages, and the sharded training step on the virtual
+"""Decoder-stack tests, one suite for every model family: decode-vs-dense
+equivalence, prefix-cached prefill, the m-token step, the window, store
+round-trip of KV pages; and the sharded training step on the virtual
 8-device mesh."""
 
+import dataclasses
 import uuid
+from types import SimpleNamespace
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from infinistore_tpu.models import llama
+from infinistore_tpu.models import decoder, llama, moe
 from infinistore_tpu.ops import paged_attention as pa
+
+
+_CFG = dict(
+    vocab_size=128,
+    d_model=64,
+    n_layers=2,
+    n_heads=4,
+    n_kv_heads=2,
+    d_ff=128,
+    max_seq=64,
+    page_size=8,
+    dtype="float32",  # exact-match tests need fp32
+)
+# capacity_factor = n_experts / top_k: per-expert capacity equals the
+# token count, so no token is ever dropped whatever T a pass routes over
+# (a whole sequence in the dense forward, one batch in a paged step) and
+# the paths compare exactly. GShard capacity is per forward pass; a
+# config that drops in one and not in the other differs BY DESIGN.
+_MOE = dict(n_experts=4, top_k=2, capacity_factor=2.0)
+
+
+def _family(name, **kw):
+    """(model module, config, params) of one family at the tiny width."""
+    if name == "llama":
+        model, cfg = llama, llama.LlamaConfig(**{**_CFG, **kw})
+    else:
+        model, cfg = moe, moe.MoEConfig(**{**_CFG, **_MOE, **kw})
+    return SimpleNamespace(
+        model=model, cfg=cfg,
+        params=model.init_params(jax.random.PRNGKey(0), cfg))
+
+
+@pytest.fixture(scope="module", params=["llama", "moe"])
+def family(request):
+    return _family(request.param)
+
+
+@pytest.fixture(scope="module", params=["llama", "moe"])
+def windowed(request):
+    """A sliding window of two pages, far shorter than the sequences."""
+    return _family(request.param, window=16)
 
 
 @pytest.fixture(scope="module")
 def cfg():
-    return llama.LlamaConfig(
-        vocab_size=128,
-        d_model=64,
-        n_layers=2,
-        n_heads=4,
-        n_kv_heads=2,
-        d_ff=128,
-        max_seq=64,
-        page_size=8,
-        dtype="float32",  # exact-match tests need fp32
-    )
+    return llama.LlamaConfig(**_CFG)
 
 
-@pytest.fixture(scope="module")
-def params(cfg):
-    return llama.init_params(jax.random.PRNGKey(0), cfg)
-
-
-def test_prefill_shapes(params, cfg):
-    tokens = jnp.asarray(
-        np.random.default_rng(0).integers(0, cfg.vocab_size, (2, 16)),
+def _tokens(seed, cfg, shape):
+    return jnp.asarray(
+        np.random.default_rng(seed).integers(0, cfg.vocab_size, shape),
         dtype=jnp.int32,
     )
-    logits, kvs = llama.prefill(params, cfg, tokens)
+
+
+def _empty_pool(cfg, total_pages):
+    k_pages = jnp.zeros((cfg.n_layers, total_pages, *cfg.kv_page_shape()),
+                        dtype=cfg.jdtype)
+    return k_pages, jnp.zeros_like(k_pages)
+
+
+def _page_in(cfg, pools, kvs, row, page_ids):
+    """Write batch row `row` of a prefill's per-layer KV into the pools
+    at `page_ids`."""
+    k_pages, v_pages = pools
+    for li, (k, v) in enumerate(kvs):
+        kp, vp = decoder.kv_to_pages(cfg, k, v)
+        ids = jnp.asarray(page_ids[: kp.shape[1]])
+        k_pages = k_pages.at[li, ids].set(kp[row])
+        v_pages = v_pages.at[li, ids].set(vp[row])
+    return k_pages, v_pages
+
+
+def _dense_greedy(f, prompt, n_new):
+    """Greedy generation by dense re-forward: the oracle of the engine's
+    token stream that has no paged cache in it."""
+    toks, out = list(prompt), []
+    for _ in range(n_new):
+        logits = f.model.forward_dense(
+            f.params, f.cfg, jnp.asarray([toks], dtype=jnp.int32))[0]
+        out.append(int(jnp.argmax(logits[0, -1])))
+        toks.append(out[-1])
+    return out
+
+
+def test_prefill_shapes(family):
+    model, cfg, params = family.model, family.cfg, family.params
+    tokens = _tokens(0, cfg, (2, 16))
+    logits, kvs, *aux = model.forward_dense(params, cfg, tokens)
     assert logits.shape == (2, 16, cfg.vocab_size)
     assert len(kvs) == cfg.n_layers
     assert kvs[0][0].shape == (2, 16, cfg.n_kv_heads, cfg.head_dim)
+    assert np.isfinite(np.asarray(logits)).all()
+    assert all(np.isfinite(float(a)) for a in aux)  # MoE: the aux loss
+    p_logits, p_kvs = model.prefill(params, cfg, tokens)
+    np.testing.assert_array_equal(np.asarray(p_logits), np.asarray(logits))
+    assert len(p_kvs) == cfg.n_layers
 
 
-def test_paged_decode_matches_dense(params, cfg):
+def test_paged_decode_matches_dense(family):
     """Decoding token s+1 with paged KV must reproduce the dense forward's
-    logits for that position — paging is a layout change, not math."""
-    rng = np.random.default_rng(1)
+    logits for that position — paging is a layout change, not math — and
+    the engine's greedy stream over the paged cache is the dense one's."""
+    from infinistore_tpu.serving import Request, ServingEngine
+
+    model, cfg, params = family.model, family.cfg, family.params
     s = 16  # two pages
-    tokens = jnp.asarray(
-        rng.integers(0, cfg.vocab_size, (1, s + 1)), dtype=jnp.int32
-    )
-    dense_logits, _ = llama.forward_dense(params, cfg, tokens)
+    tokens = _tokens(1, cfg, (1, s + 1))
+    dense_logits = model.forward_dense(params, cfg, tokens)[0]
 
     # Build the paged cache from the prefill of the first s tokens.
-    _, kvs = llama.prefill(params, cfg, tokens[:, :s])
-    n_pages_seq = s // cfg.page_size
-    max_pages = 4
-    total_pages = 8
-    k_pages = jnp.zeros(
-        (cfg.n_layers, total_pages, cfg.page_size, cfg.n_kv_heads,
-         cfg.head_dim),
-        dtype=cfg.jdtype,
-    )
-    v_pages = jnp.zeros_like(k_pages)
-    for li, (k, v) in enumerate(kvs):
-        kp, vp = llama.kv_to_pages(cfg, k, v)
-        k_pages = k_pages.at[li, :n_pages_seq].set(kp[0])
-        v_pages = v_pages.at[li, :n_pages_seq].set(vp[0])
-    page_table = jnp.zeros((1, max_pages), dtype=jnp.int32)
+    _, kvs = model.prefill(params, cfg, tokens[:, :s])
+    k_pages, v_pages = _page_in(cfg, _empty_pool(cfg, 8), kvs, 0,
+                                np.arange(3))
+    page_table = jnp.zeros((1, 4), dtype=jnp.int32)
     page_table = page_table.at[0, :3].set(jnp.arange(3, dtype=jnp.int32))
 
-    logits, _, _ = llama.decode_step(
+    logits, _, _ = model.decode_step(
         params,
         cfg,
         tokens[:, s],
@@ -88,43 +147,49 @@ def test_paged_decode_matches_dense(params, cfg):
         atol=2e-4,
     )
 
+    prompt = [int(t) for t in np.asarray(_tokens(50, cfg, (11,)))]
+    eng = ServingEngine(params, cfg, model=model)
+    out = eng.run([Request("r", prompt, max_new_tokens=9)])
+    assert out["r"] == _dense_greedy(family, prompt, 9)
 
-def test_kv_pages_store_roundtrip(params, cfg, shm_conn):
+
+def test_kv_pages_store_roundtrip(family, shm_conn):
     """Prefill → page out KV to the store → restore → decode works on the
-    restored cache (the config-3 offload flow)."""
+    restored cache (the config-3 offload flow). Every family's pages are
+    ordinary store blocks through the same helpers."""
     from infinistore_tpu.tpu import TpuKVStore
 
+    model, cfg, params = family.model, family.cfg, family.params
     store = TpuKVStore(shm_conn)
-    rng = np.random.default_rng(2)
     s = 16
-    tokens = jnp.asarray(
-        rng.integers(0, cfg.vocab_size, (1, s)), dtype=jnp.int32
-    )
-    _, kvs = llama.prefill(params, cfg, tokens)
+    tokens = _tokens(2, cfg, (1, s))
+    _, kvs = model.prefill(params, cfg, tokens)
     prefix = f"seq_{uuid.uuid4()}"
     n_pages = s // cfg.page_size
 
     # Offload every layer's pages.
     for li, (k, v) in enumerate(kvs):
-        kp, vp = llama.kv_to_pages(cfg, k, v)
-        store.put_kv_pages(llama.page_keys(prefix, li, "k", n_pages), kp[0])
-        store.put_kv_pages(llama.page_keys(prefix, li, "v", n_pages), vp[0])
+        kp, vp = decoder.kv_to_pages(cfg, k, v)
+        store.put_kv_pages(decoder.page_keys(prefix, li, "k", n_pages),
+                           kp[0])
+        store.put_kv_pages(decoder.page_keys(prefix, li, "v", n_pages),
+                           vp[0])
     shm_conn.sync()
 
     # Prefix-cache hit detection.
-    keys_l0 = llama.page_keys(prefix, 0, "k", n_pages + 2)
+    keys_l0 = decoder.page_keys(prefix, 0, "k", n_pages + 2)
     assert store.cached_prefix_len(keys_l0) == n_pages
 
     # Restore into fresh page arrays and verify bytes.
     for li, (k, v) in enumerate(kvs):
-        kp, vp = llama.kv_to_pages(cfg, k, v)
+        kp, vp = decoder.kv_to_pages(cfg, k, v)
         got_k = store.get_kv_pages(
-            llama.page_keys(prefix, li, "k", n_pages),
+            decoder.page_keys(prefix, li, "k", n_pages),
             cfg.kv_page_shape(),
             cfg.jdtype,
         )
         got_v = store.get_kv_pages(
-            llama.page_keys(prefix, li, "v", n_pages),
+            decoder.page_keys(prefix, li, "v", n_pages),
             cfg.kv_page_shape(),
             cfg.jdtype,
         )
@@ -132,19 +197,17 @@ def test_kv_pages_store_roundtrip(params, cfg, shm_conn):
         assert np.array_equal(np.asarray(got_v), np.asarray(vp[0]))
 
 
-def test_prefill_with_prefix_matches_full(params, cfg):
+def test_prefill_with_prefix_matches_full(family):
     """Suffix prefill over cached prefix KV must reproduce the full
     prefill's suffix logits AND suffix KV — the cache-hit path is a
     FLOP-saving identity, not an approximation."""
-    rng = np.random.default_rng(3)
+    model, cfg, params = family.model, family.cfg, family.params
     p_len, s_new = 24, 16
-    tokens = jnp.asarray(
-        rng.integers(0, cfg.vocab_size, (2, p_len + s_new)), dtype=jnp.int32
-    )
-    full_logits, full_kvs = llama.prefill(params, cfg, tokens)
+    tokens = _tokens(3, cfg, (2, p_len + s_new))
+    full_logits, full_kvs = model.prefill(params, cfg, tokens)
 
-    _, prefix_kvs = llama.prefill(params, cfg, tokens[:, :p_len])
-    tail_logits, tail_kvs = llama.prefill_with_prefix(
+    _, prefix_kvs = model.prefill(params, cfg, tokens[:, :p_len])
+    tail_logits, tail_kvs = model.prefill_with_prefix(
         params, cfg, tokens[:, p_len:], prefix_kvs
     )
     np.testing.assert_allclose(
@@ -161,48 +224,41 @@ def test_prefill_with_prefix_matches_full(params, cfg):
         )
 
 
-def test_prefix_cache_hit_flow(params, cfg, shm_conn):
+def test_prefix_cache_hit_flow(family, shm_conn):
     """The full vLLM cache-HIT loop against a real store: prefill A,
     page out; a second request shares A's prefix — match → restore pages
     → pages_to_kv → suffix-only prefill — and must land on the same
     logits as prefilling from scratch."""
     from infinistore_tpu.tpu import TpuKVStore
 
+    model, cfg, params = family.model, family.cfg, family.params
     store = TpuKVStore(shm_conn)
-    rng = np.random.default_rng(5)
     p_len = 16  # two pages — page-aligned prefix, as vLLM guarantees
     s_new = 8
-    prefix_tokens = rng.integers(0, cfg.vocab_size, (1, p_len))
-    tokens = jnp.asarray(
-        np.concatenate(
-            [prefix_tokens, rng.integers(0, cfg.vocab_size, (1, s_new))],
-            axis=1,
-        ),
-        dtype=jnp.int32,
-    )
+    tokens = _tokens(5, cfg, (1, p_len + s_new))
 
     # Request 1: prefill the prefix, page it out to the store.
     seq = f"pfx_{uuid.uuid4()}"
-    _, kvs = llama.prefill(params, cfg, tokens[:, :p_len])
+    _, kvs = model.prefill(params, cfg, tokens[:, :p_len])
     n_pages = p_len // cfg.page_size
     for li, (k, v) in enumerate(kvs):
-        kp, vp = llama.kv_to_pages(cfg, k, v)
-        store.put_kv_pages(llama.page_keys(seq, li, "k", n_pages), kp[0])
-        store.put_kv_pages(llama.page_keys(seq, li, "v", n_pages), vp[0])
+        kp, vp = decoder.kv_to_pages(cfg, k, v)
+        store.put_kv_pages(decoder.page_keys(seq, li, "k", n_pages), kp[0])
+        store.put_kv_pages(decoder.page_keys(seq, li, "v", n_pages), vp[0])
     shm_conn.sync()
 
     # Request 2: detect the hit, restore, suffix-prefill.
     want_pages = (p_len + s_new + cfg.page_size - 1) // cfg.page_size
     hit = store.cached_prefix_len(
-        llama.page_keys(seq, 0, "k", want_pages)
+        decoder.page_keys(seq, 0, "k", want_pages)
     )
     assert hit == n_pages
-    prefix_kvs = llama.restore_prefix_kvs(store, cfg, seq, hit)
-    tail_logits, _ = llama.prefill_with_prefix(
+    prefix_kvs = decoder.restore_prefix_kvs(store, cfg, seq, hit)
+    tail_logits, _ = model.prefill_with_prefix(
         params, cfg, tokens[:, p_len:], prefix_kvs
     )
 
-    full_logits, _ = llama.prefill(params, cfg, tokens)
+    full_logits, _ = model.prefill(params, cfg, tokens)
     np.testing.assert_allclose(
         np.asarray(tail_logits),
         np.asarray(full_logits[:, p_len:]),
@@ -210,45 +266,39 @@ def test_prefix_cache_hit_flow(params, cfg, shm_conn):
     )
 
 
-def test_verify_step_equals_sequential_decode(params, cfg):
+def _two_row_cache(f, s, seed):
+    """Two sequences of `s` tokens prefilled and paged in: batch row 0
+    owns pages 1-4, row 1 owns 5-8 (page 0 is the scratch page)."""
+    cfg = f.cfg
+    tokens = _tokens(seed, cfg, (2, s))
+    _, kvs = f.model.prefill(f.params, cfg, tokens)
+    pt = np.stack([1 + np.arange(4), 5 + np.arange(4)]).astype(np.int32)
+    pools = _empty_pool(cfg, 9)
+    for bi in range(2):
+        pools = _page_in(cfg, pools, kvs, bi, pt[bi])
+    return tokens, pools, jnp.asarray(pt)
+
+
+def test_verify_step_equals_sequential_decode(family):
     """verify_step must consume m tokens in one pass and reproduce m
     sequential decode_steps — logits at every position AND the final
     page contents (the invariant speculative decoding rests on)."""
-    rng = np.random.default_rng(7)
+    model, cfg, params = family.model, family.cfg, family.params
     s, m = 12, 3
-    tokens = jnp.asarray(
-        rng.integers(0, cfg.vocab_size, (2, s)), dtype=jnp.int32
-    )
-    step_toks = jnp.asarray(
-        rng.integers(0, cfg.vocab_size, (2, m)), dtype=jnp.int32
-    )
-    _, kvs = llama.prefill(params, cfg, tokens)
-    total_pages, max_pages = 8, 4
-    shape = (cfg.n_layers, total_pages, cfg.page_size, cfg.n_kv_heads,
-             cfg.head_dim)
-    k_pages = jnp.zeros(shape, dtype=cfg.jdtype)
-    v_pages = jnp.zeros_like(k_pages)
-    # Batch row 0 owns pages 0-3, row 1 owns 4-7 (interleaved layout on
-    # purpose — exercises the per-row page tables).
-    pt = np.stack([np.arange(4), 4 + np.arange(4)]).astype(np.int32)
-    for li, (k, v) in enumerate(kvs):
-        kp, vp = llama.kv_to_pages(cfg, k, v)
-        for bi in range(2):
-            k_pages = k_pages.at[li, pt[bi, : kp.shape[1]]].set(kp[bi])
-            v_pages = v_pages.at[li, pt[bi, : vp.shape[1]]].set(vp[bi])
-    page_table = jnp.asarray(pt)
+    _, (k_pages, v_pages), page_table = _two_row_cache(family, s, 7)
+    step_toks = _tokens(8, cfg, (2, m))
     seq_lens = jnp.asarray([s, s], dtype=jnp.int32)
 
     # Sequential reference: m single-token decode steps.
     ks, vs = k_pages, v_pages
     seq_logits = []
     for j in range(m):
-        lg, ks, vs = llama.decode_step(
+        lg, ks, vs = model.decode_step(
             params, cfg, step_toks[:, j], seq_lens + j, ks, vs, page_table
         )
         seq_logits.append(lg)
 
-    ver_logits, kv2, vv2 = llama.verify_step(
+    ver_logits, kv2, vv2 = model.verify_step(
         params, cfg, step_toks, seq_lens, k_pages, v_pages, page_table
     )
     for j in range(m):
@@ -262,6 +312,136 @@ def test_verify_step_equals_sequential_decode(params, cfg):
     np.testing.assert_allclose(
         np.asarray(vv2), np.asarray(vs), rtol=2e-5, atol=2e-5
     )
+
+
+def test_verify_step_ragged_valid_len_equals_unpadded(family):
+    """An m-token step whose rows hold 4, 2 and 0 real tokens: every
+    valid position's logits and every live page equal what each row's
+    own unpadded step gives. Padded columns and the row with nothing to
+    add write only to scratch page 0."""
+    model, cfg, params = family.model, family.cfg, family.params
+    s, m = 12, 4
+    _, (k0, v0), pt2 = _two_row_cache(family, s, 11)
+    # Row 2 is a live sequence (row 1's cache again, on pages of its
+    # own) that adds no token in this step.
+    page_table = jnp.concatenate([pt2, pt2[1:]], axis=0)
+    seq_lens = jnp.asarray([s, s, s], dtype=jnp.int32)
+    valid_len = jnp.asarray([4, 2, 0], dtype=jnp.int32)
+    step_toks = _tokens(12, cfg, (3, m))
+
+    logits, k1, v1 = model.verify_step(
+        params, cfg, step_toks, seq_lens, k0, v0, page_table, valid_len)
+
+    k_ref, v_ref = k0, v0
+    for row in (0, 1):
+        n = int(valid_len[row])
+        lg, k_ref, v_ref = model.verify_step(
+            params, cfg, step_toks[row:row + 1, :n], seq_lens[row:row + 1],
+            k_ref, v_ref, page_table[row:row + 1])
+        np.testing.assert_allclose(
+            np.asarray(logits[row, :n]), np.asarray(lg[0]),
+            rtol=2e-4, atol=2e-4)
+    # Every page but the scratch page: the live ones as the unpadded
+    # steps left them (row 2 shares row 1's, which its 0 tokens must not
+    # touch), the free ones untouched.
+    np.testing.assert_allclose(np.asarray(k1[:, 1:]), np.asarray(k_ref[:, 1:]),
+                               rtol=2e-5, atol=2e-5)
+    np.testing.assert_allclose(np.asarray(v1[:, 1:]), np.asarray(v_ref[:, 1:]),
+                               rtol=2e-5, atol=2e-5)
+
+
+def test_windowed_paged_paths_match_dense_band_mask(windowed):
+    """With a window of two pages, prefill + one paged decode step + an
+    m-token paged step give the logits (and so the tokens) of the dense
+    band-masked forward over the whole sequence. `window` reaches three
+    kernels; this is the test that sets it for both families."""
+    model, cfg, params = windowed.model, windowed.cfg, windowed.params
+    s, m = 24, 3
+    tokens = _tokens(21, cfg, (1, s + 1 + m))
+    dense = model.forward_dense(params, cfg, tokens)[0]
+    unwindowed = model.forward_dense(
+        params, dataclasses.replace(cfg, window=0), tokens)[0]
+    assert not np.allclose(np.asarray(dense[0, s:]),
+                           np.asarray(unwindowed[0, s:]), atol=1e-3)
+
+    _, kvs = model.prefill(params, cfg, tokens[:, :s])
+    pages = 1 + np.arange(4)
+    k_pages, v_pages = _page_in(cfg, _empty_pool(cfg, 6), kvs, 0, pages)
+    page_table = jnp.asarray(pages[None], dtype=jnp.int32)
+    lens = jnp.asarray([s], dtype=jnp.int32)
+    one, k_pages, v_pages = model.decode_step(
+        params, cfg, tokens[:, s], lens, k_pages, v_pages, page_table)
+    many, _, _ = model.verify_step(
+        params, cfg, tokens[:, s + 1:], lens + 1, k_pages, v_pages,
+        page_table)
+    paged = np.concatenate([np.asarray(one)[:, None], np.asarray(many)],
+                           axis=1)
+    np.testing.assert_allclose(paged, np.asarray(dense[:, s:]),
+                               rtol=2e-4, atol=2e-4)
+    np.testing.assert_array_equal(paged.argmax(-1),
+                                  np.asarray(dense[:, s:]).argmax(-1))
+
+
+def test_pos0_shifted_prefix_prefill_equals_unshifted(windowed):
+    """A prefix trimmed to its in-window tail pages and prefilled at
+    `pos0` = the trimmed length gives the suffix logits and suffix KV of
+    the whole prefix at pos0 = 0: the same absolute rope positions, and
+    a band mask that never reached the dropped page."""
+    model, cfg, params = windowed.model, windowed.cfg, windowed.params
+    p_len, s_new, cut = 24, 8, 8  # window 16: suffix sees keys >= 9
+    tokens = _tokens(22, cfg, (2, p_len + s_new))
+    _, prefix_kvs = model.prefill(params, cfg, tokens[:, :p_len])
+    whole_logits, whole_kvs = model.prefill_with_prefix(
+        params, cfg, tokens[:, p_len:], prefix_kvs)
+    tail = [(k[:, cut:], v[:, cut:]) for k, v in prefix_kvs]
+    cut_logits, cut_kvs = model.prefill_with_prefix(
+        params, cfg, tokens[:, p_len:], tail, pos0=cut)
+    np.testing.assert_allclose(np.asarray(cut_logits),
+                               np.asarray(whole_logits),
+                               rtol=2e-4, atol=2e-4)
+    for (ck, cv), (wk, wv) in zip(cut_kvs, whole_kvs):
+        np.testing.assert_allclose(np.asarray(ck), np.asarray(wk),
+                                   rtol=2e-4, atol=2e-4)
+        np.testing.assert_allclose(np.asarray(cv), np.asarray(wv),
+                                   rtol=2e-4, atol=2e-4)
+    # pos0 matters: the same trimmed prefix at pos0 = 0 is another model.
+    wrong, _ = model.prefill_with_prefix(
+        params, cfg, tokens[:, p_len:], tail)
+    assert not np.allclose(np.asarray(wrong), np.asarray(whole_logits),
+                           atol=1e-3)
+
+
+# ---------------------------------------------------------------------------
+# One stack: the families differ in their config fields, their init and
+# their feed-forward block, and in nothing else.
+# ---------------------------------------------------------------------------
+
+def _binding(model, name):
+    f = getattr(model, "_forward_stack" if name == "forward_stack" else name)
+    return getattr(f, "__wrapped__", f)  # under the jit, the partial
+
+
+@pytest.mark.parametrize("loop", ["forward_stack", "decode_step",
+                                  "verify_step"])
+def test_families_bind_one_loop(loop):
+    """Each family's program is decoder.py's loop with the family's
+    block bound in: one function object, not a copy kept equal."""
+    for model, block in ((llama, llama._mlp), (moe, moe._moe_mlp)):
+        bound = _binding(model, loop)
+        assert bound.func is getattr(decoder, loop)
+        assert bound.args == (block,)
+
+
+def test_moe_config_is_a_llama_config():
+    assert issubclass(moe.MoEConfig, llama.LlamaConfig)
+    base, ext = llama.LlamaConfig(), moe.MoEConfig()
+    shared = [f.name for f in dataclasses.fields(llama.LlamaConfig)]
+    assert {f.name for f in dataclasses.fields(moe.MoEConfig)} == set(
+        shared) | {"n_experts", "top_k", "capacity_factor",
+                   "aux_loss_weight"}
+    assert all(getattr(base, n) == getattr(ext, n) for n in shared)
+    for name in ("head_dim", "jdtype", "kv_page_shape", "kv_page_bytes"):
+        assert name not in vars(moe.MoEConfig), name  # inherited, not copied
 
 
 def test_scatter_kv_to_pages():
@@ -281,8 +461,6 @@ def test_scatter_kv_to_pages():
 # ---------------------------------------------------------------------------
 
 def _pool_cfgs():
-    from infinistore_tpu.models import moe
-
     kw = dict(vocab_size=64, d_model=32, n_layers=3, n_heads=4,
               n_kv_heads=2, d_ff=64, max_seq=64, page_size=8,
               dtype="float32")
@@ -346,7 +524,8 @@ def _step_sliced(model, params, cfg, tokens, seq_lens, k_pages, v_pages,
     the tokens' rows into the slice, attend over the slice, stack the
     slices back. tokens [batch, m]; m == 1 is a decode step."""
     b, m = tokens.shape
-    x = llama._embed(params, tokens, cfg)
+    block = model.decode_step.__wrapped__.args[0]  # the family's own
+    x = decoder.embed(params, tokens, cfg)
     positions = seq_lens[:, None] + jnp.arange(m)[None, :]
     target_page = jnp.take_along_axis(
         page_table, positions // cfg.page_size, axis=1)
@@ -358,17 +537,14 @@ def _step_sliced(model, params, cfg, tokens, seq_lens, k_pages, v_pages,
         slot = jnp.where(ok, slot, jnp.arange(m)[None, :] % cfg.page_size)
     new_k, new_v = [], []
     for li, layer in enumerate(params["layers"]):
-        q, k, v = llama._qkv(layer, x, cfg, positions)
+        q, k, v = decoder.qkv(layer, x, cfg, positions)
         kp = k_pages[li].at[target_page, slot].set(k, mode="drop")
         vp = v_pages[li].at[target_page, slot].set(v, mode="drop")
         attn = pa.multi_token_paged_attention(q, kp, vp, page_table,
                                               seq_lens, window=cfg.window)
-        x = x + llama._attn_out(layer, attn.reshape(b, m, -1))
-        if model is llama:
-            x = x + llama._mlp(layer, x, cfg)
-        else:
-            valid = (seq_lens > 0)[:, None] if valid_len is None else ok
-            x = x + model._moe_mlp(layer, x, cfg, valid)[0]
+        x = x + decoder.attn_out(layer, attn.reshape(b, m, -1))
+        valid = (seq_lens > 0)[:, None] if valid_len is None else ok
+        x = x + block(layer, x, cfg, valid)[0]
         new_k.append(kp)
         new_v.append(vp)
     return jnp.stack(new_k), jnp.stack(new_v)

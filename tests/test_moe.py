@@ -1,9 +1,12 @@
 """Sparse-MoE model family tests (8-device virtual CPU mesh).
 
-Covers: routing conservation (dispatch/combine algebra), forward shapes,
-training step, expert-parallel sharded execution matching the
-single-device result, and MoE KV pages flowing through the store like
-any other pages (the model families share the paging contract).
+Covers what is the family's own: routing conservation (dispatch/combine
+algebra), capacity drops, the training step, expert-parallel sharded
+execution matching the single-device result, and the engine's serving
+modes under routing. The decoder stack it shares with the dense family
+(shapes, paged decode against dense, pages through the store, prefix
+hits, the m-token step, the window) is tests/test_model.py's, which
+runs every such test for both families.
 """
 
 import numpy as np
@@ -13,7 +16,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from infinistore_tpu.models import llama, moe
+from infinistore_tpu.models import moe
 
 
 def tiny_cfg(**kw):
@@ -62,21 +65,6 @@ def test_capacity_drop_is_bounded():
     # Some tokens dropped, and dropped tokens contribute zero.
     kept = np.asarray(jnp.sum(dispatch, axis=(1, 2)))
     assert kept.min() == 0 and kept.max() == 1
-
-
-def test_forward_shapes_and_finiteness():
-    cfg = tiny_cfg()
-    params = moe.init_params(jax.random.PRNGKey(0), cfg)
-    tokens = jnp.asarray(
-        np.random.default_rng(0).integers(0, cfg.vocab_size, (2, 16)),
-        jnp.int32,
-    )
-    logits, kvs, aux = moe.forward_dense(params, cfg, tokens)
-    assert logits.shape == (2, 16, cfg.vocab_size)
-    assert len(kvs) == cfg.n_layers
-    assert kvs[0][0].shape == (2, 16, cfg.n_kv_heads, cfg.head_dim)
-    assert np.isfinite(np.asarray(logits)).all()
-    assert np.isfinite(float(aux))
 
 
 def test_train_step_reduces_loss():
@@ -137,44 +125,7 @@ def test_expert_parallel_matches_single_device():
     assert "ep" in (e_gate_sh.spec[0],), e_gate_sh
 
 
-def test_moe_kv_pages_through_store(shm_conn):
-    """MoE KV pages are ordinary store blocks: page out through the same
-    kv_to_pages/page_keys helpers and restore bit-exact."""
-    from infinistore_tpu.tpu import TpuKVStore
-
-    cfg = tiny_cfg()
-    params = moe.init_params(jax.random.PRNGKey(0), cfg)
-    tokens = jnp.asarray(
-        np.random.default_rng(3).integers(0, cfg.vocab_size, (1, 16)),
-        jnp.int32,
-    )
-    _, kvs = moe.prefill(params, cfg, tokens)
-    k0 = kvs[0][0]
-    kp, _vp = llama.kv_to_pages(cfg, k0, kvs[0][1])
-    n_pages = kp.shape[1]
-    store = TpuKVStore(shm_conn)
-    keys = llama.page_keys("moe_seq", 0, "k", n_pages)
-    store.put_kv_pages(keys, kp[0], sync=True)
-    back = store.get_kv_pages(keys, cfg.kv_page_shape(), cfg.jdtype)
-    assert jnp.array_equal(back, kp[0])
-
-
 # ---- MoE serving (the engine's second model family) --------------------
-
-def _moe_dense_greedy(params, cfg, prompt, n_new):
-    """Greedy generation by dense re-forward — the paged-cache-free
-    oracle for the MoE engine's token stream."""
-    toks = list(prompt)
-    out = []
-    for _ in range(n_new):
-        logits, _, _ = moe.forward_dense(
-            params, cfg, jnp.asarray([toks], dtype=jnp.int32)
-        )
-        nxt = int(jnp.argmax(logits[0, -1]))
-        out.append(nxt)
-        toks.append(nxt)
-    return out
-
 
 @pytest.fixture(scope="module")
 def serve_cfg():
@@ -189,24 +140,6 @@ def serve_cfg():
 @pytest.fixture(scope="module")
 def serve_params(serve_cfg):
     return moe.init_params(jax.random.PRNGKey(3), serve_cfg)
-
-
-def test_moe_paged_decode_matches_dense(serve_params, serve_cfg):
-    """decode_step over paged KV must continue a prefilled sequence
-    exactly like the dense forward (the llama parity property, for the
-    routed family). Capacity note: routing is per-STEP here (T = batch
-    tokens), so per-expert capacity differs from the dense pass over
-    the full sequence — with this config nothing drops, making the
-    paths exactly comparable."""
-    from infinistore_tpu.serving import Request, ServingConfig, ServingEngine
-
-    rng = np.random.default_rng(50)
-    prompt = [int(t) for t in rng.integers(0, serve_cfg.vocab_size, 11)]
-    n_new = 9
-    ref = _moe_dense_greedy(serve_params, serve_cfg, prompt, n_new)
-    eng = ServingEngine(serve_params, serve_cfg, model=moe)
-    out = eng.run([Request("r", prompt, max_new_tokens=n_new)])
-    assert out["r"] == ref
 
 
 @pytest.mark.parametrize("mode", ["spec", "chunk", "burst"])

@@ -14,6 +14,7 @@ linter with --root there; the real tree is never touched.
 """
 
 import os
+import re
 import shutil
 import subprocess
 import sys
@@ -557,6 +558,59 @@ def test_iosched_stats_key_rename_fails(tree):
     r = run_linter(str(tree))
     assert r.returncode != 0
     assert "'stats_keys' drifted" in r.stderr
+
+
+# ---------------------------------------------------------------------------
+# Documents name files that exist: a deletion (PR 28 took out the old
+# root benchmark script and everything only it read) must not leave
+# instructions that point at a file the tree no longer has.
+# ---------------------------------------------------------------------------
+
+DOCUMENTS = [
+    "README.md",
+    "docs/api.md",
+    "docs/design.md",
+    "PARITY.md",
+    ".github/workflows/ci.yml",
+    ".claude/skills/verify/SKILL.md",
+]
+
+# A repo-relative *.py / *.sh path: no leading "/" (absolute paths and
+# the reference's own tree are not ours to check), no glob or
+# placeholder characters.
+_SCRIPT_PATH = re.compile(
+    r"(?<![\w./*<{-])((?:\./)?[\w.-]+(?:/[\w.-]+)*\.(?:py|sh))(?![\w*-])")
+
+
+def _script_paths_named(text, markdown):
+    """Paths a document names in a command or in backticks: for
+    markdown, fenced blocks and `code spans`; for the workflow file,
+    every line (its prose is comments on commands)."""
+    if not markdown:
+        return set(_SCRIPT_PATH.findall(text))
+    named, fenced = set(), False
+    for line in text.split("\n"):
+        if line.lstrip().startswith("```"):
+            fenced = not fenced
+            continue
+        for span in [line] if fenced else re.findall(r"`([^`]*)`", line):
+            named.update(_SCRIPT_PATH.findall(span))
+    return named
+
+
+@pytest.mark.parametrize("doc", DOCUMENTS)
+def test_document_names_only_files_in_the_tree(doc):
+    """Every *.py / *.sh path the document names resolves from the
+    repository root or from infinistore_tpu/ (how the documents
+    abbreviate `models/llama.py`)."""
+    with open(os.path.join(REPO, doc)) as f:
+        named = _script_paths_named(f.read(), doc.endswith(".md"))
+    assert named, f"{doc} names no script at all: the extractor is broken"
+    missing = sorted(
+        p for p in named
+        if not any(os.path.exists(os.path.join(REPO, base, p))
+                   for base in ("", "infinistore_tpu")))
+    assert not missing, f"{doc} names files the tree does not have: {missing}"
 
 
 def test_make_analyze_exits_zero():

@@ -62,8 +62,7 @@ def test_shaped_pipeline_fills_link(server, rng):
     large fraction of the cap. Stop-and-wait on 64 KiB blocks would get
     64 KiB / 10 ms = 6.4 MiB/s (frac 0.05); the 64 MiB inflight window
     covers the 1.25 MiB BDP ~50x over, so >=0.5 is a loose floor that
-    still separates pipelined from serialized by an order of magnitude
-    (bench.py's stream_rtt leg publishes the tight number, ~0.9)."""
+    still separates pipelined from serialized by an order of magnitude."""
     bps = 128 * (1 << 20)
     relay, conn = _shaped_conn(server, rtt_ms=10.0, bps=bps)
     try:
@@ -156,7 +155,7 @@ def _echo_server():
 
 def test_relay_enforces_bandwidth_cap():
     """The relay's pacer must actually hold the cap — if it under-shapes,
-    every stream_rtt_* fraction in the bench flatters the client.
+    every fraction-of-cap the shaped tests assert flatters the client.
 
     DEFLAKED (ISSUE 10 satellite, PR-8 review note): the old assertion
     demanded the measured rate land within [0.75, 1.25] of the cap,

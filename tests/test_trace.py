@@ -266,8 +266,8 @@ def test_tracing_off_records_nothing(server):
 
 def test_istpu_trace_env_overrides_config(monkeypatch):
     """ISTPU_TRACE=1 flips tracing on over a trace=False config (and
-    "0" would force it off) — the operator escape hatch the bench leg
-    and ops runbooks rely on."""
+    "0" would force it off) — the operator escape hatch ops runbooks
+    rely on."""
     monkeypatch.setenv("ISTPU_TRACE", "1")
     srv = InfiniStoreServer(
         ServerConfig(

@@ -13,8 +13,7 @@ Covers the four estimators end to end plus their export planes:
     watchdog.thrash verdict whose bundle carries workload.json;
   - dedup estimator: a known-duplicate key set reports the exact
     ratio; heat classes expose hot-key skew;
-  - kill switch (ISTPU_WORKLOAD=0): recording fully off — the bench
-    denominator contract;
+  - kill switch (ISTPU_WORKLOAD=0): recording fully off;
   - export: GET /workload over the manage plane, the stats "workload"
     section, /metrics families, history-ring demand deltas, and the
     istpu_top workload panel (live shape + bundle workload.json +
@@ -35,6 +34,7 @@ import urllib.request
 import numpy as np
 import pytest
 
+import scenario
 from infinistore_tpu import InfiniStoreServer, ServerConfig
 from infinistore_tpu.config import ClientConfig
 from infinistore_tpu.lib import InfinityConnection
@@ -44,14 +44,6 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 BLOCK_KB = 4
 BLOCK = BLOCK_KB << 10
-
-
-def _bench_module():
-    spec = importlib.util.spec_from_file_location(
-        "bench_for_workload", os.path.join(REPO, "bench.py"))
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
 
 
 def _istpu_top_module():
@@ -183,8 +175,7 @@ def test_sampler_deterministic_across_servers():
     # Admission is a pure hash of the key and the trace is fixed, so
     # two servers fed the same stream must land the same sampler
     # state bit for bit.
-    bench = _bench_module()
-    trace = bench.zipf_trace(96, 1024, seed=7)
+    trace = scenario.zipf_trace(96, 1024, seed=7)
     snaps = []
     for _ in range(2):
         srv = _server(48, enable_eviction=True, reclaim_high=1.0,
@@ -210,11 +201,9 @@ def test_mrc_accuracy_vs_exact_sim_and_measured():
     # against a pool holding half the keys, exact inline LRU, sampler
     # at rate 1.0 (the sampling-noise-free contract: the Fenwick
     # byte-stack itself must be exact) — predicted-vs-measured and
-    # predicted-vs-exact-sim both within 0.05. The bench
-    # --workload-leg pins the same bound at rate 1/2.
-    bench = _bench_module()
+    # predicted-vs-exact-sim both within 0.05.
     nkeys, cap = 128, 64
-    trace = bench.zipf_trace(nkeys, 3000, seed=11)
+    trace = scenario.zipf_trace(nkeys, 3000, seed=11)
     srv = _server(cap, enable_eviction=True, reclaim_high=1.0,
                   env={"ISTPU_EXACT_LRU": "1",
                        "ISTPU_WORKLOAD_RATE": "1.0"})
@@ -244,7 +233,7 @@ def test_mrc_accuracy_vs_exact_sim_and_measured():
     assert d_acc == len(trace)
     measured = d_miss / d_acc
     predicted = 1.0 - d_hit / d_samp
-    exact = bench.exact_lru_miss_ratio(trace, cap)
+    exact = scenario.exact_lru_miss_ratio(trace, cap)
     assert abs(predicted - measured) <= 0.05, (predicted, measured)
     assert abs(predicted - exact) <= 0.05, (predicted, exact)
     # The curve is monotone non-increasing in pool size.
