@@ -390,6 +390,20 @@ def page_keys(prefix, layer, kind, n_pages):
     return [f"{prefix}/L{layer}/{kind}/p{i}" for i in range(n_pages)]
 
 
+def restored_to_pages(cfg, flat):
+    """What one page-major store call returned (`flat`: [n * L * 2,
+    page, n_kv, hd], rows ordered page, layer, k then v) as per-layer
+    stacks in pool form: (k_pages, v_pages) [n_layers, n, page, n_kv,
+    hd]. One transpose; traceable, so the serving engine's hit program
+    (serving._admit_fused_px) does it on the device inside the program
+    that also scatters the stacks into the pool."""
+    n = flat.shape[0] // (2 * cfg.n_layers)
+    both = jnp.moveaxis(
+        flat.reshape(n, cfg.n_layers, 2, *cfg.kv_page_shape()), 0, 2
+    )
+    return both[:, 0], both[:, 1]
+
+
 def restore_prefix_pages(store, cfg, key_fn, n_pages,
                          getter=None):
     """Restore a matched prefix from the store in PAGE form: the one
@@ -407,16 +421,13 @@ def restore_prefix_pages(store, cfg, key_fn, n_pages,
     one offload wrote then lie in the store's pool in the order asked
     for, and the SHM read is one zero-copy view of the pool and not a
     view a block plus a stacking copy. The split back into per-layer
-    stacks is one device transpose, then slicing.
+    stacks is one device transpose, then slicing (`restored_to_pages`).
     Returns (k_pages, v_pages) [n_layers, n_pages, page, n_kv, hd]."""
     get = getter if getter is not None else store.get_kv_pages
     per = [key_fn(li, kind) for li in range(cfg.n_layers) for kind in "kv"]
     keys = [ks[p] for p in range(n_pages) for ks in per]
-    flat = get(keys, cfg.kv_page_shape(), cfg.jdtype)
-    both = jnp.moveaxis(
-        flat.reshape(n_pages, cfg.n_layers, 2, *cfg.kv_page_shape()), 0, 2
-    )
-    return both[:, 0], both[:, 1]
+    return restored_to_pages(
+        cfg, get(keys, cfg.kv_page_shape(), cfg.jdtype))
 
 
 def restore_prefix_kvs(store, cfg, seq_id, n_pages):

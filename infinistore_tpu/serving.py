@@ -293,8 +293,10 @@ def _prefill_px_jit(params, cfg, tokens, prefix_kvs, pos0=0, model=llama):
     """Module-level prefix-HIT prefill jit (static cfg + model family):
     every engine with the same config shares one compilation — a
     per-engine jax.jit(partial) would silently recompile identical HLO
-    for each new engine instance. Cold admissions use _admit_fused
-    instead."""
+    for each new engine instance. The suffix prefill alone, for callers
+    that hold the prefix in contiguous form (decoder.restore_prefix_kvs)
+    and want every position's logits and the suffix KV; the engine's
+    own admissions use _admit_fused (cold) and _admit_fused_px (hit)."""
     return model.prefill_with_prefix(params, cfg, tokens, prefix_kvs,
                                      pos0=pos0)
 
@@ -360,6 +362,67 @@ def _admit_fused(params, cfg, tokens, k_pages, v_pages, ids, s_real,
 
 
 @partial(jax.jit, static_argnames=("cfg", "model"), donate_argnums=(4, 5))
+def _admit_fused_px(params, cfg, tokens, restored, k_pages, v_pages,
+                    restored_ids, suffix_ids, s_real, pos0, model=llama):
+    """Prefix-HIT admission as ONE device program, as `_admit_fused` is
+    for the cold one: the restored pages go into the (donated) pool,
+    the suffix is prefilled over them, its KV is paged out into the
+    pool, and the last real position's logits row comes back. Done
+    eagerly (transpose, per-layer slices, pads to max_pages_per_seq,
+    two pool writes, kv_to_pages per layer) the same data movement was
+    some 300 dispatches for 16 layers, each holding the engine thread
+    (PERF.md, PR 24 and PR 29).
+
+    restored: the store call's result as `get_kv_pages` returns it,
+      page-major [n * L * 2, page, n_kv, hd] (decoder.restored_to_pages
+      has the order); n restored pages.
+    restored_ids: [n] pool ids of those pages, exactly n: nothing is
+      padded to the pool's arity.
+    suffix_ids: the suffix pages' ids in `_pad_ids` form; the first
+      s_pad // page are read. Padded positions beyond s_real land in
+      the tail page's unused slots (`_admit_fused` says why those bytes
+      are unreachable).
+    pos0: absolute position of the restored prefix's first token (> 0
+      for a windowed engine's trimmed prefix).
+    Ids at total_pages are dropped, so with every id there the pool
+    comes back untouched (`ServingEngine.first_token_logits`).
+    tokens: [1, s_pad] (page multiple). One program per (s_pad, n), as
+    `_prefill_px_jit` has per (s_pad, prefix length)."""
+    page = cfg.page_size
+    n = restored_ids.shape[0]
+    m = tokens.shape[1] // page
+    with jax.named_scope("pool.update"):  # stage names: models/decoder.py
+        # Each layer's pages go from the page-major rows straight into
+        # that layer of the pool. Scattered as one `[:, ids]` update
+        # from the transposed stacks below, the compiled program held 8
+        # times the restored bytes in temporaries (a second layout of
+        # the stacks, and weight copies pushed out of fast memory); so
+        # it holds one (tests/test_model.py, lowered for a v5e).
+        rows = restored.reshape(n, cfg.n_layers, 2, *cfg.kv_page_shape())
+        for li in range(cfg.n_layers):
+            k_pages = k_pages.at[li, restored_ids].set(rows[:, li, 0],
+                                                       mode="drop")
+            v_pages = v_pages.at[li, restored_ids].set(rows[:, li, 1],
+                                                       mode="drop")
+        # The contiguous form the suffix attends over: the same values,
+        # layer-major, reshaped (decoder.pages_to_kv, every layer at
+        # once).
+        kp, vp = decoder.restored_to_pages(cfg, restored)
+        flat = (cfg.n_layers, 1, n * page, cfg.n_kv_heads, cfg.head_dim)
+        k_pfx, v_pfx = kp.reshape(flat), vp.reshape(flat)
+    logits, kvs = model.prefill_with_prefix(
+        params, cfg, tokens,
+        [(k_pfx[li], v_pfx[li]) for li in range(cfg.n_layers)], pos0=pos0)
+    with jax.named_scope("pool.update"):
+        shape = (cfg.n_layers, m, page, cfg.n_kv_heads, cfg.head_dim)
+        k_sfx = jnp.stack([k[0] for k, _ in kvs]).reshape(shape)
+        v_sfx = jnp.stack([v[0] for _, v in kvs]).reshape(shape)
+        k_pages = k_pages.at[:, suffix_ids[:m]].set(k_sfx, mode="drop")
+        v_pages = v_pages.at[:, suffix_ids[:m]].set(v_sfx, mode="drop")
+    return logits[0, s_real - 1], k_pages, v_pages
+
+
+@partial(jax.jit, static_argnames=("cfg", "model"), donate_argnums=(4, 5))
 def _decode_fused(params, cfg, token, seq_lens, k_pages, v_pages, rows,
                   model=llama):
     """One fused device program per decode step: model forward + argmax
@@ -381,6 +444,27 @@ def _decode_fused(params, cfg, token, seq_lens, k_pages, v_pages, rows,
     nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
     # Live-rows-only advance — see _decode_scan's body comment.
     return logits, nxt, seq_lens + (seq_lens > 0), k_pages, v_pages
+
+
+# Trivial programs dispatched behind a one-shot admission's program
+# (ServingEngine._settle): 4 ended the slow mode in two runs of two, 1
+# and 2 did not; 8 behind the admissions out of idle alone ended it some
+# nine times in ten, which left one run in three with a slow spell of
+# 3-7 s (PERF.md, PR 29). They cost 0.2 ms each on the v5e host.
+SETTLE_PROGRAMS = 16
+# ... behind every admission for this long after one that found the
+# engine idle: a slow spell starts only there and the longest seen
+# lasted 13 s. An engine that is never idle pays nothing.
+SETTLE_S = 20.0
+# An engine with nothing to step sends the device one trivial program
+# this often (ServingEngine.idle): the spell that precedes the slow
+# mode was never under 1.0 s (PERF.md, PR 29).
+IDLE_TICK_S = 0.1
+
+
+@jax.jit
+def _tick(x):
+    return x + 1
 
 
 @partial(jax.jit, donate_argnums=(0, 1))
@@ -532,10 +616,6 @@ class ServingEngine:
         # serving (full prefills, no offload) instead of failing
         # requests on a cache.
         self._store_ok = True
-        # Cold admissions ride _admit_fused; the prefix-HIT suffix
-        # prefill keeps the shared module-level jit.
-        self._prefill_px = partial(_prefill_px_jit, params, cfg,
-                                   model=model)
         # Steady-state decode device cache: (key, token_dev, lens_dev,
         # rows_dev) left by the previous fused step. While the active
         # set, page tables and emitted tokens are exactly what the
@@ -546,6 +626,11 @@ class ServingEngine:
         # staleness is structural, not heuristic.
         self._steady = None
         self._pages_rev = 0
+        # The operand of _tick, when idle() last sent it, and when an
+        # admission last found no sequence running (_settle).
+        self._tick_x = self._to_device(np.int32(0))
+        self._ticked = time.monotonic()
+        self._left_idle = -SETTLE_S
         # Everything that shapes page BYTES goes into the key namespace:
         # engines differing in any of these must never cross-hit. When
         # the caller left model_id at its default AND a store is
@@ -867,56 +952,40 @@ class ServingEngine:
             self.free_pages.extend(self._admit_ids_view)
             raise
 
-    def _restore(self, prompt, hit, digests, first_live=0, foreign=0):
-        """Pages [first_live, hit) of `prompt`'s chain, store -> HBM in
-        pool form: (k, v) [n_layers, hit - first_live, page, n_kv, hd].
-        Digests are layer/kind-independent and come from the probe — the
-        prompt is hashed ONCE per admission."""
+    def _restore(self, hit, digests, first_live=0, foreign=0):
+        """Pages [first_live, hit) of the probed chain `digests`, store ->
+        HBM in ONE batched call over every layer and kind, as the store
+        call returns them: page-major [(hit - first_live) * L * 2, page,
+        n_kv, hd] (decoder.restored_to_pages splits that; page-major is
+        the order an offload allocated the keys in, so what one offload
+        wrote is one zero-copy view of the store's pool). Digests are
+        layer/kind-independent and come from the probe — the prompt is
+        hashed ONCE per admission."""
         n = hit - first_live
-
-        def get(keys, page_shape, dtype):
-            # The span times the store call alone — the interval a
-            # span around get_kv_pages from outside times too; building
-            # the keys and splitting the result are the admission's own.
-            with self._span("istpu.cache.restore", pages=n,
-                            bytes=n * self._page_bytes,
-                            foreign_pages=foreign):
-                return self._get_pages(keys, page_shape, dtype)
-
-        return decoder.restore_prefix_pages(
-            self.store, self.cfg,
-            lambda li, kind: content_page_keys(
-                prompt, self.cfg.page_size, hit, li, kind, digests=digests
-            )[first_live:],
-            n, getter=get,
-        )
-
-    def _to_kv(self, kp, vp):
-        """Restored pages in the contiguous per-layer form the one-shot
-        suffix prefill attends over."""
-        n_tokens = kp.shape[1] * self.cfg.page_size
-        with self._span("istpu.cache.to_kv", tokens=n_tokens):
-            return [
-                decoder.pages_to_kv(self.cfg, kp[li][None], vp[li][None],
-                                  n_tokens)
-                for li in range(self.cfg.n_layers)
-            ]
+        keys = content_page_keys_by_page(digests[first_live:hit],
+                                         self.cfg.n_layers)
+        # The span times the store call alone — the interval a span
+        # around get_kv_pages from outside times too.
+        with self._span("istpu.cache.restore", pages=n,
+                        bytes=n * self._page_bytes, foreign_pages=foreign):
+            return self._get_pages(keys, self.cfg.kv_page_shape(),
+                                   self.cfg.jdtype)
 
     def _admit_restore_and_prefill(self, slot_idx, work, ids, n_prompt,
                                    n_pages, hit, digests, skip,
                                    first_live, f):
         cfg = self.cfg
         self._admit_ids_view = ids
-        prefix_kvs = None
-        kp = vp = None
+        restored = None
         if hit > 0:
-            # Restore the in-window hit pages once (into HBM tensors;
-            # pool placement follows in _do_admit_paged).
+            # Restore the in-window hit pages once (one HBM array, as
+            # the store call returns it; pool placement follows in
+            # _do_admit_paged).
             # hit pages this engine did not itself offload
             foreign = sum(d not in self._own_digests for d in digests[:hit])
             try:
-                kp, vp = self._restore(work.prompt, hit, digests,
-                                       first_live, foreign)
+                restored = self._restore(hit, digests, first_live,
+                                         foreign)
             except InfiniStoreKeyNotFound:
                 # Routine eviction race: the page was LRU-dropped
                 # between probe and restore. A cache MISS for this
@@ -928,10 +997,6 @@ class ServingEngine:
                 self._store_failed("restore", e)
                 hit = 0
             else:
-                if self.sc.prefill_chunk == 0:
-                    # Contiguous form for the one-shot suffix prefill;
-                    # the chunked path attends straight over the pages.
-                    prefix_kvs = self._to_kv(kp, vp)
                 self.stats["prefix_hit_pages"] += hit
                 self.stats["foreign_hit_pages"] += foreign
                 f["foreign_pages"] = foreign
@@ -953,14 +1018,16 @@ class ServingEngine:
         f["hit_pages"] = hit
         self._do_admit_paged(
             slot_idx, work, ids, n_prompt, n_pages, hit, skip,
-            first_live, prefix_kvs, kp, vp,
+            first_live, restored,
         )
         work.probe = None  # consumed; a future re-admission re-probes
         f["outcome"] = "admitted"
         return True
 
     def _do_admit_paged(self, slot_idx, work, ids, n_prompt, n_pages,
-                        hit, skip, first_live, prefix_kvs, kp, vp):
+                        hit, skip, first_live, restored):
+        """`restored`: what _restore returned for pages [first_live,
+        hit), or None on a miss."""
         cfg = self.cfg
         page = cfg.page_size
         # page_ids[i] for i < skip are dead placeholders (page 0, the
@@ -968,19 +1035,11 @@ class ServingEngine:
         # below the band floor, and _release/_offload honor
         # slot.released = skip so they are never freed or offloaded.
         full_ids = [0] * skip + ids
-        if hit > skip and kp is not None:
-            # Pool placement for the restored pages. A hit implies the
-            # store_chain branch chose skip = first_live, so the
-            # restored tensors ([first_live, hit)) and the pool targets
-            # ([skip, hit)) line up exactly.
-            assert skip == first_live, (skip, first_live)
-            with self._span("istpu.cache.pool_write", what="restored",
-                            pages=hit - skip):
-                self._pool_write(
-                    ids[: hit - skip],
-                    kp[:, : hit - first_live],
-                    vp[:, : hit - first_live],
-                )
+        # Pool placement for the restored pages. A hit implies the
+        # store_chain branch chose skip = first_live, so the restored
+        # pages ([first_live, hit)) and the pool targets ([skip, hit) =
+        # ids[:hit - skip]) line up exactly.
+        assert restored is None or skip == first_live, (skip, first_live)
 
         row = np.zeros(self.sc.max_pages_per_seq, dtype=np.int32)
         row[skip:n_pages] = ids
@@ -989,8 +1048,14 @@ class ServingEngine:
             # Chunked admission: no bulk prefill here — the prompt tail
             # is consumed <= prefill_chunk tokens per engine step in a
             # MIXED batch with decoding slots (_unified_step); restored
-            # pages already back the cached prefix, and chunk attention
-            # runs straight over the pages.
+            # pages go into the pool to back the cached prefix, and
+            # chunk attention runs straight over the pages.
+            if restored is not None:
+                with self._span("istpu.cache.pool_write", what="restored",
+                                pages=hit - skip):
+                    self._pool_write(
+                        ids[:hit - skip],
+                        *decoder.restored_to_pages(cfg, restored))
             self.page_table[slot_idx] = row
             self.slots[slot_idx] = _Slot(
                 work=work, page_ids=full_ids, seq_len=hit * page,
@@ -1001,7 +1066,7 @@ class ServingEngine:
             return
 
         suffix = work.prompt[hit * page:]
-        if prefix_kvs is None:
+        if restored is None:
             # Cold admission (hit == 0). Dead prompt pages [0, skip)
             # scatter to the drop sentinel: no pool page was allocated
             # for them.
@@ -1014,8 +1079,14 @@ class ServingEngine:
             # and freed by the _release_windowed below, AFTER
             # offloading — keeping the prefix chain gap-free.
             row_host = self._prefill_hit(
-                suffix, prefix_kvs, first_live * page, ids[hit - skip:])
+                suffix, restored, first_live * page,
+                ids[:hit - skip], ids[hit - skip:])
         self.stats["prefill_tokens"] += len(suffix)
+        now = time.monotonic()
+        if not any(s is not None for s in self.slots):
+            self._left_idle = now
+        if now - self._left_idle < SETTLE_S:
+            self._settle()
 
         self.page_table[slot_idx] = row
 
@@ -1032,6 +1103,43 @@ class ServingEngine:
         # already trimmed to [first_live, hit) — only the PROBE's key
         # list stays O(prompt), it is hash-only).
         self._release_windowed(slot)
+
+    def idle(self):
+        """For whoever drives an engine that has nothing to step
+        (serving_http's loop, on every pass that finds no work): one
+        trivial program every IDLE_TICK_S, so that an admission's
+        program is not the first thing the device runs after an idle
+        spell. Of the admissions that followed under 1.0 s of idle
+        none started the slow mode _settle describes, of those after
+        1.0-4.0 s three in four did (PERF.md, PR 29)."""
+        now = time.monotonic()
+        if now - self._ticked >= IDLE_TICK_S:
+            self._ticked = now
+            jax.block_until_ready(_tick(self._tick_x))
+
+    def _settle(self):
+        """SETTLE_PROGRAMS trivial programs behind a one-shot
+        admission's program, after the row pull. Seen on the v5e host
+        (PERF.md, PR 29): when a long program is the first thing the
+        device runs after an idle spell of a second or more, every
+        later wait for the device (decode steps, admissions, offload
+        gathers alike) may come back some 2.5 ms late, for 3-13 s, and
+        the engine thread's own host work takes twice as long. What
+        ends it is a burst of short programs: the hundreds of eager
+        dispatches a hit admission was before it became one program
+        ended it every time, which is why only cold admissions showed
+        it then. The cause lies under this program and is not known;
+        the cure is what those bursts did, in under a millisecond. It
+        holds some nine times in ten, so it runs behind EVERY
+        admission (as the old hit's burst did) for SETTLE_S after one
+        that found the engine idle, not only behind that one: a slow
+        spell that does start ends with one of the next admissions,
+        not seconds later. idle() is the other half: it keeps most
+        spells from starting."""
+        x = self._tick_x
+        for _ in range(SETTLE_PROGRAMS):
+            x = _tick(x)
+        jax.block_until_ready(x)
 
     def _pad_tokens(self, tokens):
         """Prompt tokens as the [1, s_pad] device array the prefill
@@ -1058,43 +1166,41 @@ class ServingEngine:
             )
             return np.asarray(row_dev)
 
-    def _prefill_hit(self, suffix, prefix_kvs, pos0, ids=None):
-        """The prefix program: the suffix attends over the restored
-        `prefix_kvs`. pos0 anchors the trimmed prefix's absolute rope
-        positions; the band mask is relative, so local indices inside
-        the kernel stay correct (decoder.forward_stack). With `ids`,
-        the suffix KV (real tokens only) is paged out into the pool
-        there — dispatched while the program runs on the device, so
-        that span lies inside this one. Returns the last real
-        position's logits row, on the host."""
-        cfg = self.cfg
-        s_real = len(suffix)
+    def _prefill_hit(self, suffix, restored, pos0, restored_ids,
+                     suffix_ids):
+        """The prefix program: ONE fused device program scatters the
+        `restored` pages (what _restore returned) into the pool at
+        `restored_ids`, prefills the suffix over them, and pages its KV
+        out into the pool at `suffix_ids`. pos0 anchors the trimmed
+        prefix's absolute rope positions; the band mask is relative, so
+        local indices inside the kernel stay correct
+        (decoder.forward_stack). Ids at the drop sentinel are not
+        written. Between the store call's return and this row pull the
+        engine thread dispatches that one program and nothing else.
+        Returns the last real position's logits row, on the host."""
         toks = self._pad_tokens(suffix)
         with self._span("istpu.model.prefill", program="prefix",
-                        tokens=s_real, padded_tokens=toks.shape[1]):
-            logits, kvs = self._prefill_px(
-                toks, prefix_kvs, self._to_device(np.int32(pos0))
+                        tokens=len(suffix), padded_tokens=toks.shape[1],
+                        restored_pages=len(restored_ids)):
+            row_dev, self.k_pages, self.v_pages = _admit_fused_px(
+                self.params, self.cfg, toks, restored,
+                self.k_pages, self.v_pages,
+                self._to_device(np.asarray(restored_ids, np.int32)),
+                self._to_device(self._pad_ids(suffix_ids)),
+                self._to_device(np.int32(len(suffix))),
+                self._to_device(np.int32(pos0)),
+                model=self.model,
             )
-            if ids is not None:
-                with self._span("istpu.cache.pool_write", what="suffix",
-                                pages=len(ids)):
-                    k_sfx = jnp.stack([k[:, :s_real] for k, _ in kvs])
-                    v_sfx = jnp.stack([v[:, :s_real] for _, v in kvs])
-                    kp_s, vp_s = [], []
-                    for li in range(cfg.n_layers):
-                        a, b = decoder.kv_to_pages(cfg, k_sfx[li], v_sfx[li])
-                        kp_s.append(a[0])
-                        vp_s.append(b[0])
-                    self._pool_write(ids, jnp.stack(kp_s), jnp.stack(vp_s))
-            return np.asarray(logits[0, s_real - 1])
+            return np.asarray(row_dev)
 
     def first_token_logits(self, prompt):
         """First-token logits of `prompt` through the programs an
         admission dispatches, without admitting anything: on a miss
         the cold program with every page id at the drop sentinel (the
         pool is untouched), on a hit probe + restore + the prefix
-        program. Returns (float32 row [vocab], hit pages). The engine
-        must be idle, and the caller on the thread that steps it."""
+        program, likewise with every id at the sentinel. Returns
+        (float32 row [vocab], hit pages). The engine must be idle, and
+        the caller on the thread that steps it."""
         if self.queue or any(s is not None for s in self.slots):
             raise RuntimeError("first_token_logits needs an idle engine")
         prompt = [int(t) for t in prompt]
@@ -1107,12 +1213,13 @@ class ServingEngine:
             first_live = max(0, hit * page - window + 1) // page \
                 if window else 0
             try:
-                kp, vp = self._restore(prompt, hit, digests, first_live)
+                restored = self._restore(hit, digests, first_live)
             except InfiniStoreKeyNotFound:
                 hit = 0  # evicted between probe and restore
         if hit > 0:
-            row = self._prefill_hit(prompt[hit * page:],
-                                    self._to_kv(kp, vp), first_live * page)
+            row = self._prefill_hit(
+                prompt[hit * page:], restored, first_live * page,
+                [self.sc.total_pages] * (hit - first_live), [])
         else:
             row = self._prefill_cold(prompt, self._pad_ids([]))
         return np.asarray(row, np.float32), hit

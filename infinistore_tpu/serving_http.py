@@ -364,6 +364,13 @@ class ServingHTTPServer:
                         continue
                     self._finish_req(rid, st, out)
             if not progressed:
+                try:
+                    eng.idle()
+                except Exception:
+                    # A device that fails a trivial program fails the
+                    # next step too, and that path takes the engine
+                    # down cleanly.
+                    pass
                 time.sleep(0.002)
 
     # -- lifecycle -----------------------------------------------------

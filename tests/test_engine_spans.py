@@ -20,6 +20,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from infinistore_tpu import serving
 from infinistore_tpu.models import llama, moe
 from infinistore_tpu.serving import (
     Request, ServingConfig, ServingEngine, _Work,
@@ -128,11 +129,13 @@ def test_hit_span_tree(params, cfg, shm_conn):
     # 4 prompt pages + the full pages the first answer completed.
     assert hit == (len(first) + len(out) - 1) // PAGE == 4
     kids = _children(spans, admit)
+    # One program from the store call's return to the row pull: the
+    # restored pages' way into the pool, the prefix form and the
+    # suffix's page-out are inside it, so none of them is a span.
     assert [k.name for k in kids] == [
-        "istpu.cache.probe", "istpu.cache.restore", "istpu.cache.to_kv",
-        "istpu.cache.pool_write", "istpu.model.prefill"]
+        "istpu.cache.probe", "istpu.cache.restore", "istpu.model.prefill"]
     assert all(k.request == "h1" for k in kids)
-    probe, restore, to_kv, pool, prefill = kids
+    probe, restore, prefill = kids
     assert probe.fields["hit_pages"] == hit
     # restore: bytes are pages x the bytes of one page over every layer
     # and both kinds, and its transfer is one h2d of as many bytes.
@@ -145,16 +148,174 @@ def test_hit_span_tree(params, cfg, shm_conn):
     (h2d,) = _children(spans, restore)
     assert h2d.name == "istpu.xfer.h2d"
     assert h2d.fields["bytes"] == hit * page_bytes
-    assert to_kv.fields == {"tokens": hit * PAGE}
-    assert pool.fields == {"what": "restored", "pages": hit}
     n_sfx = len(follow) - hit * PAGE
+    # No window here, so first_live is 0 and every hit page is restored.
     assert prefill.fields == {"program": "prefix", "tokens": n_sfx,
-                              "padded_tokens": -(-n_sfx // PAGE) * PAGE}
-    # The suffix's pool write is dispatched while the prefix program
-    # runs, so it lies inside that span.
-    (sfx,) = _children(spans, prefill)
-    assert sfx.name == "istpu.cache.pool_write"
-    assert sfx.fields == {"what": "suffix", "pages": -(-n_sfx // PAGE)}
+                              "padded_tokens": -(-n_sfx // PAGE) * PAGE,
+                              "restored_pages": hit}
+    assert not _children(spans, prefill)
+    assert not _named(spans, "istpu.cache.to_kv")
+    assert not _named(spans, "istpu.cache.pool_write")
+
+
+def test_windowed_hit_restores_from_first_live(cfg, shm_conn):
+    """With a window the restore starts at the first page the suffix
+    can attend: `restored_pages` is hit - first_live, the restore's
+    `pages` likewise, and the tree has the same three children."""
+    import dataclasses
+
+    wcfg = dataclasses.replace(cfg, window=2 * PAGE)
+    wparams = llama.init_params(jax.random.PRNGKey(0), wcfg)
+    eng = _engine(wparams, wcfg, shm_conn, "spans-hit-window")
+    first = _prompt(4, 4 * PAGE)
+    out = eng.run([Request("w0", first, max_new_tokens=PAGE)])["w0"]
+    spans = _run(eng, Request("w1", first + out + _prompt(5, 5),
+                              max_new_tokens=2))
+    (admit,) = _named(spans, "istpu.sched.admit")
+    hit = admit.fields["hit_pages"]
+    first_live = (hit * PAGE - 2 * PAGE + 1) // PAGE
+    assert (hit, first_live) == (4, 2)
+    kids = _children(spans, admit)
+    assert [k.name for k in kids] == [
+        "istpu.cache.probe", "istpu.cache.restore", "istpu.model.prefill"]
+    assert kids[1].fields["pages"] == hit - first_live
+    assert kids[2].fields["restored_pages"] == hit - first_live
+    assert kids[2].fields["program"] == "prefix"
+    assert not _named(spans, "istpu.cache.pool_write")
+
+
+def test_chunked_hit_keeps_its_restored_pool_write(params, cfg, shm_conn):
+    """The chunked path (prefill_chunk > 0) attends straight over pool
+    pages and never ran the prefix program: its restored pages still
+    go into the pool through the one eager pool write."""
+    eng = _engine(params, cfg, shm_conn, "spans-hit-chunked",
+                  prefill_chunk=PAGE)
+    first = _prompt(6, 4 * PAGE)
+    out = eng.run([Request("c0", first, max_new_tokens=PAGE)])["c0"]
+    spans = _run(eng, Request("c1", first + out + _prompt(7, 5),
+                              max_new_tokens=2))
+    (admit,) = _named(spans, "istpu.sched.admit")
+    hit = admit.fields["hit_pages"]
+    assert hit == 4
+    kids = _children(spans, admit)
+    assert [k.name for k in kids] == [
+        "istpu.cache.probe", "istpu.cache.restore",
+        "istpu.cache.pool_write"]
+    assert kids[2].fields == {"what": "restored", "pages": hit}
+    assert not _named(spans, "istpu.model.prefill", program="prefix")
+
+
+def _host_calls(trace_dir):
+    """Every call of a jitted function (an eager jnp operation is one
+    too: each dispatches a program) the profiler saw on the host, as
+    (name, start_ns, end_ns), outermost events only: jax's dispatch
+    nests two `PjitFunction(<name>)` events a call."""
+    calls = sorted((s, s + d, n) for n, s, d, _ in
+                   _xplane_events(trace_dir, prefix="PjitFunction("))
+    out = []
+    for s, e, n in calls:
+        if out and s < out[-1][2]:
+            continue  # nested in the call before
+        out.append((n[len("PjitFunction("):-1], s, e))
+    return out
+
+
+def test_a_warm_hit_admission_is_one_program(params, cfg, shm_conn,
+                                             tmp_path):
+    """The counter that says the mechanism engages: between the store
+    call's return and the pull of the logits row a warmed hit admission
+    dispatches ONE jitted program, `_admit_fused_px`, and no eager
+    operation (each would be a `PjitFunction(...)` host event of its
+    own: a pad, a slice, a stack), and compiles nothing."""
+    eng = _engine(params, cfg, shm_conn, "spans-hit-one-program")
+
+    def session(seed, rid):
+        first = _prompt(seed, 4 * PAGE)
+        out = eng.run([Request(rid + "a", first,
+                               max_new_tokens=PAGE)])[rid + "a"]
+        return Request(rid + "b", first + out + _prompt(seed + 1, 5),
+                       max_new_tokens=2)
+
+    eng.run([session(20, "warm")])  # every shape of a hit admission
+    follow = session(22, "hit")
+    built = eng.stats["compilations"]
+    with profiling.profile_window(trace_dir=tmp_path) as w:
+        eng.run([follow])
+    assert eng.stats["compilations"] == built
+    (admit,) = [s for s in w.engine_spans if s.name == "istpu.sched.admit"]
+    assert admit.fields["hit_pages"] == 4
+    # The profiler's events are on one clock of their own: take the
+    # admission's spans from the same plane.
+    events = _xplane_events(str(tmp_path))
+    (restore,) = [e for e in events if e[0] == "istpu.cache.restore"]
+    (prefill,) = [e for e in events if e[0] == "istpu.model.prefill"]
+    (whole,) = [e for e in events if e[0] == "istpu.sched.admit"]
+    calls = _host_calls(str(tmp_path))
+    # The counter counts: the decode steps after the admission are there.
+    assert "_decode_fused" in {n for n, *_ in calls}
+    between = [n for n, s, e in calls
+               if restore[1] + restore[2] <= s <= prefill[1] + prefill[2]]
+    assert between == ["_admit_fused_px"]
+    # ... and in the whole admission nothing else but, after the row
+    # pull, the trivial programs that follow every admission.
+    assert [n for n, s, e in calls
+            if whole[1] <= s <= whole[1] + whole[2]] == (
+        ["_admit_fused_px"] + ["_tick"] * serving.SETTLE_PROGRAMS)
+
+
+def test_admissions_settle_for_a_while_after_one_out_of_idle(
+        params, cfg, monkeypatch):
+    """`_settle` runs behind the program of every one-shot admission,
+    cold or hit, into an idle engine or beside running sequences, for
+    SETTLE_S after an admission that found no sequence running, and
+    behind none later: an engine that is never idle stops paying."""
+    eng = _engine(params, cfg, None, "spans-settle")
+    now = [100.0]
+    monkeypatch.setattr(serving.time, "monotonic", lambda: now[0])
+    settled = []
+    real = eng._settle
+    monkeypatch.setattr(eng, "_settle",
+                        lambda: (settled.append(len(eng.queue)), real()))
+    # Two slots, three requests: the first finds the engine idle, the
+    # second a running sequence, the third waits for a slot and is
+    # admitted beside the longer of the two.
+    eng.run([Request("s0", _prompt(30, PAGE + 2), max_new_tokens=12),
+             Request("s1", _prompt(31, PAGE + 2), max_new_tokens=3),
+             Request("s2", _prompt(32, PAGE + 2), max_new_tokens=3)])
+    assert len(settled) == 3
+    # Long after: the one out of idle settles and restarts the clock ...
+    now[0] += 10 * serving.SETTLE_S
+    eng.submit(Request("s3", _prompt(33, PAGE + 2), max_new_tokens=30))
+    eng.step()
+    assert len(settled) == 4
+    # ... one beside it, SETTLE_S later, does not.
+    now[0] += serving.SETTLE_S
+    eng.submit(Request("s4", _prompt(34, PAGE + 2), max_new_tokens=2))
+    eng.run()
+    assert len(settled) == 4
+
+
+def test_an_idle_engine_ticks_every_idle_tick_s(params, cfg, monkeypatch):
+    """`idle()`, which the HTTP loop calls on every pass that finds no
+    work, sends the device one trivial program an IDLE_TICK_S and
+    nothing between."""
+    eng = _engine(params, cfg, None, "spans-idle")
+    now = [eng._ticked]
+    monkeypatch.setattr(serving.time, "monotonic", lambda: now[0])
+    ticks = []
+    real = serving._tick
+    monkeypatch.setattr(serving, "_tick",
+                        lambda x: (ticks.append(now[0]), real(x))[1])
+    for _ in range(50):  # a pass every 2 ms for 100 ms: under the period
+        now[0] += 0.002
+        eng.idle()
+        if now[0] - eng._ticked < serving.IDLE_TICK_S - 0.003:
+            assert len(ticks) <= 1
+    assert len(ticks) == 1
+    now[0] += 10 * serving.IDLE_TICK_S  # however long the pause: one
+    eng.idle()
+    eng.idle()
+    assert len(ticks) == 2
 
 
 def test_finish_span_tree(params, cfg, shm_conn):

@@ -517,6 +517,97 @@ def test_step_program_holds_no_layer_of_the_pool(family, step):
         ma.temp_size_in_bytes, one_layer_and_kind)
 
 
+@pytest.fixture(scope="module")
+def v5e_chip():
+    """One chip of a DESCRIBED v5e host: the TPU's compiler is installed
+    here and compiles for a chip that is not attached (nothing runs).
+    Described inside the fixture, never at import: one process at a
+    time may load the TPU's library, and every xdist worker imports
+    this file."""
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setenv("TPU_LOG_DIR", "disabled")
+        mp.setenv("TPU_SKIP_MDS_QUERY", "1")
+        mp.setenv("TPU_ACCELERATOR_TYPE", "v5litepod-4")
+        mp.setenv("TPU_WORKER_HOSTNAMES", "localhost")
+        try:
+            topo = topologies.get_topology_desc(platform="tpu",
+                                                topology_name="v5e:2x2")
+        except Exception as e:
+            pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def test_hit_program_aliases_the_pools_and_holds_the_restored_pages_once(
+        v5e_chip, monkeypatch):
+    """The fused hit admission at mistral7b's widths and pool geometry,
+    compiled for a described v5e as benchmark/tools/aot_memory.py
+    compiles the other programs (the attention wrappers steered to the
+    branch the chip takes): both pools are donated and aliased, and the
+    temporaries stay under twice the restored bytes plus what the
+    suffix prefill alone holds and returns. A scatter of the
+    layer-major stacks as one `[:, ids]` update held 1.09 GB here, this
+    form 0.21 GB; a pad to max_pages_per_seq, a pool layer sliced out or
+    a pool copied would each show."""
+    import os
+    import sys
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    if root not in sys.path:
+        sys.path.insert(0, root)
+    from benchmark.lib import serve
+    from infinistore_tpu import serving
+
+    conf = serve.load_config("benchmark/configs/mistral7b.json")
+    model, cfg = serve.model_config(conf)
+    sc = conf["serving"]
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    # A compile for a described device is written to the persistent
+    # cache but cannot be read back without a chip.
+    cache = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e_chip)
+
+    params = jax.tree_util.tree_map(
+        lambda x: sds(x.shape, x.dtype),
+        jax.eval_shape(lambda k: model.init_params(k, cfg),
+                       jax.ShapeDtypeStruct((2,), jnp.uint32)))
+    # The sessions mix's largest hit: 128 restored pages (134 MB), a
+    # suffix of 352 tokens.
+    s_pad, n = 352, 128
+    i32 = jnp.int32
+    pool = sds((cfg.n_layers, sc["total_pages"], *cfg.kv_page_shape()),
+               cfg.jdtype)
+    restored = sds((n * cfg.n_layers * 2, *cfg.kv_page_shape()), cfg.jdtype)
+    kv = sds((1, n * cfg.page_size, cfg.n_kv_heads, cfg.head_dim),
+             cfg.jdtype)
+    try:
+        fused = serving._admit_fused_px.lower(
+            params, cfg, sds((1, s_pad), i32), restored, pool, pool,
+            sds((n,), i32), sds((sc["max_pages_per_seq"],), i32),
+            sds((), i32), sds((), i32), model=model,
+        ).compile().memory_analysis()
+        alone = serving._prefill_px_jit.lower(
+            params, cfg, sds((1, s_pad), i32), [(kv, kv)] * cfg.n_layers,
+            sds((), i32), model=model,
+        ).compile().memory_analysis()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cache)
+    pool_bytes = 2 * int(np.prod(pool.shape)) * cfg.jdtype.itemsize
+    restored_bytes = int(np.prod(restored.shape)) * cfg.jdtype.itemsize
+    assert fused.alias_size_in_bytes >= pool_bytes  # donated, aliased
+    # Its outputs beyond the pools: one logits row.
+    assert fused.output_size_in_bytes - pool_bytes < 1 << 20
+    bound = (2 * restored_bytes + alone.temp_size_in_bytes
+             + alone.output_size_in_bytes)
+    assert fused.temp_size_in_bytes < bound < pool_bytes // 4, (
+        fused.temp_size_in_bytes, bound)
+
+
 def _step_sliced(model, params, cfg, tokens, seq_lens, k_pages, v_pages,
                  page_table, valid_len=None):
     """The formulation decode_step / verify_step had before the pool
@@ -602,6 +693,151 @@ def test_pool_after_a_step_equals_slice_scatter_stack(family, step):
         for li in range(cfg.n_layers):  # [total_pages, page] per layer
             assert {(int(p), int(s)) for p, s in zip(*np.nonzero(diff[li]))
                     } == written, li
+
+
+# ---------------------------------------------------------------------------
+# The hit admission as one program (serving._admit_fused_px) against the
+# composition of public pieces the engine dispatched one by one before.
+# ---------------------------------------------------------------------------
+
+_HIT_TOTAL_PAGES = 12
+_HIT_ARITY = 8  # max_pages_per_seq of the `_pad_ids` form
+
+# p_len prefix tokens were cached; `cut` leading pages of them lie below
+# a windowed engine's band floor and are not restored (pos0 = cut pages).
+_HIT_CASES = {
+    "page_multiple_suffix": dict(p_len=24, s_real=16, cut=0, window=0),
+    "ragged_suffix": dict(p_len=24, s_real=13, cut=0, window=0),
+    "windowed_trimmed_prefix_pos0": dict(p_len=24, s_real=8, cut=1,
+                                         window=16),
+    "every_id_at_the_drop_sentinel": dict(p_len=16, s_real=11, cut=0,
+                                          window=0, drop=True),
+}
+
+
+def _page_major(cfg, kvs, first_page):
+    """A prefill's per-layer KV of batch row 0, from page `first_page`
+    on, in the form one store call returns it: [n * L * 2, page, n_kv,
+    hd], rows ordered page, layer, k then v."""
+    per_layer = []
+    for k, v in kvs:
+        kp, vp = decoder.kv_to_pages(cfg, k[:1], v[:1])
+        per_layer.append(jnp.stack([kp[0, first_page:],
+                                    vp[0, first_page:]]))
+    both = jnp.stack(per_layer)  # [L, 2, n, page, n_kv, hd]
+    return jnp.moveaxis(both, 2, 0).reshape(-1, *cfg.kv_page_shape())
+
+
+def _hit_composed(f, toks, restored, pools, restored_ids, suffix_ids,
+                  s_real, pos0):
+    """Today's composition, dispatch by dispatch: restore_prefix_pages
+    -> pages_to_kv -> _prefill_px_jit -> kv_to_pages -> _write_pages
+    (twice, each padded to the fixed arity)."""
+    from infinistore_tpu import serving
+
+    model, cfg, params = f.model, f.cfg, f.params
+    n = len(restored_ids)
+    kp, vp = decoder.restore_prefix_pages(
+        None, cfg, lambda li, kind: [f"L{li}/{kind}/{p}" for p in range(n)],
+        n, getter=lambda keys, shape, dtype: restored)
+    prefix_kvs = [decoder.pages_to_kv(cfg, kp[li][None], vp[li][None],
+                                      n * cfg.page_size)
+                  for li in range(cfg.n_layers)]
+    logits, kvs = serving._prefill_px_jit(
+        params, cfg, toks, prefix_kvs, jnp.int32(pos0), model=model)
+
+    def write(pools, ids, k_new, v_new):
+        ids_p = np.full(_HIT_ARITY, _HIT_TOTAL_PAGES, np.int32)
+        ids_p[:len(ids)] = ids
+        pad = [(0, 0), (0, _HIT_ARITY - k_new.shape[1])] + [(0, 0)] * 3
+        return serving._write_pages(*pools, jnp.asarray(ids_p),
+                                    jnp.pad(k_new, pad), jnp.pad(v_new, pad))
+
+    pools = write(pools, restored_ids, kp, vp)
+    k_sfx, v_sfx = [], []
+    for k, v in kvs:
+        a, b = decoder.kv_to_pages(cfg, k[:, :s_real], v[:, :s_real])
+        k_sfx.append(a[0])
+        v_sfx.append(b[0])
+    m = k_sfx[0].shape[0]
+    pools = write(pools, suffix_ids[:m], jnp.stack(k_sfx), jnp.stack(v_sfx))
+    return logits[0, s_real - 1], *pools
+
+
+@pytest.mark.parametrize("case", list(_HIT_CASES))
+@pytest.mark.parametrize("name", ["llama", "moe"])
+def test_fused_hit_program_equals_the_composition(name, case):
+    """serving._admit_fused_px on what one store call returned against
+    the composition on the same restored pages: the restored pool pages
+    bit-exact, the suffix pool pages (real positions) and the logits
+    row within the cold-against-hit tolerance, the same argmax, every
+    other pool page untouched. With every id at the drop sentinel (the
+    `first_token_logits` form) the pool comes back as it went in."""
+    from infinistore_tpu import serving
+
+    c = _HIT_CASES[case]
+    f = _family(name, window=c["window"])
+    model, cfg, params = f.model, f.cfg, f.params
+    page = cfg.page_size
+    p_len, s_real, cut = c["p_len"], c["s_real"], c["cut"]
+    s_pad = -(-s_real // page) * page
+    tokens = _tokens(31, cfg, (1, p_len + s_real))
+    _, prefix_kvs = model.prefill(params, cfg, tokens[:, :p_len])
+    restored = _page_major(cfg, prefix_kvs, cut)
+    n, m = p_len // page - cut, s_pad // page
+    assert restored.shape[0] == n * cfg.n_layers * 2
+    toks = jnp.zeros((1, s_pad), jnp.int32).at[:, :s_real].set(
+        tokens[:, p_len:])
+    if c.get("drop"):
+        restored_ids = [_HIT_TOTAL_PAGES] * n
+        suffix_ids = []
+    else:
+        restored_ids = [9, 2, 5][:n]
+        suffix_ids = [7, 3][:m]
+    rng = np.random.default_rng(32)
+    shape = (cfg.n_layers, _HIT_TOTAL_PAGES, *cfg.kv_page_shape())
+    k0 = rng.standard_normal(shape).astype(np.float32)
+    v0 = rng.standard_normal(shape).astype(np.float32)
+    pos0 = cut * page
+
+    want_row, want_k, want_v = _hit_composed(
+        f, toks, restored, (jnp.asarray(k0), jnp.asarray(v0)),
+        restored_ids, suffix_ids, s_real, pos0)
+    ids_p = np.full(_HIT_ARITY, _HIT_TOTAL_PAGES, np.int32)
+    ids_p[:len(suffix_ids)] = suffix_ids
+    row, got_k, got_v = serving._admit_fused_px(
+        params, cfg, toks, restored, jnp.asarray(k0), jnp.asarray(v0),
+        jnp.asarray(restored_ids, jnp.int32), jnp.asarray(ids_p),
+        jnp.int32(s_real), jnp.int32(pos0), model=model)
+
+    row, want_row = np.asarray(row), np.asarray(want_row)
+    assert row.shape == (cfg.vocab_size,)
+    np.testing.assert_allclose(row, want_row, rtol=2e-4, atol=2e-4)
+    assert row.argmax() == want_row.argmax()
+    # ... which is the dense forward's row over prefix + suffix.
+    dense = model.forward_dense(params, cfg, tokens)[0]
+    np.testing.assert_allclose(row, np.asarray(dense[0, -1]),
+                               rtol=2e-4, atol=2e-4)
+    tail = s_real - (m - 1) * page  # real slots of the last suffix page
+    for got, want, before in ((got_k, want_k, k0), (got_v, want_v, v0)):
+        got, want = np.asarray(got), np.asarray(want)
+        if c.get("drop"):
+            np.testing.assert_array_equal(got, before)
+            np.testing.assert_array_equal(want, before)
+            continue
+        np.testing.assert_array_equal(got[:, restored_ids],
+                                      want[:, restored_ids])
+        assert not np.array_equal(got[:, restored_ids],
+                                  before[:, restored_ids])
+        np.testing.assert_allclose(got[:, suffix_ids[:-1]],
+                                   want[:, suffix_ids[:-1]],
+                                   rtol=2e-4, atol=2e-4)
+        np.testing.assert_allclose(got[:, suffix_ids[-1], :tail],
+                                   want[:, suffix_ids[-1], :tail],
+                                   rtol=2e-4, atol=2e-4)
+        others = sorted(set(range(_HIT_TOTAL_PAGES))
+                        - set(restored_ids) - set(suffix_ids))
+        np.testing.assert_array_equal(got[:, others], before[:, others])
 
 
 def test_train_step_sharded_mesh(cfg):

@@ -29,7 +29,7 @@ from infinistore_tpu import (
 )
 from infinistore_tpu import serving
 from infinistore_tpu._native import FAKE_TOKEN
-from infinistore_tpu.models import llama, moe
+from infinistore_tpu.models import decoder, llama, moe
 from infinistore_tpu.serving import (
     Request, ServingConfig, ServingEngine, _Slot, _Work,
     content_page_keys, content_page_keys_by_page,
@@ -371,7 +371,8 @@ def test_restore_asks_in_the_order_the_offload_allocated(monkeypatch):
             seen.append(blocks.copy())
             return real(blocks, *a)
         monkeypatch.setattr(inner, "_pool_batch_view", spy)
-        kp, vp = eng._restore(prompt, n, eng._digests(prompt, n))
+        kp, vp = decoder.restored_to_pages(
+            cfg, eng._restore(n, eng._digests(prompt, n)))
         (blocks,) = seen
         assert len(blocks) == 2 * cfg.n_layers * n
         assert (blocks["pool_idx"] == blocks["pool_idx"][0]).all()

@@ -190,3 +190,47 @@ def test_trace_answers_the_span_ring_and_ttft_counts_from_arrival(
     # TTFT holds the wait and the admission it follows.
     assert res["ttft_ms"] * 1e3 >= wait["dur"] + by_name[
         "istpu.sched.admit"]["dur"] - 1e3
+
+
+@pytest.mark.parametrize("fails", [False, True],
+                         ids=["ticks", "a-failing-tick-is-not-fatal"])
+def test_the_loop_ticks_an_idle_engine_and_no_stepping_one(
+        params, cfg, monkeypatch, fails):
+    """The engine loop calls `engine.idle()` on the passes that find
+    nothing to step and on no other, and an `idle()` that raises leaves
+    the server serving (a broken device is the next step's to report)."""
+    import time
+
+    eng = ServingEngine(params, cfg,
+                        ServingConfig(max_slots=4, total_pages=64))
+    calls = []
+    real_idle, real_step = eng.idle, eng.step
+
+    def idle():
+        calls.append("idle")
+        if fails:
+            raise RuntimeError("tick failed")
+        real_idle()
+
+    def step():
+        calls.append("step")
+        return real_step()
+
+    monkeypatch.setattr(eng, "idle", idle)
+    monkeypatch.setattr(eng, "step", step)
+    srv = ServingHTTPServer(eng, port=0)
+    base = f"http://127.0.0.1:{srv.start()}"
+    try:
+        deadline = time.time() + 30
+        while calls.count("idle") < 3 and time.time() < deadline:
+            time.sleep(0.01)
+        assert calls.count("idle") >= 3 and "step" not in calls
+        prompt = [3, 1, 4, 1, 5, 9, 2, 6]
+        res = _post(base, {"prompt": prompt, "max_new_tokens": 5,
+                           "stream": False}, stream=False)
+        assert res["tokens"] == _ref(params, cfg, prompt, 5)
+        first, last = calls.index("step"), \
+            len(calls) - 1 - calls[::-1].index("step")
+        assert "idle" not in calls[first:last]
+    finally:
+        srv.shutdown()
