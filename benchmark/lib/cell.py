@@ -15,7 +15,7 @@ import tempfile
 import threading
 import time
 
-from . import correct, costs, loadgen, serve, traffic
+from . import correct, loadgen, serve, traffic
 from .store import BenchFailure, StoreChild, build_native
 
 ROOT = serve.ROOT
@@ -118,6 +118,19 @@ def _hist_delta(after, before):
     return out
 
 
+def store_sizes(conf, cfg, spec, rehearsal=False):
+    """(pool GB, block KB) of the store child, from the configuration's
+    costs module: the pool holds `store_pool_seconds` of the mix's
+    writes, the block is the smallest object an offload writes."""
+    costs = serve.costs_module(conf)
+    page, itemsize = cfg.page_size, cfg.jdtype.itemsize
+    pool_gb = 0.125 if rehearsal else traffic.store_pool_gb(
+        spec, costs.page_bytes_all_layers(conf, page, itemsize), page,
+        costs.snapshot_bytes(conf, itemsize))
+    block = costs.store_block_bytes(conf, page, itemsize)
+    return pool_gb, max(1, block >> 10)
+
+
 class Cell:
     def __init__(self, cell, config_entry, seed, rehearsal=False,
                  log=print):
@@ -150,12 +163,9 @@ class Cell:
                                f"{len(jax.devices())} devices")
         self.devices = devices
         self.run_dir = tempfile.mkdtemp(prefix="bench_run_")
-        page_all = costs.page_bytes_all_layers(
-            self.conf, self.cfg.page_size, self.cfg.jdtype.itemsize)
-        pool_gb = 0.125 if self.rehearsal else traffic.store_pool_gb(
-            self.spec, page_all, self.cfg.page_size)
-        block = page_all // (2 * self.cfg.n_layers)  # one K or V page
-        self.store = StoreChild(pool_gb, max(1, block >> 10), self.run_dir)
+        pool_gb, block_kb = store_sizes(self.conf, self.cfg, self.spec,
+                                        self.rehearsal)
+        self.store = StoreChild(pool_gb, block_kb, self.run_dir)
         t1 = time.perf_counter()
         params = serve.init_weights(self.model, self.cfg, self.seed,
                                     devices[0])
@@ -177,7 +187,8 @@ class Cell:
             "store_pool_gb": pool_gb,
             "store_up_s": round(t1 - t0 - built, 1),
             "weights_s": round(t2 - t1, 1),
-            "weights_gb": round(costs.weight_bytes(self.conf) / 1e9, 2),
+            "weights_gb": round(serve.costs_module(self.conf).weight_bytes(
+                self.conf) / 1e9, 2),
             "replicas_s": round(time.perf_counter() - t2, 1),
             "compile_cache_dir": self.cache_dir,
             "host_mem_gb": round(os.sysconf("SC_PAGE_SIZE")
@@ -194,6 +205,11 @@ class Cell:
         player = loadgen.Player(spec, self.seed, 0, time.time(), self.urls,
                                 self.cfg.vocab_size, self.cfg.page_size)
         by_session = {}
+        # The cold rows first: the sample's own first prompts, while
+        # the store holds nothing of them (correct.py, point 2).
+        cold_rows = correct.cold_first_logits(
+            spec, samples, self.replicas, self.model, self.cfg,
+            self.cfg.vocab_size) if direct else None
         for r in self.replicas:
             r.store.arm_tap()
         # Every shape is per sequence (the decode program is fixed), so
@@ -213,12 +229,11 @@ class Cell:
                 "compilations": self.meter.n,
                 "compile_s": round(self.meter.secs, 1),
                 "persistent_cache_hits": self.meter.cache_hits}
-        family = "moe" if costs.n_experts(self.conf) > 1 else "dense"
-        tol = correct.tolerances(family)
+        tol = correct.tolerances_for(self.conf)
         ok, details = correct.check(
             self.conf, spec, self.model, self.cfg, self.params,
             serve.reference_module(self.conf), self.replicas, samples,
-            by_session, self.cfg.vocab_size, tol, direct=direct,
+            by_session, self.cfg.vocab_size, tol, cold_rows=cold_rows,
             log=self.log)
         warm["check_s"] = round(time.perf_counter() - t1, 1)
         self.log("warm-up: " + json.dumps(warm))

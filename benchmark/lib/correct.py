@@ -13,8 +13,12 @@ the same whatever the load did:
    that position: an argmax that flips on rounding passes; a wrong
    page, a wrong position, a dropped token or a skipped layer does not.
    First-token logits of the cold and the hit program are also held to
-   the reference directly (`logit_tol`), through the programs the
-   engine dispatches, where the program still offers them.
+   the reference directly (`logit_tol`), through the engine's public
+   `first_token_logits`, which dispatches what an admission does: the
+   cold rows of the sample's first prompts BEFORE the sample is
+   played (the store is empty, so the cold program runs), the hit
+   rows after it. A row is labelled by the path that ran; a path the
+   traffic has and the sample never ran fails the run.
 3. Near-ties are not evidence: for a sparse-expert model a checked
    position whose router margin in the reference is under
    `router_margin` in any layer is set aside and counted.
@@ -24,8 +28,9 @@ the same whatever the load did:
    the end of the run (run.py adds those two at the end).
 5. Nothing here reads a counter the load can move.
 
-Tolerances live in benchmark/reference/tolerances.json with the
-measurements they were set from.
+Tolerances live in the file the configuration names (default:
+benchmark/reference/tolerances.json) with the measurements they were
+set from.
 """
 
 import json
@@ -39,10 +44,22 @@ CHECK_TOKENS = 8
 SAMPLE_BASE = 1_000_000  # sample sessions' indices, clear of the plan's
 
 
-def tolerances(family):
-    with open(os.path.join(ROOT, "benchmark", "reference",
-                           "tolerances.json")) as f:
+TOLERANCES = "benchmark/reference/tolerances.json"
+
+
+def tolerances(family, path=TOLERANCES):
+    with open(os.path.join(ROOT, path)) as f:
         return json.load(f)[family]
+
+
+def tolerances_for(conf):
+    """The configuration's own: "program": {"tolerances": {"file",
+    "family"}}; without it the two families of TOLERANCES, as the
+    accepted configurations are told apart."""
+    named = conf["program"].get("tolerances", {})
+    family = named.get("family") or (
+        "moe" if conf.get("num_local_experts", 1) > 1 else "dense")
+    return tolerances(family, named.get("file", TOLERANCES))
 
 
 def sample_sessions(spec, seed, copies=1):
@@ -104,78 +121,57 @@ def judge_tokens(deficits, aside, token_eps):
     return checked, failed, skipped, worst
 
 
-def _padded_tokens(tokens, page):
-    out = np.zeros((1, -(-len(tokens) // page) * page), np.int32)
-    out[0, :len(tokens)] = tokens
+def program_first_logits(replica, model, cfg, prompt, hit_expected):
+    """(float32 row [vocab], hit pages) of `prompt` through the
+    programs an admission dispatches: the engine's own
+    first_token_logits, which probes the store and runs the cold
+    program on a miss, restore + the prefix program on a hit, with the
+    engine idle and nothing admitted. The caller labels the row by the
+    hit that came back, not by `hit_expected`; a hit expected that ran
+    cold is said here. (`model` and `cfg` are the engine's own and go
+    unused: tests/test_engine_spans.py holds this signature.)"""
+    row, hit = replica.engine.first_token_logits(prompt)
+    if hit_expected and not hit > 0:
+        print(f"correct: a prompt of {len(prompt)} tokens expected as a "
+              f"hit ran the cold program (the store held none of its "
+              f"pages)", flush=True)
+    return np.asarray(row, np.float32), int(hit)
+
+
+def checked_copies(spec, samples):
+    """The sample sessions the reference runs: one copy per class."""
+    copies = max(1, len(samples) // len(spec["classes"]))
+    return [s for s in samples if not (s.index - SAMPLE_BASE) % copies]
+
+
+def cold_first_logits(spec, samples, replicas, model, cfg, vocab):
+    """{session index: (row, hit pages)} of every checked sample
+    session's FIRST prompt, on the replica that will serve it. Taken
+    before the sample is played: the store holds nothing of it yet, so
+    the cold program runs, as it will for the session's first turn."""
+    out = {}
+    for sess in checked_copies(spec, samples):
+        ctx, msgs = traffic.session_tokens(spec, sess, vocab)
+        replica = replicas[traffic.replica_of(spec, sess.index, 1)
+                           % len(replicas)]
+        out[sess.index] = program_first_logits(
+            replica, model, cfg, list(ctx) + msgs[0], False)
     return out
 
 
-def program_first_logits(replica, model, cfg, prompt, hit_expected):
-    """First-token logits of `prompt` through the programs the engine
-    dispatches, the engine idle: the cold-admission program with every
-    page id at the drop sentinel, or (hit_expected) probe + restore +
-    the prefix-prefill program. Returns None where the program no
-    longer offers these entry points (a later refactor): the token
-    check then stands alone, and the caller says so."""
-    import jax.numpy as jnp
-
-    from infinistore_tpu import serving
-    from infinistore_tpu.models import llama
-
-    eng = replica.engine
-    page = cfg.page_size
-    try:
-        if not hit_expected:
-            drop = jnp.full(eng.sc.max_pages_per_seq, eng.sc.total_pages,
-                            jnp.int32)
-            toks = eng._to_device(_padded_tokens(prompt, page))
-            row, eng.k_pages, eng.v_pages = serving._admit_fused(
-                eng.params, cfg, toks, eng.k_pages, eng.v_pages,
-                eng._to_device(np.asarray(drop)),
-                eng._to_device(np.int32(len(prompt))), model=model,
-            )
-            return np.asarray(row, np.float32), 0
-        work = serving._Work(req=serving.Request("bench-logit-check",
-                                                 list(prompt)),
-                             prompt=list(prompt))
-        hit, digests = eng._probe_hit(work)
-        if hit <= 0:
-            return None, 0
-        kp, vp = llama.restore_prefix_pages(
-            replica.inner_store, cfg,
-            lambda li, kind: serving.content_page_keys(
-                prompt, page, hit, li, kind, digests=digests),
-            hit, getter=lambda *a, **k: replica.inner_store.get_kv_pages(
-                *a, device=eng.device, **k),
-        )
-        prefix_kvs = [
-            llama.pages_to_kv(cfg, kp[li][None], vp[li][None], hit * page)
-            for li in range(cfg.n_layers)
-        ]
-        suffix = prompt[hit * page:]
-        logits, _ = serving._prefill_px_jit(
-            eng.params, cfg, eng._to_device(_padded_tokens(suffix, page)),
-            prefix_kvs, eng._to_device(np.int32(0)), model=model,
-        )
-        return np.asarray(logits[0, len(suffix) - 1], np.float32), hit
-    except (AttributeError, TypeError, ImportError) as e:
-        print(f"correct: the program's first-token entry points are gone "
-              f"({type(e).__name__}: {e}); logits not compared",
-              flush=True)
-        return None, 0
-
-
-def read_back(replica, cfg):
+def read_back(replica, cfg=None):
     """The tapped put batch, read back from the store and compared bit
-    for bit with the device array the engine gathered from its pool.
-    Returns (n pages, equal) or (0, None) if nothing was tapped."""
+    for bit with the array the engine handed to the store (shape and
+    dtype of a page are that array's; `cfg` goes unused:
+    tests/test_serving_replicas.py passes it). Returns (n pages, equal)
+    or (0, None) if nothing was tapped."""
     tapped = replica.store.tapped
     if tapped is None:
         return 0, None
-    keys, dev_pages = tapped
+    keys, handed = tapped
+    want = np.asarray(handed)
     back = replica.inner_store.get_kv_pages_host(
-        keys, cfg.kv_page_shape(), cfg.jdtype)
-    want = np.asarray(dev_pages)
+        keys, want.shape[1:], want.dtype)
     same = np.array_equal(
         np.ascontiguousarray(back).view(np.uint8),
         np.ascontiguousarray(want).view(np.uint8))
@@ -183,15 +179,22 @@ def read_back(replica, cfg):
 
 
 def check(conf, spec, model, cfg, params, reference, replicas, samples,
-          records_by_session, vocab, tol, direct=True, log=print):
-    """Runs points 1-4 on the sample; returns (ok, details)."""
+          records_by_session, vocab, tol, cold_rows=None, log=print):
+    """Runs points 1-4 on the sample; returns (ok, details).
+    `cold_rows` is what cold_first_logits took before the sample was
+    played; None (a sweep) leaves the first-token logits out."""
     page = cfg.page_size
     per_turn = []
     ok = True
     worst_token = 0.0
     worst_logit = {"cold": 0.0, "hit": 0.0}
+    # Rows of first-token logits by the path that ran: taken, and of
+    # those compared (a near-tie of the router is taken, not compared).
+    rows = {"cold": [0, 0], "hit": [0, 0]}
+    paths_due = set()
     counts = {"checked": 0, "failed": 0, "set_aside": 0,
-              "logit_checked": 0, "logit_set_aside": 0}
+              "logit_checked": 0, "logit_set_aside": 0,
+              "hit_expected_ran_cold": 0}
     seqs = {}
     for sess in samples:
         recs = records_by_session.get(sess.index, [])
@@ -206,12 +209,9 @@ def check(conf, spec, model, cfg, params, reference, replicas, samples,
     pad_to = max((len(t[-1][0]) + CHECK_TOKENS for t in seqs.values()),
                  default=0)
     pad_to = -(-pad_to // 128) * 128
-    copies = max(1, len(samples) // len(spec["classes"]))
-    for sess in samples:
+    for sess in checked_copies(spec, samples):
         if sess.index not in seqs:
             continue
-        if (sess.index - SAMPLE_BASE) % copies:
-            continue  # one copy per class is enough for the reference
         turns = seqs[sess.index]
         last_prompt, last_gen = turns[-1]
         seq = list(last_prompt) + list(last_gen[:CHECK_TOKENS])
@@ -242,30 +242,44 @@ def check(conf, spec, model, cfg, params, reference, replicas, samples,
             if margins is not None:
                 entry["margins"] = [round(float(m), 4) for m in
                                     np.min(np.asarray(margins)[sl], axis=1)]
-            path = "hit" if expected[ti]["hit"] else "cold"
             # The cold program and the first hit: later turns run the
-            # same prefix-prefill program at another shape.
-            if direct and ti < 2:
-                replica = replicas[traffic.replica_of(
-                    spec, sess.index, ti + 1) % len(replicas)]
-                row, hit = program_first_logits(
-                    replica, model, cfg, prompt, path == "hit")
-                if row is not None:
-                    diff = float(np.max(np.abs(row - ref_logits[sl][0])))
-                    entry["first_logit_diff"] = round(diff, 4)
-                    entry["hit_pages"] = hit
-                    if aside is not None and aside[0]:
-                        counts["logit_set_aside"] += 1
-                    else:
-                        counts["logit_checked"] += 1
-                        worst_logit[path] = max(worst_logit[path], diff)
-                        if not (np.isfinite(row).all()
-                                and diff <= tol["logit_tol"]):
-                            ok = False
-                            entry["first_logit_failed"] = True
+            # same prefix program at another shape.
+            if cold_rows is not None and ti < 2:
+                due = "hit" if expected[ti]["hit"] else "cold"
+                paths_due.add(due)
+                if ti == 0:
+                    row, hit = cold_rows[sess.index]
+                else:
+                    replica = replicas[traffic.replica_of(
+                        spec, sess.index, ti + 1) % len(replicas)]
+                    row, hit = program_first_logits(
+                        replica, model, cfg, prompt, due == "hit")
+                path = "hit" if hit > 0 else "cold"
+                if due == "hit" and path == "cold":
+                    counts["hit_expected_ran_cold"] += 1
+                diff = float(np.max(np.abs(row - ref_logits[sl][0])))
+                entry["first_logit_diff"] = round(diff, 4)
+                entry["hit_pages"] = hit
+                entry["path"] = path
+                rows[path][0] += 1
+                if aside is not None and aside[0]:
+                    counts["logit_set_aside"] += 1
+                else:
+                    counts["logit_checked"] += 1
+                    rows[path][1] += 1
+                    worst_logit[path] = max(worst_logit[path], diff)
+                    if not (np.isfinite(row).all()
+                            and diff <= tol["logit_tol"]):
+                        ok = False
+                        entry["first_logit_failed"] = True
             per_turn.append(entry)
     if counts["failed"]:
         ok = False
+    for path in sorted(paths_due):
+        if not rows[path][0]:
+            log(f"correct: the traffic has {path} admissions and no "
+                f"first-token row of the sample ran the {path} program")
+            ok = False
     total = counts["checked"] + counts["set_aside"]
     if total == 0 or counts["checked"] < total * tol.get(
             "min_checked_share", 0.25):
@@ -274,7 +288,7 @@ def check(conf, spec, model, cfg, params, reference, replicas, samples,
         ok = False
     n_back = 0
     for r in replicas:
-        n, same = read_back(r, cfg)
+        n, same = read_back(r)
         n_back += n
         if same is False:
             log(f"correct: replica {r.index}: {n} acknowledged pages "
@@ -284,7 +298,10 @@ def check(conf, spec, model, cfg, params, reference, replicas, samples,
         log("correct: no acknowledged page was read back")
         ok = False
     details = {
-        "ok": ok, **counts, "pages_read_back": n_back,
+        "ok": ok, **counts,
+        "logit_rows": {k: {"taken": t, "compared": c}
+                       for k, (t, c) in rows.items()},
+        "pages_read_back": n_back,
         "worst_token_deficit": round(worst_token, 4),
         "worst_first_logit_diff": {k: round(v, 4)
                                    for k, v in worst_logit.items()},

@@ -2,6 +2,15 @@
 with the benchmark so that no PR that claims a gain can change them.
 All functions take the configuration FILE's published keys.
 
+This module knows one layer shape (GQA attention + SwiGLU x experts, a
+K and a V page per layer). A configuration of another shape names a
+module of its own under "program": {"costs": ...} (lib/serve.py:
+costs_module) that answers the same questions for its layers and its
+cache: weight_bytes, decode_bytes, decode_flops, prefill_flops,
+page_bytes_all_layers, store_block_bytes and snapshot_bytes, each with
+the signature it has here. No other file of the harness reads a layer
+count or a KV geometry.
+
 Conventions: a multiply-add is 2 FLOPs. Prefill needs the matmuls of
 every prompt token it computes (suffix tokens), causal attention over
 prefix + suffix, and the head for ONE position (the engine needs only
@@ -67,7 +76,21 @@ def kv_bytes_per_token(conf, itemsize=2):
 
 
 def page_bytes_all_layers(conf, page=16, itemsize=2):
+    """Cache bytes one full page of tokens adds to the store."""
     return kv_bytes_per_token(conf, itemsize) * page
+
+
+def store_block_bytes(conf, page=16, itemsize=2):
+    """The store's allocation unit: the smallest object an offload
+    writes, here one K or one V page of one layer."""
+    return page_bytes_all_layers(conf, page, itemsize) \
+        // (2 * conf["num_hidden_layers"])
+
+
+def snapshot_bytes(conf, itemsize=2):
+    """Bytes an offload writes that do not grow with its pages (a
+    recurrent state's snapshot): none where all the cache is K and V."""
+    return 0
 
 
 def prefill_flops(conf, suffix, prefix=0):

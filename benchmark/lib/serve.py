@@ -4,8 +4,13 @@ one jitted call from the seed, one engine + HTTP server per replica, and
 the benchmark's own spans around the store and the engine step.
 
 Everything program-specific the benchmark needs is named in the
-configuration file's "program" group (model module, bridge function,
-reference module), so a later configuration is a new file, not an edit.
+configuration file's "program" group, so a later configuration is new
+files, not an edit: `model`, `bridge` and `reference` (required), and
+optionally `costs` (a module with lib/costs.py's functions for this
+family's layers and cache), `tolerances` ({"file", "family"}) and
+`programs` ({"decode": [...], "prefill": [...]}: substrings of the XLA
+module names the trace readers look for). PERF.md, section 4, lists
+what each must offer.
 """
 
 import collections
@@ -37,13 +42,20 @@ def _resolve(dotted):
     return getattr(m, attr) if attr else m
 
 
+# The file's groups that are the harness's own; every other key is
+# the published configuration's and reaches the bridge.
+HARNESS_GROUPS = ("source", "reduced", "assumed", "deployment",
+                  "guarantees", "program", "serving", "rehearsal")
+PROGRAMS = {"decode": ["decode_fused"],
+            "prefill": ["admit_fused", "prefill_px"]}
+
+
 def model_config(conf):
     """(model module, its config object) through the bridge the file
-    names. The bridge reads attributes, so the published keys are
-    handed over as a namespace, untouched."""
+    names. The bridge reads attributes, so every published key, lists
+    and groups included, is handed over as a namespace, untouched."""
     hf = types.SimpleNamespace(**{
-        k: v for k, v in conf.items()
-        if not isinstance(v, (dict, list)) or k == "rope_scaling"
+        k: v for k, v in conf.items() if k not in HARNESS_GROUPS
     })
     bridge = _resolve(conf["program"]["bridge"])
     cfg = bridge(hf, page_size=conf["serving"]["page_size"],
@@ -55,14 +67,25 @@ def reference_module(conf):
     return _resolve(conf["program"]["reference"])
 
 
+def costs_module(conf):
+    """The module that counts this configuration's bytes and FLOPs
+    (lib/costs.py's functions, from the file's published keys)."""
+    return _resolve(conf["program"].get("costs", "benchmark.lib.costs"))
+
+
+def program_names(conf, kind):
+    """Substrings of the XLA module names of the configuration's
+    "decode" or "prefill" programs, for trace.program_times."""
+    return conf["program"].get("programs", {}).get(kind, PROGRAMS[kind])
+
+
 def serving_config(conf, model_id):
+    """Every key of the file's "serving" group is a ServingConfig
+    field, but page_size, which the bridge takes."""
     from infinistore_tpu.serving import ServingConfig
 
-    s = conf["serving"]
-    return ServingConfig(
-        max_slots=s["max_slots"], total_pages=s["total_pages"],
-        max_pages_per_seq=s["max_pages_per_seq"], model_id=model_id,
-    )
+    s = {k: v for k, v in conf["serving"].items() if k != "page_size"}
+    return ServingConfig(model_id=model_id, **s)
 
 
 def init_weights(model, cfg, seed, device=None):

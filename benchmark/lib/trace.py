@@ -21,6 +21,8 @@ import glob
 import json
 import os
 
+from . import serve
+
 WINDOW_SPAN = "bench.trace_window"
 HOST_PREFIX = "bench."
 OPS_LINE = "XLA Ops"
@@ -174,3 +176,9 @@ def program_times(reduced, *needles):
         if any(n in name for n in needles):
             out += durs
     return out
+
+
+def times_of(obs, kind):
+    """program_times of the configuration's "decode" or "prefill"
+    programs, by the names its file gives (lib/serve.program_names)."""
+    return program_times(obs.trace, *serve.program_names(obs.conf, kind))

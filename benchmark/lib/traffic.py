@@ -145,11 +145,24 @@ def pages_written_per_session(spec, page=PAGE):
     )
 
 
-def store_pool_gb(spec, page_bytes_all_layers, page=PAGE):
+def offloads_per_session(spec, page=PAGE):
+    """Mean number of offloads a session makes (one a finished turn
+    that has full pages the store lacks), over the class weights."""
+    return sum(
+        c["weight"] * sum(1 for t in turn_lengths(c, spec["turns"], page)
+                          if t["offload_pages"])
+        for c in spec["classes"]
+    )
+
+
+def store_pool_gb(spec, page_bytes_all_layers, page=PAGE, snapshot_bytes=0):
     """Pool size in GB: `store_pool_seconds` of the mix's writes at the
-    fixed rate, rounded up to a quarter GB, at least half a GB."""
-    per_s = (spec["session_rate_per_s"]
-             * pages_written_per_session(spec, page) * page_bytes_all_layers)
+    fixed rate (pages, and `snapshot_bytes` an offload where the
+    configuration's cache has a part that does not grow with pages),
+    rounded up to a quarter GB, at least half a GB."""
+    per_s = spec["session_rate_per_s"] * (
+        pages_written_per_session(spec, page) * page_bytes_all_layers
+        + offloads_per_session(spec, page) * snapshot_bytes)
     gb = per_s * spec["store_pool_seconds"] / 2 ** 30
     return max(0.5, math.ceil(gb * 4) / 4)
 

@@ -2,7 +2,7 @@
 window, were admitted and had hit pages: probe, restore, pages_to_kv,
 pool write and the prefix prefill of one prefix hit.
 
-Moves itl_p95_ms: an admission runs on the one engine thread, so every
+Moves itl_mean_ms: an admission runs on the one engine thread, so every
 decoding slot sees it as a gap between two tokens.
 """
 
@@ -13,7 +13,7 @@ LAYER = "Scheduler and cache manager"
 UNIT = "ms"
 BETTER = "lower"
 SOURCE = "program_span"
-MOVES = "itl_p95_ms"
+MOVES = "itl_mean_ms"
 
 
 def value(obs, spans):

@@ -5,7 +5,7 @@ istpu.model.decode child (dispatch of the decode program to the token
 array on the host). What is left is the scheduler's own work: building
 and uploading inputs, page bookkeeping, emitting tokens.
 
-Moves itl_p95_ms: it is paid between every two tokens.
+Moves itl_mean_ms: it is paid between every two tokens.
 """
 
 from benchmark.lib import program_spans
@@ -15,7 +15,7 @@ LAYER = "Scheduler and cache manager"
 UNIT = "ms"
 BETTER = "lower"
 SOURCE = "program_span"
-MOVES = "itl_p95_ms"
+MOVES = "itl_mean_ms"
 
 OTHER_WORK = ("istpu.sched.admit", "istpu.cache.offload")
 

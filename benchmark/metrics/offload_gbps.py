@@ -8,7 +8,7 @@ LAYER = "Device and host transfer"
 UNIT = "GB/s"
 BETTER = "higher"
 SOURCE = "program_span"
-MOVES = "itl_p95_ms"
+MOVES = "itl_mean_ms"
 
 
 def read(obs):

@@ -4,7 +4,7 @@ offload holds the engine thread for (digests, keys, page gathers,
 device to host, copies into the pool, sync), which offload_gbps' spans
 around put_kv_pages see only in part.
 
-Moves itl_p95_ms: every decoding slot waits it out.
+Moves itl_mean_ms: every decoding slot waits it out.
 """
 
 from benchmark.lib import program_spans
@@ -14,7 +14,7 @@ LAYER = "Device and host transfer"
 UNIT = "ms"
 BETTER = "lower"
 SOURCE = "program_span"
-MOVES = "itl_p95_ms"
+MOVES = "itl_mean_ms"
 
 
 def value(obs, spans):

@@ -2,7 +2,7 @@
 prefix_hit_pages x page over that plus prefill_tokens, window delta of
 ServingEngine.stats.
 
-Moves itl_p95_ms: every admission (probe, restore, prefill) runs on the
+Moves itl_mean_ms: every admission (probe, restore, prefill) runs on the
 one engine thread and stalls all decoding slots. Where ttft_p50_ms is an
 end-to-end metric of the cell, it moves that too.
 """
@@ -12,7 +12,7 @@ LAYER = "Scheduler and cache manager"
 UNIT = "%"
 BETTER = "higher"
 SOURCE = "program_counter"
-MOVES = "itl_p95_ms"
+MOVES = "itl_mean_ms"
 
 
 def read(obs):

@@ -1,7 +1,7 @@
 """Bytes over seconds inside the benchmark's spans around get_kv_pages
 (store pool to HBM), over the window.
 
-Moves itl_p95_ms: every admission (probe, restore, prefill) runs on the
+Moves itl_mean_ms: every admission (probe, restore, prefill) runs on the
 one engine thread and stalls all decoding slots. Where ttft_p50_ms is an
 end-to-end metric of the cell, it moves that too.
 """
@@ -11,7 +11,7 @@ LAYER = "Device and host transfer"
 UNIT = "GB/s"
 BETTER = "higher"
 SOURCE = "program_span"
-MOVES = "itl_p95_ms"
+MOVES = "itl_mean_ms"
 
 
 def read(obs):

@@ -2,7 +2,7 @@
 the read op of the engine's shared-memory restores: window delta of
 /stats (log2 buckets, bucket midpoint).
 
-Moves itl_p95_ms: every admission (probe, restore, prefill) runs on the
+Moves itl_mean_ms: every admission (probe, restore, prefill) runs on the
 one engine thread and stalls all decoding slots. Where ttft_p50_ms is an
 end-to-end metric of the cell, it moves that too.
 """
@@ -14,7 +14,7 @@ LAYER = "Store client and server"
 UNIT = "us"
 BETTER = "lower"
 SOURCE = "program_counter"
-MOVES = "itl_p95_ms"
+MOVES = "itl_mean_ms"
 
 
 def read(obs):

@@ -9,7 +9,7 @@ LAYER = "Store client and server"
 UNIT = "us"
 BETTER = "lower"
 SOURCE = "program_counter"
-MOVES = "itl_p95_ms"
+MOVES = "itl_mean_ms"
 
 
 def read(obs):

@@ -8,7 +8,7 @@ four engine threads in one process and four clients of one store cost.
 
 A program whose spans carry no `foreign_pages` gives nothing.
 
-Moves itl_p95_ms: an admission runs on its replica's one engine thread,
+Moves itl_mean_ms: an admission runs on its replica's one engine thread,
 so every decoding slot of that replica sees it as a gap.
 """
 
@@ -19,7 +19,7 @@ LAYER = "Scheduler and cache manager"
 UNIT = "ms"
 BETTER = "lower"
 SOURCE = "program_span"
-MOVES = "itl_p95_ms"
+MOVES = "itl_mean_ms"
 
 
 def foreign_admissions(obs, spans):

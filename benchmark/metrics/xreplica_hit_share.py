@@ -8,7 +8,7 @@ pool evicted a live session.
 
 A program without the counter (a parent commit) gives nothing.
 
-Moves itl_p95_ms: every admission (probe, restore, prefill) runs on its
+Moves itl_mean_ms: every admission (probe, restore, prefill) runs on its
 replica's one engine thread and stalls that replica's decoding slots.
 """
 
@@ -17,7 +17,7 @@ LAYER = "Scheduler and cache manager"
 UNIT = "%"
 BETTER = "higher"
 SOURCE = "program_counter"
-MOVES = "itl_p95_ms"
+MOVES = "itl_mean_ms"
 
 
 def read(obs):

@@ -5,7 +5,7 @@ through the four-client store costs its engine thread.
 
 A program whose spans carry no `foreign_pages` gives nothing.
 
-Moves itl_p95_ms: it is part of the admission every decoding slot of
+Moves itl_mean_ms: it is part of the admission every decoding slot of
 the replica waits out.
 """
 
@@ -17,7 +17,7 @@ LAYER = "Store client and server"
 UNIT = "ms"
 BETTER = "lower"
 SOURCE = "program_span"
-MOVES = "itl_p95_ms"
+MOVES = "itl_mean_ms"
 
 
 def value(obs, spans):

@@ -29,8 +29,13 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, ROOT)
 
 
+COMPARED = []  # the "correct:" lines, said again on stderr at the end
+
+
 def log(msg):
     print(msg, flush=True)
+    if msg.startswith("correct: "):
+        COMPARED.append(msg)
 
 
 def main(argv=None):
@@ -183,6 +188,11 @@ def main(argv=None):
         rc, leaked = c.close()
         if leaked:
             log(f"benchmark: /dev/shm leftovers removed: {leaked}")
+    # Each number compared beside its limit, as the last lines of stderr
+    # too: where a run is not correct the driver keeps the end of that.
+    for msg in COMPARED:
+        print(msg, file=sys.stderr)
+    sys.stderr.flush()
     print(json.dumps(result), flush=True)
     return 0
 

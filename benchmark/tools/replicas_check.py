@@ -39,7 +39,7 @@ def log(msg):
     print(msg, flush=True)
 
 
-def cross_read_back(replicas, cfg):
+def cross_read_back(replicas):
     """[(writer, reader, n pages, equal)] for every tapped put batch
     through every other replica's connection."""
     import numpy as np
@@ -48,13 +48,14 @@ def cross_read_back(replicas, cfg):
     for w in replicas:
         if w.store.tapped is None:
             continue
-        keys, dev_pages = w.store.tapped
-        want = np.ascontiguousarray(np.asarray(dev_pages)).view(np.uint8)
+        keys, handed = w.store.tapped
+        pages = np.asarray(handed)
+        want = np.ascontiguousarray(pages).view(np.uint8)
         for r in replicas:
             if r is w:
                 continue
             back = r.inner_store.get_kv_pages_host(
-                keys, cfg.kv_page_shape(), cfg.jdtype)
+                keys, pages.shape[1:], pages.dtype)
             out.append((w.index, r.index, len(keys), bool(np.array_equal(
                 np.ascontiguousarray(back).view(np.uint8), want))))
     return out
@@ -110,7 +111,7 @@ def main(argv=None):
     try:
         c.setup()
         sample_ok = c.warm_and_check()
-        back = cross_read_back(c.replicas, c.cfg)
+        back = cross_read_back(c.replicas)
         log("cross read-back: " + json.dumps(
             [{"writer": w, "reader": r, "pages": n, "equal": same}
              for w, r, n, same in back]))

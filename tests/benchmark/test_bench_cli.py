@@ -68,7 +68,9 @@ def test_rehearsal_end_to_end(workload, trace):
                     "ttft_hit_p50_ms", "ttft_miss_p50_ms"} <= names
             assert res["metrics"]["prefix_hit_share"]["value"] > 30
     else:
-        assert "setup_s" in names and "itl_p95_ms" in names
+        assert {"setup_s", "itl_mean_ms"} <= names
+        # the p95 gap is end to end only where its runs are steady
+        assert ("itl_p95_ms" in names) == (workload != "mistral7b-sessions")
         assert ("tokens_per_s" in names) == ("unshared" in workload)
         # TTFT is an end-to-end metric only where its runs are steady
         assert ("ttft_p50_ms" in names) == workload.startswith("mixtral")
@@ -137,7 +139,7 @@ def later_pr_tree(tmp_path_factory):
     (tmp_path / "benchmark/metrics/dummy_steps.py").write_text(
         'KIND = "per_layer"\nLAYER = "Model step"\nUNIT = "1"\n'
         'BETTER = "higher"\nSOURCE = "program_counter"\n'
-        'MOVES = "itl_p95_ms"\n\n\ndef read(obs):\n'
+        'MOVES = "itl_mean_ms"\n\n\ndef read(obs):\n'
         '    return obs.counters.get("decode_steps") or None\n')
     bench["configs"].append({
         "name": "dummy", "source": conf["source"],
@@ -149,7 +151,7 @@ def later_pr_tree(tmp_path_factory):
     bench["per_layer"].append({
         "name": "dummy_steps", "unit": "1", "better": "higher",
         "source": "program_counter", "layer": "Model step",
-        "moves": "itl_p95_ms", "workloads": ["dummy-cell"]})
+        "moves": "itl_mean_ms", "workloads": ["dummy-cell"]})
     (tmp_path / "BENCHMARK.json").write_text(json.dumps(bench))
     return str(tmp_path)
 

@@ -199,7 +199,7 @@ def test_a_later_pr_adds_files_and_entries_and_edits_none(tmp_path,
               "w") as f:
         f.write('KIND = "per_layer"\nLAYER = "Model step"\nUNIT = "1"\n'
                 'BETTER = "higher"\nSOURCE = "program_counter"\n'
-                'MOVES = "itl_p95_ms"\n\n\ndef read(obs):\n'
+                'MOVES = "itl_mean_ms"\n\n\ndef read(obs):\n'
                 '    return obs.counters.get("decode_steps") or None\n')
     bench = copy.deepcopy(BENCH)
     bench["configs"].append({
@@ -212,7 +212,7 @@ def test_a_later_pr_adds_files_and_entries_and_edits_none(tmp_path,
     bench["per_layer"].append({
         "name": "dummy_steps", "unit": "1", "better": "higher",
         "source": "program_counter", "layer": "Model step",
-        "moves": "itl_p95_ms", "workloads": ["dummy-cell"]})
+        "moves": "itl_mean_ms", "workloads": ["dummy-cell"]})
     import benchmark.metrics
 
     monkeypatch.setattr(benchmark.metrics, "__path__", list(
