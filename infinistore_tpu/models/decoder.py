@@ -3,10 +3,12 @@
 One definition each of the three loops over a model's layers: the
 dense / prefix-cached forward (`forward_stack`), the one-token paged
 step (`decode_step`) and the m-token paged step (`verify_step`). A
-family (models/llama.py, models/moe.py) supplies its config fields, its
-parameter init and its feed-forward `block`, and gets the loops with
-that block bound in from `bind`; nothing here knows which families
-exist.
+family (models/llama.py, models/moe.py, models/hybrid.py) supplies its
+config fields, its parameter init and its feed-forward `block`, and
+gets the loops with that block bound in from `bind`; nothing here knows
+which families exist. What a layer's MIXER is the config says per layer
+(`cfg.layer_kinds`): attention over K and V pages, or the Mamba-2 mixer
+over a recurrent state (ops/ssm.py).
 
 `block(layer, x, cfg, valid)` maps the residual stream [b, s, d] to the
 block's output [b, s, d] and the family's auxiliary loss for that layer
@@ -28,6 +30,7 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
+from ..ops import ssm
 from ..ops.pallas_flash_attention import flash_prefill
 from ..ops.paged_attention import scatter_kv_multi, scatter_kv_to_pages
 from ..ops.pallas_paged_attention import (
@@ -122,10 +125,52 @@ def qkv(layer, x, cfg, positions):
         q = proj(h, layer, "wq", "bq", (b, s, cfg.n_heads, cfg.head_dim))
         k = proj(h, layer, "wk", "bk", (b, s, cfg.n_kv_heads, cfg.head_dim))
         v = proj(h, layer, "wv", "bv", (b, s, cfg.n_kv_heads, cfg.head_dim))
-    with jax.named_scope("attn.rope"):
-        q = rope(q, positions, cfg.rope_theta, cfg.rope_scaling)
-        k = rope(k, positions, cfg.rope_theta, cfg.rope_scaling)
+        if cfg.attn_scale:
+            # The kernels scale scores by head_dim ** -0.5; a family
+            # with a softmax scale of its own folds the ratio into q.
+            q = q * jnp.asarray(cfg.attn_scale * cfg.head_dim ** 0.5,
+                                q.dtype)
+    if cfg.use_rope:
+        with jax.named_scope("attn.rope"):
+            q = rope(q, positions, cfg.rope_theta, cfg.rope_scaling)
+            k = rope(k, positions, cfg.rope_theta, cfg.rope_scaling)
     return q, k, v
+
+
+def pack_heads(cfg, q, k, v):
+    """q, k, v of one token a row, [b, 1, heads, hd], in the form of a
+    cache whose rows hold `cfg.kv_pack` kv heads side by side: k and v
+    reshaped (heads p*j .. p*j + p - 1 become lanes of row j), and
+    each query head widened to a row's lanes, its values in the lanes
+    of its own kv head and zeros in the others, so that its scores
+    against a row are its scores against that head. The attention
+    kernels scale by the row width ** -0.5; sqrt(p) puts head_dim **
+    -0.5 back. `unpack_heads` picks the same lanes of the output."""
+    p = cfg.kv_pack
+    b, s, n_heads, hd = q.shape
+    lanes = _own_lanes(cfg, q.dtype)                       # [heads, p]
+    q = (q[..., None, :] * lanes[:, :, None]).reshape(b, s, n_heads, p * hd)
+    q = q * jnp.asarray(p ** 0.5, q.dtype)
+    packed = (b, s, cfg.n_kv_heads // p, p * hd)
+    return q, k.reshape(packed), v.reshape(packed)
+
+
+def _own_lanes(cfg, dtype):
+    """[n_heads, kv_pack] one-hot: which of a packed row's heads is
+    query head i's own kv head."""
+    group = cfg.n_heads // cfg.n_kv_heads
+    return jax.nn.one_hot(
+        (jnp.arange(cfg.n_heads) // group) % cfg.kv_pack, cfg.kv_pack,
+        dtype=dtype)
+
+
+def unpack_heads(cfg, attn):
+    """[b, n_heads, kv_pack * hd] from attention over packed rows ->
+    [b, n_heads, hd]: each query head's own kv head's lanes."""
+    b, n_heads, width = attn.shape
+    rows = attn.reshape(b, n_heads, cfg.kv_pack, width // cfg.kv_pack)
+    return jnp.sum(rows * _own_lanes(cfg, attn.dtype)[None, :, :, None],
+                   axis=2)
 
 
 def attn_out(layer, attn_flat):
@@ -164,31 +209,159 @@ def embed(params, tokens, cfg=None):
     return out
 
 
-def lm_head(params, x):
-    """Final projection to vocab, fp32 output."""
+def lm_head(params, x, cfg=None):
+    """Final projection to vocab, fp32 output (divided by the
+    family's `logits_div` where it has one)."""
     with jax.named_scope("lm_head"):
-        return matmul(x, params["lm_head"]).astype(jnp.float32)
+        if "lm_head" in params:
+            out = matmul(x, params["lm_head"])
+        else:  # tied: the embedding's rows, contracted where they lie
+            out = jnp.einsum("...d,vd->...v", x, params["embed"])
+        out = out.astype(jnp.float32)
+        if cfg is not None and cfg.logits_div != 1.0:
+            out = out / cfg.logits_div
+        return out
 
 
-def forward_stack(block, params, cfg, tokens, prefix_kvs=None, pos0=0):
+def residual(cfg, x, out):
+    """x + out, the branch scaled by the family's `residual_mult`."""
+    if cfg.residual_mult != 1.0:
+        out = out * jnp.asarray(cfg.residual_mult, out.dtype)
+    return x + out
+
+
+# Per-layer spec: `cfg.layer_kinds` names each layer's mixer, and with
+# it the cache that layer keeps. "attention": GQA over K and V pages,
+# pool layer = the layer's rank among the attention layers (the page
+# pools hold those alone). "mamba": the Mamba-2 mixer below over a
+# recurrent state (`h` [b, H, P, N] float32 and the convolution's tail
+# [b, K-1, C]), state index = its rank among the state layers. Every
+# layer ends in the family's feed-forward `block`.
+
+
+def _ssm_project(layer, x, cfg):
+    """rmsnorm and in_proj of a Mamba-2 mixer: (z, xBC, dt raw)."""
+    di = cfg.ssm_heads * cfg.ssm_head_dim
+    c = di + 2 * cfg.ssm_groups * cfg.ssm_state
+    with jax.named_scope("ssm.in"):
+        u = rms_norm(x, layer["ln1"], cfg.norm_eps, cfg.norm_plus_one)
+        zxbcdt = matmul(u, layer["in_proj"])
+    return zxbcdt[..., :di], zxbcdt[..., di:di + c], zxbcdt[..., di + c:]
+
+
+def _ssm_split(xbc, cfg):
+    """The convolved xBC as (x [..., H, P], B [..., N], C [..., N])."""
+    di = cfg.ssm_heads * cfg.ssm_head_dim
+    n = cfg.ssm_groups * cfg.ssm_state
+    x = xbc[..., :di].reshape(*xbc.shape[:-1], cfg.ssm_heads,
+                              cfg.ssm_head_dim)
+    return x, xbc[..., di:di + n], xbc[..., di + n:]
+
+
+def _ssm_dt(layer, dt):
+    f32 = jnp.float32
+    return (jax.nn.softplus(dt.astype(f32) + layer["dt_bias"].astype(f32)),
+            -jnp.exp(layer["A_log"].astype(f32)))
+
+
+def _ssm_out(layer, y, xs, z, cfg):
+    """y + D x, gated by silu(z), rmsnorm over the whole inner width
+    (one group), out_proj. y float32 [..., H, P]."""
+    f32 = jnp.float32
+    with jax.named_scope("ssm.out"):
+        y = y + layer["D"].astype(f32)[:, None] * xs.astype(f32)
+        y = y.reshape(*z.shape) * jax.nn.silu(z.astype(f32))
+        var = jnp.mean(jnp.square(y), axis=-1, keepdims=True)
+        y = y * jax.lax.rsqrt(var + cfg.norm_eps) \
+            * layer["ssm_norm"].astype(f32)
+        return matmul(y.astype(z.dtype), layer["out_proj"])
+
+
+def ssm_zero_state(cfg, b):
+    """(h, conv tail) of `b` sequences at position 0."""
+    c = (cfg.ssm_heads * cfg.ssm_head_dim
+         + 2 * cfg.ssm_groups * cfg.ssm_state)
+    return (jnp.zeros((b, cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state),
+                      cfg.state_jdtype),
+            jnp.zeros((b, cfg.ssm_conv - 1, c), cfg.state_jdtype))
+
+
+def ssm_mixer_seq(layer, x, cfg, state, s_real):
+    """The Mamba-2 mixer over a sequence [b, s, d], from `state` = (h,
+    conv tail) of what came before. Only the first `s_real` positions
+    (traced scalar) are real: the others get dt = 0 and so leave the
+    state alone, and all of them lie in the last page of the sequence.
+
+    Returns (out [b, s, d], {"h", "conv": the state after s_real
+    tokens; "h_b", "conv_b": the state at the last page edge at or
+    before s_real, which is where stored pages can end}). The scan is
+    cut at the start of the last page so that both exist."""
+    b, s, _ = x.shape
+    h0, conv0 = state
+    z, xbc, dt = _ssm_project(layer, x, cfg)
+    xbc, full = ssm.conv_seq(conv0, xbc, layer["conv_w"], layer["conv_b"])
+    xs, B, C = _ssm_split(xbc, cfg)
+    dt, A = _ssm_dt(layer, dt)
+    dt = jnp.where((jnp.arange(s) < s_real)[None, :, None], dt, 0.0)
+    first = (s - 1) // cfg.page_size * cfg.page_size
+    h1, ys = h0, []
+    if first:
+        y, h1 = ssm.scan(h0, xs[:, :first], dt[:, :first], A, B[:, :first],
+                         C[:, :first], cfg.ssm_chunk)
+        ys.append(y)
+    y, h2 = ssm.scan(h1, xs[:, first:], dt[:, first:], A, B[:, first:],
+                     C[:, first:], cfg.ssm_chunk)
+    y = jnp.concatenate(ys + [y], axis=1)
+    at_edge = s_real == first + cfg.page_size
+    k = cfg.ssm_conv
+    st = {
+        "h": h2, "conv": ssm.conv_tail(full, s_real, k),
+        "h_b": jnp.where(at_edge, h2, h1),
+        "conv_b": ssm.conv_tail(full, jnp.where(at_edge, s_real, first), k),
+    }
+    return _ssm_out(layer, y, xs, z, cfg), st
+
+
+def ssm_mixer_step(layer, x, cfg, state):
+    """The same mixer for one token a row: x [b, 1, d], `state` = (h
+    [b, H, P, N], conv tail [b, K-1, C]). Returns (out [b, 1, d], new
+    (h, conv tail))."""
+    h, conv = state
+    z, xbc, dt = _ssm_project(layer, x[:, 0], cfg)
+    xbc, conv = ssm.conv_step(conv, xbc, layer["conv_w"], layer["conv_b"])
+    xs, B, C = _ssm_split(xbc, cfg)
+    dt, A = _ssm_dt(layer, dt)
+    y, h = ssm.step(h, xs, dt, A, B, C)
+    return _ssm_out(layer, y, xs, z, cfg)[:, None], (h, conv)
+
+
+def forward_stack(block, params, cfg, tokens, prefix_kvs=None, pos0=0,
+                  state=None, s_real=None):
     """The ONE decoder-stack loop shared by dense forward and
     prefix-cached prefill (the cache-hit identity depends on these two
-    paths never diverging). With `prefix_kvs` (per-layer (k, v) of shape
-    [batch, P, n_kv, hd], post-RoPE), positions shift by P and each
-    layer attends over prefix + suffix KV through the rectangular flash
-    kernel; with None this reduces exactly to the dense causal forward.
+    paths never diverging). With `prefix_kvs` (per attention layer (k,
+    v) of shape [batch, P, n_kv, hd], post-RoPE), positions shift by P
+    and each attention layer attends over prefix + suffix KV through
+    the rectangular flash kernel; with None this reduces exactly to the
+    dense causal forward.
 
     tokens: [batch, seq] int32. Returns (logits [batch, seq, vocab]
-    fp32, per-layer (k, v) [batch, seq, n_kv, hd] — the KV to page out
-    to the store — and the per-layer list of what `block` returned as
-    its auxiliary loss).
+    fp32, per attention layer (k, v) [batch, seq, n_kv, hd] — the KV to
+    page out to the store — and the per-layer list of what `block`
+    returned as its auxiliary loss). A family with state layers gets a
+    fourth element: per state layer what `ssm_mixer_seq` returned.
 
     `pos0` shifts every ABSOLUTE rope position (prefix starts at pos0,
     suffix at pos0 + P): a sliding-window engine trims the restored
     prefix to the in-window tail pages, whose KV was roped at absolute
     positions — the band mask itself needs no shift because it depends
     only on RELATIVE (query - key) distance, which local indices
-    preserve."""
+    preserve.
+
+    `state`: per state layer (h, conv tail) of the prefix the suffix
+    continues (None: position 0); `s_real`: how many of the seq
+    positions are real tokens (None: all; the others must lie in the
+    last page and may not advance a recurrence)."""
     b, s = tokens.shape
     prefix_len = 0 if prefix_kvs is None else prefix_kvs[0][0].shape[1]
     x = embed(params, tokens, cfg)
@@ -197,46 +370,62 @@ def forward_stack(block, params, cfg, tokens, prefix_kvs=None, pos0=0):
     )
     kvs = []
     auxes = []
-    for li, layer in enumerate(params["layers"]):
-        q, k, v = qkv(layer, x, cfg, positions)
-        if prefix_kvs is None:
-            k_full, v_full = k, v
+    states = []
+    for layer, kind in zip(params["layers"], cfg.layer_kinds):
+        if kind == "mamba":
+            st = state[len(states)] if state is not None \
+                else ssm_zero_state(cfg, b)
+            out, st = ssm_mixer_seq(layer, x, cfg, st,
+                                    s if s_real is None else s_real)
+            x = residual(cfg, x, out)
+            states.append(st)
         else:
-            pk, pv = prefix_kvs[li]
-            k_full = jnp.concatenate([pk.astype(k.dtype), k], axis=1)
-            v_full = jnp.concatenate([pv.astype(v.dtype), v], axis=1)
-        # Pallas flash kernel on TPU (O(S) memory; speed against the
-        # XLA path not measured), XLA path elsewhere. kv may be
-        # longer than q — the causal diagonal shifts by the prefix.
-        with jax.named_scope("attn.kernel"):
-            attn = flash_prefill(q, k_full, v_full, causal=True,
-                                 window=cfg.window)
-        x = x + attn_out(layer, attn.reshape(b, s, -1))
+            q, k, v = qkv(layer, x, cfg, positions)
+            if prefix_kvs is None:
+                k_full, v_full = k, v
+            else:
+                pk, pv = prefix_kvs[len(kvs)]
+                k_full = jnp.concatenate([pk.astype(k.dtype), k], axis=1)
+                v_full = jnp.concatenate([pv.astype(v.dtype), v], axis=1)
+            # Pallas flash kernel on TPU (O(S) memory; speed against the
+            # XLA path not measured), XLA path elsewhere. kv may be
+            # longer than q — the causal diagonal shifts by the prefix.
+            with jax.named_scope("attn.kernel"):
+                attn = flash_prefill(q, k_full, v_full, causal=True,
+                                     window=cfg.window)
+            x = residual(cfg, x, attn_out(layer, attn.reshape(b, s, -1)))
+            kvs.append((k, v))
         out, aux = block(layer, x, cfg, None)
-        x = x + out
-        kvs.append((k, v))
+        x = residual(cfg, x, out)
         auxes.append(aux)
     x = rms_norm(x, params["final_ln"], cfg.norm_eps, cfg.norm_plus_one)
-    logits = lm_head(params, x)
+    logits = lm_head(params, x, cfg)
+    if states:
+        return logits, kvs, auxes, states
     return logits, kvs, auxes
 
 
 def decode_step(block, params, cfg, token, seq_lens, k_pages, v_pages,
-                page_table):
+                page_table, state=None):
     """One decode step over paged KV.
 
     token:      [batch] int32 — current input token
     seq_lens:   [batch] int32 — tokens already in cache (excl. current)
-    k_pages/v_pages: [n_layers, n_pages, page, n_kv, hd]
+    k_pages/v_pages: [n_kv_layers, n_pages, page, n_kv, hd]
     page_table: [batch, max_pages] int32
+    state:      for a family with state layers, {"h": [...], "conv":
+                [...]}: per state layer the batch's recurrent state
+                and convolution tail, row = slot
 
     Returns (logits [batch, vocab] fp32, k_pages, v_pages): the pools
-    it was given with, per layer, the new token's K and V rows written
-    at (layer, page of position seq_lens, seq_lens % page_size). The
-    pools stay the 5-D arrays they arrive as — every layer's scatter and
-    every layer's attention address `li` inside them, and nothing the
-    size of a layer is sliced out or stacked back — so a caller that
-    donates them (the engine's fused programs) updates them in place.
+    it was given with, per attention layer, the new token's K and V
+    rows written at (layer, page of position seq_lens, seq_lens %
+    page_size). The pools stay the 5-D arrays they arrive as — every
+    layer's scatter and every layer's attention address the layer
+    inside them, and nothing the size of a layer is sliced out or
+    stacked back — so a caller that donates them (the engine's fused
+    programs) updates them in place. With `state`, a fourth element:
+    the state after this token, in the form it came.
     """
     b = token.shape[0]
     x = embed(params, token[:, None], cfg)  # [b, 1, d]
@@ -252,23 +441,41 @@ def decode_step(block, params, cfg, token, seq_lens, k_pages, v_pages,
     # counts as valid).
     valid = (seq_lens > 0)[:, None]  # [b, 1]
 
-    for li, layer in enumerate(params["layers"]):
-        q, k, v = qkv(layer, x, cfg, positions)
-        with jax.named_scope("pool.update"):
-            k_pages = scatter_kv_to_pages(k_pages, k, target_page, slot,
-                                          layer=li)
-            v_pages = scatter_kv_to_pages(v_pages, v, target_page, slot,
-                                          layer=li)
-        with jax.named_scope("attn.kernel"):
-            attn = paged_decode_attention(
-                q[:, 0], k_pages, v_pages, page_table, seq_lens + 1,
-                window=cfg.window, layer=li
-            )
-        x = x + attn_out(layer, attn.reshape(b, 1, -1))
+    li = mi = 0  # rank among the attention / the state layers
+    hs, convs = [], []
+    for layer, kind in zip(params["layers"], cfg.layer_kinds):
+        if kind == "mamba":
+            out, (h, conv) = ssm_mixer_step(
+                layer, x, cfg, (state["h"][mi], state["conv"][mi]))
+            x = residual(cfg, x, out)
+            hs.append(h)
+            convs.append(conv)
+            mi += 1
+        else:
+            q, k, v = qkv(layer, x, cfg, positions)
+            if cfg.kv_pack > 1:
+                q, k, v = pack_heads(cfg, q, k, v)
+            with jax.named_scope("pool.update"):
+                k_pages = scatter_kv_to_pages(k_pages, k, target_page, slot,
+                                              layer=li)
+                v_pages = scatter_kv_to_pages(v_pages, v, target_page, slot,
+                                              layer=li)
+            with jax.named_scope("attn.kernel"):
+                attn = paged_decode_attention(
+                    q[:, 0], k_pages, v_pages, page_table, seq_lens + 1,
+                    window=cfg.window, layer=li
+                )
+                if cfg.kv_pack > 1:
+                    attn = unpack_heads(cfg, attn)
+            x = residual(cfg, x, attn_out(layer, attn.reshape(b, 1, -1)))
+            li += 1
         out, _aux = block(layer, x, cfg, valid)
-        x = x + out
+        x = residual(cfg, x, out)
     x = rms_norm(x, params["final_ln"], cfg.norm_eps, cfg.norm_plus_one)
-    return lm_head(params, x[:, 0]), k_pages, v_pages
+    logits = lm_head(params, x[:, 0], cfg)
+    if state is not None:
+        return logits, k_pages, v_pages, {"h": hs, "conv": convs}
+    return logits, k_pages, v_pages
 
 
 def verify_step(block, params, cfg, tokens, seq_lens, k_pages, v_pages,
@@ -300,8 +507,17 @@ def verify_step(block, params, cfg, tokens, seq_lens, k_pages, v_pages,
     throughout as in `decode_step`.
     A rejected speculative tail needs no rollback: its KV sits at
     positions >= the accepted seq_len, which later steps overwrite
-    before attending (attention is masked by per-token length).
+    before attending (attention is masked by per-token length). A
+    recurrent state has no such rollback, so a family with state
+    layers is refused here (speculation and chunked prefill over state
+    are not built).
     """
+    if "mamba" in cfg.layer_kinds:
+        raise NotImplementedError(
+            "verify_step over state layers: a rejected draft cannot be "
+            "rolled back out of a recurrent state")
+    if cfg.kv_pack > 1:
+        raise NotImplementedError("verify_step over packed kv heads")
     b, m = tokens.shape
     x = embed(params, tokens, cfg)  # [b, m, d]
     positions = seq_lens[:, None] + jnp.arange(m)[None, :]
@@ -328,11 +544,11 @@ def verify_step(block, params, cfg, tokens, seq_lens, k_pages, v_pages,
                 q, k_pages, v_pages, page_table, seq_lens,
                 window=cfg.window, layer=li
             )
-        x = x + attn_out(layer, attn.reshape(b, m, -1))
+        x = residual(cfg, x, attn_out(layer, attn.reshape(b, m, -1)))
         out, _aux = block(layer, x, cfg, ok)
-        x = x + out
+        x = residual(cfg, x, out)
     x = rms_norm(x, params["final_ln"], cfg.norm_eps, cfg.norm_plus_one)
-    return lm_head(params, x), k_pages, v_pages
+    return lm_head(params, x, cfg), k_pages, v_pages
 
 
 def bind(block):
@@ -393,13 +609,13 @@ def page_keys(prefix, layer, kind, n_pages):
 def restored_to_pages(cfg, flat):
     """What one page-major store call returned (`flat`: [n * L * 2,
     page, n_kv, hd], rows ordered page, layer, k then v) as per-layer
-    stacks in pool form: (k_pages, v_pages) [n_layers, n, page, n_kv,
-    hd]. One transpose; traceable, so the serving engine's hit program
+    stacks in pool form: (k_pages, v_pages) [n_kv_layers, n, page,
+    n_kv, hd]. One transpose; traceable, so the serving engine's hit program
     (serving._admit_fused_px) does it on the device inside the program
     that also scatters the stacks into the pool."""
-    n = flat.shape[0] // (2 * cfg.n_layers)
+    n = flat.shape[0] // (2 * cfg.n_kv_layers)
     both = jnp.moveaxis(
-        flat.reshape(n, cfg.n_layers, 2, *cfg.kv_page_shape()), 0, 2
+        flat.reshape(n, cfg.n_kv_layers, 2, *cfg.kv_page_shape()), 0, 2
     )
     return both[:, 0], both[:, 1]
 
@@ -424,7 +640,8 @@ def restore_prefix_pages(store, cfg, key_fn, n_pages,
     stacks is one device transpose, then slicing (`restored_to_pages`).
     Returns (k_pages, v_pages) [n_layers, n_pages, page, n_kv, hd]."""
     get = getter if getter is not None else store.get_kv_pages
-    per = [key_fn(li, kind) for li in range(cfg.n_layers) for kind in "kv"]
+    per = [key_fn(li, kind) for li in range(cfg.n_kv_layers)
+           for kind in "kv"]
     keys = [ks[p] for p in range(n_pages) for ks in per]
     return restored_to_pages(
         cfg, get(keys, cfg.kv_page_shape(), cfg.jdtype))
@@ -444,5 +661,5 @@ def restore_prefix_kvs(store, cfg, seq_id, n_pages):
     return [
         pages_to_kv(cfg, kp[li][None], vp[li][None],
                     n_pages * cfg.page_size)
-        for li in range(cfg.n_layers)
+        for li in range(cfg.n_kv_layers)
     ]

@@ -210,7 +210,9 @@ def load_hf(model_or_state_dict, hf_cfg=None, page_size=16,
 
 
 __all__ = ["config_from_hf", "params_from_hf", "load_hf",
-           "moe_config_from_hf", "moe_params_from_hf", "load_hf_moe"]
+           "moe_config_from_hf", "moe_params_from_hf", "load_hf_moe",
+           "hybrid_config_from_hf", "hybrid_params_from_hf",
+           "load_hf_hybrid"]
 
 
 def moe_config_from_hf(hf_cfg, page_size=16, dtype="float32"):
@@ -339,3 +341,140 @@ def load_hf_moe(model_or_state_dict, hf_cfg=None, page_size=16,
         hf_cfg = model_or_state_dict.config
     cfg = moe_config_from_hf(hf_cfg, page_size=page_size, dtype=dtype)
     return cfg, moe_params_from_hf(model_or_state_dict, cfg)
+
+
+def hybrid_config_from_hf(hf_cfg, page_size=16, dtype="float32"):
+    """Map a ``transformers.GraniteMoeHybridConfig`` (granite-4.0-h)
+    onto :class:`models.hybrid.HybridConfig`: `layer_types` of Mamba-2
+    and attention layers, the four multipliers, no positional
+    embedding. Refuses what the JAX model does not implement: routed
+    experts, rotary positions, biases, more than one group of B and
+    C, an untied head."""
+    from .hybrid import HybridConfig
+
+    def refuse(what):
+        raise NotImplementedError(
+            f"granitemoehybrid: {what} is not implemented by "
+            "models/hybrid.py")
+
+    if getattr(hf_cfg, "num_local_experts", 0) > 0:
+        refuse(f"num_local_experts={hf_cfg.num_local_experts} (routed "
+               "experts beside the shared MLP)")
+    pos = getattr(hf_cfg, "position_embedding_type", "nope")
+    if pos != "nope":
+        refuse(f"position_embedding_type={pos!r} (only 'nope')")
+    if getattr(hf_cfg, "rope_scaling", None):
+        refuse("rope_scaling")
+    if getattr(hf_cfg, "attention_bias", False):
+        refuse("attention_bias=True")
+    if getattr(hf_cfg, "mamba_proj_bias", False):
+        refuse("mamba_proj_bias=True")
+    if not getattr(hf_cfg, "mamba_conv_bias", True):
+        refuse("mamba_conv_bias=False")
+    if getattr(hf_cfg, "mamba_n_groups", 1) != 1:
+        refuse(f"mamba_n_groups={hf_cfg.mamba_n_groups} (one group)")
+    if not getattr(hf_cfg, "tie_word_embeddings", True):
+        refuse("tie_word_embeddings=False")
+    if getattr(hf_cfg, "hidden_act", "silu") not in ("silu", "swish"):
+        refuse(f"hidden_act={hf_cfg.hidden_act!r}")
+    norm = getattr(hf_cfg, "normalization_function", "rmsnorm")
+    if norm != "rmsnorm":
+        refuse(f"normalization_function={norm!r}")
+    kinds = tuple(hf_cfg.layer_types)
+    if len(kinds) != hf_cfg.num_hidden_layers or set(kinds) - {
+            "mamba", "attention"}:
+        refuse(f"layer_types {sorted(set(kinds))} over "
+               f"{hf_cfg.num_hidden_layers} layers")
+    # Pack kv heads into cache rows of up to 128 lanes (head_dim 64:
+    # two a row), as many as divide the kv heads.
+    hd = hf_cfg.hidden_size // hf_cfg.num_attention_heads
+    kv_pack = 1
+    while hd * kv_pack * 2 <= 128 \
+            and hf_cfg.num_key_value_heads % (kv_pack * 2) == 0:
+        kv_pack *= 2
+    heads, p = hf_cfg.mamba_n_heads, hf_cfg.mamba_d_head
+    if heads * p != hf_cfg.mamba_expand * hf_cfg.hidden_size:
+        refuse("mamba_n_heads * mamba_d_head != mamba_expand * "
+               "hidden_size")
+    return HybridConfig(
+        vocab_size=hf_cfg.vocab_size,
+        d_model=hf_cfg.hidden_size,
+        n_layers=hf_cfg.num_hidden_layers,
+        n_heads=hf_cfg.num_attention_heads,
+        n_kv_heads=hf_cfg.num_key_value_heads,
+        d_ff=hf_cfg.shared_intermediate_size,
+        max_seq=hf_cfg.max_position_embeddings,
+        page_size=page_size,
+        norm_eps=float(hf_cfg.rms_norm_eps),
+        embed_scale=float(hf_cfg.embedding_multiplier),
+        attn_scale=float(hf_cfg.attention_multiplier),
+        use_rope=False,
+        kv_pack=kv_pack,
+        residual_mult=float(hf_cfg.residual_multiplier),
+        logits_div=float(hf_cfg.logits_scaling),
+        layer_types=kinds,
+        ssm_heads=heads,
+        ssm_head_dim=p,
+        ssm_state=hf_cfg.mamba_d_state,
+        ssm_groups=1,
+        ssm_conv=hf_cfg.mamba_d_conv,
+        ssm_chunk=hf_cfg.mamba_chunk_size,
+        dtype=dtype,
+    )
+
+
+def hybrid_params_from_hf(model_or_state_dict, cfg):
+    """Build the models/hybrid.py parameter pytree from a HF
+    ``GraniteMoeHybridForCausalLM`` or its state dict: `input_linear`
+    ([2 * ff, d]) splits into gate and up, `conv1d.weight` [C, 1, K]
+    becomes [K, C], every projection transposes."""
+    sd = model_or_state_dict
+    if hasattr(sd, "state_dict"):
+        sd = sd.state_dict()
+    dt = cfg.jdtype
+    layers = []
+    for li, kind in enumerate(cfg.layer_types):
+        p = f"model.layers.{li}."
+        w_in = _t(sd, p + "shared_mlp.input_linear.weight", dt).T
+        layer = {
+            "ln1": _t(sd, p + "input_layernorm.weight", dt),
+            "ln2": _t(sd, p + "post_attention_layernorm.weight", dt),
+            "w_gate": w_in[:, :cfg.d_ff],
+            "w_up": w_in[:, cfg.d_ff:],
+            "w_down": _t(sd, p + "shared_mlp.output_linear.weight", dt).T,
+        }
+        if kind == "mamba":
+            m = p + "mamba."
+            layer.update({
+                "in_proj": _t(sd, m + "in_proj.weight", dt).T,
+                "conv_w": _t(sd, m + "conv1d.weight", dt)[:, 0, :].T,
+                "conv_b": _t(sd, m + "conv1d.bias", dt),
+                "A_log": _t(sd, m + "A_log", "float32"),
+                "dt_bias": _t(sd, m + "dt_bias", "float32"),
+                "D": _t(sd, m + "D", "float32"),
+                "ssm_norm": _t(sd, m + "norm.weight", dt),
+                "out_proj": _t(sd, m + "out_proj.weight", dt).T,
+            })
+        else:
+            a = p + "self_attn."
+            layer.update({
+                "wq": _t(sd, a + "q_proj.weight", dt).T,
+                "wk": _t(sd, a + "k_proj.weight", dt).T,
+                "wv": _t(sd, a + "v_proj.weight", dt).T,
+                "wo": _t(sd, a + "o_proj.weight", dt).T,
+            })
+        layers.append(layer)
+    return {
+        "embed": _t(sd, "model.embed_tokens.weight", dt),
+        "layers": layers,
+        "final_ln": _t(sd, "model.norm.weight", dt),
+    }
+
+
+def load_hf_hybrid(model_or_state_dict, hf_cfg=None, page_size=16,
+                   dtype="float32"):
+    """One-call granite-4.0-h bridge: returns (cfg, params)."""
+    if hf_cfg is None:
+        hf_cfg = model_or_state_dict.config
+    cfg = hybrid_config_from_hf(hf_cfg, page_size=page_size, dtype=dtype)
+    return cfg, hybrid_params_from_hf(model_or_state_dict, cfg)

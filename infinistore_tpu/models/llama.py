@@ -65,6 +65,20 @@ class LlamaConfig:
     norm_plus_one: bool = False  # rms_norm multiplies by (1 + w)
     embed_scale: float = 1.0     # embedding output multiplier
     head_dim_override: int = 0   # 0 = d_model // n_heads
+    # ... and the Granite knobs: a softmax scale that is not
+    # head_dim ** -0.5 (0 = that default), no positional embedding,
+    # scaled residual branches and divided logits.
+    attn_scale: float = 0.0
+    use_rope: bool = True
+    residual_mult: float = 1.0
+    logits_div: float = 1.0
+    # KV heads packed side by side into one cache row: a head_dim
+    # under the 128 lanes of a TPU tile would make every paged-decode
+    # call slice, pad and re-lay-out its layer of the pool (30 ms of a
+    # 48 ms step at head_dim 64, PERF.md, PR 31); `kv_pack` heads of
+    # head_dim lanes each fill the row instead, the same bytes in the
+    # same order (decoder.pack_heads has the attention side). 1 = off.
+    kv_pack: int = 1
     dtype: str = "bfloat16"
 
     @property
@@ -75,10 +89,24 @@ class LlamaConfig:
     def jdtype(self):
         return jnp.dtype(self.dtype)
 
+    @property
+    def layer_kinds(self):
+        """Each layer's mixer (decoder.py's per-layer spec): all
+        attention here; models/hybrid.py's config overrides it."""
+        return ("attention",) * self.n_layers
+
+    @property
+    def n_kv_layers(self):
+        """Layers that keep K and V pages: the page pools' layer
+        axis."""
+        return sum(k == "attention" for k in self.layer_kinds)
+
     def kv_page_shape(self):
         """Shape of one K (or V) page for ONE layer — what goes into the
-        store as one block: [page_size, n_kv_heads, head_dim]."""
-        return (self.page_size, self.n_kv_heads, self.head_dim)
+        store as one block: [page_size, n_kv_heads, head_dim], with
+        `kv_pack` heads side by side in a row where that is set."""
+        return (self.page_size, self.n_kv_heads // self.kv_pack,
+                self.head_dim * self.kv_pack)
 
     def kv_page_bytes(self):
         import numpy as np

@@ -10,9 +10,17 @@ the store's primitives into end-to-end serving:
   sequences decodes in lockstep through ONE jitted `decode_step` (static
   shapes — one compile, any request mix); requests are admitted into free
   slots as others finish, vLLM-style.
-- **Paged HBM pool**: KV lives in fixed-size pages [n_layers,
+- **Paged HBM pool**: KV lives in fixed-size pages [n_kv_layers,
   total_pages, page, n_kv, hd] with a host-side free list and per-slot
   page tables; pages are allocated on demand as sequences grow.
+- **A second kind of cache, per slot**: a family with recurrent layers
+  (models/hybrid.py) keeps pages for its attention layers alone and,
+  for the others, a state a slot that does not grow, plus a copy of it
+  taken when the sequence last crossed a page edge (a recurrence
+  cannot be rewound to where the stored pages end). A finish or a
+  preemption offloads the new full pages AND that copy as a snapshot
+  keyed by the same digest chain; a prefix hit is the pages and the
+  snapshot at their end, both or nothing.
 - **Prefix-cache HIT admission**: page keys are content-addressed (a
   hash chain over token ids, vLLM-style — see `content_page_keys`), so
   any request whose prompt extends a cached token prefix automatically
@@ -124,6 +132,14 @@ def content_page_keys_by_page(digests, n_layers):
     offload is one key list over one array."""
     return [f"cp/{d}/L{layer}/{kind}" for d in digests
             for layer in range(n_layers) for kind in "kv"]
+
+
+def snapshot_keys(digest, lo, hi):
+    """Store keys of rows [lo, hi) of the state snapshot taken at the
+    end of the page whose digest is `digest` (one row a state layer):
+    the page's own chain, so a snapshot belongs to exactly the token
+    prefix its pages belong to."""
+    return [f"cp/{digest}/S{j}" for j in range(lo, hi)]
 
 
 @dataclass(frozen=True)
@@ -250,6 +266,8 @@ class _Slot:
     pending: list = field(default_factory=list)  # prompt tokens not yet
     #                                              prefilled (chunked
     #                                              prefill phase)
+    index: int = -1           # the slot it sits in: its row of the
+    #                           state pools (families with state)
 
     def total_generated(self):
         return len(self.work.done) + len(self.generated)
@@ -331,6 +349,22 @@ def _decode_scan(params, cfg, token, seq_lens, k_pages, v_pages, rows,
     return toks.T, lens, kp, vp  # [batch, n_steps]
 
 
+def _page_out(cfg, kvs, k_pages, v_pages, ids):
+    """A prefill's per-layer (k, v) [1, s_pad, kv, hd], cut into pages
+    and scattered into the pools at the first s_pad // page of `ids`
+    (`_pad_ids` form; ids at total_pages are dropped). Traced inside
+    the admission programs."""
+    with jax.named_scope("pool.update"):  # stage names: models/decoder.py
+        page = cfg.page_size
+        m = kvs[0][0].shape[1] // page
+        shape = (cfg.n_kv_layers, m, *cfg.kv_page_shape())
+        k_sfx = jnp.stack([k[0] for k, _ in kvs]).reshape(shape)
+        v_sfx = jnp.stack([v[0] for _, v in kvs]).reshape(shape)
+        k_pages = k_pages.at[:, ids[:m]].set(k_sfx, mode="drop")
+        v_pages = v_pages.at[:, ids[:m]].set(v_sfx, mode="drop")
+    return k_pages, v_pages
+
+
 @partial(jax.jit, static_argnames=("cfg", "model"), donate_argnums=(3, 4))
 def _admit_fused(params, cfg, tokens, k_pages, v_pages, ids, s_real,
                  model=llama):
@@ -343,22 +377,41 @@ def _admit_fused(params, cfg, tokens, k_pages, v_pages, ids, s_real,
     write their (garbage) KV into the tail page's unused slots — those
     slots are masked by seq_len, overwritten by decode before the page
     can ever fill, and partial pages are never offloaded, so the bytes
-    are unreachable. `ids` is padded with total_pages (mode=drop).
+    are unreachable. `ids` is padded with total_pages (mode=drop);
+    the first s_pad // page of them are read.
     tokens: [1, s_pad] (page multiple); ids: [max_pages_per_seq]."""
     logits, kvs = model.prefill(params, cfg, tokens)
-    page = cfg.page_size
-    n = tokens.shape[1] // page
-    with jax.named_scope("pool.update"):  # stage names: models/decoder.py
-        k_sfx = jnp.stack([k[0] for k, _ in kvs])  # [L, s_pad, kv, hd]
-        v_sfx = jnp.stack([v[0] for _, v in kvs])
-        shape = (cfg.n_layers, n, page, cfg.n_kv_heads, cfg.head_dim)
-        kp = k_sfx.reshape(shape)
-        vp = v_sfx.reshape(shape)
-        m = ids.shape[0]
-        pad = ((0, 0), (0, m - n), (0, 0), (0, 0), (0, 0))
-        k_pages = k_pages.at[:, ids].set(jnp.pad(kp, pad), mode="drop")
-        v_pages = v_pages.at[:, ids].set(jnp.pad(vp, pad), mode="drop")
+    k_pages, v_pages = _page_out(cfg, kvs, k_pages, v_pages, ids)
     return logits[0, s_real - 1], k_pages, v_pages
+
+
+def _place_restored(cfg, restored, k_pages, v_pages, restored_ids):
+    """A hit's restored pages into the pools at `restored_ids`, and as
+    the per-layer contiguous prefix (k, v) the suffix attends over.
+    Traced inside the hit programs (`_admit_fused_px` has the forms)."""
+    page = cfg.page_size
+    n = restored_ids.shape[0]
+    L = cfg.n_kv_layers
+    with jax.named_scope("pool.update"):  # stage names: models/decoder.py
+        # Each layer's pages go from the page-major rows straight into
+        # that layer of the pool. Scattered as one `[:, ids]` update
+        # from the transposed stacks below, the compiled program held 8
+        # times the restored bytes in temporaries (a second layout of
+        # the stacks, and weight copies pushed out of fast memory); so
+        # it holds one (tests/test_model.py, lowered for a v5e).
+        rows = restored.reshape(n, L, 2, *cfg.kv_page_shape())
+        for li in range(L):
+            k_pages = k_pages.at[li, restored_ids].set(rows[:, li, 0],
+                                                       mode="drop")
+            v_pages = v_pages.at[li, restored_ids].set(rows[:, li, 1],
+                                                       mode="drop")
+        # The contiguous form the suffix attends over: the same values,
+        # layer-major, reshaped (decoder.pages_to_kv, every layer at
+        # once).
+        kp, vp = decoder.restored_to_pages(cfg, restored)
+        flat = (L, 1, n * page, cfg.n_kv_heads, cfg.head_dim)
+        k_pfx, v_pfx = kp.reshape(flat), vp.reshape(flat)
+    return k_pages, v_pages, [(k_pfx[li], v_pfx[li]) for li in range(L)]
 
 
 @partial(jax.jit, static_argnames=("cfg", "model"), donate_argnums=(4, 5))
@@ -388,37 +441,11 @@ def _admit_fused_px(params, cfg, tokens, restored, k_pages, v_pages,
     comes back untouched (`ServingEngine.first_token_logits`).
     tokens: [1, s_pad] (page multiple). One program per (s_pad, n), as
     `_prefill_px_jit` has per (s_pad, prefix length)."""
-    page = cfg.page_size
-    n = restored_ids.shape[0]
-    m = tokens.shape[1] // page
-    with jax.named_scope("pool.update"):  # stage names: models/decoder.py
-        # Each layer's pages go from the page-major rows straight into
-        # that layer of the pool. Scattered as one `[:, ids]` update
-        # from the transposed stacks below, the compiled program held 8
-        # times the restored bytes in temporaries (a second layout of
-        # the stacks, and weight copies pushed out of fast memory); so
-        # it holds one (tests/test_model.py, lowered for a v5e).
-        rows = restored.reshape(n, cfg.n_layers, 2, *cfg.kv_page_shape())
-        for li in range(cfg.n_layers):
-            k_pages = k_pages.at[li, restored_ids].set(rows[:, li, 0],
-                                                       mode="drop")
-            v_pages = v_pages.at[li, restored_ids].set(rows[:, li, 1],
-                                                       mode="drop")
-        # The contiguous form the suffix attends over: the same values,
-        # layer-major, reshaped (decoder.pages_to_kv, every layer at
-        # once).
-        kp, vp = decoder.restored_to_pages(cfg, restored)
-        flat = (cfg.n_layers, 1, n * page, cfg.n_kv_heads, cfg.head_dim)
-        k_pfx, v_pfx = kp.reshape(flat), vp.reshape(flat)
-    logits, kvs = model.prefill_with_prefix(
-        params, cfg, tokens,
-        [(k_pfx[li], v_pfx[li]) for li in range(cfg.n_layers)], pos0=pos0)
-    with jax.named_scope("pool.update"):
-        shape = (cfg.n_layers, m, page, cfg.n_kv_heads, cfg.head_dim)
-        k_sfx = jnp.stack([k[0] for k, _ in kvs]).reshape(shape)
-        v_sfx = jnp.stack([v[0] for _, v in kvs]).reshape(shape)
-        k_pages = k_pages.at[:, suffix_ids[:m]].set(k_sfx, mode="drop")
-        v_pages = v_pages.at[:, suffix_ids[:m]].set(v_sfx, mode="drop")
+    k_pages, v_pages, prefix = _place_restored(cfg, restored, k_pages,
+                                               v_pages, restored_ids)
+    logits, kvs = model.prefill_with_prefix(params, cfg, tokens, prefix,
+                                            pos0=pos0)
+    k_pages, v_pages = _page_out(cfg, kvs, k_pages, v_pages, suffix_ids)
     return logits[0, s_real - 1], k_pages, v_pages
 
 
@@ -444,6 +471,148 @@ def _decode_fused(params, cfg, token, seq_lens, k_pages, v_pages, rows,
     nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
     # Live-rows-only advance — see _decode_scan's body comment.
     return logits, nxt, seq_lens + (seq_lens > 0), k_pages, v_pages
+
+
+# ---- the same programs for a family with state layers ------------------
+# Each carries, beside the page pools, the state pools its model
+# declares (`model.state_pools`: {"h": [...], "conv": [...]}, one array
+# a state layer, a row a slot) and, where it admits, the pools of
+# boundary copies: the state as it was at the slot's last page edge.
+# They have names of their own, so a trace tells the two kinds of
+# family apart and the families without state run exactly the
+# programs above.
+
+
+def _state_rows(cfg, pools, slot):
+    """Slot `slot` of the state pools `pools` as snapshot rows: one
+    flat row a state layer, h then the convolution tail, zero-padded
+    to `_snapshot_row_elems`. [n_state_layers, row]."""
+    row = _snapshot_row_elems(cfg)
+    out = []
+    # a layer's arrays, kind by kind in the order of cfg.state_shapes()
+    # (a dict that has been through jit comes back with sorted keys)
+    for layer in zip(*(pools[kind] for kind in cfg.state_shapes())):
+        flat = jnp.concatenate([
+            jax.lax.dynamic_index_in_dim(a, slot, keepdims=False).reshape(-1)
+            for a in layer])
+        out.append(jnp.pad(flat, (0, row - flat.shape[0])))
+    return jnp.stack(out)
+
+
+def _snapshot_row_elems(cfg):
+    """Elements of one snapshot row: a state layer's h and convolution
+    tail for one sequence, rounded up to whole K pages' bytes (the
+    store's allocation unit is the smallest object an offload writes,
+    one K page; rows that are whole blocks lie back to back in its
+    pool, so a snapshot reads back as one view)."""
+    raw = sum(int(np.prod(shape)) for shape in cfg.state_shapes().values())
+    block = max(1, cfg.kv_page_bytes() // cfg.state_jdtype.itemsize)
+    return -(-raw // block) * block
+
+
+def _rows_to_state(cfg, snap):
+    """Inverse of `_state_rows` for one sequence: per state layer its
+    arrays in the order of cfg.state_shapes(), each [1, *shape]."""
+    out = []
+    for j in range(snap.shape[0]):
+        at, layer = 0, []
+        for shape in cfg.state_shapes().values():
+            n = int(np.prod(shape))
+            layer.append(snap[j, at:at + n].reshape(1, *shape))
+            at += n
+        out.append(tuple(layer))
+    return out
+
+
+def _state_in(state, bstate, states, slot):
+    """An admission's per-layer states (decoder.ssm_mixer_seq) into row
+    `slot` of the state pools and of the boundary copies; a slot of
+    max_slots is dropped (first_token_logits admits nothing)."""
+    with jax.named_scope("state.update"):
+        for j, st in enumerate(states):
+            for kind in state:
+                state[kind][j] = state[kind][j].at[slot].set(
+                    st[kind][0], mode="drop")
+                bstate[kind][j] = bstate[kind][j].at[slot].set(
+                    st[kind + "_b"][0], mode="drop")
+    return state, bstate
+
+
+@partial(jax.jit, static_argnames=("cfg", "model"),
+         donate_argnums=(3, 4, 5, 6))
+def _admit_fused_st(params, cfg, tokens, k_pages, v_pages, state, bstate,
+                    ids, s_real, slot, model):
+    """`_admit_fused` for a family with state layers: the attention
+    layers' KV is paged out as there; the state layers run from
+    position 0 with the padded positions masked (dt = 0: they may not
+    advance a recurrence), and the state after `s_real` tokens and the
+    one at the last page edge go into row `slot` of the (donated) state
+    pools and boundary copies."""
+    logits, kvs, states = model.prefill(params, cfg, tokens, s_real=s_real)
+    k_pages, v_pages = _page_out(cfg, kvs, k_pages, v_pages, ids)
+    state, bstate = _state_in(state, bstate, states, slot)
+    return logits[0, s_real - 1], k_pages, v_pages, state, bstate
+
+
+@partial(jax.jit, static_argnames=("cfg", "model"),
+         donate_argnums=(5, 6, 7, 8))
+def _admit_fused_px_st(params, cfg, tokens, restored, snap, k_pages,
+                       v_pages, state, bstate, restored_ids, suffix_ids,
+                       s_real, slot, model):
+    """`_admit_fused_px` for a family with state layers: the restored
+    pages go into the pool, the restored snapshot `snap` ([n_state
+    layers, row], the state at the end of the restored pages) is where
+    the state layers continue from, the suffix is prefilled over both,
+    and pages and states go where `_admit_fused_st` puts them. One
+    program per (s_pad, n)."""
+    k_pages, v_pages, prefix = _place_restored(cfg, restored, k_pages,
+                                               v_pages, restored_ids)
+    logits, kvs, states = model.prefill_with_prefix(
+        params, cfg, tokens, prefix, state=_rows_to_state(cfg, snap),
+        s_real=s_real)
+    k_pages, v_pages = _page_out(cfg, kvs, k_pages, v_pages, suffix_ids)
+    state, bstate = _state_in(state, bstate, states, slot)
+    return logits[0, s_real - 1], k_pages, v_pages, state, bstate
+
+
+@partial(jax.jit, static_argnames=("cfg", "model"),
+         donate_argnums=(4, 5, 6))
+def _decode_fused_st(params, cfg, token, seq_lens, k_pages, v_pages,
+                     state, rows, model):
+    """`_decode_fused` for a family with state layers: every slot's
+    state is read and written where it lies (one array a layer,
+    donated). An inactive slot's row takes a garbage update; an
+    admission overwrites the whole row."""
+    logits, k_pages, v_pages, state = model.decode_step(
+        params, cfg, token, seq_lens, k_pages, v_pages, rows, state
+    )
+    nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+    return logits, nxt, seq_lens + (seq_lens > 0), k_pages, v_pages, state
+
+
+@partial(jax.jit, donate_argnums=(1,))
+def _copy_boundary(state, bstate, slot):
+    """Slot `slot`'s state into its boundary copy: dispatched behind
+    the decode step in which the slot's sequence reached a page edge,
+    for that slot alone (76 MB at granite-4.0-h-micro's widths; doing
+    it for every slot inside every step would move 16 times that)."""
+    with jax.named_scope("state.snapshot"):
+        return jax.tree_util.tree_map(
+            lambda live, copy: jax.lax.dynamic_update_slice_in_dim(
+                copy, jax.lax.dynamic_slice_in_dim(live, slot, 1), slot, 0),
+            state, bstate)
+
+
+@partial(jax.jit, static_argnames=("cfg", "rows_a_chunk"))
+def _gather_snapshot(cfg, bstate, slot, rows_a_chunk):
+    """Slot `slot`'s boundary copy as snapshot rows (`_state_rows`),
+    in flat chunks of `rows_a_chunk` rows: the state's part of an
+    offload, one program; each chunk is one device-to-host transfer
+    and one store batch."""
+    with jax.named_scope("state.gather"):
+        rows = _state_rows(cfg, bstate, slot)
+        return tuple(rows[a:a + rows_a_chunk].reshape(-1)
+                     for a in range(0, rows.shape[0], rows_a_chunk))
 
 
 # Trivial programs dispatched behind a one-shot admission's program
@@ -486,6 +655,11 @@ def _write_pages(k_pool, v_pool, ids, k_new, v_new):
 # An offload larger than this goes in chunks of it, each transferred
 # while the one before is copied into the store's pool.
 OFFLOAD_CHUNK_BYTES = 16 << 20
+# How many pages below the matched depth a hit of a family with state
+# looks for a snapshot, one single-key probe a page (some 0.1 ms
+# each): the next turn of a conversation adds an answer and a message,
+# a few hundred tokens, to where the last snapshot lies.
+SNAPSHOT_WALK = 32
 
 
 @jax.jit
@@ -575,12 +749,25 @@ class ServingEngine:
         self.store = store
         self.proposer = proposer if proposer is not None \
             else prompt_lookup_propose
-        L = cfg.n_layers
-        shape = (L, self.sc.total_pages, cfg.page_size, cfg.n_kv_heads,
-                 cfg.head_dim)
+        # The cache manager's pools, by kind. Pages: K and V of the
+        # layers that attend (all of them, for most families).
+        shape = (cfg.n_kv_layers, self.sc.total_pages,
+                 *cfg.kv_page_shape())
         self.k_pages = jnp.zeros(shape, dtype=cfg.jdtype,
                                  device=self.device)
         self.v_pages = jnp.zeros_like(self.k_pages)
+        # State: for a family with recurrent layers, what its model
+        # declares (`model.state_pools`), a row a slot, and the same
+        # again for the copies taken at each slot's last page edge:
+        # where its stored pages can end, so what an offload writes
+        # beside them. None for every other family.
+        self.state = self.bstate = None
+        if getattr(cfg, "n_state_layers", 0):
+            self._check_state_family()
+            self.state = model.state_pools(cfg, self.sc.max_slots,
+                                           self.device)
+            self.bstate = model.state_pools(cfg, self.sc.max_slots,
+                                            self.device)
         # Page 0 is the scratch page: inactive decode slots scatter their
         # garbage KV there; sequences never own it.
         self.free_pages = list(range(1, self.sc.total_pages))
@@ -604,13 +791,29 @@ class ServingEngine:
             # XLA programs built (or read from the persistent cache)
             # inside a step: 0 once every shape is warm
             "compilations": 0,
+            # families with state: snapshots an offload wrote / a hit
+            # restored, page hits refused because the snapshot at
+            # their end was not in the store, and boundary copies
+            # dispatched behind decode steps
+            "snapshots_written": 0, "snapshots_restored": 0,
+            "snapshot_misses": 0, "boundary_copies": 0,
+            # hits whose snapshot lay below the pages' matched depth
+            "snapshot_walkbacks": 0,
         }
         self.engine_id = profiling.next_engine_id()
         self._own_digests = {}  # insertion-ordered, at most OWN_DIGESTS
-        # One sequence page over every layer and both kinds, in bytes.
-        self._page_bytes = (
-            2 * L * int(np.prod(cfg.kv_page_shape())) * cfg.jdtype.itemsize
-        )
+        # One sequence page over every layer and kind the page pools
+        # hold, and one state snapshot, in bytes.
+        self._page_objects = 2 * self.k_pages.shape[0]
+        self._page_bytes = (self.k_pages.nbytes + self.v_pages.nbytes) \
+            // self.sc.total_pages
+        self._snapshot_bytes = 0
+        self._snapshot_fields = {}  # what its spans carry beyond pages'
+        if self.state is not None:
+            self._snapshot_row = _snapshot_row_elems(cfg)
+            self._snapshot_bytes = (cfg.n_state_layers * self._snapshot_row
+                                    * cfg.state_jdtype.itemsize)
+            self._snapshot_fields = {"snapshot_bytes": self._snapshot_bytes}
         # The store is an accelerator, never a dependency: after the
         # first store failure the engine downgrades itself to store-less
         # serving (full prefills, no offload) instead of failing
@@ -645,6 +848,13 @@ class ServingEngine:
             f"{model_id}/p{cfg.page_size}/l{cfg.n_layers}"
             f"/kv{cfg.n_kv_heads}x{cfg.head_dim}/{wire}"
         )
+        if self.state is not None:
+            # ... of every cache kind: which layers keep pages, and the
+            # state's geometry and dtype.
+            shapes = "+".join("x".join(map(str, shape))
+                              for shape in cfg.state_shapes().values())
+            self._ns += (f"/kvl{cfg.n_kv_layers}/st{cfg.n_state_layers}"
+                         f"x{shapes}/{cfg.state_dtype}")
         if store is not None and self.sc.quantized_store:
             self._get_pages = partial(store.get_kv_pages_quantized,
                                       device=self.device)
@@ -653,6 +863,25 @@ class ServingEngine:
             self._get_pages = partial(store.get_kv_pages,
                                       device=self.device)
             self._put_pages = store.put_kv_pages
+
+    def _check_state_family(self):
+        """What is not built over a recurrent state is refused at
+        construction, not found at the first request: a rejected draft
+        or a chunk boundary inside a page cannot be rolled back out of
+        a state, bursts would need the boundary copy inside the scan,
+        and the int8 wire is defined for pages. A sliding window is
+        refused too: its release frees pages by one global band, and a
+        family whose state layers carry the long range has none."""
+        sc = self.sc
+        for name, on in (("spec_k", sc.spec_k > 0),
+                         ("host_steps", sc.host_steps > 1),
+                         ("prefill_chunk", sc.prefill_chunk > 0),
+                         ("quantized_store", sc.quantized_store),
+                         ("window", bool(self.cfg.window))):
+            if on:
+                raise ValueError(
+                    f"{name} is not supported for a model with state "
+                    f"layers ({type(self.cfg).__name__})")
 
     def _to_device(self, host):
         """Host array -> the engine's device (None: jax's default)."""
@@ -809,10 +1038,40 @@ class ServingEngine:
                 self._store_failed("probe", e)
                 return 0, []
             hit = min(hit, cap)
+            if hit > 0 and self.state is not None:
+                hit = self._probe_snapshot(hit, digests)
             f["hit_pages"] = hit
             if hit > 0:
                 self._prefetch_chain(work.prompt, hit, digests[:hit])
         return hit, digests[:hit]
+
+    def _probe_snapshot(self, hit, digests):
+        """The depth a family with state can restore, given `hit`
+        matched pages: the deepest d <= hit at which a snapshot lies in
+        the store, 0 if none within SNAPSHOT_WALK pages. Pages without
+        the state at their end are no prefix. A finish writes pages and
+        snapshot at ONE depth, so the common case is one probe, of the
+        snapshot at `hit` (its last row's key: rows are written in
+        order). Where that is not there (the pages matched deeper than
+        the snapshot lies: a prompt that shares only part of a stored
+        sequence, or that sequence's own earlier turn asked again), the
+        walk goes back a page a probe; the store's match over a key
+        list stops at the first hole, so sparse snapshot keys cannot be
+        found by one call over the chain. Pages beyond d are prefilled
+        again. Nothing found within the bound (or the snapshot was
+        evicted and its pages not): admitted cold, and counted."""
+        last = self.cfg.n_state_layers - 1
+        try:
+            for d in range(hit, max(hit - SNAPSHOT_WALK, 0), -1):
+                if self.store.cached_prefix_len(
+                        snapshot_keys(digests[d - 1], last, last + 1)):
+                    self.stats["snapshot_walkbacks"] += d < hit
+                    return d
+        except Exception as e:
+            self._store_failed("probe", e)
+            return 0
+        self.stats["snapshot_misses"] += 1
+        return 0
 
     def _prefetch_chain(self, prompt, hit, digests):
         """Fire-and-forget OP_PREFETCH for the matched page chain —
@@ -830,12 +1089,15 @@ class ServingEngine:
         cfg = self.cfg
         try:
             keys = []
-            for li in range(cfg.n_layers):
+            for li in range(cfg.n_kv_layers):
                 for kind in ("k", "v"):
                     keys.extend(content_page_keys(
                         prompt, cfg.page_size, hit, li, kind,
                         digests=digests,
                     ))
+            if self.state is not None:
+                keys.extend(snapshot_keys(digests[hit - 1], 0,
+                                          self.cfg.n_state_layers))
             if fn(keys):
                 self.stats["prefetched_pages"] += len(keys)
         except Exception:
@@ -960,23 +1222,36 @@ class ServingEngine:
         the order an offload allocated the keys in, so what one offload
         wrote is one zero-copy view of the store's pool). Digests are
         layer/kind-independent and come from the probe — the prompt is
-        hashed ONCE per admission."""
+        hashed ONCE per admission. For a family with state, a second
+        call brings the snapshot taken at the end of page `hit`, [state
+        layers, row]; returns (pages, snapshot or None)."""
         n = hit - first_live
         keys = content_page_keys_by_page(digests[first_live:hit],
-                                         self.cfg.n_layers)
-        # The span times the store call alone — the interval a span
+                                         self.cfg.n_kv_layers)
+        # The span times the store calls alone — the interval a span
         # around get_kv_pages from outside times too.
         with self._span("istpu.cache.restore", pages=n,
-                        bytes=n * self._page_bytes, foreign_pages=foreign):
-            return self._get_pages(keys, self.cfg.kv_page_shape(),
-                                   self.cfg.jdtype)
+                        bytes=n * self._page_bytes + self._snapshot_bytes,
+                        foreign_pages=foreign, **self._snapshot_fields):
+            pages = self._get_pages(keys, self.cfg.kv_page_shape(),
+                                    self.cfg.jdtype)
+            if self.state is None:
+                return pages, None
+            # The snapshot's way in: its store call (store -> HBM); it
+            # is placed into the slot inside the hit program.
+            with self._span("istpu.cache.state_in",
+                            bytes=self._snapshot_bytes):
+                return pages, self._get_pages(
+                    snapshot_keys(digests[hit - 1], 0,
+                                  self.cfg.n_state_layers),
+                    (self._snapshot_row,), self.cfg.state_jdtype)
 
     def _admit_restore_and_prefill(self, slot_idx, work, ids, n_prompt,
                                    n_pages, hit, digests, skip,
                                    first_live, f):
         cfg = self.cfg
         self._admit_ids_view = ids
-        restored = None
+        restored = snap = None
         if hit > 0:
             # Restore the in-window hit pages once (one HBM array, as
             # the store call returns it; pool placement follows in
@@ -984,8 +1259,8 @@ class ServingEngine:
             # hit pages this engine did not itself offload
             foreign = sum(d not in self._own_digests for d in digests[:hit])
             try:
-                restored = self._restore(hit, digests, first_live,
-                                         foreign)
+                restored, snap = self._restore(hit, digests, first_live,
+                                               foreign)
             except InfiniStoreKeyNotFound:
                 # Routine eviction race: the page was LRU-dropped
                 # between probe and restore. A cache MISS for this
@@ -1001,8 +1276,9 @@ class ServingEngine:
                 self.stats["foreign_hit_pages"] += foreign
                 f["foreign_pages"] = foreign
                 self.stats["restored_pages"] += (
-                    (hit - first_live) * cfg.n_layers * 2
+                    (hit - first_live) * self._page_objects
                 )
+                self.stats["snapshots_restored"] += snap is not None
             if hit == 0 and skip > 0:
                 # Restore failed after a skip-trimmed allocation: the
                 # cold path needs the skipped pages after all. Top up
@@ -1018,16 +1294,16 @@ class ServingEngine:
         f["hit_pages"] = hit
         self._do_admit_paged(
             slot_idx, work, ids, n_prompt, n_pages, hit, skip,
-            first_live, restored,
+            first_live, restored, snap,
         )
         work.probe = None  # consumed; a future re-admission re-probes
         f["outcome"] = "admitted"
         return True
 
     def _do_admit_paged(self, slot_idx, work, ids, n_prompt, n_pages,
-                        hit, skip, first_live, restored):
-        """`restored`: what _restore returned for pages [first_live,
-        hit), or None on a miss."""
+                        hit, skip, first_live, restored, snap=None):
+        """`restored`, `snap`: what _restore returned for pages
+        [first_live, hit), or None on a miss."""
         cfg = self.cfg
         page = cfg.page_size
         # page_ids[i] for i < skip are dead placeholders (page 0, the
@@ -1071,7 +1347,7 @@ class ServingEngine:
             # scatter to the drop sentinel: no pool page was allocated
             # for them.
             row_host = self._prefill_cold(
-                suffix, self._pad_ids(ids, offset=skip))
+                suffix, self._pad_ids(ids, offset=skip), slot_idx)
         else:
             # A hit implies skip = first_live <= hit, so every suffix
             # page has a pool id; sub-floor suffix pages (if any are
@@ -1080,7 +1356,7 @@ class ServingEngine:
             # offloading — keeping the prefix chain gap-free.
             row_host = self._prefill_hit(
                 suffix, restored, first_live * page,
-                ids[:hit - skip], ids[hit - skip:])
+                ids[:hit - skip], ids[hit - skip:], snap, slot_idx)
         self.stats["prefill_tokens"] += len(suffix)
         now = time.monotonic()
         if not any(s is not None for s in self.slots):
@@ -1092,7 +1368,7 @@ class ServingEngine:
 
         slot = _Slot(
             work=work, page_ids=full_ids, seq_len=n_prompt,
-            cached_pages=hit, released=skip,
+            cached_pages=hit, released=skip, index=slot_idx,
         )
         self._emit(slot, [self._pick(work, row_host)])
         self.slots[slot_idx] = slot
@@ -1144,30 +1420,55 @@ class ServingEngine:
     def _pad_tokens(self, tokens):
         """Prompt tokens as the [1, s_pad] device array the prefill
         programs take: bucketed to a page multiple (causal attention
-        makes tail padding inert for the positions we read)."""
+        makes tail padding inert for the positions we read; a
+        recurrence is told the real length and masks the rest)."""
         page = self.cfg.page_size
         toks = np.zeros((1, -(-len(tokens) // page) * page), dtype=np.int32)
         toks[0, :len(tokens)] = tokens
         return self._to_device(toks)
 
-    def _prefill_cold(self, tokens, ids_padded):
+    def _scan_fields(self, padded_tokens):
+        """What istpu.model.prefill carries for a family with state:
+        `chunks`, the chunks its scan of `padded_tokens` runs in."""
+        if self.state is None:
+            return {}
+        return {"chunks": -(-padded_tokens // self.cfg.ssm_chunk)}
+
+    def _prefill_cold(self, tokens, ids_padded, slot_idx=None):
         """The cold program: ONE fused device program does prefill +
         page-out + pool scatter at `ids_padded` (_pad_ids form) +
-        logits-row slice. Returns the last real position's logits row,
-        on the host."""
+        logits-row slice; for a family with state it also leaves the
+        sequence's state and boundary copy in row `slot_idx` of the
+        state pools (None: dropped, nothing is admitted). Returns the
+        last real position's logits row, on the host."""
         toks = self._pad_tokens(tokens)
         with self._span("istpu.model.prefill", program="cold",
-                        tokens=len(tokens), padded_tokens=toks.shape[1]):
-            row_dev, self.k_pages, self.v_pages = _admit_fused(
-                self.params, self.cfg, toks, self.k_pages, self.v_pages,
-                self._to_device(ids_padded),
-                self._to_device(np.int32(len(tokens))),
-                model=self.model,
-            )
+                        tokens=len(tokens), padded_tokens=toks.shape[1],
+                        **self._scan_fields(toks.shape[1])):
+            ids = self._to_device(ids_padded)
+            s_real = self._to_device(np.int32(len(tokens)))
+            if self.state is None:
+                row_dev, self.k_pages, self.v_pages = _admit_fused(
+                    self.params, self.cfg, toks, self.k_pages,
+                    self.v_pages, ids, s_real, model=self.model,
+                )
+            else:
+                (row_dev, self.k_pages, self.v_pages, self.state,
+                 self.bstate) = _admit_fused_st(
+                    self.params, self.cfg, toks, self.k_pages,
+                    self.v_pages, self.state, self.bstate, ids, s_real,
+                    self._slot_dev(slot_idx), model=self.model,
+                )
             return np.asarray(row_dev)
 
+    def _slot_dev(self, slot_idx):
+        """A state-pool row index on the device; None is max_slots,
+        which the admission programs drop."""
+        return self._to_device(np.int32(
+            self.sc.max_slots if slot_idx is None else slot_idx))
+
     def _prefill_hit(self, suffix, restored, pos0, restored_ids,
-                     suffix_ids):
+                     suffix_ids, snap=None, slot_idx=None):
         """The prefix program: ONE fused device program scatters the
         `restored` pages (what _restore returned) into the pool at
         `restored_ids`, prefills the suffix over them, and pages its KV
@@ -1177,20 +1478,33 @@ class ServingEngine:
         (decoder.forward_stack). Ids at the drop sentinel are not
         written. Between the store call's return and this row pull the
         engine thread dispatches that one program and nothing else.
+        For a family with state, `snap` (the restored snapshot) is
+        where its state layers continue from, and the sequence's state
+        and boundary copy land in row `slot_idx` (None: dropped).
         Returns the last real position's logits row, on the host."""
         toks = self._pad_tokens(suffix)
         with self._span("istpu.model.prefill", program="prefix",
                         tokens=len(suffix), padded_tokens=toks.shape[1],
-                        restored_pages=len(restored_ids)):
-            row_dev, self.k_pages, self.v_pages = _admit_fused_px(
-                self.params, self.cfg, toks, restored,
-                self.k_pages, self.v_pages,
-                self._to_device(np.asarray(restored_ids, np.int32)),
-                self._to_device(self._pad_ids(suffix_ids)),
-                self._to_device(np.int32(len(suffix))),
-                self._to_device(np.int32(pos0)),
-                model=self.model,
-            )
+                        restored_pages=len(restored_ids),
+                        **self._scan_fields(toks.shape[1])):
+            r_ids = self._to_device(np.asarray(restored_ids, np.int32))
+            s_ids = self._to_device(self._pad_ids(suffix_ids))
+            s_real = self._to_device(np.int32(len(suffix)))
+            if self.state is None:
+                row_dev, self.k_pages, self.v_pages = _admit_fused_px(
+                    self.params, self.cfg, toks, restored,
+                    self.k_pages, self.v_pages, r_ids, s_ids, s_real,
+                    self._to_device(np.int32(pos0)),
+                    model=self.model,
+                )
+            else:
+                (row_dev, self.k_pages, self.v_pages, self.state,
+                 self.bstate) = _admit_fused_px_st(
+                    self.params, self.cfg, toks, restored, snap,
+                    self.k_pages, self.v_pages, self.state, self.bstate,
+                    r_ids, s_ids, s_real, self._slot_dev(slot_idx),
+                    model=self.model,
+                )
             return np.asarray(row_dev)
 
     def first_token_logits(self, prompt):
@@ -1198,9 +1512,11 @@ class ServingEngine:
         admission dispatches, without admitting anything: on a miss
         the cold program with every page id at the drop sentinel (the
         pool is untouched), on a hit probe + restore + the prefix
-        program, likewise with every id at the sentinel. Returns
-        (float32 row [vocab], hit pages). The engine must be idle, and
-        the caller on the thread that steps it."""
+        program, likewise with every id at the sentinel (and, for a
+        family with state, the state-pool row: no slot is written).
+        Returns (float32 row [vocab], hit pages): the depth that RAN.
+        The engine must be idle, and the caller on the thread that
+        steps it."""
         if self.queue or any(s is not None for s in self.slots):
             raise RuntimeError("first_token_logits needs an idle engine")
         prompt = [int(t) for t in prompt]
@@ -1213,13 +1529,13 @@ class ServingEngine:
             first_live = max(0, hit * page - window + 1) // page \
                 if window else 0
             try:
-                restored = self._restore(hit, digests, first_live)
+                restored, snap = self._restore(hit, digests, first_live)
             except InfiniStoreKeyNotFound:
                 hit = 0  # evicted between probe and restore
         if hit > 0:
             row = self._prefill_hit(
                 prompt[hit * page:], restored, first_live * page,
-                [self.sc.total_pages] * (hit - first_live), [])
+                [self.sc.total_pages] * (hit - first_live), [], snap)
         else:
             row = self._prefill_cold(prompt, self._pad_ids([]))
         return np.asarray(row, np.float32), hit
@@ -1294,7 +1610,10 @@ class ServingEngine:
         left the window. Keys hash prompt + generated tokens (page i's
         key depends only on tokens < (i+1)*page_size, so release-time
         and finish-time keys agree), so a future request whose prompt
-        extends this sequence hits these pages."""
+        extends this sequence hits these pages. A family with state
+        writes, behind the pages and before the sync, the slot's
+        boundary copy as the snapshot at the end of page n_full
+        (`_offload_snapshot`): pages and snapshot at ONE depth."""
         if (self.store is None or not self._store_ok
                 or not slot.work.req.cache):
             return
@@ -1315,13 +1634,15 @@ class ServingEngine:
         # page contents must be durable in the store BEFORE the pool
         # page is freed for reuse (and before the caller hears `done`).
         n = n_full - lo
-        L = self.cfg.n_layers
+        L = self.cfg.n_kv_layers
         page_ids = slot.page_ids[lo:n_full]
         c = min(self.sc.max_pages_per_seq,
                 max(1, OFFLOAD_CHUNK_BYTES // self._page_bytes))
         with self._span("istpu.cache.offload", slot.work.req.request_id,
-                        reason=reason, pages=n, bytes=n * self._page_bytes,
-                        padded_pages=0, puts=0) as f:
+                        reason=reason, pages=n,
+                        bytes=n * self._page_bytes + self._snapshot_bytes,
+                        padded_pages=0, puts=0,
+                        **self._snapshot_fields) as f:
 
             def gather(a):
                 # The chunk's ids, padded to a bucket with the scratch
@@ -1350,6 +1671,9 @@ class ServingEngine:
                     self._put_pages(keys, pages[:len(keys)])
                     f["puts"] += 1
                     flat = ahead
+                if self.state is not None:
+                    f["puts"] += self._offload_snapshot(slot,
+                                                        new_digests[-1])
                 with self._span("istpu.cache.offload_sync"):
                     self.store.conn.sync()
             except Exception as e:
@@ -1358,10 +1682,32 @@ class ServingEngine:
                 self._store_failed("offload", e)
                 return
         self.stats["offloaded_pages"] += n
+        self.stats["snapshots_written"] += self.state is not None
         own = self._own_digests
         own.update(dict.fromkeys(new_digests))
         while len(own) > OWN_DIGESTS:
             del own[next(iter(own))]
+
+    def _offload_snapshot(self, slot, digest):
+        """The slot's boundary copy to the store, keyed by `digest`
+        (of the last full page: the copy IS the state at its end), in
+        the offload's form: ONE gather program, then one device-to-
+        host transfer and one store batch per chunk of at most
+        OFFLOAD_CHUNK_BYTES, every transfer started before the first
+        is waited for. Returns the store batches made."""
+        row_bytes = self._snapshot_bytes // self.cfg.n_state_layers
+        c = max(1, OFFLOAD_CHUNK_BYTES // row_bytes)
+        with self._span("istpu.cache.state_out", slot.work.req.request_id,
+                        bytes=self._snapshot_bytes):
+            chunks = _gather_snapshot(self.cfg, self.bstate,
+                                      self._slot_dev(slot.index), c)
+            for flat in chunks:
+                flat.copy_to_host_async()
+            for i, flat in enumerate(chunks):
+                rows = to_host(flat).reshape(-1, self._snapshot_row)
+                self._put_pages(
+                    snapshot_keys(digest, i * c, i * c + len(rows)), rows)
+        return len(chunks)
 
     def _release(self, slot_idx, slot):
         # [0:released) already went back to the pool when those pages
@@ -1583,12 +1929,22 @@ class ServingEngine:
             return len(active)
 
         with self._span("istpu.model.decode", program="decode_fused"):
-            logits, nxt_dev, lens_next, self.k_pages, self.v_pages = (
-                _decode_fused(
-                    self.params, self.cfg, token_dev, lens_dev,
-                    self.k_pages, self.v_pages, rows_dev, model=self.model,
+            if self.state is None:
+                logits, nxt_dev, lens_next, self.k_pages, self.v_pages = (
+                    _decode_fused(
+                        self.params, self.cfg, token_dev, lens_dev,
+                        self.k_pages, self.v_pages, rows_dev,
+                        model=self.model,
+                    )
                 )
-            )
+            else:
+                (logits, nxt_dev, lens_next, self.k_pages, self.v_pages,
+                 self.state) = _decode_fused_st(
+                    self.params, self.cfg, token_dev, lens_dev,
+                    self.k_pages, self.v_pages, self.state, rows_dev,
+                    model=self.model,
+                )
+                self._copy_boundaries(active)
             nxt = np.asarray(nxt_dev)
         # Reusable next step iff every emitted token is the device's
         # argmax (greedy) — samplers/spec/finishes invalidate via key.
@@ -1607,6 +1963,22 @@ class ServingEngine:
             self.stats["decoded_tokens"] += 1
         self.stats["decode_steps"] += 1
         return len(active)
+
+    def _copy_boundaries(self, active):
+        """Behind a decode step of a family with state: the boundary
+        copy of every slot whose sequence the step brought to a page
+        edge (its state now is the state at the end of a full page,
+        which is where its stored pages can end). One small program a
+        crossing slot, dispatched and not waited for."""
+        page = self.cfg.page_size
+        for i, s in active:
+            if (s.seq_len + 1) % page == 0:
+                with self._span("istpu.cache.snapshot",
+                                s.work.req.request_id, slot=i,
+                                pos=s.seq_len + 1, reason="boundary"):
+                    self.bstate = _copy_boundary(
+                        self.state, self.bstate, self._slot_dev(i))
+                self.stats["boundary_copies"] += 1
 
     def _verify_batch(self, entries, m):
         """Shared multi-token verify plumbing: pack {slot_idx: tokens}

@@ -372,7 +372,7 @@ def test_restore_asks_in_the_order_the_offload_allocated(monkeypatch):
             return real(blocks, *a)
         monkeypatch.setattr(inner, "_pool_batch_view", spy)
         kp, vp = decoder.restored_to_pages(
-            cfg, eng._restore(n, eng._digests(prompt, n)))
+            cfg, eng._restore(n, eng._digests(prompt, n))[0])
         (blocks,) = seen
         assert len(blocks) == 2 * cfg.n_layers * n
         assert (blocks["pool_idx"] == blocks["pool_idx"][0]).all()
