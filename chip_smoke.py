@@ -473,6 +473,24 @@ def check_h2d_honest(conn, store, cfg, n_pages):
     check(zero_copy and np.array_equal(np.asarray(dev), pages),
           f"device_put of {pages.nbytes >> 10} KiB from the pinned pool "
           f"is complete when it returns (source overwritten after)")
+    # A read across put batches is copied once, run by run, into the
+    # store's staging buffer, which the NEXT such read reuses: the
+    # device copy of the first must not change under the second.
+    halves = [keys[:n_pages // 2], keys[n_pages // 2:]]
+    for ks, ps in zip(halves, np.split(pages, [n_pages // 2])):
+        store.put_kv_pages(ks, ps, sync=True)
+        store.put_kv_pages([ks[0] + "/spacer"], ps[:1], sync=True)
+    crossed = halves[1] + halves[0]
+    try:
+        first = store.get_kv_pages(crossed, shape, np.uint16)
+        runs = store.last_read["runs"]
+        store.get_kv_pages(crossed[::-1], shape, np.uint16)
+    finally:
+        conn.delete_keys(keys + [h[0] + "/spacer" for h in halves])
+    want = np.concatenate([pages[n_pages // 2:], pages[:n_pages // 2]])
+    check(runs >= 2 and np.array_equal(np.asarray(first), want),
+          f"a read of {runs} runs of the pool, staged and transferred, "
+          f"equals the pages written (staging reused after)")
 
 
 def check_stream_roundtrip(service_port, cfg):

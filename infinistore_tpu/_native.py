@@ -10,7 +10,9 @@ buffers viewed zero-copy as numpy structured arrays (the analogue of
 ``PYBIND11_NUMPY_DTYPE(remote_block_t)``, pybind.cpp:47).
 """
 
+import contextlib
 import ctypes as ct
+import fcntl
 import os
 import struct
 import subprocess
@@ -56,6 +58,28 @@ CALLBACK = ct.CFUNCTYPE(None, ct.c_uint32, ct.c_void_p)
 
 _build_lock = threading.Lock()
 _lib = None
+
+
+@contextlib.contextmanager
+def _process_lock():
+    """Exclusive flock on a lock file beside the library: `_build_lock`
+    stops this process's threads from building twice, this stops the
+    processes that import the package at once (pytest-xdist's workers,
+    a server child beside its parent) from running `make` into one file
+    while another loads it half written. Where the directory cannot be
+    written (an installed, read-only package) there is nothing to
+    build either, and the load goes ahead unlocked."""
+    try:
+        os.makedirs(os.path.dirname(_LIB_PATH), exist_ok=True)
+        fd = os.open(_LIB_PATH + ".lock", os.O_CREAT | os.O_RDWR, 0o666)
+    except OSError:
+        yield
+        return
+    try:
+        fcntl.flock(fd, fcntl.LOCK_EX)
+        yield
+    finally:
+        os.close(fd)  # drops the lock
 
 
 def _build_native():
@@ -417,18 +441,20 @@ def get_lib():
     with _build_lock:
         if _lib is not None:
             return _lib
-        if not os.path.exists(_LIB_PATH):
-            if "INFINISTORE_TPU_NATIVE_LIB" in os.environ:
-                # An explicit override names a specific build variant;
-                # auto-building would produce the DEFAULT library and
-                # still fail — fail fast with the actionable cause.
-                raise RuntimeError(
-                    f"INFINISTORE_TPU_NATIVE_LIB points at {_LIB_PATH}, "
-                    "which does not exist (build it first, e.g. "
-                    "`make -C native tsan|asan`)"
-                )
-            _build_native()
-        lib = ct.CDLL(_LIB_PATH)
+        # check -> build -> load, one process at a time
+        with _process_lock():
+            if not os.path.exists(_LIB_PATH):
+                if "INFINISTORE_TPU_NATIVE_LIB" in os.environ:
+                    # An explicit override names a specific build variant;
+                    # auto-building would produce the DEFAULT library and
+                    # still fail — fail fast with the actionable cause.
+                    raise RuntimeError(
+                        f"INFINISTORE_TPU_NATIVE_LIB points at {_LIB_PATH}, "
+                        "which does not exist (build it first, e.g. "
+                        "`make -C native tsan|asan`)"
+                    )
+                _build_native()
+            lib = ct.CDLL(_LIB_PATH)
         _decls(lib)
         _lib = lib
     return _lib

@@ -785,6 +785,9 @@ class ServingEngine:
             # hit pages this engine did not itself offload: another
             # engine over the same store wrote them
             "foreign_hit_pages": 0,
+            # restores' page reads: contiguous runs of the store's pool
+            # they spanned, bytes copied on the host (0 for one run)
+            "restore_runs": 0, "restore_copied_bytes": 0,
             "chunk_steps": 0, "burst_steps": 0, "prefetched_pages": 0,
             # admissions that returned for want of pool pages
             "admit_retries": 0,
@@ -1232,9 +1235,18 @@ class ServingEngine:
         # around get_kv_pages from outside times too.
         with self._span("istpu.cache.restore", pages=n,
                         bytes=n * self._page_bytes + self._snapshot_bytes,
-                        foreign_pages=foreign, **self._snapshot_fields):
+                        foreign_pages=foreign, **self._snapshot_fields) as f:
             pages = self._get_pages(keys, self.cfg.kv_page_shape(),
                                     self.cfg.jdtype)
+            # How the pages lay in the store's pool: the contiguous runs
+            # the read spanned and the bytes it copied on the host (0:
+            # one run, transferred from the pool itself). A store that
+            # does not say leaves the fields out.
+            read = getattr(self.store, "last_read", None)
+            if read is not None:
+                f.update(read)
+                self.stats["restore_runs"] += read["runs"]
+                self.stats["restore_copied_bytes"] += read["copied_bytes"]
             if self.state is None:
                 return pages, None
             # The snapshot's way in: its store call (store -> HBM); it

@@ -7,11 +7,15 @@ with ``cudaMemcpyAsync`` through IPC-shared device memory
 (infinistore.cpp:570-804). TPUs expose no device-pointer/IPC model, so the
 equivalent design is explicit host staging through the server's pool:
 
-- **get (store → TPU)**: pin the committed blocks, build a numpy view
-  directly over the mapped SHM pool, and ``jax.device_put`` from that
-  view — XLA's host-to-device DMA reads straight out of the server pool,
-  with no intermediate host copy. This is the moral equivalent of the
-  GPUDirect zero-copy read.
+- **get (store → TPU)**: pin the committed blocks and hand the pool's
+  bytes to ``jax.device_put``. Blocks that lie in the pool as ONE
+  contiguous run (what one put batch allocated) are a numpy view over
+  the mapped SHM pool — XLA's host-to-device DMA reads straight out of
+  the server pool, with no intermediate host copy, the moral equivalent
+  of the GPUDirect zero-copy read. A read that spans several runs (a
+  hit over more than one offload) is copied ONCE, one memcpy a run,
+  into a staging buffer the store keeps and reuses, and transferred
+  from there (``TpuKVStore._pool_batch_view``).
 - **put (TPU → store)**: device-to-host transfer (``np.asarray`` /
   ``copy_to_host_async``) followed by a one-sided memcpy into the
   allocated pool blocks + commit. One host-side copy, matching the
@@ -153,6 +157,32 @@ def _abort_uncommitted(conn, blocks, keys=None):
             pass
 
 
+# A read of more runs than this is gathered (np.take) instead of copied
+# run by run, where every offset is a multiple of a unit this large: a
+# gather pays per unit, a run copy per run.
+_GATHER_RUNS = 256
+_GATHER_UNIT = 4096
+_PAGE_ALIGN = 4096  # the staging buffer starts on a memory page
+
+
+def _gather_blocks(pools, pool_idx, offs, page_bytes, unit, dst):
+    """dst[i * page_bytes :][: page_bytes] = pools[pool_idx[i]][offs[i] :]
+    for every block i, by np.take: a pool as rows of `unit` bytes (every
+    offset and page_bytes are multiples of it), a block as page_bytes //
+    unit consecutive rows. One take for each stretch of blocks in the
+    same pool, which is one where the store has not grown."""
+    n = len(offs)
+    per = page_bytes // unit
+    rows = (offs // unit)[:, None] + np.arange(per)
+    dst = dst.reshape(n, per, unit)
+    turns = (np.flatnonzero(pool_idx[1:] != pool_idx[:-1]) + 1).tolist()
+    for a, b in zip([0, *turns], [*turns, n]):
+        pool = pools[int(pool_idx[a])]
+        table = pool[: pool.size // unit * unit].reshape(-1, unit)
+        # mode: "raise" would gather into a buffer and copy that out
+        np.take(table, rows[a:b], axis=0, out=dst[a:b], mode="clip")
+
+
 class TpuKVStore:
     """High-level KV-page interface over an :class:`InfinityConnection`.
 
@@ -170,6 +200,17 @@ class TpuKVStore:
         # else on the surface is signature-compatible (shm_connected is
         # False there, selecting the staged read path).
         self._sharded = hasattr(conn, "shard_of")
+        # Per calling thread: the staging buffer of a read that spans
+        # several runs of the pool, and what the last read did.
+        self._tls = threading.local()
+
+    @property
+    def last_read(self):
+        """What this thread's last SHM read of pages did: ``{"runs":
+        contiguous runs of the pool it spanned, "copied_bytes": bytes
+        copied on the host on their way out (0: one zero-copy view)}``.
+        None before the first."""
+        return getattr(self._tls, "last_read", None)
 
     def _write(self, cache, offsets, page_size, blocks, keys):
         if self._sharded:
@@ -279,8 +320,13 @@ class TpuKVStore:
 
     def get_kv_pages(self, keys, page_shape, dtype, device=None):
         """Fetch pages for ``keys``; returns a device array of shape
-        [len(keys), *page_shape]. SHM path: single device_put gathers all
-        pages straight from the pinned pool."""
+        [len(keys), *page_shape]. SHM path, one pin and ONE device_put:
+        keys whose blocks are one contiguous run of the pool (what one
+        put batch wrote, asked for in its order) transfer straight from
+        the pinned pool, zero host copies; a read across several runs is
+        copied once, run by run, into this store's reused staging buffer
+        and transferred from there (:meth:`_pool_batch_view`;
+        :attr:`last_read` says which it was)."""
         dtype = np.dtype(dtype)
         page_elems = int(np.prod(page_shape))
         page_bytes = page_elems * dtype.itemsize
@@ -305,8 +351,9 @@ class TpuKVStore:
         return jax.device_put(buf.view(dtype).reshape(n, *page_shape), device)
 
     def get_kv_pages_host(self, keys, page_shape, dtype):
-        """Fetch pages as a host numpy array ([len(keys), *page_shape]),
-        no device transfer: one copy out of the pinned pool (SHM) or the
+        """Fetch pages as a host numpy array ([len(keys), *page_shape])
+        the caller owns, no device transfer: one copy out of the pinned
+        pool, run by run, straight into the array returned (SHM) or the
         socket scatter (STREAM). For consumers that stage placement
         themselves (e.g. IciKVPool injection)."""
         dtype = np.dtype(dtype)
@@ -316,15 +363,14 @@ class TpuKVStore:
         if n == 0:
             return np.zeros((0, *page_shape), dtype=dtype)
         if self.conn.shm_connected:
+            out = np.empty(n * page_bytes, dtype=np.uint8)
             lease, blocks = self.conn.pin(keys)
             try:
-                stacked = self._pool_batch_view(
-                    blocks, n, page_bytes, dtype, page_shape
+                return self._pool_batch_view(
+                    blocks, n, page_bytes, dtype, page_shape, out=out
                 )
-                out = np.array(stacked, copy=True)  # own bytes pre-release
             finally:
                 self.conn.release(lease)
-            return out
         buf = np.empty(n * page_bytes, dtype=np.uint8)
         self.conn.read_cache(
             buf, [(k, i * page_bytes) for i, k in enumerate(keys)], page_bytes
@@ -375,8 +421,9 @@ class TpuKVStore:
             return jnp.zeros((0, *page_shape), dtype=dtype)
         block = kv_quant.packed_page_bytes(page_shape)
         if self.conn.shm_connected:
-            # Same zero-staging read as get_kv_pages: packed pages are
-            # viewed directly in the pinned server pool under a lease.
+            # Same read as get_kv_pages: packed pages are viewed in the
+            # pinned server pool (one run) or its staging copy (several)
+            # under a lease.
             lease, blocks = self.conn.pin(keys)
             try:
                 packed = self._pool_batch_view(
@@ -400,30 +447,77 @@ class TpuKVStore:
             scales = jax.device_put(scales, device)
         return kv_quant.dequantize_kv_pages(q, scales, jnp.dtype(dtype))
 
-    def _pool_batch_view(self, blocks, n, page_bytes, dtype, page_shape):
-        """[n, *page_shape] view/copy over the pinned pool. First-fit
-        allocation makes batch allocations mostly contiguous, so the
-        common case is ONE zero-copy view of the pool — XLA's host→device
-        DMA then reads straight out of the server pool with no host copy
-        at all. Non-contiguous batches fall back to per-page views +
-        one stack copy."""
+    def _pool_batch_view(self, blocks, n, page_bytes, dtype, page_shape,
+                         out=None):
+        """The pinned ``blocks`` (what ``conn.pin`` returned for n keys)
+        as one [n, *page_shape] array, valid until the lease is released.
+
+        The blocks are split into contiguous RUNS of one pool, found
+        vectorised (a break wherever the pool changes or an offset is
+        not its predecessor's plus page_bytes); nothing here costs
+        Python work per block. First-fit allocation lays one put batch
+        down as one run, so:
+
+        - one run, no ``out``: ONE zero-copy view of the pool — XLA's
+          host→device DMA then reads straight out of the server pool;
+        - several runs (a read across put batches), or ``out`` given:
+          each run is copied once, one slice assignment (a memcpy,
+          outside the GIL), into ``out`` or else into this thread's
+          staging buffer, which grows geometrically to the largest read
+          seen, is touched when it grows and is reused by the next read:
+          the caller is done with it before then (_device_put_owned
+          returns with the transfer complete, and copies on a CPU
+          target);
+        - very many short runs (a pool fragmented down to blocks): one
+          C-level gather a pool (np.take over the pool as rows of the
+          unit every offset is a multiple of) instead of a copy a run.
+
+        ``last_read`` records the runs and the bytes copied."""
         pool_idx = blocks["pool_idx"]
-        offs = blocks["offset"]
-        if n > 0 and (pool_idx == pool_idx[0]).all():
-            base = int(offs[0])
-            expect = base + np.arange(n, dtype=np.uint64) * page_bytes
-            if (offs == expect).all():
-                pool = self.conn.pool_view(int(pool_idx[0]))
-                flat = pool[base : base + n * page_bytes]
-                return flat.view(dtype).reshape(n, *page_shape)
-        views = []
-        for i in range(n):
-            pool = self.conn.pool_view(int(pool_idx[i]))
-            off = int(offs[i])
-            views.append(
-                pool[off : off + page_bytes].view(dtype).reshape(page_shape)
-            )
-        return np.stack(views)
+        offs = blocks["offset"].astype(np.int64)
+        cuts = np.flatnonzero(
+            (pool_idx[1:] != pool_idx[:-1])
+            | (offs[1:] != offs[:-1] + page_bytes)
+        ) + 1
+        n_runs = len(cuts) + 1
+        copied = nbytes = n * page_bytes
+        if n_runs == 1 and out is None:
+            pool = self.conn.pool_view(int(pool_idx[0]))
+            dst = pool[offs[0] : offs[0] + nbytes]
+            copied = 0
+        else:
+            dst = self._staging(nbytes) if out is None else out
+            pools = {
+                int(p): self.conn.pool_view(int(p))
+                for p in np.unique(pool_idx)
+            }
+            if n_runs > _GATHER_RUNS and (
+                unit := int(np.gcd.reduce(offs, initial=page_bytes))
+            ) >= _GATHER_UNIT:
+                _gather_blocks(pools, pool_idx, offs, page_bytes, unit, dst)
+            else:
+                lo = np.concatenate(([0], cuts))
+                lens = (np.diff(lo, append=n) * page_bytes).tolist()
+                srcs = offs[lo].tolist()
+                at = 0
+                for p, src, ln in zip(pool_idx[lo].tolist(), srcs, lens):
+                    dst[at : at + ln] = pools[p][src : src + ln]
+                    at += ln
+        self._tls.last_read = {"runs": n_runs, "copied_bytes": copied}
+        return dst.view(dtype).reshape(n, *page_shape)
+
+    def _staging(self, nbytes):
+        """This thread's staging buffer, at least ``nbytes`` long: grown
+        to twice its size or the read's, whichever is larger, and written
+        once when it grows so that no read pays its first touch."""
+        buf = getattr(self._tls, "staging", None)
+        if buf is None or buf.size < nbytes:
+            size = max(nbytes, 2 * (0 if buf is None else buf.size))
+            raw = np.empty(size + _PAGE_ALIGN, dtype=np.uint8)
+            skip = -raw.ctypes.data % _PAGE_ALIGN
+            buf = self._tls.staging = raw[skip : skip + size]
+            buf.fill(0)
+        return buf[:nbytes]
 
     def prefetch(self, keys):
         """Advisory fire-and-forget promotion kick (OP_PREFETCH) for

@@ -140,9 +140,17 @@ def test_hit_span_tree(params, cfg, shm_conn):
     # restore: bytes are pages x the bytes of one page over every layer
     # and both kinds, and its transfer is one h2d of as many bytes.
     page_bytes = 2 * cfg.n_layers * cfg.kv_page_bytes()
-    # the engine offloaded these pages itself: none is foreign
-    assert restore.fields == {"pages": hit, "bytes": hit * page_bytes,
-                              "foreign_pages": 0}
+    # the engine offloaded these pages itself: none is foreign. How
+    # they lay in the store's pool is the store's to say: one run is
+    # transferred from the pool itself, more are copied once.
+    runs = restore.fields["runs"]
+    assert runs >= 1
+    assert restore.fields == {
+        "pages": hit, "bytes": hit * page_bytes, "foreign_pages": 0,
+        "runs": runs, "copied_bytes": hit * page_bytes * (runs > 1)}
+    assert eng.stats["restore_runs"] == runs
+    assert eng.stats["restore_copied_bytes"] \
+        == restore.fields["copied_bytes"]
     assert admit.fields["foreign_pages"] == 0
     assert eng.stats["foreign_hit_pages"] == 0
     (h2d,) = _children(spans, restore)
@@ -156,6 +164,71 @@ def test_hit_span_tree(params, cfg, shm_conn):
     assert not _children(spans, prefill)
     assert not _named(spans, "istpu.cache.to_kv")
     assert not _named(spans, "istpu.cache.pool_write")
+
+
+def test_a_hit_over_two_offloads_is_two_runs_and_one_copy(params, cfg,
+                                                          shm_conn):
+    """Turn 3 restores what turn 1's finish and turn 2's finish wrote,
+    with another session's offload between them in the pool: at least
+    two runs, every byte copied once on the host, the same span tree;
+    the counters add the restores up."""
+    eng = _engine(params, cfg, shm_conn, "spans-hit-two-offloads")
+    t1 = _prompt(40, 4 * PAGE)
+    out1 = eng.run([Request("a1", t1, max_new_tokens=PAGE)])["a1"]
+    eng.run([Request("b1", _prompt(41, 4 * PAGE), max_new_tokens=PAGE)])
+    t2 = t1 + out1 + _prompt(42, PAGE)
+    spans2 = _run(eng, Request("a2", t2, max_new_tokens=PAGE))
+    out2 = eng.outputs["a2"]
+    spans3 = _run(eng, Request("a3", t2 + out2 + _prompt(43, 3),
+                               max_new_tokens=2))
+    page_bytes = 2 * cfg.n_layers * cfg.kv_page_bytes()
+    total = {"runs": 0, "copied_bytes": 0}
+    for spans, want_hit, min_runs in ((spans2, 4, 1), (spans3, 6, 2)):
+        (admit,) = _named(spans, "istpu.sched.admit")
+        assert [k.name for k in _children(spans, admit)] == [
+            "istpu.cache.probe", "istpu.cache.restore",
+            "istpu.model.prefill"]
+        (restore,) = _named(spans, "istpu.cache.restore")
+        f = restore.fields
+        assert f["pages"] == admit.fields["hit_pages"] == want_hit
+        assert f["runs"] >= min_runs
+        assert f["copied_bytes"] == (f["bytes"] if f["runs"] > 1 else 0)
+        assert f["bytes"] == want_hit * page_bytes
+        (h2d,) = _children(spans, restore)
+        assert h2d.name == "istpu.xfer.h2d"
+        for k in total:
+            total[k] += f[k]
+    assert total["copied_bytes"] > 0
+    assert eng.stats["restore_runs"] == total["runs"]
+    assert eng.stats["restore_copied_bytes"] == total["copied_bytes"]
+
+
+def test_a_store_that_does_not_say_leaves_the_fields_out(params, cfg,
+                                                         shm_conn):
+    """`runs` and `copied_bytes` are what the store's `last_read` says;
+    a store without it (a double, a wrapper of the engine's surface
+    alone) restores as before."""
+    inner = TpuKVStore(shm_conn)
+
+    class Plain:
+        conn = inner.conn
+        cached_prefix_len = staticmethod(inner.cached_prefix_len)
+        get_kv_pages = staticmethod(inner.get_kv_pages)
+        put_kv_pages = staticmethod(inner.put_kv_pages)
+        prefetch = staticmethod(inner.prefetch)
+
+    eng = ServingEngine(params, cfg, ServingConfig(
+        model_id="spans-plain-store", max_slots=2, total_pages=64),
+        store=Plain())
+    first = _prompt(44, 4 * PAGE)
+    out = eng.run([Request("p0", first, max_new_tokens=PAGE)])["p0"]
+    spans = _run(eng, Request("p1", first + out + _prompt(45, 5),
+                              max_new_tokens=2))
+    (restore,) = _named(spans, "istpu.cache.restore")
+    assert set(restore.fields) == {"pages", "bytes", "foreign_pages"}
+    assert restore.fields["pages"] == 4
+    assert eng.stats["restore_runs"] == 0
+    assert eng.stats["store_errors"] == 0
 
 
 def test_windowed_hit_restores_from_first_live(cfg, shm_conn):
@@ -300,14 +373,17 @@ def test_an_idle_engine_ticks_every_idle_tick_s(params, cfg, monkeypatch):
     work, sends the device one trivial program an IDLE_TICK_S and
     nothing between."""
     eng = _engine(params, cfg, None, "spans-idle")
-    now = [eng._ticked]
+    # Times a float holds exactly: a sum of 0.002s may fall a rounding
+    # short of the period on the pass that should reach it.
+    now = [1024.0]
+    eng._ticked = now[0]
     monkeypatch.setattr(serving.time, "monotonic", lambda: now[0])
     ticks = []
     real = serving._tick
     monkeypatch.setattr(serving, "_tick",
                         lambda x: (ticks.append(now[0]), real(x))[1])
-    for _ in range(50):  # a pass every 2 ms for 100 ms: under the period
-        now[0] += 0.002
+    for _ in range(52):  # a pass every 2 ms for 102 ms: over the period once
+        now[0] += 2.0 ** -9
         eng.idle()
         if now[0] - eng._ticked < serving.IDLE_TICK_S - 0.003:
             assert len(ticks) <= 1

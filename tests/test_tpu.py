@@ -172,3 +172,273 @@ def test_get_array_to_explicit_device(store, rng):
     y = store.get_array(k, (32,), np.float32, device=dev)
     assert list(y.devices())[0] == dev
     assert np.array_equal(np.asarray(y), np.asarray(x))
+
+
+# -- the SHM read: contiguous runs of the pool, not blocks -----------------
+BLOCK = 16 << 10  # the store's allocation unit below: one page, one block
+
+
+@pytest.fixture
+def shm_store(request):
+    """A TpuKVStore over a store of its own, so that what one put batch
+    allocates is one run of the pool whatever ran before: one pool of
+    32 MB, or (parametrised with a size in MB) pools of that size which
+    the store adds as it fills."""
+    from infinistore_tpu import (
+        TYPE_SHM, ClientConfig, InfinityConnection, InfiniStoreServer,
+        ServerConfig,
+    )
+
+    mb = getattr(request, "param", None)
+    srv = InfiniStoreServer(ServerConfig(
+        service_port=0, minimal_allocate_size=16,
+        prealloc_size=(mb or 32) / 1024, auto_increase=mb is not None,
+        extend_size=(mb or 0) / 1024))
+    srv.start()
+    c = InfinityConnection(ClientConfig(
+        host_addr="127.0.0.1", service_port=srv.service_port,
+        connection_type=TYPE_SHM))
+    c.connect()
+    yield tpu.TpuKVStore(c)
+    c.close()
+    srv.stop()
+
+
+class _Getter:
+    """One of the three getters with the pages it is given to write:
+    `page_bytes` is what one page takes in the pool."""
+
+    def __init__(self, name, page_shape, dtype, page_bytes):
+        self.name = name
+        self.page_shape = page_shape
+        self.dtype = dtype
+        self.page_bytes = page_bytes
+
+    def __repr__(self):
+        return self.name
+
+    def pages(self, rng, n):
+        return rng.standard_normal((n, *self.page_shape)).astype(self.dtype)
+
+    def put(self, store, keys, pages):
+        put = (store.put_kv_pages_quantized if self.name == "quantized"
+               else store.put_kv_pages)
+        put(keys, pages, sync=True)
+
+    def get(self, store, keys):
+        get = {"device": store.get_kv_pages, "host": store.get_kv_pages_host,
+               "quantized": store.get_kv_pages_quantized}[self.name]
+        return np.asarray(get(keys, self.page_shape, self.dtype))
+
+
+def _getters(whole_blocks=True):
+    """Pages of exactly one block (or, for the gather's units, of three
+    blocks and of 1000 bytes): float32 [16, 8, 32] is 16 KB, and a
+    packed int8 page of [16, 8, 124] is 15,872 + 512 bytes of scales."""
+    if whole_blocks:
+        return [_Getter("device", (16, 8, 32), np.float32, BLOCK),
+                _Getter("host", (16, 8, 32), np.float32, BLOCK),
+                _Getter("quantized", (16, 8, 124), np.float32, BLOCK)]
+    return [_Getter("device", (48, 8, 32), np.float32, 3 * BLOCK),
+            _Getter("host", (250,), np.float32, 1000)]
+
+
+def _three_batches(store, g, rng, per=4):
+    """Three put batches of `per` pages with a spacer key after each,
+    the spacers deleted: returns (keys, pages) batch after batch, and
+    the order of a read that crosses the batches."""
+    keys, pages = [], []
+    for b in range(3):
+        ks = [key() for _ in range(per)]
+        ps = g.pages(rng, per)
+        g.put(store, ks, ps)
+        keys += ks
+        pages.append(ps)
+        spacer = key()
+        store.put_kv_pages([spacer], np.zeros((1, BLOCK), np.uint8),
+                           sync=True)
+        store.conn.delete_keys([spacer])
+    order = [b * per + i for i in range(per) for b in (2, 0, 1)]
+    return keys, np.concatenate(pages), order
+
+
+def _runs_by_hand(conn, keys, page_bytes):
+    """(contiguous runs, distinct pools) of `keys` in the pool, block by
+    block."""
+    lease, blocks = conn.pin(keys)
+    conn.release(lease)
+    runs = 1
+    for a, b in zip(blocks[:-1], blocks[1:]):
+        runs += (a["pool_idx"] != b["pool_idx"]
+                 or int(a["offset"]) + page_bytes != int(b["offset"]))
+    return runs, len(set(blocks["pool_idx"].tolist()))
+
+
+def _count_pool_views(monkeypatch, conn):
+    calls = []
+    real = conn.pool_view
+    monkeypatch.setattr(conn, "pool_view",
+                        lambda idx: (calls.append(idx), real(idx))[1])
+    return calls
+
+
+@pytest.mark.parametrize("g", _getters(), ids=repr)
+def test_a_read_across_put_batches_is_bit_exact(shm_store, rng, g,
+                                                monkeypatch):
+    """Pages of three put batches read in an order that crosses them
+    come back bit for bit, by run and not by block: the pool is viewed
+    at most once a pool, and `last_read` says what the read did."""
+    store = shm_store
+    keys, pages, order = _three_batches(store, g, rng)
+    # What each batch reads back as by itself, one run each: for the
+    # plain getters the pages as written, for the int8 wire what they
+    # dequantize to.
+    alone = np.concatenate([g.get(store, keys[i:i + 4])
+                            for i in range(0, 12, 4)])
+    if g.name != "quantized":
+        assert np.array_equal(alone, pages)
+    for i in range(0, 12, 4):
+        assert _runs_by_hand(store.conn, keys[i:i + 4], g.page_bytes) \
+            == (1, 1)
+    g.get(store, keys[:4])
+    assert store.last_read == {
+        "runs": 1, "copied_bytes": 4 * BLOCK * (g.name == "host")}
+
+    crossing = [keys[i] for i in order]
+    runs, pools = _runs_by_hand(store.conn, crossing, g.page_bytes)
+    assert runs >= 2
+    views = _count_pool_views(monkeypatch, store.conn)
+    got = g.get(store, crossing)
+    assert np.array_equal(got, alone[order])
+    assert len(views) <= pools
+    assert store.last_read == {"runs": runs, "copied_bytes": 12 * BLOCK}
+
+
+@pytest.mark.parametrize("g", _getters(), ids=repr)
+def test_what_a_read_hands_to_the_transfer(shm_store, rng, g, monkeypatch):
+    """One put batch is handed over as the pool's own memory; a read
+    across batches as this store's staging buffer, the same one on a
+    second, smaller read. get_kv_pages_host returns memory the caller
+    owns and stages nothing."""
+    store = shm_store
+    keys, _, order = _three_batches(store, g, rng)
+    handed = []
+    real = tpu._device_put_owned
+    monkeypatch.setattr(
+        tpu, "_device_put_owned",
+        lambda view, device: (handed.append(view), real(view, device))[1])
+    pool = store.conn.pool_view(0)
+    first = g.get(store, keys[:4])
+    crossed = g.get(store, [keys[i] for i in order])
+    fewer = g.get(store, [keys[i] for i in order[:5]])
+    if g.name == "host":
+        assert not handed and not hasattr(store._tls, "staging")
+        for out in (first, crossed, fewer):
+            assert not np.shares_memory(out, pool)
+        return
+    one, across, again = handed
+    assert np.shares_memory(one, pool)
+    assert not np.shares_memory(across, pool)
+    staging = store._tls.staging
+    assert np.shares_memory(across, staging)
+    assert store._tls.staging is staging and staging.size == 12 * BLOCK
+    assert np.shares_memory(again, staging)
+    assert not np.shares_memory(again, pool)
+
+
+@pytest.mark.parametrize("g", _getters() + _getters(whole_blocks=False),
+                         ids=lambda g: f"{g}-{g.page_bytes}")
+def test_a_fragmented_pool_is_gathered(shm_store, rng, g, monkeypatch):
+    """Every second page of one batch: as many runs as pages. Beyond
+    _GATHER_RUNS runs the read is one np.take a pool, over rows of the
+    unit all offsets share (a block here, also where a page is three),
+    and a run by run copy where that unit is too small to pay; bit for
+    bit either way."""
+    store = shm_store
+    # one block ahead of them, so that pages of three start off their size
+    store.put_kv_pages([key()], np.zeros((1, BLOCK), np.uint8), sync=True)
+    n = 12
+    keys = [key() for _ in range(n)]
+    pages = g.pages(rng, n)
+    g.put(store, keys, pages)
+    whole = g.get(store, keys)
+    if g.name != "quantized":
+        assert np.array_equal(whole, pages)
+    monkeypatch.setattr(tpu, "_GATHER_RUNS", 4)
+    gathers = []
+    real = tpu._gather_blocks
+    monkeypatch.setattr(
+        tpu, "_gather_blocks",
+        lambda *a: (gathers.append(a[4]), real(*a))[1])
+    views = _count_pool_views(monkeypatch, store.conn)
+    odd = list(range(1, n, 2)) + [0]
+    got = g.get(store, [keys[i] for i in odd])
+    assert np.array_equal(got, whole[odd])
+    assert store.last_read["runs"] == len(odd)
+    assert len(views) == 1
+    # 1000-byte pages lie a block apart: the unit is gcd(1000, 16384).
+    assert gathers == ([BLOCK] if g.page_bytes >= BLOCK else [])
+    # A few runs are copied run by run, not gathered.
+    del gathers[:]
+    got = g.get(store, [keys[i] for i in (4, 5, 6, 1, 2)])
+    assert np.array_equal(got, whole[[4, 5, 6, 1, 2]])
+    assert not gathers and store.last_read["runs"] == (
+        2 if g.page_bytes >= BLOCK else 5)
+
+
+@pytest.mark.parametrize("g", _getters(), ids=repr)
+def test_two_threads_read_through_one_store(shm_store, rng, g):
+    """Each thread's read across batches brings its own pages: the
+    staging buffer and last_read are the calling thread's."""
+    import threading
+
+    store = shm_store
+    sets = []
+    for _ in range(2):
+        keys, _, order = _three_batches(store, g, rng)
+        crossing = [keys[i] for i in order]
+        sets.append((crossing, g.get(store, crossing)))
+    gate = threading.Barrier(2)
+    wrong = []
+
+    def reader(mine, k):
+        crossing, want = mine
+        crossing, want = crossing[:k], want[:k]
+        gate.wait()
+        for _ in range(20):
+            if not np.array_equal(g.get(store, crossing), want):
+                wrong.append(k)
+            if store.last_read["copied_bytes"] != k * BLOCK:
+                wrong.append(("last_read", k))
+
+    threads = [threading.Thread(target=reader, args=(sets[i], 12 - 5 * i))
+               for i in range(2)]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join()
+    assert not wrong
+
+
+@pytest.mark.parametrize("shm_store", [1], indirect=True)
+@pytest.mark.parametrize("gather_runs", [1, 1000])
+def test_a_read_over_two_pools(shm_store, rng, monkeypatch, gather_runs):
+    """100 pages into pools of 64 blocks: a read of all of them is two
+    runs, and every second one gathers or copies out of both pools,
+    each viewed once."""
+    store = shm_store
+    pages = rng.integers(0, 255, (100, BLOCK), dtype=np.uint8)
+    keys = [key() for _ in range(100)]
+    for i in range(0, 100, 20):
+        store.put_kv_pages(keys[i:i + 20], pages[i:i + 20], sync=True)
+    assert _runs_by_hand(store.conn, keys, BLOCK) == (2, 2)
+    monkeypatch.setattr(tpu, "_GATHER_RUNS", gather_runs)
+    views = _count_pool_views(monkeypatch, store.conn)
+    out = store.get_kv_pages(keys, (BLOCK,), np.uint8)
+    assert np.array_equal(np.asarray(out), pages)
+    assert store.last_read["runs"] == 2 and len(views) == 2
+    even = list(range(0, 100, 2))
+    out = store.get_kv_pages_host([keys[i] for i in even], (BLOCK,),
+                                  np.uint8)
+    assert np.array_equal(out, pages[even])
+    assert store.last_read["runs"] == 50 and len(views) == 4
