@@ -10,12 +10,14 @@ which families exist. What a layer's MIXER is the config says per layer
 (`cfg.layer_kinds`): attention over K and V pages, or the Mamba-2 mixer
 over a recurrent state (ops/ssm.py).
 
-`block(layer, x, cfg, valid)` maps the residual stream [b, s, d] to the
-block's output [b, s, d] and the family's auxiliary loss for that layer
-(None where it has none). `valid` ([b, s] bool or None) marks the rows
-that hold a real token: a block whose tokens compete for something (MoE
-expert capacity) keeps the others out, a block that treats tokens
-independently ignores it.
+`block(layer, x, cfg, valid, h_attn)` maps the residual stream [b, s, d]
+to the block's output [b, s, d] and the family's auxiliary loss for that
+layer (None where it has none). `valid` ([b, s] bool or None) marks the
+rows that hold a real token: a block whose tokens compete for something
+(MoE expert capacity) keeps the others out, a block that treats tokens
+independently ignores it. `h_attn` is the normalised input the layer's
+attention block read (None after a state layer): a family whose router
+sits before attention routes on it, the others ignore it.
 
 The attention side is GQA + RoPE over a paged KV cache: bf16 params
 with fp32 softmax accumulation, static shapes everywhere (page budgets
@@ -117,7 +119,16 @@ def proj(h, layer, w, b_, shape=None):
 #   moe.combine in models/moe.py), pool.update, lm_head.
 
 
-def qkv(layer, x, cfg, positions):
+def qkv(layer, x, cfg, positions, rotate=None):
+    """q, k, v of one attention layer; `rotate` (None: cfg.use_rope)
+    says whether this layer applies rotary positions."""
+    return _qkv(layer, x, cfg, positions, rotate)[:3]
+
+
+def _qkv(layer, x, cfg, positions, rotate=None):
+    """... and `h`, the normalised input they were projected from,
+    which a family's feed-forward block may read too (a router placed
+    before attention)."""
     b = x.shape[0]
     s = x.shape[1]
     with jax.named_scope("attn.qkv"):
@@ -130,11 +141,11 @@ def qkv(layer, x, cfg, positions):
             # with a softmax scale of its own folds the ratio into q.
             q = q * jnp.asarray(cfg.attn_scale * cfg.head_dim ** 0.5,
                                 q.dtype)
-    if cfg.use_rope:
+    if cfg.use_rope if rotate is None else rotate:
         with jax.named_scope("attn.rope"):
             q = rope(q, positions, cfg.rope_theta, cfg.rope_scaling)
             k = rope(k, positions, cfg.rope_theta, cfg.rope_scaling)
-    return q, k, v
+    return q, k, v, h
 
 
 def pack_heads(cfg, q, k, v):
@@ -237,6 +248,39 @@ def residual(cfg, x, out):
 # recurrent state (`h` [b, H, P, N] float32 and the convolution's tail
 # [b, K-1, C]), state index = its rank among the state layers. Every
 # layer ends in the family's feed-forward `block`.
+#
+# An attention layer also has a band (`cfg.layer_windows`: 0 = full
+# causal attention, w = the last w positions) and may or may not rotate
+# (`cfg.layer_ropes`); `LlamaConfig.window` and `use_rope` are the case
+# of one value for every layer. A model whose attention layers are of
+# BOTH kinds (full and banded: `cfg.two_kinds`) keeps two page pools,
+# because the two kinds of page live differently long: the full layers'
+# under the page table, the banded layers' under a short table a
+# sequence that holds the band alone (`attn_layers`; serving.py has the
+# cache manager's side).
+
+
+def attn_layers(cfg):
+    """Per attention layer, in model order: (band, rotates, pool,
+    layer of that pool). pool is "full" (the page pools every family
+    has) or "window" (the second pair of pools of a model with both
+    kinds, whose banded layers are held there)."""
+    out = []
+    n = {"full": 0, "window": 0}
+    for kind, band, rotates in zip(cfg.layer_kinds, cfg.layer_windows,
+                                   cfg.layer_ropes):
+        if kind != "attention":
+            continue
+        pool = "window" if cfg.two_kinds and band else "full"
+        out.append((band, rotates, pool, n[pool]))
+        n[pool] += 1
+    return out
+
+
+def _kernel_scope(cfg, pool):
+    """attn.kernel, split by kind of layer only where a model has
+    both."""
+    return f"attn.kernel.{pool}" if cfg.two_kinds else "attn.kernel"
 
 
 def _ssm_project(layer, x, cfg):
@@ -356,14 +400,18 @@ def forward_stack(block, params, cfg, tokens, prefix_kvs=None, pos0=0,
     prefix to the in-window tail pages, whose KV was roped at absolute
     positions — the band mask itself needs no shift because it depends
     only on RELATIVE (query - key) distance, which local indices
-    preserve.
+    preserve. The layers' prefixes may differ in length (a banded
+    layer's is the tail its band needs): P is the longest, each
+    shorter one is its layer's last positions before the suffix.
 
     `state`: per state layer (h, conv tail) of the prefix the suffix
     continues (None: position 0); `s_real`: how many of the seq
     positions are real tokens (None: all; the others must lie in the
     last page and may not advance a recurrence)."""
     b, s = tokens.shape
-    prefix_len = 0 if prefix_kvs is None else prefix_kvs[0][0].shape[1]
+    prefix_len = 0 if prefix_kvs is None else max(
+        k.shape[1] for k, _ in prefix_kvs)
+    spec = attn_layers(cfg)
     x = embed(params, tokens, cfg)
     positions = jnp.broadcast_to(
         pos0 + prefix_len + jnp.arange(s)[None], (b, s)
@@ -372,6 +420,7 @@ def forward_stack(block, params, cfg, tokens, prefix_kvs=None, pos0=0,
     auxes = []
     states = []
     for layer, kind in zip(params["layers"], cfg.layer_kinds):
+        h_attn = None
         if kind == "mamba":
             st = state[len(states)] if state is not None \
                 else ssm_zero_state(cfg, b)
@@ -380,7 +429,8 @@ def forward_stack(block, params, cfg, tokens, prefix_kvs=None, pos0=0,
             x = residual(cfg, x, out)
             states.append(st)
         else:
-            q, k, v = qkv(layer, x, cfg, positions)
+            band, rotates, pool, _ = spec[len(kvs)]
+            q, k, v, h_attn = _qkv(layer, x, cfg, positions, rotates)
             if prefix_kvs is None:
                 k_full, v_full = k, v
             else:
@@ -390,12 +440,12 @@ def forward_stack(block, params, cfg, tokens, prefix_kvs=None, pos0=0,
             # Pallas flash kernel on TPU (O(S) memory; speed against the
             # XLA path not measured), XLA path elsewhere. kv may be
             # longer than q — the causal diagonal shifts by the prefix.
-            with jax.named_scope("attn.kernel"):
+            with jax.named_scope(_kernel_scope(cfg, pool)):
                 attn = flash_prefill(q, k_full, v_full, causal=True,
-                                     window=cfg.window)
+                                     window=band)
             x = residual(cfg, x, attn_out(layer, attn.reshape(b, s, -1)))
             kvs.append((k, v))
-        out, aux = block(layer, x, cfg, None)
+        out, aux = block(layer, x, cfg, None, h_attn)
         x = residual(cfg, x, out)
         auxes.append(aux)
     x = rms_norm(x, params["final_ln"], cfg.norm_eps, cfg.norm_plus_one)
@@ -406,7 +456,7 @@ def forward_stack(block, params, cfg, tokens, prefix_kvs=None, pos0=0,
 
 
 def decode_step(block, params, cfg, token, seq_lens, k_pages, v_pages,
-                page_table, state=None):
+                page_table, state=None, win=None):
     """One decode step over paged KV.
 
     token:      [batch] int32 — current input token
@@ -416,6 +466,13 @@ def decode_step(block, params, cfg, token, seq_lens, k_pages, v_pages,
     state:      for a family with state layers, {"h": [...], "conv":
                 [...]}: per state layer the batch's recurrent state
                 and convolution tail, row = slot
+    win:        for a model with full AND banded attention layers
+                (`cfg.two_kinds`), the banded layers' cache: (k pool,
+                v pool [n_window_layers, n_pages, page, n_kv, hd],
+                short table [batch, entries] int32, base [batch] int32:
+                the absolute position of each row's first entry, a
+                page multiple). k_pages/v_pages then hold the full
+                layers alone.
 
     Returns (logits [batch, vocab] fp32, k_pages, v_pages): the pools
     it was given with, per attention layer, the new token's K and V
@@ -425,25 +482,39 @@ def decode_step(block, params, cfg, token, seq_lens, k_pages, v_pages,
     inside them, and nothing the size of a layer is sliced out or
     stacked back — so a caller that donates them (the engine's fused
     programs) updates them in place. With `state`, a fourth element:
-    the state after this token, in the form it came.
+    the state after this token, in the form it came. With `win`, the
+    banded layers' two pools follow, updated the same way: a banded
+    layer's kernel call walks the short table with lengths counted
+    from its base (keys were rotated at their absolute positions
+    before they were cached, and the band is relative).
     """
     b = token.shape[0]
     x = embed(params, token[:, None], cfg)  # [b, 1, d]
     positions = seq_lens[:, None]  # current position
-    page_idx_in_seq = seq_lens // cfg.page_size
-    target_page = jnp.take_along_axis(
-        page_table, page_idx_in_seq[:, None], axis=1
-    )[:, 0]
-    slot = seq_lens % cfg.page_size
+
+    def place(table, lens):
+        page = jnp.take_along_axis(
+            table, (lens // cfg.page_size)[:, None], axis=1)[:, 0]
+        return table, lens, page, lens % cfg.page_size
+
+    pools = {"full": [k_pages, v_pages, *place(page_table, seq_lens)]}
+    if win is not None:
+        wk, wv, wtable, wbase = win
+        # inactive rows (seq_lens 0) stay at 0: entry 0 of an empty
+        # short table is the banded pools' scratch page
+        pools["window"] = [wk, wv, *place(
+            wtable, jnp.maximum(seq_lens - wbase, 0))]
     # Slots with an empty cache are the engine's inactive rows; `block`
     # may keep their garbage tokens out of whatever its tokens compete
     # for (best-effort: a previously-active slot's stale row still
     # counts as valid).
     valid = (seq_lens > 0)[:, None]  # [b, 1]
 
+    spec = attn_layers(cfg)
     li = mi = 0  # rank among the attention / the state layers
     hs, convs = [], []
     for layer, kind in zip(params["layers"], cfg.layer_kinds):
+        h_attn = None
         if kind == "mamba":
             out, (h, conv) = ssm_mixer_step(
                 layer, x, cfg, (state["h"][mi], state["conv"][mi]))
@@ -452,30 +523,34 @@ def decode_step(block, params, cfg, token, seq_lens, k_pages, v_pages,
             convs.append(conv)
             mi += 1
         else:
-            q, k, v = qkv(layer, x, cfg, positions)
+            band, rotates, pool, pl = spec[li]
+            q, k, v, h_attn = _qkv(layer, x, cfg, positions, rotates)
             if cfg.kv_pack > 1:
                 q, k, v = pack_heads(cfg, q, k, v)
+            held = pools[pool]
+            kp, vp, table, lens, target_page, slot = held
             with jax.named_scope("pool.update"):
-                k_pages = scatter_kv_to_pages(k_pages, k, target_page, slot,
-                                              layer=li)
-                v_pages = scatter_kv_to_pages(v_pages, v, target_page, slot,
-                                              layer=li)
-            with jax.named_scope("attn.kernel"):
+                kp = scatter_kv_to_pages(kp, k, target_page, slot, layer=pl)
+                vp = scatter_kv_to_pages(vp, v, target_page, slot, layer=pl)
+            held[0], held[1] = kp, vp
+            with jax.named_scope(_kernel_scope(cfg, pool)):
                 attn = paged_decode_attention(
-                    q[:, 0], k_pages, v_pages, page_table, seq_lens + 1,
-                    window=cfg.window, layer=li
+                    q[:, 0], kp, vp, table, lens + 1, window=band, layer=pl
                 )
                 if cfg.kv_pack > 1:
                     attn = unpack_heads(cfg, attn)
             x = residual(cfg, x, attn_out(layer, attn.reshape(b, 1, -1)))
             li += 1
-        out, _aux = block(layer, x, cfg, valid)
+        out, _aux = block(layer, x, cfg, valid, h_attn)
         x = residual(cfg, x, out)
     x = rms_norm(x, params["final_ln"], cfg.norm_eps, cfg.norm_plus_one)
     logits = lm_head(params, x[:, 0], cfg)
+    out = (logits, *pools["full"][:2])
     if state is not None:
-        return logits, k_pages, v_pages, {"h": hs, "conv": convs}
-    return logits, k_pages, v_pages
+        out += ({"h": hs, "conv": convs},)
+    if win is not None:
+        out += tuple(pools["window"][:2])
+    return out
 
 
 def verify_step(block, params, cfg, tokens, seq_lens, k_pages, v_pages,
@@ -518,6 +593,10 @@ def verify_step(block, params, cfg, tokens, seq_lens, k_pages, v_pages,
             "rolled back out of a recurrent state")
     if cfg.kv_pack > 1:
         raise NotImplementedError("verify_step over packed kv heads")
+    if cfg.two_kinds:
+        raise NotImplementedError(
+            "verify_step over full and banded layers: speculation and "
+            "chunked prefill over two kinds of page are not built")
     b, m = tokens.shape
     x = embed(params, tokens, cfg)  # [b, m, d]
     positions = seq_lens[:, None] + jnp.arange(m)[None, :]
@@ -530,8 +609,10 @@ def verify_step(block, params, cfg, tokens, seq_lens, k_pages, v_pages,
         target_page = jnp.where(ok, target_page, 0)
         slot = jnp.where(ok, slot, jnp.arange(m)[None, :] % cfg.page_size)
 
+    spec = attn_layers(cfg)
     for li, layer in enumerate(params["layers"]):
-        q, k, v = qkv(layer, x, cfg, positions)
+        band, rotates, _, _ = spec[li]
+        q, k, v, h_attn = _qkv(layer, x, cfg, positions, rotates)
         with jax.named_scope("pool.update"):
             k_pages = scatter_kv_multi(k_pages, k, target_page, slot,
                                        layer=li)
@@ -542,10 +623,10 @@ def verify_step(block, params, cfg, tokens, seq_lens, k_pages, v_pages,
         with jax.named_scope("attn.kernel"):
             attn = paged_verify_attention(
                 q, k_pages, v_pages, page_table, seq_lens,
-                window=cfg.window, layer=li
+                window=band, layer=li
             )
         x = residual(cfg, x, attn_out(layer, attn.reshape(b, m, -1)))
-        out, _aux = block(layer, x, cfg, ok)
+        out, _aux = block(layer, x, cfg, ok, h_attn)
         x = residual(cfg, x, out)
     x = rms_norm(x, params["final_ln"], cfg.norm_eps, cfg.norm_plus_one)
     return lm_head(params, x, cfg), k_pages, v_pages

@@ -12,9 +12,12 @@ zero-centered (1+w) RMSNorm, sqrt(d_model)-scaled embeddings and a
 decoupled head_dim, which also unlocks Mistral-NeMo geometry), and
 sliding-window attention maps onto ``LlamaConfig.window`` (banded masks in every attention path — a real
 windowed Mistral matches transformers on prefill, paged decode, and
-the engine's greedy stream). Unsupported features (yarn/linear/dynamic
-rope, ``mlp_bias``, Qwen2 MIXED per-layer windowing) hard-error rather
-than silently diverging. The conversion is pure
+the engine's greedy stream); Qwen2's MIXED per-layer windowing
+(``max_window_layers`` bottom layers full, the others banded) maps onto
+the per-layer spec ``LlamaConfig.layer_bands``, which the serving
+engine holds as two kinds of page. Unsupported features
+(yarn/linear/dynamic rope, ``mlp_bias``) hard-error rather than
+silently diverging. The conversion is pure
 layout work: torch ``nn.Linear`` stores [out, in] and computes
 ``x @ W.T``, our params store [in, out] and compute ``x @ W`` — so every
 projection transposes; head layouts, the half-split RoPE convention
@@ -55,15 +58,17 @@ def config_from_hf(hf_cfg, page_size=16, dtype="float32"):
                 "dynamic checkpoint would produce wrong logits at "
                 "every position"
             )
-    # Sliding-window attention maps onto LlamaConfig.window (a single
-    # global band width; llama.py applies it in every attention path).
-    # The signalling differs per family: Qwen2 carries
-    # sliding_window=4096 gated behind use_sliding_window, with
-    # max_window_layers giving the count of BOTTOM layers that keep
-    # full attention (mixed per-layer windowing has no slot here and
-    # hard-errors); Mistral's window is active whenever sliding_window
-    # is not None, on every layer.
+    # Sliding-window attention maps onto LlamaConfig.window (one band
+    # for every layer; decoder.py applies it in every attention path)
+    # or, where the layers differ, onto the per-layer spec
+    # LlamaConfig.layer_bands. The signalling differs per family:
+    # Qwen2 carries sliding_window=4096 gated behind
+    # use_sliding_window, with max_window_layers giving the count of
+    # BOTTOM layers that keep full attention (between 0 and all:
+    # mixed, the per-layer spec); Mistral's window is active whenever
+    # sliding_window is not None, on every layer.
     window = 0
+    layer_bands = ()
     if hasattr(hf_cfg, "use_sliding_window"):
         # transformers itself additionally gates SWA on sliding_window
         # being set: use_sliding_window=True with sliding_window=None
@@ -75,11 +80,10 @@ def config_from_hf(hf_cfg, page_size=16, dtype="float32"):
             elif mwl == 0:
                 window = int(hf_cfg.sliding_window)
             else:
-                raise NotImplementedError(
-                    f"mixed per-layer sliding window (max_window_layers="
-                    f"{mwl} of {hf_cfg.num_hidden_layers}) — the JAX "
-                    "model has one global window"
-                )
+                # mixed per-layer sliding window: the bottom mwl layers
+                # full, the others banded
+                layer_bands = (0,) * mwl + (int(hf_cfg.sliding_window),) \
+                    * (hf_cfg.num_hidden_layers - mwl)
     else:
         sw = getattr(hf_cfg, "sliding_window", None)
         if sw is not None:
@@ -129,6 +133,7 @@ def config_from_hf(hf_cfg, page_size=16, dtype="float32"):
         rope_theta=float(hf_cfg.rope_theta),
         rope_scaling=rope_scaling,
         window=window,
+        layer_bands=layer_bands,
         act=act,
         norm_plus_one=is_gemma,
         embed_scale=float(hf_cfg.hidden_size) ** 0.5 if is_gemma else 1.0,
@@ -212,7 +217,8 @@ def load_hf(model_or_state_dict, hf_cfg=None, page_size=16,
 __all__ = ["config_from_hf", "params_from_hf", "load_hf",
            "moe_config_from_hf", "moe_params_from_hf", "load_hf_moe",
            "hybrid_config_from_hf", "hybrid_params_from_hf",
-           "load_hf_hybrid"]
+           "load_hf_hybrid", "smallthinker_config_from_hf",
+           "smallthinker_params_from_hf"]
 
 
 def moe_config_from_hf(hf_cfg, page_size=16, dtype="float32"):
@@ -225,11 +231,10 @@ def moe_config_from_hf(hf_cfg, page_size=16, dtype="float32"):
     semantics; production serving can lower it and accept drops)."""
     from .moe import MoEConfig
 
-    if getattr(hf_cfg, "sliding_window", None) is not None:
-        raise NotImplementedError(
-            "Mixtral sliding_window set: the MoE family does not route "
-            "windowed attention configs yet"
-        )
+    # One band on every layer, as Mistral's: MoEConfig.window, which
+    # the shared stack (models/decoder.py) applies in every attention
+    # path, whatever the feed-forward block.
+    sw = getattr(hf_cfg, "sliding_window", None)
     # Never silently diverge (the dense bridge's contract): MoEConfig
     # has LlamaConfig's rope_scaling slot and the shared stack
     # (models/decoder.py) applies it, but no test pins a scaled Mixtral
@@ -269,6 +274,7 @@ def moe_config_from_hf(hf_cfg, page_size=16, dtype="float32"):
         max_seq=hf_cfg.max_position_embeddings,
         page_size=page_size,
         rope_theta=float(hf_cfg.rope_theta),
+        window=0 if sw is None else int(sw),
         norm_eps=float(hf_cfg.rms_norm_eps),
         dtype=dtype,
     )
@@ -478,3 +484,123 @@ def load_hf_hybrid(model_or_state_dict, hf_cfg=None, page_size=16,
         hf_cfg = model_or_state_dict.config
     cfg = hybrid_config_from_hf(hf_cfg, page_size=page_size, dtype=dtype)
     return cfg, hybrid_params_from_hf(model_or_state_dict, cfg)
+
+
+def smallthinker_config_from_hf(hf_cfg, page_size=16, dtype="float32"):
+    """Map a SmallThinker ``config.json`` (PowerInfer/SmallThinker-
+    21BA3B-Instruct; any object with its keys as attributes) onto
+    :class:`models.smallthinker.SmallThinkerConfig`. Every key of the
+    published config that shapes the model is read: the two per-layer
+    lists become the per-layer spec (`layer_bands`, `layer_rope`; any
+    combination of the two is held, a layer may be banded without
+    rotary or full with it), the experts and the router's top-k the
+    MoE fields. Refused, because models/smallthinker.py does not
+    implement it: a router without softmax over the chosen logits
+    (``moe_primary_router_apply_softmax: false``) or without
+    renormalisation (``norm_topk_prob: false``), a ``rope_scaling``,
+    tied embeddings, lists that are not one entry a layer. The
+    activation (ReGLU) and the router's input (the attention block's
+    normalised input) are the family's own: the config has no key for
+    either."""
+    from .smallthinker import SmallThinkerConfig
+
+    def refuse(what):
+        raise NotImplementedError(
+            f"smallthinker: {what} is not implemented by "
+            "models/smallthinker.py")
+
+    if not getattr(hf_cfg, "moe_primary_router_apply_softmax", True):
+        refuse("moe_primary_router_apply_softmax false (sigmoid gates)")
+    if not getattr(hf_cfg, "norm_topk_prob", True):
+        refuse("norm_topk_prob false (gates not renormalised on the "
+               "chosen experts)")
+    if getattr(hf_cfg, "rope_scaling", None):
+        refuse(f"rope_scaling {hf_cfg.rope_scaling!r}")
+    if getattr(hf_cfg, "tie_word_embeddings", False):
+        refuse("tie_word_embeddings (the head is a leaf of its own)")
+    n = hf_cfg.num_hidden_layers
+    banded = list(hf_cfg.sliding_window_layout)
+    rotates = list(hf_cfg.rope_layout)
+    if len(banded) != n or len(rotates) != n:
+        refuse(f"a sliding_window_layout of {len(banded)} or a "
+               f"rope_layout of {len(rotates)} entries for {n} layers")
+    band = int(hf_cfg.sliding_window_size)
+    bands = tuple(band if b else 0 for b in banded)
+    ropes = tuple(bool(r) for r in rotates)
+    # all layers alike: the one-band case (LlamaConfig.window /
+    # use_rope), which the engine holds in one pool
+    one_band = len(set(bands)) == 1
+    one_rope = len(set(ropes)) == 1
+    hd = getattr(hf_cfg, "head_dim", None)
+    derived = hf_cfg.hidden_size // hf_cfg.num_attention_heads
+    return SmallThinkerConfig(
+        head_dim_override=hd if (hd is not None and hd != derived) else 0,
+        vocab_size=hf_cfg.vocab_size,
+        d_model=hf_cfg.hidden_size,
+        n_layers=n,
+        n_heads=hf_cfg.num_attention_heads,
+        n_kv_heads=hf_cfg.num_key_value_heads,
+        d_ff=hf_cfg.moe_ffn_hidden_size,
+        n_experts=hf_cfg.moe_num_primary_experts,
+        top_k=hf_cfg.moe_num_active_primary_experts,
+        max_seq=hf_cfg.max_position_embeddings,
+        page_size=page_size,
+        rope_theta=float(hf_cfg.rope_theta),
+        window=bands[0] if one_band else 0,
+        layer_bands=() if one_band else bands,
+        use_rope=ropes[0] if one_rope else True,
+        layer_rope=() if one_rope else ropes,
+        norm_eps=float(hf_cfg.rms_norm_eps),
+        dtype=dtype,
+    )
+
+
+def smallthinker_params_from_hf(model_or_state_dict, cfg):
+    """Build the models/smallthinker.py parameter pytree (models/
+    moe.py's leaves) from a SmallThinker state dict. Names as the
+    family's published modeling file has them, as far as they could be
+    written down without the network (transformers here has no
+    SmallThinker class to check them against; a name that is not there
+    raises the KeyError that says which): attention and norms as
+    Llama's, ``block_sparse_moe.primary_router.weight`` and per expert
+    ``block_sparse_moe.experts.{e}.{gate,up,down}.weight``, each
+    [out, in] and transposed like every projection."""
+    import jax.numpy as jnp
+
+    sd = model_or_state_dict
+    if hasattr(sd, "state_dict"):
+        sd = sd.state_dict()
+    dt = cfg.jdtype
+    layers = []
+    for li in range(cfg.n_layers):
+        p = f"model.layers.{li}."
+        m = p + "block_sparse_moe."
+        for proj in ("q_proj", "k_proj", "v_proj", "o_proj"):
+            if p + f"self_attn.{proj}.bias" in sd:
+                raise NotImplementedError(
+                    f"smallthinker: {p}self_attn.{proj}.bias has no "
+                    "parameter slot (no attention bias)")
+
+        def experts(which):
+            return jnp.stack([
+                _t(sd, m + f"experts.{e}.{which}.weight", dt).T
+                for e in range(cfg.n_experts)])
+
+        layers.append({
+            "ln1": _t(sd, p + "input_layernorm.weight", dt),
+            "wq": _t(sd, p + "self_attn.q_proj.weight", dt).T,
+            "wk": _t(sd, p + "self_attn.k_proj.weight", dt).T,
+            "wv": _t(sd, p + "self_attn.v_proj.weight", dt).T,
+            "wo": _t(sd, p + "self_attn.o_proj.weight", dt).T,
+            "ln2": _t(sd, p + "post_attention_layernorm.weight", dt),
+            "router": _t(sd, m + "primary_router.weight", "float32").T,
+            "e_gate": experts("gate"),
+            "e_up": experts("up"),
+            "e_down": experts("down"),
+        })
+    return {
+        "embed": _t(sd, "model.embed_tokens.weight", dt),
+        "layers": layers,
+        "final_ln": _t(sd, "model.norm.weight", dt),
+        "lm_head": _t(sd, "lm_head.weight", dt).T,
+    }

@@ -55,6 +55,14 @@ class LlamaConfig:
     # prefill, prefix-cached prefill, paged decode and multi-token
     # verify (parity vs transformers pinned in tests/test_hf_bridge).
     window: int = 0
+    # The per-layer spec of the attention layers (models/decoder.py),
+    # where the layers differ: layer i's band (0 = full causal
+    # attention, w = the last w positions) and whether it rotates.
+    # () = `window` / `use_rope` for every layer, the one-band case of
+    # the same spec. A model with full AND banded layers keeps two
+    # kinds of page (`two_kinds`; serving.py has the cache manager).
+    layer_bands: tuple = ()
+    layer_rope: tuple = ()
     norm_eps: float = 1e-5
     # Family knobs beyond the Llama defaults (the Gemma-1 geometry:
     # GeGLU activation, zero-centered RMSNorm weights applied as
@@ -94,6 +102,32 @@ class LlamaConfig:
         """Each layer's mixer (decoder.py's per-layer spec): all
         attention here; models/hybrid.py's config overrides it."""
         return ("attention",) * self.n_layers
+
+    @property
+    def layer_windows(self):
+        """Each layer's band (decoder.py's per-layer spec; 0 = full
+        causal attention)."""
+        return self.layer_bands or (self.window,) * self.n_layers
+
+    @property
+    def layer_ropes(self):
+        """Whether each layer rotates."""
+        return self.layer_rope or (self.use_rope,) * self.n_layers
+
+    @property
+    def two_kinds(self):
+        """Full AND banded attention layers in one model: two kinds of
+        page with lives of their own, so two page pools
+        (decoder.attn_layers; serving.py). One band, the only other
+        case built, is `window_band`."""
+        bands = {w for w, k in zip(self.layer_windows, self.layer_kinds)
+                 if k == "attention"}
+        return 0 in bands and len(bands) > 1
+
+    @property
+    def window_band(self):
+        """The band of the banded attention layers (0: none)."""
+        return max(self.layer_windows, default=0)
 
     @property
     def n_kv_layers(self):
@@ -261,10 +295,10 @@ def param_bytes(params):
     )
 
 
-def _mlp(layer, x, cfg, valid):
+def _mlp(layer, x, cfg, valid, h_attn=None):
     """The family's feed-forward block (decoder.py's `block` contract):
-    a gated MLP, every token on its own, so `valid` is not needed; no
-    auxiliary loss."""
+    a gated MLP, every token on its own, so `valid` is not needed, nor
+    the attention block's input; no auxiliary loss."""
     with jax.named_scope("mlp"):
         h = decoder.rms_norm(x, layer["ln2"], cfg.norm_eps,
                              cfg.norm_plus_one)

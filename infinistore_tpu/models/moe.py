@@ -36,8 +36,9 @@ from . import decoder, llama
 @dataclass(frozen=True)
 class MoEConfig(llama.LlamaConfig):
     """LlamaConfig plus the routed feed-forward's own fields. `d_ff` is
-    the per-expert hidden size; `act` is not read (the expert FFN is
-    SwiGLU, and the HF bridge refuses anything else)."""
+    the per-expert hidden size; the dense dispatch does not read `act`
+    (its expert FFN is SwiGLU, and the HF bridge refuses anything
+    else), the sorted dispatch does ("relu": ReGLU)."""
 
     n_experts: int = 4
     top_k: int = 2
@@ -138,11 +139,12 @@ def _route(layer, h, cfg: MoEConfig, valid=None):
     return dispatch, combine, aux
 
 
-def _moe_mlp(layer, x, cfg: MoEConfig, valid):
+def _moe_mlp(layer, x, cfg: MoEConfig, valid, h_attn=None):
     """The family's feed-forward block (decoder.py's `block` contract):
     [B, S, d] → [B, S, d] through the routed expert FFN, and the
     layer's aux loss. `valid` ([B, S] bool or None) masks tokens out of
-    routing (see _route)."""
+    routing (see _route); the router reads the block's own normalised
+    input, so `h_attn` goes unused."""
     b, s, d = x.shape
     # Stage names as in models/decoder.py (one a stage, no layer index).
     with jax.named_scope("moe.route"):
@@ -164,6 +166,115 @@ def _moe_mlp(layer, x, cfg: MoEConfig, valid):
     return out.reshape(b, s, d), aux
 
 _forward_stack, decode_step, verify_step = decoder.bind(_moe_mlp)
+
+
+# ---------------------------------------------------------------------------
+# The sorted dispatch: no capacity, no dropped token
+# ---------------------------------------------------------------------------
+# The dense dispatch above builds [T, E, C] tensors; with many small
+# experts (64 of them, 6 a token) and C = T they cannot be built at all
+# for a long prompt. Here the T x k chosen (token, expert) pairs are
+# sorted by expert and the experts run as ONE grouped matmul over the
+# sorted rows; every chosen pair is computed, whatever the router's
+# skew. Models/smallthinker.py binds it; this family keeps the dense
+# dispatch until its cell has been measured on the other (ROADMAP S4).
+
+# With token x expert rows at or under this, every token runs through
+# every expert and the gates zero the ones it did not choose. Measured
+# on a v5e at 64 experts of 2560 x 768, 6 a token (PERF.md, PR 35; ms a
+# layer, dense / sorted): 16 tokens 1.32 / 1.25, 128 1.16 / 2.22, 256
+# 1.24 / 3.05, 1024 4.21 / 3.73. A decode step's batch and a hit's
+# short suffix read nearly every expert's weights anyway (16 tokens x
+# 6 of 64 touch 51 in expectation) and are bound by that read, which
+# the dense form does at 70 % of the HBM's rate; the sort, two gathers
+# and a grouped matmul over groups of a few rows cost more than the
+# rows they save until the rows are many.
+DENSE_EXPERTS_MAX_ROWS = 512 * 64
+
+
+def route_top_k(router, h, top_k):
+    """(router logits [T, E] float32, chosen experts [T, k], their
+    gates [T, k] float32): the k largest logits, softmax over those k
+    (= softmax over all E, renormalised on the chosen)."""
+    logits = h.astype(jnp.float32) @ router
+    top_z, top_idx = jax.lax.top_k(logits, top_k)
+    return logits, top_idx, jax.nn.softmax(top_z, axis=-1)
+
+
+# The grouped matmul's rows are padded to a multiple of this. Measured
+# on a v5e (PERF.md, PR 35; one layer, 64 experts of 2560 x 768, 6 a
+# token): 12,544 tokens (75,264 rows = 147 x 512) 19.6 ms, 12,528 tokens
+# (75,168 rows) 67.3 ms, 6,256 tokens 34.4: `ragged_dot` over a row count
+# that is no multiple of its tile runs at a third of its speed.
+SORTED_ROW_TILE = 512
+
+
+def experts_sorted(layer, u, top_idx, gates, act):
+    """sum over a token's chosen experts of gate * W_down(act(W_gate u)
+    * (W_up u)), by sorting the pairs by expert. u: [T, d]; top_idx,
+    gates: [T, k]. Returns [T, d]."""
+    T, d = u.shape
+    k = top_idx.shape[1]
+    E = layer["e_gate"].shape[0]
+    pad = -(T * k) % SORTED_ROW_TILE
+    with jax.named_scope("moe.dispatch"):
+        flat = top_idx.reshape(-1)
+        order = jnp.argsort(flat)          # pairs by expert (stable)
+        sizes = jnp.bincount(flat, length=E).astype(jnp.int32)
+        # the padding rows ride in the last expert's group (copies of
+        # token 0, computed and cut off below): the sizes still sum to
+        # the row count
+        sizes = sizes.at[-1].add(pad)
+        rows = jnp.take(u, jnp.pad(order // k, (0, pad)), axis=0)
+    with jax.named_scope("moe.experts"):
+        a = act(jax.lax.ragged_dot(rows, layer["e_gate"], sizes))
+        a = a * jax.lax.ragged_dot(rows, layer["e_up"], sizes)
+        out = jax.lax.ragged_dot(a, layer["e_down"], sizes)  # [T k, d]
+    with jax.named_scope("moe.combine"):
+        back = jnp.take(out, jnp.argsort(order), axis=0).reshape(T, k, d)
+        return jnp.einsum("tkd,tk->td", back, gates.astype(back.dtype))
+
+
+def experts_dense(layer, u, top_idx, gates, act):
+    """The same sum with every token through every expert and the
+    gates of the experts it did not choose at zero: for a handful of
+    tokens (DENSE_EXPERTS_MAX_ROWS)."""
+    E = layer["e_gate"].shape[0]
+    with jax.named_scope("moe.dispatch"):
+        dense = jnp.einsum("tk,tke->te", gates,
+                           jax.nn.one_hot(top_idx, E, dtype=gates.dtype))
+    with jax.named_scope("moe.experts"):
+        a = act(jnp.einsum("td,edf->tef", u, layer["e_gate"]))
+        a = a * jnp.einsum("td,edf->tef", u, layer["e_up"])
+        a = a * dense[..., None].astype(a.dtype)
+    with jax.named_scope("moe.combine"):
+        return jnp.einsum("tef,efd->td", a, layer["e_down"])
+
+
+def sorted_moe_mlp(layer, x, cfg: MoEConfig, valid, h_attn=None,
+                   early_router=False):
+    """A feed-forward block (decoder.py's `block` contract) over the
+    sorted dispatch. No capacity, so a row that holds no real token
+    takes nothing from one that does and `valid` is not needed. With
+    `early_router` the router reads `h_attn`, the attention block's
+    normalised input, and not the block's own. Which of the two forms
+    runs is decided by the number of tokens, which is a shape. The
+    gate's activation is the config's (`cfg.act`). No auxiliary loss
+    (serving only)."""
+    b, s, d = x.shape
+    with jax.named_scope("moe.route"):
+        u = decoder.rms_norm(x, layer["ln2"], cfg.norm_eps,
+                             cfg.norm_plus_one).reshape(b * s, d)
+        seen = h_attn.reshape(b * s, d) if early_router else u
+        _, top_idx, gates = route_top_k(layer["router"], seen, cfg.top_k)
+    form = experts_dense \
+        if b * s * cfg.n_experts <= DENSE_EXPERTS_MAX_ROWS else experts_sorted
+    out = form(layer, u, top_idx, gates, _gate_act(cfg))
+    return out.reshape(b, s, d), None
+
+
+def _gate_act(cfg):
+    return jax.nn.relu if cfg.act == "relu" else decoder.act(cfg)
 
 
 def forward_dense(params, cfg: MoEConfig, tokens):

@@ -37,6 +37,7 @@ CPU CI covers the same code path bit-for-bit).
 """
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -295,10 +296,28 @@ def paged_flash_decode(q, k_pages, v_pages, page_table, seq_lens,
     q_p, _ = _pad_to(q, 2, 128)
     hd_p = q_p.shape[2]
     group = n_heads // n_kv
-    _, n_kv_p = _decode_dims(q.dtype, n_kv, group)
-    if n_kv_p != n_kv:
+    sublane, n_kv_p = _decode_dims(q.dtype, n_kv, group)
+    group_p = group
+    if (n_kv_p != n_kv and k_pages.ndim == 5 and hd % 128 == 0
+            and math.gcd(group, sublane) == 1):
+        # A group that shares no factor with the sublane count, so
+        # that only `sublane` kv heads make a sublane multiple of
+        # query rows (7 query heads a kv head: 4 kv heads would become
+        # 16), over a whole pool whose lanes are aligned: pad the
+        # GROUP with zero query rows instead (7 -> 8), so that the
+        # pool still goes to the kernel whole and as it is. Padding
+        # its kv heads would slice out, copy and widen a layer of the
+        # pool on every layer's call. The zero rows attend uniformly
+        # and are dropped below.
+        group_p = next(g for g in range(group, group + sublane + 1)
+                       if (n_kv * g) % sublane == 0)
+        n_kv_p = n_kv
+        q_p = jnp.pad(q_p.reshape(batch, n_kv, group, hd_p),
+                      ((0, 0), (0, 0), (0, group_p - group), (0, 0))
+                      ).reshape(batch, n_kv * group_p, hd_p)
+    elif n_kv_p != n_kv:
         q_p = jnp.pad(q_p, ((0, 0), (0, (n_kv_p - n_kv) * group), (0, 0)))
-    n_heads_p = n_kv_p * group
+    n_heads_p = n_kv_p * group_p
 
     k_f = _kv_operand(k_pages, layer, n_kv_p)
     v_f = _kv_operand(v_pages, layer, n_kv_p)
@@ -337,6 +356,9 @@ def paged_flash_decode(q, k_pages, v_pages, page_table, seq_lens,
         grid_spec=grid_spec,
         interpret=interpret,
     )(page_table, seq_lens, q_p, k_f, v_f)
+    if group_p != group:
+        out = out.reshape(batch, n_kv, group_p, hd_p)[:, :, :group]
+        return out.reshape(batch, n_heads, hd_p)[..., :hd]
     return out[:, :n_heads, :hd]
 
 

@@ -126,12 +126,16 @@ def content_page_keys(tokens, page_size, n_pages, layer, kind,
     return [f"cp/{d}/L{layer}/{kind}" for d in digests]
 
 
-def content_page_keys_by_page(digests, n_layers):
+def content_page_keys_by_page(digests, layers):
     """The same keys for every layer and kind of each page, page-major
     (page, layer, k then v): the row order of `_gather_pages`, so one
-    offload is one key list over one array."""
+    offload is one key list over one array. `layers`: how many (0 ..
+    layers - 1), or which: a pool that holds some of the layers that
+    keep pages (one kind of two) names them."""
+    if isinstance(layers, int):
+        layers = range(layers)
     return [f"cp/{d}/L{layer}/{kind}" for d in digests
-            for layer in range(n_layers) for kind in "kv"]
+            for layer in layers for kind in "kv"]
 
 
 def snapshot_keys(digest, lo, hi):
@@ -268,6 +272,13 @@ class _Slot:
     #                                              prefill phase)
     index: int = -1           # the slot it sits in: its row of the
     #                           state pools (families with state)
+    # A model with full and banded layers (two kinds of page): the
+    # banded layers' pool pages, in sequence order from page `wbase`
+    # of the sequence on (what lies below left the band), and the page
+    # below which the store holds every banded layer's page already.
+    wpage_ids: list = field(default_factory=list)
+    wbase: int = 0
+    wstored: int = 0
 
     def total_generated(self):
         return len(self.work.done) + len(self.generated)
@@ -615,6 +626,145 @@ def _gather_snapshot(cfg, bstate, slot, rows_a_chunk):
                      for a in range(0, rows.shape[0], rows_a_chunk))
 
 
+# ---- the same programs for a model with two kinds of attention layer ----
+# Full layers keep every page for the life of a sequence; banded layers
+# need the last `band` positions alone (decoder.attn_layers). Each kind
+# has its pools: the full layers' under the page table every family
+# has, the banded layers' (`wk`, `wv`: [banded layers, slots x short
+# table + 1, page, n_kv, hd]) under a short table a slot. The store's
+# contract is the one every family has (every full page of every layer
+# is written once), so what a banded layer computes below its band at
+# admission leaves the admission program as `sub`, on its way to the
+# store without a pool page of its own. Names of their own, so a trace
+# tells them apart; a model of one kind runs exactly the programs
+# above.
+
+
+def _sub_chunk_pages(cfg):
+    """Pages (over every banded layer, K and V) that one chunk of an
+    admission's `sub` holds: at most OFFLOAD_CHUNK_BYTES, as every
+    device-to-host transfer of an offload."""
+    n_win = sum(pool == "window" for *_, pool, _ in decoder.attn_layers(cfg))
+    return max(1, OFFLOAD_CHUNK_BYTES // (2 * n_win * cfg.kv_page_bytes()))
+
+
+def _page_out_two(cfg, kvs, k_pages, v_pages, wk, wv, ids, wids, n_sub):
+    """`_page_out` for two kinds. Suffix page i of a full layer goes to
+    ids[i] of the full pools, of a banded layer to wids[i] of the
+    banded pools (both in `_pad_ids` form; the sentinel drops). The
+    banded layers' first `n_sub` suffix pages (static; the caller left
+    their wids at the sentinel: they lie below the band) come back as
+    `sub`: page-major rows (page, layer, k then v), flat, in chunks of
+    `_sub_chunk_pages` pages."""
+    page = cfg.page_size
+    m = kvs[0][0].shape[1] // page
+    spec = decoder.attn_layers(cfg)
+
+    def stack(pool, which):
+        rows = [kv[which][0] for kv, (*_, held, _) in zip(kvs, spec)
+                if held == pool]
+        return jnp.stack(rows).reshape(len(rows), m, *cfg.kv_page_shape())
+
+    with jax.named_scope("pool.update"):  # stage names: models/decoder.py
+        k_pages = k_pages.at[:, ids[:m]].set(stack("full", 0), mode="drop")
+        v_pages = v_pages.at[:, ids[:m]].set(stack("full", 1), mode="drop")
+        kw, vw = stack("window", 0), stack("window", 1)
+        wk = wk.at[:, wids[:m]].set(kw, mode="drop")
+        wv = wv.at[:, wids[:m]].set(vw, mode="drop")
+    with jax.named_scope("pool.gather"):
+        rows = jnp.swapaxes(
+            jnp.stack([kw[:, :n_sub], vw[:, :n_sub]], axis=2), 0, 1)
+        c = _sub_chunk_pages(cfg)
+        sub = tuple(rows[a:a + c].reshape(-1) for a in range(0, n_sub, c))
+    return k_pages, v_pages, wk, wv, sub
+
+
+@partial(jax.jit, static_argnames=("cfg", "model", "n_sub"),
+         donate_argnums=(3, 4, 5, 6))
+def _admit_fused_wf(params, cfg, tokens, k_pages, v_pages, wk, wv, ids,
+                    wids, s_real, model, n_sub):
+    """`_admit_fused` for two kinds of attention layer: every layer's K
+    and V are computed for the whole prompt; the full layers' pages go
+    to their pools at `ids`, the banded layers' in-band tail to theirs
+    at `wids`, and the banded layers' first `n_sub` pages come back as
+    `sub` (`_page_out_two`). One program per (s_pad, n_sub); n_sub is a
+    function of s_pad in an admission."""
+    logits, kvs = model.prefill(params, cfg, tokens)
+    k_pages, v_pages, wk, wv, sub = _page_out_two(
+        cfg, kvs, k_pages, v_pages, wk, wv, ids, wids, n_sub)
+    return logits[0, s_real - 1], k_pages, v_pages, wk, wv, sub
+
+
+@partial(jax.jit, static_argnames=("cfg", "model", "n_sub"),
+         donate_argnums=(4, 5, 6, 7))
+def _admit_fused_px_wf(params, cfg, tokens, restored, k_pages, v_pages, wk,
+                       wv, r_ids, wr_ids, s_ids, ws_ids, s_real, model,
+                       n_sub):
+    """`_admit_fused_px` for two kinds of attention layer.
+
+    restored: ONE store call's result: first the full layers' pages
+      [0, P) page-major (page, full layer, k then v), then the banded
+      layers' pages [first_live, P) page-major (page, banded layer, k
+      then v); P = len(r_ids), P - first_live = len(wr_ids).
+    r_ids / wr_ids: pool ids of those pages, exactly that many; a
+      banded layer's restored page that the sequence will not attend
+      after this admission (below the floor of the prompt's last
+      position) has the sentinel: the suffix attends it here and it
+      takes no pool page.
+    s_ids / ws_ids, n_sub, sub: as in `_admit_fused_wf`, for the
+      suffix.
+    The prefix a layer attends is what that layer may attend: [0, P)
+    for a full layer, [first_live, P) for a banded one; rotary
+    positions are absolute (keys were rotated before they were cached)
+    and the band is relative, so neither needs the other's length
+    (decoder.forward_stack). One program per (s_pad, P)."""
+    page = cfg.page_size
+    spec = decoder.attn_layers(cfg)
+    n_full = sum(pool == "full" for *_, pool, _ in spec)
+    p, nw = r_ids.shape[0], wr_ids.shape[0]
+    with jax.named_scope("pool.update"):  # stage names: models/decoder.py
+        cut = p * n_full * 2
+        rf = restored[:cut].reshape(p, n_full, 2, *cfg.kv_page_shape())
+        rw = restored[cut:].reshape(nw, len(spec) - n_full, 2,
+                                    *cfg.kv_page_shape())
+        # per layer, straight from the page-major rows, as
+        # `_place_restored` does and for its reason
+        for li in range(n_full):
+            k_pages = k_pages.at[li, r_ids].set(rf[:, li, 0], mode="drop")
+            v_pages = v_pages.at[li, r_ids].set(rf[:, li, 1], mode="drop")
+        for li in range(len(spec) - n_full):
+            wk = wk.at[li, wr_ids].set(rw[:, li, 0], mode="drop")
+            wv = wv.at[li, wr_ids].set(rw[:, li, 1], mode="drop")
+        prefix = []
+        for *_, pool, li in spec:
+            rows = rf if pool == "full" else rw
+            flat = (1, rows.shape[0] * page, cfg.n_kv_heads, cfg.head_dim)
+            prefix.append((rows[:, li, 0].reshape(flat),
+                           rows[:, li, 1].reshape(flat)))
+    logits, kvs = model.prefill_with_prefix(params, cfg, tokens, prefix)
+    k_pages, v_pages, wk, wv, sub = _page_out_two(
+        cfg, kvs, k_pages, v_pages, wk, wv, s_ids, ws_ids, n_sub)
+    return logits[0, s_real - 1], k_pages, v_pages, wk, wv, sub
+
+
+@partial(jax.jit, static_argnames=("cfg", "model"),
+         donate_argnums=(4, 5, 6, 7))
+def _decode_fused_wf(params, cfg, token, seq_lens, k_pages, v_pages, wk, wv,
+                     rows, model):
+    """`_decode_fused` for two kinds of attention layer. `rows`: (page
+    table, the banded layers' short table [slots, entries], its base
+    [slots]: the absolute position of each row's first entry). A banded
+    layer's kernel call walks the short table alone: `band / page + 1`
+    live entries a sequence however long the sequence is."""
+    table, wtable, wbase = rows
+    logits, k_pages, v_pages, wk, wv = model.decode_step(
+        params, cfg, token, seq_lens, k_pages, v_pages, table,
+        win=(wk, wv, wtable, wbase))
+    nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+    return (logits, nxt, seq_lens + (seq_lens > 0), k_pages, v_pages, wk,
+            wv)
+
+
 # Trivial programs dispatched behind a one-shot admission's program
 # (ServingEngine._settle): 4 ended the slow mode in two runs of two, 1
 # and 2 did not; 8 behind the admissions out of idle alone ended it some
@@ -693,6 +843,17 @@ def _offload_bucket(n, cap):
     return min(cap, -(-n // step) * step)
 
 
+def _pow2_bucket(n, cap):
+    """`_offload_bucket` for gathers whose page counts traffic does
+    not fix (the banded layers' pool: what a slot has shed by its
+    finish depends on the slots beside it): `n` rounded up to a power
+    of two, at most `cap`. Few enough programs that the engine builds
+    them all when it is made (`_init_window_pools`), so none is built
+    inside a step; the padding is under half of a gather of at most
+    16 MiB."""
+    return min(cap, 1 << max(0, (n - 1).bit_length()))
+
+
 class ServingEngine:
     """Continuous-batching engine over the store, serving any model
     family that exposes the shared surface (models.llama, models.moe —
@@ -751,11 +912,24 @@ class ServingEngine:
             else prompt_lookup_propose
         # The cache manager's pools, by kind. Pages: K and V of the
         # layers that attend (all of them, for most families).
-        shape = (cfg.n_kv_layers, self.sc.total_pages,
+        # Which layers (by their rank among those that keep pages:
+        # the L of a page's store keys) each pool holds. One kind of
+        # attention layer: one pool of them all. Full AND banded
+        # layers: the full ones here, the banded ones in a second pair
+        # of pools under a short table a slot (`_init_window_pools`).
+        spec = decoder.attn_layers(cfg)
+        self._full_layers = [i for i, (*_, pool, _) in enumerate(spec)
+                             if pool == "full"]
+        self._win_layers = [i for i, (*_, pool, _) in enumerate(spec)
+                            if pool == "window"]
+        shape = (len(self._full_layers), self.sc.total_pages,
                  *cfg.kv_page_shape())
         self.k_pages = jnp.zeros(shape, dtype=cfg.jdtype,
                                  device=self.device)
         self.v_pages = jnp.zeros_like(self.k_pages)
+        self.wk_pages = self.wv_pages = None
+        if self._win_layers:
+            self._init_window_pools()
         # State: for a family with recurrent layers, what its model
         # declares (`model.state_pools`), a row a slot, and the same
         # again for the copies taken at each slot's last page edge:
@@ -802,6 +976,14 @@ class ServingEngine:
             "snapshot_misses": 0, "boundary_copies": 0,
             # hits whose snapshot lay below the pages' matched depth
             "snapshot_walkbacks": 0,
+            # two kinds of attention layer: banded layers' pages that
+            # left the band during decode (freed / of those written to
+            # the store first), pages of banded layers a hit did not
+            # transfer because its band spared them, and banded
+            # layers' pages an admission wrote to the store without a
+            # pool page (sequence pages, each over every banded layer)
+            "window_pages_released": 0, "window_pages_offloaded": 0,
+            "restore_trimmed_pages": 0, "subfloor_pages_written": 0,
         }
         self.engine_id = profiling.next_engine_id()
         self._own_digests = {}  # insertion-ordered, at most OWN_DIGESTS
@@ -851,6 +1033,12 @@ class ServingEngine:
             f"{model_id}/p{cfg.page_size}/l{cfg.n_layers}"
             f"/kv{cfg.n_kv_heads}x{cfg.head_dim}/{wire}"
         )
+        if self._win_layers:
+            # ... of every cache kind: which layers are banded, and how
+            # widely (a page of a banded layer is not a page of a full
+            # one, whatever the weights).
+            self._ns += (f"/band{cfg.window_band}@"
+                         + ".".join(map(str, self._win_layers)))
         if self.state is not None:
             # ... of every cache kind: which layers keep pages, and the
             # state's geometry and dtype.
@@ -866,6 +1054,61 @@ class ServingEngine:
             self._get_pages = partial(store.get_kv_pages,
                                       device=self.device)
             self._put_pages = store.put_kv_pages
+
+    def _init_window_pools(self):
+        """The banded layers' pools, short tables and free list (a
+        model with full and banded attention layers). Sized from the
+        slots, the band and the page, not configured: a sequence never
+        attends more than `band` positions of a banded layer, which
+        lie in at most band / page + 1 pages; the table has room for
+        one more (the page being written) and is rounded up to 8
+        entries, and that slack is what lets the pages that left the
+        band go a few at a time and several slots' together
+        (`_shed_windows`). Page 0 is scratch, as in
+        the full pools. What is not built over two kinds is refused
+        here, not found at the first request."""
+        cfg, sc = self.cfg, self.sc
+        bands = {w for w, *_ in decoder.attn_layers(cfg) if w}
+        refused = [
+            ("spec_k", sc.spec_k > 0), ("host_steps", sc.host_steps > 1),
+            ("prefill_chunk", sc.prefill_chunk > 0),
+            ("quantized_store", sc.quantized_store),
+            ("kv_pack", cfg.kv_pack > 1),
+            ("state layers", bool(getattr(cfg, "n_state_layers", 0))),
+            ("more than one band", len(bands) > 1),
+            ("a band that is no page multiple",
+             cfg.window_band % cfg.page_size != 0),
+        ]
+        for name, on in refused:
+            if on:
+                raise ValueError(
+                    f"{name} is not supported for a model with full and "
+                    f"banded attention layers ({type(cfg).__name__}): "
+                    f"verify, burst and chunk steps, the int8 wire and "
+                    f"packed rows address ONE page pool")
+        self._band_pages = cfg.window_band // cfg.page_size
+        self._wtable_w = -(-(self._band_pages + 2) // 8) * 8
+        self._shed_pages = max(
+            1, (self._wtable_w - self._band_pages - 1) // 2)
+        self._wpool_pages = sc.max_slots * self._wtable_w + 1
+        shape = (len(self._win_layers), self._wpool_pages,
+                 *cfg.kv_page_shape())
+        self.wk_pages = jnp.zeros(shape, dtype=cfg.jdtype,
+                                  device=self.device)
+        self.wv_pages = jnp.zeros_like(self.wk_pages)
+        self.wfree = list(range(1, self._wpool_pages))
+        self.wtable = np.zeros((sc.max_slots, self._wtable_w), np.int32)
+        # One sequence page over the banded layers, K and V, in bytes.
+        self._wpage_bytes = 2 * len(self._win_layers) * cfg.kv_page_bytes()
+        # Every gather program over these pools, built now
+        # (`_pow2_bucket`): the counts they run at are decided by what
+        # the slots shed and when, not by the traffic's shapes.
+        cap = self._chunk_pages(self._wpage_bytes)
+        for n in sorted({_pow2_bucket(1 << i, cap)
+                         for i in range(cap.bit_length() + 1)}):
+            jax.block_until_ready(_gather_pages(
+                self.wk_pages, self.wv_pages,
+                self._to_device(np.zeros(n, np.int32))))
 
     def _check_state_family(self):
         """What is not built over a recurrent state is refused at
@@ -1091,13 +1334,15 @@ class ServingEngine:
             return
         cfg = self.cfg
         try:
-            keys = []
-            for li in range(cfg.n_kv_layers):
-                for kind in ("k", "v"):
-                    keys.extend(content_page_keys(
-                        prompt, cfg.page_size, hit, li, kind,
-                        digests=digests,
-                    ))
+            if self._win_layers:  # what the restore will read, no more
+                keys = self._restore_keys(hit, digests,
+                                          self._first_live(hit))
+            else:
+                keys = [key for li in range(cfg.n_kv_layers)
+                        for kind in ("k", "v")
+                        for key in content_page_keys(
+                            prompt, cfg.page_size, hit, li, kind,
+                            digests=digests)]
             if self.state is not None:
                 keys.extend(snapshot_keys(digests[hit - 1], 0,
                                           self.cfg.n_state_layers))
@@ -1134,8 +1379,10 @@ class ServingEngine:
         if work.probe is None:
             work.probe = self._probe_hit(work)
         hit, digests = work.probe
-        store_chain = (self.store is not None and self._store_ok
-                       and work.req.cache)
+        store_chain = self._store_chain(work)
+        if self._win_layers:
+            return self._do_admit_two(slot_idx, work, n_prompt, n_pages,
+                                      hit if store_chain else 0, digests, f)
         if not store_chain and hit:
             # The probe is cached on work while the request waits under
             # pool pressure, so it can OUTLIVE the store: another slot's
@@ -1162,8 +1409,7 @@ class ServingEngine:
         #     chunk queries attend POOL pages, and its floor rises as
         #     chunks consume the prompt — _release_windowed frees on
         #     the way).
-        first_live = max(0, hit * page - window + 1) // page if window \
-            else 0
+        first_live = self._first_live(hit)
         p0 = max(0, n_prompt - window) // page if window else 0
         # How many leading pages never get a pool page:
         #   - with a store (and caching on), only pages the store
@@ -1229,13 +1475,24 @@ class ServingEngine:
         call brings the snapshot taken at the end of page `hit`, [state
         layers, row]; returns (pages, snapshot or None)."""
         n = hit - first_live
-        keys = content_page_keys_by_page(digests[first_live:hit],
-                                         self.cfg.n_kv_layers)
+        keys = self._restore_keys(hit, digests, first_live)
+        kinds = {}
+        if self._win_layers:
+            # Two kinds: the full layers' [0, hit) and the banded
+            # layers' [first_live, hit) in the one call, in the order
+            # `_admit_fused_px_wf` takes them; `pages` stays the hit's
+            # depth, `bytes` what crosses over.
+            kinds = {"full_pages": hit, "window_pages": n,
+                     "trimmed_pages": first_live}
+            nbytes = hit * self._page_bytes + n * self._wpage_bytes
+            n = hit
+        else:
+            nbytes = n * self._page_bytes + self._snapshot_bytes
         # The span times the store calls alone — the interval a span
         # around get_kv_pages from outside times too.
-        with self._span("istpu.cache.restore", pages=n,
-                        bytes=n * self._page_bytes + self._snapshot_bytes,
-                        foreign_pages=foreign, **self._snapshot_fields) as f:
+        with self._span("istpu.cache.restore", pages=n, bytes=nbytes,
+                        foreign_pages=foreign, **kinds,
+                        **self._snapshot_fields) as f:
             pages = self._get_pages(keys, self.cfg.kv_page_shape(),
                                     self.cfg.jdtype)
             # How the pages lay in the store's pool: the contiguous runs
@@ -1258,6 +1515,63 @@ class ServingEngine:
                                   self.cfg.n_state_layers),
                     (self._snapshot_row,), self.cfg.state_jdtype)
 
+    def _store_chain(self, work):
+        """Whether this request's pages go to and come from the
+        store."""
+        return (self.store is not None and self._store_ok
+                and work.req.cache)
+
+    def _try_restore(self, hit, digests, first_live, f):
+        """`_restore`, counted, with a failure turned into a miss:
+        (pages, snapshot, the hit that holds: 0 after a failure). `f`:
+        the admission span's fields."""
+        # hit pages this engine did not itself offload
+        foreign = sum(d not in self._own_digests for d in digests[:hit])
+        try:
+            restored, snap = self._restore(hit, digests, first_live,
+                                           foreign)
+        except InfiniStoreKeyNotFound:
+            # Routine eviction race: the page was LRU-dropped between
+            # probe and restore. A cache MISS for this admission only —
+            # the store stays in use.
+            self.stats["restore_misses"] += 1
+            return None, None, 0
+        except Exception as e:
+            # Connection-class failure: downgrade to store-less.
+            self._store_failed("restore", e)
+            return None, None, 0
+        self.stats["prefix_hit_pages"] += hit
+        self.stats["foreign_hit_pages"] += foreign
+        f["foreign_pages"] = foreign
+        self.stats["restored_pages"] += restored.shape[0]
+        self.stats["snapshots_restored"] += snap is not None
+        if self._win_layers:
+            self.stats["restore_trimmed_pages"] += first_live
+        return restored, snap, hit
+
+    def _first_live(self, hit):
+        """The first page a banded layer's suffix prefill can attend
+        over a hit of `hit` pages: the first suffix query sits at hit *
+        page and its band floor at hit * page - band + 1."""
+        band = self.cfg.window_band
+        if not band:
+            return 0
+        return max(0, hit * self.cfg.page_size - band + 1) \
+            // self.cfg.page_size
+
+    def _restore_keys(self, hit, digests, first_live):
+        """The keys of a hit's one store call. One kind of page: pages
+        [first_live, hit) of every layer, page-major. Two kinds: the
+        full layers' [0, hit) page-major, then the banded layers'
+        [first_live, hit) page-major: each kind in the order its
+        offloads wrote it, so each reads back in few runs."""
+        if not self._win_layers:
+            return content_page_keys_by_page(digests[first_live:hit],
+                                             self.cfg.n_kv_layers)
+        return content_page_keys_by_page(digests[:hit], self._full_layers) \
+            + content_page_keys_by_page(digests[first_live:hit],
+                                        self._win_layers)
+
     def _admit_restore_and_prefill(self, slot_idx, work, ids, n_prompt,
                                    n_pages, hit, digests, skip,
                                    first_live, f):
@@ -1268,29 +1582,8 @@ class ServingEngine:
             # Restore the in-window hit pages once (one HBM array, as
             # the store call returns it; pool placement follows in
             # _do_admit_paged).
-            # hit pages this engine did not itself offload
-            foreign = sum(d not in self._own_digests for d in digests[:hit])
-            try:
-                restored, snap = self._restore(hit, digests, first_live,
-                                               foreign)
-            except InfiniStoreKeyNotFound:
-                # Routine eviction race: the page was LRU-dropped
-                # between probe and restore. A cache MISS for this
-                # admission only — the store stays in use.
-                self.stats["restore_misses"] += 1
-                hit = 0
-            except Exception as e:
-                # Connection-class failure: downgrade to store-less.
-                self._store_failed("restore", e)
-                hit = 0
-            else:
-                self.stats["prefix_hit_pages"] += hit
-                self.stats["foreign_hit_pages"] += foreign
-                f["foreign_pages"] = foreign
-                self.stats["restored_pages"] += (
-                    (hit - first_live) * self._page_objects
-                )
-                self.stats["snapshots_restored"] += snap is not None
+            restored, snap, hit = self._try_restore(hit, digests,
+                                                    first_live, f)
             if hit == 0 and skip > 0:
                 # Restore failed after a skip-trimmed allocation: the
                 # cold path needs the skipped pages after all. Top up
@@ -1370,11 +1663,7 @@ class ServingEngine:
                 suffix, restored, first_live * page,
                 ids[:hit - skip], ids[hit - skip:], snap, slot_idx)
         self.stats["prefill_tokens"] += len(suffix)
-        now = time.monotonic()
-        if not any(s is not None for s in self.slots):
-            self._left_idle = now
-        if now - self._left_idle < SETTLE_S:
-            self._settle()
+        self._settle_if_left_idle()
 
         self.page_table[slot_idx] = row
 
@@ -1392,6 +1681,156 @@ class ServingEngine:
         # list stays O(prompt), it is hash-only).
         self._release_windowed(slot)
 
+    # ---- admission over two kinds of attention layer -------------------
+
+    def _walloc(self, n):
+        """`n` pages of the banded layers' pools, or None."""
+        if len(self.wfree) < n:
+            return None
+        ids, self.wfree = self.wfree[:n], self.wfree[n:]
+        return ids
+
+    def _wbase_after(self, n_pages):
+        """The first page of a sequence whose banded layers' pages are
+        held after an admission of `n_pages` prompt pages: the page the
+        band floor of the prompt's last position can lie in at the
+        lowest (the prompt's last page may hold one token). What lies
+        below is attended by the admission's own queries alone."""
+        return max(0, n_pages - self._band_pages - 1)
+
+    def _do_admit_two(self, slot_idx, work, n_prompt, n_pages, hit,
+                      digests, f):
+        """`_do_admit` from the allocation on, for a model with full
+        and banded attention layers. The full layers take a pool page
+        for every page of the prompt, as a model without a window does;
+        the banded layers for pages [wbase, n_pages) alone. A hit of P
+        pages restores the full layers' [0, P) and the banded layers'
+        [first_live(P), P) in one store call; what a banded layer
+        computes below wbase goes from the program to the store
+        (`_put_subfloor`). Both allocations before the restore, both
+        refunded on any way out but the admitted one."""
+        wbase = self._wbase_after(n_pages)
+        ids = self._alloc(n_pages)
+        wids = self._walloc(n_pages - wbase) if ids is not None else None
+        if wids is None:
+            if ids is not None:
+                self.free_pages.extend(ids)
+            self.stats["admit_retries"] += 1
+            f["outcome"] = "no_pages"
+            return False
+        try:
+            restored = None
+            if hit > 0:
+                restored, _, hit = self._try_restore(
+                    hit, digests, self._first_live(hit), f)
+            f["hit_pages"] = hit
+            row_host, sub = self._prefill_two(work.prompt, hit, restored,
+                                              ids, wids, wbase)
+        except BaseException:
+            self.free_pages.extend(ids)
+            self.wfree.extend(wids)
+            raise
+        page = self.cfg.page_size
+        self.stats["prefill_tokens"] += n_prompt - hit * page
+        self._settle_if_left_idle()
+        self._pages_rev += 1  # admission rewrites this slot's rows
+        self.page_table[slot_idx] = 0
+        self.page_table[slot_idx, :n_pages] = ids
+        self.wtable[slot_idx] = 0
+        self.wtable[slot_idx, :len(wids)] = wids
+        slot = _Slot(work=work, page_ids=ids, seq_len=n_prompt,
+                     cached_pages=hit, index=slot_idx, wpage_ids=wids,
+                     wbase=wbase, wstored=hit)
+        self._emit(slot, [self._pick(work, row_host)])
+        self.slots[slot_idx] = slot
+        f["subfloor_pages"] = 0
+        if sub and self._store_chain(work):
+            f["subfloor_pages"] = self._put_subfloor(slot, sub, hit, wbase)
+        work.probe = None  # consumed; a future re-admission re-probes
+        f["outcome"] = "admitted"
+        return True
+
+    def _prefill_two(self, prompt, hit, restored, ids, wids, wbase):
+        """The admission program of a model with two kinds of attention
+        layer: cold (`hit` 0) or prefix (`restored`: what `_restore`
+        returned for a hit of `hit` pages). `ids`: the full pools' ids
+        of the prompt's pages [0, n_pages); `wids`: the banded pools'
+        of [wbase, n_pages); None for either: nothing is written
+        (`first_token_logits`). Returns (the last real position's
+        logits row on the host, `sub`: the banded layers' pages [hit,
+        wbase) as device chunks, see `_page_out_two`)."""
+        cfg, sc = self.cfg, self.sc
+        page = cfg.page_size
+        suffix = prompt[hit * page:]
+        toks = self._pad_tokens(suffix)
+        n_pages = hit + toks.shape[1] // page
+        # pool ids by page of the sequence; the sentinels drop
+        m = sc.max_pages_per_seq
+        fa = np.full(hit + m, sc.total_pages, np.int32)
+        wa = np.full(hit + m, self._wpool_pages, np.int32)
+        if ids is not None:
+            fa[:n_pages] = ids
+        if wids is not None:
+            wa[wbase:n_pages] = wids
+        n_sub = max(0, wbase - hit)
+        s_real = self._to_device(np.int32(len(suffix)))
+        pools = (self.k_pages, self.v_pages, self.wk_pages, self.wv_pages)
+        fields = {"restored_pages": hit} if hit else {}
+        with self._span("istpu.model.prefill",
+                        program="prefix" if hit else "cold",
+                        tokens=len(suffix), padded_tokens=toks.shape[1],
+                        **fields):
+            if hit:
+                first_live = self._first_live(hit)
+                out = _admit_fused_px_wf(
+                    self.params, cfg, toks, restored, *pools,
+                    self._to_device(fa[:hit]),
+                    self._to_device(wa[first_live:hit]),
+                    self._to_device(fa[hit:]), self._to_device(wa[hit:]),
+                    s_real, model=self.model, n_sub=n_sub)
+            else:
+                out = _admit_fused_wf(
+                    self.params, cfg, toks, *pools, self._to_device(fa),
+                    self._to_device(wa), s_real, model=self.model,
+                    n_sub=n_sub)
+            (row_dev, self.k_pages, self.v_pages, self.wk_pages,
+             self.wv_pages, sub) = out
+            return np.asarray(row_dev), sub
+
+    def _put_subfloor(self, slot, sub, lo, hi):
+        """The banded layers' pages [lo, hi) of the slot's sequence,
+        which its admission computed below the band (`sub`, device
+        chunks of `_sub_chunk_pages` pages), to the store: they never
+        had a pool page, and the store's contract wants every full
+        page of every layer. On the engine thread, behind the first
+        token: one device-to-host transfer and one store batch a
+        chunk, every transfer started before the first is waited for,
+        one sync. Returns the pages written."""
+        digests = self._slot_digests(slot, hi)[lo:hi]
+        c = _sub_chunk_pages(self.cfg)
+        n = hi - lo
+        with self._span("istpu.cache.offload", slot.work.req.request_id,
+                        reason="subfloor", pages=n,
+                        bytes=n * self._wpage_bytes, padded_pages=n,
+                        puts=0) as f:
+            try:
+                for flat in sub:
+                    flat.copy_to_host_async()
+                for i, flat in enumerate(sub):
+                    keys = content_page_keys_by_page(
+                        digests[i * c:(i + 1) * c], self._win_layers)
+                    self._put_pages(keys, to_host(flat).reshape(
+                        -1, *self.cfg.kv_page_shape()))
+                    f["puts"] += 1
+                with self._span("istpu.cache.offload_sync"):
+                    self.store.conn.sync()
+            except Exception as e:
+                self._store_failed("offload", e)
+                return 0
+        slot.wstored = max(slot.wstored, hi)
+        self.stats["subfloor_pages_written"] += n
+        return n
+
     def idle(self):
         """For whoever drives an engine that has nothing to step
         (serving_http's loop, on every pass that finds no work): one
@@ -1404,6 +1843,15 @@ class ServingEngine:
         if now - self._ticked >= IDLE_TICK_S:
             self._ticked = now
             jax.block_until_ready(_tick(self._tick_x))
+
+    def _settle_if_left_idle(self):
+        """Behind a one-shot admission's program: `_settle`, for
+        SETTLE_S after an admission that found no sequence running."""
+        now = time.monotonic()
+        if not any(s is not None for s in self.slots):
+            self._left_idle = now
+        if now - self._left_idle < SETTLE_S:
+            self._settle()
 
     def _settle(self):
         """SETTLE_PROGRAMS trivial programs behind a one-shot
@@ -1533,13 +1981,23 @@ class ServingEngine:
             raise RuntimeError("first_token_logits needs an idle engine")
         prompt = [int(t) for t in prompt]
         page = self.cfg.page_size
-        window = getattr(self.cfg, "window", 0)
         work = _Work(req=Request("first-token-logits", prompt),
                      prompt=prompt)
         hit, digests = self._probe_hit(work)
+        if self._win_layers:
+            restored = None
+            if hit > 0:
+                try:
+                    restored, _ = self._restore(hit, digests,
+                                                self._first_live(hit))
+                except InfiniStoreKeyNotFound:
+                    hit = 0  # evicted between probe and restore
+            row, _ = self._prefill_two(
+                prompt, hit, restored, None, None,
+                self._wbase_after(-(-len(prompt) // page)))
+            return np.asarray(row, np.float32), hit
         if hit > 0:
-            first_live = max(0, hit * page - window + 1) // page \
-                if window else 0
+            first_live = self._first_live(hit)
             try:
                 restored, snap = self._restore(hit, digests, first_live)
             except InfiniStoreKeyNotFound:
@@ -1606,6 +2064,15 @@ class ServingEngine:
             self.page_table[slot_idx, len(slot.page_ids)] = ids[0]
             slot.page_ids.extend(ids)
             self._pages_rev += 1
+        while self._win_layers and \
+                slot.wbase + len(slot.wpage_ids) <= need_idx:
+            # The short table has room: `_shed_windows` ran before.
+            ids = self._walloc(1)
+            if ids is None:
+                return False
+            self.wtable[slot_idx, len(slot.wpage_ids)] = ids[0]
+            slot.wpage_ids.extend(ids)
+            self._pages_rev += 1
         return True
 
     def _ensure_page(self, slot_idx, slot):
@@ -1626,8 +2093,7 @@ class ServingEngine:
         writes, behind the pages and before the sync, the slot's
         boundary copy as the snapshot at the end of page n_full
         (`_offload_snapshot`): pages and snapshot at ONE depth."""
-        if (self.store is None or not self._store_ok
-                or not slot.work.req.cache):
+        if not self._store_chain(slot.work):
             return
         n_full = slot.seq_len // self.cfg.page_size
         if hi is not None:
@@ -1646,43 +2112,30 @@ class ServingEngine:
         # page contents must be durable in the store BEFORE the pool
         # page is freed for reuse (and before the caller hears `done`).
         n = n_full - lo
-        L = self.cfg.n_kv_layers
-        page_ids = slot.page_ids[lo:n_full]
-        c = min(self.sc.max_pages_per_seq,
-                max(1, OFFLOAD_CHUNK_BYTES // self._page_bytes))
+        new_digests = self._slot_digests(slot, n_full)[lo:]
+        # Two kinds of attention layer: the banded layers' part of the
+        # same offload, what the store lacks of the pages their pool
+        # still holds ([0, wstored) it has; [wstored, wbase) cannot be:
+        # a page leaves that pool through the store).
+        wlo = max(slot.wstored, slot.wbase) if self._win_layers else n_full
+        nw = max(0, n_full - wlo)
         with self._span("istpu.cache.offload", slot.work.req.request_id,
                         reason=reason, pages=n,
-                        bytes=n * self._page_bytes + self._snapshot_bytes,
+                        bytes=n * self._page_bytes + self._snapshot_bytes
+                        + (nw * self._wpage_bytes if nw else 0),
                         padded_pages=0, puts=0,
                         **self._snapshot_fields) as f:
-
-            def gather(a):
-                # The chunk's ids, padded to a bucket with the scratch
-                # page 0: those rows are the tail of the array and never
-                # reach the store.
-                part = page_ids[a:a + c]
-                ids = np.zeros(_offload_bucket(len(part), c), np.int32)
-                ids[:len(part)] = part
-                f["padded_pages"] += len(ids)
-                flat = _gather_pages(self.k_pages, self.v_pages,
-                                     self._to_device(ids))
-                if not self.sc.quantized_store:
-                    flat.copy_to_host_async()
-                return flat
-
-            new_digests = self._slot_digests(slot, n_full)[lo:]
             try:
-                flat = gather(0)
-                for a in range(0, n, c):
-                    ahead = gather(a + c) if a + c < n else None
-                    keys = content_page_keys_by_page(new_digests[a:a + c], L)
-                    # Quantized pages stay on the device: the store call
-                    # quantizes there, so only packed int8 crosses over.
-                    pages = flat if self.sc.quantized_store else to_host(flat)
-                    pages = pages.reshape(-1, *self.cfg.kv_page_shape())
-                    self._put_pages(keys, pages[:len(keys)])
-                    f["puts"] += 1
-                    flat = ahead
+                self._put_pool_pages(
+                    f, self.k_pages, self.v_pages, slot.page_ids[lo:n_full],
+                    new_digests, self._full_layers, self._page_bytes)
+                if nw:
+                    self._put_pool_pages(
+                        f, self.wk_pages, self.wv_pages,
+                        slot.wpage_ids[wlo - slot.wbase:
+                                       n_full - slot.wbase],
+                        self._slot_digests(slot, n_full)[wlo:],
+                        self._win_layers, self._wpage_bytes, _pow2_bucket)
                 if self.state is not None:
                     f["puts"] += self._offload_snapshot(slot,
                                                         new_digests[-1])
@@ -1695,10 +2148,119 @@ class ServingEngine:
                 return
         self.stats["offloaded_pages"] += n
         self.stats["snapshots_written"] += self.state is not None
+        if nw:
+            slot.wstored = n_full
         own = self._own_digests
         own.update(dict.fromkeys(new_digests))
         while len(own) > OWN_DIGESTS:
             del own[next(iter(own))]
+
+    def _chunk_pages(self, page_bytes):
+        """Pages of `page_bytes` each that one chunk of an offload
+        holds: at most OFFLOAD_CHUNK_BYTES, and a page table's
+        width."""
+        return min(self.sc.max_pages_per_seq,
+                   max(1, OFFLOAD_CHUNK_BYTES // page_bytes))
+
+    def _put_pool_pages(self, f, k_pool, v_pool, page_ids, digests, layers,
+                        page_bytes, bucket=_offload_bucket):
+        """Pages `page_ids` of one pair of pools to the store, page i
+        under `digests[i]`'s keys for `layers` (the pool's layers, by
+        their rank among those that keep pages). In chunks of at most
+        OFFLOAD_CHUNK_BYTES: each is one gather program, one
+        device-to-host transfer and one store batch, and chunk i + 1
+        is gathered and on its way to the host while chunk i is copied
+        into the store's pool. The caller syncs. `f`: the offload
+        span's fields (`padded_pages`, `puts`)."""
+        n = len(page_ids)
+        c = self._chunk_pages(page_bytes)
+
+        def gather(a):
+            # The chunk's ids, padded to a bucket with the scratch
+            # page 0: those rows are the tail of the array and never
+            # reach the store.
+            part = page_ids[a:a + c]
+            ids = np.zeros(bucket(len(part), c), np.int32)
+            ids[:len(part)] = part
+            f["padded_pages"] += len(ids)
+            flat = _gather_pages(k_pool, v_pool, self._to_device(ids))
+            if not self.sc.quantized_store:
+                flat.copy_to_host_async()
+            return flat
+
+        flat = gather(0)
+        for a in range(0, n, c):
+            ahead = gather(a + c) if a + c < n else None
+            keys = content_page_keys_by_page(digests[a:a + c], layers)
+            # Quantized pages stay on the device: the store call
+            # quantizes there, so only packed int8 crosses over.
+            pages = flat if self.sc.quantized_store else to_host(flat)
+            pages = pages.reshape(-1, *self.cfg.kv_page_shape())
+            self._put_pages(keys, pages[:len(keys)])
+            f["puts"] += 1
+            flat = ahead
+
+    def _shed_windows(self, active):
+        """Before a decode step of a model with two kinds of attention
+        layer: the banded layers' pages that lie wholly below a slot's
+        band floor (seq_len - band: decode masks below it) are shed,
+        written to the store where the store lacks them, then freed,
+        and the slot's short table moved up. Not a page an edge a
+        slot: nothing is shed until some slot has `_shed_pages` such
+        pages (half the table's slack) or a full table, and then EVERY
+        slot that has any sheds them in ONE offload
+        (`_offload_window`). The full layers' pages stay
+        (`_release`)."""
+        page = self.cfg.page_size
+        band = self.cfg.window_band
+        due = [(i, s, (s.seq_len - band) // page) for i, s in active]
+        due = [(i, s, dead) for i, s, dead in due if dead > s.wbase]
+        if not any(dead - s.wbase >= self._shed_pages
+                   or s.seq_len // page - s.wbase >= self._wtable_w
+                   for _, s, dead in due):
+            return
+        self._offload_window(due)
+        for i, s, dead in due:
+            n = dead - s.wbase
+            self.wfree.extend(s.wpage_ids[:n])
+            s.wpage_ids = s.wpage_ids[n:]
+            s.wbase = dead
+            s.wstored = max(s.wstored, dead)
+            self.wtable[i] = 0
+            self.wtable[i, :len(s.wpage_ids)] = s.wpage_ids
+            self.stats["window_pages_released"] += n
+        self._pages_rev += 1
+
+    def _offload_window(self, due):
+        """The banded layers' pages that `due` slots are about to shed
+        ([(slot index, slot, first page that stays)]), what the store
+        lacks of them, as one offload: one gather over all the slots'
+        pages, one sync. Durable in the store BEFORE the pool pages
+        are freed for reuse."""
+        ids, digests, slots = [], [], 0
+        for _, s, dead in due:
+            lo = max(s.wstored, s.wbase)
+            if dead <= lo or not self._store_chain(s.work):
+                continue
+            ids += s.wpage_ids[lo - s.wbase:dead - s.wbase]
+            digests += self._slot_digests(s, dead)[lo:dead]
+            slots += 1
+        if not ids:
+            return
+        with self._span("istpu.cache.offload", reason="window",
+                        pages=len(ids), slots=slots,
+                        bytes=len(ids) * self._wpage_bytes,
+                        padded_pages=0, puts=0) as f:
+            try:
+                self._put_pool_pages(f, self.wk_pages, self.wv_pages, ids,
+                                     digests, self._win_layers,
+                                     self._wpage_bytes, _pow2_bucket)
+                with self._span("istpu.cache.offload_sync"):
+                    self.store.conn.sync()
+            except Exception as e:
+                self._store_failed("offload", e)
+                return
+        self.stats["window_pages_offloaded"] += len(ids)
 
     def _offload_snapshot(self, slot, digest):
         """The slot's boundary copy to the store, keyed by `digest`
@@ -1726,6 +2288,8 @@ class ServingEngine:
         # left the sliding window — freeing them twice would hand the
         # same pool page to two slots.
         self.free_pages.extend(slot.page_ids[slot.released:])
+        if self._win_layers:
+            self.wfree.extend(slot.wpage_ids)
         self.slots[slot_idx] = None
         self._pages_rev += 1
 
@@ -1855,6 +2419,8 @@ class ServingEngine:
             while k & (k - 1):
                 k &= k - 1
 
+        if self._win_layers:
+            self._shed_windows(active)
         for i, s in active:
             if not self._ensure_pages(i, s, s.seq_len + k - 1):
                 if k > 1 and self._ensure_page(i, s):
@@ -1906,6 +2472,16 @@ class ServingEngine:
             token_dev = self._to_device(token)
             lens_dev = self._to_device(seq_lens)
             rows_dev = self._to_device(rows)
+            if self._win_layers:
+                # ... and the banded layers' short tables with their
+                # bases (inactive rows: scratch page 0 from position 0)
+                wrows = np.zeros_like(self.wtable)
+                wbase = np.zeros(self.sc.max_slots, dtype=np.int32)
+                for i, s in active:
+                    wrows[i] = self.wtable[i]
+                    wbase[i] = s.wbase * self.cfg.page_size
+                rows_dev = (rows_dev, self._to_device(wrows),
+                            self._to_device(wbase))
 
         if k > 1:
             with self._span("istpu.model.decode", program="decode_scan"):
@@ -1941,7 +2517,14 @@ class ServingEngine:
             return len(active)
 
         with self._span("istpu.model.decode", program="decode_fused"):
-            if self.state is None:
+            if self._win_layers:
+                (logits, nxt_dev, lens_next, self.k_pages, self.v_pages,
+                 self.wk_pages, self.wv_pages) = _decode_fused_wf(
+                    self.params, self.cfg, token_dev, lens_dev,
+                    self.k_pages, self.v_pages, self.wk_pages,
+                    self.wv_pages, rows_dev, model=self.model,
+                )
+            elif self.state is None:
                 logits, nxt_dev, lens_next, self.k_pages, self.v_pages = (
                     _decode_fused(
                         self.params, self.cfg, token_dev, lens_dev,

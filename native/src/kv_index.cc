@@ -1181,7 +1181,8 @@ uint64_t KVIndex::oldest_eligible_age(uint32_t si, bool held,
         auto mit = st.map.find(it->key);
         if (mit == st.map.end() || !mit->second.block) continue;
         const Entry& e = mit->second;
-        if (e.block.use_count() > 1) continue;  // pinned / queued spill
+        if (block_pinned(e)) continue;  // pinned / queued spill
+        if (block_shared(e) && disk_ != nullptr) continue;  // never spills
         if (!eviction_ && !(disk_ != nullptr && e.size < disk_min_fail)) {
             continue;  // spill-only mode and the tier refused this size
         }
@@ -1226,7 +1227,18 @@ size_t KVIndex::evict_from_stripe(uint32_t si, bool held, size_t want,
         // Skip entries whose blocks are pinned (reads in flight — or a
         // queued spill — hold extra refs): their memory would not
         // return to the pool yet.
-        if (e.block.use_count() > 1) {
+        // A block shared through content-addressed dedup is held once
+        // by EVERY committed sharer's entry: those holds are no pins.
+        // Counting them as pins left every sharer unevictable, its
+        // node stuck at its stripe's cold tail, the tail's age stale —
+        // and with one such node a stripe the strict pass below found
+        // nothing, so the relaxed pass swept whole stripes, fresh
+        // entries included (PERF.md, PR 35). Without a disk tier a
+        // sharer is hard-evicted like any victim (its bytes stay with
+        // the survivors, so nothing is counted as freed); with a tier
+        // it is skipped as before (a shared block never spills).
+        const bool shared = block_shared(e);
+        if (block_pinned(e) || (shared && disk_ != nullptr)) {
             ++it;
             continue;
         }
@@ -1293,7 +1305,7 @@ size_t KVIndex::evict_from_stripe(uint32_t si, bool held, size_t want,
         }
         // Count the block-granular pool footprint, not the logical size —
         // a 4 KB value in a 64 KB-block pool frees a whole block.
-        freed += (size_t(e.size) + bs - 1) / bs * bs;
+        if (!shared) freed += (size_t(e.size) + bs - 1) / bs * bs;
         // Remove the victim from the LRU in place and keep walking
         // coldward from the same position (restarting at rbegin would
         // re-scan every pinned cold entry per eviction).

@@ -620,6 +620,22 @@ class KVIndex {
         std::atomic<uint64_t> tail_age{UINT64_MAX};
     };
 
+    // Committed entries holding `e`'s block (content-addressed dedup:
+    // one hold a sharer; 1 without dedup), and whether anything beyond
+    // them — a read in flight, a queued spill — pins it. Under the
+    // entry's stripe lock; a sharer attaching or leaving in another
+    // stripe moves use_count before / after the holder count, so a
+    // race reads as pinned, never as free.
+    long block_holders(const Entry& e) const {
+        if (!dedup_enabled_) return 1;
+        long n = long(e.block->dedup_sharers.load(std::memory_order_relaxed));
+        return n > 1 ? n : 1;
+    }
+    bool block_shared(const Entry& e) const { return block_holders(e) > 1; }
+    bool block_pinned(const Entry& e) const {
+        return e.block.use_count() > block_holders(e);
+    }
+
     // One hash per op: the hooked hot paths compute hash_of(key) once
     // and derive both the stripe (low bits — identical to the
     // historical stripe_of) and the workload-profiler key from it.

@@ -126,15 +126,21 @@ def benchmark_tests_pinned_before_pr26(request, monkeypatch):
     if module.__name__.endswith("test_bench_observations"):
         if _granite4h_in_the_pinned_tests(node, name, module, monkeypatch):
             return
+        if _smallthinker_in_the_pinned_tests(node, name, module,
+                                             monkeypatch):
+            return
     if module.__name__.endswith("test_bench_manifest") \
             and name == "test_reduced_never_names_a_width":
         # It holds every configuration to mistral7b's widths (4096,
         # 14336, 32 / 8), which the three configurations before PR 31
         # share; granite4h-micro's file is held to its catalog row by
-        # tests/benchmark/test_bench_granite4h.py, and reduces nothing.
+        # tests/benchmark/test_bench_granite4h.py, and reduces nothing;
+        # smallthinker21b's by tests/benchmark/test_bench_smallthinker.py
+        # (its `reduced` is the depth and the two per-layer lists).
         bench = dict(module.BENCH)
         bench["configs"] = [c for c in bench["configs"]
-                            if c["name"] != "granite4h-micro"]
+                            if c["name"] not in ("granite4h-micro",
+                                                 "smallthinker21b")]
         monkeypatch.setattr(module, "BENCH", bench)
         return
     if module.__name__.endswith("test_bench_observations") \
@@ -166,11 +172,13 @@ def benchmark_tests_pinned_before_pr26(request, monkeypatch):
             names = [m["name"] for m in bench["per_layer"]]
             last = names.index("decode_host_p50_ms")
             bench["per_layer"] = bench["per_layer"][:last + 1]
-            # ... and the cells they listed then (PR 31 appended its
-            # cell to admit_hit_p50_ms's)
+            # ... and the cells they listed then (PR 31 and PR 35
+            # appended their cells to admit_hit_p50_ms's)
             for m in bench["per_layer"]:
-                if "granite4h-micro-sessions4k" in m.get("workloads", ()):
-                    m["workloads"].remove("granite4h-micro-sessions4k")
+                for later in ("granite4h-micro-sessions4k",
+                              "smallthinker21b-sessions12k"):
+                    if later in m.get("workloads", ()):
+                        m["workloads"].remove(later)
             return bench
 
         monkeypatch.setattr(module.manifest, "load", load_as_of_pr24)
@@ -221,4 +229,52 @@ def _granite4h_in_the_pinned_tests(node, name, module, monkeypatch):
     monkeypatch.setattr(profiling, "spans", lambda: by_hand.RING)
     monkeypatch.setattr(_scoped_ops, "seconds",
                         lambda obs, kind, scopes: by_hand.SCOPED[kind])
+    return True
+
+
+def _smallthinker_in_the_pinned_tests(node, name, module, monkeypatch):
+    """PR 35 (`model_config`: may add benchmark files, edit none) added
+    the configuration smallthinker21b and five per-layer metrics; as
+    `_granite4h_in_the_pinned_tests` for PR 31's. Returns True where it
+    dealt with the test.
+
+    - the table test gets the five new metrics' hand-worked numbers
+      from tests/benchmark/smallthinker_by_hand.py, on the same
+      synthetic window with the new configuration's file as `obs.conf`;
+    - "an accepted configuration resolves to today's defaults": this
+      one names a costs module and tolerances of its own, so its cases
+      are skipped and tests/benchmark/test_bench_smallthinker.py holds
+      what it names instead."""
+    import pytest
+
+    params = getattr(getattr(node, "callspec", None), "params", {})
+    if name == "test_an_accepted_configuration_resolves_to_todays_defaults":
+        if params.get("config") == "smallthinker21b":
+            pytest.skip("smallthinker21b brings its own costs and "
+                        "tolerances: test_bench_smallthinker.py")
+        return False
+    if name != "test_reader_gives_the_number_worked_by_hand":
+        return False
+    import smallthinker_by_hand as by_hand
+
+    if params.get("name") not in by_hand.BY_HAND:
+        return False
+    from benchmark.lib import serve
+    from benchmark.metrics import _scoped_ops
+    from infinistore_tpu.utils import profiling
+
+    table, window = module.expected, module.full_window
+
+    def full_window():
+        obs = window()
+        obs.conf = serve.load_config("benchmark/configs/smallthinker21b.json")
+        return obs
+
+    monkeypatch.setattr(module, "full_window", full_window)
+    monkeypatch.setattr(module, "expected",
+                        lambda obs: {**table(window()), **by_hand.BY_HAND})
+    monkeypatch.setattr(profiling, "spans", lambda: by_hand.RING)
+    monkeypatch.setattr(
+        _scoped_ops, "seconds",
+        lambda obs, kind, scopes: by_hand.SCOPED[kind, tuple(scopes)])
     return True

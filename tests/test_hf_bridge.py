@@ -8,6 +8,7 @@ path)."""
 import numpy as np
 import pytest
 
+import jax
 import jax.numpy as jnp
 
 torch = pytest.importorskip("torch")
@@ -393,13 +394,30 @@ def test_mistral_sliding_window_paged_decode_matches_transformers():
     assert int(ours.argmax()) == int(ref.argmax())
 
 
-def test_qwen2_mixed_window_layers_raises():
+def test_qwen2_mixed_window_layers_map_onto_the_per_layer_spec():
+    """max_window_layers bottom layers keep full attention, the others
+    the band: the per-layer spec (LlamaConfig.layer_bands), which the
+    serving engine holds as two kinds of page. Logits match
+    transformers with the band genuinely active (24 > 8)."""
     cfg = transformers.Qwen2Config(
-        num_hidden_layers=8, use_sliding_window=True,
-        sliding_window=64, max_window_layers=4,
+        vocab_size=128, hidden_size=64, intermediate_size=160,
+        num_hidden_layers=4, num_attention_heads=4, num_key_value_heads=2,
+        max_position_embeddings=128, rms_norm_eps=1e-5, rope_theta=10000.0,
+        use_sliding_window=True, sliding_window=8, max_window_layers=2,
+        tie_word_embeddings=False, attn_implementation="eager",
     )
-    with pytest.raises(NotImplementedError, match="mixed per-layer"):
-        hf.config_from_hf(cfg)
+    jcfg = hf.config_from_hf(cfg, page_size=8)
+    assert jcfg.window == 0 and jcfg.layer_windows == (0, 0, 8, 8)
+    assert jcfg.two_kinds and jcfg.window_band == 8
+    torch.manual_seed(41)
+    model = transformers.Qwen2ForCausalLM(cfg).eval()
+    jcfg, params = hf.load_hf(model, page_size=8, dtype="float32")
+    tokens = np.random.default_rng(42).integers(0, 128, (1, 24),
+                                                dtype=np.int64)
+    with torch.no_grad():
+        ref = model(torch.from_numpy(tokens)).logits.numpy()
+    ours, _ = llama.prefill(params, jcfg, jnp.asarray(tokens, jnp.int32))
+    assert np.abs(np.asarray(ours) - ref).max() < 2e-4
 
 
 def test_windowed_mistral_serves_through_engine():
@@ -527,13 +545,14 @@ def test_exact_gelu_checkpoint_matches():
     assert np.abs(ours - ref).max() < 2e-4
 
 
-def _tiny_mixtral():
+def _tiny_mixtral(sliding_window=None):
     cfg = transformers.MixtralConfig(
         vocab_size=128, hidden_size=64, intermediate_size=96,
         num_hidden_layers=2, num_attention_heads=4, num_key_value_heads=2,
         num_local_experts=4, num_experts_per_tok=2,
         max_position_embeddings=128, rms_norm_eps=1e-5,
-        sliding_window=None, tie_word_embeddings=False,
+        sliding_window=sliding_window, tie_word_embeddings=False,
+        attn_implementation="eager",
     )
     torch.manual_seed(61)
     return transformers.MixtralForCausalLM(cfg).eval()
@@ -642,6 +661,38 @@ def test_mixtral_rope_scaling_rejected():
         hf.moe_config_from_hf(cfg)
 
 
+def test_windowed_mixtral_matches_transformers():
+    """A windowed Mixtral with the band genuinely active (24 > 8):
+    logits parity with transformers, as the dense family's windowed
+    Mistral has."""
+    from infinistore_tpu.models import moe
+
+    model = _tiny_mixtral(sliding_window=8)
+    jcfg, params = hf.load_hf_moe(model, page_size=8, dtype="float32")
+    assert jcfg.window == 8
+    tokens = np.random.default_rng(63).integers(0, 128, (2, 24),
+                                                dtype=np.int64)
+    with torch.no_grad():
+        ref = model(torch.from_numpy(tokens)).logits.numpy()
+        full = _tiny_mixtral()(torch.from_numpy(tokens)).logits.numpy()
+    ours, _, _ = moe.forward_dense(params, jcfg,
+                                   jnp.asarray(tokens, jnp.int32))
+    assert np.abs(np.asarray(ours) - ref).max() < 2e-4
+    assert np.abs(full - ref).max() > 1e-2  # the band changed something
+
+
+def test_mixtral_sliding_window_maps_onto_the_one_band():
+    """The MoE bridge takes a window: MoEConfig.window, which the
+    shared stack applies in every attention path whatever the
+    feed-forward block (it refused any window before PR 35)."""
+    jcfg = hf.moe_config_from_hf(transformers.MixtralConfig(
+        num_hidden_layers=2, sliding_window=4096))
+    assert jcfg.window == 4096 and not jcfg.two_kinds
+    assert jcfg.layer_windows == (4096, 4096)
+    assert hf.moe_config_from_hf(
+        transformers.MixtralConfig(sliding_window=None)).window == 0
+
+
 def test_mixtral_attention_bias_rejected():
     """self_attn.*.bias tensors have no slot in the MoE attention —
     dropping them silently would shift every attention output, so the
@@ -654,3 +705,91 @@ def test_mixtral_attention_bias_rejected():
     sd = {"model.layers.0.self_attn.v_proj.bias": torch.zeros(8)}
     with pytest.raises(NotImplementedError, match="attention_bias"):
         hf.moe_params_from_hf(sd, jcfg)
+
+
+# -- SmallThinker: full and banded layers, many small experts ---------------
+SMALLTHINKER = dict(
+    head_dim=16, hidden_size=64, max_position_embeddings=4096,
+    model_name="tiny", moe_ffn_hidden_size=32,
+    moe_num_active_primary_experts=2, moe_num_primary_experts=8,
+    moe_primary_router_apply_softmax=True, norm_topk_prob=True,
+    num_attention_heads=4, num_hidden_layers=4, num_key_value_heads=2,
+    rms_norm_eps=1e-6, rope_layout=[0, 1, 1, 1], rope_scaling=None,
+    rope_theta=1500000, sliding_window_layout=[0, 1, 1, 1],
+    sliding_window_size=32, tie_word_embeddings=False, vocab_size=128)
+
+
+def _st(**changed):
+    import types
+
+    return types.SimpleNamespace(**{**SMALLTHINKER, **changed})
+
+
+def test_smallthinker_config_maps_every_key():
+    cfg = hf.smallthinker_config_from_hf(_st(), page_size=8)
+    assert (cfg.d_model, cfg.n_layers, cfg.n_heads, cfg.n_kv_heads,
+            cfg.head_dim, cfg.d_ff, cfg.n_experts, cfg.top_k,
+            cfg.vocab_size, cfg.max_seq, cfg.rope_theta, cfg.norm_eps,
+            cfg.act, cfg.early_router) == (
+        64, 4, 4, 2, 16, 32, 8, 2, 128, 4096, 1.5e6, 1e-6, "relu", True)
+    assert cfg.layer_windows == (0, 32, 32, 32)
+    assert cfg.layer_ropes == (False, True, True, True)
+    # a rope_layout that is not the window layout's is held too: the
+    # per-layer spec carries the two apart
+    odd = hf.smallthinker_config_from_hf(_st(rope_layout=[1, 0, 1, 0]))
+    assert odd.layer_ropes == (True, False, True, False)
+    assert odd.layer_windows == (0, 32, 32, 32)
+
+
+@pytest.mark.parametrize("changed,match", [
+    ({"moe_primary_router_apply_softmax": False}, "apply_softmax"),
+    ({"norm_topk_prob": False}, "norm_topk_prob"),
+    ({"rope_scaling": {"rope_type": "yarn", "factor": 4.0}}, "rope_scaling"),
+    ({"tie_word_embeddings": True}, "tie_word_embeddings"),
+    ({"rope_layout": [0, 1, 1]}, "rope_layout"),
+    ({"sliding_window_layout": [0, 1, 1, 1, 0]}, "sliding_window_layout"),
+])
+def test_smallthinker_bridge_refuses_what_is_not_implemented(changed, match):
+    with pytest.raises(NotImplementedError, match=match):
+        hf.smallthinker_config_from_hf(_st(**changed))
+
+
+def test_smallthinker_params_round_trip_a_state_dict():
+    """transformers here has no SmallThinker class, so no parity with
+    it: a state dict under the family's names, made from our own
+    parameters ([out, in], as torch stores them), loads back equal; an
+    attention bias or a missing expert is refused by name."""
+    from infinistore_tpu.models import smallthinker
+
+    cfg = hf.smallthinker_config_from_hf(_st(), page_size=8)
+    params = smallthinker.init_params(jax.random.PRNGKey(3), cfg)
+    sd = {"model.embed_tokens.weight": np.asarray(params["embed"]),
+          "model.norm.weight": np.asarray(params["final_ln"]),
+          "lm_head.weight": np.asarray(params["lm_head"]).T}
+    for li, layer in enumerate(params["layers"]):
+        p = f"model.layers.{li}."
+        sd[p + "input_layernorm.weight"] = np.asarray(layer["ln1"])
+        sd[p + "post_attention_layernorm.weight"] = np.asarray(layer["ln2"])
+        for ours, theirs in (("wq", "q_proj"), ("wk", "k_proj"),
+                             ("wv", "v_proj"), ("wo", "o_proj")):
+            sd[p + f"self_attn.{theirs}.weight"] = np.asarray(layer[ours]).T
+        m = p + "block_sparse_moe."
+        sd[m + "primary_router.weight"] = np.asarray(layer["router"]).T
+        for e in range(cfg.n_experts):
+            for ours, theirs in (("e_gate", "gate"), ("e_up", "up"),
+                                 ("e_down", "down")):
+                sd[m + f"experts.{e}.{theirs}.weight"] = np.asarray(
+                    layer[ours][e]).T
+    back = hf.smallthinker_params_from_hf(sd, cfg)
+    assert jax.tree_util.tree_structure(back) \
+        == jax.tree_util.tree_structure(params)
+    for a, b in zip(jax.tree_util.tree_leaves(back),
+                    jax.tree_util.tree_leaves(params)):
+        assert a.dtype == b.dtype and np.array_equal(a, b)
+    with pytest.raises(NotImplementedError, match="bias"):
+        hf.smallthinker_params_from_hf(
+            {**sd, "model.layers.0.self_attn.q_proj.bias": np.zeros(64)},
+            cfg)
+    del sd["model.layers.1.block_sparse_moe.experts.7.up.weight"]
+    with pytest.raises(KeyError, match="experts.7.up"):
+        hf.smallthinker_params_from_hf(sd, cfg)

@@ -590,3 +590,32 @@ def test_pool_operand_that_needs_padding_is_sliced_first(kind, n_heads,
     )
     assert ranks == [3, 3]
     assert pads and all(r < 5 for r in pads)
+
+
+@pytest.mark.parametrize("window", [0, 20])
+def test_a_group_of_seven_pads_the_group_and_the_pool_goes_whole(window):
+    """28 query heads over 4 kv heads (SmallThinker): no padding of kv
+    heads short of 16 makes 7 a row a sublane multiple, and padding
+    them would slice, copy and widen a layer of the pool on every
+    layer's call (2.6 GB of temporaries in that model's decode step,
+    lowered for a v5e). The wrapper pads the group with a zero query
+    row instead: the 5-D pool goes to the kernel whole, and the result
+    is the XLA reference's."""
+    from infinistore_tpu.ops import paged_attention as xla_ref
+
+    fn, q, k, v, pt, sl = _pool_case("decode", 28, 4, 128, 31, jnp.bfloat16)
+    k_pool = _as_pool(k, 3, 1, 5)
+    v_pool = _as_pool(v, 3, 1, 6)
+    got = fn(q, k_pool, v_pool, pt, sl, interpret=True, window=window,
+             layer=1)
+    want = xla_ref.paged_decode_attention(q, k, v, pt, sl, window=window)
+    assert got.shape == want.shape == (2, 28, 128)
+    np.testing.assert_allclose(np.asarray(got, np.float32),
+                               np.asarray(want, np.float32), atol=2e-2)
+    sliced = fn(q, k, v, pt, sl, interpret=True, window=window)
+    np.testing.assert_allclose(np.asarray(got, np.float32),
+                               np.asarray(sliced, np.float32), atol=1e-2)
+    ranks, pads = _kernel_operand_ranks(
+        lambda *a: fn(*a, interpret=True, window=window, layer=1),
+        q, k_pool, v_pool, pt, sl)
+    assert ranks == [5, 5] and all(r < 5 for r in pads)
