@@ -1,39 +1,68 @@
-"""Pallas TPU kernel: flash-decode attention over paged KV.
+"""Pallas TPU kernels: flash attention over paged KV.
 
 The XLA implementation (paged_attention.paged_decode_attention) gathers
 every page into one [batch, T, heads, hd] tensor in HBM before the
-matmuls. This kernel streams pages HBM → VMEM instead: the grid runs
-(batch, max_pages); each step DMAs exactly one KV page — selected by the
-scalar-prefetched page table, so the DMA address is known before the body
-runs (pltpu.PrefetchScalarGridSpec) — computes the partial attention on
-the MXU, and folds it into an online-softmax accumulator held in VMEM
-scratch. HBM traffic is exactly one pass over the pages a sequence
-actually uses; nothing is materialized.
+matmuls. These kernels stream pages HBM → VMEM instead, pick each page
+through the scalar-prefetched page table (pltpu.PrefetchScalarGridSpec),
+compute the partial attention on the MXU and fold it into an
+online-softmax accumulator held in VMEM scratch. Nothing is
+materialized.
 
-Operand layout. The bf16 decode and verify kernels take K and V in one
-of two forms, chosen by the operand's shape alone (`_kv_operand`):
+**The bf16 decode kernel** (`paged_flash_decode`, one call a layer in
+every decode step of every cell) pays for live pages, not for table
+length. Its grid is (batch,): one step a sequence. K and V go to the
+call in HBM as they lie (`memory_space=pl.ANY`) and the kernel issues
+its own asynchronous copies, one a page, of the next BLOCK of P pages
+into one half of a double buffer while it folds the current block, one
+`_attend_rows` over P x page keys. The walk over a sequence's blocks is
+a loop whose bounds come from `seq_lens`: from the block that holds the
+band's floor (`window`, else 0) to the block that holds position
+seq_len - 1; a sequence's last block starts the next sequence's first
+copy, so the copies run ahead across grid steps too. An inactive row
+(length 1 over table entry 0) costs one block; a table entry past a
+sequence's last page, below its band or beyond the table (a table that
+is no multiple of P ends in a partial block) costs nothing: it is
+neither copied nor, being masked, attended. P follows the shapes
+(`_pages_per_block`); no option sets it. Before this form the grid was
+(batch, max_pages) with ONE page a step, live or not, at 0.2-0.3 us a
+step whatever it held: 16 x 192 steps a layer in mistral7b, nine in ten
+dead (PERF.md, PR 36). The pattern is the one of JAX's own
+`pallas/ops/tpu/paged_attention` kernel on this repo's pool layout.
+
+`paged_flash_verify` (m tokens a sequence) and
+`paged_flash_decode_quantized` (int8 pages) keep the one-page grid
+(batch, max_pages): each step one page by BlockSpec, its index map
+frozen at the sequence's last used page (`_make_page_idx`), folded by
+`_attend`. No benchmark cell runs them.
+
+Operand layout. The bf16 kernels take K and V in one of two forms,
+chosen by the operand's shape alone (`_kv_aligned`):
 
 - the WHOLE pool [n_layers, n_pages, page, n_kv, hd] plus a static
   `layer`, when head_dim is a lane multiple (128) and the kv heads
   already make the query rows a sublane multiple. The pool goes to the
-  call as it lies in HBM, the block is one page (None, 1, page, n_kv,
-  hd) and the index map leads with the layer, so the serving step never
-  produces a layer-sized array. It is NOT viewed as [..., page,
-  n_kv * hd]: on the TPU's tiled layout (the last two dims in (8,128)
-  tiles) that reshape is a relayout, and XLA materialised the whole pool
-  for it, once per layer and kind (seen in the AOT-compiled decode
-  program of PR 25). The kernel body is the same either way: a page
-  block reads as [page, n_kv, hd].
+  call as it lies in HBM and the kernel indexes the layer itself, so
+  the serving step never produces a layer-sized array. Verify takes it
+  5-D, a block (None, 1, page, n_kv, hd). Decode views a page as
+  [page * n_kv, hd] ROWS (row = token * n_kv + kv head): merging (page,
+  n_kv) leaves every (8, 128) tile of the TPU's tiled layout where it
+  is, a bitcast for XLA, and a block of pages is then one
+  [keys * n_kv, hd] matmul operand with no relayout in the kernel. The
+  pool is NOT viewed as [..., page, n_kv * hd]: that reshape moves
+  tiles, and XLA materialised the whole pool for it, once per layer and
+  kind (seen in the AOT-compiled decode program of PR 25).
+  tests/test_model.py compiles the three decode programs for a
+  described v5e and holds their temporaries under a layer of the pool.
 - one layer [n_pages, page, n_kv, hd] (or a pool that needs padding,
   which is sliced to its layer first): the wrapper pads head_dim to a
-  lane multiple of 128 and the kv heads to the sublane multiple, and
-  flattens pages to [n_pages, page, n_kv * hd]. That costs a copy of
-  the layer per call. Padding contributes zeros to logits and is sliced
-  off the output.
+  lane multiple of 128 and the kv heads to the sublane multiple (rows
+  for decode, [n_pages, page, n_kv * hd] for verify). That costs a copy
+  of the layer per call. Padding contributes zeros to logits and is
+  sliced off the output.
 
-`decode_attention` picks this kernel on TPU backends and falls back to
-the XLA gather path elsewhere (tests run the kernel in interpret mode so
-CPU CI covers the same code path bit-for-bit).
+`decode_attention` picks the decode kernel on TPU backends and falls
+back to the XLA gather path elsewhere (tests run the kernels in
+interpret mode so CPU CI covers the same code path).
 """
 
 import functools
@@ -47,39 +76,177 @@ from jax.experimental.pallas import tpu as pltpu
 from . import paged_attention as xla_ref
 
 
-def _kernel(page_tbl_ref, seq_lens_ref, q_ref, k_ref, v_ref, o_ref,
-            acc_ref, m_ref, l_ref, *, page_size, n_kv, hd, n_heads, scale,
-            window=0):
+# The decode kernel's block: K and V of `_pages_per_block` pages, two
+# halves of each, within this many bytes of VMEM, and at most this many
+# keys (their logits over every kv head stay in registers).
+_BLOCK_VMEM_BYTES = 2 << 20
+_BLOCK_MAX_KEYS = 512
+
+
+def _pages_per_block(page_size, page_bytes, max_pages):
+    """Pages the decode kernel fetches and folds at once, from what the
+    call can see: as many as keep the two halves of K's and of V's
+    buffer within `_BLOCK_VMEM_BYTES` (mistral7b: 32 KB a page a kind,
+    so 16 pages = 256 keys), at least the 128 keys that fill one lane
+    tile of logits, at most `_BLOCK_MAX_KEYS`, and never more than the
+    table holds."""
+    lane_tile = -(-128 // page_size)
+    fit = _BLOCK_VMEM_BYTES // (4 * page_bytes)
+    pages = max(lane_tile, min(fit, _BLOCK_MAX_KEYS // page_size))
+    return max(1, min(pages, max_pages))
+
+
+def _kernel(page_tbl_ref, seq_lens_ref, q_ref, k_hbm, v_hbm, o_ref,
+            k_buf, v_buf, sems, slot_ref, acc_ref, m_ref, l_ref, *,
+            layer, page_size, n_kv, n_heads, scale, window=0):
+    """One grid step a sequence. K and V stay in HBM, a page a
+    [page * n_kv, hd] slab of rows (row = token * n_kv + kv head); the
+    kernel walks the sequence's LIVE blocks of P pages (from the one
+    that holds the band's floor to the one that holds position
+    seq_len - 1), copies each block's live pages itself, one
+    asynchronous copy a page, into one half of a double buffer while it
+    folds the other half, and starts the next sequence's first block
+    behind its own last. Dead pages and pages beyond the table are
+    neither copied nor (being masked) attended; what they leave in a
+    buffer is an earlier page or the zeros the first grid step wrote,
+    never uninitialised bits that 0 x V could turn into a NaN. q and
+    the output are whole in VMEM for the call: a grid step moves
+    nothing but pages."""
     b = pl.program_id(0)
-    j = pl.program_id(1)
-    n_pages = pl.num_programs(1)
+    n_rows = pl.num_programs(0)
+    max_pages = page_tbl_ref.shape[1]
+    n_pool_pages = k_hbm.shape[-3]
+    _, P, page_rows, hd = k_buf.shape
+    block = P * page_size
 
-    @pl.when(j == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        m_ref[...] = jnp.full_like(m_ref, -1e30)
-        l_ref[...] = jnp.zeros_like(l_ref)
+    def live_pages(row):
+        """(first, last) page of `row` that holds a key of its band;
+        last clamped into the table as `_make_page_idx` clamps it."""
+        seq_len = seq_lens_ref[row]
+        last = jnp.minimum(jnp.maximum(seq_len - 1, 0) // page_size,
+                           max_pages - 1)
+        first = jnp.maximum(seq_len - window, 0) // page_size if window else 0
+        return first, last
 
+    def copies(row, blk, slot, act):
+        """`act` (start or wait) on the copy of every live page of block
+        `blk` of `row` into half `slot`. A loop over the live pages
+        alone, not P guarded copies: a block with one live page (an
+        inactive row's) then costs one page's scalar work, which read
+        16 us a call of 16 such rows against 26-38 unrolled, for 4 %
+        more on whole tables (PERF.md, PR 36)."""
+        first, last = live_pages(row)
+
+        def page_copies(j, carry):
+            page = jnp.clip(page_tbl_ref[row, j], 0, n_pool_pages - 1)
+            for kind, (hbm, buf) in enumerate(((k_hbm, k_buf),
+                                               (v_hbm, v_buf))):
+                src = hbm.at[page] if layer is None else hbm.at[layer,
+                                                                page]
+                act(pltpu.make_async_copy(
+                    src, buf.at[slot, j - blk * P], sems.at[kind, slot]))
+            return carry
+
+        jax.lax.fori_loop(jnp.maximum(first, blk * P),
+                          jnp.minimum(last, blk * P + P - 1) + 1,
+                          page_copies, None)
+
+    first, last = live_pages(b)
+    first_blk = first // P
+    n_blocks = last // P - first_blk + 1
+
+    @pl.when(b == 0)
+    def _first():
+        slot_ref[0] = 0
+        v_buf[...] = jnp.zeros_like(v_buf)
+        copies(b, first_blk, 0, lambda c: c.start())
+
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+    m_ref[...] = jnp.full_like(m_ref, -1e30)
+    l_ref[...] = jnp.zeros_like(l_ref)
+
+    slot0 = slot_ref[0]
     seq_len = seq_lens_ref[b]
-    start = j * page_size
-    # Sliding window: the band floor (current token is seq_len - 1);
-    # pages wholly below it are skipped for compute.
     low = jnp.maximum(seq_len - window, 0) if window else None
-    live = start < seq_len
-    if window:
-        live = jnp.logical_and(live, start + page_size > low)
+    next_row = jnp.minimum(b + 1, n_rows - 1)
+    next_first_blk = live_pages(next_row)[0] // P
+    q = q_ref[b]
 
-    @pl.when(live)
-    def _step():
-        _attend(q_ref[0],
-                k_ref[0].reshape(page_size, n_kv, hd),
-                v_ref[0].reshape(page_size, n_kv, hd),
-                acc_ref, m_ref, l_ref, n_kv=n_kv, n_heads=n_heads,
-                scale=scale, start=start, seq_len=seq_len, low=low)
+    def fold(i, carry):
+        slot = (slot0 + i) % 2
+        blk = first_blk + i
+        ends = i + 1 == n_blocks
 
-    @pl.when(j == n_pages - 1)
-    def _finish():
-        o_ref[0] = (acc_ref[...] / l_ref[...]).astype(o_ref.dtype)
+        @pl.when(jnp.logical_or(jnp.logical_not(ends), b + 1 < n_rows))
+        def _prefetch():
+            copies(jnp.where(ends, next_row, b),
+                   jnp.where(ends, next_first_blk, blk + 1), 1 - slot,
+                   lambda c: c.start())
+
+        copies(b, blk, slot, lambda c: c.wait())
+        _attend_rows(q,
+                     k_buf[slot].reshape(P * page_rows, hd),
+                     v_buf[slot].reshape(P * page_rows, hd),
+                     acc_ref, m_ref, l_ref, n_kv=n_kv, n_heads=n_heads,
+                     scale=scale, start=blk * block, seq_len=seq_len,
+                     low=low)
+        return carry
+
+    jax.lax.fori_loop(0, n_blocks, fold, None)
+    slot_ref[0] = (slot0 + n_blocks) % 2
+    o_ref[b] = (acc_ref[...] / l_ref[...]).astype(o_ref.dtype)
+
+
+def _attend_rows(q, k, v, acc_ref, m_ref, l_ref, *, n_kv, n_heads, scale,
+                 start, seq_len, low=None):
+    """One block's online-softmax fold of the decode kernel: ONE product
+    over every kv head. q: [n_heads, D], a kv head's query rows
+    together; k/v: [T * n_kv, D], row = token * n_kv + kv head, as the
+    pages lie. Logits are [n_heads, T * n_kv]; a column of another kv
+    head than the row's is masked like a dead position. That is n_kv
+    times the products and exponentials the keys need, on units a decode
+    step leaves idle, against `_attend`'s strided read of every kv
+    head's rows out of [T, n_kv, D] (the block form read 1.5-2 x slower
+    with it on the chip, PERF.md, PR 36). Same masks as `_attend`:
+    position < seq_len, and >= low for a band."""
+    group = n_heads // n_kv
+    precision = (
+        jax.lax.Precision.HIGHEST
+        if q.dtype == jnp.float32
+        else jax.lax.Precision.DEFAULT
+    )
+    logits = jax.lax.dot_general(
+        q, k, (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32, precision=precision,
+    ) * scale  # [n_heads, T * n_kv]
+    col = jax.lax.broadcasted_iota(jnp.int32, logits.shape, 1)
+    row = jax.lax.broadcasted_iota(jnp.int32, logits.shape, 0)
+    pos = start + jax.lax.div(col, n_kv)
+    valid = jnp.logical_and(pos < seq_len,
+                            jax.lax.rem(col, n_kv) == jax.lax.div(row, group))
+    if low is not None:
+        valid = jnp.logical_and(valid, pos >= low)
+    logits = jnp.where(valid, logits, -1e30)
+    _fold_softmax(
+        logits, acc_ref, m_ref, l_ref,
+        lambda p: jax.lax.dot_general(
+            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32, precision=precision,
+        ))  # [n_heads, D]
+
+
+def _fold_softmax(logits, acc_ref, m_ref, l_ref, weighted_values):
+    """The online-softmax update every paged kernel ends a fold with:
+    masked float32 `logits` [rows, keys] into the running maximum
+    `m_ref`, denominator `l_ref` and accumulator `acc_ref`;
+    `weighted_values(p)` is the fold's p x V product, [rows, D]."""
+    m_prev = m_ref[...]  # [rows, 1]
+    m_new = jnp.maximum(m_prev, jnp.max(logits, axis=-1, keepdims=True))
+    p = jnp.exp(logits - m_new)  # [rows, keys]
+    alpha = jnp.exp(m_prev - m_new)
+    acc_ref[...] = acc_ref[...] * alpha + weighted_values(p)
+    m_ref[...] = m_new
+    l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=-1, keepdims=True)
 
 
 def _kernel_q(page_tbl_ref, seq_lens_ref, q_ref, kq_ref, ks_ref, vq_ref,
@@ -167,29 +334,21 @@ def _attend(q, kv, vv, acc_ref, m_ref, l_ref, *, n_kv, n_heads, scale,
         valid = jnp.logical_and(valid, pos >= low)
     logits = jnp.where(valid, logits, -1e30)
 
-    m_prev = m_ref[...]  # [rows, 1]
-    l_prev = l_ref[...]
-    m_cur = jnp.max(logits, axis=-1, keepdims=True)  # [rows, 1]
-    m_new = jnp.maximum(m_prev, m_cur)
-    p = jnp.exp(logits - m_new)  # [rows, P]
-    l_cur = jnp.sum(p, axis=-1, keepdims=True)
-    alpha = jnp.exp(m_prev - m_new)
+    def weighted_values(p):  # [rows, P] -> [rows, D]
+        pv_blocks = []
+        for h in range(n_kv):
+            ph = p[h * rows_per_kv : (h + 1) * rows_per_kv]  # [rows_kv, P]
+            vvh = vv[:, h]  # [P, D]
+            pv_blocks.append(
+                jax.lax.dot_general(
+                    ph.astype(vvh.dtype), vvh, (((1,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32,
+                    precision=precision,
+                )  # [rows_kv, D]
+            )
+        return jnp.concatenate(pv_blocks, axis=0)
 
-    pv_blocks = []
-    for h in range(n_kv):
-        ph = p[h * rows_per_kv : (h + 1) * rows_per_kv]  # [rows_kv, P]
-        vvh = vv[:, h]  # [P, D]
-        pv_blocks.append(
-            jax.lax.dot_general(
-                ph.astype(vvh.dtype), vvh, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32,
-                precision=precision,
-            )  # [rows_kv, D]
-        )
-    pv = jnp.concatenate(pv_blocks, axis=0)  # [rows, D]
-    acc_ref[...] = acc_ref[...] * alpha + pv
-    m_ref[...] = m_new
-    l_ref[...] = l_prev * alpha + l_cur
+    _fold_softmax(logits, acc_ref, m_ref, l_ref, weighted_values)
 
 
 def _pad_to(x, axis, mult):
@@ -232,28 +391,38 @@ def _make_page_idx(page_size, n_pages, tok_offset=0, layer=None):
     return _page_idx
 
 
-def _kv_operand(pages, layer, n_kv_p):
-    """The K or V operand of the bf16 paged kernels, from a layer
+def _kv_aligned(pages, layer, n_kv_p):
+    """K or V for the bf16 paged kernels, from a layer
     [n_pages, page, n_kv, hd] or from the whole pool [n_layers, n_pages,
     page, n_kv, hd] plus a static `layer`.
 
     A pool whose lanes and kv heads are already tile-aligned
-    (hd % 128 == 0, n_kv == n_kv_p) goes to the kernel WHOLE and as it
-    is, 5-D (see the module docstring for why it is not flattened).
+    (hd % 128 == 0, n_kv == n_kv_p) comes back WHOLE and as it is, 5-D.
     Anything else is sliced to its layer first and that slice is padded
-    and flattened to [n_pages, page, n_kv_p * hd_p] — padding the pool
-    itself would copy every layer on every layer's call. The shape
-    decides, nothing else does."""
+    to [n_pages, page, n_kv_p, hd_p] — padding the pool itself would
+    copy every layer on every layer's call. The shape decides, nothing
+    else does."""
     if pages.ndim == 5:
         n_kv, hd = pages.shape[3:]
         if hd % 128 == 0 and n_kv == n_kv_p:
             return pages
         pages = pages[layer]
     pages, _ = _pad_to(pages, 3, 128)
-    n_pages, page_size, n_kv, hd_p = pages.shape
+    n_kv = pages.shape[2]
     if n_kv_p != n_kv:
         pages = jnp.pad(pages, ((0, 0), (0, 0), (0, n_kv_p - n_kv), (0, 0)))
-    return pages.reshape(n_pages, page_size, n_kv_p * hd_p)
+    return pages
+
+
+def _kv_operand(pages, layer, n_kv_p):
+    """The K or V operand of the verify kernel: `_kv_aligned`'s whole
+    pool, 5-D (see the module docstring for why it is not flattened),
+    or its padded layer flattened to [n_pages, page, n_kv_p * hd_p]."""
+    pages = _kv_aligned(pages, layer, n_kv_p)
+    if pages.ndim == 5:
+        return pages
+    n_pages, page_size, n_kv, hd_p = pages.shape
+    return pages.reshape(n_pages, page_size, n_kv * hd_p)
 
 
 def _kv_spec(operand, layer, tok_offset=0):
@@ -319,22 +488,35 @@ def paged_flash_decode(q, k_pages, v_pages, page_table, seq_lens,
         q_p = jnp.pad(q_p, ((0, 0), (0, (n_kv_p - n_kv) * group), (0, 0)))
     n_heads_p = n_kv_p * group_p
 
-    k_f = _kv_operand(k_pages, layer, n_kv_p)
-    v_f = _kv_operand(v_pages, layer, n_kv_p)
-    kv_spec = _kv_spec(k_f, layer)
+    # A page as [page * n_kv, hd] rows: token-major as it lies, so the
+    # merge of (page, n_kv) leaves every (8, 128) tile where it is (a
+    # bitcast for XLA, on the whole pool too) and a block of pages is
+    # one [keys * n_kv, hd] operand of `_attend_rows`.
+    k_f = _kv_aligned(k_pages, layer, n_kv_p)
+    v_f = _kv_aligned(v_pages, layer, n_kv_p)
+    if k_f.ndim != 5:
+        layer = None  # sliced out by `_kv_aligned`
+    rows = (*k_f.shape[:-3], page_size * n_kv_p, hd_p)
+    k_f, v_f = k_f.reshape(rows), v_f.reshape(rows)
+    n_pages_block = _pages_per_block(
+        page_size, math.prod(rows[-2:]) * k_f.dtype.itemsize, max_pages)
+    buf = pltpu.VMEM((2, n_pages_block, *rows[-2:]), k_f.dtype)
+    whole = pl.BlockSpec((batch, n_heads_p, hd_p),
+                         lambda b, pt, sl: (0, 0, 0))
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,  # page_table, seq_lens
-        grid=(batch, max_pages),
+        grid=(batch,),
         in_specs=[
-            pl.BlockSpec((1, n_heads_p, hd_p), lambda b, j, pt, sl: (b, 0, 0)),
-            kv_spec,
-            kv_spec,
+            whole,                              # q
+            pl.BlockSpec(memory_space=pl.ANY),  # K, V: as they lie in HBM
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
-        out_specs=pl.BlockSpec(
-            (1, n_heads_p, hd_p), lambda b, j, pt, sl: (b, 0, 0)
-        ),
+        out_specs=whole,
         scratch_shapes=[
+            buf, buf,                                    # K, V halves
+            pltpu.SemaphoreType.DMA((2, 2)),             # [kind, half]
+            pltpu.SMEM((1,), jnp.int32),                 # next block's half
             pltpu.VMEM((n_heads_p, hd_p), jnp.float32),  # acc
             pltpu.VMEM((n_heads_p, 1), jnp.float32),     # m
             pltpu.VMEM((n_heads_p, 1), jnp.float32),     # l
@@ -342,9 +524,9 @@ def paged_flash_decode(q, k_pages, v_pages, page_table, seq_lens,
     )
     kernel = functools.partial(
         _kernel,
+        layer=layer,
         page_size=page_size,
         n_kv=n_kv_p,
-        hd=hd_p,
         n_heads=n_heads_p,
         window=window,
         scale=hd ** -0.5,  # NOT hd_p: zero-padded lanes add nothing, but

@@ -56,6 +56,7 @@ jit cache stays small; pool writes are a fixed-arity donated jit with
 out-of-range page ids dropped — no recompilation as counts vary.
 """
 
+import collections
 import hashlib
 import logging
 import time
@@ -984,7 +985,20 @@ class ServingEngine:
             # pool page (sequence pages, each over every banded layer)
             "window_pages_released": 0, "window_pages_offloaded": 0,
             "restore_trimmed_pages": 0, "subfloor_pages_written": 0,
+            # the paged-decode kernel's work, summed over decode steps
+            # and attention layers: table entries that held a key of an
+            # active row's band, of all the entries of every row's
+            # table (what a grid of one step an entry walked)
+            "attn_pages_live": 0, "attn_pages_table": 0,
         }
+        # (pool, band) -> attention layers of that kind, and the entries
+        # of every row's table over every attention layer
+        self._attn_kinds = collections.Counter(
+            (pool, band) for band, _, pool, _ in spec)
+        self._attn_table = self.sc.max_slots * sum(
+            layers * (self.wtable if pool == "window"
+                      else self.page_table).shape[1]
+            for (pool, _), layers in self._attn_kinds.items())
         self.engine_id = profiling.next_engine_id()
         self._own_digests = {}  # insertion-ordered, at most OWN_DIGESTS
         # One sequence page over every layer and kind the page pools
@@ -2483,8 +2497,10 @@ class ServingEngine:
                 rows_dev = (rows_dev, self._to_device(wrows),
                             self._to_device(wbase))
 
+        live_pages = self._count_attn_pages(active, k)
         if k > 1:
-            with self._span("istpu.model.decode", program="decode_scan"):
+            with self._span("istpu.model.decode", program="decode_scan",
+                            live_pages=live_pages):
                 (toks_dev, lens_next, self.k_pages,
                  self.v_pages) = _decode_scan(
                     self.params, self.cfg, token_dev, lens_dev,
@@ -2516,7 +2532,8 @@ class ServingEngine:
             )
             return len(active)
 
-        with self._span("istpu.model.decode", program="decode_fused"):
+        with self._span("istpu.model.decode", program="decode_fused",
+                        live_pages=live_pages):
             if self._win_layers:
                 (logits, nxt_dev, lens_next, self.k_pages, self.v_pages,
                  self.wk_pages, self.wv_pages) = _decode_fused_wf(
@@ -2558,6 +2575,24 @@ class ServingEngine:
             self.stats["decoded_tokens"] += 1
         self.stats["decode_steps"] += 1
         return len(active)
+
+    def _count_attn_pages(self, active, k=1):
+        """Count what the paged-decode kernel walks in `k` decode steps
+        over `active` into `attn_pages_live` / `attn_pages_table`, from
+        the lengths held here (no device work); returns the live pages
+        of the first step (a scan's k steps count as k of its first)."""
+        page = self.cfg.page_size
+        live = 0
+        for (pool, band), layers in self._attn_kinds.items():
+            for _, s in active:
+                n = s.seq_len + 1  # keys the step attends from 0
+                if pool == "window":
+                    n -= s.wbase * page
+                first = max(n - band, 0) // page if band else 0
+                live += layers * ((n - 1) // page - first + 1)
+        self.stats["attn_pages_live"] += k * live
+        self.stats["attn_pages_table"] += k * self._attn_table
+        return live
 
     def _copy_boundaries(self, active):
         """Behind a decode step of a family with state: the boundary

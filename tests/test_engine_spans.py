@@ -445,6 +445,38 @@ def test_children_inside_parents_and_steps_hold_their_sum(
     assert max(st.fields["active"] for st in steps) == 3
 
 
+@pytest.mark.parametrize("window", [0, 2 * PAGE])
+def test_decode_spans_carry_live_pages_and_counters_sum_them(params, cfg,
+                                                             window):
+    """What the paged-decode kernel walks, counted on the host from the
+    lengths the engine holds: a decode span's `live_pages` are the
+    table entries (over every attention layer) that hold a key of an
+    active row's band in that step, `attn_pages_live` their sum and
+    `attn_pages_table` the whole grid a step (slots x table entries x
+    layers), so the two give the live share of the grid."""
+    import dataclasses
+
+    cfg = dataclasses.replace(cfg, window=window)
+    eng = _engine(params, cfg, None, f"spans-live-{window}", max_slots=3,
+                  max_pages_per_seq=12)
+    n_prompt, n_new = 3 * PAGE + 2, 9
+    spans = _run(eng, Request("l1", _prompt(7, n_prompt),
+                              max_new_tokens=n_new))
+    decodes = _named(spans, "istpu.model.decode", program="decode_fused")
+    assert len(decodes) == eng.stats["decode_steps"] == n_new - 1
+
+    def live(n):  # keys 0..n-1, the band's floor at n - window
+        first = max(n - window, 0) // PAGE if window else 0
+        return cfg.n_layers * ((n - 1) // PAGE - first + 1)
+
+    # step i attends the prompt, the tokens before it and its own
+    want = [live(n_prompt + i + 1) for i in range(n_new - 1)]
+    assert [d.fields["live_pages"] for d in decodes] == want
+    assert eng.stats["attn_pages_live"] == sum(want)
+    assert eng.stats["attn_pages_table"] == (
+        (n_new - 1) * cfg.n_layers * 3 * 12)
+
+
 def test_step_kinds_burst_unified_spec(params, cfg):
     def kinds(**sc):
         eng = _engine(params, cfg, None, "spans-kinds", **sc)

@@ -608,6 +608,113 @@ def test_hit_program_aliases_the_pools_and_holds_the_restored_pages_once(
         fused.temp_size_in_bytes, bound)
 
 
+def _decode_program(family):
+    """(lower, pools' bytes, one layer-and-kind of the smallest pool,
+    attention layers) of the engine's decode program for a family at
+    the cells' attention widths (bf16, 128 lanes a cache row, pages of
+    16) and a few narrow layers: `lower(sds)` lowers it over
+    ShapeDtypeStructs."""
+    import types
+
+    from infinistore_tpu import serving
+    from infinistore_tpu.models import hf, hybrid, smallthinker
+
+    slots, total, table = 16, 4096, 192
+    if family == "llama":  # mistral7b, mixtral8x7b: 8 kv heads, group 4
+        model = llama
+        cfg = llama.LlamaConfig(
+            vocab_size=256, d_model=256, n_layers=3, n_heads=32,
+            n_kv_heads=8, head_dim_override=128, d_ff=256, page_size=16)
+    elif family == "hybrid":  # granite4h-micro: head_dim 64, two a row
+        model = hybrid
+        cfg = hf.hybrid_config_from_hf(types.SimpleNamespace(
+            vocab_size=256, hidden_size=2048, shared_intermediate_size=256,
+            intermediate_size=256, num_hidden_layers=4,
+            num_attention_heads=32, num_key_value_heads=8,
+            layer_types=["mamba", "attention", "mamba", "attention"],
+            mamba_n_heads=64, mamba_d_head=64, mamba_d_state=128,
+            mamba_n_groups=1, mamba_d_conv=4, mamba_expand=2,
+            mamba_chunk_size=256, mamba_conv_bias=True,
+            mamba_proj_bias=False, num_local_experts=0,
+            num_experts_per_tok=0, attention_multiplier=0.0625,
+            embedding_multiplier=12, residual_multiplier=0.22,
+            logits_scaling=8, position_embedding_type="nope",
+            rope_scaling=None, attention_bias=False, hidden_act="silu",
+            normalization_function="rmsnorm", tie_word_embeddings=True,
+            rms_norm_eps=1e-5, max_position_embeddings=8192), page_size=16)
+        assert cfg.kv_pack == 2
+    else:  # smallthinker21b: the group of 7, full and banded layers
+        model = smallthinker
+        cfg = hf.smallthinker_config_from_hf(types.SimpleNamespace(
+            vocab_size=256, hidden_size=256, num_hidden_layers=4,
+            num_attention_heads=28, num_key_value_heads=4, head_dim=128,
+            moe_ffn_hidden_size=64, moe_num_primary_experts=8,
+            moe_num_active_primary_experts=2,
+            moe_primary_router_apply_softmax=True, norm_topk_prob=True,
+            rms_norm_eps=1e-6, rope_layout=[0, 1, 1, 1],
+            sliding_window_layout=[0, 1, 1, 1], sliding_window_size=4096,
+            rope_theta=1500000, rope_scaling=None,
+            tie_word_embeddings=False, max_position_embeddings=16384),
+            page_size=16)
+    n_full = sum(pool == "full" for *_, pool, _ in decoder.attn_layers(cfg))
+    n_win = sum(pool == "window" for *_, pool, _ in decoder.attn_layers(cfg))
+    page = cfg.kv_page_shape()
+    i32 = jnp.int32
+
+    def lower(sds):
+        params = jax.tree_util.tree_map(
+            lambda x: sds(x.shape, x.dtype),
+            jax.eval_shape(lambda k: model.init_params(k, cfg),
+                           jax.ShapeDtypeStruct((2,), jnp.uint32)))
+        pool = sds((n_full, total, *page), cfg.jdtype)
+        lens, rows = sds((slots,), i32), sds((slots, table), i32)
+        if family == "llama":
+            return serving._decode_fused.lower(
+                params, cfg, lens, lens, pool, pool, rows, model=model)
+        if family == "hybrid":
+            state = jax.tree_util.tree_map(
+                lambda x: sds(x.shape, x.dtype),
+                jax.eval_shape(lambda: model.state_pools(cfg, slots)))
+            return serving._decode_fused_st.lower(
+                params, cfg, lens, lens, pool, pool, state, rows,
+                model=model)
+        wpool = sds((n_win, slots * 264 + 1, *page), cfg.jdtype)
+        return serving._decode_fused_wf.lower(
+            params, cfg, lens, lens, pool, pool, wpool, wpool,
+            (rows, sds((slots, 264), i32), lens), model=model)
+
+    kind = total * int(np.prod(page)) * cfg.jdtype.itemsize
+    return lower, 2 * (n_full + n_win) * kind, kind, n_full + n_win
+
+
+@pytest.mark.parametrize("family", ["llama", "hybrid", "smallthinker"])
+def test_decode_program_for_the_chip_holds_no_layer_of_the_pool(
+        family, v5e_chip, monkeypatch):
+    """`_decode_fused`, `_decode_fused_st` and `_decode_fused_wf` with
+    the Pallas decode kernel in them (compiled for a described v5e, the
+    attention wrapper steered to the branch the chip takes): K and V
+    reach every layer's kernel as the donated pools themselves. The
+    kernel takes them in HBM as they lie and a page as [page * n_kv,
+    hd] rows; if that view moved one tile, or the group of 7 or the
+    packed rows made the wrapper slice a layer out, the program would
+    hold a layer-and-kind of a pool for it (a relayout of the whole
+    pool was 29.5 ms of a 48 ms step, PERF.md, PRs 25 and 31)."""
+    lower, pool_bytes, one_layer_and_kind, n_calls = _decode_program(family)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cache = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    try:
+        lowered = lower(lambda shape, dtype: jax.ShapeDtypeStruct(
+            shape, dtype, sharding=v5e_chip))
+        assert lowered.as_text().count("tpu_custom_call") == n_calls
+        ma = lowered.compile().memory_analysis()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cache)
+    assert ma.alias_size_in_bytes >= pool_bytes * 0.99  # donated, aliased
+    assert ma.temp_size_in_bytes < one_layer_and_kind // 2, (
+        ma.temp_size_in_bytes, one_layer_and_kind)
+
+
 def _step_sliced(model, params, cfg, tokens, seq_lens, k_pages, v_pages,
                  page_table, valid_len=None):
     """The formulation decode_step / verify_step had before the pool

@@ -495,9 +495,15 @@ def test_tp_decode_kernel_sliding_window():
 
 # ---------------------------------------------------------------------------
 # Whole-pool operand: [n_layers, n_pages, page, n_kv, hd] plus a static
-# layer. Aligned shapes go to the kernel whole (its index map leads with
-# the layer); anything that needs padding is sliced to its layer first.
+# layer. Aligned shapes go to the kernel whole (verify: 5-D, its index
+# map leads with the layer; decode: every layer still there, a page as
+# [page * n_kv, hd] rows, which moves no tile); anything that needs
+# padding is sliced to its layer first.
 # ---------------------------------------------------------------------------
+
+# rank of the kernel's K and V operands when the pool goes whole
+_WHOLE_POOL_RANK = {"verify": 5, "decode": 4}
+
 
 def _as_pool(pages, n_layers, layer, seed):
     """A pool whose `layer` is `pages` and whose other layers are other
@@ -563,7 +569,7 @@ def test_pool_operand_equals_layer_slice_bit_for_bit(kind, layer, window):
         lambda *a: fn(*a, interpret=True, window=window, layer=layer),
         q, k_pool, v_pool, pt, sl,
     )
-    assert ranks == [5, 5] and pads == []
+    assert ranks == [_WHOLE_POOL_RANK[kind]] * 2 and pads == []
 
 
 @pytest.mark.parametrize("n_heads,n_kv,hd", [
@@ -618,4 +624,170 @@ def test_a_group_of_seven_pads_the_group_and_the_pool_goes_whole(window):
     ranks, pads = _kernel_operand_ranks(
         lambda *a: fn(*a, interpret=True, window=window, layer=1),
         q, k_pool, v_pool, pt, sl)
-    assert ranks == [5, 5] and all(r < 5 for r in pads)
+    assert ranks == [4, 4] and all(r < 5 for r in pads)
+
+
+# ---------------------------------------------------------------------------
+# The block form of the decode kernel: blocks of `_pages_per_block` pages
+# fetched by the kernel's own copies, and only a sequence's live blocks.
+# Pages of 64 tokens keep a block at 8 pages (512 keys), so tables of a
+# few dozen entries hold several blocks; the page-16 cases are the
+# cells' own operand shapes.
+# ---------------------------------------------------------------------------
+
+def _block_pages(page, n_kv, hd, dtype, table):
+    from infinistore_tpu.ops.pallas_paged_attention import _pages_per_block
+
+    return _pages_per_block(
+        page, page * n_kv * hd * jnp.dtype(dtype).itemsize, table)
+
+
+def _mk_lens(lens, table, n_heads=4, n_kv=2, hd=128, page=64, seed=0,
+             dtype=np.float32):
+    """A case of len(lens) rows over tables of `table` entries, every
+    entry a page of its own."""
+    q, k, v, pt, _ = _mk(len(lens), n_heads, n_kv, hd,
+                         len(lens) * table + 1, page, table, seed=seed,
+                         dtype=dtype)
+    return q, k, v, pt, jnp.asarray(lens, jnp.int32)
+
+
+def _assert_block_matches(q, k, v, pt, sl, window=0, tol=2e-5, **kw):
+    want = paged_decode_attention(q, k, v, pt, sl, window=window)
+    got = paged_flash_decode(q, k, v, pt, sl, interpret=True, window=window,
+                             **kw)
+    np.testing.assert_allclose(np.asarray(got, np.float32),
+                               np.asarray(want, np.float32),
+                               rtol=tol, atol=tol)
+
+
+BLOCK_TABLE = 19  # 8 + 8 + 3: two whole blocks and a partial one
+
+
+@pytest.mark.parametrize("edge", [-1, 0, 1])
+@pytest.mark.parametrize("blocks", [1, 2])
+def test_block_form_at_every_block_edge(blocks, edge):
+    """A length one short of a block's end, at it and one past it, in a
+    row beside a one-token row and a whole table."""
+    P = _block_pages(64, 2, 128, np.float32, BLOCK_TABLE)
+    assert P == 8 and BLOCK_TABLE % P
+    n = blocks * P * 64 + edge
+    _assert_block_matches(
+        *_mk_lens([n, 1, BLOCK_TABLE * 64], BLOCK_TABLE, seed=50 + n))
+
+
+@pytest.mark.parametrize("table", [9, 11, 26])
+@pytest.mark.parametrize("dtype", [np.float32, jnp.bfloat16])
+def test_block_form_table_no_multiple_of_the_block(table, dtype):
+    """264 and 832 entries are no multiple of any block: the last block
+    is partial, its absent pages neither copied (the table has no such
+    entry) nor attended. Lengths that end in the partial block, at the
+    table's end and just before the partial block."""
+    P = _block_pages(64, 2, 128, dtype, table)
+    assert table % P
+    whole = table // P * P
+    lens = [table * 64, whole * 64 + 5, whole * 64, table * 64 - 1]
+    tol = 2e-5 if dtype is np.float32 else 3e-2
+    _assert_block_matches(*_mk_lens(lens, table, seed=table, dtype=dtype),
+                          tol=tol)
+
+
+@pytest.mark.parametrize("long_rows", [(0,), (3,), (1, 2), (0, 3)])
+def test_block_form_inactive_rows_over_entry_zero(long_rows):
+    """What an engine with free slots passes: length 1 over table entry
+    0 (the scratch page) in every inactive row, first, last and between
+    long ones — the copy a row's last block starts for the NEXT row must
+    be that row's own first block, whichever kind of row follows."""
+    table = 19
+    lens = [1] * 4
+    for i, r in enumerate(long_rows):
+        lens[r] = (table - i) * 64 - 7
+    q, k, v, pt, sl = _mk_lens(lens, table, seed=60 + sum(long_rows))
+    pt = pt.at[jnp.asarray([i for i in range(4) if lens[i] == 1])].set(0)
+    _assert_block_matches(q, k, v, pt, sl)
+
+
+def test_block_form_clamps_out_of_range_entries():
+    """Entries past a row's last page may be anything ("padded
+    arbitrarily"), inside a live block too, and an entry in use may lie
+    outside the pool: clamped, as the XLA path's take clamps."""
+    table = 19
+    q, k, v, pt, sl = _mk_lens([9 * 64 + 3, 64, 17 * 64], table, seed=70)
+    n_pages = k.shape[0]
+    pt = pt.at[0, 10:].set(jnp.asarray([-1, 99999, 7, -5, 2 ** 30, 0, 1,
+                                        n_pages, -n_pages], jnp.int32))
+    pt = pt.at[1, 1:].set(-3)
+    pt = pt.at[2, 4].set(n_pages + 17)  # in use: reads the last page
+    want = paged_decode_attention(q, k, v, jnp.clip(pt, 0, n_pages - 1), sl)
+    got = paged_flash_decode(q, k, v, pt, sl, interpret=True)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=2e-5, atol=2e-5)
+
+
+@pytest.mark.parametrize("window", [
+    5,          # inside the last page
+    64 * 3,     # floor inside the last block
+    64 * 8,     # exactly a block
+    64 * 9 + 1,  # floor inside an earlier block
+    64 * 30,    # wider than any row
+])
+def test_block_form_band_floor_inside_and_across_blocks(window):
+    """A band whose floor falls inside a block (pages under it in that
+    block are not copied, keys under it in its page are masked) and one
+    whose floor skips whole blocks (they are not walked)."""
+    table = 26
+    lens = [table * 64, 17 * 64 + 9, 8 * 64, 3]
+    _assert_block_matches(*_mk_lens(lens, table, seed=80 + window % 7),
+                          window=window)
+
+
+@pytest.mark.parametrize("n_heads,n_kv,dtype,tol", [
+    (32, 8, jnp.bfloat16, 3e-2),   # mistral7b, mixtral8x7b: group 4
+    (32, 4, jnp.bfloat16, 3e-2),   # granite4h-micro: kv_pack rows, group 8
+    (28, 4, jnp.bfloat16, 3e-2),   # smallthinker21b: the group of 7
+    (8, 2, np.float32, 2e-5),      # kv heads padded, a layer sliced out
+])
+@pytest.mark.parametrize("window", [0, 16 * 40])
+def test_block_form_whole_pool_is_the_layer_slice_bit_for_bit(
+        n_heads, n_kv, dtype, tol, window):
+    """The cells' operand shapes at pages of 16 tokens over several
+    blocks: the whole pool + `layer` gives the XLA reference's result
+    and, bit for bit, what the call on the layer's slice gives."""
+    table = 70
+    P = _block_pages(16, n_kv, 128, dtype, table)
+    assert table > 2 * P and table % P
+    lens = [table * 16, 1, P * 16 + 1, 33 * 16 - 1]
+    q, k, v, pt, sl = _mk_lens(lens, table, n_heads, n_kv, 128, 16,
+                               seed=90 + n_heads, dtype=dtype)
+    pt = pt.at[1].set(0)
+    k_pool = _as_pool(k, 2, 1, 7)
+    v_pool = _as_pool(v, 2, 1, 8)
+    _assert_block_matches(q, k, v, pt, sl, window=window, tol=tol)
+    got = paged_flash_decode(q, k_pool, v_pool, pt, sl, interpret=True,
+                             window=window, layer=1)
+    sliced = paged_flash_decode(q, k, v, pt, sl, interpret=True,
+                                window=window)
+    if n_heads // n_kv == 7:
+        # the slice pads its kv heads 4 -> 16, the pool its group 7 -> 8:
+        # two orders of the same sums
+        np.testing.assert_allclose(np.asarray(got, np.float32),
+                                   np.asarray(sliced, np.float32), atol=1e-2)
+    else:
+        np.testing.assert_array_equal(np.asarray(got, np.float32),
+                                      np.asarray(sliced, np.float32))
+
+
+def test_pages_per_block_follows_the_shapes():
+    """No option sets it: mistral7b's 32 KB a page a kind gives 16 pages
+    (256 keys, 2 MB of buffers), the 16 KB pages of granite4h-micro and
+    smallthinker21b 32 (512 keys), a short table its own length, and a
+    block is never under the 128 keys of a lane tile where the table
+    has them."""
+    from infinistore_tpu.ops.pallas_paged_attention import _pages_per_block
+
+    assert _pages_per_block(16, 16 * 8 * 128 * 2, 192) == 16
+    assert _pages_per_block(16, 16 * 4 * 128 * 2, 384) == 32
+    assert _pages_per_block(16, 16 * 4 * 128 * 2, 264) == 32
+    assert _pages_per_block(16, 16 * 32 * 128 * 4, 192) == 8
+    assert _pages_per_block(16, 16 * 8 * 128 * 2, 3) == 3
+    assert _pages_per_block(256, 256 * 8 * 128 * 2, 64) == 1

@@ -501,3 +501,19 @@ def test_spans_and_counters_of_two_kinds(cfg, params, shm_conn):
         s.fields["pages"] for s in by_reason["window"])
     assert eng.stats["window_pages_released"] \
         >= eng.stats["window_pages_offloaded"]
+    # What the paged-decode kernel walks, per kind of pool: a full
+    # layer every page of the sequence, a banded layer the band's (its
+    # short table's lengths count from the table's base).
+    decodes = [s for s in spans if s.name == "istpu.model.decode"]
+    assert len(decodes) == eng.stats["decode_steps"] == 69
+
+    def live(i):  # decode step i attends n keys
+        n = len(prompt) + i + 1
+        return (L_FULL * ((n - 1) // PAGE + 1)
+                + L_WIN * ((n - 1) // PAGE - (n - BAND) // PAGE + 1))
+
+    assert [d.fields["live_pages"] for d in decodes] == \
+        [live(i) for i in range(69)]
+    assert eng.stats["attn_pages_live"] == sum(live(i) for i in range(69))
+    assert eng.stats["attn_pages_table"] == 69 * eng.sc.max_slots * (
+        L_FULL * eng.sc.max_pages_per_seq + L_WIN * eng.wtable.shape[1])
