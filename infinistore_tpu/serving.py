@@ -963,7 +963,7 @@ class ServingEngine:
             # restores' page reads: contiguous runs of the store's pool
             # they spanned, bytes copied on the host (0 for one run)
             "restore_runs": 0, "restore_copied_bytes": 0,
-            "chunk_steps": 0, "burst_steps": 0, "prefetched_pages": 0,
+            "prefetched_pages": 0,
             # admissions that returned for want of pool pages
             "admit_retries": 0,
             # XLA programs built (or read from the persistent cache)
@@ -1793,7 +1793,7 @@ class ServingEngine:
         with self._span("istpu.model.prefill",
                         program="prefix" if hit else "cold",
                         tokens=len(suffix), padded_tokens=toks.shape[1],
-                        **fields):
+                        **fields) as f:
             if hit:
                 first_live = self._first_live(hit)
                 out = _admit_fused_px_wf(
@@ -1809,6 +1809,7 @@ class ServingEngine:
                     n_sub=n_sub)
             (row_dev, self.k_pages, self.v_pages, self.wk_pages,
              self.wv_pages, sub) = out
+            f["dispatch_ns"] = profiling.elapsed_ns()
             return np.asarray(row_dev), sub
 
     def _put_subfloor(self, slot, sub, lo, hi):
@@ -1852,11 +1853,14 @@ class ServingEngine:
         program is not the first thing the device runs after an idle
         spell. Of the admissions that followed under 1.0 s of idle
         none started the slow mode _settle describes, of those after
-        1.0-4.0 s three in four did (PERF.md, PR 29)."""
+        1.0-4.0 s three in four did (PERF.md, PR 29). Returns whether
+        this call sent one (the loop's no_work span counts them)."""
         now = time.monotonic()
-        if now - self._ticked >= IDLE_TICK_S:
-            self._ticked = now
-            jax.block_until_ready(_tick(self._tick_x))
+        if now - self._ticked < IDLE_TICK_S:
+            return False
+        self._ticked = now
+        jax.block_until_ready(_tick(self._tick_x))
+        return True
 
     def _settle_if_left_idle(self):
         """Behind a one-shot admission's program: `_settle`, for
@@ -1886,10 +1890,11 @@ class ServingEngine:
         spell that does start ends with one of the next admissions,
         not seconds later. idle() is the other half: it keeps most
         spells from starting."""
-        x = self._tick_x
-        for _ in range(SETTLE_PROGRAMS):
-            x = _tick(x)
-        jax.block_until_ready(x)
+        with self._span("istpu.engine.settle", programs=SETTLE_PROGRAMS):
+            x = self._tick_x
+            for _ in range(SETTLE_PROGRAMS):
+                x = _tick(x)
+            jax.block_until_ready(x)
 
     def _pad_tokens(self, tokens):
         """Prompt tokens as the [1, s_pad] device array the prefill
@@ -1918,7 +1923,7 @@ class ServingEngine:
         toks = self._pad_tokens(tokens)
         with self._span("istpu.model.prefill", program="cold",
                         tokens=len(tokens), padded_tokens=toks.shape[1],
-                        **self._scan_fields(toks.shape[1])):
+                        **self._scan_fields(toks.shape[1])) as f:
             ids = self._to_device(ids_padded)
             s_real = self._to_device(np.int32(len(tokens)))
             if self.state is None:
@@ -1933,6 +1938,7 @@ class ServingEngine:
                     self.v_pages, self.state, self.bstate, ids, s_real,
                     self._slot_dev(slot_idx), model=self.model,
                 )
+            f["dispatch_ns"] = profiling.elapsed_ns()
             return np.asarray(row_dev)
 
     def _slot_dev(self, slot_idx):
@@ -1960,7 +1966,7 @@ class ServingEngine:
         with self._span("istpu.model.prefill", program="prefix",
                         tokens=len(suffix), padded_tokens=toks.shape[1],
                         restored_pages=len(restored_ids),
-                        **self._scan_fields(toks.shape[1])):
+                        **self._scan_fields(toks.shape[1])) as f:
             r_ids = self._to_device(np.asarray(restored_ids, np.int32))
             s_ids = self._to_device(self._pad_ids(suffix_ids))
             s_real = self._to_device(np.int32(len(suffix)))
@@ -1979,6 +1985,7 @@ class ServingEngine:
                     r_ids, s_ids, s_real, self._slot_dev(slot_idx),
                     model=self.model,
                 )
+            f["dispatch_ns"] = profiling.elapsed_ns()
             return np.asarray(row_dev)
 
     def first_token_logits(self, prompt):
@@ -2469,11 +2476,12 @@ class ServingEngine:
         # input arrays are built ONLY on a cache miss: on the hit path
         # they were pure per-step waste (built, then discarded for the
         # cached device copies).
-        f.update(kind="burst" if k > 1 else "decode", active=len(active),
-                 k=k)
         key = (tuple(i for i, _ in active), self._pages_rev)
-        if (self._steady is not None and greedy
-                and self._steady[0] == key):
+        steady = (self._steady is not None and greedy
+                  and self._steady[0] == key)
+        f.update(kind="burst" if k > 1 else "decode", active=len(active),
+                 k=k, steady=steady)
+        if steady:
             _, token_dev, lens_dev, rows_dev = self._steady
         else:
             token = np.zeros(self.sc.max_slots, dtype=np.int32)
@@ -2500,13 +2508,14 @@ class ServingEngine:
         live_pages = self._count_attn_pages(active, k)
         if k > 1:
             with self._span("istpu.model.decode", program="decode_scan",
-                            live_pages=live_pages):
+                            live_pages=live_pages) as df:
                 (toks_dev, lens_next, self.k_pages,
                  self.v_pages) = _decode_scan(
                     self.params, self.cfg, token_dev, lens_dev,
                     self.k_pages, self.v_pages, rows_dev, k,
                     model=self.model,
                 )
+                df["dispatch_ns"] = profiling.elapsed_ns()
                 toks = np.asarray(toks_dev)  # [B, k] — the one D2H
             trimmed = False
             for i, s in active:
@@ -2522,7 +2531,6 @@ class ServingEngine:
                 self._release_windowed(s)
                 self.stats["decoded_tokens"] += len(burst)
             self.stats["decode_steps"] += k
-            self.stats["burst_steps"] += 1
             # `key` is still valid here: nothing between its
             # computation and this point mutates the active set or
             # _pages_rev (the steady-key invariant lives in ONE place).
@@ -2533,7 +2541,7 @@ class ServingEngine:
             return len(active)
 
         with self._span("istpu.model.decode", program="decode_fused",
-                        live_pages=live_pages):
+                        live_pages=live_pages) as df:
             if self._win_layers:
                 (logits, nxt_dev, lens_next, self.k_pages, self.v_pages,
                  self.wk_pages, self.wv_pages) = _decode_fused_wf(
@@ -2557,6 +2565,8 @@ class ServingEngine:
                     model=self.model,
                 )
                 self._copy_boundaries(active)
+            # Dispatched; what is left of the span is the wait.
+            df["dispatch_ns"] = profiling.elapsed_ns()
             nxt = np.asarray(nxt_dev)
         # Reusable next step iff every emitted token is the device's
         # argmax (greedy) — samplers/spec/finishes invalidate via key.
@@ -2633,14 +2643,16 @@ class ServingEngine:
         ]
         if not active:
             return [], None, None
-        with self._span("istpu.model.decode", program="verify"):
+        with self._span("istpu.model.decode", program="verify") as df:
             logits, self.k_pages, self.v_pages = self.model.verify_step(
                 self.params, self.cfg,
                 self._to_device(token), self._to_device(seq_lens),
                 self.k_pages, self.v_pages, self._to_device(rows),
                 self._to_device(valid),
             )
-            nxt = np.asarray(jnp.argmax(logits, axis=-1))
+            nxt_dev = jnp.argmax(logits, axis=-1)
+            df["dispatch_ns"] = profiling.elapsed_ns()
+            nxt = np.asarray(nxt_dev)
         return active, nxt, logits
 
     def _unified_step(self, active):
@@ -2693,7 +2705,6 @@ class ServingEngine:
                 self._release_windowed(s)
                 self.stats["decoded_tokens"] += 1
                 decoded = True
-        self.stats["chunk_steps"] += 1
         if decoded:
             self.stats["decode_steps"] += 1
         return len(active)

@@ -297,6 +297,12 @@ class ServingHTTPServer:
         step the continuous batch, and deliver completions. Handler
         threads only ever touch the queues."""
         eng = self.engine
+        engine_id = getattr(eng, "engine_id", None)
+        # istpu.engine.no_work: ONE span a spell in which the loop finds
+        # neither a submission nor anything to step, open across its
+        # 2 ms passes (`ticks`: the programs idle() sent meanwhile); a
+        # pass with work pays one comparison for it.
+        no_work = None
         while not self._stop.is_set():
             progressed = False
             while True:
@@ -304,10 +310,17 @@ class ServingHTTPServer:
                     rid, req = self._submit.get_nowait()
                 except queue.Empty:
                     break
+                if no_work is not None:
+                    no_work.__exit__(None, None, None)
+                    no_work = None
                 with self._lock:
                     st = self._reqs.get(rid)
                 try:
-                    eng.submit(req)
+                    with profiling.span("istpu.sched.submit", rid,
+                                        engine_id,
+                                        prompt_tokens=len(req.prompt),
+                                        queue_len=len(eng.queue)):
+                        eng.submit(req)
                 except Exception:
                     # Impossible request (e.g. needs more pages than the
                     # engine has): deliver an empty result rather than
@@ -317,6 +330,9 @@ class ServingHTTPServer:
                     continue
                 progressed = True
             if eng.queue or any(s is not None for s in eng.slots):
+                if no_work is not None:
+                    no_work.__exit__(None, None, None)
+                    no_work = None
                 before_out = len(eng.outputs)
                 try:
                     decoded = eng.step()
@@ -364,14 +380,21 @@ class ServingHTTPServer:
                         continue
                     self._finish_req(rid, st, out)
             if not progressed:
+                if no_work is None:
+                    no_work = profiling.span("istpu.engine.no_work",
+                                             engine=engine_id, ticks=0)
+                    no_work.__enter__()
                 try:
-                    eng.idle()
+                    if eng.idle():
+                        no_work.fields["ticks"] += 1
                 except Exception:
                     # A device that fails a trivial program fails the
                     # next step too, and that path takes the engine
                     # down cleanly.
                     pass
                 time.sleep(0.002)
+        if no_work is not None:
+            no_work.__exit__(None, None, None)
 
     # -- lifecycle -----------------------------------------------------
 

@@ -219,6 +219,27 @@ class TpuKVStore:
             )
         return self.conn.write_cache(cache, offsets, page_size, blocks)
 
+    def _put_batch(self, keys, flat, page_elems):
+        """One store batch of uniform pages: allocate, then the copy
+        into the store's pool and the commit's submission, each under
+        a span of its own (istpu.store.allocate / .write). Returns the
+        blocks."""
+        n = len(keys)
+        nbytes = page_elems * flat.itemsize
+        with profiling.span("istpu.store.allocate", keys=n,
+                            bytes=n * nbytes):
+            blocks = self.conn.allocate(keys, nbytes)
+        try:
+            with profiling.span("istpu.store.write", bytes=n * nbytes):
+                self._write(
+                    flat, [i * page_elems for i in range(n)], page_elems,
+                    blocks, keys,
+                )
+        except BaseException:
+            _abort_uncommitted(self.conn, blocks, keys)
+            raise
+        return blocks
+
     # -- generic arrays --------------------------------------------------
 
     def put_arrays(self, items, sync=False):
@@ -304,16 +325,8 @@ class TpuKVStore:
         if n != len(keys):
             raise ValueError("len(keys) must equal pages.shape[0]")
         page_elems = int(np.prod(host.shape[1:]))
-        flat = host.reshape(n * page_elems)
-        blocks = self.conn.allocate(keys, page_elems * host.itemsize)
-        try:
-            self._write(
-                flat, [i * page_elems for i in range(n)], page_elems,
-                blocks, keys,
-            )
-        except BaseException:
-            _abort_uncommitted(self.conn, blocks, keys)
-            raise
+        blocks = self._put_batch(keys, host.reshape(n * page_elems),
+                                 page_elems)
         if sync:
             self.conn.sync()
         return blocks
@@ -334,11 +347,14 @@ class TpuKVStore:
         if n == 0:
             return jnp.zeros((0, *page_shape), dtype=dtype)
         if self.conn.shm_connected:
-            lease, blocks = self.conn.pin(keys)
+            with profiling.span("istpu.store.pin", keys=n):
+                lease, blocks = self.conn.pin(keys)
             try:
-                stacked = self._pool_batch_view(
-                    blocks, n, page_bytes, dtype, page_shape
-                )
+                with profiling.span("istpu.store.view") as f:
+                    stacked = self._pool_batch_view(
+                        blocks, n, page_bytes, dtype, page_shape
+                    )
+                    f.update(self.last_read)
                 out = _device_put_owned(stacked, device)
             finally:
                 self.conn.release(lease)
@@ -398,15 +414,7 @@ class TpuKVStore:
         q, scales = kv_quant.quantize_kv_pages(pages)
         packed = kv_quant.pack_pages_host(to_host(q), to_host(scales))
         block = kv_quant.packed_page_bytes(page_shape)
-        blocks = self.conn.allocate(keys, block)
-        try:
-            self._write(
-                packed.reshape(-1), [i * block for i in range(n)], block,
-                blocks, keys,
-            )
-        except BaseException:
-            _abort_uncommitted(self.conn, blocks, keys)
-            raise
+        blocks = self._put_batch(keys, packed.reshape(-1), block)
         if sync:
             self.conn.sync()
         return blocks

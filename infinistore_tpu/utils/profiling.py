@@ -7,6 +7,7 @@ scheduler, cache manager, model step, transfers) records a span here:
     with span("istpu.sched.admit", request=rid, prompt_tokens=n) as f:
         ...
         f["outcome"] = "admitted"        # fields may be added inside
+        f["dispatch_ns"] = elapsed_ns()  # ... a point inside the span too
     record("istpu.sched.queue_wait", t0_ns, dur_ns, request=rid)
 
 ``span`` enters a ``jax.profiler.TraceAnnotation`` (free while no
@@ -154,6 +155,13 @@ class span:
                           threading.get_ident(), self.request, self.engine,
                           self.fields))
         return False
+
+
+def elapsed_ns():
+    """ns since the innermost span open on this thread was entered: how
+    a block marks a point inside its own span (a step's `dispatch_ns`)
+    at the cost of one clock read."""
+    return time.perf_counter_ns() - _stack()[-1]._p0
 
 
 def record(name, t0_ns, dur_ns, request=None, engine=None, **fields):
@@ -524,6 +532,6 @@ def profile_window(conn_or_server=None, trace_dir=None, trace=False):
 
 __all__ = [
     "ProfileWindow", "Span", "chrome_trace", "clock_offset_ns",
-    "clock_pair", "compilations", "next_engine_id", "profile_window",
-    "record", "span", "spans",
+    "clock_pair", "compilations", "elapsed_ns", "next_engine_id",
+    "profile_window", "record", "span", "spans",
 ]

@@ -81,6 +81,14 @@ def _children(spans, parent):
                   key=lambda s: s.t0_ns)
 
 
+def _less_dispatch(span):
+    """A model span's fields without `dispatch_ns`, which is a time:
+    held to lie inside the span."""
+    fields = dict(span.fields)
+    assert 0 < fields.pop("dispatch_ns") <= span.dur_ns
+    return fields
+
+
 def _inside(child, parent):
     """A child never outlasts its parent, nor starts before it — but
     for a queue wait, which began long before the step that ended it."""
@@ -102,12 +110,17 @@ def test_miss_span_tree(params, cfg, shm_conn):
     step = next(s for s in spans if s.id == admit.parent)
     assert step.name == "istpu.engine.step"
     kids = _children(spans, admit)
+    # ... and, behind the program of an admission out of idle, the
+    # trivial programs of `_settle` as a span of their own.
     assert [k.name for k in kids] == ["istpu.cache.probe",
-                                      "istpu.model.prefill"]
+                                      "istpu.model.prefill",
+                                      "istpu.engine.settle"]
     assert all(k.request == "m1" for k in kids)
     assert kids[0].fields == {"pages": 3, "hit_pages": 0}
-    assert kids[1].fields == {"program": "cold", "tokens": 3 * PAGE + 2,
-                              "padded_tokens": 4 * PAGE}
+    assert _less_dispatch(kids[1]) == {
+        "program": "cold", "tokens": 3 * PAGE + 2,
+        "padded_tokens": 4 * PAGE}
+    assert kids[2].fields == {"programs": serving.SETTLE_PROGRAMS}
     # The cold program writes the pool itself: no separate pool write.
     assert not _named(spans, "istpu.cache.pool_write")
     # Queue wait: recorded after the fact, from the request's arrival
@@ -133,9 +146,10 @@ def test_hit_span_tree(params, cfg, shm_conn):
     # restored pages' way into the pool, the prefix form and the
     # suffix's page-out are inside it, so none of them is a span.
     assert [k.name for k in kids] == [
-        "istpu.cache.probe", "istpu.cache.restore", "istpu.model.prefill"]
+        "istpu.cache.probe", "istpu.cache.restore", "istpu.model.prefill",
+        "istpu.engine.settle"]
     assert all(k.request == "h1" for k in kids)
-    probe, restore, prefill = kids
+    probe, restore, prefill, _ = kids
     assert probe.fields["hit_pages"] == hit
     # restore: bytes are pages x the bytes of one page over every layer
     # and both kinds, and its transfer is one h2d of as many bytes.
@@ -153,14 +167,21 @@ def test_hit_span_tree(params, cfg, shm_conn):
         == restore.fields["copied_bytes"]
     assert admit.fields["foreign_pages"] == 0
     assert eng.stats["foreign_hit_pages"] == 0
-    (h2d,) = _children(spans, restore)
-    assert h2d.name == "istpu.xfer.h2d"
+    # The store call as three numbers: the PIN (a key a layer and
+    # kind of every page), the host's view of the pinned blocks (what
+    # `last_read` says), the transfer.
+    pin, view, h2d = _children(spans, restore)
+    assert (pin.name, view.name, h2d.name) == (
+        "istpu.store.pin", "istpu.store.view", "istpu.xfer.h2d")
+    assert pin.fields == {"keys": hit * 2 * cfg.n_layers}
+    assert view.fields == {"runs": runs,
+                           "copied_bytes": restore.fields["copied_bytes"]}
     assert h2d.fields["bytes"] == hit * page_bytes
     n_sfx = len(follow) - hit * PAGE
     # No window here, so first_live is 0 and every hit page is restored.
-    assert prefill.fields == {"program": "prefix", "tokens": n_sfx,
-                              "padded_tokens": -(-n_sfx // PAGE) * PAGE,
-                              "restored_pages": hit}
+    assert _less_dispatch(prefill) == {
+        "program": "prefix", "tokens": n_sfx,
+        "padded_tokens": -(-n_sfx // PAGE) * PAGE, "restored_pages": hit}
     assert not _children(spans, prefill)
     assert not _named(spans, "istpu.cache.to_kv")
     assert not _named(spans, "istpu.cache.pool_write")
@@ -187,15 +208,15 @@ def test_a_hit_over_two_offloads_is_two_runs_and_one_copy(params, cfg,
         (admit,) = _named(spans, "istpu.sched.admit")
         assert [k.name for k in _children(spans, admit)] == [
             "istpu.cache.probe", "istpu.cache.restore",
-            "istpu.model.prefill"]
+            "istpu.model.prefill", "istpu.engine.settle"]
         (restore,) = _named(spans, "istpu.cache.restore")
         f = restore.fields
         assert f["pages"] == admit.fields["hit_pages"] == want_hit
         assert f["runs"] >= min_runs
         assert f["copied_bytes"] == (f["bytes"] if f["runs"] > 1 else 0)
         assert f["bytes"] == want_hit * page_bytes
-        (h2d,) = _children(spans, restore)
-        assert h2d.name == "istpu.xfer.h2d"
+        assert [k.name for k in _children(spans, restore)] == [
+            "istpu.store.pin", "istpu.store.view", "istpu.xfer.h2d"]
         for k in total:
             total[k] += f[k]
     assert total["copied_bytes"] > 0
@@ -250,7 +271,8 @@ def test_windowed_hit_restores_from_first_live(cfg, shm_conn):
     assert (hit, first_live) == (4, 2)
     kids = _children(spans, admit)
     assert [k.name for k in kids] == [
-        "istpu.cache.probe", "istpu.cache.restore", "istpu.model.prefill"]
+        "istpu.cache.probe", "istpu.cache.restore", "istpu.model.prefill",
+        "istpu.engine.settle"]
     assert kids[1].fields["pages"] == hit - first_live
     assert kids[2].fields["restored_pages"] == hit - first_live
     assert kids[2].fields["program"] == "prefix"
@@ -394,6 +416,103 @@ def test_an_idle_engine_ticks_every_idle_tick_s(params, cfg, monkeypatch):
     assert len(ticks) == 2
 
 
+def _post(port, prompt, n):
+    req = urllib.request.Request(
+        f"http://127.0.0.1:{port}/generate",
+        data=json.dumps({"prompt": prompt, "max_new_tokens": n,
+                         "stream": False}).encode(), method="POST")
+    with urllib.request.urlopen(req, timeout=120) as r:
+        return json.loads(r.read())
+
+
+def test_an_engine_left_without_work_records_one_no_work_a_spell(
+        params, cfg, monkeypatch):
+    """Behind ServingHTTPServer an engine with nothing to step is ONE
+    `istpu.engine.no_work` a spell, however many 2 ms passes it lasts:
+    opened by the first pass that finds no work, closed by the next
+    submission (or by shutdown), `ticks` the programs `idle()` sent in
+    it; while the engine steps there is none."""
+    eng = _engine(params, cfg, None, "spans-no-work")
+    sent = []
+    real = eng.idle
+    monkeypatch.setattr(eng, "idle",
+                        lambda: bool(real() and sent.append(time.time_ns())
+                                     is None))
+    t0 = time.time_ns()
+    srv = ServingHTTPServer(eng, port=0)
+    port = srv.start()
+    try:
+        deadline = time.time() + 30
+        while len(sent) < 2 and time.time() < deadline:
+            time.sleep(0.01)  # two tick periods and many passes
+        res = _post(port, _prompt(50, PAGE + 2), 6)
+        assert len(res["tokens"]) == 6
+        while len(sent) < 4 and time.time() < deadline:
+            time.sleep(0.01)
+    finally:
+        srv.shutdown()
+    spans = profiling.spans(since_ns=t0)
+    mine = [s for s in spans if s.engine == eng.engine_id]
+    first, second = _named(mine, "istpu.engine.no_work")
+    assert (first.parent, first.request) == (0, None)
+    (submit,) = _named(mine, "istpu.sched.submit")
+    assert submit.request == res["request_id"]
+    assert submit.fields == {"prompt_tokens": PAGE + 2, "queue_len": 0}
+    steps = _named(mine, "istpu.engine.step")
+    assert len(steps) >= 6 and submit.parent == 0
+    # The first spell ends where the submission is taken, the second
+    # starts behind the last step and is closed by shutdown; no step
+    # and no submission lies in either.
+    assert first.t0_ns + first.dur_ns <= submit.t0_ns + SLACK_NS
+    assert second.t0_ns >= steps[-1].t0_ns + steps[-1].dur_ns - SLACK_NS
+    for spell in (first, second):
+        end = spell.t0_ns + spell.dur_ns
+        assert not [s for s in steps + [submit]
+                    if spell.t0_ns + SLACK_NS < s.t0_ns < end - SLACK_NS]
+        assert spell.fields == {"ticks": sum(
+            1 for t in sent if spell.t0_ns <= t <= end + SLACK_NS)}
+    assert first.fields["ticks"] >= 2
+    assert first.fields["ticks"] + second.fields["ticks"] == len(sent)
+    # One span a spell: the first alone held some tens of passes.
+    assert first.dur_ns > 1.5 * serving.IDLE_TICK_S * 1e9
+
+
+def test_steps_say_whether_their_inputs_were_on_the_device(params, cfg):
+    """`steady` on a plain step: false where the inputs were rebuilt
+    and uploaded (the first step after an admission; a finish changes
+    the active set too), true where the device still held them; and a
+    decode span's `dispatch_ns`, the start of the span to the return of
+    the dispatch, lies inside it."""
+    eng = _engine(params, cfg, None, "spans-steady")
+    spans = _run(eng, Request("a", _prompt(51, PAGE + 2), max_new_tokens=6))
+    t0 = time.time_ns()
+    eng.submit(Request("b", _prompt(52, PAGE + 2), max_new_tokens=9))
+    eng.step()
+    eng.submit(Request("c", _prompt(53, PAGE + 2), max_new_tokens=3))
+    eng.run()
+    spans += profiling.spans(since_ns=t0)
+    by_id = {s.id: s for s in spans}
+    steps = [s for s in _named(spans, "istpu.engine.step")
+             if s.fields["kind"] == "decode"]
+    assert [s.fields["steady"] for s in steps[:5]] == [False] + [True] * 4
+    for st in steps:
+        admitted = bool([k for k in _children(spans, st)
+                         if k.name == "istpu.sched.admit"])
+        assert not (admitted and st.fields["steady"])
+    # "b" was admitted, "c" beside it, "c" left before it, and "b" took
+    # a second page at its 17th token: four steps rebuilt their inputs,
+    # and the steps between held them.
+    later = [s.fields["steady"] for s in steps[5:]]
+    assert later.count(False) == 4 and later.count(True) >= 4
+    assert "steady" not in _named(spans, "istpu.engine.step",
+                                  kind="idle")[0].fields
+    decodes = _named(spans, "istpu.model.decode")
+    assert len(decodes) == len(steps)
+    for d in decodes:
+        assert _less_dispatch(d).keys() == {"program", "live_pages"}
+        assert by_id[d.parent].fields["kind"] == "decode"
+
+
 def test_finish_span_tree(params, cfg, shm_conn):
     eng = _engine(params, cfg, shm_conn, "spans-finish")
     spans = _run(eng, Request("f1", _prompt(4, 2 * PAGE + 1),
@@ -407,11 +526,16 @@ def test_finish_span_tree(params, cfg, shm_conn):
     # One gather program, one transfer of the bucket's rows (3 pages
     # are a bucket of their own), one store batch, one sync.
     assert off.fields["padded_pages"] == 3 and off.fields["puts"] == 1
-    d2h, sync = _children(spans, off)
-    assert (d2h.name, sync.name) == ("istpu.xfer.d2h",
-                                     "istpu.cache.offload_sync")
-    assert d2h.fields == {"bytes": off.fields["bytes"]}
-    assert d2h.request == sync.request == "f1"
+    # ... the store batch as allocate (a key a layer and kind of every
+    # page) and the copy into the store's pool with the commit.
+    d2h, allocate, write, sync = _children(spans, off)
+    assert (d2h.name, allocate.name, write.name, sync.name) == (
+        "istpu.xfer.d2h", "istpu.store.allocate", "istpu.store.write",
+        "istpu.cache.offload_sync")
+    assert d2h.fields == write.fields == {"bytes": off.fields["bytes"]}
+    assert allocate.fields == {"keys": 3 * 2 * cfg.n_layers,
+                               "bytes": off.fields["bytes"]}
+    assert {s.request for s in (d2h, allocate, write, sync)} == {"f1"}
     step = next(s for s in spans if s.id == off.parent)
     assert step.name == "istpu.engine.step"
 
@@ -666,26 +790,35 @@ def _xplane_events(trace_dir, prefix="istpu."):
     return out
 
 
-def test_ring_and_profiler_record_the_same_spans(params, cfg, tmp_path):
+def test_ring_and_profiler_record_the_same_spans(params, cfg, shm_conn,
+                                                 tmp_path):
     """Under a jax.profiler session every istpu.* span is also a host
     event of the xplane. The profiler's clock counts from the start of
     its session (neither unix nor monotonic time), so the ring's starts
     match the trace's after ONE offset, measured from the matched
     pairs: within 1 ms at the quartiles here (tens of microseconds on
     an idle host; PERF.md has the chip's figure)."""
-    eng = _engine(params, cfg, None, "spans-xplane")
-    eng.run([Request("warm", _prompt(13, PAGE + 3), max_new_tokens=3)])
+    eng = _engine(params, cfg, shm_conn, "spans-xplane")
+    first = _prompt(13, 2 * PAGE)
+    eng.run([Request("warm", first, max_new_tokens=3)])
     with profiling.profile_window(trace_dir=tmp_path) as w:
-        eng.run([Request("x", _prompt(14, PAGE + 3), max_new_tokens=5)])
+        out = eng.run([Request("x", _prompt(14, 2 * PAGE),
+                               max_new_tokens=PAGE)])["x"]
+        eng.run([Request("y", _prompt(14, 2 * PAGE) + out + [1, 2],
+                         max_new_tokens=2)])
     events = _xplane_events(str(tmp_path))
     assert {p for *_, p in events} == {"/host:CPU"}
     names = {n for n, *_ in events}
+    # a miss, its finish, a hit: every span of the engine thread
     assert {profiling.WINDOW_SPAN, "istpu.engine.step",
             "istpu.sched.admit", "istpu.model.prefill",
-            "istpu.model.decode"} <= names
+            "istpu.model.decode", "istpu.engine.settle",
+            "istpu.cache.offload", "istpu.store.allocate",
+            "istpu.store.write", "istpu.cache.restore", "istpu.store.pin",
+            "istpu.store.view", "istpu.xfer.h2d"} <= names
     # A wait recorded after the fact was never an annotation.
     ring = [s for s in w.engine_spans if s.name != "istpu.sched.queue_wait"]
-    assert len(ring) == len(w.engine_spans) - 1
+    assert len(ring) == len(w.engine_spans) - 2
     assert sorted(n for n, *_ in events) == sorted(s.name for s in ring)
     offset, spread, pairs = profiling.clock_offset_ns(
         ring, [(n, s) for n, s, *_ in events])

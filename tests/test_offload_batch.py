@@ -222,13 +222,21 @@ def test_rows_reach_the_store_bit_for_bit(families, shm_conn, monkeypatch,
         "reason": reason, "pages": n,
         "bytes": n * 2 * L * _row_bytes(eng.cfg),
         "padded_pages": sum(buckets), "puts": len(sizes)}
-    # One transfer a chunk, of its bucket's rows, then the one sync.
+    # One transfer a chunk, of its bucket's rows, and one store batch
+    # (allocate, then the copy into the pool) of its pages' rows; then
+    # the one sync.
     kids = sorted((s for s in spans if s.parent == off.id),
                   key=lambda s: s.t0_ns)
     assert [k.name for k in kids] == (
-        ["istpu.xfer.d2h"] * len(sizes) + ["istpu.cache.offload_sync"])
-    assert [k.fields["bytes"] for k in kids[:-1]] == [
+        ["istpu.xfer.d2h", "istpu.store.allocate", "istpu.store.write"]
+        * len(sizes) + ["istpu.cache.offload_sync"])
+    assert [k.fields["bytes"] for k in kids[:-1:3]] == [
         b * 2 * L * _row_bytes(eng.cfg) for b in buckets]
+    assert [k.fields for k in kids[1:-1:3]] == [
+        {"keys": m * 2 * L, "bytes": m * 2 * L * _row_bytes(eng.cfg)}
+        for m in sizes]
+    assert [k.fields["bytes"] for k in kids[2:-1:3]] == [
+        m * 2 * L * _row_bytes(eng.cfg) for m in sizes]
 
 
 def _offload_per_layer(eng, slot):

@@ -16,6 +16,7 @@ from infinistore_tpu.serving import (
     content_page_keys,
     prompt_lookup_propose,
 )
+from infinistore_tpu.utils import profiling
 
 
 @pytest.fixture(scope="module")
@@ -40,6 +41,14 @@ def params(cfg):
 
 def _prompt(rng, cfg, n):
     return [int(t) for t in rng.integers(0, cfg.vocab_size, n)]
+
+
+def _steps_of_kind(eng, kind):
+    """How many of the engine's steps were of `kind`, by the spans they
+    left (utils/profiling.py)."""
+    return sum(1 for s in profiling.spans()
+               if s.name == "istpu.engine.step" and s.engine == eng.engine_id
+               and s.fields["kind"] == kind)
 
 
 def _dense_greedy_reference(params, cfg, prompt, n_new):
@@ -459,7 +468,7 @@ def test_chunked_prefill_token_parity(params, cfg, chunk):
     )
     out = eng.run([Request("r", prompt, max_new_tokens=7)])
     assert out["r"] == ref["x"]
-    assert eng.stats["chunk_steps"] > 0
+    assert _steps_of_kind(eng, "unified") > 0
     assert eng.stats["prefill_tokens"] == 21
 
 
@@ -483,7 +492,7 @@ def test_chunked_prefill_interleaves_with_decode(params, cfg):
     while eng.queue or any(s is not None for s in eng.slots):
         eng.step()
     # Mixed steps happened: chunk steps that ALSO decoded.
-    assert eng.stats["chunk_steps"] > 0
+    assert _steps_of_kind(eng, "unified") > 0
     assert eng.stats["decode_steps"] > 0
     for rid, prompt, mx in [("short", short, 16), ("long", long_p, 4)]:
         ref = ServingEngine(params, cfg).run(
@@ -690,7 +699,7 @@ def test_multi_step_scheduling_token_parity(params, cfg, hs):
          for i, (p, m) in enumerate(reqs)]
     )
     assert out == refs
-    assert eng.stats["burst_steps"] > 0
+    assert _steps_of_kind(eng, "burst") > 0
     assert eng.stats["decoded_tokens"] == ref_eng.stats["decoded_tokens"]
 
 
