@@ -493,6 +493,70 @@ def check_h2d_honest(conn, store, cfg, n_pages):
           f"equals the pages written (staging reused after)")
 
 
+def check_offload_ownership(eng, conn, cfg):
+    """serving.py frees a finished slot's pool pages as soon as the
+    gathers over them are dispatched, and the engine's upload thread
+    writes them to the store later (`_offload_full_pages` has the
+    rule). Hold the upload thread before its first wait for a transfer,
+    finish a slot over a whole page table of random rows, overwrite
+    every one of its pool pages by a program dispatched behind the
+    gathers (what the next admission's scatter does), then let the
+    upload go: the store must hold the rows as they were."""
+    import threading
+
+    import jax
+    import numpy as np
+
+    from infinistore_tpu import serving
+
+    n, L = eng.sc.max_pages_per_seq, cfg.n_kv_layers
+    shape = (L, n, *cfg.kv_page_shape())
+    kk, kv = jax.random.split(jax.random.PRNGKey(5))
+    k_new = jax.random.normal(kk, shape, cfg.jdtype)
+    v_new = jax.random.normal(kv, shape, cfg.jdtype)
+    ids = eng._alloc(n)[::-1]
+    eng._pool_write(ids, k_new, v_new)
+    # page-major (page, layer, k then v): the order of an offload's rows
+    want = np.swapaxes(np.stack([np.asarray(k_new), np.asarray(v_new)],
+                                axis=2), 0, 1)
+    prompt = [int(t) for t in np.random.default_rng(5).integers(
+        0, cfg.vocab_size, n * cfg.page_size)]
+    slot = serving._Slot(
+        work=serving._Work(req=serving.Request("smoke-own", prompt,
+                                               max_new_tokens=1),
+                           prompt=prompt),
+        page_ids=ids, seq_len=len(prompt))
+    eng.slots[0] = slot
+    gate, real = threading.Event(), serving.to_host
+
+    def held(arr):
+        gate.wait()
+        return real(arr)
+    serving.to_host = held
+    try:
+        eng._finish(0, slot)
+        freed = set(ids) <= set(eng.free_pages)
+        eng._pool_write(ids, jax.numpy.zeros_like(k_new),
+                        jax.numpy.zeros_like(v_new))
+        jax.block_until_ready((eng.k_pages, eng.v_pages))
+        held_back = eng.collect_uploads() == 0 and not eng.outputs
+    finally:
+        gate.set()
+        serving.to_host = real
+    eng.drain_uploads()
+    keys = serving.content_page_keys_by_page(eng._digests(prompt, n), L)
+    back = eng.store.get_kv_pages_host(keys, cfg.kv_page_shape(),
+                                       cfg.jdtype)
+    conn.delete_keys(keys)
+    check(freed and held_back and eng.outputs.pop("smoke-own") == []
+          and eng.stats["store_errors"] == 0
+          and np.array_equal(np.asarray(back).view(np.uint16),
+                             want.reshape(back.shape).view(np.uint16)),
+          f"{n} pages ({want.nbytes >> 20} MiB) freed and overwritten "
+          f"behind their gathers reach the store as they were; `done` "
+          f"waited for the upload thread's sync")
+
+
 def check_stream_roundtrip(service_port, cfg):
     """A few pages device -> store -> device over the STREAM (TCP) path,
     bit for bit."""
@@ -583,6 +647,9 @@ def run(size, rehearsal, tmp):
             params, cfg, ServingConfig(**size["serving"]), store=store
         )
         params = eng.params  # committed to the engine's device
+        print("offload:", flush=True)
+        check_offload_ownership(eng, conn, cfg)
+        store.tapped = None  # the first batch a TURN offloads is kept
         web = ServingHTTPServer(eng)
         base = f"http://127.0.0.1:{web.start()}"
 

@@ -32,7 +32,11 @@ the store's primitives into end-to-end serving:
   store (first-writer-wins dedup makes repeats free), so the next request
   sharing the prompt — e.g. the next turn of the same conversation —
   hits. One gather program, one device-to-host transfer and one store
-  batch per chunk of OFFLOAD_CHUNK_BYTES, closed by one sync.
+  batch per chunk of OFFLOAD_CHUNK_BYTES, closed by one sync: the
+  engine thread dispatches the gathers, frees the pages and steps on;
+  the engine's upload thread makes the transfers' waits, the store
+  batches and the sync, and the request's `done` follows its
+  acknowledgement (`_offload_full_pages`, `_finish`).
 - **Quantized wire (opt-in)**: `ServingConfig(quantized_store=True)`
   moves pages to/from the store int8-packed (per-token-per-head scales,
   ops/kv_quant.py) — half the restore/offload bytes and store capacity
@@ -59,7 +63,10 @@ out-of-range page ids dropped — no recompilation as counts vary.
 import collections
 import hashlib
 import logging
+import queue
+import threading
 import time
+import weakref
 from dataclasses import dataclass, field
 from functools import partial
 
@@ -283,6 +290,34 @@ class _Slot:
 
     def total_generated(self):
         return len(self.work.done) + len(self.generated)
+
+
+@dataclass
+class _Upload:
+    """One offload on its way to the store: what the engine thread
+    hands the engine's upload thread, and gets back as the
+    acknowledgement. Plain data: the upload thread holds the last one
+    while it waits for the next."""
+    reason: str
+    request: object           # request id of the offload's span, or None
+    pages: int                # as the offload's span counts them
+    nbytes: int               # ... and what the in-flight cap counts
+    # Store batches in order: (flat device array, its gather dispatched
+    # and its transfer started, or a device array the quantized wire
+    # packs itself; shape of one row; the function that formats the
+    # batch's keys; its arguments). Rows beyond the keys are a bucket's
+    # padding and stay behind.
+    chunks: list = field(default_factory=list)
+    counts: dict = field(default_factory=dict)  # stats an ack adds
+    digests: list = field(default_factory=list)  # -> _own_digests
+    # (request id, tokens, perf_counter at its finish) of the request
+    # whose `done` waits for this acknowledgement; set by _finish.
+    done: tuple = None
+    put_ns: int = 0           # perf_counter_ns at the put on the queue
+    # The upload thread's answer: the exception that ended it, or that
+    # it was not tried (an earlier upload had failed).
+    error: object = None
+    skipped: bool = False
 
 
 def prompt_lookup_propose(context, k, ngram=2):
@@ -803,9 +838,21 @@ def _write_pages(k_pool, v_pool, ids, k_new, v_new):
 # a transfer lands in a host buffer PJRT allocates anew, and above
 # glibc's largest mmap threshold (32 MiB) every such buffer is fresh
 # pages, faulted in one by one: 0.7-0.9 GB/s against 5.6 GB/s below it.
-# An offload larger than this goes in chunks of it, each transferred
-# while the one before is copied into the store's pool.
+# An offload larger than this goes in chunks of it, every chunk gathered
+# and on its way to the host before the first is copied into the
+# store's pool.
 OFFLOAD_CHUNK_BYTES = 16 << 20
+# The most bytes of offloads that may lie between their gathers'
+# dispatch and their acknowledgement by the upload thread (each chunk
+# lives in HBM until it is transferred, then on the host until its store
+# batch is synced). Past it the engine thread waits for acknowledgements
+# before it gathers more (stats "upload_backpressure_waits"); an offload
+# larger than this alone goes when nothing else is in flight. The v5e
+# cells peak at 11.7-12.95 of 16 GB of HBM, and the chunks in flight do
+# not raise that peak. At 256 MiB granite-4.0-h-micro's finishes (a
+# 78 MB snapshot each, 2.4 a second) made the engine thread wait 8-12
+# times in a window of 51 s, at 512 MiB once (PERF.md, PR 39).
+UPLOAD_INFLIGHT_BYTES = 512 << 20
 # How many pages below the matched depth a hit of a family with state
 # looks for a snapshot, one single-key probe a page (some 0.1 ms
 # each): the next turn of a conversation adds an answer and a message,
@@ -853,6 +900,21 @@ def _pow2_bucket(n, cap):
     inside a step; the padding is under half of a gather of at most
     16 MiB."""
     return min(cap, 1 << max(0, (n - 1).bit_length()))
+
+
+def _upload_loop(engine_ref, todo, acked):
+    """An engine's upload thread: `_Upload`s off `todo` in order, each
+    run by the engine's `_run_upload` and put on `acked` whatever came
+    of it; None ends it (`ServingEngine.close`, or the engine was
+    collected). It holds the engine only while an upload runs."""
+    while True:
+        up = todo.get()
+        engine = None if up is None else engine_ref()
+        if engine is None:
+            return
+        engine._run_upload(up)
+        acked.put(up)
+        del engine, up
 
 
 class ServingEngine:
@@ -990,7 +1052,26 @@ class ServingEngine:
             # active row's band, of all the entries of every row's
             # table (what a grid of one step an entry walked)
             "attn_pages_live": 0, "attn_pages_table": 0,
+            # offloads handed to the upload thread, times the engine
+            # thread waited for room under UPLOAD_INFLIGHT_BYTES, and
+            # what `done` waited for acknowledgements (a request's
+            # finish to its tokens in `outputs`, summed, ms)
+            "uploads": 0, "upload_backpressure_waits": 0,
+            "done_held_ms": 0.0,
         }
+        # Requests finished: in `outputs`, or held for their offload's
+        # acknowledgement. What a driver reads as progress.
+        self.finished = 0
+        # The upload thread (started by the first offload), its queue
+        # and the one acknowledgements come back on; uploads put and
+        # not yet collected, and their bytes. Engine thread only, but
+        # `_upload_failed`, which is the upload thread's.
+        self._upload_thread = None
+        self._todo = queue.SimpleQueue()
+        self._acked = queue.SimpleQueue()
+        self.uploads_pending = 0
+        self._upload_bytes = 0
+        self._upload_failed = False
         # (pool, band) -> attention layers of that kind, and the entries
         # of every row's table over every attention layer
         self._attn_kinds = collections.Counter(
@@ -1817,33 +1898,30 @@ class ServingEngine:
         which its admission computed below the band (`sub`, device
         chunks of `_sub_chunk_pages` pages), to the store: they never
         had a pool page, and the store's contract wants every full
-        page of every layer. On the engine thread, behind the first
-        token: one device-to-host transfer and one store batch a
-        chunk, every transfer started before the first is waited for,
-        one sync. Returns the pages written."""
+        page of every layer. Behind the first token the engine thread
+        starts every chunk's device-to-host transfer and hands them to
+        the upload thread as one upload (one store batch a chunk, one
+        sync). Returns the pages on their way."""
         digests = self._slot_digests(slot, hi)[lo:hi]
         c = _sub_chunk_pages(self.cfg)
         n = hi - lo
-        with self._span("istpu.cache.offload", slot.work.req.request_id,
-                        reason="subfloor", pages=n,
-                        bytes=n * self._wpage_bytes, padded_pages=n,
-                        puts=0) as f:
-            try:
-                for flat in sub:
-                    flat.copy_to_host_async()
-                for i, flat in enumerate(sub):
-                    keys = content_page_keys_by_page(
-                        digests[i * c:(i + 1) * c], self._win_layers)
-                    self._put_pages(keys, to_host(flat).reshape(
-                        -1, *self.cfg.kv_page_shape()))
-                    f["puts"] += 1
-                with self._span("istpu.cache.offload_sync"):
-                    self.store.conn.sync()
-            except Exception as e:
-                self._store_failed("offload", e)
+        nbytes = n * self._wpage_bytes
+        rid = slot.work.req.request_id
+        up = _Upload("subfloor", rid, n, nbytes,
+                     counts={"subfloor_pages_written": n})
+        with self._span("istpu.cache.offload", rid, reason="subfloor",
+                        pages=n, bytes=nbytes, padded_pages=n,
+                        puts=len(sub)):
+            if not self._upload_room(nbytes):
                 return 0
+            for i, flat in enumerate(sub):
+                flat.copy_to_host_async()
+                up.chunks.append((
+                    flat, self.cfg.kv_page_shape(),
+                    content_page_keys_by_page,
+                    (digests[i * c:(i + 1) * c], self._win_layers)))
+            self._enqueue_upload(up)
         slot.wstored = max(slot.wstored, hi)
-        self.stats["subfloor_pages_written"] += n
         return n
 
     def idle(self):
@@ -2101,9 +2179,9 @@ class ServingEngine:
         return self._ensure_pages(slot_idx, slot, slot.seq_len)
 
     def _offload_full_pages(self, slot, hi=None, reason="finish"):
-        """Persist the slot's NEW full pages [lo, hi) to the store
-        (shared by finish, preemption and windowed release). Offloads
-        FULL pages only — partial tail pages would poison page-granular
+        """Send the slot's NEW full pages [lo, hi) to the store (shared
+        by finish, preemption and windowed release). Offloads FULL
+        pages only — partial tail pages would poison page-granular
         prefix matching — and skips [0:cached_pages) which the store
         already holds (first-writer-wins makes re-putting them wasted
         transfer) plus [0:released) which was offloaded when the pages
@@ -2113,68 +2191,73 @@ class ServingEngine:
         extends this sequence hits these pages. A family with state
         writes, behind the pages and before the sync, the slot's
         boundary copy as the snapshot at the end of page n_full
-        (`_offload_snapshot`): pages and snapshot at ONE depth."""
+        (`_gather_snapshot_rows`): pages and snapshot at ONE depth.
+
+        Which thread does what. The ENGINE thread pays the digests,
+        the bucketed page ids and the dispatch of EVERY gather program
+        of the offload, each result's device-to-host transfer started,
+        and one put on the upload queue: the `istpu.cache.offload`
+        span. The engine's UPLOAD thread (`_run_upload`) waits for
+        each transfer, formats its keys and makes the store batch
+        (allocate, the copy into the store's pool), then ONE sync an
+        offload, and acknowledges: `istpu.cache.upload`.
+
+        Who owns the pages. A gather takes the pools undonated and its
+        value is fixed at the call: a later program that writes a page
+        (a decode step or an admission that took it off the free list)
+        is enqueued behind the gather on the same device. So the
+        caller frees the pool pages, the slot and its boundary copy as
+        soon as this returns; what must wait for the store is `done`
+        (`_finish`) and a preemption's re-admission (`_preempt`).
+
+        Returns the upload on its way, or None for nothing to send."""
         if not self._store_chain(slot.work):
-            return
+            return None
         n_full = slot.seq_len // self.cfg.page_size
         if hi is not None:
             n_full = min(n_full, hi)
         lo = max(slot.cached_pages, slot.released)
         if n_full <= lo:
-            return
+            return None
         # Digests come from the slot's incremental chain and only the
-        # [lo, n_full) keys are ever formatted — windowed release calls
-        # this every page_size tokens, so per-call work must stay
-        # O(pages released), not O(seq). The pages go in chunks of at
-        # most OFFLOAD_CHUNK_BYTES: each is one gather program, one
-        # device-to-host transfer and one store batch, and chunk i+1 is
-        # gathered and on its way to the host while chunk i is copied
-        # into the store's pool. One sync closes them, on this thread:
-        # page contents must be durable in the store BEFORE the pool
-        # page is freed for reuse (and before the caller hears `done`).
+        # [lo, n_full) keys are ever formatted (on the upload thread) —
+        # windowed release calls this every page_size tokens, so
+        # per-call work must stay O(pages released), not O(seq).
         n = n_full - lo
-        new_digests = self._slot_digests(slot, n_full)[lo:]
         # Two kinds of attention layer: the banded layers' part of the
         # same offload, what the store lacks of the pages their pool
         # still holds ([0, wstored) it has; [wstored, wbase) cannot be:
         # a page leaves that pool through the store).
         wlo = max(slot.wstored, slot.wbase) if self._win_layers else n_full
         nw = max(0, n_full - wlo)
-        with self._span("istpu.cache.offload", slot.work.req.request_id,
-                        reason=reason, pages=n,
-                        bytes=n * self._page_bytes + self._snapshot_bytes
-                        + (nw * self._wpage_bytes if nw else 0),
-                        padded_pages=0, puts=0,
+        nbytes = n * self._page_bytes + self._snapshot_bytes \
+            + (nw * self._wpage_bytes if nw else 0)
+        new_digests = self._slot_digests(slot, n_full)[lo:]
+        rid = slot.work.req.request_id
+        up = _Upload(reason, rid, n, nbytes, digests=new_digests,
+                     counts={"offloaded_pages": n, "snapshots_written":
+                             int(self.state is not None)})
+        with self._span("istpu.cache.offload", rid, reason=reason, pages=n,
+                        bytes=nbytes, padded_pages=0, puts=0,
                         **self._snapshot_fields) as f:
-            try:
-                self._put_pool_pages(
-                    f, self.k_pages, self.v_pages, slot.page_ids[lo:n_full],
-                    new_digests, self._full_layers, self._page_bytes)
-                if nw:
-                    self._put_pool_pages(
-                        f, self.wk_pages, self.wv_pages,
-                        slot.wpage_ids[wlo - slot.wbase:
-                                       n_full - slot.wbase],
-                        self._slot_digests(slot, n_full)[wlo:],
-                        self._win_layers, self._wpage_bytes, _pow2_bucket)
-                if self.state is not None:
-                    f["puts"] += self._offload_snapshot(slot,
-                                                        new_digests[-1])
-                with self._span("istpu.cache.offload_sync"):
-                    self.store.conn.sync()
-            except Exception as e:
-                # The sequence's OUTPUT does not depend on the offload;
-                # losing it only costs future cache hits.
-                self._store_failed("offload", e)
-                return
-        self.stats["offloaded_pages"] += n
-        self.stats["snapshots_written"] += self.state is not None
+            if not self._upload_room(nbytes):
+                return None
+            self._gather_pool_pages(
+                up, f, self.k_pages, self.v_pages, slot.page_ids[lo:n_full],
+                new_digests, self._full_layers, self._page_bytes)
+            if nw:
+                self._gather_pool_pages(
+                    up, f, self.wk_pages, self.wv_pages,
+                    slot.wpage_ids[wlo - slot.wbase:n_full - slot.wbase],
+                    self._slot_digests(slot, n_full)[wlo:],
+                    self._win_layers, self._wpage_bytes, _pow2_bucket)
+            if self.state is not None:
+                self._gather_snapshot_rows(up, slot, new_digests[-1])
+            f["puts"] = len(up.chunks)
+            self._enqueue_upload(up)
         if nw:
             slot.wstored = n_full
-        own = self._own_digests
-        own.update(dict.fromkeys(new_digests))
-        while len(own) > OWN_DIGESTS:
-            del own[next(iter(own))]
+        return up
 
     def _chunk_pages(self, page_bytes):
         """Pages of `page_bytes` each that one chunk of an offload
@@ -2183,20 +2266,17 @@ class ServingEngine:
         return min(self.sc.max_pages_per_seq,
                    max(1, OFFLOAD_CHUNK_BYTES // page_bytes))
 
-    def _put_pool_pages(self, f, k_pool, v_pool, page_ids, digests, layers,
-                        page_bytes, bucket=_offload_bucket):
-        """Pages `page_ids` of one pair of pools to the store, page i
-        under `digests[i]`'s keys for `layers` (the pool's layers, by
-        their rank among those that keep pages). In chunks of at most
-        OFFLOAD_CHUNK_BYTES: each is one gather program, one
-        device-to-host transfer and one store batch, and chunk i + 1
-        is gathered and on its way to the host while chunk i is copied
-        into the store's pool. The caller syncs. `f`: the offload
-        span's fields (`padded_pages`, `puts`)."""
-        n = len(page_ids)
+    def _gather_pool_pages(self, up, f, k_pool, v_pool, page_ids, digests,
+                           layers, page_bytes, bucket=_offload_bucket):
+        """Pages `page_ids` of one pair of pools onto the upload `up`,
+        page i under `digests[i]`'s keys for `layers` (the pool's
+        layers, by their rank among those that keep pages). In chunks
+        of at most OFFLOAD_CHUNK_BYTES, each one gather program and
+        one device-to-host transfer, all dispatched here and now; each
+        is one store batch on the upload thread. `f`: the offload
+        span's fields (`padded_pages`)."""
         c = self._chunk_pages(page_bytes)
-
-        def gather(a):
+        for a in range(0, len(page_ids), c):
             # The chunk's ids, padded to a bucket with the scratch
             # page 0: those rows are the tail of the array and never
             # reach the store.
@@ -2205,21 +2285,173 @@ class ServingEngine:
             ids[:len(part)] = part
             f["padded_pages"] += len(ids)
             flat = _gather_pages(k_pool, v_pool, self._to_device(ids))
-            if not self.sc.quantized_store:
-                flat.copy_to_host_async()
-            return flat
-
-        flat = gather(0)
-        for a in range(0, n, c):
-            ahead = gather(a + c) if a + c < n else None
-            keys = content_page_keys_by_page(digests[a:a + c], layers)
             # Quantized pages stay on the device: the store call
             # quantizes there, so only packed int8 crosses over.
-            pages = flat if self.sc.quantized_store else to_host(flat)
-            pages = pages.reshape(-1, *self.cfg.kv_page_shape())
-            self._put_pages(keys, pages[:len(keys)])
-            f["puts"] += 1
-            flat = ahead
+            if not self.sc.quantized_store:
+                flat.copy_to_host_async()
+            up.chunks.append((flat, self.cfg.kv_page_shape(),
+                              content_page_keys_by_page,
+                              (digests[a:a + c], layers)))
+
+    def _gather_snapshot_rows(self, up, slot, digest):
+        """The slot's boundary copy onto the upload `up`, keyed by
+        `digest` (of the last full page: the copy IS the state at its
+        end), in the offload's form: ONE gather program, its result in
+        chunks of at most OFFLOAD_CHUNK_BYTES, each a device-to-host
+        transfer started here and a store batch on the upload
+        thread."""
+        layers = self.cfg.n_state_layers
+        c = max(1, OFFLOAD_CHUNK_BYTES // (self._snapshot_bytes // layers))
+        with self._span("istpu.cache.state_out", slot.work.req.request_id,
+                        bytes=self._snapshot_bytes):
+            chunks = _gather_snapshot(self.cfg, self.bstate,
+                                      self._slot_dev(slot.index), c)
+            for i, flat in enumerate(chunks):
+                flat.copy_to_host_async()
+                up.chunks.append((flat, (self._snapshot_row,), snapshot_keys,
+                                  (digest, i * c, min(i * c + c, layers))))
+
+    # ---- the upload thread and its acknowledgements --------------------
+
+    def _upload_room(self, nbytes):
+        """Before an offload of `nbytes` gathers anything, inside its
+        span: wait (and count it) while uploads are in flight and this
+        one would take them past UPLOAD_INFLIGHT_BYTES. Returns
+        whether the store is still in use: a failure may have come
+        home meanwhile."""
+        def full():
+            return self.uploads_pending and \
+                self._upload_bytes + nbytes > UPLOAD_INFLIGHT_BYTES
+
+        if full():
+            self.stats["upload_backpressure_waits"] += 1
+            with self._span("istpu.cache.upload_backpressure",
+                            bytes=self._upload_bytes):
+                while full():
+                    self._await_ack()
+        return self._store_ok
+
+    def _enqueue_upload(self, up):
+        """One put on the upload queue; the thread starts with the
+        first. It holds no reference to the engine between uploads,
+        and ends when the engine is closed or collected."""
+        if self._upload_thread is None:
+            self._upload_thread = threading.Thread(
+                target=_upload_loop,
+                args=(weakref.ref(self), self._todo, self._acked),
+                name=f"istpu-upload-{self.engine_id}", daemon=True)
+            self._stop_uploads = weakref.finalize(self, self._todo.put, None)
+            self._upload_thread.start()
+        self.uploads_pending += 1
+        self._upload_bytes += up.nbytes
+        self.stats["uploads"] += bool(up.chunks)
+        up.put_ns = time.perf_counter_ns()
+        self._todo.put(up)
+
+    def _run_upload(self, up):
+        """ON THE UPLOAD THREAD, one upload at a time in the order they
+        were put: per chunk the keys, the wait for its transfer
+        (`to_host`) and the store batch, through the same calls by the
+        same names as ever (`store.put_kv_pages[_quantized]`), then ONE
+        `store.conn.sync()`. An exception stays in `up.error` for the
+        engine thread (`_acknowledge`), and every later upload comes
+        back untried: the engine serves store-less from then on."""
+        up.skipped = self._upload_failed
+        if up.skipped or not up.chunks:  # ... or a marker: nothing to do
+            up.chunks = []
+            return
+        try:
+            with self._span("istpu.cache.upload", up.request,
+                            reason=up.reason, pages=up.pages,
+                            bytes=up.nbytes, puts=0,
+                            queued_ns=time.perf_counter_ns() - up.put_ns
+                            ) as f:
+                while up.chunks:
+                    flat, row, keys_of, args = up.chunks.pop(0)
+                    keys = keys_of(*args)
+                    rows = flat if self.sc.quantized_store else to_host(flat)
+                    rows = rows.reshape(-1, *row)
+                    self._put_pages(keys, rows[:len(keys)])
+                    f["puts"] += 1
+                    del flat, rows  # the chunk's HBM goes with them
+                with self._span("istpu.cache.offload_sync"):
+                    self.store.conn.sync()
+        except Exception as e:
+            # The sequence's OUTPUT does not depend on the offload;
+            # losing it only costs future cache hits.
+            up.error = e
+            up.chunks = []
+            self._upload_failed = True
+
+    def collect_uploads(self, wait_s=0.0):
+        """On the engine thread (every step starts with it, and a
+        driver calls it while `uploads_pending` and nothing to step):
+        take the acknowledgements that have come back, waiting up to
+        `wait_s` for the first. Each counts what its upload wrote,
+        brings a failure home, and puts the tokens of the request
+        whose `done` it held into `outputs`. Returns how many."""
+        n = 0
+        while self.uploads_pending:
+            try:
+                if wait_s and not n:
+                    up = self._acked.get(timeout=wait_s)
+                else:
+                    up = self._acked.get_nowait()
+            except queue.Empty:
+                break
+            n += 1
+            self._acknowledge(up)
+        return n
+
+    def _acknowledge(self, up):
+        self.uploads_pending -= 1
+        self._upload_bytes -= up.nbytes
+        if up.error is not None:
+            self._store_failed("offload", up.error)
+        elif not up.skipped:
+            for name, n in up.counts.items():
+                self.stats[name] += n
+            own = self._own_digests
+            own.update(dict.fromkeys(up.digests))
+            while len(own) > OWN_DIGESTS:
+                del own[next(iter(own))]
+        if up.done is not None:
+            rid, tokens, finished_at = up.done
+            self.outputs[rid] = tokens
+            self.stats["done_held_ms"] += \
+                (time.perf_counter() - finished_at) * 1e3
+
+    def _await_ack(self):
+        """Block until one more acknowledgement is collected."""
+        while not self.collect_uploads(wait_s=1.0):
+            if not self._upload_thread.is_alive():
+                raise RuntimeError(
+                    f"the upload thread ended with {self.uploads_pending} "
+                    f"uploads unacknowledged")
+
+    def drain_uploads(self):
+        """Block until every upload is acknowledged and collected:
+        what was offloaded is in the store, what was held is in
+        `outputs`."""
+        while self.uploads_pending:
+            self._await_ack()
+
+    def close(self):
+        """Drain the uploads and stop the upload thread. Whoever
+        closes the store's connection calls this first: a native call
+        on a closed handle is a use-after-free (`LayerStreamer.close`
+        has the note). The engine stays usable; its next offload
+        starts a thread anew."""
+        if self._upload_thread is None:
+            return
+        self.drain_uploads()
+        self._stop_uploads()
+        self._upload_thread.join(timeout=60)
+        if self._upload_thread.is_alive():
+            raise RuntimeError(
+                "the upload thread did not stop; the store connection "
+                "must not be destroyed while it is running")
+        self._upload_thread = None
 
     def _shed_windows(self, active):
         """Before a decode step of a model with two kinds of attention
@@ -2256,8 +2488,8 @@ class ServingEngine:
         """The banded layers' pages that `due` slots are about to shed
         ([(slot index, slot, first page that stays)]), what the store
         lacks of them, as one offload: one gather over all the slots'
-        pages, one sync. Durable in the store BEFORE the pool pages
-        are freed for reuse."""
+        pages, one upload. The caller frees the pool pages at once
+        (`_offload_full_pages` has the rule)."""
         ids, digests, slots = [], [], 0
         for _, s, dead in due:
             lo = max(s.wstored, s.wbase)
@@ -2266,43 +2498,21 @@ class ServingEngine:
             ids += s.wpage_ids[lo - s.wbase:dead - s.wbase]
             digests += self._slot_digests(s, dead)[lo:dead]
             slots += 1
+        nbytes = len(ids) * self._wpage_bytes
         if not ids:
             return
+        up = _Upload("window", None, len(ids), nbytes,
+                     counts={"window_pages_offloaded": len(ids)})
         with self._span("istpu.cache.offload", reason="window",
-                        pages=len(ids), slots=slots,
-                        bytes=len(ids) * self._wpage_bytes,
+                        pages=len(ids), slots=slots, bytes=nbytes,
                         padded_pages=0, puts=0) as f:
-            try:
-                self._put_pool_pages(f, self.wk_pages, self.wv_pages, ids,
-                                     digests, self._win_layers,
-                                     self._wpage_bytes, _pow2_bucket)
-                with self._span("istpu.cache.offload_sync"):
-                    self.store.conn.sync()
-            except Exception as e:
-                self._store_failed("offload", e)
+            if not self._upload_room(nbytes):
                 return
-        self.stats["window_pages_offloaded"] += len(ids)
-
-    def _offload_snapshot(self, slot, digest):
-        """The slot's boundary copy to the store, keyed by `digest`
-        (of the last full page: the copy IS the state at its end), in
-        the offload's form: ONE gather program, then one device-to-
-        host transfer and one store batch per chunk of at most
-        OFFLOAD_CHUNK_BYTES, every transfer started before the first
-        is waited for. Returns the store batches made."""
-        row_bytes = self._snapshot_bytes // self.cfg.n_state_layers
-        c = max(1, OFFLOAD_CHUNK_BYTES // row_bytes)
-        with self._span("istpu.cache.state_out", slot.work.req.request_id,
-                        bytes=self._snapshot_bytes):
-            chunks = _gather_snapshot(self.cfg, self.bstate,
-                                      self._slot_dev(slot.index), c)
-            for flat in chunks:
-                flat.copy_to_host_async()
-            for i, flat in enumerate(chunks):
-                rows = to_host(flat).reshape(-1, self._snapshot_row)
-                self._put_pages(
-                    snapshot_keys(digest, i * c, i * c + len(rows)), rows)
-        return len(chunks)
+            self._gather_pool_pages(up, f, self.wk_pages, self.wv_pages, ids,
+                                    digests, self._win_layers,
+                                    self._wpage_bytes, _pow2_bucket)
+            f["puts"] = len(up.chunks)
+            self._enqueue_upload(up)
 
     def _release(self, slot_idx, slot):
         # [0:released) already went back to the pool when those pages
@@ -2339,10 +2549,30 @@ class ServingEngine:
         slot.released = dead
 
     def _finish(self, slot_idx, slot):
-        self.outputs[slot.work.req.request_id] = (
-            slot.work.done + slot.generated
-        )
-        self._offload_full_pages(slot)
+        """The request's pages go on their way to the store and its
+        slot and pool pages are free at once. Its tokens reach
+        `outputs` (what a driver sends `done` from) only when the
+        upload thread has acknowledged its offload's sync, and so
+        every earlier write of the request, the queue being FIFO: the
+        next turn of the conversation may not overtake them. With
+        nothing to wait for (no store chain, nothing new to write and
+        nothing in flight) they are there when this returns. Every
+        token was streamed as it was made (`_emit`), so no gap between
+        tokens holds the wait."""
+        work = slot.work
+        rid, tokens = work.req.request_id, work.done + slot.generated
+        up = self._offload_full_pages(slot)
+        if up is None and self.uploads_pending and self._store_chain(work):
+            # Nothing new of its own, but writes still in flight (a
+            # window's shed pages, an admission's sub-floor ones): a
+            # marker behind them.
+            up = _Upload("finish", rid, 0, 0)
+            self._enqueue_upload(up)
+        if up is None:
+            self.outputs[rid] = tokens
+        else:
+            up.done = (rid, tokens, time.perf_counter())
+        self.finished += 1
         self._release(slot_idx, slot)
 
     def _preempt(self, slot_idx, slot):
@@ -2351,8 +2581,10 @@ class ServingEngine:
         full pages, free its pool pages, and requeue it at the FRONT;
         re-admission travels the normal prefix-HIT path — restore the
         cached pages, recompute only the partial tail page — and decoding
-        resumes exactly where it left off."""
+        resumes exactly where it left off. Its re-admission needs the
+        pages in the store, so this alone waits for its upload."""
         self._offload_full_pages(slot, reason="preempt")
+        self.drain_uploads()
         work = slot.work
         work.done.extend(slot.generated)
         work.prompt = list(work.prompt) + slot.generated
@@ -2377,6 +2609,7 @@ class ServingEngine:
     def _step(self, f):
         """step() proper; `f` holds the step span's fields (kind,
         active slots, k)."""
+        self.collect_uploads()
         for i in range(self.sc.max_slots):
             if self.slots[i] is None and self.queue:
                 if self._admit(i, self.queue[0]):
@@ -2811,15 +3044,16 @@ class ServingEngine:
         return len(active)
 
     def run(self, requests=()):
-        """Submit `requests`, drive the loop to completion, and return
-        {request_id: generated token list}."""
+        """Submit `requests`, drive the loop to completion (every
+        upload acknowledged: what was offloaded is in the store), and
+        return {request_id: generated token list}."""
         for r in requests:
             self.submit(r)
         while self.queue or any(s is not None for s in self.slots):
-            before = (len(self.queue), len(self.outputs))
+            before = (len(self.queue), self.finished)
             decoded = self.step()
             progressed = decoded > 0 or (
-                (len(self.queue), len(self.outputs)) != before
+                (len(self.queue), self.finished) != before
             )
             if not progressed and not any(
                 s is not None for s in self.slots
@@ -2837,9 +3071,11 @@ class ServingEngine:
                     self.queue.pop(0)
                     self.outputs[work.req.request_id] = list(work.done)
                     continue
+                self.drain_uploads()
                 raise RuntimeError(
                     f"request {work.req.request_id} needs more pool "
                     f"pages than exist ({self.sc.total_pages - 1} usable); "
                     "completed outputs remain available in .outputs"
                 )
+        self.drain_uploads()
         return dict(self.outputs)

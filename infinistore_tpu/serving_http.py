@@ -292,17 +292,46 @@ class ServingHTTPServer:
             out["tok_s_mean"] = round(a["tok_s_sum"] / a["tok_s_n"], 1)
         return out
 
+    def _deliver(self):
+        """Completions out of the engine's `outputs` to their clients:
+        a request's tokens are there once its offload is in the store
+        (`ServingEngine._finish`), so `done` follows the sync."""
+        eng = self.engine
+        for rid in list(eng.outputs):
+            out = eng.outputs.pop(rid)
+            with self._lock:
+                st = self._reqs.get(rid)
+            if st is not None:
+                self._finish_req(rid, st, out)
+
     def _engine_loop(self):
         """The single engine driver: admit newly submitted requests,
         step the continuous batch, and deliver completions. Handler
         threads only ever touch the queues."""
         eng = self.engine
         engine_id = getattr(eng, "engine_id", None)
-        # istpu.engine.no_work: ONE span a spell in which the loop finds
-        # neither a submission nor anything to step, open across its
-        # 2 ms passes (`ticks`: the programs idle() sent meanwhile); a
-        # pass with work pays one comparison for it.
-        no_work = None
+        # ONE span a spell in which the loop finds neither a submission
+        # nor anything to step, open across its 2 ms passes:
+        # istpu.engine.no_work (`ticks`: the programs idle() sent
+        # meanwhile), or istpu.engine.upload_wait while finished
+        # requests wait for the upload thread's acknowledgement, which
+        # is work: their `done` is due. A pass with work pays one
+        # comparison for it.
+        spell = None
+
+        def leave():
+            nonlocal spell
+            if spell is not None:
+                spell.__exit__(None, None, None)
+                spell = None
+
+        def enter(name, **fields):
+            nonlocal spell
+            if spell is None or spell.name != name:
+                leave()
+                spell = profiling.span(name, engine=engine_id, **fields)
+                spell.__enter__()
+
         while not self._stop.is_set():
             progressed = False
             while True:
@@ -310,9 +339,7 @@ class ServingHTTPServer:
                     rid, req = self._submit.get_nowait()
                 except queue.Empty:
                     break
-                if no_work is not None:
-                    no_work.__exit__(None, None, None)
-                    no_work = None
+                leave()
                 with self._lock:
                     st = self._reqs.get(rid)
                 try:
@@ -330,10 +357,8 @@ class ServingHTTPServer:
                     continue
                 progressed = True
             if eng.queue or any(s is not None for s in eng.slots):
-                if no_work is not None:
-                    no_work.__exit__(None, None, None)
-                    no_work = None
-                before_out = len(eng.outputs)
+                leave()
+                before = eng.finished
                 try:
                     decoded = eng.step()
                 except Exception:
@@ -362,7 +387,7 @@ class ServingHTTPServer:
                     for rid, st in pending:
                         self._finish_req(rid, st, [])
                     return
-                if (decoded == 0 and len(eng.outputs) == before_out
+                if (decoded == 0 and eng.finished == before
                         and eng.queue
                         and not any(s is not None for s in eng.slots)):
                     # Every slot (hence the whole pool) is free and the
@@ -372,29 +397,26 @@ class ServingHTTPServer:
                     work = eng.queue.pop(0)
                     eng.outputs[work.req.request_id] = list(work.done)
                 progressed = True
-                for rid in list(eng.outputs):
-                    out = eng.outputs.pop(rid)
-                    with self._lock:
-                        st = self._reqs.get(rid)
-                    if st is None:
-                        continue
-                    self._finish_req(rid, st, out)
+                self._deliver()
+            elif eng.uploads_pending:
+                # Nothing to step, but acknowledgements are due: wait
+                # for them as long as a pass without work sleeps.
+                enter("istpu.engine.upload_wait")
+                eng.collect_uploads(wait_s=0.002)
+                self._deliver()
+                continue
             if not progressed:
-                if no_work is None:
-                    no_work = profiling.span("istpu.engine.no_work",
-                                             engine=engine_id, ticks=0)
-                    no_work.__enter__()
+                enter("istpu.engine.no_work", ticks=0)
                 try:
                     if eng.idle():
-                        no_work.fields["ticks"] += 1
+                        spell.fields["ticks"] += 1
                 except Exception:
                     # A device that fails a trivial program fails the
                     # next step too, and that path takes the engine
                     # down cleanly.
                     pass
                 time.sleep(0.002)
-        if no_work is not None:
-            no_work.__exit__(None, None, None)
+        leave()
 
     # -- lifecycle -----------------------------------------------------
 
@@ -417,11 +439,19 @@ class ServingHTTPServer:
         self.httpd.serve_forever()
 
     def shutdown(self):
+        """Stop the HTTP server and the engine thread, then drain the
+        engine's uploads and join its upload thread: the caller may
+        close the store's connection as soon as this returns, and no
+        native call may be running on it then."""
         self._stop.set()
         self.httpd.shutdown()
         self.httpd.server_close()
         if self._engine_thread is not None:
             self._engine_thread.join(timeout=30)
+            if self._engine_thread.is_alive():
+                return  # it still drives the engine: nothing to take over
+        self.engine.close()
+        self._deliver()
 
 
 __all__ = ["ServingHTTPServer"]

@@ -10,6 +10,7 @@ without TPUs (SURVEY.md §4 implication).
 """
 
 import os
+import threading
 
 # Must happen before jax import anywhere in the test session.
 _flags = os.environ.get("XLA_FLAGS", "")
@@ -88,6 +89,38 @@ def shm_conn(server):
     c = _connect(server, TYPE_SHM)
     yield c
     c.close()
+
+
+@pytest.fixture
+def gated_transfers(monkeypatch):
+    """Every engine's upload thread held before its first wait for a
+    device-to-host transfer (`serving.to_host`) until the event this
+    gives is set: what an offload gathered is still on its way while
+    the test goes on."""
+    from infinistore_tpu import serving
+
+    gate, real = threading.Event(), serving.to_host
+
+    def held(arr):
+        assert gate.wait(60)
+        return real(arr)
+    monkeypatch.setattr(serving, "to_host", held)
+    yield gate
+    gate.set()
+
+
+@pytest.fixture
+def gated_sync(shm_conn, monkeypatch):
+    """`shm_conn.sync` held until the event this gives is set: an
+    offload's acknowledgement does not come."""
+    gate, real = threading.Event(), shm_conn.sync
+
+    def held():
+        assert gate.wait(60)
+        return real()
+    monkeypatch.setattr(shm_conn, "sync", held)
+    yield gate
+    gate.set()
 
 
 @pytest.fixture

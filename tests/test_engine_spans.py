@@ -514,6 +514,11 @@ def test_steps_say_whether_their_inputs_were_on_the_device(params, cfg):
 
 
 def test_finish_span_tree(params, cfg, shm_conn):
+    """A finish on two threads: `istpu.cache.offload` on the engine
+    thread, a child of its step, is the gathers' dispatch alone;
+    `istpu.cache.upload` on the engine's upload thread, under the same
+    engine and request, holds the wait for the transfer, the store
+    batch and the sync."""
     eng = _engine(params, cfg, shm_conn, "spans-finish")
     spans = _run(eng, Request("f1", _prompt(4, 2 * PAGE + 1),
                               max_new_tokens=PAGE))
@@ -523,12 +528,24 @@ def test_finish_span_tree(params, cfg, shm_conn):
     assert off.fields["pages"] == 3
     assert off.fields["bytes"] == 3 * 2 * cfg.n_layers * cfg.kv_page_bytes()
     assert eng.stats["offloaded_pages"] == 3
-    # One gather program, one transfer of the bucket's rows (3 pages
-    # are a bucket of their own), one store batch, one sync.
+    # One gather program over the bucket's rows (3 pages are a bucket
+    # of their own), one store batch to come: nothing on this thread
+    # waits for a transfer or calls the store.
     assert off.fields["padded_pages"] == 3 and off.fields["puts"] == 1
-    # ... the store batch as allocate (a key a layer and kind of every
-    # page) and the copy into the store's pool with the commit.
-    d2h, allocate, write, sync = _children(spans, off)
+    assert _children(spans, off) == []
+    step = next(s for s in spans if s.id == off.parent)
+    assert step.name == "istpu.engine.step"
+    # ... on the other thread one transfer, the store batch as allocate
+    # (a key a layer and kind of every page) and the copy into the
+    # store's pool with the commit, and one sync.
+    (upl,) = _named(spans, "istpu.cache.upload")
+    assert (upl.request, upl.engine, upl.parent) == ("f1", eng.engine_id, 0)
+    assert upl.tid != off.tid == step.tid
+    assert upl.fields.pop("queued_ns") >= 0
+    assert upl.fields == {"reason": "finish", "pages": 3,
+                          "bytes": off.fields["bytes"], "puts": 1}
+    assert upl.t0_ns >= off.t0_ns - SLACK_NS
+    d2h, allocate, write, sync = _children(spans, upl)
     assert (d2h.name, allocate.name, write.name, sync.name) == (
         "istpu.xfer.d2h", "istpu.store.allocate", "istpu.store.write",
         "istpu.cache.offload_sync")
@@ -536,8 +553,97 @@ def test_finish_span_tree(params, cfg, shm_conn):
     assert allocate.fields == {"keys": 3 * 2 * cfg.n_layers,
                                "bytes": off.fields["bytes"]}
     assert {s.request for s in (d2h, allocate, write, sync)} == {"f1"}
-    step = next(s for s in spans if s.id == off.parent)
-    assert step.name == "istpu.engine.step"
+    assert {s.engine for s in (d2h, allocate, write, sync)} == {
+        eng.engine_id}
+    assert {s.tid for s in (d2h, allocate, write, sync)} == {upl.tid}
+    assert all(_inside(s, upl) for s in (d2h, allocate, write, sync))
+    assert (eng.stats["uploads"], eng.stats["upload_backpressure_waits"]
+            ) == (1, 0)
+    assert eng.stats["done_held_ms"] > 0
+
+
+def test_done_waits_for_the_sync_behind_the_http_loop(params, cfg, shm_conn,
+                                                      gated_sync):
+    """Behind ServingHTTPServer: while a finished request's sync is
+    held, its client has every token and no `done`, another request
+    runs to ITS finish meanwhile, and the loop, with nothing left to
+    step, is in ONE `istpu.engine.upload_wait` and not in
+    `istpu.engine.no_work`. Both are delivered, in order, once the sync
+    returns; shutdown leaves no upload and no upload thread."""
+    eng = _engine(params, cfg, shm_conn, "spans-http-held")
+    srv = ServingHTTPServer(eng, port=0)
+    port = srv.start()
+    t0 = time.time_ns()
+    try:
+        first = srv.submit_request(_prompt(60, 2 * PAGE), max_new_tokens=4)
+        second = srv.submit_request(_prompt(61, PAGE + 3),
+                                    max_new_tokens=3 * PAGE)
+        deadline = time.time() + 60
+        while eng.finished < 2 and time.time() < deadline:
+            time.sleep(0.01)
+        assert eng.finished == 2 and eng.uploads_pending == 2
+        time.sleep(0.05)  # some passes of the loop with nothing to step
+        for (rid, st), n in ((first, 4), (second, 3 * PAGE)):
+            assert st.n_tokens == n and st.tokens is None
+            assert rid not in eng.outputs
+        assert eng.stats["offloaded_pages"] == 0
+        gated_sync.set()
+        while (first[1].tokens is None or second[1].tokens is None) \
+                and time.time() < deadline:
+            time.sleep(0.005)
+        assert len(first[1].tokens) == 4 and len(second[1].tokens) == 3 * PAGE
+        assert first[1].done_t <= second[1].done_t
+    finally:
+        gated_sync.set()
+        srv.shutdown()
+    assert eng.uploads_pending == 0 and eng._upload_thread is None
+    assert eng.stats["offloaded_pages"] == 2 + 4 and eng.stats["uploads"] == 2
+    mine = [s for s in profiling.spans(since_ns=t0)
+            if s.engine == eng.engine_id]
+    (wait,) = _named(mine, "istpu.engine.upload_wait")
+    assert wait.parent == 0 and wait.dur_ns > 40e6
+    last = _named(mine, "istpu.engine.step")[-1]
+    assert wait.t0_ns >= last.t0_ns + last.dur_ns - SLACK_NS
+    for spell in _named(mine, "istpu.engine.no_work"):
+        assert (spell.t0_ns + spell.dur_ns <= wait.t0_ns + SLACK_NS
+                or spell.t0_ns >= wait.t0_ns + wait.dur_ns - SLACK_NS)
+    # done_held_ms: what the two `done`s waited, from each finish on.
+    assert eng.stats["done_held_ms"] >= 2 * 50
+
+
+def test_shutdown_returns_with_the_uploads_in_the_store(params, cfg,
+                                                        shm_conn,
+                                                        monkeypatch):
+    """`shutdown()` while a finished request's store write is still
+    running: it returns only when the write is synced, the upload
+    thread joined; the caller may close the connection then."""
+    real = shm_conn.sync
+    synced = []
+
+    def slow():
+        time.sleep(0.3)
+        real()
+        synced.append(time.perf_counter())
+    monkeypatch.setattr(shm_conn, "sync", slow)
+    eng = _engine(params, cfg, shm_conn, "spans-shutdown")
+    srv = ServingHTTPServer(eng, port=0)
+    srv.start()
+    prompt = _prompt(62, 2 * PAGE)
+    try:
+        rid, st = srv.submit_request(prompt, max_new_tokens=2)
+        deadline = time.time() + 60
+        while eng.finished < 1 and time.time() < deadline:
+            time.sleep(0.005)
+        assert eng.uploads_pending == 1 and not synced
+    finally:
+        srv.shutdown()
+    returned = time.perf_counter()
+    assert synced and synced[0] <= returned
+    assert eng.uploads_pending == 0 and eng._upload_thread is None
+    assert st.tokens is not None and len(st.tokens) == 2
+    other = _engine(params, cfg, shm_conn, "spans-shutdown")
+    assert other._probe_hit(_Work(
+        req=Request("probe", prompt + [1]), prompt=prompt + [1]))[0] == 2
 
 
 def test_children_inside_parents_and_steps_hold_their_sum(
@@ -645,7 +751,8 @@ def test_one_request_id_from_http_to_offload(params, cfg, shm_conn):
     assert {"istpu.http.request", "istpu.sched.queue_wait",
             "istpu.sched.admit", "istpu.cache.probe",
             "istpu.model.prefill", "istpu.cache.offload",
-            "istpu.xfer.d2h", "istpu.cache.offload_sync"} <= names
+            "istpu.cache.upload", "istpu.xfer.d2h",
+            "istpu.cache.offload_sync"} <= names
     (http,) = _named(mine, "istpu.http.request")
     (wait,) = _named(mine, "istpu.sched.queue_wait")
     (admit,) = _named(mine, "istpu.sched.admit")
@@ -659,6 +766,10 @@ def test_one_request_id_from_http_to_offload(params, cfg, shm_conn):
     assert abs(first_ns / 1e6 - res["ttft_ms"]) < 0.01
     assert wait.dur_ns + admit.dur_ns <= first_ns + SLACK_NS
     assert _inside(admit, http) and _inside(off, http)
+    # `done` follows the acknowledgement: the upload thread's sync has
+    # returned before the response is written.
+    (upl,) = _named(mine, "istpu.cache.upload")
+    assert upl.tid != off.tid and _inside(upl, http)
     assert {s.engine for s in mine} == {eng.engine_id}
 
 

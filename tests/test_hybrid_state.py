@@ -426,6 +426,56 @@ def test_bridge_refuses_what_it_does_not_implement(key, value):
         hf.hybrid_config_from_hf(types.SimpleNamespace(**conf))
 
 
+# -- the upload thread and who owns a finished slot's pages ---------------
+def test_pages_and_snapshot_of_a_finished_slot_survive_the_next_admission(
+        params, cfg, shm_conn, gated_transfers):
+    """One slot, a pool with room for one sequence: request a finishes
+    and b is admitted into ITS slot, ITS pool pages and ITS row of the
+    boundary copies, and runs to its own finish, all before the upload
+    thread has waited for a single transfer of a's offload. What the
+    store then holds of a (pages and snapshot) equals, bit for bit,
+    what an engine that ran a alone wrote."""
+    pa, pb = _prompt(70, 3 * PAGE + 2), _prompt(71, 4 * PAGE + 1)
+    eng = _engine(params, cfg, shm_conn, "reuse", max_slots=1,
+                  total_pages=8)
+    eng.submit(_req("a", pa, PAGE))
+    eng.submit(_req("b", pb, 4))
+    held_by = {"a": set(), "b": set()}
+    while eng.finished < 2:
+        eng.step()
+        if eng.slots[0] is not None:
+            held_by[eng.slots[0].work.req.request_id] |= set(
+                eng.slots[0].page_ids)
+        assert eng.outputs == {}
+    assert len(held_by["a"] & held_by["b"]) >= 3
+    assert eng.uploads_pending == 2 and not eng.stats["snapshots_written"]
+    gated_transfers.set()
+    eng.drain_uploads()
+    assert eng.stats["snapshots_written"] == 2
+    ref = _engine(params, cfg, shm_conn, "reuse-alone", max_slots=1,
+                  total_pages=8)
+    out = ref.run([_req("a", pa, PAGE)])["a"]
+    assert out == eng.outputs["a"]
+    seq = pa + out
+    n = (len(seq) - 1) // PAGE
+    assert n == 4
+    store = eng.store
+    read = {}
+    for e in (eng, ref):
+        digests = e._digests(seq, n)
+        pages = store.get_kv_pages_host(
+            serving.content_page_keys_by_page(digests, cfg.n_kv_layers),
+            cfg.kv_page_shape(), cfg.jdtype)
+        snap = store.get_kv_pages_host(
+            serving.snapshot_keys(digests[-1], 0, cfg.n_state_layers),
+            (e._snapshot_row,), cfg.state_jdtype)
+        read[e] = (np.asarray(pages), np.asarray(snap))
+    assert np.abs(read[ref][0]).max() > 0 and np.abs(read[ref][1]).max() > 0
+    for got, want in zip(read[eng], read[ref]):
+        assert got.shape == want.shape
+        assert np.array_equal(got.view(np.uint8), want.view(np.uint8))
+
+
 # -- spans and counters ----------------------------------------------------
 def test_spans_and_counters_of_the_state_cache(params, cfg, shm_conn):
     turn1 = _prompt(60, 23)
@@ -447,6 +497,21 @@ def test_spans_and_counters_of_the_state_cache(params, cfg, shm_conn):
         * e1._page_bytes + snap
     out = by["istpu.cache.state_out"][0]
     assert out.parent == off[0].id and out.fields["bytes"] == snap
+    # The engine thread dispatches the two gathers (pages, snapshot)
+    # and waits for neither; the upload thread has the two transfers
+    # and store batches and the one sync.
+    assert off[0].fields["puts"] == 2
+    assert not [s for s in ring if s.parent == out.id]
+    upl = [s for s in by["istpu.cache.upload"] if s.engine == e1.engine_id]
+    assert len(upl) == 1 and upl[0].tid != off[0].tid
+    assert upl[0].fields["bytes"] == off[0].fields["bytes"]
+    kids = sorted((s for s in ring if s.parent == upl[0].id),
+                  key=lambda s: s.t0_ns)
+    assert [k.name for k in kids] == [
+        "istpu.xfer.d2h", "istpu.store.allocate", "istpu.store.write"] * 2 \
+        + ["istpu.cache.offload_sync"]
+    assert kids[3].fields["bytes"] == snap  # the snapshot's transfer
+    assert kids[4].fields["keys"] == cfg.n_state_layers
     rest = by["istpu.cache.restore"][0]
     assert rest.fields["snapshot_bytes"] == snap
     assert rest.fields["bytes"] == rest.fields["pages"] * e2._page_bytes \

@@ -3,7 +3,10 @@ a chunk (`ServingEngine._offload_full_pages`; most offloads are one
 chunk): what reaches the store is the pool's rows bit for bit under the
 keys the per-layer form wrote, the padding of a bucket never does, the
 gather programs are bounded by the bucket grid, and a store failure
-leaves no uncommitted key behind.
+leaves no uncommitted key behind. The engine thread dispatches the
+gathers and frees the pages; the engine's upload thread makes the
+store batches and the sync (`_run_upload`), so a test that reads the
+store drains the uploads first, as `run()` does.
 
 CPU, tiny widths, the in-process loop-back store of conftest.py. The
 engine's pool is filled with random rows and slots are built by hand,
@@ -13,6 +16,7 @@ driven through the methods the engine itself calls.
 
 import dataclasses
 import itertools
+import threading
 import time
 
 import jax
@@ -158,12 +162,16 @@ def _chunk_by_pages(monkeypatch, eng, pages):
 
 
 def _drive(eng, slot, reason):
+    """... and the upload thread's part of it (a preemption waits for
+    that itself)."""
     if reason == "finish":
         eng._finish(0, slot)
     elif reason == "preempt":
         eng._preempt(0, slot)
+        assert not eng.uploads_pending
     else:
         eng._release_windowed(slot)
+    eng.drain_uploads()
 
 
 @pytest.mark.parametrize("chunk", [None, 4], ids=["one_chunk", "chunks_of_4"])
@@ -210,22 +218,36 @@ def test_rows_reach_the_store_bit_for_bit(families, shm_conn, monkeypatch,
     assert eng.stats["offloaded_pages"] == n
     assert eng.stats["store_errors"] == 0
     assert list(eng._own_digests) == digests
-    # The pool pages went back only after the store had them.
     assert len(eng.free_pages) == free_before + (
         n if windowed else len(slot.page_ids))
+    assert eng.stats["uploads"] == 1
+    if reason == "finish":
+        assert eng.outputs == {slot.work.req.request_id: []}
+        assert eng.stats["done_held_ms"] > 0
     spans = profiling.spans(since_ns=t0)
+    rid = slot.work.req.request_id
     (off,) = [s for s in spans if s.name == "istpu.cache.offload"
-              and s.request == slot.work.req.request_id]
+              and s.request == rid]
     cap = chunk or MAX_PAGES
     buckets = [serving._offload_bucket(m, cap) for m in sizes]
     assert off.fields == {
         "reason": reason, "pages": n,
         "bytes": n * 2 * L * _row_bytes(eng.cfg),
         "padded_pages": sum(buckets), "puts": len(sizes)}
-    # One transfer a chunk, of its bucket's rows, and one store batch
+    # The engine thread's part: the gathers' dispatch alone, no wait
+    # for a transfer and no store call. The upload thread's: one
+    # transfer a chunk, of its bucket's rows, and one store batch
     # (allocate, then the copy into the pool) of its pages' rows; then
     # the one sync.
-    kids = sorted((s for s in spans if s.parent == off.id),
+    assert not [s for s in spans if s.parent == off.id]
+    (upl,) = [s for s in spans if s.name == "istpu.cache.upload"
+              and s.request == rid]
+    assert upl.tid != off.tid and upl.engine == eng.engine_id
+    queued_ns = upl.fields.pop("queued_ns")
+    assert 0 <= queued_ns <= upl.t0_ns - off.t0_ns
+    assert upl.fields == {"reason": reason, "pages": n,
+                          "bytes": off.fields["bytes"], "puts": len(sizes)}
+    kids = sorted((s for s in spans if s.parent == upl.id),
                   key=lambda s: s.t0_ns)
     assert [k.name for k in kids] == (
         ["istpu.xfer.d2h", "istpu.store.allocate", "istpu.store.write"]
@@ -265,8 +287,11 @@ def test_keys_are_the_per_layer_schemes(families, shm_conn, family, first):
     n, L = 5, eng.cfg.n_layers
     slot = _slot(eng, n * PAGE + 2)
     want = _pool_rows(eng, slot.page_ids[:n])
-    writers = [lambda: _offload_per_layer(eng, slot),
-               lambda: eng._offload_full_pages(slot)]
+    def batch():
+        eng._offload_full_pages(slot)
+        eng.drain_uploads()
+
+    writers = [lambda: _offload_per_layer(eng, slot), batch]
     if first == "batch":
         writers.reverse()
     writers[0]()
@@ -313,6 +338,7 @@ def test_twenty_counts_compile_no_more_programs_than_buckets(
     before = serving._gather_pages._cache_size()
     for n in counts:
         eng._finish(0, _slot(eng, n * PAGE, seed=100 + n))
+    eng.drain_uploads()
     assert len(eng.store.puts) == len(counts)
     assert serving._gather_pages._cache_size() - before == len(buckets)
 
@@ -370,6 +396,7 @@ def test_restore_asks_in_the_order_the_offload_allocated(monkeypatch):
         eng.slots[0] = slot
         want = _pool_rows(eng, ids[:n])
         eng._finish(0, slot)
+        eng.drain_uploads()
         assert len(eng.store.puts) == 3
         seen = []
         inner = eng.store._inner
@@ -455,11 +482,17 @@ def test_a_failed_offload_leaves_no_uncommitted_key(
         slot.seq_len = n * PAGE
         want = _pool_rows(eng, slot.page_ids[:stored])
     eng._finish(0, slot)
+    # The slot and its pages are free and the engine knows of no
+    # failure yet: it comes home with the acknowledgement, once, and
+    # the request is delivered all the same.
+    assert eng.slots[0] is None and slot.page_ids[0] in eng.free_pages
+    assert slot.work.req.request_id not in eng.outputs
+    eng.drain_uploads()
     monkeypatch.undo()
 
     assert not eng._store_ok and eng.stats["store_errors"] == 1
     assert eng.stats["offloaded_pages"] == 0 and not eng._own_digests
-    assert eng.slots[0] is None and slot.page_ids[0] in eng.free_pages
+    assert eng.outputs == {slot.work.req.request_id: []}
     digests = eng._digests(slot.work.prompt, n)
     keys = content_page_keys_by_page(digests, L)
     store = TpuKVStore(conn)
@@ -489,6 +522,104 @@ def test_a_failed_offload_leaves_no_uncommitted_key(
                                 prompt=slot.work.prompt)) == (0, [])
 
 
+@pytest.mark.parametrize("chunk", [None, 4], ids=["one_chunk", "chunks_of_4"])
+@pytest.mark.parametrize("family", ["llama", "moe"])
+def test_pages_overwritten_behind_the_gather_reach_the_store_as_they_were(
+        families, shm_conn, monkeypatch, gated_transfers, family, chunk):
+    """The ownership rule: a finished slot's pool pages are free once
+    their gathers are dispatched. A program that overwrites every one
+    of them (what the next admission's scatter does), dispatched behind
+    the gathers and before the upload thread has waited for a single
+    transfer, changes nothing of what the store gets."""
+    eng = _engine(families, family, shm_conn)
+    if chunk:
+        _chunk_by_pages(monkeypatch, eng, chunk)
+    n = 11
+    slot = _slot(eng, n * PAGE + 3, seed=5)
+    ids = list(slot.page_ids[:n])
+    want = _pool_rows(eng, ids)
+    eng._finish(0, slot)
+    assert set(ids) <= set(eng.free_pages) and eng.uploads_pending == 1
+    L, shape = eng.cfg.n_layers, eng.cfg.kv_page_shape()
+    junk = jnp.full((L, n, *shape), 7, eng.cfg.jdtype)
+    eng._pool_write(ids, junk, -junk)
+    got = _pool_rows(eng, ids)
+    assert all((got[li, "k"] == 7).all() and (got[li, "v"] == -7).all()
+               for li in range(L))
+    assert eng.collect_uploads() == 0 and not eng.store.puts
+    gated_transfers.set()
+    eng.drain_uploads()
+    _assert_stored(eng, eng._digests(slot.work.prompt, n), want)
+    assert eng.stats["offloaded_pages"] == n
+
+
+def test_uploads_behind_a_failed_one_come_back_untried(
+        families, small_store, monkeypatch, gated_transfers):
+    """Three finishes in a row, the first one's sync fails: the failure
+    reaches `_store_failed` once; the second, put on the queue before
+    the engine knew, is acknowledged untried (no store call, nothing
+    counted); the third finds the store off and is delivered at once.
+    All three are delivered."""
+    srv, conn = small_store
+    eng = _engine(families, "llama", conn)
+
+    def sync_raises():
+        raise ConnectionError("injected sync failure")
+    monkeypatch.setattr(conn, "sync", sync_raises)
+    slots = [_slot(eng, 2 * PAGE, seed=20 + i) for i in range(3)]
+    rids = [s.work.req.request_id for s in slots]
+    eng._finish(0, slots[0])
+    eng._finish(0, slots[1])
+    assert eng.uploads_pending == 2 and eng.outputs == {}
+    gated_transfers.set()
+    eng.drain_uploads()
+    monkeypatch.undo()
+    assert not eng._store_ok and eng.stats["store_errors"] == 1
+    assert [len(p[1]) for p in eng.store.puts] == [2 * 2 * eng.cfg.n_layers]
+    assert eng.stats["offloaded_pages"] == 0 and not eng._own_digests
+    assert eng.stats["uploads"] == 2
+    eng._finish(0, slots[2])
+    assert eng.uploads_pending == 0 and eng.stats["uploads"] == 2
+    assert eng.outputs == {rid: [] for rid in rids}
+    assert eng.stats["store_errors"] == 1
+
+
+def test_the_in_flight_cap_makes_the_engine_wait_and_counts_it(
+        families, shm_conn, monkeypatch, gated_transfers):
+    """UPLOAD_INFLIGHT_BYTES: an offload that would take the bytes in
+    flight past the cap waits, before it gathers anything, for
+    acknowledgements, and `upload_backpressure_waits` counts the wait;
+    one that fits does not wait, and one larger than the cap alone goes
+    when nothing else is in flight."""
+    eng = _engine(families, "llama", shm_conn)
+    monkeypatch.setattr(serving, "UPLOAD_INFLIGHT_BYTES",
+                        5 * eng._page_bytes)
+    eng._finish(0, _slot(eng, 3 * PAGE, seed=30))
+    eng._finish(0, _slot(eng, 2 * PAGE, seed=31))  # 5 pages: at the cap
+    assert eng.uploads_pending == 2
+    assert eng.stats["upload_backpressure_waits"] == 0
+    threading.Timer(0.2, gated_transfers.set).start()
+    t0 = time.time_ns()
+    eng._finish(0, _slot(eng, 8 * PAGE, seed=32))  # alone past the cap
+    # It waited until BOTH were acknowledged, and only then gathered.
+    assert eng.uploads_pending == 1 and eng._upload_bytes == \
+        8 * eng._page_bytes
+    assert eng.stats["upload_backpressure_waits"] == 1
+    assert eng.stats["offloaded_pages"] == 5 and len(eng.outputs) == 2
+    spans = profiling.spans(since_ns=t0)
+    (off,) = [s for s in spans if s.name == "istpu.cache.offload"]
+    (wait,) = [s for s in spans
+               if s.name == "istpu.cache.upload_backpressure"]
+    assert wait.fields == {"bytes": 5 * eng._page_bytes}
+    # ... a child of the offload's own span: what the engine thread
+    # pays for an offload, the wait for room included.
+    assert wait.dur_ns > 0.1e9 and wait.parent == off.id
+    assert off.fields["puts"] == 1 and off.dur_ns >= wait.dur_ns
+    eng.drain_uploads()
+    assert eng.stats["offloaded_pages"] == 13 and eng._upload_bytes == 0
+    assert eng.stats["uploads"] == 3
+
+
 @pytest.mark.parametrize("n,chunk", [(3, None), (11, None), (11, 4)])
 def test_the_quantized_wire_takes_the_same_batches(
         families, shm_conn, monkeypatch, n, chunk):
@@ -500,6 +631,7 @@ def test_the_quantized_wire_takes_the_same_batches(
     want = _pool_rows(eng, slot.page_ids[:n])
     t0 = time.time_ns()
     eng._finish(0, slot)
+    eng.drain_uploads()
     sizes = [min(chunk, n - a) for a in range(0, n, chunk)] if chunk \
         else [n]
     assert [(p[0], p[2]) for p in eng.store.puts] == [
@@ -552,6 +684,7 @@ def test_a_sharded_connection_takes_the_same_batches(
     slot = _slot(eng, n * PAGE + 5)
     want = _pool_rows(eng, slot.page_ids[:n])
     eng._finish(0, slot)
+    eng.drain_uploads()
     assert len(eng.store.puts) == (-(-n // chunk) if chunk else 1)
     assert {p[0] for p in eng.store.puts} == {"put_kv_pages"}
     assert sum(len(p[1]) for p in eng.store.puts) == 2 * L * n

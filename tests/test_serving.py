@@ -3,6 +3,8 @@ scheduling concern (same tokens as isolated runs), the store must carry
 prefixes across requests (multi-turn hit), and pool pressure must
 degrade gracefully."""
 
+import time
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -246,6 +248,116 @@ def test_preemption_through_store_resumes_exactly(params, cfg, shm_conn):
         assert out[r.request_id] == ref["x"], r.request_id
         assert len(out[r.request_id]) == 24, r.request_id
     assert sorted(eng.free_pages) == list(range(1, 8))
+
+
+def test_a_preempted_request_readmits_as_a_full_hit(params, cfg, shm_conn):
+    """A preemption alone waits for its upload (its re-admission needs
+    the pages in the store): when `_preempt` returns nothing is in
+    flight, and the swapped-out sequence comes back as a hit over every
+    full page it had, not a shorter one."""
+    from infinistore_tpu.tpu import TpuKVStore
+
+    rng = np.random.default_rng(17)
+    eng = ServingEngine(
+        params, cfg,
+        ServingConfig(max_slots=2, total_pages=8, max_pages_per_seq=8),
+        store=TpuKVStore(shm_conn))
+    for i in range(2):
+        eng.submit(Request(f"r{i}", _prompt(rng, cfg, 16),
+                           max_new_tokens=24))
+    real, swapped = eng._preempt, []
+
+    def preempt(i, slot):
+        real(i, slot)
+        assert eng.uploads_pending == 0
+        swapped.append((slot.work.req.request_id,
+                        slot.seq_len // cfg.page_size))
+    eng._preempt = preempt
+    t0 = time.time_ns()
+    out = eng.run()
+    assert swapped and {len(v) for v in out.values()} == {24}
+    hits = [s for s in profiling.spans(since_ns=t0)
+            if s.name == "istpu.sched.admit" and s.engine == eng.engine_id
+            and s.fields["outcome"] == "admitted"
+            and s.fields["hit_pages"]]
+    # A re-admission keeps one token to prefill, so a victim swapped
+    # out on a page edge hits one page less than it had.
+    assert [(s.request, s.fields["hit_pages"]) for s in hits] in (
+        swapped, [(rid, n - 1) for rid, n in swapped])
+    assert eng.stats["restore_misses"] == 0 == eng.stats["store_errors"]
+
+
+def test_done_follows_the_sync_while_other_slots_decode(params, cfg,
+                                                        shm_conn,
+                                                        gated_sync):
+    """With the store's sync held: the finished request's slot and
+    pages are free at once and the other slot decodes on, step after
+    step, while the finished request is NOT in `outputs`; it is there
+    within one pass after the acknowledgement came back."""
+    from infinistore_tpu.tpu import TpuKVStore
+
+    rng = np.random.default_rng(18)
+    eng = ServingEngine(params, cfg, ServingConfig(max_slots=2,
+                                                   model_id="held-sync"),
+                        store=TpuKVStore(shm_conn))
+    eng.submit(Request("short", _prompt(rng, cfg, 16), max_new_tokens=3))
+    eng.submit(Request("long", _prompt(rng, cfg, 11), max_new_tokens=40))
+    while eng.finished == 0:
+        eng.step()
+    assert eng.uploads_pending == 1 and eng.stats["uploads"] == 1
+    assert eng.slots[0] is None and len(eng.free_pages) >= 63 - 3
+    before = eng.stats["decoded_tokens"]
+    for n in range(1, 13):
+        assert eng.step() == 1
+        assert eng.stats["decoded_tokens"] == before + n
+        assert "short" not in eng.outputs
+    assert eng.stats["offloaded_pages"] == 0
+    assert eng.stats["done_held_ms"] == 0
+    gated_sync.set()
+    deadline = time.time() + 60
+    while eng._acked.empty() and time.time() < deadline:
+        time.sleep(0.001)
+    eng.step()
+    assert len(eng.outputs["short"]) == 3
+    assert eng.stats["offloaded_pages"] == 2 and eng.uploads_pending == 0
+    assert eng.stats["done_held_ms"] > 0
+    ref = ServingEngine(params, cfg).run(
+        [Request("x", eng.slots[1].work.prompt, max_new_tokens=40)])
+    assert eng.run()["long"] == ref["x"]
+
+
+def test_run_returns_only_with_every_upload_acknowledged(params, cfg,
+                                                         shm_conn,
+                                                         monkeypatch):
+    """`run()` ends with the queue of uploads empty: what it offloaded
+    is in the store and counted, however slow the store was."""
+    from infinistore_tpu.tpu import TpuKVStore
+
+    real = shm_conn.sync
+
+    def slow():
+        time.sleep(0.2)
+        return real()
+    monkeypatch.setattr(shm_conn, "sync", slow)
+    rng = np.random.default_rng(19)
+    prompts = [_prompt(rng, cfg, 16 + i) for i in range(3)]
+    store = TpuKVStore(shm_conn)
+    eng = ServingEngine(params, cfg, ServingConfig(max_slots=3,
+                                                   model_id="run-drains"),
+                        store=store)
+    out = eng.run([Request(f"r{i}", p, max_new_tokens=2 + i)
+                   for i, p in enumerate(prompts)])
+    assert sorted(out) == ["r0", "r1", "r2"]
+    assert eng.uploads_pending == 0 and eng.stats["uploads"] == 3
+    assert eng.stats["offloaded_pages"] == 6
+    assert eng.stats["done_held_ms"] >= 3 * 200
+    for p in prompts:
+        keys = content_page_keys(p, cfg.page_size, 2, 0, "k",
+                                 namespace=eng._ns)
+        assert store.cached_prefix_len(keys) == 2
+    eng.close()
+    assert eng._upload_thread is None
+    eng.close()  # twice is once
 
 
 def test_preemption_without_store_recomputes(params, cfg):

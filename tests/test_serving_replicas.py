@@ -194,23 +194,30 @@ def test_read_back_through_every_other_connection_is_bit_exact(reps, tiny):
 
 def test_a_probe_right_after_the_writers_sync_finds_the_whole_chain(
         reps, tiny):
-    """The writer's offload ends in conn.sync(). The moment that returns
-    - before the engine counts the offload, let alone sends a done
-    event - every other connection's probe finds all of the chain, in
-    every layer and kind. Nothing is slept on: the probes run from a
-    hook on the acknowledgement itself."""
+    """The writer's offload ends in conn.sync(), on its engine's upload
+    thread. The moment that returns - before the engine counts the
+    offload, let alone puts the request's tokens where a done event is
+    sent from - every other connection's probe finds all of the chain,
+    in every layer and kind. Nothing is slept on: the probes run from a
+    hook on the acknowledgement itself; what the finished slot held is
+    noted at its finish, the slot being free by then."""
     page = tiny.cfg.page_size
     writer = reps[1]
     others = [r for r in reps if r is not writer]
     inner_sync = writer.conn.sync
-    found = []
+    inner_finish = writer.engine._finish
+    found, finished = [], []
+
+    def finish(i, slot):
+        finished.append((list(slot.work.prompt) + list(slot.generated),
+                         slot.seq_len // page, slot.work.req.request_id))
+        inner_finish(i, slot)
 
     def sync_then_probe(*a, **kw):
         out = inner_sync(*a, **kw)
-        slot = next(s for s in writer.engine.slots if s is not None)
-        seq = list(slot.work.prompt) + list(slot.generated)
-        n_full = slot.seq_len // page
+        seq, n_full, rid = finished[-1]
         assert writer.engine.stats["offloaded_pages"] == counted
+        assert rid not in writer.engine.outputs
         for other in others:
             for li in range(tiny.cfg.n_layers):
                 for kind in ("k", "v"):
@@ -221,6 +228,7 @@ def test_a_probe_right_after_the_writers_sync_finds_the_whole_chain(
         return out
 
     writer.conn.sync = sync_then_probe
+    writer.engine._finish = finish
     try:
         for rnd in range(3):
             counted = writer.engine.stats["offloaded_pages"]
@@ -230,6 +238,8 @@ def test_a_probe_right_after_the_writers_sync_finds_the_whole_chain(
                                        max_new_tokens=ANSWER)])
     finally:
         writer.conn.sync = inner_sync
+        del writer.engine._finish
+    assert writer.engine.stats["store_errors"] == 0
     assert len(found) == 3 * (R - 1) * tiny.cfg.n_layers * 2
     assert all(got == want and want >= 6 for got, want in found), found
 

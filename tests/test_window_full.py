@@ -481,6 +481,51 @@ def test_an_evicted_banded_page_is_a_miss_not_an_error(cfg, params,
     assert sorted(eng.wfree) == list(range(1, eng._wpool_pages))
 
 
+def test_pages_of_both_pools_survive_the_next_admission(cfg, params,
+                                                       shm_conn,
+                                                       gated_transfers):
+    """One slot, pools with room for one sequence: request a's
+    sub-floor pages, the pages it sheds and its finish are on the
+    upload queue, and b is admitted into a's slot and a's pages of
+    BOTH pools and runs to its own finish, before the upload thread
+    has waited for a single transfer. Every page of every layer the
+    store then holds of a equals, bit for bit, what an engine that ran
+    a alone wrote."""
+    pa, pb = _prompt(40, 70), _prompt(41, 60)
+    sizes = dict(max_slots=1, total_pages=12)
+    eng = _engine(params, cfg, shm_conn, model_id="wf-reuse", **sizes)
+    eng.submit(_req("a", pa, 10))
+    eng.submit(_req("b", pb, 4))
+    full = {"a": set(), "b": set()}
+    banded = {"a": set(), "b": set()}
+    while eng.finished < 2:
+        eng.step()
+        slot = eng.slots[0]
+        if slot is not None:
+            full[slot.work.req.request_id] |= set(slot.page_ids)
+            banded[slot.work.req.request_id] |= set(slot.wpage_ids)
+        assert eng.outputs == {}
+    assert len(full["a"] & full["b"]) >= 6
+    assert len(banded["a"] & banded["b"]) >= 2
+    assert eng.uploads_pending >= 4  # sub-floor and finish of each
+    assert eng.stats["offloaded_pages"] == 0
+    gated_transfers.set()
+    eng.drain_uploads()
+    ref = _engine(params, cfg, shm_conn, model_id="wf-reuse-alone", **sizes)
+    out = ref.run([_req("a", pa, 10)])["a"]
+    assert out == eng.outputs["a"]
+    seq = pa + out
+    n = (len(seq) - 1) // PAGE
+    assert n == 9
+    for layer in range(L_FULL + L_WIN):
+        got = _stored(eng.store, eng, seq, layer, 0, n)
+        want = _stored(eng.store, ref, seq, layer, 0, n)
+        for g, w in zip(got, want):
+            assert np.abs(w).max() > 0
+            assert np.array_equal(np.asarray(g).view(np.uint8),
+                                  np.asarray(w).view(np.uint8)), layer
+
+
 def test_spans_and_counters_of_two_kinds(cfg, params, shm_conn):
     eng = _engine(params, cfg, shm_conn, model_id="wf-spans")
     t0 = time.time_ns()
@@ -494,6 +539,19 @@ def test_spans_and_counters_of_two_kinds(cfg, params, shm_conn):
         if s.name == "istpu.cache.offload":
             by_reason.setdefault(s.fields["reason"], []).append(s)
     assert set(by_reason) == {"subfloor", "window", "finish"}
+    # Every writer goes through the one queue: an upload an offload, in
+    # the order they were put, with its bytes and its store batches,
+    # all on one other thread.
+    offs = sorted((s for v in by_reason.values() for s in v),
+                  key=lambda s: s.t0_ns)
+    ups = sorted((s for s in spans if s.name == "istpu.cache.upload"),
+                 key=lambda s: s.t0_ns)
+    assert [(u.fields["reason"], u.fields["bytes"], u.fields["puts"])
+            for u in ups] == [(o.fields["reason"], o.fields["bytes"],
+                               o.fields["puts"]) for o in offs]
+    assert len({u.tid for u in ups}) == 1 and ups[0].tid != offs[0].tid
+    assert eng.stats["uploads"] == len(ups)
+    assert not [s for s in spans if s.parent in {o.id for o in offs}]
     shed = by_reason["window"][0].fields
     assert shed["slots"] == 1 and shed["pages"] >= eng._shed_pages == 1
     assert shed["bytes"] == shed["pages"] * 2 * L_WIN * cfg.kv_page_bytes()
