@@ -31,8 +31,10 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from ..ops import ssm
+from ..ops.pallas_latent_attention import latent_decode_attention
 from ..ops.pallas_flash_attention import flash_prefill
 from ..ops.paged_attention import scatter_kv_multi, scatter_kv_to_pages
 from ..ops.pallas_paged_attention import (
@@ -67,8 +69,38 @@ def _llama3_scale_freqs(freqs, scaling):
     )
 
 
-def rope(x, positions, theta, scaling=()):
-    """x: [..., seq, heads, hd]; positions broadcastable to [..., seq]."""
+def yarn_mscale(factor, mscale):
+    """YaRN's attention temperature term: 0.1 mscale ln(factor) + 1."""
+    return 0.1 * mscale * float(np.log(factor)) + 1.0 if factor > 1 else 1.0
+
+
+def _yarn_scale_freqs(freqs, theta, yarn):
+    """YaRN ("NTK-by-parts", arXiv:2309.00071, as DeepSeek-V2/V3 compute
+    it): dimensions that turn more than `beta_fast` times within the
+    original context keep their frequency, those that turn fewer than
+    `beta_slow` times are slowed by `factor`, a linear ramp between.
+    `yarn` = (factor, original_max, beta_fast, beta_slow, mscale,
+    mscale_all_dim). Returns (frequencies, the multiplier of cos and
+    sin: mscale over mscale_all_dim's term, 1 where they are equal)."""
+    factor, orig_max, beta_fast, beta_slow, mscale, mscale_all = yarn
+    half = freqs.shape[0]
+
+    def turns_dim(turns):
+        return (2 * half * np.log(orig_max / (turns * 2 * np.pi))
+                / (2 * np.log(theta)))
+
+    low = max(int(np.floor(turns_dim(beta_fast))), 0)
+    high = min(int(np.ceil(turns_dim(beta_slow))), 2 * half - 1)
+    ramp = jnp.clip((jnp.arange(half, dtype=jnp.float32) - low)
+                    / max(high - low, 1e-3), 0.0, 1.0)
+    freqs = freqs / factor * ramp + freqs * (1.0 - ramp)
+    return freqs, yarn_mscale(factor, mscale) / yarn_mscale(factor,
+                                                            mscale_all)
+
+
+def rope(x, positions, theta, scaling=(), yarn=()):
+    """x: [..., seq, heads, hd]; positions broadcastable to [..., seq].
+    `scaling`: the llama3 rescale; `yarn`: the YaRN one."""
     hd = x.shape[-1]
     half = hd // 2
     freqs = jnp.exp(
@@ -76,9 +108,16 @@ def rope(x, positions, theta, scaling=()):
     )
     if scaling:
         freqs = _llama3_scale_freqs(freqs, scaling)
+    mult = 1.0
+    if yarn:
+        freqs, mult = _yarn_scale_freqs(freqs, theta, yarn)
     angles = positions[..., None].astype(jnp.float32) * freqs  # [..., s, half]
-    cos = jnp.cos(angles)[..., None, :].astype(x.dtype)
-    sin = jnp.sin(angles)[..., None, :].astype(x.dtype)
+
+    def trig(f):
+        t = f(angles) * mult if mult != 1.0 else f(angles)
+        return t[..., None, :].astype(x.dtype)
+
+    cos, sin = trig(jnp.cos), trig(jnp.sin)
     x1, x2 = x[..., :half], x[..., half:]
     return jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1)
 
@@ -234,11 +273,82 @@ def lm_head(params, x, cfg=None):
         return out
 
 
-def residual(cfg, x, out):
-    """x + out, the branch scaled by the family's `residual_mult`."""
+# The residual path, a function of the family: what carries a layer's
+# input around each of its two sublayers. One stream (`cfg.hc_mult` 1,
+# every family but models/xing.py): `x + out`, and `stream_in` hands the
+# sublayer the stream itself. n streams [b, s, n, d] mixed by
+# manifold-constrained hyper-connections (mHC, arXiv:2512.24880): per
+# token, from the normalised streams, a read vector Hpre (sigmoid), a
+# write vector Hpost (2 sigmoid) and a doubly stochastic stream mixer
+# Hres (exp, then `hc_iters` Sinkhorn iterations), all float32; the
+# sublayer reads sum_i Hpre[i] X[i] and the streams become
+# Hres X + Hpost y. Each sublayer has coefficients of its own (the
+# layer's "hc_attn" / "hc_ffn": norm [n d], proj [n d, n n + 2 n],
+# bias [n n + 2 n], a [3] = a_pre, a_post, a_res).
+
+
+def hc_coef(p, x, cfg):
+    """(Hpre [b, s, n], Hpost [b, s, n], Hres [b, s, n, n]) float32 of
+    the streams x [b, s, n, d]."""
+    f32 = jnp.float32
+    b, s, n, d = x.shape
+    with jax.named_scope("hc.coef"):
+        xt = rms_norm(x.reshape(b, s, n * d), p["norm"], cfg.norm_eps)
+        z = xt.astype(f32) @ p["proj"]                     # [b, s, 2n + nn]
+        a, bias = p["a"], p["bias"]
+        pre = jax.nn.sigmoid(a[0] * z[..., :n] + bias[:n])
+        post = 2.0 * jax.nn.sigmoid(a[1] * z[..., n:2 * n] + bias[n:2 * n])
+        res = (a[2] * z[..., 2 * n:] + bias[2 * n:]).reshape(b, s, n, n)
+        m = jnp.exp(jnp.clip(res, -cfg.hc_clamp, cfg.hc_clamp))
+        for _ in range(cfg.hc_iters):
+            m = m / (jnp.sum(m, axis=-2, keepdims=True) + cfg.hc_eps)
+            m = m / (jnp.sum(m, axis=-1, keepdims=True) + cfg.hc_eps)
+    return pre, post, m
+
+
+def stream_open(cfg, x):
+    """The embedding as the stack's streams: itself, or n copies."""
+    if cfg.hc_mult == 1:
+        return x
+    return jnp.broadcast_to(x[:, :, None], (*x.shape[:2], cfg.hc_mult,
+                                            x.shape[-1]))
+
+
+def stream_in(cfg, layer, x, which):
+    """(a sublayer's input [b, s, d], what `residual` needs to write
+    its output back): the stream itself and None, or the streams read
+    through Hpre and this sublayer's (Hpost, Hres). `which`: "attn" or
+    "ffn"."""
+    if cfg.hc_mult == 1:
+        return x, None
+    pre, post, res = hc_coef(layer["hc_" + which], x, cfg)
+    with jax.named_scope("hc.mix"):
+        x_in = sum(pre[..., i, None] * x[:, :, i]
+                   for i in range(cfg.hc_mult))
+    return x_in.astype(x.dtype), (post, res)
+
+
+def residual(cfg, x, out, mix=None):
+    """x + out, the branch scaled by the family's `residual_mult`; with
+    `mix` (n streams) Hres x + Hpost out."""
+    if mix is not None:
+        post, res = mix
+        n = cfg.hc_mult
+        with jax.named_scope("hc.mix"):
+            rows = [sum(res[..., i, j, None] * x[:, :, j] for j in range(n))
+                    + post[..., i, None] * out for i in range(n)]
+            return jnp.stack(rows, axis=2).astype(x.dtype)
     if cfg.residual_mult != 1.0:
         out = out * jnp.asarray(cfg.residual_mult, out.dtype)
     return x + out
+
+
+def stream_close(cfg, x):
+    """The streams as the final norm's input: their sum."""
+    if cfg.hc_mult == 1:
+        return x
+    with jax.named_scope("hc.mix"):
+        return jnp.sum(x.astype(jnp.float32), axis=2).astype(x.dtype)
 
 
 # Per-layer spec: `cfg.layer_kinds` names each layer's mixer, and with
@@ -269,7 +379,7 @@ def attn_layers(cfg):
     n = {"full": 0, "window": 0}
     for kind, band, rotates in zip(cfg.layer_kinds, cfg.layer_windows,
                                    cfg.layer_ropes):
-        if kind != "attention":
+        if kind not in ("attention", "latent"):
             continue
         pool = "window" if cfg.two_kinds and band else "full"
         out.append((band, rotates, pool, n[pool]))
@@ -379,6 +489,94 @@ def ssm_mixer_step(layer, x, cfg, state):
     return _ssm_out(layer, y, xs, z, cfg)[:, None], (h, conv)
 
 
+# A "latent" layer (multi-head latent attention, DeepSeek-V2/V3): the
+# cache of a token is ONE row, the normalised compressed key-value c
+# [kv_lora_rank] and the rotated shared key k_pe [qk_rope], zero-padded
+# to `cfg.latent_width` lanes; K and V of every head are c Wkvb. Two
+# paths on purpose (tests/test_latent.py pins that they agree): a
+# prefill EXPANDS the rows of prefix and suffix into per-head K (nope |
+# pe) and V and runs the flash kernel; a decode step ABSORBS Wkvb's key
+# half into the query and its value half into the output, and attends
+# the rows as they lie in the pool (ops/pallas_latent_attention.py).
+
+
+def latent_scale(cfg):
+    """The softmax scale: (qk_nope + qk_rope) ** -0.5 times YaRN's
+    mscale(factor, mscale_all_dim) squared."""
+    m = yarn_mscale(cfg.yarn[0], cfg.yarn[5]) if cfg.yarn else 1.0
+    return (cfg.qk_nope + cfg.qk_rope) ** -0.5 * m * m
+
+
+def latent_project(layer, x, cfg, positions):
+    """(q_nope [b, s, H, nope], q_pe [b, s, H, rope] rotated, the cache
+    rows [b, s, latent_width], h the normalised input)."""
+    b, s, _ = x.shape
+    r = cfg.kv_lora_rank
+    with jax.named_scope("attn.qkv"):
+        h = rms_norm(x, layer["ln1"], cfg.norm_eps)
+        cq = rms_norm(matmul(h, layer["wqa"]), layer["q_ln"], cfg.norm_eps)
+        q = matmul(cq, layer["wqb"]).reshape(
+            b, s, cfg.n_heads, cfg.qk_nope + cfg.qk_rope)
+        ckv = matmul(h, layer["wkva"])
+        c = rms_norm(ckv[..., :r], layer["kv_ln"], cfg.norm_eps)
+    with jax.named_scope("attn.rope"):
+        q_pe = rope(q[..., cfg.qk_nope:], positions, cfg.rope_theta,
+                    yarn=cfg.yarn)
+        k_pe = rope(ckv[..., None, r:], positions, cfg.rope_theta,
+                    yarn=cfg.yarn)[..., 0, :]
+        pad = cfg.latent_width - r - cfg.qk_rope
+        rows = jnp.concatenate(
+            [c, k_pe, jnp.zeros((b, s, pad), c.dtype)], axis=-1)
+    return q[..., :cfg.qk_nope], q_pe, rows, h
+
+
+def _wkvb(layer, cfg):
+    """Wkvb as [kv_lora_rank, H, nope + v]."""
+    return layer["wkvb"].reshape(cfg.kv_lora_rank, cfg.n_heads,
+                                 cfg.qk_nope + cfg.v_dim)
+
+
+def latent_prefill_attention(layer, cfg, q_nope, q_pe, rows):
+    """Causal attention of s queries over the rows [b, S, width] of
+    prefix + suffix (S >= s; the queries are the last s), unabsorbed:
+    [b, s, H * v_dim]."""
+    b, s = q_nope.shape[:2]
+    r, hq = cfg.kv_lora_rank, cfg.qk_nope + cfg.qk_rope
+    with jax.named_scope("attn.expand"):
+        kv = jnp.einsum("bsr,rhd->bshd", rows[..., :r], _wkvb(layer, cfg))
+        k_pe = jnp.broadcast_to(
+            rows[:, :, None, r:r + cfg.qk_rope],
+            (*kv.shape[:3], cfg.qk_rope))
+        k = jnp.concatenate([kv[..., :cfg.qk_nope], k_pe], axis=-1)
+        v = kv[..., cfg.qk_nope:]
+        # flash scales by the query width ** -0.5; YaRN's mscale ** 2
+        # rides on q
+        q = jnp.concatenate([q_nope, q_pe], axis=-1) * jnp.asarray(
+            latent_scale(cfg) * hq ** 0.5, q_nope.dtype)
+    with jax.named_scope("attn.kernel"):
+        return flash_prefill(q, k, v, causal=True).reshape(b, s, -1)
+
+
+def latent_decode(layer, cfg, q_nope, q_pe, pool, table, lens, pl):
+    """One new token a row over the latent pool, absorbed: q_nope
+    [b, H, nope], q_pe [b, H, rope] -> [b, H * v_dim]. K and V are
+    never built."""
+    b = q_nope.shape[0]
+    w = _wkvb(layer, cfg)
+    with jax.named_scope("attn.absorb"):
+        q_lat = jnp.einsum("bhd,rhd->bhr", q_nope, w[..., :cfg.qk_nope])
+        pad = cfg.latent_width - cfg.kv_lora_rank - cfg.qk_rope
+        q = jnp.concatenate(
+            [q_lat, q_pe, jnp.zeros((*q_pe.shape[:2], pad), q_pe.dtype)],
+            axis=-1) * jnp.asarray(latent_scale(cfg), q_pe.dtype)
+    with jax.named_scope("attn.kernel"):
+        o_lat = latent_decode_attention(q, pool, table, lens,
+                                        rank=cfg.kv_lora_rank, layer=pl)
+    with jax.named_scope("attn.absorb"):
+        out = jnp.einsum("bhr,rhd->bhd", o_lat, w[..., cfg.qk_nope:])
+    return out.reshape(b, -1)
+
+
 def forward_stack(block, params, cfg, tokens, prefix_kvs=None, pos0=0,
                   state=None, s_real=None):
     """The ONE decoder-stack loop shared by dense forward and
@@ -412,7 +610,7 @@ def forward_stack(block, params, cfg, tokens, prefix_kvs=None, pos0=0,
     prefix_len = 0 if prefix_kvs is None else max(
         k.shape[1] for k, _ in prefix_kvs)
     spec = attn_layers(cfg)
-    x = embed(params, tokens, cfg)
+    x = stream_open(cfg, embed(params, tokens, cfg))
     positions = jnp.broadcast_to(
         pos0 + prefix_len + jnp.arange(s)[None], (b, s)
     )
@@ -421,16 +619,26 @@ def forward_stack(block, params, cfg, tokens, prefix_kvs=None, pos0=0,
     states = []
     for layer, kind in zip(params["layers"], cfg.layer_kinds):
         h_attn = None
+        x_in, mix = stream_in(cfg, layer, x, "attn")
         if kind == "mamba":
             st = state[len(states)] if state is not None \
                 else ssm_zero_state(cfg, b)
-            out, st = ssm_mixer_seq(layer, x, cfg, st,
+            out, st = ssm_mixer_seq(layer, x_in, cfg, st,
                                     s if s_real is None else s_real)
-            x = residual(cfg, x, out)
+            x = residual(cfg, x, out, mix)
             states.append(st)
+        elif kind == "latent":
+            q_nope, q_pe, rows, h_attn = latent_project(layer, x_in, cfg,
+                                                        positions)
+            rows_all = rows if prefix_kvs is None else jnp.concatenate(
+                [prefix_kvs[len(kvs)][0].astype(rows.dtype), rows], axis=1)
+            attn = latent_prefill_attention(layer, cfg, q_nope, q_pe,
+                                            rows_all)
+            x = residual(cfg, x, attn_out(layer, attn), mix)
+            kvs.append((rows, None))
         else:
             band, rotates, pool, _ = spec[len(kvs)]
-            q, k, v, h_attn = _qkv(layer, x, cfg, positions, rotates)
+            q, k, v, h_attn = _qkv(layer, x_in, cfg, positions, rotates)
             if prefix_kvs is None:
                 k_full, v_full = k, v
             else:
@@ -443,12 +651,15 @@ def forward_stack(block, params, cfg, tokens, prefix_kvs=None, pos0=0,
             with jax.named_scope(_kernel_scope(cfg, pool)):
                 attn = flash_prefill(q, k_full, v_full, causal=True,
                                      window=band)
-            x = residual(cfg, x, attn_out(layer, attn.reshape(b, s, -1)))
+            x = residual(cfg, x, attn_out(layer, attn.reshape(b, s, -1)),
+                         mix)
             kvs.append((k, v))
-        out, aux = block(layer, x, cfg, None, h_attn)
-        x = residual(cfg, x, out)
+        x_in, mix = stream_in(cfg, layer, x, "ffn")
+        out, aux = block(layer, x_in, cfg, None, h_attn)
+        x = residual(cfg, x, out, mix)
         auxes.append(aux)
-    x = rms_norm(x, params["final_ln"], cfg.norm_eps, cfg.norm_plus_one)
+    x = rms_norm(stream_close(cfg, x), params["final_ln"], cfg.norm_eps,
+                 cfg.norm_plus_one)
     logits = lm_head(params, x, cfg)
     if states:
         return logits, kvs, auxes, states
@@ -489,7 +700,7 @@ def decode_step(block, params, cfg, token, seq_lens, k_pages, v_pages,
     before they were cached, and the band is relative).
     """
     b = token.shape[0]
-    x = embed(params, token[:, None], cfg)  # [b, 1, d]
+    x = stream_open(cfg, embed(params, token[:, None], cfg))  # [b, 1, d]
     positions = seq_lens[:, None]  # current position
 
     def place(table, lens):
@@ -515,16 +726,30 @@ def decode_step(block, params, cfg, token, seq_lens, k_pages, v_pages,
     hs, convs = [], []
     for layer, kind in zip(params["layers"], cfg.layer_kinds):
         h_attn = None
+        x_in, mix = stream_in(cfg, layer, x, "attn")
         if kind == "mamba":
             out, (h, conv) = ssm_mixer_step(
-                layer, x, cfg, (state["h"][mi], state["conv"][mi]))
-            x = residual(cfg, x, out)
+                layer, x_in, cfg, (state["h"][mi], state["conv"][mi]))
+            x = residual(cfg, x, out, mix)
             hs.append(h)
             convs.append(conv)
             mi += 1
+        elif kind == "latent":
+            # ONE pool (k_pages), no V pool: v_pages is None throughout
+            q_nope, q_pe, rows, h_attn = latent_project(layer, x_in, cfg,
+                                                        positions)
+            held = pools["full"]
+            _, _, table, lens, target_page, slot = held
+            with jax.named_scope("pool.update"):
+                held[0] = held[0].at[li, target_page, slot].set(
+                    rows[:, 0], mode="drop")
+            attn = latent_decode(layer, cfg, q_nope[:, 0], q_pe[:, 0],
+                                 held[0], table, lens + 1, li)
+            x = residual(cfg, x, attn_out(layer, attn[:, None]), mix)
+            li += 1
         else:
             band, rotates, pool, pl = spec[li]
-            q, k, v, h_attn = _qkv(layer, x, cfg, positions, rotates)
+            q, k, v, h_attn = _qkv(layer, x_in, cfg, positions, rotates)
             if cfg.kv_pack > 1:
                 q, k, v = pack_heads(cfg, q, k, v)
             held = pools[pool]
@@ -539,11 +764,14 @@ def decode_step(block, params, cfg, token, seq_lens, k_pages, v_pages,
                 )
                 if cfg.kv_pack > 1:
                     attn = unpack_heads(cfg, attn)
-            x = residual(cfg, x, attn_out(layer, attn.reshape(b, 1, -1)))
+            x = residual(cfg, x, attn_out(layer, attn.reshape(b, 1, -1)),
+                         mix)
             li += 1
-        out, _aux = block(layer, x, cfg, valid, h_attn)
-        x = residual(cfg, x, out)
-    x = rms_norm(x, params["final_ln"], cfg.norm_eps, cfg.norm_plus_one)
+        x_in, mix = stream_in(cfg, layer, x, "ffn")
+        out, _aux = block(layer, x_in, cfg, valid, h_attn)
+        x = residual(cfg, x, out, mix)
+    x = rms_norm(stream_close(cfg, x), params["final_ln"], cfg.norm_eps,
+                 cfg.norm_plus_one)
     logits = lm_head(params, x[:, 0], cfg)
     out = (logits, *pools["full"][:2])
     if state is not None:
@@ -591,6 +819,10 @@ def verify_step(block, params, cfg, tokens, seq_lens, k_pages, v_pages,
         raise NotImplementedError(
             "verify_step over state layers: a rejected draft cannot be "
             "rolled back out of a recurrent state")
+    if "latent" in cfg.layer_kinds or cfg.hc_mult > 1:
+        raise NotImplementedError(
+            "verify_step over a latent cache or several residual streams "
+            "is not built")
     if cfg.kv_pack > 1:
         raise NotImplementedError("verify_step over packed kv heads")
     if cfg.two_kinds:

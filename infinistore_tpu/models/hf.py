@@ -218,7 +218,7 @@ __all__ = ["config_from_hf", "params_from_hf", "load_hf",
            "moe_config_from_hf", "moe_params_from_hf", "load_hf_moe",
            "hybrid_config_from_hf", "hybrid_params_from_hf",
            "load_hf_hybrid", "smallthinker_config_from_hf",
-           "smallthinker_params_from_hf"]
+           "smallthinker_params_from_hf", "xing_config_from_hf"]
 
 
 def moe_config_from_hf(hf_cfg, page_size=16, dtype="float32"):
@@ -604,3 +604,96 @@ def smallthinker_params_from_hf(model_or_state_dict, cfg):
         "final_ln": _t(sd, "model.norm.weight", dt),
         "lm_head": _t(sd, "lm_head.weight", dt).T,
     }
+
+
+def xing_config_from_hf(hf_cfg, page_size=16, dtype="float32"):
+    """Map a ``model_type: xing4_0`` ``config.json`` (XingChen-AGI/
+    Xing4.0-29B-A4B; any object with its keys as attributes) onto
+    :class:`models.xing.XingConfig`: latent attention's ranks and head
+    widths, the leading dense layers and the routed and shared experts,
+    the sigmoid router's scale, the residual streams and their Sinkhorn
+    settings, YaRN. Refused, because models/xing.py does not implement
+    it: expert groups (``n_group`` / ``topk_group`` over 1), another
+    score than sigmoid or another selection than ``noaux_tc``, gates
+    not normalised on the chosen, an activation other than silu,
+    attention bias, experts on other than every layer after the dense
+    ones, a rope scaling other than YaRN, a clamp that is not
+    symmetric, tied embeddings, and a multi-token-prediction module
+    (``num_nextn_predict_layers`` over 0: it is not held). Config
+    only: a checkpoint's weights would also need the published rotary
+    lane order and the mHC parameters' names, which no public key
+    gives."""
+    from .xing import XingConfig
+
+    def refuse(what):
+        raise NotImplementedError(
+            f"xing4_0: {what} is not implemented by models/xing.py")
+
+    g = lambda k, d=None: getattr(hf_cfg, k, d)  # noqa: E731
+    if g("n_group", 1) != 1 or g("topk_group", 1) != 1:
+        refuse(f"expert groups (n_group {g('n_group')}, topk_group "
+               f"{g('topk_group')})")
+    if g("scoring_func", "sigmoid") != "sigmoid":
+        refuse(f"scoring_func {g('scoring_func')!r}")
+    if g("topk_method", "noaux_tc") != "noaux_tc":
+        refuse(f"topk_method {g('topk_method')!r}")
+    if not g("norm_topk_prob", True):
+        refuse("norm_topk_prob false")
+    if g("hidden_act", "silu") != "silu":
+        refuse(f"hidden_act {g('hidden_act')!r}")
+    if g("attention_bias", False):
+        refuse("attention_bias")
+    if g("moe_layer_freq", 1) != 1:
+        refuse(f"moe_layer_freq {g('moe_layer_freq')}")
+    if g("tie_word_embeddings", False):
+        refuse("tie_word_embeddings (the head is a leaf of its own)")
+    if g("num_nextn_predict_layers", 0):
+        refuse("a multi-token-prediction module "
+               f"(num_nextn_predict_layers {g('num_nextn_predict_layers')})")
+    lo, hi = g("mhc_h_res_clamp_min", -30), g("mhc_h_res_clamp_max", 30)
+    if lo != -hi:
+        refuse(f"a clamp that is not symmetric ({lo}, {hi})")
+    yarn = ()
+    rs = g("rope_scaling")
+    if rs:
+        rs = rs if isinstance(rs, dict) else vars(rs)
+        if rs.get("type", rs.get("rope_type")) != "yarn":
+            refuse(f"rope_scaling {rs!r}")
+        yarn = (float(rs["factor"]),
+                int(rs["original_max_position_embeddings"]),
+                float(rs.get("beta_fast", 32)), float(rs.get("beta_slow", 1)),
+                float(rs.get("mscale", 1)), float(rs.get("mscale_all_dim", 0)))
+    n = hf_cfg.num_hidden_layers
+    lead = g("first_k_dense_replace", 0)
+    if lead > n:
+        refuse(f"first_k_dense_replace {lead} over {n} layers")
+    return XingConfig(
+        vocab_size=hf_cfg.vocab_size,
+        d_model=hf_cfg.hidden_size,
+        n_layers=n,
+        n_heads=hf_cfg.num_attention_heads,
+        n_kv_heads=hf_cfg.num_attention_heads,
+        head_dim_override=hf_cfg.qk_nope_head_dim + hf_cfg.qk_rope_head_dim,
+        d_ff=hf_cfg.moe_intermediate_size,
+        ffn_dense=hf_cfg.intermediate_size,
+        n_dense_lead=lead,
+        n_experts=hf_cfg.n_routed_experts,
+        top_k=hf_cfg.num_experts_per_tok,
+        n_shared=g("n_shared_experts", 0) or 0,
+        route_scale=float(g("routed_scaling_factor", 1.0)),
+        q_lora_rank=hf_cfg.q_lora_rank,
+        kv_lora_rank=hf_cfg.kv_lora_rank,
+        qk_nope=hf_cfg.qk_nope_head_dim,
+        qk_rope=hf_cfg.qk_rope_head_dim,
+        v_dim=hf_cfg.v_head_dim,
+        hc_mult=g("hc_mult", 1),
+        hc_iters=g("hc_sinkhorn_iters", 20),
+        hc_eps=float(g("hc_eps", 1e-6)),
+        hc_clamp=float(hi),
+        yarn=yarn,
+        max_seq=hf_cfg.max_position_embeddings,
+        page_size=page_size,
+        rope_theta=float(hf_cfg.rope_theta),
+        norm_eps=float(hf_cfg.rms_norm_eps),
+        dtype=dtype,
+    )

@@ -87,6 +87,9 @@ class LlamaConfig:
     # head_dim lanes each fill the row instead, the same bytes in the
     # same order (decoder.pack_heads has the attention side). 1 = off.
     kv_pack: int = 1
+    # Residual streams (models/decoder.py's residual path): 1 = the
+    # one stream `x + out` every family but models/xing.py has.
+    hc_mult: int = 1
     dtype: str = "bfloat16"
 
     @property
@@ -134,6 +137,13 @@ class LlamaConfig:
         """Layers that keep K and V pages: the page pools' layer
         axis."""
         return sum(k == "attention" for k in self.layer_kinds)
+
+    @property
+    def page_kinds(self):
+        """The kinds of page a layer keeps, one letter each: the kind
+        of a page's store key, in the order of a page-major row. K and
+        V here; a latent family (models/xing.py) keeps one."""
+        return "kv"
 
     def kv_page_shape(self):
         """Shape of one K (or V) page for ONE layer — what goes into the

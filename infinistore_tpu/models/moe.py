@@ -44,6 +44,13 @@ class MoEConfig(llama.LlamaConfig):
     top_k: int = 2
     capacity_factor: float = 1.5
     aux_loss_weight: float = 0.01
+    # The sorted dispatch's router form and what runs beside the routed
+    # experts: "softmax" (`route_top_k`) or "sigmoid" (`route_sigmoid`,
+    # gates summing to `route_scale`), and `n_shared` experts every
+    # token goes through ungated (weights s_gate / s_up / s_down).
+    router: str = "softmax"
+    route_scale: float = 1.0
+    n_shared: int = 0
 
     def capacity(self, n_tokens):
         """Per-expert token slots: ceil(top_k * T / E * factor), rounded
@@ -201,6 +208,29 @@ def route_top_k(router, h, top_k):
     return logits, top_idx, jax.nn.softmax(top_z, axis=-1)
 
 
+def route_sigmoid(router, bias, h, top_k, scale):
+    """(scores + bias [T, E] float32, chosen experts [T, k], their
+    gates [T, k] float32) of a sigmoid router with a selection bias
+    (DeepSeek-V3's `noaux_tc` without groups): the score of an expert
+    is sigmoid(h . w_e); the k largest of score + bias are chosen (the
+    bias chooses, it does not weigh); a gate is the chosen expert's
+    SCORE over the sum of the chosen scores, times `scale`."""
+    scores = jax.nn.sigmoid(h.astype(jnp.float32) @ router)
+    biased = scores + bias
+    _, top_idx = jax.lax.top_k(biased, top_k)
+    top_s = jnp.take_along_axis(scores, top_idx, axis=-1)
+    gates = top_s / jnp.sum(top_s, axis=-1, keepdims=True) * scale
+    return biased, top_idx, gates
+
+
+def shared_expert(layer, u, act):
+    """The shared expert: every token, ungated. u: [T, d]."""
+    with jax.named_scope("moe.shared"):
+        a = act(decoder.matmul(u, layer["s_gate"])) \
+            * decoder.matmul(u, layer["s_up"])
+        return decoder.matmul(a, layer["s_down"])
+
+
 # The grouped matmul's rows are padded to a multiple of this. Measured
 # on a v5e (PERF.md, PR 35; one layer, 64 experts of 2560 x 768, 6 a
 # token): 12,544 tokens (75,264 rows = 147 x 512) 19.6 ms, 12,528 tokens
@@ -259,17 +289,26 @@ def sorted_moe_mlp(layer, x, cfg: MoEConfig, valid, h_attn=None,
     `early_router` the router reads `h_attn`, the attention block's
     normalised input, and not the block's own. Which of the two forms
     runs is decided by the number of tokens, which is a shape. The
-    gate's activation is the config's (`cfg.act`). No auxiliary loss
-    (serving only)."""
+    gate's activation is the config's (`cfg.act`), and so are the
+    router's form (`cfg.router`) and the shared expert (`cfg.n_shared`).
+    No auxiliary loss (serving only)."""
     b, s, d = x.shape
     with jax.named_scope("moe.route"):
         u = decoder.rms_norm(x, layer["ln2"], cfg.norm_eps,
                              cfg.norm_plus_one).reshape(b * s, d)
         seen = h_attn.reshape(b * s, d) if early_router else u
-        _, top_idx, gates = route_top_k(layer["router"], seen, cfg.top_k)
+        if cfg.router == "sigmoid":
+            _, top_idx, gates = route_sigmoid(
+                layer["router"], layer["router_bias"], seen, cfg.top_k,
+                cfg.route_scale)
+        else:
+            _, top_idx, gates = route_top_k(layer["router"], seen,
+                                            cfg.top_k)
     form = experts_dense \
         if b * s * cfg.n_experts <= DENSE_EXPERTS_MAX_ROWS else experts_sorted
     out = form(layer, u, top_idx, gates, _gate_act(cfg))
+    if cfg.n_shared:
+        out = out + shared_expert(layer, u, _gate_act(cfg))
     return out.reshape(b, s, d), None
 
 

@@ -297,6 +297,13 @@ def _forward_impl(q, k, v, causal, block_q, block_k, interpret, with_lse,
     kf = _layout_rows(k, n_kv, block_k)
     vf = _layout_rows(v, n_kv, block_k)
     hd_p = qf.shape[2]
+    # The values' width may differ from the queries' and keys' (latent
+    # attention: 192 against 128); output and accumulator are V's.
+    hd_v, hd_vp = v.shape[3], vf.shape[2]
+    if with_lse and hd_v != hd:
+        raise NotImplementedError(
+            "flash backward with a value width that differs from the "
+            "query width")
     nq = qf.shape[1] // block_q
     nk = kf.shape[1] // block_k
     _, _kv_idx, _ = _make_row_maps(
@@ -304,9 +311,9 @@ def _forward_impl(q, k, v, causal, block_q, block_k, interpret, with_lse,
         offset=kv_len - q_len,
     )
 
-    out_shapes = [jax.ShapeDtypeStruct(qf.shape, q.dtype)]
+    out_shapes = [jax.ShapeDtypeStruct((*qf.shape[:2], hd_vp), q.dtype)]
     out_specs = [
-        pl.BlockSpec((1, block_q, hd_p), lambda bh, qi, ki: (bh, qi, 0))
+        pl.BlockSpec((1, block_q, hd_vp), lambda bh, qi, ki: (bh, qi, 0))
     ]
     if with_lse:
         out_shapes.append(jax.ShapeDtypeStruct(
@@ -326,18 +333,18 @@ def _forward_impl(q, k, v, causal, block_q, block_k, interpret, with_lse,
         in_specs=[
             pl.BlockSpec((1, block_q, hd_p), lambda bh, qi, ki: (bh, qi, 0)),
             pl.BlockSpec((1, block_k, hd_p), _kv_idx),
-            pl.BlockSpec((1, block_k, hd_p), _kv_idx),
+            pl.BlockSpec((1, block_k, hd_vp), _kv_idx),
         ],
         out_specs=out_specs,
         scratch_shapes=[
-            pltpu.VMEM((block_q, hd_p), jnp.float32),  # acc
+            pltpu.VMEM((block_q, hd_vp), jnp.float32),  # acc
             pltpu.VMEM((block_q, 1), jnp.float32),     # m
             pltpu.VMEM((block_q, 1), jnp.float32),     # l
         ],
         interpret=interpret,
     )(qf, kf, vf)
-    out = res[0][:, :q_len, :hd]
-    out = out.reshape(batch, n_heads, q_len, hd).transpose(0, 2, 1, 3)
+    out = res[0][:, :q_len, :hd_v]
+    out = out.reshape(batch, n_heads, q_len, hd_v).transpose(0, 2, 1, 3)
     if not with_lse:
         return out
     # Residual logsumexp as unpadded [B, H, S] (lane 0 of the replicated
@@ -359,6 +366,7 @@ def flash_prefill_attention(q, k, v, causal=True, block_q=None, block_k=None,
 
     q: [batch, s_q, n_heads, hd]; k/v: [batch, s_kv, n_kv, hd] (GQA —
     n_heads must be a multiple of n_kv). Returns [batch, s_q, n_heads, hd].
+    v may have a width of its own, which is then the output's.
     s_kv may exceed s_q (prefix-cached prefill: suffix queries attending
     over restored-prefix + suffix KV); under `causal` the diagonal then
     shifts right by s_kv - s_q, i.e. query i sees kv j <= i + prefix_len.

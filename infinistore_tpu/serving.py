@@ -134,16 +134,17 @@ def content_page_keys(tokens, page_size, n_pages, layer, kind,
     return [f"cp/{d}/L{layer}/{kind}" for d in digests]
 
 
-def content_page_keys_by_page(digests, layers):
+def content_page_keys_by_page(digests, layers, kinds="kv"):
     """The same keys for every layer and kind of each page, page-major
     (page, layer, k then v): the row order of `_gather_pages`, so one
     offload is one key list over one array. `layers`: how many (0 ..
     layers - 1), or which: a pool that holds some of the layers that
-    keep pages (one kind of two) names them."""
+    keep pages (one kind of two) names them. `kinds`: the family's
+    kinds of page (`cfg.page_kinds`; a latent family has one)."""
     if isinstance(layers, int):
         layers = range(layers)
     return [f"cp/{d}/L{layer}/{kind}" for d in digests
-            for layer in layers for kind in "kv"]
+            for layer in layers for kind in kinds]
 
 
 def snapshot_keys(digest, lo, hi):
@@ -195,6 +196,16 @@ class ServingConfig:
     #                              decoding slots, so a long prompt
     #                              never stalls other sequences' decode
     #                              (vLLM-style chunked prefill)
+    admit_piece: int = 0         # admission in pieces (0 = off): tokens,
+    #                              a page multiple. A prompt whose
+    #                              uncached part is longer is admitted a
+    #                              piece an engine step, each piece ONE
+    #                              call of the program a hit runs (the
+    #                              prefix program) over the pages the
+    #                              slot holds so far; the other slots
+    #                              decode between pieces. Bounds an
+    #                              admission program's temporaries by
+    #                              the piece, not by the prompt
 
 
 @dataclass
@@ -280,6 +291,10 @@ class _Slot:
     #                                              prefill phase)
     index: int = -1           # the slot it sits in: its row of the
     #                           state pools (families with state)
+    todo: list = field(default_factory=list)  # prompt tokens whose pieces
+    #                           are still to run (admission in pieces):
+    #                           the slot decodes once this is empty
+    pieces: tuple = (0, 0)    # pieces run, and of how many
     # A model with full and banded layers (two kinds of page): the
     # banded layers' pool pages, in sequence order from page `wbase`
     # of the sequence on (what lies below left the band), and the page
@@ -406,6 +421,8 @@ def _page_out(cfg, kvs, k_pages, v_pages, ids):
         m = kvs[0][0].shape[1] // page
         shape = (cfg.n_kv_layers, m, *cfg.kv_page_shape())
         k_sfx = jnp.stack([k[0] for k, _ in kvs]).reshape(shape)
+        if v_pages is None:  # a latent family's one pool
+            return k_pages.at[:, ids[:m]].set(k_sfx, mode="drop"), None
         v_sfx = jnp.stack([v[0] for _, v in kvs]).reshape(shape)
         k_pages = k_pages.at[:, ids[:m]].set(k_sfx, mode="drop")
         v_pages = v_pages.at[:, ids[:m]].set(v_sfx, mode="drop")
@@ -439,6 +456,16 @@ def _place_restored(cfg, restored, k_pages, v_pages, restored_ids):
     page = cfg.page_size
     n = restored_ids.shape[0]
     L = cfg.n_kv_layers
+    if v_pages is None:
+        # A latent family: ONE page a layer, rows (page, layer); the
+        # prefix is the rows themselves, layer-major.
+        with jax.named_scope("pool.update"):
+            rows = restored.reshape(n, L, *cfg.kv_page_shape())
+            for li in range(L):
+                k_pages = k_pages.at[li, restored_ids].set(rows[:, li],
+                                                           mode="drop")
+            pfx = jnp.moveaxis(rows, 0, 1).reshape(L, 1, n * page, -1)
+        return k_pages, None, [(pfx[li], None) for li in range(L)]
     with jax.named_scope("pool.update"):  # stage names: models/decoder.py
         # Each layer's pages go from the page-major rows straight into
         # that layer of the pool. Scattered as one `[:, ids]` update
@@ -875,6 +902,8 @@ def _gather_pages(k_pool, v_pool, ids):
     second dispatch."""
     with jax.named_scope("pool.gather"):
         k = k_pool.at[:, ids].get(mode="promise_in_bounds")
+        if v_pool is None:  # a latent family's one pool: [ids, L, ...]
+            return jnp.swapaxes(k, 0, 1).reshape(-1)
         v = v_pool.at[:, ids].get(mode="promise_in_bounds")
         rows = jnp.stack([k, v], axis=2)  # [L, len(ids), 2, page, kv, hd]
         return jnp.swapaxes(rows, 0, 1).reshape(-1)
@@ -989,7 +1018,15 @@ class ServingEngine:
                  *cfg.kv_page_shape())
         self.k_pages = jnp.zeros(shape, dtype=cfg.jdtype,
                                  device=self.device)
-        self.v_pages = jnp.zeros_like(self.k_pages)
+        # A latent family keeps ONE page a layer (`cfg.page_kinds` one
+        # letter): its rows live in `k_pages` [layers, pages, page,
+        # width] and there is no second pool. Every program takes the
+        # pair as it is: None is an empty pytree to jit.
+        self._latent = "latent" in cfg.layer_kinds
+        if self._latent:
+            self._check_latent_family()
+        self.v_pages = None if self._latent \
+            else jnp.zeros_like(self.k_pages)
         self.wk_pages = self.wv_pages = None
         if self._win_layers:
             self._init_window_pools()
@@ -1058,6 +1095,11 @@ class ServingEngine:
             # finish to its tokens in `outputs`, summed, ms)
             "uploads": 0, "upload_backpressure_waits": 0,
             "done_held_ms": 0.0,
+            # admission in pieces: pieces run; a latent family: its
+            # pages (a sequence page of one layer each) that offloads
+            # wrote to the store and hits restored from it
+            "admit_pieces": 0, "latent_pages_written": 0,
+            "latent_pages_restored": 0,
         }
         # Requests finished: in `outputs`, or held for their offload's
         # acknowledgement. What a driver reads as progress.
@@ -1084,9 +1126,20 @@ class ServingEngine:
         self._own_digests = {}  # insertion-ordered, at most OWN_DIGESTS
         # One sequence page over every layer and kind the page pools
         # hold, and one state snapshot, in bytes.
-        self._page_objects = 2 * self.k_pages.shape[0]
-        self._page_bytes = (self.k_pages.nbytes + self.v_pages.nbytes) \
-            // self.sc.total_pages
+        kinds = len(cfg.page_kinds)
+        self._page_objects = kinds * self.k_pages.shape[0]
+        self._page_bytes = kinds * self.k_pages.nbytes // self.sc.total_pages
+        if self.sc.admit_piece % cfg.page_size:
+            raise ValueError(
+                f"admit_piece {self.sc.admit_piece} is no multiple of the "
+                f"page ({cfg.page_size} tokens)")
+        if self.sc.admit_piece and (self.sc.prefill_chunk or cfg.window
+                                    or self._win_layers or self.state):
+            raise ValueError(
+                "admit_piece runs the prefix program over the slot's own "
+                "pool pages: not built beside prefill_chunk, a sliding "
+                "window, two kinds of attention layer or state layers")
+        self._piece_ran = False  # a piece ran in the step under way
         self._snapshot_bytes = 0
         self._snapshot_fields = {}  # what its spans carry beyond pages'
         if self.state is not None:
@@ -1128,6 +1181,10 @@ class ServingEngine:
             f"{model_id}/p{cfg.page_size}/l{cfg.n_layers}"
             f"/kv{cfg.n_kv_heads}x{cfg.head_dim}/{wire}"
         )
+        if self._latent:
+            # ... a latent row is no K page, whatever its bytes
+            self._ns += f"/latent{cfg.kv_lora_rank}+{cfg.qk_rope}" \
+                f"w{cfg.latent_width}"
         if self._win_layers:
             # ... of every cache kind: which layers are banded, and how
             # widely (a page of a banded layer is not a page of a full
@@ -1204,6 +1261,26 @@ class ServingEngine:
             jax.block_until_ready(_gather_pages(
                 self.wk_pages, self.wv_pages,
                 self._to_device(np.zeros(n, np.int32))))
+
+    def _check_latent_family(self):
+        """What is not built over a latent pool is refused at
+        construction, not found at the first request: verify, burst and
+        chunk steps address K and V pages by head, the int8 wire
+        quantizes a row a head, and packed rows are heads side by
+        side."""
+        sc, cfg = self.sc, self.cfg
+        for name, on in (("spec_k", sc.spec_k > 0),
+                         ("host_steps", sc.host_steps > 1),
+                         ("prefill_chunk", sc.prefill_chunk > 0),
+                         ("quantized_store", sc.quantized_store),
+                         ("kv_pack", cfg.kv_pack > 1),
+                         ("window", bool(cfg.window_band)),
+                         ("state layers",
+                          bool(getattr(cfg, "n_state_layers", 0)))):
+            if on:
+                raise ValueError(
+                    f"{name} is not supported for a model with a latent "
+                    f"cache ({type(cfg).__name__})")
 
     def _check_state_family(self):
         """What is not built over a recurrent state is refused at
@@ -1373,7 +1450,8 @@ class ServingEngine:
             try:
                 hit = self.store.cached_prefix_len(
                     content_page_keys(work.prompt, self.cfg.page_size,
-                                      cap, 0, "k", digests=digests)
+                                      cap, 0, self.cfg.page_kinds[0],
+                                      digests=digests)
                 )
             except Exception as e:
                 self._store_failed("probe", e)
@@ -1434,7 +1512,7 @@ class ServingEngine:
                                           self._first_live(hit))
             else:
                 keys = [key for li in range(cfg.n_kv_layers)
-                        for kind in ("k", "v")
+                        for kind in cfg.page_kinds
                         for key in content_page_keys(
                             prompt, cfg.page_size, hit, li, kind,
                             digests=digests)]
@@ -1639,6 +1717,8 @@ class ServingEngine:
         self.stats["foreign_hit_pages"] += foreign
         f["foreign_pages"] = foreign
         self.stats["restored_pages"] += restored.shape[0]
+        if self._latent:
+            self.stats["latent_pages_restored"] += restored.shape[0]
         self.stats["snapshots_restored"] += snap is not None
         if self._win_layers:
             self.stats["restore_trimmed_pages"] += first_live
@@ -1662,7 +1742,8 @@ class ServingEngine:
         offloads wrote it, so each reads back in few runs."""
         if not self._win_layers:
             return content_page_keys_by_page(digests[first_live:hit],
-                                             self.cfg.n_kv_layers)
+                                             self.cfg.n_kv_layers,
+                                             self.cfg.page_kinds)
         return content_page_keys_by_page(digests[:hit], self._full_layers) \
             + content_page_keys_by_page(digests[first_live:hit],
                                         self._win_layers)
@@ -1742,6 +1823,22 @@ class ServingEngine:
             return
 
         suffix = work.prompt[hit * page:]
+        piece = self.sc.admit_piece
+        if piece and len(suffix) > piece:
+            # Admission in pieces: the slot holds its pages and the
+            # tokens still to admit; `_step_pieces` runs a piece an
+            # engine step and the slot decodes once none is left. A
+            # hit's restored pages go in with its first piece, here.
+            self.page_table[slot_idx] = row
+            slot = _Slot(
+                work=work, page_ids=full_ids, seq_len=hit * page,
+                cached_pages=hit, released=skip, index=slot_idx,
+                todo=list(suffix), pieces=(0, -(-len(suffix) // piece)),
+            )
+            self.slots[slot_idx] = slot
+            if restored is not None:
+                self._run_piece(slot, restored)
+            return
         if restored is None:
             # Cold admission (hit == 0). Dead prompt pages [0, skip)
             # scatter to the drop sentinel: no pool page was allocated
@@ -1775,6 +1872,66 @@ class ServingEngine:
         # already trimmed to [first_live, hit) — only the PROBE's key
         # list stays O(prompt), it is hash-only).
         self._release_windowed(slot)
+
+    # ---- admission in pieces --------------------------------------------
+
+    def _run_piece(self, slot, restored=None):
+        """The next piece of `slot`'s prompt (`ServingConfig.
+        admit_piece` tokens, or the tail): ONE program call. A cold
+        prompt's first piece is the cold program; every other piece is
+        the prefix program (`_prefill_hit`, the program a hit runs)
+        over the pages the slot holds so far: `restored`, a hit's pages
+        as its store call returned them (they go into the pool here),
+        or the slot's own pool pages, gathered into that same form.
+        Returns the piece's last logits row (the first token's, once
+        `slot.todo` is empty)."""
+        page = self.cfg.page_size
+        tokens = slot.todo[:self.sc.admit_piece]
+        held = slot.seq_len // page  # pieces and hits end on page edges
+        ids = slot.page_ids[held:held - (-len(tokens) // page)]
+        k, of = slot.pieces
+        with self._span("istpu.sched.admit_piece",
+                        slot.work.req.request_id, tokens=len(tokens),
+                        prefix_pages=held, piece=k + 1, of=of):
+            if restored is None and held == 0:
+                row = self._prefill_cold(tokens, self._pad_ids(ids),
+                                         slot.index)
+            else:
+                r_ids = slot.page_ids[:held]
+                if restored is None:
+                    with self._span("istpu.cache.pool_read", pages=held):
+                        restored = _gather_pages(
+                            self.k_pages, self.v_pages, self._to_device(
+                                np.asarray(r_ids, np.int32))
+                        ).reshape(-1, *self.cfg.kv_page_shape())
+                    r_ids = [self.sc.total_pages] * held  # they are there
+                row = self._prefill_hit(tokens, restored, 0, r_ids, ids)
+        slot.todo = slot.todo[len(tokens):]
+        slot.seq_len += len(tokens)
+        slot.pieces = (k + 1, of)
+        self.stats["admit_pieces"] += 1
+        self.stats["prefill_tokens"] += len(tokens)
+        self._piece_ran = True
+        return row
+
+    def _step_pieces(self):
+        """One piece of the first slot that is still being admitted,
+        unless this step ran one already (an admission's own first).
+        After the last piece the slot has its first token and decodes
+        from this step on."""
+        for s in self.slots:
+            if s is None or not s.todo or self._piece_ran:
+                continue
+            row = self._run_piece(s)
+            if not s.todo:
+                self._emit(s, [self._pick(s.work, row)])
+            self._settle_if_left_idle()
+
+    def _active(self):
+        """(index, slot) of the slots that decode: occupied and
+        admitted whole."""
+        return [(i, s) for i, s in enumerate(self.slots)
+                if s is not None and not s.todo]
 
     # ---- admission over two kinds of attention layer -------------------
 
@@ -2101,7 +2258,25 @@ class ServingEngine:
                 restored, snap = self._restore(hit, digests, first_live)
             except InfiniStoreKeyNotFound:
                 hit = 0  # evicted between probe and restore
-        if hit > 0:
+        piece = self.sc.admit_piece
+        if piece and len(prompt) - hit * page > piece:
+            # What an admission in pieces runs, on pool pages taken for
+            # the call and given back (the engine is idle: they are
+            # free, and free again after).
+            ids = self._alloc(-(-len(prompt) // page))
+            if ids is None:
+                raise RuntimeError("first_token_logits: the prompt needs "
+                                   "more pool pages than are free")
+            tail = prompt[hit * page:]
+            slot = _Slot(work=work, page_ids=ids, seq_len=hit * page,
+                         todo=list(tail), pieces=(0, -(-len(tail) // piece)))
+            try:
+                row = self._run_piece(slot, restored if hit > 0 else None)
+                while slot.todo:
+                    row = self._run_piece(slot)
+            finally:
+                self.free_pages.extend(ids)
+        elif hit > 0:
             row = self._prefill_hit(
                 prompt[hit * page:], restored, first_live * page,
                 [self.sc.total_pages] * (hit - first_live), [], snap)
@@ -2236,7 +2411,9 @@ class ServingEngine:
         rid = slot.work.req.request_id
         up = _Upload(reason, rid, n, nbytes, digests=new_digests,
                      counts={"offloaded_pages": n, "snapshots_written":
-                             int(self.state is not None)})
+                             int(self.state is not None),
+                             "latent_pages_written":
+                             n * self._page_objects * self._latent})
         with self._span("istpu.cache.offload", rid, reason=reason, pages=n,
                         bytes=nbytes, padded_pages=0, puts=0,
                         **self._snapshot_fields) as f:
@@ -2291,7 +2468,8 @@ class ServingEngine:
                 flat.copy_to_host_async()
             up.chunks.append((flat, self.cfg.kv_page_shape(),
                               content_page_keys_by_page,
-                              (digests[a:a + c], layers)))
+                              (digests[a:a + c], layers,
+                               self.cfg.page_kinds)))
 
     def _gather_snapshot_rows(self, up, slot, digest):
         """The slot's boundary copy onto the upload `up`, keyed by
@@ -2610,14 +2788,15 @@ class ServingEngine:
         """step() proper; `f` holds the step span's fields (kind,
         active slots, k)."""
         self.collect_uploads()
+        self._piece_ran = False
         for i in range(self.sc.max_slots):
             if self.slots[i] is None and self.queue:
                 if self._admit(i, self.queue[0]):
                     self.queue.pop(0)
+        if self.sc.admit_piece:
+            self._step_pieces()
 
-        active = [
-            (i, s) for i, s in enumerate(self.slots) if s is not None
-        ]
+        active = self._active()
         if not active:
             return 0
 
@@ -2630,9 +2809,7 @@ class ServingEngine:
             )
             if done:
                 self._finish(i, s)
-        active = [
-            (i, s) for i, s in enumerate(self.slots) if s is not None
-        ]
+        active = self._active()
         if not active:
             return 0
 
@@ -2695,9 +2872,7 @@ class ServingEngine:
                     else:
                         self._finish(i, s)
                     continue
-        active = [
-            (i, s) for i, s in enumerate(self.slots) if s is not None
-        ]
+        active = self._active()
         if not active:
             return 0
 

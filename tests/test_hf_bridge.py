@@ -793,3 +793,106 @@ def test_smallthinker_params_round_trip_a_state_dict():
     del sd["model.layers.1.block_sparse_moe.experts.7.up.weight"]
     with pytest.raises(KeyError, match="experts.7.up"):
         hf.smallthinker_params_from_hf(sd, cfg)
+
+
+# -- xing4_0: a latent cache, residual streams, a sigmoid router ------------
+XING = dict(
+    attention_bias=False, first_k_dense_replace=1, hidden_act="silu",
+    hidden_size=64, intermediate_size=128, kv_lora_rank=32,
+    max_position_embeddings=4096, model_type="xing4_0",
+    moe_intermediate_size=32, moe_layer_freq=1, n_group=1,
+    n_routed_experts=8, n_shared_experts=1, norm_topk_prob=True,
+    num_attention_heads=4, num_experts_per_tok=2, num_hidden_layers=3,
+    num_key_value_heads=4, num_nextn_predict_layers=0, hc_mult=4,
+    hc_sinkhorn_iters=20, hc_eps=1e-6, mhc_h_res_clamp_min=-30,
+    mhc_h_res_clamp_max=30, q_lora_rank=48, qk_nope_head_dim=16,
+    qk_rope_head_dim=8, rms_norm_eps=1e-6, rope_theta=10000,
+    rope_scaling=dict(beta_fast=32, beta_slow=1, factor=8, mscale=1,
+                      mscale_all_dim=1,
+                      original_max_position_embeddings=32, type="yarn"),
+    routed_scaling_factor=2, scoring_func="sigmoid",
+    tie_word_embeddings=False, topk_group=1, topk_method="noaux_tc",
+    v_head_dim=16, vocab_size=128)
+
+
+def _xing(**changed):
+    import types
+
+    return types.SimpleNamespace(**{**XING, **changed})
+
+
+def test_xing_config_maps_every_key():
+    cfg = hf.xing_config_from_hf(_xing(), page_size=8)
+    assert (cfg.d_model, cfg.n_layers, cfg.n_heads, cfg.vocab_size,
+            cfg.max_seq, cfg.rope_theta, cfg.norm_eps, cfg.act) == (
+        64, 3, 4, 128, 4096, 1e4, 1e-6, "silu")
+    assert (cfg.q_lora_rank, cfg.kv_lora_rank, cfg.qk_nope, cfg.qk_rope,
+            cfg.v_dim, cfg.head_dim) == (48, 32, 16, 8, 16, 24)
+    assert (cfg.n_dense_lead, cfg.ffn_dense, cfg.d_ff, cfg.n_experts,
+            cfg.top_k, cfg.n_shared, cfg.route_scale, cfg.router) == (
+        1, 128, 32, 8, 2, 1, 2.0, "sigmoid")
+    assert (cfg.hc_mult, cfg.hc_iters, cfg.hc_eps, cfg.hc_clamp) == (
+        4, 20, 1e-6, 30.0)
+    assert cfg.yarn == (8.0, 32, 32.0, 1.0, 1.0, 1.0)
+    assert cfg.layer_kinds == ("latent",) * 3 and cfg.page_kinds == "c"
+    assert cfg.kv_page_shape() == (8, 128)
+    plain = hf.xing_config_from_hf(_xing(rope_scaling=None, hc_mult=1,
+                                         n_shared_experts=0))
+    assert plain.yarn == () and plain.hc_mult == 1 and plain.n_shared == 0
+
+
+@pytest.mark.parametrize("changed,match", [
+    ({"n_group": 8, "topk_group": 4}, "expert groups"),
+    ({"scoring_func": "softmax"}, "scoring_func"),
+    ({"topk_method": "greedy"}, "topk_method"),
+    ({"norm_topk_prob": False}, "norm_topk_prob"),
+    ({"hidden_act": "gelu"}, "hidden_act"),
+    ({"attention_bias": True}, "attention_bias"),
+    ({"moe_layer_freq": 2}, "moe_layer_freq"),
+    ({"tie_word_embeddings": True}, "tie_word_embeddings"),
+    ({"num_nextn_predict_layers": 1}, "multi-token-prediction"),
+    ({"mhc_h_res_clamp_min": -10}, "clamp"),
+    ({"rope_scaling": {"rope_type": "llama3", "factor": 8.0}},
+     "rope_scaling"),
+    ({"first_k_dense_replace": 4}, "first_k_dense_replace"),
+])
+def test_xing_bridge_refuses_what_is_not_implemented(changed, match):
+    with pytest.raises(NotImplementedError, match=match):
+        hf.xing_config_from_hf(_xing(**changed))
+
+
+def test_yarn_frequencies_are_the_published_recipe():
+    """decoder.rope under YaRN against the recipe written out in numpy
+    (DeepSeek-V2's `yarn_find_correction_range` / `yarn_linear_ramp_mask`):
+    fast dimensions keep their frequency, slow ones are divided by the
+    factor, a linear ramp between."""
+    import math
+
+    import jax.numpy as jnp
+
+    from infinistore_tpu.models import decoder
+
+    dim, theta, factor, orig = 64, 10000.0, 64.0, 4096
+    inv = theta ** (-np.arange(0, dim, 2) / dim)
+
+    def correction_dim(turns):
+        return dim * math.log(orig / (turns * 2 * math.pi)) \
+            / (2 * math.log(theta))
+
+    low = max(math.floor(correction_dim(32)), 0)
+    high = min(math.ceil(correction_dim(1)), dim - 1)
+    ramp = np.clip((np.arange(dim // 2) - low) / (high - low), 0, 1)
+    want = inv / factor * ramp + inv * (1 - ramp)
+    got, mult = decoder._yarn_scale_freqs(
+        jnp.asarray(inv, jnp.float32), theta, (factor, orig, 32, 1, 1, 1))
+    assert mult == 1.0
+    assert np.allclose(np.asarray(got), want, rtol=1e-6)
+    assert got[0] == pytest.approx(inv[0]) \
+        and got[-1] == pytest.approx(inv[-1] / factor)
+    assert decoder.yarn_mscale(64.0, 1.0) == pytest.approx(
+        0.1 * math.log(64) + 1)
+    # without YaRN the rotation is what it was
+    x = jnp.ones((1, 4, 1, 8))
+    pos = jnp.arange(4)[None]
+    assert np.array_equal(np.asarray(decoder.rope(x, pos, theta)),
+                          np.asarray(decoder.rope(x, pos, theta, yarn=())))

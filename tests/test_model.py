@@ -438,7 +438,11 @@ def test_moe_config_is_a_llama_config():
     shared = [f.name for f in dataclasses.fields(llama.LlamaConfig)]
     assert {f.name for f in dataclasses.fields(moe.MoEConfig)} == set(
         shared) | {"n_experts", "top_k", "capacity_factor",
-                   "aux_loss_weight"}
+                   "aux_loss_weight",
+                   # the sorted dispatch's router form and shared experts
+                   "router", "route_scale", "n_shared"}
+    assert (ext.router, ext.route_scale, ext.n_shared) == ("softmax", 1.0,
+                                                           0)
     assert all(getattr(base, n) == getattr(ext, n) for n in shared)
     for name in ("head_dim", "jdtype", "kv_page_shape", "kv_page_bytes"):
         assert name not in vars(moe.MoEConfig), name  # inherited, not copied
