@@ -12,7 +12,10 @@ over a recurrent state (ops/ssm.py).
 
 `block(layer, x, cfg, valid, h_attn)` maps the residual stream [b, s, d]
 to the block's output [b, s, d] and the family's auxiliary loss for that
-layer (None where it has none). `valid` ([b, s] bool or None) marks the
+layer (None where it has none); a block over routed experts returns a
+third: the experts this call fetched, an int32 scalar, where it fetched
+fewer than it holds, else None (`decode_step` sums them for the
+engine's counter). `valid` ([b, s] bool or None) marks the
 rows that hold a real token: a block whose tokens compete for something
 (MoE expert capacity) keeps the others out, a block that treats tokens
 independently ignores it. `h_attn` is the normalised input the layer's
@@ -655,7 +658,7 @@ def forward_stack(block, params, cfg, tokens, prefix_kvs=None, pos0=0,
                          mix)
             kvs.append((k, v))
         x_in, mix = stream_in(cfg, layer, x, "ffn")
-        out, aux = block(layer, x_in, cfg, None, h_attn)
+        out, aux, *_ = block(layer, x_in, cfg, None, h_attn)
         x = residual(cfg, x, out, mix)
         auxes.append(aux)
     x = rms_norm(stream_close(cfg, x), params["final_ln"], cfg.norm_eps,
@@ -667,7 +670,7 @@ def forward_stack(block, params, cfg, tokens, prefix_kvs=None, pos0=0,
 
 
 def decode_step(block, params, cfg, token, seq_lens, k_pages, v_pages,
-                page_table, state=None, win=None):
+                page_table, state=None, win=None, fetched=False):
     """One decode step over paged KV.
 
     token:      [batch] int32 — current input token
@@ -684,6 +687,9 @@ def decode_step(block, params, cfg, token, seq_lens, k_pages, v_pages,
                 the absolute position of each row's first entry, a
                 page multiple). k_pages/v_pages then hold the full
                 layers alone.
+    fetched:    also return, last, the experts this step's blocks
+                fetched, summed over the layers (int32; 0 where no
+                block reports any).
 
     Returns (logits [batch, vocab] fp32, k_pages, v_pages): the pools
     it was given with, per attention layer, the new token's K and V
@@ -723,7 +729,7 @@ def decode_step(block, params, cfg, token, seq_lens, k_pages, v_pages,
 
     spec = attn_layers(cfg)
     li = mi = 0  # rank among the attention / the state layers
-    hs, convs = [], []
+    hs, convs, experts = [], [], []
     for layer, kind in zip(params["layers"], cfg.layer_kinds):
         h_attn = None
         x_in, mix = stream_in(cfg, layer, x, "attn")
@@ -768,7 +774,8 @@ def decode_step(block, params, cfg, token, seq_lens, k_pages, v_pages,
                          mix)
             li += 1
         x_in, mix = stream_in(cfg, layer, x, "ffn")
-        out, _aux = block(layer, x_in, cfg, valid, h_attn)
+        out, _aux, *n = block(layer, x_in, cfg, valid, h_attn)
+        experts += [c for c in n if c is not None]
         x = residual(cfg, x, out, mix)
     x = rms_norm(stream_close(cfg, x), params["final_ln"], cfg.norm_eps,
                  cfg.norm_plus_one)
@@ -778,6 +785,8 @@ def decode_step(block, params, cfg, token, seq_lens, k_pages, v_pages,
         out += ({"h": hs, "conv": convs},)
     if win is not None:
         out += tuple(pools["window"][:2])
+    if fetched:
+        out += (sum(experts, jnp.int32(0)),)
     return out
 
 
@@ -858,7 +867,7 @@ def verify_step(block, params, cfg, tokens, seq_lens, k_pages, v_pages,
                 window=band, layer=li
             )
         x = residual(cfg, x, attn_out(layer, attn.reshape(b, m, -1)))
-        out, _aux = block(layer, x, cfg, ok, h_attn)
+        out, _aux, *_ = block(layer, x, cfg, ok, h_attn)
         x = residual(cfg, x, out)
     x = rms_norm(x, params["final_ln"], cfg.norm_eps, cfg.norm_plus_one)
     return lm_head(params, x, cfg), k_pages, v_pages
@@ -878,7 +887,7 @@ def bind(block):
 
     return (
         bound(forward_stack),
-        jax.jit(bound(decode_step), static_argnames=("cfg",)),
+        jax.jit(bound(decode_step), static_argnames=("cfg", "fetched")),
         jax.jit(bound(verify_step), static_argnames=("cfg",)),
     )
 

@@ -30,6 +30,7 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 
+from ..ops import pallas_moe_decode
 from . import decoder, llama
 
 
@@ -100,6 +101,24 @@ def init_params(rng, cfg: MoEConfig):
     }
 
 
+def _top_k_gates(layer, h, cfg: MoEConfig):
+    """(router probabilities [T, E] float32, chosen experts [T, k],
+    their gates [T, k] renormalised over the chosen: the Mixtral
+    convention)."""
+    logits = h.astype(jnp.float32) @ layer["router"]  # [T, E]
+    probs = jax.nn.softmax(logits, axis=-1)
+    top_w, top_idx = jax.lax.top_k(probs, cfg.top_k)  # [T, k]
+    return probs, top_idx, top_w / jnp.sum(top_w, axis=-1, keepdims=True)
+
+
+def _balance_loss(chosen, probs):
+    """Switch-style load-balance loss: E * Σ_e (frac tokens to e) *
+    (mean router prob of e) — minimized when both are uniform.
+    chosen: [T, E] in {0, 1}."""
+    return chosen.shape[1] * jnp.sum(jnp.mean(chosen, axis=0)
+                                     * jnp.mean(probs, axis=0))
+
+
 def _route(layer, h, cfg: MoEConfig, valid=None):
     """Top-k routing → static dispatch/combine tensors + aux loss.
 
@@ -114,11 +133,7 @@ def _route(layer, h, cfg: MoEConfig, valid=None):
     T = h.shape[0]
     E = cfg.n_experts
     C = cfg.capacity(T)
-    logits = h.astype(jnp.float32) @ layer["router"]  # [T, E]
-    probs = jax.nn.softmax(logits, axis=-1)
-    top_w, top_idx = jax.lax.top_k(probs, cfg.top_k)  # [T, k]
-    # Renormalize the selected gates (Mixtral convention).
-    top_w = top_w / jnp.sum(top_w, axis=-1, keepdims=True)
+    probs, top_idx, top_w = _top_k_gates(layer, h, cfg)
 
     # mask[t, e] = gate weight if e selected for t else 0.
     sel = jax.nn.one_hot(top_idx, E, dtype=jnp.float32)  # [T, k, E]
@@ -138,26 +153,40 @@ def _route(layer, h, cfg: MoEConfig, valid=None):
     )
     combine = dispatch * gates[..., None]  # [T, E, C]
 
-    # Switch-style load-balance loss: E * Σ_e (frac tokens to e) * (mean
-    # router prob of e) — minimized when both are uniform.
-    frac = jnp.mean(chosen, axis=0)
-    mean_prob = jnp.mean(probs, axis=0)
-    aux = E * jnp.sum(frac * mean_prob)
-    return dispatch, combine, aux
+    return dispatch, combine, _balance_loss(chosen, probs)
 
 
 def _moe_mlp(layer, x, cfg: MoEConfig, valid, h_attn=None):
     """The family's feed-forward block (decoder.py's `block` contract):
-    [B, S, d] → [B, S, d] through the routed expert FFN, and the
-    layer's aux loss. `valid` ([B, S] bool or None) masks tokens out of
-    routing (see _route); the router reads the block's own normalised
-    input, so `h_attn` goes unused."""
+    [B, S, d] → [B, S, d] through the routed expert FFN, the layer's
+    aux loss, and the experts the layer fetched where that is fewer
+    than all (else None). `valid` ([B, S] bool or None) masks tokens
+    out of routing (see _route); the router reads the block's own
+    normalised input, so `h_attn` goes unused.
+
+    A handful of rows of which none can be dropped (the capacity holds
+    them all, so the capacity dispatch and the plain sum over a row's
+    chosen experts are the same mathematics) go through
+    `experts_gathered`: the row count and the capacity are shapes."""
     b, s, d = x.shape
+    T = b * s
+    vflat = None if valid is None else valid.reshape(T)
     # Stage names as in models/decoder.py (one a stage, no layer index).
     with jax.named_scope("moe.route"):
         h = decoder.rms_norm(x, layer["ln2"], cfg.norm_eps,
-                     cfg.norm_plus_one).reshape(b * s, d)
-        vflat = None if valid is None else valid.reshape(b * s)
+                             cfg.norm_plus_one).reshape(T, d)
+    if T <= GATHERED_EXPERTS_MAX_ROWS and cfg.capacity(T) >= T:
+        with jax.named_scope("moe.route"):
+            probs, top_idx, top_w = _top_k_gates(layer, h, cfg)
+            chosen = jnp.sum(jax.nn.one_hot(top_idx, cfg.n_experts,
+                                            dtype=jnp.float32), axis=1)
+            if vflat is not None:
+                chosen = chosen * vflat.astype(jnp.float32)[:, None]
+            aux = _balance_loss(chosen, probs)
+        out, fetched = experts_gathered(layer, h, top_idx, top_w,
+                                        jax.nn.silu, vflat)
+        return out.reshape(b, s, d), aux, fetched
+    with jax.named_scope("moe.route"):
         dispatch, combine, aux = _route(layer, h, cfg, vflat)
     # Scatter to per-expert slots: ONE einsum, [E, C, d] activations.
     with jax.named_scope("moe.dispatch"):
@@ -170,7 +199,7 @@ def _moe_mlp(layer, x, cfg: MoEConfig, valid, h_attn=None):
         oe = jnp.einsum("ecf,efd->ecd", a, layer["e_down"])
     with jax.named_scope("moe.combine"):
         out = jnp.einsum("tec,ecd->td", combine.astype(oe.dtype), oe)
-    return out.reshape(b, s, d), aux
+    return out.reshape(b, s, d), aux, None
 
 _forward_stack, decode_step, verify_step = decoder.bind(_moe_mlp)
 
@@ -184,18 +213,29 @@ _forward_stack, decode_step, verify_step = decoder.bind(_moe_mlp)
 # sorted by expert and the experts run as ONE grouped matmul over the
 # sorted rows; every chosen pair is computed, whatever the router's
 # skew. Models/smallthinker.py binds it; this family keeps the dense
-# dispatch until its cell has been measured on the other (ROADMAP S4).
+# dispatch above a decode batch until its cell has been measured on the
+# other (ROADMAP S4, the prefill half).
 
-# With token x expert rows at or under this, every token runs through
-# every expert and the gates zero the ones it did not choose. Measured
-# on a v5e at 64 experts of 2560 x 768, 6 a token (PERF.md, PR 35; ms a
-# layer, dense / sorted): 16 tokens 1.32 / 1.25, 128 1.16 / 2.22, 256
-# 1.24 / 3.05, 1024 4.21 / 3.73. A decode step's batch and a hit's
-# short suffix read nearly every expert's weights anyway (16 tokens x
-# 6 of 64 touch 51 in expectation) and are bound by that read, which
-# the dense form does at 70 % of the HBM's rate; the sort, two gathers
-# and a grouped matmul over groups of a few rows cost more than the
-# rows they save until the rows are many.
+# Which form runs a block's experts, by the rows it holds. Measured on
+# a v5e (tools/time_moe_decode.py; PERF.md, PR 41; us a layer, 16 rows,
+# dense / sorted / gathered by the rows that hold a token): 64 experts
+# of 2,560 x 768, 6 a token: 1,003 / 2,175 / 110 at 1 row (6 experts
+# fetched), 203 at 2 (12), 342 at 4 (21), 805 at 16 (51 of 64); 64 of
+# 3,584 x 1,024, 4 a token, 8 rows: 1,919 / 1,952 / 133 at 1 (4), 824
+# at 8 (28); 8 of 4,096 x 14,336, 2 a token: 3,751 / 8,899 / 962 at 1
+# (2), 2,808 at 4 (6), 3,731 where all 8 are touched (the capacity
+# dispatch: 3,753). Every form is bound by the weights it reads, at
+# 79-92 % of the HBM's rate; the gathered kernel reads the experts some
+# valid row chose and is no slower than the dense form where that is
+# all of them, so up to a decode step's batch it always runs.
+GATHERED_EXPERTS_MAX_ROWS = 16
+# Above that, with token x expert rows at or under this, every token
+# runs through every expert and the gates zero the ones it did not
+# choose (PERF.md, PR 35; ms a layer at the first shape, dense /
+# sorted: 128 tokens 1.16 / 2.22, 256 1.24 / 3.05, 1024 4.21 / 3.73):
+# a hit's short suffix touches every expert anyway, and the sort, two
+# gathers and a grouped matmul over groups of a few rows cost more than
+# the rows they save until the rows are many.
 DENSE_EXPERTS_MAX_ROWS = 512 * 64
 
 
@@ -281,17 +321,34 @@ def experts_dense(layer, u, top_idx, gates, act):
         return jnp.einsum("tef,efd->td", a, layer["e_down"])
 
 
+def experts_gathered(layer, u, top_idx, gates, act, valid=None):
+    """The same sum by fetching only the experts some valid row chose
+    (ops/pallas_moe_decode.py), and how many those were: for the rows
+    of a decode step (GATHERED_EXPERTS_MAX_ROWS). valid: [T] bool or
+    None; a row that is not valid comes back zero."""
+    with jax.named_scope("moe.dispatch"):
+        dense, ids, n = pallas_moe_decode.live_experts(
+            top_idx, gates, valid, layer["e_gate"].shape[0])
+    with jax.named_scope("moe.experts"):
+        out = pallas_moe_decode.gathered_call(
+            u, layer["e_gate"], layer["e_up"], layer["e_down"], dense, ids,
+            n, act=act, interpret=jax.default_backend() != "tpu")
+    return out, n
+
+
 def sorted_moe_mlp(layer, x, cfg: MoEConfig, valid, h_attn=None,
                    early_router=False):
-    """A feed-forward block (decoder.py's `block` contract) over the
-    sorted dispatch. No capacity, so a row that holds no real token
-    takes nothing from one that does and `valid` is not needed. With
+    """A feed-forward block (decoder.py's `block` contract) without
+    capacity: a row that holds no real token takes nothing from one
+    that does, so `valid` ([b, s] bool or None) only keeps such a
+    row's experts from being fetched (`experts_gathered`). With
     `early_router` the router reads `h_attn`, the attention block's
-    normalised input, and not the block's own. Which of the two forms
-    runs is decided by the number of tokens, which is a shape. The
-    gate's activation is the config's (`cfg.act`), and so are the
+    normalised input, and not the block's own. Which of the three
+    forms runs is decided by the number of tokens, which is a shape.
+    The gate's activation is the config's (`cfg.act`), and so are the
     router's form (`cfg.router`) and the shared expert (`cfg.n_shared`).
-    No auxiliary loss (serving only)."""
+    No auxiliary loss (serving only); third, the experts the layer
+    fetched where that is fewer than all (else None)."""
     b, s, d = x.shape
     with jax.named_scope("moe.route"):
         u = decoder.rms_norm(x, layer["ln2"], cfg.norm_eps,
@@ -304,12 +361,18 @@ def sorted_moe_mlp(layer, x, cfg: MoEConfig, valid, h_attn=None,
         else:
             _, top_idx, gates = route_top_k(layer["router"], seen,
                                             cfg.top_k)
-    form = experts_dense \
-        if b * s * cfg.n_experts <= DENSE_EXPERTS_MAX_ROWS else experts_sorted
-    out = form(layer, u, top_idx, gates, _gate_act(cfg))
+    fetched = None
+    if b * s <= GATHERED_EXPERTS_MAX_ROWS:
+        out, fetched = experts_gathered(
+            layer, u, top_idx, gates, _gate_act(cfg),
+            None if valid is None else valid.reshape(b * s))
+    elif b * s * cfg.n_experts <= DENSE_EXPERTS_MAX_ROWS:
+        out = experts_dense(layer, u, top_idx, gates, _gate_act(cfg))
+    else:
+        out = experts_sorted(layer, u, top_idx, gates, _gate_act(cfg))
     if cfg.n_shared:
         out = out + shared_expert(layer, u, _gate_act(cfg))
-    return out.reshape(b, s, d), None
+    return out.reshape(b, s, d), None, fetched
 
 
 def _gate_act(cfg):

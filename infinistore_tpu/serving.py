@@ -523,9 +523,17 @@ def _admit_fused_px(params, cfg, tokens, restored, k_pages, v_pages,
     return logits[0, s_real - 1], k_pages, v_pages
 
 
-@partial(jax.jit, static_argnames=("cfg", "model"), donate_argnums=(4, 5))
+def _with_fetched(nxt, fetched):
+    """The array the host pulls after a step of a family with routed
+    experts: the next tokens and, last, the experts the step fetched
+    (one transfer for both)."""
+    return jnp.append(nxt, fetched)
+
+
+@partial(jax.jit, static_argnames=("cfg", "model", "fetched"),
+         donate_argnums=(4, 5))
 def _decode_fused(params, cfg, token, seq_lens, k_pages, v_pages, rows,
-                  model=llama):
+                  model=llama, fetched=False):
     """One fused device program per decode step: model forward + argmax
     + seq_lens advance, with the KV pools DONATED. Donation alone does
     not keep the pool in place: the step must also never slice a layer
@@ -538,13 +546,16 @@ def _decode_fused(params, cfg, token, seq_lens, k_pages, v_pages, rows,
     (4 bytes/slot) in the greedy steady state; `logits` stays
     device-resident unless a sampling slot needs it. Fusing also
     collapses ~6 host API calls per step into one dispatch + one tiny
-    D2H."""
-    logits, k_pages, v_pages = model.decode_step(
-        params, cfg, token, seq_lens, k_pages, v_pages, rows
+    D2H. With `fetched` (a family with routed experts) a sixth output
+    is what the host pulls in place of `nxt`: `_with_fetched`."""
+    logits, k_pages, v_pages, *n = model.decode_step(
+        params, cfg, token, seq_lens, k_pages, v_pages, rows,
+        fetched=fetched
     )
     nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
     # Live-rows-only advance — see _decode_scan's body comment.
-    return logits, nxt, seq_lens + (seq_lens > 0), k_pages, v_pages
+    out = (logits, nxt, seq_lens + (seq_lens > 0), k_pages, v_pages)
+    return out + (_with_fetched(nxt, n[0]),) if fetched else out
 
 
 # ---- the same programs for a family with state layers ------------------
@@ -810,22 +821,23 @@ def _admit_fused_px_wf(params, cfg, tokens, restored, k_pages, v_pages, wk,
     return logits[0, s_real - 1], k_pages, v_pages, wk, wv, sub
 
 
-@partial(jax.jit, static_argnames=("cfg", "model"),
+@partial(jax.jit, static_argnames=("cfg", "model", "fetched"),
          donate_argnums=(4, 5, 6, 7))
 def _decode_fused_wf(params, cfg, token, seq_lens, k_pages, v_pages, wk, wv,
-                     rows, model):
+                     rows, model, fetched=False):
     """`_decode_fused` for two kinds of attention layer. `rows`: (page
     table, the banded layers' short table [slots, entries], its base
     [slots]: the absolute position of each row's first entry). A banded
     layer's kernel call walks the short table alone: `band / page + 1`
     live entries a sequence however long the sequence is."""
     table, wtable, wbase = rows
-    logits, k_pages, v_pages, wk, wv = model.decode_step(
+    logits, k_pages, v_pages, wk, wv, *n = model.decode_step(
         params, cfg, token, seq_lens, k_pages, v_pages, table,
-        win=(wk, wv, wtable, wbase))
+        win=(wk, wv, wtable, wbase), fetched=fetched)
     nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-    return (logits, nxt, seq_lens + (seq_lens > 0), k_pages, v_pages, wk,
-            wv)
+    out = (logits, nxt, seq_lens + (seq_lens > 0), k_pages, v_pages, wk,
+           wv)
+    return out + (_with_fetched(nxt, n[0]),) if fetched else out
 
 
 # Trivial programs dispatched behind a one-shot admission's program
@@ -1089,6 +1101,10 @@ class ServingEngine:
             # active row's band, of all the entries of every row's
             # table (what a grid of one step an entry walked)
             "attn_pages_live": 0, "attn_pages_table": 0,
+            # routed experts whose weights the single decode steps
+            # fetched (models/moe.py:experts_gathered), of those their
+            # layers hold (layers x experts a step)
+            "moe_experts_fetched": 0, "moe_experts_held": 0,
             # offloads handed to the upload thread, times the engine
             # thread waited for room under UPLOAD_INFLIGHT_BYTES, and
             # what `done` waited for acknowledgements (a request's
@@ -1122,6 +1138,12 @@ class ServingEngine:
             layers * (self.wtable if pool == "window"
                       else self.page_table).shape[1]
             for (pool, _), layers in self._attn_kinds.items())
+        # routed experts the layers hold (0: a family without any);
+        # where there are some, a decode program also says how many it
+        # fetched
+        self._experts_held = sum(
+            layer["e_gate"].shape[0] for layer in params.get("layers", ())
+            if "e_gate" in layer)
         self.engine_id = profiling.next_engine_id()
         self._own_digests = {}  # insertion-ordered, at most OWN_DIGESTS
         # One sequence page over every layer and kind the page pools
@@ -2948,22 +2970,24 @@ class ServingEngine:
             )
             return len(active)
 
+        sparse = self._experts_held > 0
+        pulled = ()  # in place of nxt_dev, where the step counts experts
         with self._span("istpu.model.decode", program="decode_fused",
                         live_pages=live_pages) as df:
             if self._win_layers:
                 (logits, nxt_dev, lens_next, self.k_pages, self.v_pages,
-                 self.wk_pages, self.wv_pages) = _decode_fused_wf(
+                 self.wk_pages, self.wv_pages, *pulled) = _decode_fused_wf(
                     self.params, self.cfg, token_dev, lens_dev,
                     self.k_pages, self.v_pages, self.wk_pages,
                     self.wv_pages, rows_dev, model=self.model,
+                    fetched=sparse,
                 )
             elif self.state is None:
-                logits, nxt_dev, lens_next, self.k_pages, self.v_pages = (
-                    _decode_fused(
-                        self.params, self.cfg, token_dev, lens_dev,
-                        self.k_pages, self.v_pages, rows_dev,
-                        model=self.model,
-                    )
+                (logits, nxt_dev, lens_next, self.k_pages, self.v_pages,
+                 *pulled) = _decode_fused(
+                    self.params, self.cfg, token_dev, lens_dev,
+                    self.k_pages, self.v_pages, rows_dev,
+                    model=self.model, fetched=sparse,
                 )
             else:
                 (logits, nxt_dev, lens_next, self.k_pages, self.v_pages,
@@ -2975,7 +2999,11 @@ class ServingEngine:
                 self._copy_boundaries(active)
             # Dispatched; what is left of the span is the wait.
             df["dispatch_ns"] = profiling.elapsed_ns()
-            nxt = np.asarray(nxt_dev)
+            nxt = np.asarray(pulled[0] if pulled else nxt_dev)
+            if pulled:
+                df["experts_fetched"] = int(nxt[-1])
+                self.stats["moe_experts_fetched"] += int(nxt[-1])
+                self.stats["moe_experts_held"] += self._experts_held
         # Reusable next step iff every emitted token is the device's
         # argmax (greedy) — samplers/spec/finishes invalidate via key.
         self._steady = (

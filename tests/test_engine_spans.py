@@ -1065,11 +1065,13 @@ def test_every_stage_is_named_in_the_step_programs(model):
         params, cfg, jnp.zeros(2, jnp.int32), jnp.ones(2, jnp.int32),
         pool, pool, jnp.zeros((2, 4), jnp.int32)).as_text(debug_info=True)
     prefill = jax.jit(model.prefill, static_argnums=1).lower(
-        params, cfg, jnp.zeros((1, 2 * PAGE), jnp.int32)
+        params, cfg, jnp.zeros((1, 3 * PAGE), jnp.int32)  # > a decode batch
     ).as_text(debug_info=True)
     found = set(re.findall(r"/((?:attn|moe|pool)\.\w+|embed|mlp|lm_head)/",
                            decode))
-    assert found == STAGES[model]
+    # a decode step's experts are one kernel under `moe.experts`, the
+    # gates' sum inside it
+    assert found == STAGES[model] - {"moe.combine"}
     found = set(re.findall(r"/((?:attn|moe|pool)\.\w+|embed|mlp|lm_head)/",
                            prefill))
     assert found == STAGES[model] - {"pool.update"}

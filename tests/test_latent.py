@@ -56,8 +56,9 @@ MARGIN = 1e-3
 @pytest.fixture(autouse=True)
 def sorted_dispatch_above_a_decode_batch(monkeypatch):
     """As tests/test_window_full.py: prefills run the sorted dispatch,
-    decode steps the dense one, as at the published widths."""
-    monkeypatch.setattr(moe, "DENSE_EXPERTS_MAX_ROWS", 16 * 8)
+    a suffix of 17-24 tokens the dense form, decode steps the gathered
+    kernel, as at the published widths."""
+    monkeypatch.setattr(moe, "DENSE_EXPERTS_MAX_ROWS", 24 * 8)
 
 
 @pytest.fixture(scope="module")
@@ -246,16 +247,23 @@ def test_sigmoid_router_by_hand():
 def test_shared_expert_is_counted_once(cfg, params):
     layer = params["layers"][1]
     x = jax.random.normal(jax.random.PRNGKey(5), (1, 24, 64))
-    both, _ = moe.sorted_moe_mlp(layer, x, cfg, None)
-    routed, _ = moe.sorted_moe_mlp(layer, x, dataclasses.replace(
+    both, *_ = moe.sorted_moe_mlp(layer, x, cfg, None)         # dense form
+    routed, *_ = moe.sorted_moe_mlp(layer, x, dataclasses.replace(
         cfg, n_shared=0), None)
     u = decoder.rms_norm(x, layer["ln2"], cfg.norm_eps).reshape(24, 64)
     shared = moe.shared_expert(layer, u, jax.nn.silu).reshape(1, 24, 64)
     assert np.abs(np.asarray(both - routed - shared)).max() < 1e-5
     assert np.abs(np.asarray(shared)).max() > 1e-2
-    # the two forms of the dispatch agree with the shared expert on
-    few, _ = moe.sorted_moe_mlp(layer, x[:, :2], cfg, None)   # dense form
+    # the forms of the dispatch agree with the shared expert on: the
+    # gathered kernel over two rows (which fetches their 2 x top_k
+    # experts at most, and never counts the shared one), the sorted
+    # dispatch over 25
+    few, _, fetched = moe.sorted_moe_mlp(layer, x[:, :2], cfg, None)
     assert np.abs(np.asarray(few - both[:, :2])).max() < 1e-5
+    assert cfg.top_k <= int(fetched) <= 2 * cfg.top_k
+    more = jnp.concatenate([x, x[:, :1]], axis=1)
+    many, *_ = moe.sorted_moe_mlp(layer, more, cfg, None)
+    assert np.abs(np.asarray(many[:, :24] - both)).max() < 1e-5
 
 
 # -- the decode kernel and the two attention paths ---------------------------
@@ -316,6 +324,12 @@ def test_cold_admission_and_decode_match_the_reference(cfg, params):
     assert len(out) == 24
     assert _worst(eng, params, "a", prompt, out) < TOL
     assert eng.stats["decode_steps"] == 23 and eng.stats["admit_pieces"] == 0
+    # the leading layer is dense; an expert layer fetches the one
+    # row's top_k of its experts (the shared expert is not counted)
+    routed = sum("e_gate" in layer for layer in params["layers"])
+    assert 0 < routed < cfg.n_layers
+    assert eng.stats["moe_experts_fetched"] == 23 * routed * cfg.top_k
+    assert eng.stats["moe_experts_held"] == 23 * routed * cfg.n_experts
 
 
 def test_admission_in_pieces_is_the_admission_in_one(cfg, params):

@@ -685,10 +685,14 @@ def _decode_program(family):
         wpool = sds((n_win, slots * 264 + 1, *page), cfg.jdtype)
         return serving._decode_fused_wf.lower(
             params, cfg, lens, lens, pool, pool, wpool, wpool,
-            (rows, sds((slots, 264), i32), lens), model=model)
+            (rows, sds((slots, 264), i32), lens), model=model, fetched=True)
 
     kind = total * int(np.prod(page)) * cfg.jdtype.itemsize
-    return lower, 2 * (n_full + n_win) * kind, kind, n_full + n_win
+    # a paged-decode kernel a layer and, over routed experts, the
+    # gathered expert kernel (ops/pallas_moe_decode.py: one function,
+    # called by every layer)
+    kernels = n_full + n_win + (family == "smallthinker")
+    return lower, 2 * (n_full + n_win) * kind, kind, kernels
 
 
 @pytest.mark.parametrize("family", ["llama", "hybrid", "smallthinker"])

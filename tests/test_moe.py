@@ -232,3 +232,218 @@ def test_moe_multiturn_prefix_hit_through_store(serve_params, serve_cfg,
         [Request("x", turn2, max_new_tokens=6)]
     )
     assert out2["t2"] == ref["x"]
+
+
+# ---- the gathered expert kernel (ops/pallas_moe_decode.py) --------------
+# One call over a decode step's rows that fetches only the experts some
+# valid row chose; on the CPU in interpret mode, the same code the chip
+# compiles.
+
+# name: (experts, a token, d, f, rows): the three sparse families' forms
+# at tiny widths (f of 256 over 128-wide tiles where the budget is cut)
+FAMILY_SHAPES = {
+    "mixtral8x7b": (8, 2, 64, 256, 16),
+    "smallthinker21b": (64, 6, 32, 48, 16),
+    "xing4-29b": (64, 4, 32, 128, 8),
+}
+
+
+def _experts(E, d, f, T, k, dtype, seed=0):
+    ks = jax.random.split(jax.random.PRNGKey(seed + E * 1000 + T), 5)
+    layer = {
+        "e_gate": (jax.random.normal(ks[0], (E, d, f)) * d ** -0.5
+                   ).astype(dtype),
+        "e_up": (jax.random.normal(ks[1], (E, d, f)) * d ** -0.5
+                 ).astype(dtype),
+        "e_down": (jax.random.normal(ks[2], (E, f, d)) * f ** -0.5
+                   ).astype(dtype)}
+    u = jax.random.normal(ks[3], (T, d)).astype(dtype)
+    router = jax.random.normal(ks[4], (d, E))
+    _, top_idx, gates = moe.route_top_k(router, u, k)
+    return layer, u, top_idx, gates
+
+
+def _gathered(layer, u, top_idx, gates, valid, act):
+    from infinistore_tpu.ops import pallas_moe_decode
+
+    return pallas_moe_decode.gathered_experts(
+        u, layer["e_gate"], layer["e_up"], layer["e_down"], top_idx, gates,
+        valid, act, interpret=True)
+
+
+@pytest.mark.parametrize("act", [jax.nn.silu, jax.nn.relu],
+                         ids=["silu", "relu"])
+@pytest.mark.parametrize("dtype,tol", [("float32", 1e-5), ("bfloat16", 2e-2)])
+@pytest.mark.parametrize("family", list(FAMILY_SHAPES))
+def test_gathered_experts_equal_the_dense_form(family, dtype, tol, act,
+                                               monkeypatch):
+    """`gathered_experts` against `experts_dense` over float32 copies
+    of the same weights and rows, to the rows' own precision; the f
+    axis in several tiles where the block budget is small."""
+    from infinistore_tpu.ops import pallas_moe_decode
+
+    E, k, d, f, T = FAMILY_SHAPES[family]
+    layer, u, top_idx, gates = _experts(E, d, f, T, k, dtype)
+    monkeypatch.setattr(pallas_moe_decode, "_WEIGHT_BLOCK_BYTES",
+                        3 * d * 128 * jnp.dtype(dtype).itemsize)
+    assert pallas_moe_decode._f_tile(d, f, jnp.dtype(dtype).itemsize) == (
+        128 if f % 128 == 0 else f)
+    got = _gathered(layer, u, top_idx, gates, None, act)
+    assert got.dtype == u.dtype and got.shape == u.shape
+    f32 = jax.tree_util.tree_map(lambda a: a.astype(jnp.float32), layer)
+    want = moe.experts_dense(f32, u.astype(jnp.float32), top_idx, gates, act)
+    err = np.abs(np.asarray(got, np.float32) - np.asarray(want)).max()
+    assert err < tol * max(1.0, float(jnp.abs(want).max())), err
+
+
+def test_rows_that_are_not_valid_fetch_nothing():
+    """16 rows of which 2 hold a token, 2 experts a row: at most 4
+    experts are fetched, the ids are those rows' own, and a row that is
+    not valid comes back zero."""
+    from infinistore_tpu.ops import pallas_moe_decode
+
+    E, k, d, f, T = FAMILY_SHAPES["mixtral8x7b"]
+    layer, u, top_idx, gates = _experts(E, d, f, T, k, "float32")
+    valid = jnp.arange(T) < 2
+    dense, ids, n = pallas_moe_decode.live_experts(top_idx, gates, valid, E)
+    chosen = sorted(set(np.asarray(top_idx[:2]).ravel()))
+    assert int(n) == len(chosen) <= 4
+    assert list(np.asarray(ids[:int(n)])) == chosen
+    assert set(np.asarray(ids[int(n):])) <= {chosen[-1]}  # the last, again
+    assert ids.shape == (min(E, T * k),)
+    assert not np.asarray(dense[2:]).any()
+    got = _gathered(layer, u, top_idx, gates, valid, jax.nn.silu)
+    want = moe.experts_dense(layer, u, top_idx, gates, jax.nn.silu)
+    assert np.abs(np.asarray(got[:2] - want[:2])).max() < 1e-5
+    assert not np.asarray(got[2:]).any()
+    # no row valid: nothing fetched, nothing computed
+    none = jnp.zeros(T, bool)
+    assert int(pallas_moe_decode.live_experts(top_idx, gates, none, E)[2]) == 0
+    assert not np.asarray(
+        _gathered(layer, u, top_idx, gates, none, jax.nn.silu)).any()
+
+
+@pytest.mark.parametrize("case", ["duplicates", "every_expert", "one_row"])
+def test_gathered_experts_by_what_the_rows_chose(case):
+    """Every row the same experts: n = k. Every expert chosen by some
+    row: n = E. One row alone: n = k."""
+    from infinistore_tpu.ops import pallas_moe_decode
+
+    E, k, d, f = 8, 2, 32, 16
+    T = 1 if case == "one_row" else 16
+    layer, u, top_idx, gates = _experts(E, d, f, T, k, "float32", seed=7)
+    if case == "duplicates":
+        top_idx = jnp.broadcast_to(jnp.asarray([5, 1], jnp.int32), (T, k))
+    if case == "every_expert":
+        top_idx = (jnp.arange(T * k, dtype=jnp.int32) % E).reshape(T, k)
+    n = int(pallas_moe_decode.live_experts(top_idx, gates, None, E)[2])
+    assert n == {"duplicates": k, "every_expert": E, "one_row": k}[case]
+    got = _gathered(layer, u, top_idx, gates, None, jax.nn.relu)
+    want = moe.experts_dense(layer, u, top_idx, gates, jax.nn.relu)
+    assert np.abs(np.asarray(got - want)).max() < 1e-5 * max(
+        1.0, float(jnp.abs(want).max()))
+
+
+def _capacity_form(layer, x, cfg, valid, monkeypatch):
+    with monkeypatch.context() as m:
+        m.setattr(moe, "GATHERED_EXPERTS_MAX_ROWS", 0)
+        return moe._moe_mlp(layer, x, cfg, valid)
+
+
+def test_moe_block_gathers_where_no_row_can_be_dropped(monkeypatch):
+    """`_moe_mlp` over a decode batch: with a capacity that holds every
+    row (the bridge's capacity_factor E / top_k) it takes the gathered
+    kernel and equals its own capacity dispatch, aux loss and all, with
+    rows that are not valid kept out; at capacity_factor 1.0, where a
+    row can be dropped, it does not take it."""
+    seen = []
+    real = moe.experts_gathered
+    monkeypatch.setattr(moe, "experts_gathered",
+                        lambda *a: (seen.append(1), real(*a))[1])
+    x = jax.random.normal(jax.random.PRNGKey(4), (16, 1, 32))
+    valid = (jnp.arange(16) % 3 != 0)[:, None]
+    for factor, gathers in ((4.0, True), (1.0, False)):
+        cfg = tiny_cfg(n_experts=8, capacity_factor=factor)
+        assert (cfg.capacity(16) >= 16) == gathers
+        layer = moe.init_params(jax.random.PRNGKey(5), cfg)["layers"][0]
+        for v in (None, valid):
+            del seen[:]
+            out, aux, fetched = moe._moe_mlp(layer, x, cfg, v)
+            assert bool(seen) == gathers == (fetched is not None)
+            want, want_aux, none = _capacity_form(layer, x, cfg, v,
+                                                  monkeypatch)
+            assert none is None
+            np.testing.assert_allclose(float(aux), float(want_aux),
+                                       rtol=1e-6)
+            if gathers:
+                assert np.abs(np.asarray(out - want)).max() < 1e-5
+                assert 2 <= int(fetched) <= 8
+    # above a decode batch the capacity dispatch stays, whatever it holds
+    many = jnp.ones((17, 1, 32))
+    del seen[:]
+    assert moe._moe_mlp(layer, many, tiny_cfg(
+        n_experts=8, capacity_factor=4.0), None)[2] is None and not seen
+
+
+def test_decode_step_counts_the_experts_it_fetched(monkeypatch):
+    """`decode_step(..., fetched=True)` returns, last, the experts its
+    layers fetched: the host's own count of the distinct experts the
+    valid rows chose, layer by layer, on a seeded step (run eagerly, so
+    the routing can be read)."""
+    cfg = tiny_cfg(n_experts=8, capacity_factor=4.0)
+    params = moe.init_params(jax.random.PRNGKey(11), cfg)
+    slots, pages = 6, 4
+    shape = (cfg.n_layers, slots * pages + 1, *cfg.kv_page_shape())
+    kp = jax.random.normal(jax.random.PRNGKey(12), shape) * 0.1
+    table = 1 + jnp.arange(slots * pages, dtype=jnp.int32).reshape(
+        slots, pages)
+    seq_lens = jnp.asarray([5, 0, 9, 0, 0, 0], jnp.int32)  # 2 rows hold
+    token = jnp.asarray([3, 1, 4, 1, 5, 9], jnp.int32)
+    # without the flag the step returns what it always did
+    assert len(moe.decode_step(params, cfg, token, seq_lens, kp, kp,
+                               table)) == 3
+    routed = []
+    real = moe.experts_gathered
+
+    def spy(layer, u, top_idx, gates, act, valid):
+        routed.append(len(set(np.asarray(top_idx)[np.asarray(valid)].ravel())))
+        return real(layer, u, top_idx, gates, act, valid)
+
+    monkeypatch.setattr(moe, "experts_gathered", spy)
+    with jax.disable_jit():
+        *_, fetched = moe.decode_step(params, cfg, token, seq_lens, kp, kp,
+                                      table, fetched=True)
+    assert len(routed) == cfg.n_layers
+    assert int(fetched) == sum(routed) <= 2 * cfg.top_k * cfg.n_layers
+
+
+def test_engine_counts_experts_fetched_and_held(serve_params, serve_cfg):
+    """The count rides in the array the host pulls for the tokens:
+    `stats["moe_experts_fetched"]` of `moe_experts_held` (layers x
+    experts a single decode step), and `experts_fetched` on the step's
+    `istpu.model.decode` span. One request: each layer fetches its
+    row's top_k experts."""
+    from infinistore_tpu.serving import Request, ServingEngine
+
+    eng = ServingEngine(serve_params, serve_cfg, model=moe)
+    eng.run([Request("c", [3, 7, 3, 9, 2], max_new_tokens=6)])
+    steps = eng.stats["decode_steps"]
+    held = serve_cfg.n_layers * serve_cfg.n_experts
+    assert eng.stats["moe_experts_held"] == steps * held > 0
+    assert eng.stats["moe_experts_fetched"] == (
+        steps * serve_cfg.n_layers * serve_cfg.top_k)
+    spans = [s for s in profiling.spans()
+             if s.name == "istpu.model.decode" and s.engine == eng.engine_id]
+    assert sum(s.fields["experts_fetched"] for s in spans) == (
+        eng.stats["moe_experts_fetched"])
+    # a family without routed experts counts nothing and pulls tokens alone
+    from infinistore_tpu.models import llama
+
+    lcfg = llama.LlamaConfig()
+    dense = ServingEngine(llama.init_params(jax.random.PRNGKey(0), lcfg),
+                          lcfg)
+    dense.run([Request("d", [3, 7, 3], max_new_tokens=3)])
+    assert dense.stats["moe_experts_held"] == 0
+    assert all("experts_fetched" not in s.fields for s in profiling.spans()
+               if s.name == "istpu.model.decode"
+               and s.engine == dense.engine_id)

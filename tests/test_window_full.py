@@ -55,10 +55,11 @@ MARGIN = 1e-3
 @pytest.fixture(autouse=True)
 def sorted_dispatch_above_a_decode_batch(monkeypatch):
     """At this preset's 8 experts the measured threshold would send
-    every prompt here through the dense form; with it at 16 tokens the
-    prefills run the sorted dispatch and the decode steps the dense
-    one, as at the published widths."""
-    monkeypatch.setattr(moe, "DENSE_EXPERTS_MAX_ROWS", 16 * 8)
+    every prompt here through the dense form; with it at 24 tokens the
+    prefills run the sorted dispatch, a suffix of 17-24 tokens the
+    dense form and the decode steps the gathered kernel, as at the
+    published widths."""
+    monkeypatch.setattr(moe, "DENSE_EXPERTS_MAX_ROWS", 24 * 8)
 
 
 @pytest.fixture(scope="module")
@@ -228,18 +229,21 @@ def test_sorted_dispatch_equals_the_per_expert_loop(E, k, T, skewed):
 
 def test_the_token_count_chooses_the_form(cfg, params, monkeypatch):
     seen = []
-    for name in ("experts_dense", "experts_sorted"):
+    for name in ("experts_gathered", "experts_dense", "experts_sorted"):
         form = getattr(moe, name)
         monkeypatch.setattr(moe, name, lambda *a, _f=form, _n=name: (
             seen.append(_n), _f(*a))[1])
     layer = params["layers"][0]
-    most = moe.DENSE_EXPERTS_MAX_ROWS // cfg.n_experts
-    assert most == 16
-    few = jnp.ones((1, most, cfg.d_model))
-    many = jnp.ones((1, most + 8, cfg.d_model))
-    moe.sorted_moe_mlp(layer, few, cfg, None, few, early_router=True)
-    moe.sorted_moe_mlp(layer, many, cfg, None, many, early_router=True)
-    assert seen == ["experts_dense", "experts_sorted"]
+    few_rows = moe.GATHERED_EXPERTS_MAX_ROWS
+    assert few_rows == 16 and moe.DENSE_EXPERTS_MAX_ROWS == 24 * cfg.n_experts
+    for rows in (few_rows, few_rows + 1, 24, 25):
+        x = jnp.ones((1, rows, cfg.d_model))
+        out, aux, fetched = moe.sorted_moe_mlp(layer, x, cfg, None, x,
+                                               early_router=True)
+        assert aux is None and (fetched is None) == (rows > few_rows)
+    assert seen == [
+        "experts_gathered", "experts_dense", "experts_dense",
+        "experts_sorted"]
 
 
 # -- the engine: two kinds of page -------------------------------------------
@@ -575,3 +579,10 @@ def test_spans_and_counters_of_two_kinds(cfg, params, shm_conn):
     assert eng.stats["attn_pages_live"] == sum(live(i) for i in range(69))
     assert eng.stats["attn_pages_table"] == 69 * eng.sc.max_slots * (
         L_FULL * eng.sc.max_pages_per_seq + L_WIN * eng.wtable.shape[1])
+    # What the expert kernel fetches: one row holds a token, so every
+    # layer fetches that row's top_k of the experts it holds.
+    layers = L_FULL + L_WIN
+    assert [d.fields["experts_fetched"] for d in decodes] == \
+        [layers * cfg.top_k] * 69
+    assert eng.stats["moe_experts_fetched"] == 69 * layers * cfg.top_k
+    assert eng.stats["moe_experts_held"] == 69 * layers * cfg.n_experts

@@ -35,10 +35,14 @@ def family(name, model, cfg, sc=None):
     ids = jnp.asarray(eng._pad_ids([1, 2]))
     slots = jnp.zeros((2,), i32); rows = jnp.zeros((2, 8), i32)
     L = eng.k_pages.shape[0]
+    # the decode program as the engine calls it: a family with routed
+    # experts also returns what it fetched (PR 41; a tree before that
+    # has neither the attribute nor the argument)
+    counts = {"fetched": True} if getattr(eng, "_experts_held", 0) else {}
     if eng._win_layers:
         fn = lambda p, t, k, v, wk, wv, i, wi, s: serving._admit_fused_wf.__wrapped__(p, cfg, t, k, v, wk, wv, i, wi, s, model, 0)
         out[name + ".cold"] = h(fn, params, toks, eng.k_pages, eng.v_pages, eng.wk_pages, eng.wv_pages, ids, jnp.asarray(np.full(eng._wtable_w, eng._wpool_pages, np.int32))[:8], jnp.int32(30))
-        fn = lambda p, t, s, k, v, wk, wv, r: serving._decode_fused_wf.__wrapped__(p, cfg, t, s, k, v, wk, wv, r, model)
+        fn = lambda p, t, s, k, v, wk, wv, r: serving._decode_fused_wf.__wrapped__(p, cfg, t, s, k, v, wk, wv, r, model, **counts)
         wrows = (rows, jnp.zeros((2, eng._wtable_w), i32), slots)
         out[name + ".decode"] = h(fn, params, slots, slots, eng.k_pages, eng.v_pages, eng.wk_pages, eng.wv_pages, wrows)
         return
@@ -53,7 +57,7 @@ def family(name, model, cfg, sc=None):
     restored = jnp.zeros((2 * L * 2, *cfg.kv_page_shape()), cfg.jdtype)
     fn = lambda p, t, r, k, v, ri, si, s, p0: serving._admit_fused_px.__wrapped__(p, cfg, t, r, k, v, ri, si, s, p0, model)
     out[name + ".prefix"] = h(fn, params, toks, restored, eng.k_pages, eng.v_pages, jnp.asarray([1, 2], i32), ids, jnp.int32(30), jnp.int32(0))
-    fn = lambda p, t, s, k, v, r: serving._decode_fused.__wrapped__(p, cfg, t, s, k, v, r, model)
+    fn = lambda p, t, s, k, v, r: serving._decode_fused.__wrapped__(p, cfg, t, s, k, v, r, model, **counts)
     out[name + ".decode"] = h(fn, params, slots, slots, eng.k_pages, eng.v_pages, rows)
     fn = lambda k, v, i: serving._gather_pages.__wrapped__(k, v, i)
     out[name + ".gather"] = h(fn, eng.k_pages, eng.v_pages, jnp.asarray([1, 2], i32))
