@@ -15,7 +15,12 @@ to the block's output [b, s, d] and the family's auxiliary loss for that
 layer (None where it has none); a block over routed experts returns a
 third: the experts this call fetched, an int32 scalar, where it fetched
 fewer than it holds, else None (`decode_step` sums them for the
-engine's counter). `valid` ([b, s] bool or None) marks the
+engine's counter); a block whose layer holds a SHARE of the experts its
+router scores returns a fourth, its counts: "pairs_held" [b, s] int32,
+per row the pairs (row, chosen expert) that fell on an expert held
+here, zero for a row that is not valid, and "rows", the rows its
+matmuls over a prompt ran (the loops sum both over the layers).
+`valid` ([b, s] bool or None) marks the
 rows that hold a real token: a block whose tokens compete for something
 (MoE expert capacity) keeps the others out, a block that treats tokens
 independently ignores it. `h_attn` is the normalised input the layer's
@@ -52,6 +57,25 @@ def rms_norm(x, w, eps=1e-5, plus_one=False):
     var = jnp.mean(jnp.square(x.astype(jnp.float32)), axis=-1, keepdims=True)
     xn = x * jax.lax.rsqrt(var + eps).astype(x.dtype)
     return xn * (1.0 + w) if plus_one else xn * w
+
+
+def layer_norm(x, w, eps=1e-5):
+    """The mean-centred norm (Cohere's LayerNorm): (x - mean) over the
+    standard deviation, a weight and no bias, statistics and product
+    in float32."""
+    xf = x.astype(jnp.float32)
+    xc = xf - jnp.mean(xf, axis=-1, keepdims=True)
+    var = jnp.mean(jnp.square(xc), axis=-1, keepdims=True)
+    return (xc * jax.lax.rsqrt(var + eps)
+            * w.astype(jnp.float32)).astype(x.dtype)
+
+
+def norm(cfg, x, w):
+    """The family's norm of a layer's input and of the stack's output:
+    `rms_norm`, or `layer_norm` where `cfg.norm_center`."""
+    if cfg.norm_center:
+        return layer_norm(x, w, cfg.norm_eps)
+    return rms_norm(x, w, cfg.norm_eps, cfg.norm_plus_one)
 
 
 def _llama3_scale_freqs(freqs, scaling):
@@ -101,9 +125,11 @@ def _yarn_scale_freqs(freqs, theta, yarn):
                                                             mscale_all)
 
 
-def rope(x, positions, theta, scaling=(), yarn=()):
+def rope(x, positions, theta, scaling=(), yarn=(), adjacent=False):
     """x: [..., seq, heads, hd]; positions broadcastable to [..., seq].
-    `scaling`: the llama3 rescale; `yarn`: the YaRN one."""
+    `scaling`: the llama3 rescale; `yarn`: the YaRN one. A frequency
+    turns the pair of lanes (i, i + hd / 2), or with `adjacent`
+    (GPT-J's form, Cohere's) the pair (2 i, 2 i + 1)."""
     hd = x.shape[-1]
     half = hd // 2
     freqs = jnp.exp(
@@ -121,6 +147,17 @@ def rope(x, positions, theta, scaling=(), yarn=()):
         return t[..., None, :].astype(x.dtype)
 
     cos, sin = trig(jnp.cos), trig(jnp.sin)
+    if adjacent:
+        # x cos + (the pair's other lane, signed) sin, every lane where
+        # it lies: lane 2 i takes -x[2 i + 1], lane 2 i + 1 takes
+        # x[2 i]. Two rolls along the lanes and a select; slicing the
+        # even and the odd lanes apart compiles to gathers that put the
+        # head's lanes on a major dimension.
+        cos, sin = jnp.repeat(cos, 2, axis=-1), jnp.repeat(sin, 2, axis=-1)
+        even = jnp.arange(hd) % 2 == 0
+        other = jnp.where(even, -jnp.roll(x, -1, axis=-1),
+                          jnp.roll(x, 1, axis=-1))
+        return x * cos + other * sin
     x1, x2 = x[..., :half], x[..., half:]
     return jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1)
 
@@ -174,7 +211,7 @@ def _qkv(layer, x, cfg, positions, rotate=None):
     b = x.shape[0]
     s = x.shape[1]
     with jax.named_scope("attn.qkv"):
-        h = rms_norm(x, layer["ln1"], cfg.norm_eps, cfg.norm_plus_one)
+        h = norm(cfg, x, layer["ln1"])
         q = proj(h, layer, "wq", "bq", (b, s, cfg.n_heads, cfg.head_dim))
         k = proj(h, layer, "wk", "bk", (b, s, cfg.n_kv_heads, cfg.head_dim))
         v = proj(h, layer, "wv", "bv", (b, s, cfg.n_kv_heads, cfg.head_dim))
@@ -185,8 +222,10 @@ def _qkv(layer, x, cfg, positions, rotate=None):
                                 q.dtype)
     if cfg.use_rope if rotate is None else rotate:
         with jax.named_scope("attn.rope"):
-            q = rope(q, positions, cfg.rope_theta, cfg.rope_scaling)
-            k = rope(k, positions, cfg.rope_theta, cfg.rope_scaling)
+            q = rope(q, positions, cfg.rope_theta, cfg.rope_scaling,
+                     adjacent=cfg.rope_adjacent)
+            k = rope(k, positions, cfg.rope_theta, cfg.rope_scaling,
+                     adjacent=cfg.rope_adjacent)
     return q, k, v, h
 
 
@@ -594,7 +633,9 @@ def forward_stack(block, params, cfg, tokens, prefix_kvs=None, pos0=0,
     fp32, per attention layer (k, v) [batch, seq, n_kv, hd] — the KV to
     page out to the store — and the per-layer list of what `block`
     returned as its auxiliary loss). A family with state layers gets a
-    fourth element: per state layer what `ssm_mixer_seq` returned.
+    fourth element: per state layer what `ssm_mixer_seq` returned; one
+    whose layers hold a share of their experts a last: the blocks'
+    counts (the `block` contract's fourth), summed over the layers.
 
     `pos0` shifts every ABSOLUTE rope position (prefix starts at pos0,
     suffix at pos0 + P): a sliding-window engine trims the restored
@@ -620,6 +661,7 @@ def forward_stack(block, params, cfg, tokens, prefix_kvs=None, pos0=0,
     kvs = []
     auxes = []
     states = []
+    held = []
     for layer, kind in zip(params["layers"], cfg.layer_kinds):
         h_attn = None
         x_in, mix = stream_in(cfg, layer, x, "attn")
@@ -658,15 +700,17 @@ def forward_stack(block, params, cfg, tokens, prefix_kvs=None, pos0=0,
                          mix)
             kvs.append((k, v))
         x_in, mix = stream_in(cfg, layer, x, "ffn")
-        out, aux, *_ = block(layer, x_in, cfg, None, h_attn)
+        out, aux, *more = block(layer, x_in, cfg, None, h_attn)
+        held += more[1:]
         x = residual(cfg, x, out, mix)
         auxes.append(aux)
-    x = rms_norm(stream_close(cfg, x), params["final_ln"], cfg.norm_eps,
-                 cfg.norm_plus_one)
-    logits = lm_head(params, x, cfg)
+    x = norm(cfg, stream_close(cfg, x), params["final_ln"])
+    out = (lm_head(params, x, cfg), kvs, auxes)
     if states:
-        return logits, kvs, auxes, states
-    return logits, kvs, auxes
+        out += (states,)
+    if held:
+        out += ({k: sum(c[k] for c in held) for k in held[0]},)
+    return out
 
 
 def decode_step(block, params, cfg, token, seq_lens, k_pages, v_pages,
@@ -689,7 +733,9 @@ def decode_step(block, params, cfg, token, seq_lens, k_pages, v_pages,
                 layers alone.
     fetched:    also return, last, the experts this step's blocks
                 fetched, summed over the layers (int32; 0 where no
-                block reports any).
+                block reports any); where the layers hold a share of
+                their experts, int32 [2]: that, and the valid rows'
+                pairs that fell on experts held here.
 
     Returns (logits [batch, vocab] fp32, k_pages, v_pages): the pools
     it was given with, per attention layer, the new token's K and V
@@ -729,7 +775,7 @@ def decode_step(block, params, cfg, token, seq_lens, k_pages, v_pages,
 
     spec = attn_layers(cfg)
     li = mi = 0  # rank among the attention / the state layers
-    hs, convs, experts = [], [], []
+    hs, convs, experts, pairs = [], [], [], []
     for layer, kind in zip(params["layers"], cfg.layer_kinds):
         h_attn = None
         x_in, mix = stream_in(cfg, layer, x, "attn")
@@ -775,10 +821,10 @@ def decode_step(block, params, cfg, token, seq_lens, k_pages, v_pages,
             li += 1
         x_in, mix = stream_in(cfg, layer, x, "ffn")
         out, _aux, *n = block(layer, x_in, cfg, valid, h_attn)
-        experts += [c for c in n if c is not None]
+        experts += [c for c in n[:1] if c is not None]
+        pairs += [jnp.sum(c["pairs_held"]) for c in n[1:]]
         x = residual(cfg, x, out, mix)
-    x = rms_norm(stream_close(cfg, x), params["final_ln"], cfg.norm_eps,
-                 cfg.norm_plus_one)
+    x = norm(cfg, stream_close(cfg, x), params["final_ln"])
     logits = lm_head(params, x[:, 0], cfg)
     out = (logits, *pools["full"][:2])
     if state is not None:
@@ -786,7 +832,8 @@ def decode_step(block, params, cfg, token, seq_lens, k_pages, v_pages,
     if win is not None:
         out += tuple(pools["window"][:2])
     if fetched:
-        out += (sum(experts, jnp.int32(0)),)
+        count = sum(experts, jnp.int32(0))
+        out += (jnp.stack([count, sum(pairs)]) if pairs else count,)
     return out
 
 
@@ -869,7 +916,7 @@ def verify_step(block, params, cfg, tokens, seq_lens, k_pages, v_pages,
         x = residual(cfg, x, attn_out(layer, attn.reshape(b, m, -1)))
         out, _aux, *_ = block(layer, x, cfg, ok, h_attn)
         x = residual(cfg, x, out)
-    x = rms_norm(x, params["final_ln"], cfg.norm_eps, cfg.norm_plus_one)
+    x = norm(cfg, x, params["final_ln"])
     return lm_head(params, x, cfg), k_pages, v_pages
 
 

@@ -218,7 +218,8 @@ __all__ = ["config_from_hf", "params_from_hf", "load_hf",
            "moe_config_from_hf", "moe_params_from_hf", "load_hf_moe",
            "hybrid_config_from_hf", "hybrid_params_from_hf",
            "load_hf_hybrid", "smallthinker_config_from_hf",
-           "smallthinker_params_from_hf", "xing_config_from_hf"]
+           "smallthinker_params_from_hf", "xing_config_from_hf",
+           "cohere_moe_config_from_hf"]
 
 
 def moe_config_from_hf(hf_cfg, page_size=16, dtype="float32"):
@@ -486,6 +487,18 @@ def load_hf_hybrid(model_or_state_dict, hf_cfg=None, page_size=16,
     return cfg, hybrid_params_from_hf(model_or_state_dict, cfg)
 
 
+def _layer_spec(bands, ropes):
+    """LlamaConfig's fields for a per-layer spec of band and rotary:
+    the two tuples, or where all layers are alike the one-band case
+    (`window` / `use_rope`), which the engine holds in one pool."""
+    one_band = len(set(bands)) == 1
+    one_rope = len(set(ropes)) == 1
+    return dict(window=bands[0] if one_band else 0,
+                layer_bands=() if one_band else tuple(bands),
+                use_rope=ropes[0] if one_rope else True,
+                layer_rope=() if one_rope else tuple(ropes))
+
+
 def smallthinker_config_from_hf(hf_cfg, page_size=16, dtype="float32"):
     """Map a SmallThinker ``config.json`` (PowerInfer/SmallThinker-
     21BA3B-Instruct; any object with its keys as attributes) onto
@@ -527,10 +540,6 @@ def smallthinker_config_from_hf(hf_cfg, page_size=16, dtype="float32"):
     band = int(hf_cfg.sliding_window_size)
     bands = tuple(band if b else 0 for b in banded)
     ropes = tuple(bool(r) for r in rotates)
-    # all layers alike: the one-band case (LlamaConfig.window /
-    # use_rope), which the engine holds in one pool
-    one_band = len(set(bands)) == 1
-    one_rope = len(set(ropes)) == 1
     hd = getattr(hf_cfg, "head_dim", None)
     derived = hf_cfg.hidden_size // hf_cfg.num_attention_heads
     return SmallThinkerConfig(
@@ -546,10 +555,7 @@ def smallthinker_config_from_hf(hf_cfg, page_size=16, dtype="float32"):
         max_seq=hf_cfg.max_position_embeddings,
         page_size=page_size,
         rope_theta=float(hf_cfg.rope_theta),
-        window=bands[0] if one_band else 0,
-        layer_bands=() if one_band else bands,
-        use_rope=ropes[0] if one_rope else True,
-        layer_rope=() if one_rope else ropes,
+        **_layer_spec(bands, ropes),
         norm_eps=float(hf_cfg.rms_norm_eps),
         dtype=dtype,
     )
@@ -695,5 +701,106 @@ def xing_config_from_hf(hf_cfg, page_size=16, dtype="float32"):
         page_size=page_size,
         rope_theta=float(hf_cfg.rope_theta),
         norm_eps=float(hf_cfg.rms_norm_eps),
+        dtype=dtype,
+    )
+
+
+def cohere_moe_config_from_hf(hf_cfg, page_size=16, dtype="float32"):
+    """Map a ``model_type: cohere2_moe`` ``config.json`` (CohereLabs/
+    command-a-plus-05-2026; any object with its keys as attributes) onto
+    :class:`models.cohere.CohereConfig`: ``layer_types`` becomes the
+    per-layer spec (a sliding layer has the band ``sliding_window`` and
+    rotates, a full layer has neither), the sigmoid router, the routed
+    and the averaged shared experts, the mean-centred norm, rotary in
+    adjacent pairs, ``logit_scale``. One chip's share of the routed
+    experts is the group ``expert_share`` ({"router_width",
+    "first_expert"}: the router scores ``router_width`` experts and
+    this chip holds the ``num_experts`` with ids from ``first_expert``
+    on); without it every expert the router scores is held. The group
+    ``random_init`` ({"query_gain"}) is no published key either: how
+    much wider than the other matrices a configuration without a
+    checkpoint draws its query projection (``CohereConfig.q_init_gain``;
+    absent: 1, every matrix alike). Refused,
+    because models/cohere.py does not implement it: ``use_qk_norm``,
+    ``attention_bias``, leading dense layers (``first_k_dense_replace``
+    over 0), another selection function than sigmoid, gates not
+    normalised on the chosen, another combination of the shared experts
+    than their average, an activation other than gated silu, rotary
+    over part of a head (``rotary_pct`` under 1) or in another form
+    than ``rope_gptj``, a sequential block (``use_parallel_block``
+    false), an untied head, a ``layer_types`` list that is not one
+    known entry a layer. Config only: no public key names a
+    checkpoint's tensors."""
+    from .cohere import CohereConfig
+
+    def refuse(what):
+        raise NotImplementedError(
+            f"cohere2_moe: {what} is not implemented by models/cohere.py")
+
+    g = lambda k, d=None: getattr(hf_cfg, k, d)  # noqa: E731
+    if g("use_qk_norm", False):
+        refuse("use_qk_norm")
+    if g("attention_bias", False):
+        refuse("attention_bias")
+    if g("first_k_dense_replace", 0):
+        refuse("leading dense layers (first_k_dense_replace "
+               f"{g('first_k_dense_replace')})")
+    if g("expert_selection_fn", "sigmoid") != "sigmoid":
+        refuse(f"expert_selection_fn {g('expert_selection_fn')!r}")
+    if not g("norm_topk_prob", True):
+        refuse("norm_topk_prob false")
+    if g("num_shared_experts", 0) and g(
+            "shared_expert_combination_strategy", "average") != "average":
+        refuse("shared_expert_combination_strategy "
+               f"{g('shared_expert_combination_strategy')!r}")
+    if g("hidden_act", "silu") != "silu" \
+            or not g("use_gated_activation", True):
+        refuse(f"hidden_act {g('hidden_act')!r} with use_gated_activation "
+               f"{g('use_gated_activation')!r}")
+    if g("rotary_pct", 1) != 1:
+        refuse(f"rotary_pct {g('rotary_pct')}")
+    if g("position_embedding_type", "rope_gptj") != "rope_gptj":
+        refuse(f"position_embedding_type {g('position_embedding_type')!r}")
+    if not g("use_parallel_block", True):
+        refuse("use_parallel_block false (a sequential block)")
+    if not g("tie_word_embeddings", True):
+        refuse("an untied head (the head is the embedding's rows)")
+    n = hf_cfg.num_hidden_layers
+    kinds = list(hf_cfg.layer_types)
+    known = {"sliding_attention", "full_attention"}
+    if len(kinds) != n or set(kinds) - known:
+        refuse(f"a layer_types list of {len(kinds)} entries "
+               f"{sorted(set(kinds))} for {n} layers")
+    band = int(g("sliding_window", 0) or 0)
+    bands = tuple(band if k == "sliding_attention" else 0 for k in kinds)
+    ropes = tuple(k == "sliding_attention" for k in kinds)
+    share = g("expert_share")
+    if share is not None and not isinstance(share, dict):
+        share = vars(share)
+    init = g("random_init")
+    if init is not None and not isinstance(init, dict):
+        init = vars(init)
+    hd = g("head_dim")
+    derived = hf_cfg.hidden_size // hf_cfg.num_attention_heads
+    return CohereConfig(
+        head_dim_override=hd if (hd is not None and hd != derived) else 0,
+        vocab_size=hf_cfg.vocab_size,
+        d_model=hf_cfg.hidden_size,
+        n_layers=n,
+        n_heads=hf_cfg.num_attention_heads,
+        n_kv_heads=hf_cfg.num_key_value_heads,
+        d_ff=hf_cfg.intermediate_size,
+        n_experts=hf_cfg.num_experts,
+        n_routed=int(share["router_width"]) if share else 0,
+        first_expert=int(share["first_expert"]) if share else 0,
+        top_k=hf_cfg.num_experts_per_tok,
+        n_shared=g("num_shared_experts", 0) or 0,
+        max_seq=hf_cfg.max_position_embeddings,
+        page_size=page_size,
+        rope_theta=float(hf_cfg.rope_theta),
+        **_layer_spec(bands, ropes),
+        norm_eps=float(hf_cfg.layer_norm_eps),
+        logits_div=1.0 / float(g("logit_scale", 1) or 1),
+        q_init_gain=float(init["query_gain"]) if init else 1.0,
         dtype=dtype,
     )

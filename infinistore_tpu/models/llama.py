@@ -64,6 +64,13 @@ class LlamaConfig:
     layer_bands: tuple = ()
     layer_rope: tuple = ()
     norm_eps: float = 1e-5
+    # The norm of a layer's input and of the stack's output is
+    # mean-centred (Cohere's LayerNorm, a weight and no bias:
+    # decoder.layer_norm), not RMSNorm.
+    norm_center: bool = False
+    # Rotary positions turn ADJACENT lanes (2 i, 2 i + 1), GPT-J's and
+    # Cohere's form, not the halves (i, i + head_dim / 2).
+    rope_adjacent: bool = False
     # Family knobs beyond the Llama defaults (the Gemma-1 geometry:
     # GeGLU activation, zero-centered RMSNorm weights applied as
     # (1 + w), sqrt(d_model)-scaled embeddings, and a head_dim that

@@ -52,6 +52,22 @@ class MoEConfig(llama.LlamaConfig):
     router: str = "softmax"
     route_scale: float = 1.0
     n_shared: int = 0
+    # One chip's SHARE of a layer's experts (the sorted dispatch only):
+    # the router scores `n_routed` experts (0: `n_experts`, all of them
+    # held here) and chooses `top_k` of them; this chip holds the
+    # `n_experts` with ids from `first_expert` on (the weights' leading
+    # axis) and computes their part of a token's sum. A chosen pair
+    # whose expert is absent contributes nothing: the other chips'
+    # parts, and the exchange that would add them, are not here.
+    n_routed: int = 0
+    first_expert: int = 0
+    # the shared experts' outputs are averaged, not summed
+    shared_mean: bool = False
+
+    @property
+    def holds_share(self):
+        """The router scores more experts than are held here."""
+        return self.n_routed > self.n_experts
 
     def capacity(self, n_tokens):
         """Per-expert token slots: ceil(top_k * T / E * factor), rounded
@@ -236,6 +252,18 @@ GATHERED_EXPERTS_MAX_ROWS = 16
 # a hit's short suffix touches every expert anyway, and the sort, two
 # gathers and a grouped matmul over groups of a few rows cost more than
 # the rows they save until the rows are many.
+# The rows are counted over the experts the ROUTER scores: a chip that
+# holds a share of them (`MoEConfig.n_routed`) holds fewer and the
+# dense form's work grows with tokens x held, while what it saves, the
+# sort and the gathers, does not. Measured for a share (PERF.md, PR 42;
+# 16 held of 128 scored, 4,096 x 4,096, 8 a token; ms a layer, dense /
+# `experts_sorted_held`): 128 tokens 2.23 / 5.21, 256 2.40 / 5.23, 512
+# 4.48 / 5.54. A hit's suffix of 128-256 tokens leaves a held pair a
+# token, 8-16 rows an expert: both forms read all 16 experts' weights
+# (1.61 GB, 1.97 ms at the HBM's rate), the dense form at 82-88 % of
+# that rate, the grouped matmul over groups that small at 38 %. The
+# crossover lies above 512 tokens, where no program of a cell runs
+# (suffixes end at 256, cold prompts start at 1,136).
 DENSE_EXPERTS_MAX_ROWS = 512 * 64
 
 
@@ -253,22 +281,26 @@ def route_sigmoid(router, bias, h, top_k, scale):
     gates [T, k] float32) of a sigmoid router with a selection bias
     (DeepSeek-V3's `noaux_tc` without groups): the score of an expert
     is sigmoid(h . w_e); the k largest of score + bias are chosen (the
-    bias chooses, it does not weigh); a gate is the chosen expert's
+    bias chooses, it does not weigh; None: a router without one); a gate is the chosen expert's
     SCORE over the sum of the chosen scores, times `scale`."""
     scores = jax.nn.sigmoid(h.astype(jnp.float32) @ router)
-    biased = scores + bias
+    biased = scores if bias is None else scores + bias
     _, top_idx = jax.lax.top_k(biased, top_k)
     top_s = jnp.take_along_axis(scores, top_idx, axis=-1)
     gates = top_s / jnp.sum(top_s, axis=-1, keepdims=True) * scale
     return biased, top_idx, gates
 
 
-def shared_expert(layer, u, act):
-    """The shared expert: every token, ungated. u: [T, d]."""
+def shared_expert(layer, u, act, mean_of=1):
+    """The shared experts, side by side in one gated block: every
+    token, ungated; their sum, or with `mean_of` their number the
+    mean. u: [T, d]."""
     with jax.named_scope("moe.shared"):
         a = act(decoder.matmul(u, layer["s_gate"])) \
             * decoder.matmul(u, layer["s_up"])
-        return decoder.matmul(a, layer["s_down"])
+        out = decoder.matmul(a, layer["s_down"])
+        return out if mean_of == 1 else out * jnp.asarray(1.0 / mean_of,
+                                                          out.dtype)
 
 
 # The grouped matmul's rows are padded to a multiple of this. Measured
@@ -305,6 +337,78 @@ def experts_sorted(layer, u, top_idx, gates, act):
         return jnp.einsum("tkd,tk->td", back, gates.astype(back.dtype))
 
 
+# A layer that holds a SHARE of the experts its router scores runs the
+# grouped matmul over the pairs that fell on held experts, in passes of
+# a fixed number of rows (a shape): what falls here in expectation, T k
+# n_experts / n_routed, and a quarter more, in whole tiles. One pass
+# holds every held pair unless the routing leans this chip's way by
+# more than that quarter; then a second pass runs (a loop whose trip
+# count the held pairs set), so no pair is dropped and no capacity
+# exists, and the rows computed follow the held pairs, not T k.
+HELD_ROWS_SLACK = 1.25
+
+
+def held_rows(n_tokens, cfg):
+    """Rows one pass of `experts_sorted_held` runs for `n_tokens`."""
+    def tiles(n):
+        return -(-n // SORTED_ROW_TILE) * SORTED_ROW_TILE
+
+    pairs = n_tokens * cfg.top_k
+    want = int(np.ceil(pairs * cfg.n_experts / cfg.n_routed
+                       * HELD_ROWS_SLACK))
+    return min(tiles(want), tiles(pairs))
+
+
+def experts_sorted_held(layer, u, local, gates, act, rows):
+    """`experts_sorted` over the pairs whose expert is held here.
+    local: [T, k] chosen ids counted from the first held expert, so
+    outside [0, E) where the expert is absent; such a pair adds
+    nothing. The held pairs, sorted by expert, run `rows` at a time.
+    Returns ([T, d], the passes run, int32)."""
+    T, d = u.shape
+    k = local.shape[1]
+    E = layer["e_gate"].shape[0]
+    with jax.named_scope("moe.dispatch"):
+        here = (local >= 0) & (local < E)
+        flat = jnp.where(here, local, E).reshape(-1)  # absent pairs last
+        order = jnp.argsort(flat)
+        place = jnp.argsort(order).reshape(T, k)  # a pair's sorted row
+        ends = jnp.cumsum(
+            jnp.bincount(flat, length=E + 1)[:E]).astype(jnp.int32)
+        starts = ends - jnp.diff(ends, prepend=0)
+        token = jnp.pad(order // k, (0, rows))
+        w = jnp.where(here, gates, 0.0)
+
+    def one_pass(i, acc):
+        lo = i * rows
+        with jax.named_scope("moe.dispatch"):
+            sizes = (jnp.clip(ends, lo, lo + rows)
+                     - jnp.clip(starts, lo, lo + rows))
+            # rows past the last held pair ride in the last expert's
+            # group: computed, and combined into no token
+            sizes = sizes.at[-1].add(rows - jnp.sum(sizes))
+            x = jnp.take(u, jax.lax.dynamic_slice(token, (lo,), (rows,)),
+                         axis=0)
+        with jax.named_scope("moe.experts"):
+            a = act(jax.lax.ragged_dot(x, layer["e_gate"], sizes))
+            a = a * jax.lax.ragged_dot(x, layer["e_up"], sizes)
+            y = jax.lax.ragged_dot(a, layer["e_down"], sizes)  # [rows, d]
+        with jax.named_scope("moe.combine"):
+            at = place - lo
+            mine = here & (at >= 0) & (at < rows)
+            for j in range(k):  # a token's j-th pair, where it ran here
+                part = jnp.take(y, jnp.clip(at[:, j], 0, rows - 1), axis=0)
+                acc = acc + jnp.where(
+                    mine[:, j, None],
+                    part.astype(acc.dtype) * w[:, j, None], 0.0)
+        return acc
+
+    passes = -(-ends[-1] // rows)
+    acc = jax.lax.fori_loop(0, passes, one_pass,
+                            jnp.zeros((T, d), jnp.float32))
+    return acc.astype(u.dtype), passes
+
+
 def experts_dense(layer, u, top_idx, gates, act):
     """The same sum with every token through every expert and the
     gates of the experts it did not choose at zero: for a handful of
@@ -337,41 +441,77 @@ def experts_gathered(layer, u, top_idx, gates, act, valid=None):
 
 
 def sorted_moe_mlp(layer, x, cfg: MoEConfig, valid, h_attn=None,
-                   early_router=False):
+                   early_router=False, own_norm=True):
     """A feed-forward block (decoder.py's `block` contract) without
     capacity: a row that holds no real token takes nothing from one
     that does, so `valid` ([b, s] bool or None) only keeps such a
     row's experts from being fetched (`experts_gathered`). With
     `early_router` the router reads `h_attn`, the attention block's
-    normalised input, and not the block's own. Which of the three
+    normalised input, and not the block's own; without `own_norm` the
+    block has no norm of its own and its input IS `h_attn` (a parallel
+    block: attention and experts read one normalised input, and `x`
+    goes unread). Which of the three
     forms runs is decided by the number of tokens, which is a shape.
     The gate's activation is the config's (`cfg.act`), and so are the
     router's form (`cfg.router`) and the shared expert (`cfg.n_shared`).
     No auxiliary loss (serving only); third, the experts the layer
-    fetched where that is fewer than all (else None)."""
+    fetched where that is fewer than all (else None).
+
+    A layer that holds a share of the experts its router scores
+    (`cfg.holds_share`) routes over all of them and computes its own
+    experts' part: the chosen ids are counted from `cfg.first_expert`,
+    and in every form a pair whose expert is absent adds nothing (a
+    one-hot of an id outside the held ones is all zero; the sorted
+    form sorts such pairs last and runs the others). It returns a
+    fourth, the block contract's counts: `pairs_held` [b, s] int32
+    (zero for a row that is not valid) and `rows`, the rows its
+    matmuls over a prompt's tokens ran (0 for a decode step's)."""
     b, s, d = x.shape
+    T = b * s
     with jax.named_scope("moe.route"):
-        u = decoder.rms_norm(x, layer["ln2"], cfg.norm_eps,
-                             cfg.norm_plus_one).reshape(b * s, d)
-        seen = h_attn.reshape(b * s, d) if early_router else u
+        if own_norm:
+            u = decoder.rms_norm(x, layer["ln2"], cfg.norm_eps,
+                                 cfg.norm_plus_one).reshape(T, d)
+        else:
+            u = h_attn.reshape(T, d)
+        seen = h_attn.reshape(T, d) if early_router else u
         if cfg.router == "sigmoid":
             _, top_idx, gates = route_sigmoid(
-                layer["router"], layer["router_bias"], seen, cfg.top_k,
+                layer["router"], layer.get("router_bias"), seen, cfg.top_k,
                 cfg.route_scale)
         else:
             _, top_idx, gates = route_top_k(layer["router"], seen,
                                             cfg.top_k)
+        if cfg.holds_share:
+            top_idx = top_idx - cfg.first_expert
+            here = (top_idx >= 0) & (top_idx < cfg.n_experts)
+            if valid is not None:
+                here = here & valid.reshape(T, 1)
+            pairs_held = jnp.sum(here, axis=-1, dtype=jnp.int32)
     fetched = None
-    if b * s <= GATHERED_EXPERTS_MAX_ROWS:
+    rows = 0  # of the matmuls over a prompt's tokens (a share's count)
+    if T <= GATHERED_EXPERTS_MAX_ROWS:
         out, fetched = experts_gathered(
             layer, u, top_idx, gates, _gate_act(cfg),
-            None if valid is None else valid.reshape(b * s))
-    elif b * s * cfg.n_experts <= DENSE_EXPERTS_MAX_ROWS:
+            None if valid is None else valid.reshape(T))
+    elif T * (cfg.n_routed or cfg.n_experts) <= DENSE_EXPERTS_MAX_ROWS:
         out = experts_dense(layer, u, top_idx, gates, _gate_act(cfg))
+        rows = T * cfg.n_experts
+    elif cfg.holds_share:
+        per_pass = held_rows(T, cfg)
+        out, passes = experts_sorted_held(layer, u, top_idx, gates,
+                                          _gate_act(cfg), per_pass)
+        rows = passes * per_pass
     else:
         out = experts_sorted(layer, u, top_idx, gates, _gate_act(cfg))
     if cfg.n_shared:
-        out = out + shared_expert(layer, u, _gate_act(cfg))
+        out = out + shared_expert(
+            layer, u, _gate_act(cfg),
+            cfg.n_shared if cfg.shared_mean else 1)
+    if cfg.holds_share:
+        return out.reshape(b, s, d), None, fetched, {
+            "pairs_held": pairs_held.reshape(b, s),
+            "rows": jnp.asarray(rows, jnp.int32)}
     return out.reshape(b, s, d), None, fetched
 
 

@@ -429,6 +429,24 @@ def _page_out(cfg, kvs, k_pages, v_pages, ids):
     return k_pages, v_pages
 
 
+def _last_row(logits, s_real, counts):
+    """An admission program's logits row, the last real position's.
+    Where the model's layers hold a share of the experts their routers
+    score (`counts`: what its prefill returned third, else empty), two
+    more values ride behind it in the host's one pull: the real rows'
+    pairs that fell on experts held here and the rows the experts'
+    matmuls ran, summed over the layers (whole numbers, exact in
+    float32 below 2 ** 24)."""
+    row = logits[0, s_real - 1]
+    if not counts:
+        return row
+    c, = counts
+    real = jnp.arange(c["pairs_held"].shape[1]) < s_real
+    tail = jnp.stack([jnp.sum(jnp.where(real, c["pairs_held"][0], 0)),
+                      c["rows"]])
+    return jnp.concatenate([row, tail.astype(row.dtype)])
+
+
 @partial(jax.jit, static_argnames=("cfg", "model"), donate_argnums=(3, 4))
 def _admit_fused(params, cfg, tokens, k_pages, v_pages, ids, s_real,
                  model=llama):
@@ -444,9 +462,9 @@ def _admit_fused(params, cfg, tokens, k_pages, v_pages, ids, s_real,
     are unreachable. `ids` is padded with total_pages (mode=drop);
     the first s_pad // page of them are read.
     tokens: [1, s_pad] (page multiple); ids: [max_pages_per_seq]."""
-    logits, kvs = model.prefill(params, cfg, tokens)
+    logits, kvs, *counts = model.prefill(params, cfg, tokens)
     k_pages, v_pages = _page_out(cfg, kvs, k_pages, v_pages, ids)
-    return logits[0, s_real - 1], k_pages, v_pages
+    return _last_row(logits, s_real, counts), k_pages, v_pages
 
 
 def _place_restored(cfg, restored, k_pages, v_pages, restored_ids):
@@ -517,10 +535,10 @@ def _admit_fused_px(params, cfg, tokens, restored, k_pages, v_pages,
     `_prefill_px_jit` has per (s_pad, prefix length)."""
     k_pages, v_pages, prefix = _place_restored(cfg, restored, k_pages,
                                                v_pages, restored_ids)
-    logits, kvs = model.prefill_with_prefix(params, cfg, tokens, prefix,
-                                            pos0=pos0)
+    logits, kvs, *counts = model.prefill_with_prefix(
+        params, cfg, tokens, prefix, pos0=pos0)
     k_pages, v_pages = _page_out(cfg, kvs, k_pages, v_pages, suffix_ids)
-    return logits[0, s_real - 1], k_pages, v_pages
+    return _last_row(logits, s_real, counts), k_pages, v_pages
 
 
 def _with_fetched(nxt, fetched):
@@ -763,10 +781,11 @@ def _admit_fused_wf(params, cfg, tokens, k_pages, v_pages, wk, wv, ids,
     at `wids`, and the banded layers' first `n_sub` pages come back as
     `sub` (`_page_out_two`). One program per (s_pad, n_sub); n_sub is a
     function of s_pad in an admission."""
-    logits, kvs = model.prefill(params, cfg, tokens)
+    logits, kvs, *counts = model.prefill(params, cfg, tokens)
     k_pages, v_pages, wk, wv, sub = _page_out_two(
         cfg, kvs, k_pages, v_pages, wk, wv, ids, wids, n_sub)
-    return logits[0, s_real - 1], k_pages, v_pages, wk, wv, sub
+    return (_last_row(logits, s_real, counts), k_pages, v_pages, wk, wv,
+            sub)
 
 
 @partial(jax.jit, static_argnames=("cfg", "model", "n_sub"),
@@ -815,10 +834,12 @@ def _admit_fused_px_wf(params, cfg, tokens, restored, k_pages, v_pages, wk,
             flat = (1, rows.shape[0] * page, cfg.n_kv_heads, cfg.head_dim)
             prefix.append((rows[:, li, 0].reshape(flat),
                            rows[:, li, 1].reshape(flat)))
-    logits, kvs = model.prefill_with_prefix(params, cfg, tokens, prefix)
+    logits, kvs, *counts = model.prefill_with_prefix(params, cfg, tokens,
+                                                     prefix)
     k_pages, v_pages, wk, wv, sub = _page_out_two(
         cfg, kvs, k_pages, v_pages, wk, wv, s_ids, ws_ids, n_sub)
-    return logits[0, s_real - 1], k_pages, v_pages, wk, wv, sub
+    return (_last_row(logits, s_real, counts), k_pages, v_pages, wk, wv,
+            sub)
 
 
 @partial(jax.jit, static_argnames=("cfg", "model", "fetched"),
@@ -1105,6 +1126,14 @@ class ServingEngine:
             # fetched (models/moe.py:experts_gathered), of those their
             # layers hold (layers x experts a step)
             "moe_experts_fetched": 0, "moe_experts_held": 0,
+            # a model whose layers hold a SHARE of the experts their
+            # routers score (MoEConfig.holds_share), over admissions
+            # and decode steps: the (token, chosen expert) pairs its
+            # routers made for real tokens, those that fell on experts
+            # held here (counted by the programs), and the rows the
+            # admission programs' expert matmuls ran
+            "moe_pairs_routed": 0, "moe_pairs_held": 0,
+            "moe_rows_computed": 0,
             # offloads handed to the upload thread, times the engine
             # thread waited for room under UPLOAD_INFLIGHT_BYTES, and
             # what `done` waited for acknowledgements (a request's
@@ -1144,6 +1173,11 @@ class ServingEngine:
         self._experts_held = sum(
             layer["e_gate"].shape[0] for layer in params.get("layers", ())
             if "e_gate" in layer)
+        # ... and those layers, where they hold a share of the experts
+        # their routers score (0: every expert scored is held)
+        self._share_layers = sum(
+            "e_gate" in layer for layer in params.get("layers", ())
+        ) if getattr(cfg, "holds_share", False) else 0
         self.engine_id = profiling.next_engine_id()
         self._own_digests = {}  # insertion-ordered, at most OWN_DIGESTS
         # One sequence page over every layer and kind the page pools
@@ -2070,7 +2104,7 @@ class ServingEngine:
             (row_dev, self.k_pages, self.v_pages, self.wk_pages,
              self.wv_pages, sub) = out
             f["dispatch_ns"] = profiling.elapsed_ns()
-            return np.asarray(row_dev), sub
+            return self._pull_row(row_dev, len(suffix), f), sub
 
     def _put_subfloor(self, slot, sub, lo, hi):
         """The banded layers' pages [lo, hi) of the slot's sequence,
@@ -2196,7 +2230,23 @@ class ServingEngine:
                     self._slot_dev(slot_idx), model=self.model,
                 )
             f["dispatch_ns"] = profiling.elapsed_ns()
-            return np.asarray(row_dev)
+            return self._pull_row(row_dev, len(tokens), f)
+
+    def _pull_row(self, row_dev, n_tokens, f):
+        """An admission program's logits row on the host. Behind the
+        row of a model whose layers hold a share of their experts lie
+        the program's two counts (`_last_row`): they go to the span
+        `f` and the counters, the row comes back alone."""
+        row = np.asarray(row_dev)
+        if not self._share_layers:
+            return row
+        held, rows = int(row[-2]), int(row[-1])
+        f["pairs_held"], f["rows_computed"] = held, rows
+        self.stats["moe_pairs_routed"] += (n_tokens * self.cfg.top_k
+                                           * self._share_layers)
+        self.stats["moe_pairs_held"] += held
+        self.stats["moe_rows_computed"] += rows
+        return row[:-2]
 
     def _slot_dev(self, slot_idx):
         """A state-pool row index on the device; None is max_slots,
@@ -2243,7 +2293,7 @@ class ServingEngine:
                     model=self.model,
                 )
             f["dispatch_ns"] = profiling.elapsed_ns()
-            return np.asarray(row_dev)
+            return self._pull_row(row_dev, len(suffix), f)
 
     def first_token_logits(self, prompt):
         """First-token logits of `prompt` through the programs an
@@ -3001,9 +3051,15 @@ class ServingEngine:
             df["dispatch_ns"] = profiling.elapsed_ns()
             nxt = np.asarray(pulled[0] if pulled else nxt_dev)
             if pulled:
-                df["experts_fetched"] = int(nxt[-1])
-                self.stats["moe_experts_fetched"] += int(nxt[-1])
+                fetched = int(nxt[self.sc.max_slots])
+                df["experts_fetched"] = fetched
+                self.stats["moe_experts_fetched"] += fetched
                 self.stats["moe_experts_held"] += self._experts_held
+            if self._share_layers:
+                df["pairs_held"] = int(nxt[-1])
+                self.stats["moe_pairs_routed"] += (
+                    len(active) * self.cfg.top_k * self._share_layers)
+                self.stats["moe_pairs_held"] += int(nxt[-1])
         # Reusable next step iff every emitted token is the device's
         # argmax (greedy) — samplers/spec/finishes invalidate via key.
         self._steady = (

@@ -166,6 +166,8 @@ def benchmark_tests_pinned_before_pr26(request, monkeypatch):
             return
         if _xing_in_the_pinned_tests(node, name, module, monkeypatch):
             return
+        if _command_a_in_the_pinned_tests(node, name, module, monkeypatch):
+            return
     if module.__name__.endswith("test_bench_manifest") \
             and name == "test_reduced_never_names_a_width":
         # It holds every configuration to mistral7b's widths (4096,
@@ -175,12 +177,16 @@ def benchmark_tests_pinned_before_pr26(request, monkeypatch):
         # smallthinker21b's by tests/benchmark/test_bench_smallthinker.py
         # (its `reduced` is the depth and the two per-layer lists);
         # xing4-29b's by tests/benchmark/test_bench_xing.py (depth,
-        # leading dense layers, the prediction module).
+        # leading dense layers, the prediction module);
+        # command-a-plus's by tests/benchmark/test_bench_command_a.py
+        # (depth, its per-layer list, the experts HELD, the
+        # vocabulary's slice: the chip's share, no width).
         bench = dict(module.BENCH)
         bench["configs"] = [c for c in bench["configs"]
                             if c["name"] not in ("granite4h-micro",
                                                  "smallthinker21b",
-                                                 "xing4-29b")]
+                                                 "xing4-29b",
+                                                 _COMMAND_A)]
         monkeypatch.setattr(module, "BENCH", bench)
         return
     if module.__name__.endswith("test_bench_observations") \
@@ -217,7 +223,7 @@ def benchmark_tests_pinned_before_pr26(request, monkeypatch):
             for m in bench["per_layer"]:
                 for later in ("granite4h-micro-sessions4k",
                               "smallthinker21b-sessions12k",
-                              _XING_CELL):
+                              _XING_CELL, _COMMAND_A_CELL):
                     if later in m.get("workloads", ()):
                         m["workloads"].remove(later)
             return bench
@@ -226,6 +232,68 @@ def benchmark_tests_pinned_before_pr26(request, monkeypatch):
 
 
 _XING_CELL = "xing4-29b-docs32k"
+_COMMAND_A, _COMMAND_A_CELL = "command-a-plus", "command-a-plus-mixed12k"
+
+
+def _as_before_pr42(bench):
+    """The manifest without what PR 42 appended: the configuration
+    command-a-plus, its cell, its two per-layer metrics and the cell's
+    name on the older metrics' lists."""
+    bench["workloads"] = [w for w in bench["workloads"]
+                          if w["name"] != _COMMAND_A_CELL]
+    bench["configs"] = [c for c in bench["configs"]
+                        if c["name"] != _COMMAND_A]
+    bench["per_layer"] = [m for m in bench["per_layer"]
+                          if m.get("workloads") != [_COMMAND_A_CELL]]
+    for m in bench["per_layer"]:
+        if _COMMAND_A_CELL in m.get("workloads", ()):
+            m["workloads"].remove(_COMMAND_A_CELL)
+    return bench
+
+
+def _command_a_in_the_pinned_tests(node, name, module, monkeypatch):
+    """PR 42 (`model_config`: may add benchmark files, edit none) added
+    the configuration command-a-plus and two per-layer metrics; as
+    `_xing_in_the_pinned_tests` for PR 40's. Returns True where it
+    dealt with the test: the table test gets the two new metrics'
+    hand-worked numbers from tests/benchmark/command_a_by_hand.py (one
+    reads the window's counters, one the program's ring), and the
+    configuration's cases of "resolves to today's defaults" are skipped
+    (it names a costs module and tolerances of its own, which
+    tests/benchmark/test_bench_command_a.py holds)."""
+    import pytest
+
+    params = getattr(getattr(node, "callspec", None), "params", {})
+    if name == "test_an_accepted_configuration_resolves_to_todays_defaults":
+        if params.get("config") == _COMMAND_A:
+            pytest.skip("command-a-plus brings its own costs and "
+                        "tolerances: test_bench_command_a.py")
+        return False
+    if name != "test_reader_gives_the_number_worked_by_hand":
+        return False
+    import command_a_by_hand as by_hand
+
+    if params.get("name") not in by_hand.BY_HAND:
+        return False
+    from infinistore_tpu.utils import profiling
+
+    table, window = module.expected, module.full_window
+
+    def full_window():
+        from benchmark.lib import serve
+
+        obs = window()
+        obs.counters.update(by_hand.COUNTERS)
+        # the skew is read against the file's even share (16 of 128)
+        obs.conf = serve.load_config(
+            "benchmark/configs/command-a-plus.json")
+        return obs
+
+    monkeypatch.setattr(module, "full_window", full_window)
+    monkeypatch.setattr(module, "expected",
+                        lambda obs: {**table(window()), **by_hand.BY_HAND})
+    monkeypatch.setattr(profiling, "spans", lambda: by_hand.RING)
+    return True
 
 
 def _xing_in_the_pinned_tests(node, name, module, monkeypatch):
@@ -295,8 +363,9 @@ def _idle_by_span_in_the_pinned_tests(node, name, module, monkeypatch):
             names = [m["name"] for m in bench["per_layer"]]
             bench["per_layer"] = bench["per_layer"][
                 :names.index("idle_no_work_share")]
-            # ... and without what PR 40 appended behind PR 35's cell,
-            # configuration and lists
+            # ... and without what PRs 40 and 42 appended behind PR
+            # 35's cell, configuration and lists
+            _as_before_pr42(bench)
             bench["workloads"] = [w for w in bench["workloads"]
                                   if w["name"] != _XING_CELL]
             bench["configs"] = [c for c in bench["configs"]
@@ -307,6 +376,15 @@ def _idle_by_span_in_the_pinned_tests(node, name, module, monkeypatch):
             return bench
 
         monkeypatch.setattr(module.manifest, "load", load_as_of_pr35)
+        return True
+    if module.__name__.endswith("test_bench_xing") and name == \
+            "test_the_cell_its_configuration_and_its_metrics_are_in_the_manifest":
+        # ... and test_bench_xing.py that PR 40's cell, configuration
+        # and four metrics are the LAST: it is shown the manifest
+        # without what PR 42 appended
+        load = module.manifest.load
+        monkeypatch.setattr(module.manifest, "load",
+                            lambda *a, **kw: _as_before_pr42(load(*a, **kw)))
         return True
     if not module.__name__.endswith("test_bench_observations") \
             or name != "test_reader_gives_the_number_worked_by_hand":

@@ -896,3 +896,76 @@ def test_yarn_frequencies_are_the_published_recipe():
     pos = jnp.arange(4)[None]
     assert np.array_equal(np.asarray(decoder.rope(x, pos, theta)),
                           np.asarray(decoder.rope(x, pos, theta, yarn=())))
+
+
+# -- cohere2_moe: a parallel block, window and full layers, a share ----------
+COHERE = dict(
+    attention_bias=False, expert_selection_fn="sigmoid",
+    first_k_dense_replace=0, head_dim=16, hidden_act="silu", hidden_size=64,
+    intermediate_size=32, layer_norm_eps=1e-5,
+    layer_types=["sliding_attention"] * 3 + ["full_attention"],
+    logit_scale=0.5, max_position_embeddings=4096, model_type="cohere2_moe",
+    norm_topk_prob=True, num_attention_heads=32, num_experts=8,
+    num_experts_per_tok=2, num_hidden_layers=4, num_key_value_heads=2,
+    num_shared_experts=4, position_embedding_type="rope_gptj",
+    rms_norm_eps=None, rope_theta=50000, rotary_pct=1,
+    shared_expert_combination_strategy="average", sliding_window=32,
+    tie_word_embeddings=True, use_gated_activation=True,
+    use_parallel_block=True, use_qk_norm=False, vocab_size=128)
+
+
+def _cohere(**changed):
+    import types
+
+    return types.SimpleNamespace(**{**COHERE, **changed})
+
+
+def test_cohere_config_maps_every_key():
+    cfg = hf.cohere_moe_config_from_hf(_cohere(), page_size=8)
+    assert (cfg.d_model, cfg.n_layers, cfg.n_heads, cfg.n_kv_heads,
+            cfg.head_dim, cfg.vocab_size, cfg.max_seq, cfg.rope_theta,
+            cfg.norm_eps, cfg.act) == (
+        64, 4, 32, 2, 16, 128, 4096, 5e4, 1e-5, "silu")
+    assert cfg.layer_windows == (32, 32, 32, 0)
+    assert cfg.layer_ropes == (True, True, True, False)
+    assert (cfg.d_ff, cfg.n_experts, cfg.top_k, cfg.n_shared, cfg.router,
+            cfg.shared_mean) == (32, 8, 2, 4, "sigmoid", True)
+    # every expert the router scores is held, unless the file says which
+    assert (cfg.n_routed, cfg.first_expert, cfg.holds_share) == (0, 0, False)
+    assert cfg.norm_center and cfg.rope_adjacent and cfg.two_kinds
+    assert cfg.logits_div == 2.0  # logits x logit_scale
+    share = hf.cohere_moe_config_from_hf(_cohere(
+        num_experts=2, expert_share={"router_width": 8, "first_expert": 4}))
+    assert (share.n_routed, share.n_experts, share.first_expert,
+            share.holds_share) == (8, 2, 4, True)
+    # random weights: every matrix alike unless the file asks otherwise
+    assert cfg.q_init_gain == share.q_init_gain == 1.0
+    assert hf.cohere_moe_config_from_hf(_cohere(
+        random_init={"query_gain": 4})).q_init_gain == 4.0
+    one = hf.cohere_moe_config_from_hf(_cohere(
+        layer_types=["full_attention"] * 4))
+    assert one.layer_bands == () and one.window == 0 and not one.use_rope
+    assert not one.two_kinds
+
+
+@pytest.mark.parametrize("changed,match", [
+    ({"use_qk_norm": True}, "use_qk_norm"),
+    ({"attention_bias": True}, "attention_bias"),
+    ({"first_k_dense_replace": 1}, "leading dense"),
+    ({"expert_selection_fn": "softmax"}, "expert_selection_fn"),
+    ({"norm_topk_prob": False}, "norm_topk_prob"),
+    ({"shared_expert_combination_strategy": "sum"},
+     "shared_expert_combination_strategy"),
+    ({"hidden_act": "gelu"}, "hidden_act"),
+    ({"use_gated_activation": False}, "use_gated_activation"),
+    ({"rotary_pct": 0.5}, "rotary_pct"),
+    ({"position_embedding_type": "rope_neox"}, "position_embedding_type"),
+    ({"use_parallel_block": False}, "use_parallel_block"),
+    ({"tie_word_embeddings": False}, "untied"),
+    ({"layer_types": ["sliding_attention"] * 3}, "layer_types"),
+    ({"layer_types": ["sliding_attention"] * 3 + ["linear_attention"]},
+     "layer_types"),
+])
+def test_cohere_bridge_refuses_what_is_not_implemented(changed, match):
+    with pytest.raises(NotImplementedError, match=match):
+        hf.cohere_moe_config_from_hf(_cohere(**changed))

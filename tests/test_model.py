@@ -440,9 +440,13 @@ def test_moe_config_is_a_llama_config():
         shared) | {"n_experts", "top_k", "capacity_factor",
                    "aux_loss_weight",
                    # the sorted dispatch's router form and shared experts
-                   "router", "route_scale", "n_shared"}
+                   "router", "route_scale", "n_shared", "shared_mean",
+                   # one chip's share of the experts the router scores
+                   "n_routed", "first_expert"}
     assert (ext.router, ext.route_scale, ext.n_shared) == ("softmax", 1.0,
                                                            0)
+    assert (ext.n_routed, ext.first_expert, ext.holds_share,
+            ext.shared_mean) == (0, 0, False, False)
     assert all(getattr(base, n) == getattr(ext, n) for n in shared)
     for name in ("head_dim", "jdtype", "kv_page_shape", "kv_page_bytes"):
         assert name not in vars(moe.MoEConfig), name  # inherited, not copied
@@ -621,7 +625,7 @@ def _decode_program(family):
     import types
 
     from infinistore_tpu import serving
-    from infinistore_tpu.models import hf, hybrid, smallthinker
+    from infinistore_tpu.models import cohere, hf, hybrid, smallthinker
 
     slots, total, table = 16, 4096, 192
     if family == "llama":  # mistral7b, mixtral8x7b: 8 kv heads, group 4
@@ -647,6 +651,17 @@ def _decode_program(family):
             normalization_function="rmsnorm", tie_word_embeddings=True,
             rms_norm_eps=1e-5, max_position_embeddings=8192), page_size=16)
         assert cfg.kv_pack == 2
+    elif family == "cohere":  # command-a-plus: 128 query heads in groups
+        model = cohere       # of 16, window and full layers, a share of
+        cfg = hf.cohere_moe_config_from_hf(types.SimpleNamespace(  # experts
+            vocab_size=256, hidden_size=256, num_hidden_layers=4,
+            num_attention_heads=128, num_key_value_heads=8, head_dim=128,
+            intermediate_size=128, num_experts=2, num_experts_per_tok=2,
+            num_shared_experts=4, layer_norm_eps=1e-5, rope_theta=50000,
+            layer_types=["sliding_attention"] * 3 + ["full_attention"],
+            sliding_window=4096, max_position_embeddings=16384,
+            expert_share={"router_width": 8, "first_expert": 2}),
+            page_size=16)
     else:  # smallthinker21b: the group of 7, full and banded layers
         model = smallthinker
         cfg = hf.smallthinker_config_from_hf(types.SimpleNamespace(
@@ -691,11 +706,12 @@ def _decode_program(family):
     # a paged-decode kernel a layer and, over routed experts, the
     # gathered expert kernel (ops/pallas_moe_decode.py: one function,
     # called by every layer)
-    kernels = n_full + n_win + (family == "smallthinker")
+    kernels = n_full + n_win + (family in ("smallthinker", "cohere"))
     return lower, 2 * (n_full + n_win) * kind, kind, kernels
 
 
-@pytest.mark.parametrize("family", ["llama", "hybrid", "smallthinker"])
+@pytest.mark.parametrize("family", ["llama", "hybrid", "smallthinker",
+                                    "cohere"])
 def test_decode_program_for_the_chip_holds_no_layer_of_the_pool(
         family, v5e_chip, monkeypatch):
     """`_decode_fused`, `_decode_fused_st` and `_decode_fused_wf` with

@@ -1,7 +1,8 @@
 #!/usr/bin/env python3
 """Hashes of the jaxprs of the serving engine's fused programs (cold and
 prefix admission, decode step, offload gather) for the four families
-that keep K and V pages, at tiny widths. A change that must leave
+that keep K and V pages and the one that keeps a latent row (xing,
+since PR 42), at tiny widths. A change that must leave
 their programs alone is checked by running this in both trees and
 comparing the output (PR 40: the parent unpacked under build/parent):
 
@@ -18,7 +19,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from infinistore_tpu import serving
-from infinistore_tpu.models import hybrid, llama, moe, smallthinker
+from infinistore_tpu.models import hybrid, llama, moe, smallthinker, xing
 from infinistore_tpu.serving import ServingEngine, ServingConfig
 
 def h(fn, *a, **kw):
@@ -54,7 +55,7 @@ def family(name, model, cfg, sc=None):
         return
     fn = lambda p, t, k, v, i, s: serving._admit_fused.__wrapped__(p, cfg, t, k, v, i, s, model)
     out[name + ".cold"] = h(fn, params, toks, eng.k_pages, eng.v_pages, ids, jnp.int32(30))
-    restored = jnp.zeros((2 * L * 2, *cfg.kv_page_shape()), cfg.jdtype)
+    restored = jnp.zeros((2 * L * len(cfg.page_kinds), *cfg.kv_page_shape()), cfg.jdtype)
     fn = lambda p, t, r, k, v, ri, si, s, p0: serving._admit_fused_px.__wrapped__(p, cfg, t, r, k, v, ri, si, s, p0, model)
     out[name + ".prefix"] = h(fn, params, toks, restored, eng.k_pages, eng.v_pages, jnp.asarray([1, 2], i32), ids, jnp.int32(30), jnp.int32(0))
     fn = lambda p, t, s, k, v, r: serving._decode_fused.__wrapped__(p, cfg, t, s, k, v, r, model, **counts)
@@ -66,4 +67,5 @@ family("llama", llama, llama.LlamaConfig())
 family("moe", moe, moe.MoEConfig())
 family("hybrid", hybrid, hybrid.HybridConfig(n_layers=3, layer_types=("mamba", "attention", "mamba"), use_rope=False))
 family("smallthinker", smallthinker, smallthinker.SmallThinkerConfig(n_layers=4, layer_bands=(0, 32, 32, 32), layer_rope=(False, True, True, True), n_experts=8, top_k=2))
+family("xing", xing, xing.XingConfig(n_layers=3, n_experts=8, top_k=2))
 print(json.dumps(out, indent=1, sort_keys=True))
