@@ -178,11 +178,33 @@ def matmul(h, w):
     return h @ w
 
 
-def proj(h, layer, w, b_, shape=None):
+def weight_where_it_lies(product):
+    """A decode step's product of one token a row with a projection's
+    weight, as it leaves the dot and before bias, scale or reshape:
+    nothing the compiler does behind this point reaches the dot. The
+    Pallas decode kernels take q (and the pools' scatters k and v) in
+    a layout of their own, and the TPU's layout assignment otherwise
+    pulls it back through the reshape into the dot and TRANSPOSES THE
+    WEIGHT to fit, a copy of every q, k and v weight every step (134 MB
+    a layer at 128 query heads: 1.8 ms of an 8.2 ms step, PERF.md
+    section 6, PR 43); held here, the dot reads the parameter as it
+    lies and what is re-laid is the handful of rows. No value changes.
+    The barrier placed after the reshape keeps every copy. The
+    admission programs and `verify_step` do not ask for it: their
+    weights are re-laid once for hundreds of rows (under 1 % of a cold
+    program, a few percent of a hit's: ROADMAP S12), and a barrier
+    there stands between the dot and what fuses into it."""
+    return jax.lax.optimization_barrier(product)
+
+
+def proj(h, layer, w, b_, shape=None, decode=False):
     """matmul with an optional bias leaf (absent in native checkpoints;
     the HF bridge adds bq/bk/bv/bo for attention_bias=True families
-    like Qwen2 — pytree structure is static under jit either way)."""
+    like Qwen2 — pytree structure is static under jit either way).
+    `decode`: the product is `weight_where_it_lies`'s."""
     out = matmul(h, layer[w])
+    if decode:
+        out = weight_where_it_lies(out)
     bias = layer.get(b_)
     if bias is not None:
         out = out + bias
@@ -204,17 +226,20 @@ def qkv(layer, x, cfg, positions, rotate=None):
     return _qkv(layer, x, cfg, positions, rotate)[:3]
 
 
-def _qkv(layer, x, cfg, positions, rotate=None):
+def _qkv(layer, x, cfg, positions, rotate=None, decode=False):
     """... and `h`, the normalised input they were projected from,
     which a family's feed-forward block may read too (a router placed
-    before attention)."""
+    before attention). `decode`: a decode step asks (`proj`)."""
     b = x.shape[0]
     s = x.shape[1]
     with jax.named_scope("attn.qkv"):
         h = norm(cfg, x, layer["ln1"])
-        q = proj(h, layer, "wq", "bq", (b, s, cfg.n_heads, cfg.head_dim))
-        k = proj(h, layer, "wk", "bk", (b, s, cfg.n_kv_heads, cfg.head_dim))
-        v = proj(h, layer, "wv", "bv", (b, s, cfg.n_kv_heads, cfg.head_dim))
+        q = proj(h, layer, "wq", "bq", (b, s, cfg.n_heads, cfg.head_dim),
+                 decode)
+        k = proj(h, layer, "wk", "bk", (b, s, cfg.n_kv_heads, cfg.head_dim),
+                 decode)
+        v = proj(h, layer, "wv", "bv", (b, s, cfg.n_kv_heads, cfg.head_dim),
+                 decode)
         if cfg.attn_scale:
             # The kernels scale scores by head_dim ** -0.5; a family
             # with a softmax scale of its own folds the ratio into q.
@@ -549,16 +574,21 @@ def latent_scale(cfg):
     return (cfg.qk_nope + cfg.qk_rope) ** -0.5 * m * m
 
 
-def latent_project(layer, x, cfg, positions):
+def latent_project(layer, x, cfg, positions, decode=False):
     """(q_nope [b, s, H, nope], q_pe [b, s, H, rope] rotated, the cache
-    rows [b, s, latent_width], h the normalised input)."""
+    rows [b, s, latent_width], h the normalised input). `decode`: a
+    decode step asks, as of `proj`: Wqb's is the product that goes
+    reshaped into the kernel (Wqa's and Wkva's go through a norm, and
+    their weights were never re-laid)."""
     b, s, _ = x.shape
     r = cfg.kv_lora_rank
     with jax.named_scope("attn.qkv"):
         h = rms_norm(x, layer["ln1"], cfg.norm_eps)
         cq = rms_norm(matmul(h, layer["wqa"]), layer["q_ln"], cfg.norm_eps)
-        q = matmul(cq, layer["wqb"]).reshape(
-            b, s, cfg.n_heads, cfg.qk_nope + cfg.qk_rope)
+        q = matmul(cq, layer["wqb"])
+        if decode:
+            q = weight_where_it_lies(q)
+        q = q.reshape(b, s, cfg.n_heads, cfg.qk_nope + cfg.qk_rope)
         ckv = matmul(h, layer["wkva"])
         c = rms_norm(ckv[..., :r], layer["kv_ln"], cfg.norm_eps)
     with jax.named_scope("attn.rope"):
@@ -788,8 +818,8 @@ def decode_step(block, params, cfg, token, seq_lens, k_pages, v_pages,
             mi += 1
         elif kind == "latent":
             # ONE pool (k_pages), no V pool: v_pages is None throughout
-            q_nope, q_pe, rows, h_attn = latent_project(layer, x_in, cfg,
-                                                        positions)
+            q_nope, q_pe, rows, h_attn = latent_project(
+                layer, x_in, cfg, positions, decode=True)
             held = pools["full"]
             _, _, table, lens, target_page, slot = held
             with jax.named_scope("pool.update"):
@@ -801,7 +831,8 @@ def decode_step(block, params, cfg, token, seq_lens, k_pages, v_pages,
             li += 1
         else:
             band, rotates, pool, pl = spec[li]
-            q, k, v, h_attn = _qkv(layer, x_in, cfg, positions, rotates)
+            q, k, v, h_attn = _qkv(layer, x_in, cfg, positions, rotates,
+                                   decode=True)
             if cfg.kv_pack > 1:
                 q, k, v = pack_heads(cfg, q, k, v)
             held = pools[pool]

@@ -3,6 +3,7 @@ equivalence, prefix-cached prefill, the m-token step, the window, store
 round-trip of KV pages; and the sharded training step on the virtual
 8-device mesh."""
 
+import contextlib
 import dataclasses
 import uuid
 from types import SimpleNamespace
@@ -548,6 +549,22 @@ def v5e_chip():
     return SingleDeviceSharding(topo.devices[0])
 
 
+@contextlib.contextmanager
+def _the_chips_branch(monkeypatch):
+    """While a program is lowered and compiled for the described chip:
+    `jax.default_backend` answers "tpu", so that the attention wrappers
+    trace the branch the chip takes (the Pallas kernels), and the
+    persistent compile cache is off (a compile for a described device
+    is written to it but cannot be read back without a chip)."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cache = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    try:
+        yield
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cache)
+
+
 def test_hit_program_aliases_the_pools_and_holds_the_restored_pages_once(
         v5e_chip, monkeypatch):
     """The fused hit admission at mistral7b's widths and pool geometry,
@@ -571,11 +588,6 @@ def test_hit_program_aliases_the_pools_and_holds_the_restored_pages_once(
     conf = serve.load_config("benchmark/configs/mistral7b.json")
     model, cfg = serve.model_config(conf)
     sc = conf["serving"]
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    # A compile for a described device is written to the persistent
-    # cache but cannot be read back without a chip.
-    cache = jax.config.jax_enable_compilation_cache
-    jax.config.update("jax_enable_compilation_cache", False)
 
     def sds(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e_chip)
@@ -593,7 +605,7 @@ def test_hit_program_aliases_the_pools_and_holds_the_restored_pages_once(
     restored = sds((n * cfg.n_layers * 2, *cfg.kv_page_shape()), cfg.jdtype)
     kv = sds((1, n * cfg.page_size, cfg.n_kv_heads, cfg.head_dim),
              cfg.jdtype)
-    try:
+    with _the_chips_branch(monkeypatch):
         fused = serving._admit_fused_px.lower(
             params, cfg, sds((1, s_pad), i32), restored, pool, pool,
             sds((n,), i32), sds((sc["max_pages_per_seq"],), i32),
@@ -603,8 +615,6 @@ def test_hit_program_aliases_the_pools_and_holds_the_restored_pages_once(
             params, cfg, sds((1, s_pad), i32), [(kv, kv)] * cfg.n_layers,
             sds((), i32), model=model,
         ).compile().memory_analysis()
-    finally:
-        jax.config.update("jax_enable_compilation_cache", cache)
     pool_bytes = 2 * int(np.prod(pool.shape)) * cfg.jdtype.itemsize
     restored_bytes = int(np.prod(restored.shape)) * cfg.jdtype.itemsize
     assert fused.alias_size_in_bytes >= pool_bytes  # donated, aliased
@@ -616,18 +626,19 @@ def test_hit_program_aliases_the_pools_and_holds_the_restored_pages_once(
         fused.temp_size_in_bytes, bound)
 
 
-def _decode_program(family):
+def _decode_program(family, slots=16):
     """(lower, pools' bytes, one layer-and-kind of the smallest pool,
     attention layers) of the engine's decode program for a family at
     the cells' attention widths (bf16, 128 lanes a cache row, pages of
     16) and a few narrow layers: `lower(sds)` lowers it over
-    ShapeDtypeStructs."""
+    ShapeDtypeStructs, and `lower.layers` are its layers' weights as
+    shapes."""
     import types
 
     from infinistore_tpu import serving
-    from infinistore_tpu.models import cohere, hf, hybrid, smallthinker
+    from infinistore_tpu.models import cohere, hf, hybrid, smallthinker, xing
 
-    slots, total, table = 16, 4096, 192
+    total, table = 4096, 192
     if family == "llama":  # mistral7b, mixtral8x7b: 8 kv heads, group 4
         model = llama
         cfg = llama.LlamaConfig(
@@ -662,6 +673,13 @@ def _decode_program(family):
             sliding_window=4096, max_position_embeddings=16384,
             expert_share={"router_width": 8, "first_expert": 2}),
             page_size=16)
+    elif family == "xing":  # xing4-29b: one latent row a token, 32 heads
+        model = xing
+        cfg = xing.XingConfig(
+            vocab_size=256, d_model=256, n_layers=2, n_heads=32,
+            q_lora_rank=768, kv_lora_rank=512, qk_nope=128, qk_rope=64,
+            v_dim=128, d_ff=128, ffn_dense=256, n_experts=8, top_k=2,
+            page_size=16)
     else:  # smallthinker21b: the group of 7, full and banded layers
         model = smallthinker
         cfg = hf.smallthinker_config_from_hf(types.SimpleNamespace(
@@ -680,16 +698,21 @@ def _decode_program(family):
     page = cfg.kv_page_shape()
     i32 = jnp.int32
 
+    weights = jax.eval_shape(lambda k: model.init_params(k, cfg),
+                             jax.ShapeDtypeStruct((2,), jnp.uint32))
+
     def lower(sds):
         params = jax.tree_util.tree_map(
-            lambda x: sds(x.shape, x.dtype),
-            jax.eval_shape(lambda k: model.init_params(k, cfg),
-                           jax.ShapeDtypeStruct((2,), jnp.uint32)))
+            lambda x: sds(x.shape, x.dtype), weights)
         pool = sds((n_full, total, *page), cfg.jdtype)
         lens, rows = sds((slots,), i32), sds((slots, table), i32)
         if family == "llama":
             return serving._decode_fused.lower(
                 params, cfg, lens, lens, pool, pool, rows, model=model)
+        if family == "xing":  # ONE pool
+            return serving._decode_fused.lower(
+                params, cfg, lens, lens, pool, None, rows, model=model,
+                fetched=True)
         if family == "hybrid":
             state = jax.tree_util.tree_map(
                 lambda x: sds(x.shape, x.dtype),
@@ -702,6 +725,7 @@ def _decode_program(family):
             params, cfg, lens, lens, pool, pool, wpool, wpool,
             (rows, sds((slots, 264), i32), lens), model=model, fetched=True)
 
+    lower.layers = weights["layers"]
     kind = total * int(np.prod(page)) * cfg.jdtype.itemsize
     # a paged-decode kernel a layer and, over routed experts, the
     # gathered expert kernel (ops/pallas_moe_decode.py: one function,
@@ -724,19 +748,185 @@ def test_decode_program_for_the_chip_holds_no_layer_of_the_pool(
     hold a layer-and-kind of a pool for it (a relayout of the whole
     pool was 29.5 ms of a 48 ms step, PERF.md, PRs 25 and 31)."""
     lower, pool_bytes, one_layer_and_kind, n_calls = _decode_program(family)
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    cache = jax.config.jax_enable_compilation_cache
-    jax.config.update("jax_enable_compilation_cache", False)
-    try:
+    with _the_chips_branch(monkeypatch):
         lowered = lower(lambda shape, dtype: jax.ShapeDtypeStruct(
             shape, dtype, sharding=v5e_chip))
         assert lowered.as_text().count("tpu_custom_call") == n_calls
         ma = lowered.compile().memory_analysis()
-    finally:
-        jax.config.update("jax_enable_compilation_cache", cache)
     assert ma.alias_size_in_bytes >= pool_bytes * 0.99  # donated, aliased
     assert ma.temp_size_in_bytes < one_layer_and_kind // 2, (
         ma.temp_size_in_bytes, one_layer_and_kind)
+
+
+def _relaid(text):
+    """[(instruction, dims, bytes)] of every `copy` and `transpose` in
+    the ENTRY computation of a compiled program's text."""
+    import re
+
+    out = []
+    for name, bits, dims in re.findall(
+            r"%(\S+) = [a-z]+(\d+)\[([\d,]+)\]\{[^}]*\} (?:copy|transpose)\(",
+            text[text.index("\nENTRY"):]):
+        dims = tuple(int(d) for d in dims.split(","))
+        out.append((name, dims, int(np.prod(dims)) * int(bits) // 8))
+    return out
+
+
+# The projections whose product goes, reshaped, to a decode kernel or
+# a pool's scatter.
+_PROJECTIONS = ("wq", "wk", "wv", "wqb")
+
+
+@pytest.mark.parametrize("family", ["llama", "hybrid", "smallthinker",
+                                    "cohere", "xing", "planted"])
+def test_decode_program_for_the_chip_copies_no_projection_weight(
+        family, v5e_chip, monkeypatch):
+    """The decode program of each family, compiled for a described v5e:
+    its entry computation holds no `copy` or `transpose` with the shape
+    of a q, k or v projection's weight (xing: Wqb's), and nothing of
+    more than 1 MB at all (the pools are donated and never copied; 1 MB
+    is the 16 rows of 128 query heads re-laid in float32, the most any
+    family's rows come to here). Before PR 43 every such weight was
+    transposed anew in every step, because the kernel's operand layout
+    reached back through the reshape into the dot (decoder.
+    weight_where_it_lies; 604 MB a step at command-a-plus's widths, 805
+    at mistral7b's). xing's Wkvb is still re-laid: the absorbed
+    products are batched over the heads, which lie in the MIDDLE of
+    Wkvb's [rank, heads, width] (PERF.md section 7); the case holds it
+    to that one shape. "planted" is llama's program with the barrier
+    taken out again (at another batch, so that no cached trace of the
+    real form answers): the copies must show, or the cases above prove
+    nothing."""
+    planted = family == "planted"
+    if planted:
+        monkeypatch.setattr(decoder, "weight_where_it_lies", lambda p: p)
+    lower = _decode_program("llama" if planted else family,
+                            slots=8 if planted else 16)[0]
+    with _the_chips_branch(monkeypatch):
+        text = lower(lambda shape, dtype: jax.ShapeDtypeStruct(
+            shape, dtype, sharding=v5e_chip)).compile().as_text()
+    layers = lower.layers
+    weights = {tuple(sorted(layer[w].shape)) for layer in layers
+               for w in _PROJECTIONS if w in layer}
+    assert weights and all(int(np.prod(w)) * 2 >= 1 << 18 for w in weights)
+    relaid = _relaid(text)
+    of_weights = [r for r in relaid if tuple(sorted(r[1])) in weights]
+    if planted:
+        # a q, a k and a v weight a layer
+        assert len(of_weights) == 3 * len(layers), of_weights
+        return
+    assert not of_weights, of_weights
+    known = {tuple(sorted(layers[0]["wkvb"].shape))} \
+        if family == "xing" else set()
+    large = [r for r in relaid
+             if r[2] > 1 << 20 and tuple(sorted(r[1])) not in known]
+    assert not large, large
+
+
+def _tool(name):
+    """tools/<name>.py as a module."""
+    import importlib.util
+    import os
+
+    spec = importlib.util.spec_from_file_location(name, os.path.join(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+        "tools", name + ".py"))
+    tool = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(tool)
+    return tool
+
+
+@pytest.mark.parametrize("family", ["llama", "moe", "hybrid", "smallthinker",
+                                    "xing", "cohere"])
+def test_decode_step_is_bit_equal_to_the_form_without_the_barrier(
+        family, monkeypatch):
+    """The engine's decode program of each family at its tiny
+    configuration, jitted on the CPU over random pools (state, banded
+    pools), both rows active: logits, next tokens and every pool the
+    program returns are bit for bit what the form before PR 43 gives
+    (decoder.weight_where_it_lies taken out). The barrier moves no
+    value; where a backend fused the bias or a scale into the dot in
+    another order, this would show it. The caches are cleared between
+    the two forms and after them: `decode_step` is jitted inside the
+    program, and a cached trace would answer for the other form."""
+    fn, args = _tool("jaxpr_hashes").programs(family)["decode"]
+    keys = iter(jax.random.split(jax.random.PRNGKey(1), 64))
+
+    def filled(x):
+        if x is None or not jnp.issubdtype(x.dtype, jnp.floating):
+            return x
+        return jax.random.normal(next(keys), x.shape).astype(x.dtype)
+
+    params, _, _, *pools, rows = args
+    pools = jax.tree_util.tree_map(filled, pools)
+    table = jnp.arange(1, 17, dtype=jnp.int32).reshape(2, 8)
+    if isinstance(rows, tuple):  # (table, the banded pools' table, base)
+        short = rows[1].shape[1]
+        rows = (table, 1 + jnp.arange(2 * short, dtype=jnp.int32).reshape(
+            2, short) % (pools[2].shape[1] - 1), rows[2])
+    else:
+        rows = table
+    args = (params, jnp.asarray([3, 7], jnp.int32),
+            jnp.asarray([5, 9], jnp.int32), *pools, rows)
+
+    def run():
+        jax.clear_caches()
+        out = jax.jit(fn)(*args)
+        return out, str(jax.make_jaxpr(fn)(*args))
+
+    try:
+        new, text = run()
+        assert "optimization_barrier" in text
+        monkeypatch.setattr(decoder, "weight_where_it_lies", lambda p: p)
+        old, text = run()
+        assert "optimization_barrier" not in text
+    finally:
+        monkeypatch.undo()
+        jax.clear_caches()
+    new, old = (jax.tree_util.tree_leaves(o) for o in (new, old))
+    assert len(new) == len(old) >= 3
+    assert np.isfinite(np.asarray(new[0])).all() and np.asarray(new[0]).any()
+    for a, b in zip(new, old):
+        assert a.dtype == b.dtype
+        np.testing.assert_array_equal(np.asarray(a.astype(jnp.float32)),
+                                      np.asarray(b.astype(jnp.float32)))
+
+
+@pytest.mark.parametrize("where, stage", [
+    ("jit(_decode_fused)/jit(decode_step)/attn.qkv/dot_general fusion.3",
+     "attn.qkv"),
+    ("jit(_decode_fused_wf)/jit(decode_step)/attn.kernel.window/"
+     "pallas_call custom-call.5", "attn.kernel.window"),
+    ("jit(_decode_fused)/jit(decode_step)/moe.experts/jit(gathered_call)/"
+     "while fusion.9", "moe.experts"),
+    ("jit(f)/mlp/attn.out/dot_general fusion.1", "attn.out"),
+    ("jit(_decode_fused)/jit(decode_step)/jit(_take)/gather fusion.2",
+     "no scope"),
+    (" copy.454", "no scope"),
+])
+def test_step_by_scope_files_an_operation_under_its_innermost_stage(
+        where, stage):
+    """tools/step_by_scope.py: the stage of a device operation is the
+    innermost `jax.named_scope` stage name on its `op_name` path (what
+    benchmark/metrics/_scoped_ops.py hands over: the path, a space,
+    the instruction), and a weight's `copy`, which carries none, is
+    named under "no scope". Only runs of the decode programs that lie
+    whole inside the traced window count."""
+    tool = _tool("step_by_scope")
+    assert tool.stage_of(where) == stage
+    ms = 10 ** 6
+    modules = [("jit__decode_fused(1)", 0, 8 * ms),        # before it
+               ("jit__decode_fused(1)", 10 * ms, 8 * ms),
+               ("jit__admit_fused_px(2)", 20 * ms, 15 * ms),
+               ("jit__decode_fused(1)", 40 * ms, 6 * ms)]
+    ops = [(where, 1 * ms, 1 * ms), (where, 11 * ms, 2 * ms),
+           (where, 21 * ms, 9 * ms), (where, 41 * ms, 1 * ms),
+           ("jit(f)/lm_head/dot_general fusion.7", 45 * ms, ms // 2)]
+    found = tool.by_stage(ops, modules, (9 * ms, 60 * ms), ["decode_fused"])
+    assert found["runs"] == 2 and found["step_ms"] == 7.0
+    assert found["stage_ms"] == {stage: 1.5, "lm_head": 0.25}
+    assert found["unscoped_top_ms"] == (
+        {where.rsplit(" ", 1)[-1]: 1.5} if stage == "no scope" else {})
 
 
 def _step_sliced(model, params, cfg, tokens, seq_lens, k_pages, v_pages,
