@@ -335,6 +335,20 @@ class _Upload:
     skipped: bool = False
 
 
+@dataclass
+class _Flight:
+    """A plain decode step that is dispatched and not landed: what
+    `_land` needs when its tokens are pulled. The engine holds at most
+    one (`ServingEngine._flight`); the device arrays the step behind it
+    takes as inputs are the steady cache's (`_steady`)."""
+    active: list              # [(slot index, slot)] it decodes
+    pull: object              # device array the host pulls: the next
+    #                           tokens and the counts that ride in it
+    counted: bool             # ... the experts it fetched among them
+    logits: object            # device logits (a sampling slot's row)
+    ahead: bool               # dispatched before the step before it landed
+
+
 def prompt_lookup_propose(context, k, ngram=2):
     """Draft-model-free proposer (prompt-lookup / n-gram speculation):
     find the most recent earlier occurrence of the context's last
@@ -1140,6 +1154,11 @@ class ServingEngine:
             # finish to its tokens in `outputs`, summed, ms)
             "uploads": 0, "upload_backpressure_waits": 0,
             "done_held_ms": 0.0,
+            # plain decode steps (of decode_steps) whose program was
+            # dispatched before the step before it had landed, and the
+            # rows of such steps that were dropped unseen: their
+            # sequence had ended at an EOS in the step before
+            "decode_steps_ahead": 0, "decode_rows_dropped": 0,
             # admission in pieces: pieces run; a latent family: its
             # pages (a sequence page of one layer each) that offloads
             # wrote to the store and hits restored from it
@@ -1218,6 +1237,9 @@ class ServingEngine:
         # staleness is structural, not heuristic.
         self._steady = None
         self._pages_rev = 0
+        # The plain decode step that is dispatched and not landed
+        # (`_step` sends the next one behind it), or None.
+        self._flight = None
         # The operand of _tick, when idle() last sent it, and when an
         # admission last found no sequence running (_settle).
         self._tick_x = self._to_device(np.int32(0))
@@ -2146,6 +2168,7 @@ class ServingEngine:
         none started the slow mode _settle describes, of those after
         1.0-4.0 s three in four did (PERF.md, PR 29). Returns whether
         this call sent one (the loop's no_work span counts them)."""
+        self.land()
         now = time.monotonic()
         if now - self._ticked < IDLE_TICK_S:
             return False
@@ -2682,7 +2705,8 @@ class ServingEngine:
     def drain_uploads(self):
         """Block until every upload is acknowledged and collected:
         what was offloaded is in the store, what was held is in
-        `outputs`."""
+        `outputs`. A decode step in flight lands first."""
+        self.land()
         while self.uploads_pending:
             self._await_ack()
 
@@ -2692,6 +2716,7 @@ class ServingEngine:
         on a closed handle is a use-after-free (`LayerStreamer.close`
         has the note). The engine stays usable; its next offload
         starts a thread anew."""
+        self.land()
         if self._upload_thread is None:
             return
         self.drain_uploads()
@@ -2703,9 +2728,11 @@ class ServingEngine:
                 "must not be destroyed while it is running")
         self._upload_thread = None
 
-    def _shed_windows(self, active):
+    def _shed_windows(self, active, more=0):
         """Before a decode step of a model with two kinds of attention
-        layer: the banded layers' pages that lie wholly below a slot's
+        layer (`more` = 1: the step BEHIND the one in flight, whose
+        lengths lie one beyond the slots'): the banded layers' pages
+        that lie wholly below a slot's
         band floor (seq_len - band: decode masks below it) are shed,
         written to the store where the store lacks them, then freed,
         and the slot's short table moved up. Not a page an edge a
@@ -2716,10 +2743,10 @@ class ServingEngine:
         (`_release`)."""
         page = self.cfg.page_size
         band = self.cfg.window_band
-        due = [(i, s, (s.seq_len - band) // page) for i, s in active]
+        due = [(i, s, (s.seq_len + more - band) // page) for i, s in active]
         due = [(i, s, dead) for i, s, dead in due if dead > s.wbase]
         if not any(dead - s.wbase >= self._shed_pages
-                   or s.seq_len // page - s.wbase >= self._wtable_w
+                   or (s.seq_len + more) // page - s.wbase >= self._wtable_w
                    for _, s, dead in due):
             return
         self._offload_window(due)
@@ -2846,7 +2873,15 @@ class ServingEngine:
 
     def step(self):
         """One engine iteration: admit into free slots, then decode one
-        token for every active slot. Returns #active slots decoded."""
+        token for every active slot. Returns #active slots decoded.
+
+        A plain decode step runs one step AHEAD wherever the host can
+        prove the next batch from what it holds (`_proven`): step N+1
+        is dispatched, with step N's device outputs as its tokens and
+        lengths, BEFORE the host waits for N's tokens, so the host's
+        round between two steps runs beside a program and the device
+        does not stand idle for it. The contract holds either way: a call lands one token for every active slot, and
+        `decode_steps` / `decoded_tokens` move when a step LANDS."""
         with self._span("istpu.engine.step", kind="idle", active=0,
                         k=0, device=self._device_index) as f:
             c0 = profiling.compilations()
@@ -2860,11 +2895,28 @@ class ServingEngine:
         """step() proper; `f` holds the step span's fields (kind,
         active slots, k)."""
         self.collect_uploads()
-        self._piece_ran = False
-        for i in range(self.sc.max_slots):
-            if self.slots[i] is None and self.queue:
-                if self._admit(i, self.queue[0]):
-                    self.queue.pop(0)
+        if self._flight is not None:
+            # A plain step N is on the device: step N+1 behind it
+            # first, where proven and backed, THEN N's tokens, so that
+            # the host's whole round (the wait's return lag, the emit,
+            # the loop, the bookkeeping, the dispatch) lies beside a
+            # running program. What `_proven` refuses (a finish, a
+            # sampling slot, a piece...) lands here and finds, in the
+            # next call, the synchronous order below; but an admission
+            # keeps the place that order gives it, BEFORE the step's
+            # tokens: its probe, store call and transfer run beside
+            # the program in flight and its own program queues behind
+            # it, so an arrival waits for what is left of one program
+            # and not for its return as well.
+            flight, self._flight = self._flight, None
+            active = flight.active
+            f.update(kind="decode", active=len(active), k=1)
+            if self._proven(active):
+                self._send_behind(active, f)
+            else:
+                self._admit_queued()
+            return self._land_span(flight)
+        self._admit_queued()
         if self.sc.admit_piece:
             self._step_pieces()
 
@@ -2875,11 +2927,7 @@ class ServingEngine:
         # Sequences at max_new_tokens finish BEFORE the step (their last
         # sampled token never needs its KV appended).
         for i, s in list(active):
-            done = s.total_generated() >= s.work.req.max_new_tokens or (
-                self.sc.eos_id >= 0 and s.generated
-                and s.generated[-1] == self.sc.eos_id
-            )
-            if done:
+            if self._done(s):
                 self._finish(i, s)
         active = self._active()
         if not active:
@@ -2948,45 +2996,12 @@ class ServingEngine:
         if not active:
             return 0
 
-        # Steady-state fast path: if the device already holds exactly
-        # this step's inputs (previous fused step's outputs, same active
-        # set, no page-table mutation, pure-greedy slots), skip the
-        # host->device uploads entirely — one dispatch + one 32-byte
-        # D2H per decode step (or per k-step burst). The host-side
-        # input arrays are built ONLY on a cache miss: on the hit path
-        # they were pure per-step waste (built, then discarded for the
-        # cached device copies).
-        key = (tuple(i for i, _ in active), self._pages_rev)
-        steady = (self._steady is not None and greedy
-                  and self._steady[0] == key)
         f.update(kind="burst" if k > 1 else "decode", active=len(active),
-                 k=k, steady=steady)
-        if steady:
-            _, token_dev, lens_dev, rows_dev = self._steady
-        else:
-            token = np.zeros(self.sc.max_slots, dtype=np.int32)
-            seq_lens = np.zeros(self.sc.max_slots, dtype=np.int32)
-            rows = np.zeros_like(self.page_table)  # inactive → scratch 0
-            for i, s in active:
-                token[i] = s.generated[-1]
-                seq_lens[i] = s.seq_len
-                rows[i] = self.page_table[i]
-            token_dev = self._to_device(token)
-            lens_dev = self._to_device(seq_lens)
-            rows_dev = self._to_device(rows)
-            if self._win_layers:
-                # ... and the banded layers' short tables with their
-                # bases (inactive rows: scratch page 0 from position 0)
-                wrows = np.zeros_like(self.wtable)
-                wbase = np.zeros(self.sc.max_slots, dtype=np.int32)
-                for i, s in active:
-                    wrows[i] = self.wtable[i]
-                    wbase[i] = s.wbase * self.cfg.page_size
-                rows_dev = (rows_dev, self._to_device(wrows),
-                            self._to_device(wbase))
-
-        live_pages = self._count_attn_pages(active, k)
+                 k=k)
         if k > 1:
+            key, token_dev, lens_dev, rows_dev = self._step_inputs(
+                active, greedy, f)
+            live_pages = self._count_attn_pages(active, k)
             with self._span("istpu.model.decode", program="decode_scan",
                             live_pages=live_pages) as df:
                 (toks_dev, lens_next, self.k_pages,
@@ -3020,53 +3035,224 @@ class ServingEngine:
             )
             return len(active)
 
+        # The plain step: its two halves, `_dispatch` and `_land`.
+        # Synchronous, they lie under ONE span from the dispatch to the
+        # tokens on the host. Where the step behind is proven (asked
+        # BEFORE the dispatch, of the same host state) a run ahead
+        # begins: the span ends at the dispatch, the step behind goes
+        # out, and then this one lands.
+        proven = self._proven(active)
+        with self._span("istpu.model.decode", program="decode_fused") as df:
+            flight = self._dispatch(active, greedy, f, df)
+            if not proven:
+                return self._land(flight, df)
+        self._send_behind(active, f)
+        return self._land_span(flight)
+
+    def _admit_queued(self):
+        """The queue's head into every free slot, while it admits."""
+        self._piece_ran = False
+        for i in range(self.sc.max_slots):
+            if self.slots[i] is None and self.queue:
+                if self._admit(i, self.queue[0]):
+                    self.queue.pop(0)
+
+    def _done(self, slot, more=0):
+        """Whether `slot` has its last token once `more` further
+        tokens have landed: `max_new_tokens` by count, or an EOS as
+        the last one that landed."""
+        return (slot.total_generated() + more
+                >= slot.work.req.max_new_tokens
+                or (self.sc.eos_id >= 0 and bool(slot.generated)
+                    and slot.generated[-1] == self.sc.eos_id))
+
+    def _proven(self, active):
+        """THE predicate of the run ahead: whether, with a plain step N
+        of `active` dispatched (or about to be) and NOT landed, the
+        host can prove step N+1's batch from what it holds. Then N+1
+        needs nothing of N but its device outputs: tokens and lengths
+        are N's, the page tables follow from lengths the host counts.
+        From what the engine observes alone:
+
+        - no burst and no draft (`host_steps`, `spec_k`): a burst's
+          size and a proposal follow N's tokens;
+        - not a family with recurrent state under `eos_id >= 0`: an
+          EOS that N shows drops the slot's row of N+1, and a boundary
+          copy taken behind N+1 would hold the dropped token's state.
+          A family without state runs ahead under an EOS all the same:
+          the dropped row's KV lies beyond `seq_len` in a page the
+          slot owns, and no offload reads it (`_land`);
+        - the active set is every occupied slot (no admission in
+          pieces under way) and no admission this call could make (a
+          queue head and a free slot): the step behind an admission
+          holds the new sequence too, and an arrival never finds two
+          programs queued before its own;
+        - every slot greedy (a sampler needs N's logits row) and still
+          short of its last token AFTER N's (a count; or an EOS
+          landed).
+
+        The pages of N+1 are `_send_behind`'s, behind N's dispatch."""
+        sc = self.sc
+        if sc.host_steps > 1 or sc.spec_k > 0 \
+                or (sc.eos_id >= 0 and self.state is not None):
+            return False
+        if sum(s is not None for s in self.slots) != len(active) \
+                or (self.queue and len(active) < sc.max_slots):
+            return False
+        return not any(s.work.req.temperature > 0 or self._done(s, 1)
+                       for _, s in active)
+
+    def _send_behind(self, active, f):
+        """Step N+1 behind the step N in flight (`_proven` said it is
+        this batch's), with the lengths it will see, one beyond the
+        slots': its pages as `_step` has them for a step at rest (the
+        banded layers' shed, every slot's page of position `seq_len +
+        1`), then its dispatch, alone under its span; the call is one
+        that ran `ahead`. Where the pool is out nothing goes out: the
+        caller lands N, and the next call's preemption path decides
+        (the pages taken so far stay the slots')."""
+        if self._win_layers:
+            self._shed_windows(active, more=1)
+        if not all(self._ensure_pages(i, s, s.seq_len + 1)
+                   for i, s in active):
+            return
+        with self._span("istpu.model.decode", program="decode_fused") as df:
+            self._flight = self._dispatch(active, True, f, df, more=1)
+        f["ahead"] = True
+
+    def land(self):
+        """The decode step in flight, if any, lands: for whoever stops
+        stepping the engine (`drain_uploads`, `close` and `idle` call
+        it), on the thread that steps it."""
+        if self._flight is not None:
+            flight, self._flight = self._flight, None
+            self._land_span(flight)
+
+    def _land_span(self, flight):
+        """`_land` under a span of its own: all of it is the wait."""
+        with self._span("istpu.model.decode", program="land",
+                        dispatch_ns=0) as df:
+            return self._land(flight, df)
+
+    def _step_inputs(self, active, greedy, f, more=0):
+        """(steady key, tokens, lengths, page tables) on the device
+        for a decode step or burst of `active`.
+
+        Steady-state fast path: if the device already holds exactly
+        this step's inputs (previous fused step's outputs, same active
+        set, no page-table mutation, pure-greedy slots), skip the
+        host->device uploads entirely — one dispatch + one 32-byte
+        D2H per decode step (or per k-step burst). The host-side
+        input arrays are built ONLY on a cache miss: on the hit path
+        they were pure per-step waste (built, then discarded for the
+        cached device copies). `more` = 1: behind the step in flight,
+        whose outputs the steady cache holds; where a slot crossed a
+        page edge only the tables go up anew, tokens and lengths stay
+        the device's (the host has not seen them yet)."""
+        key = (tuple(i for i, _ in active), self._pages_rev)
+        steady = (self._steady is not None and greedy
+                  and self._steady[0] == key)
+        # of every step the call dispatched (two, where a run begins)
+        f["steady"] = f.get("steady", True) and steady
+        f["rows_uploaded"] = not f["steady"]
+        if steady:
+            return self._steady
+        if more:
+            _, token_dev, lens_dev, _ = self._steady
+        else:
+            token = np.zeros(self.sc.max_slots, dtype=np.int32)
+            seq_lens = np.zeros(self.sc.max_slots, dtype=np.int32)
+            for i, s in active:
+                token[i] = s.generated[-1]
+                seq_lens[i] = s.seq_len
+            token_dev = self._to_device(token)
+            lens_dev = self._to_device(seq_lens)
+        rows = np.zeros_like(self.page_table)  # inactive → scratch 0
+        for i, _ in active:
+            rows[i] = self.page_table[i]
+        rows_dev = self._to_device(rows)
+        if self._win_layers:
+            # ... and the banded layers' short tables with their
+            # bases (inactive rows: scratch page 0 from position 0)
+            wrows = np.zeros_like(self.wtable)
+            wbase = np.zeros(self.sc.max_slots, dtype=np.int32)
+            for i, s in active:
+                wrows[i] = self.wtable[i]
+                wbase[i] = s.wbase * self.cfg.page_size
+            rows_dev = (rows_dev, self._to_device(wrows),
+                        self._to_device(wbase))
+        return key, token_dev, lens_dev, rows_dev
+
+    def _dispatch(self, active, greedy, f, df, more=0):
+        """The first half of a plain decode step of `active`: choose
+        the inputs and send the fused program (for a family with
+        state, its boundary copies behind it). `more` = 1: behind a
+        step in flight, so every length lies one beyond the slots'.
+        Returns the step, in flight; `df` is its span's fields."""
+        key, token_dev, lens_dev, rows_dev = self._step_inputs(
+            active, greedy, f, more)
+        df["live_pages"] = self._count_attn_pages(active, more=more)
         sparse = self._experts_held > 0
         pulled = ()  # in place of nxt_dev, where the step counts experts
-        with self._span("istpu.model.decode", program="decode_fused",
-                        live_pages=live_pages) as df:
-            if self._win_layers:
-                (logits, nxt_dev, lens_next, self.k_pages, self.v_pages,
-                 self.wk_pages, self.wv_pages, *pulled) = _decode_fused_wf(
-                    self.params, self.cfg, token_dev, lens_dev,
-                    self.k_pages, self.v_pages, self.wk_pages,
-                    self.wv_pages, rows_dev, model=self.model,
-                    fetched=sparse,
-                )
-            elif self.state is None:
-                (logits, nxt_dev, lens_next, self.k_pages, self.v_pages,
-                 *pulled) = _decode_fused(
-                    self.params, self.cfg, token_dev, lens_dev,
-                    self.k_pages, self.v_pages, rows_dev,
-                    model=self.model, fetched=sparse,
-                )
-            else:
-                (logits, nxt_dev, lens_next, self.k_pages, self.v_pages,
-                 self.state) = _decode_fused_st(
-                    self.params, self.cfg, token_dev, lens_dev,
-                    self.k_pages, self.v_pages, self.state, rows_dev,
-                    model=self.model,
-                )
-                self._copy_boundaries(active)
-            # Dispatched; what is left of the span is the wait.
-            df["dispatch_ns"] = profiling.elapsed_ns()
-            nxt = np.asarray(pulled[0] if pulled else nxt_dev)
-            if pulled:
-                fetched = int(nxt[self.sc.max_slots])
-                df["experts_fetched"] = fetched
-                self.stats["moe_experts_fetched"] += fetched
-                self.stats["moe_experts_held"] += self._experts_held
-            if self._share_layers:
-                df["pairs_held"] = int(nxt[-1])
-                self.stats["moe_pairs_routed"] += (
-                    len(active) * self.cfg.top_k * self._share_layers)
-                self.stats["moe_pairs_held"] += int(nxt[-1])
+        if self._win_layers:
+            (logits, nxt_dev, lens_next, self.k_pages, self.v_pages,
+             self.wk_pages, self.wv_pages, *pulled) = _decode_fused_wf(
+                self.params, self.cfg, token_dev, lens_dev,
+                self.k_pages, self.v_pages, self.wk_pages,
+                self.wv_pages, rows_dev, model=self.model,
+                fetched=sparse,
+            )
+        elif self.state is None:
+            (logits, nxt_dev, lens_next, self.k_pages, self.v_pages,
+             *pulled) = _decode_fused(
+                self.params, self.cfg, token_dev, lens_dev,
+                self.k_pages, self.v_pages, rows_dev,
+                model=self.model, fetched=sparse,
+            )
+        else:
+            (logits, nxt_dev, lens_next, self.k_pages, self.v_pages,
+             self.state) = _decode_fused_st(
+                self.params, self.cfg, token_dev, lens_dev,
+                self.k_pages, self.v_pages, self.state, rows_dev,
+                model=self.model,
+            )
+            self._copy_boundaries(active, more)
         # Reusable next step iff every emitted token is the device's
         # argmax (greedy) — samplers/spec/finishes invalidate via key.
         self._steady = (
             (key, nxt_dev, lens_next, rows_dev) if greedy else None
         )
-        lhost = _LazyHost(logits)
+        # Dispatched; what is left of a synchronous step's span is the
+        # wait.
+        df["dispatch_ns"] = profiling.elapsed_ns()
+        return _Flight(active, pulled[0] if pulled else nxt_dev,
+                       bool(pulled), logits, bool(more))
+
+    def _land(self, flight, df):
+        """The second half of a plain decode step, and the ONE place
+        its tokens reach the host: pull the token array with the
+        counts that ride in it, emit, advance. A row whose sequence
+        ended at an EOS while this step was in flight behind the one
+        that showed it is dropped: never emitted, never counted.
+        Returns the tokens landed."""
+        nxt = np.asarray(flight.pull)
+        active = flight.active
+        if flight.counted:
+            fetched = int(nxt[self.sc.max_slots])
+            df["experts_fetched"] = fetched
+            self.stats["moe_experts_fetched"] += fetched
+            self.stats["moe_experts_held"] += self._experts_held
+        if self._share_layers:
+            df["pairs_held"] = int(nxt[-1])
+            self.stats["moe_pairs_routed"] += (
+                len(active) * self.cfg.top_k * self._share_layers)
+            self.stats["moe_pairs_held"] += int(nxt[-1])
+        lhost = _LazyHost(flight.logits)
+        landed = 0
         for i, s in active:
+            if self._done(s):
+                self.stats["decode_rows_dropped"] += 1
+                continue
             if s.work.req.temperature > 0:
                 tok = self._pick(s.work, lhost()[i])
             else:
@@ -3074,20 +3260,23 @@ class ServingEngine:
             self._emit(s, [tok])
             s.seq_len += 1
             self._release_windowed(s)
-            self.stats["decoded_tokens"] += 1
+            landed += 1
+        self.stats["decoded_tokens"] += landed
         self.stats["decode_steps"] += 1
-        return len(active)
+        self.stats["decode_steps_ahead"] += flight.ahead
+        return landed
 
-    def _count_attn_pages(self, active, k=1):
+    def _count_attn_pages(self, active, k=1, more=0):
         """Count what the paged-decode kernel walks in `k` decode steps
         over `active` into `attn_pages_live` / `attn_pages_table`, from
-        the lengths held here (no device work); returns the live pages
-        of the first step (a scan's k steps count as k of its first)."""
+        the lengths held here plus `more` (no device work); returns the
+        live pages of the first step (a scan's k steps count as k of
+        its first)."""
         page = self.cfg.page_size
         live = 0
         for (pool, band), layers in self._attn_kinds.items():
             for _, s in active:
-                n = s.seq_len + 1  # keys the step attends from 0
+                n = s.seq_len + more + 1  # keys the step attends from 0
                 if pool == "window":
                     n -= s.wbase * page
                 first = max(n - band, 0) // page if band else 0
@@ -3096,18 +3285,20 @@ class ServingEngine:
         self.stats["attn_pages_table"] += k * self._attn_table
         return live
 
-    def _copy_boundaries(self, active):
-        """Behind a decode step of a family with state: the boundary
+    def _copy_boundaries(self, active, more=0):
+        """Behind a decode step of a family with state (whose lengths
+        lay `more` beyond the slots'): the boundary
         copy of every slot whose sequence the step brought to a page
         edge (its state now is the state at the end of a full page,
         which is where its stored pages can end). One small program a
         crossing slot, dispatched and not waited for."""
         page = self.cfg.page_size
         for i, s in active:
-            if (s.seq_len + 1) % page == 0:
+            pos = s.seq_len + more + 1
+            if pos % page == 0:
                 with self._span("istpu.cache.snapshot",
                                 s.work.req.request_id, slot=i,
-                                pos=s.seq_len + 1, reason="boundary"):
+                                pos=pos, reason="boundary"):
                     self.bstate = _copy_boundary(
                         self.state, self.bstate, self._slot_dev(i))
                 self.stats["boundary_copies"] += 1

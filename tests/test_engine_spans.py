@@ -478,11 +478,14 @@ def test_an_engine_left_without_work_records_one_no_work_a_spell(
 
 
 def test_steps_say_whether_their_inputs_were_on_the_device(params, cfg):
-    """`steady` on a plain step: false where the inputs were rebuilt
-    and uploaded (the first step after an admission; a finish changes
-    the active set too), true where the device still held them; and a
-    decode span's `dispatch_ns`, the start of the span to the return of
-    the dispatch, lies inside it."""
+    """`steady` on a plain step that dispatched: false where inputs
+    were rebuilt and uploaded (the first step after an admission; a
+    finish changes the active set too; behind a step in flight the
+    tables alone, where a slot took a new page), true where the device
+    still held them; a call that only lands the step a run ends with
+    dispatched nothing and says nothing; and a decode span's
+    `dispatch_ns`, the start of the span to the return of the dispatch,
+    lies inside it."""
     eng = _engine(params, cfg, None, "spans-steady")
     spans = _run(eng, Request("a", _prompt(51, PAGE + 2), max_new_tokens=6))
     t0 = time.time_ns()
@@ -494,20 +497,26 @@ def test_steps_say_whether_their_inputs_were_on_the_device(params, cfg):
     by_id = {s.id: s for s in spans}
     steps = [s for s in _named(spans, "istpu.engine.step")
              if s.fields["kind"] == "decode"]
-    assert [s.fields["steady"] for s in steps[:5]] == [False] + [True] * 4
+    # "a" alone: its first step began a run, the last landed it
+    assert [s.fields.get("steady") for s in steps[:5]] \
+        == [False] + [True] * 3 + [None]
     for st in steps:
         admitted = bool([k for k in _children(spans, st)
                          if k.name == "istpu.sched.admit"])
-        assert not (admitted and st.fields["steady"])
-    # "b" was admitted, "c" beside it, "c" left before it, and "b" took
-    # a second page at its 17th token: four steps rebuilt their inputs,
-    # and the steps between held them.
-    later = [s.fields["steady"] for s in steps[5:]]
-    assert later.count(False) == 4 and later.count(True) >= 4
+        assert not (admitted and st.fields.get("steady"))
+        assert st.fields.get("rows_uploaded") == (
+            None if "steady" not in st.fields else not st.fields["steady"])
+    # "b" was admitted; "c" was, beside the step in flight, which then
+    # landed; a step of both began a run; "c"'s budget ended it; it
+    # left; "b" took a second page at its 17th token (the
+    # tables alone went up, behind a step in flight); one step held
+    # its inputs; "b"'s budget ended the run.
+    later = [s.fields.get("steady") for s in steps[5:]]
+    assert later == [False, None, False, None, False, False, True, None]
     assert "steady" not in _named(spans, "istpu.engine.step",
                                   kind="idle")[0].fields
-    decodes = _named(spans, "istpu.model.decode")
-    assert len(decodes) == len(steps)
+    decodes = _named(spans, "istpu.model.decode", program="decode_fused")
+    assert len(decodes) == len(steps) == eng.stats["decode_steps"]
     for d in decodes:
         assert _less_dispatch(d).keys() == {"program", "live_pages"}
         assert by_id[d.parent].fields["kind"] == "decode"
@@ -720,8 +729,9 @@ def test_step_kinds_burst_unified_spec(params, cfg):
 
     assert kinds(host_steps=4) == ({"burst", "decode"},
                                    {"decode_scan", "decode_fused"})
+    # behind the chunks the plain steps run ahead: a dispatch, a wait
     assert kinds(prefill_chunk=PAGE) == ({"unified", "decode"},
-                                         {"verify", "decode_fused"})
+                                         {"verify", "decode_fused", "land"})
     k, p = kinds(spec_k=3)
     assert "spec" in k and "verify" in p
 
@@ -1130,3 +1140,121 @@ def test_threads_record_while_the_ring_is_read():
         assert [s.fields["i"] for s in outer] == list(range(n_each))
         assert all(inner[s.id].fields["i"] == s.fields["i"]
                    and inner[s.id].request == f"w{k}" for s in outer)
+
+
+# ---- a plain decode step runs one step ahead (PR 44) ----
+
+
+def test_a_run_ahead_call_is_a_dispatch_then_a_wait(params, cfg):
+    """A call that ran ahead is a kind-`decode` step with `ahead=True`
+    whose last two `istpu.model.decode` children are the dispatch of
+    the step behind alone (`dispatch_ns`, `program`, `live_pages`),
+    ending before the second begins, and the wait for the step in
+    flight (`program="land"`). The call that BEGINS a run dispatched
+    its own step first, under a span of the same form; the call that
+    ENDS one only waits. The two counters are what the spans count."""
+    eng = _engine(params, cfg, None, "spans-ahead", max_slots=3)
+    spans = _run(eng, Request("a", _prompt(61, PAGE + 3), max_new_tokens=12),
+                 Request("b", _prompt(62, PAGE + 5), max_new_tokens=7))
+    steps = [s for s in _named(spans, "istpu.engine.step")
+             if s.fields["kind"] == "decode"]
+    ahead = [s for s in steps if s.fields.get("ahead")]
+    assert eng.stats["decode_steps"] == len(steps) == 11
+    # two runs (one ends with b's budget), each but its last step
+    assert eng.stats["decode_steps_ahead"] == len(ahead) == 9
+    assert eng.stats["decode_rows_dropped"] == 0
+    shapes = []
+    for st in steps:
+        kids = [k for k in _children(spans, st)
+                if k.name == "istpu.model.decode"]
+        shapes.append("".join("w" if k.fields["program"] == "land" else "d"
+                              for k in kids))
+        for k in kids:
+            if k.fields["program"] == "land":
+                assert k.fields == {"program": "land", "dispatch_ns": 0}
+            else:
+                assert _less_dispatch(k).keys() == {"program", "live_pages"}
+                assert k.fields["program"] == "decode_fused"
+                # the dispatch alone: it returned as the span ended
+                assert k.dur_ns - k.fields["dispatch_ns"] < SLACK_NS
+        for a, b in zip(kids, kids[1:]):
+            assert a.t0_ns + a.dur_ns <= b.t0_ns + SLACK_NS
+        # the step's own host work is what no child covers
+        assert sum(k.dur_ns for k in kids) < st.dur_ns + SLACK_NS
+        assert bool(st.fields.get("ahead")) == shapes[-1].endswith("dw")
+        if "steady" in st.fields:
+            assert st.fields["rows_uploaded"] == (not st.fields["steady"])
+        else:
+            assert shapes[-1] == "w"
+    # d: a dispatch alone, w: a wait alone
+    assert shapes == ["ddw"] + ["dw"] * 4 + ["w"] + ["ddw"] + ["dw"] * 3 + ["w"]
+    # b crossed a page edge at its 4th token, a at its 6th: the tables
+    # went up anew behind a step in flight, tokens and lengths did not
+    assert [s.fields["rows_uploaded"] for s in ahead].count(True) >= 3
+
+
+def test_the_span_readers_over_synchronous_and_run_ahead_steps():
+    """`benchmark/metrics/_idle_by_span.lead_and_lag` and
+    `decode_host_p50_ms.value`, as they stand, over a hand-made ring of
+    one synchronous plain step and three run-ahead ones with the
+    device's programs beside them: lead and lag come from the
+    synchronous step alone (a run-ahead call's program starts inside
+    its WAIT, not inside its first decode span), the host time from
+    all four."""
+    from benchmark.metrics import _idle_by_span, decode_host_p50_ms
+
+    ms = 1_000_000
+    ids = iter(range(1, 100))
+
+    def step(t0, dur, kids, **fields):
+        """One step and its decode children [(offset, dur, fields)],
+        as the ring holds them: a span enters as it ends."""
+        sid = next(ids)
+        out = [profiling.Span(next(ids), sid, "istpu.model.decode",
+                              t0 + at, d, 1, None, 1, f)
+               for at, d, f in kids]
+        return out + [profiling.Span(sid, 0, "istpu.engine.step", t0, dur,
+                                     1, None, 1,
+                                     dict(kind="decode", active=2, k=1,
+                                          **fields))]
+
+    sent = dict(program="decode_fused", live_pages=4)
+    land = dict(program="land", dispatch_ns=0)
+    ring = (
+        # synchronous: dispatch to tokens in ONE span; its program runs
+        # [1.0, 4.0) ms, the tokens are there at 4.7
+        step(0, 5 * ms, [(300_000, 4_400_000,
+                          dict(sent, dispatch_ns=200_000))],
+             steady=False, rows_uploaded=True)
+        # begins a run: its own step's dispatch (the program runs [6.0,
+        # 9.0)), the dispatch of the one behind it, then the wait
+        + step(5 * ms, 5 * ms, [(300_000, 200_000,
+                                 dict(sent, dispatch_ns=200_000)),
+                                (700_000, 200_000,
+                                 dict(sent, dispatch_ns=200_000)),
+                                (1 * ms, 3_700_000, land)],
+               steady=False, ahead=True, rows_uploaded=True)
+        # two in the middle of it: programs [9, 12) and [12, 15) start
+        # as the one before ends, inside the call's wait
+        + step(10 * ms, 3 * ms, [(400_000, 200_000,
+                                  dict(sent, dispatch_ns=200_000)),
+                                 (700_000, 2 * ms, land)],
+               steady=True, ahead=True, rows_uploaded=False)
+        + step(13 * ms, 3 * ms, [(400_000, 200_000,
+                                  dict(sent, dispatch_ns=200_000)),
+                                 (700_000, 2 * ms, land)],
+               steady=True, ahead=True, rows_uploaded=False))
+    modules = [("jit__decode_fused", 1 * ms, 3 * ms),
+               ("jit__decode_fused", 6 * ms, 3 * ms),
+               ("jit__decode_fused", 9 * ms, 3 * ms),
+               ("jit__decode_fused", 12 * ms, 3 * ms),
+               ("jit__decode_fused", 15 * ms, 3 * ms)]
+    lead, lag = _idle_by_span.lead_and_lag(ring, modules, 0, 20 * ms,
+                                           ("decode_fused",))
+    assert lead == [1 * ms] and lag == [700_000]
+
+    class Obs:
+        window = (0.0, 0.02)
+
+    # step less ALL its decode children: 0.6, 0.9, 0.8, 0.8 ms
+    assert decode_host_p50_ms.value(Obs(), ring) == pytest.approx(0.8)

@@ -1492,3 +1492,268 @@ def test_eviction_race_during_prefetch_degrades_to_miss(
     cold = ServingEngine(params, cfg)
     ref = cold.run([Request("x", turn2, max_new_tokens=6)])
     assert out2["t2"] == ref["x"]
+
+
+# ---- a plain decode step runs one step ahead (PR 44) ----
+# Token for token, the engine as it is against the same engine held
+# synchronous (the one predicate, `_proven`, patched to False), on the
+# five families at tiny widths.
+
+
+@pytest.fixture(scope="module")
+def families(cfg, params):
+    """name -> (model module, config, params, prompt length that makes
+    a sequence pass what the family sheds), built on first use."""
+    import types
+
+    from test_hybrid_state import CONF as STATE
+    from test_latent import CONF as LATENT
+    from test_window_full import CONF as BANDED
+
+    from infinistore_tpu.models import hf, hybrid, moe, smallthinker, xing
+
+    def sparse():
+        c = moe.MoEConfig(
+            vocab_size=64, d_model=32, n_layers=2, n_heads=4, n_kv_heads=2,
+            d_ff=64, n_experts=4, top_k=2, max_seq=128, page_size=8,
+            dtype="float32", capacity_factor=4.0)
+        return moe, c, moe.init_params(jax.random.PRNGKey(3), c), 8
+
+    def bridged(model, bridge, conf, base):
+        c = bridge(types.SimpleNamespace(**conf), page_size=8,
+                   dtype="float32")
+        return model, c, model.init_params(jax.random.PRNGKey(0), c), base
+
+    makers = {
+        "llama": lambda: (llama, cfg, params, 8),
+        "moe": sparse,
+        "state": lambda: bridged(hybrid, hf.hybrid_config_from_hf, STATE, 8),
+        # a band of 4 pages: 40 tokens and more shed banded pages
+        "banded": lambda: bridged(smallthinker,
+                                  hf.smallthinker_config_from_hf, BANDED, 40),
+        "latent": lambda: bridged(xing, hf.xing_config_from_hf, LATENT, 8),
+    }
+    made = {}
+
+    def family(name):
+        if name not in made:
+            made[name] = makers[name]()
+        return made[name]
+    return family
+
+
+class _Pair:
+    """The engine as it is and the same engine held synchronous, each
+    with the on_token streams it fired."""
+
+    def __init__(self, family, store=None, **sc):
+        self.model, self.cfg, self.params, self.base = family
+        sc.setdefault("max_slots", 3)
+        sc.setdefault("total_pages", 64)
+        sc.setdefault("max_pages_per_seq", 16)
+        self.engines, self.fired = {}, {}
+        for name in ("ahead", "sync"):
+            self.engines[name] = ServingEngine(
+                self.params, self.cfg,
+                ServingConfig(model_id=f"{name}-{id(self)}", **sc),
+                store=store, model=self.model)
+            self.fired[name] = []
+        self.engines["sync"]._proven = lambda active: False
+
+    def prompt(self, seed, more):
+        rng = np.random.default_rng(seed)
+        return _prompt(rng, self.cfg, self.base + more)
+
+    def play(self, name, script, between=None):
+        """Step engine `name` through `script`: [(the count of landed
+        decode steps at which the request is due, request fields)]."""
+        eng, fired = self.engines[name], self.fired[name]
+        due = sorted(script, key=lambda e: e[0])
+        while due or eng.queue or any(s is not None for s in eng.slots):
+            idle = not eng.queue and all(s is None for s in eng.slots)
+            while due and (idle or eng.stats["decode_steps"] >= due[0][0]):
+                kw = dict(due.pop(0)[1])
+                eng.submit(Request(
+                    kw.pop("rid"), kw.pop("prompt"),
+                    on_token=lambda rid, t: fired.append((rid, t)), **kw))
+                idle = False
+            eng.step()
+            if between is not None:
+                between(eng)
+        eng.drain_uploads()
+        return dict(eng.outputs)
+
+    def same(self, script, between=None):
+        """Both engines through `script`: the same tokens, each
+        request's stream its output, in order and number."""
+        ahead = self.play("ahead", script, between)
+        sync = self.play("sync", script)
+        assert ahead == sync and all(sync.values())
+        for name, out in (("ahead", ahead), ("sync", sync)):
+            fired, stats = self.fired[name], self.engines[name].stats
+            for rid, tokens in out.items():
+                assert [t for r, t in fired if r == rid] == tokens
+            assert len(fired) == sum(map(len, out.values()))
+            assert stats["decode_steps_ahead"] <= stats["decode_steps"]
+        a, s = self.engines["ahead"].stats, self.engines["sync"].stats
+        assert s["decode_steps_ahead"] == 0 and s["decode_rows_dropped"] == 0
+        assert a["decoded_tokens"] == s["decoded_tokens"]
+        assert a["preemptions"] == s["preemptions"]
+        return ahead
+
+
+def _ahead_steps(eng):
+    return [s for s in profiling.spans()
+            if s.name == "istpu.engine.step" and s.engine == eng.engine_id
+            and s.fields.get("ahead")]
+
+
+def _ahead_admitted_while_others_decode(pair, _):
+    """... into a free slot, and (the latent family) in pieces."""
+    script = [(0, dict(rid="a", prompt=pair.prompt(1, 1), max_new_tokens=14)),
+              (0, dict(rid="b", prompt=pair.prompt(2, 3), max_new_tokens=14)),
+              (4, dict(rid="c", prompt=pair.prompt(3, 30),
+                       max_new_tokens=9))]
+    pair.same(script)
+    eng = pair.engines["ahead"]
+    # ahead before the admission and after it, not across it
+    assert 0 < eng.stats["decode_steps_ahead"] < eng.stats["decode_steps"]
+    if eng.sc.admit_piece:
+        assert eng.stats["admit_pieces"] >= 2
+
+
+def _ahead_finish_while_others_go_on(pair, _):
+    pair.same([(0, dict(rid=f"f{n}", prompt=pair.prompt(10 + n, 2),
+                        max_new_tokens=n)) for n in (4, 9, 15)])
+    eng = pair.engines["ahead"]
+    assert 0 < eng.stats["decode_steps_ahead"] < eng.stats["decode_steps"]
+
+
+def _ahead_page_edges_on_different_steps(pair, _):
+    """A step whose tables changed still runs ahead: only they go up."""
+    pair.same([(0, dict(rid=f"e{more}", prompt=pair.prompt(20 + more, more),
+                        max_new_tokens=22)) for more in (1, 3, 6)])
+    eng = pair.engines["ahead"]
+    steps = _ahead_steps(eng)
+    assert any(s.fields["rows_uploaded"] for s in steps)
+    assert any(s.fields["steady"] for s in steps)
+    # all but the first step; the last ends every budget
+    assert eng.stats["decode_steps_ahead"] == eng.stats["decode_steps"] - 1
+    if eng._win_layers:
+        assert eng.stats["window_pages_released"] > 0
+    if eng.state is not None:
+        assert eng.stats["boundary_copies"] >= 3
+
+
+def _ahead_pool_runs_out(pair, _):
+    out = pair.same([(0, dict(rid=f"p{n}", prompt=pair.prompt(30 + n, n),
+                              max_new_tokens=30)) for n in (1, 2, 3)])
+    assert pair.engines["ahead"].stats["preemptions"] > 0
+    assert all(len(t) == 30 for t in out.values())
+
+
+def _ahead_sampler_joins_greedy(pair, _):
+    script = [(0, dict(rid="g0", prompt=pair.prompt(40, 1),
+                       max_new_tokens=20)),
+              (0, dict(rid="g1", prompt=pair.prompt(41, 4),
+                       max_new_tokens=20)),
+              (3, dict(rid="s", prompt=pair.prompt(42, 2), max_new_tokens=6,
+                       temperature=0.9, top_k=8, seed=7))]
+    pair.same(script)
+    eng = pair.engines["ahead"]
+    # synchronous while the sampler is there, ahead before and after
+    assert 0 < eng.stats["decode_steps_ahead"] <= eng.stats["decode_steps"] - 5
+
+
+def _ahead_eos_in_mid_answer(pair, store):
+    """The row behind an EOS is dropped unseen; what the store holds is
+    what the synchronous engine wrote. A family with state stays
+    synchronous under an EOS."""
+    prompts = {"x": pair.prompt(50, 2), "y": pair.prompt(51, 5)}
+    plain = ServingEngine(pair.params, pair.cfg, ServingConfig(
+        max_slots=3, total_pages=64, max_pages_per_seq=16), model=pair.model)
+    free = plain.run([Request(r, p, max_new_tokens=12)
+                      for r, p in prompts.items()])
+    # an EOS that first shows in mid-answer of x; the tiny family with
+    # state repeats one token a prompt, so there y's, which x never says
+    ends, at = "x", next((i for i in range(3, 11)
+                          if free["x"][i] not in free["x"][:i]), None)
+    if at is None:
+        ends, at = "y", 0
+    eos = free[ends][at]
+    ended = _Pair((pair.model, pair.cfg, pair.params, pair.base),
+                  store=store, eos_id=eos)
+    out = ended.same([(0, dict(rid=r, prompt=p, max_new_tokens=12))
+                      for r, p in prompts.items()])
+    assert out[ends] == free[ends][:at + 1]
+    assert len(out["x"]) > 8 or ends == "x"
+    a = ended.engines["ahead"].stats
+    if ended.engines["ahead"].state is not None:
+        assert a["decode_steps_ahead"] == 0 and a["decode_rows_dropped"] == 0
+    else:
+        assert a["decode_steps_ahead"] > 0 and a["decode_rows_dropped"] >= 1
+    # read back through a fresh engine of each namespace
+    rows = {}
+    for name, eng in ended.engines.items():
+        assert eng.stats["offloaded_pages"] > 0
+        reader = ServingEngine(pair.params, pair.cfg, eng.sc, store=store,
+                               model=pair.model)
+        rows[name] = reader.first_token_logits(prompts["x"] + out["x"])
+    assert rows["ahead"][1] == rows["sync"][1] > 0
+    assert np.array_equal(rows["ahead"][0], rows["sync"][0])
+
+
+def _ahead_close_and_drain_with_a_step_in_flight(pair, _):
+    """Whoever stops stepping lands the step in flight first."""
+    stops = iter(("drain_uploads", "close", "idle"))
+    landed = []
+
+    def between(eng):
+        if eng._flight is not None and eng.stats["decode_steps"] % 4 == 2:
+            stop = next(stops, None)
+            if stop is not None:
+                before = eng.stats["decode_steps"]
+                getattr(eng, stop)()
+                assert eng._flight is None
+                assert eng.stats["decode_steps"] == before + 1
+                landed.append(stop)
+    pair.same([(0, dict(rid=f"d{n}", prompt=pair.prompt(60 + n, n),
+                        max_new_tokens=18)) for n in (1, 2)], between)
+    assert landed == ["drain_uploads", "close", "idle"]
+
+
+def _ahead_on_token_order_and_count(pair, _):
+    """Every request's stream in order and none twice (`same`), with
+    arrivals at every third step; submitted together, the two engines
+    fire ONE stream (an arrival joins the step BEHIND the one in
+    flight, so its later tokens interleave one step on)."""
+    out = pair.same([(3 * n, dict(rid=f"o{n}", prompt=pair.prompt(70 + n, n),
+                                  max_new_tokens=8 + n)) for n in range(5)])
+    fired = pair.fired["ahead"]
+    assert len(fired) == sum(8 + n for n in range(5)) and len(out) == 5
+    again = _Pair((pair.model, pair.cfg, pair.params, pair.base))
+    again.same([(0, dict(rid=f"t{n}", prompt=pair.prompt(80 + n, n),
+                         max_new_tokens=6 + n)) for n in range(3)])
+    assert again.fired["ahead"] == again.fired["sync"]
+
+
+@pytest.mark.parametrize("case", [
+    _ahead_admitted_while_others_decode, _ahead_finish_while_others_go_on,
+    _ahead_page_edges_on_different_steps, _ahead_pool_runs_out,
+    _ahead_sampler_joins_greedy, _ahead_eos_in_mid_answer,
+    _ahead_close_and_drain_with_a_step_in_flight,
+    _ahead_on_token_order_and_count,
+], ids=lambda f: f.__name__[len("_ahead_"):])
+@pytest.mark.parametrize("name", ["llama", "moe", "state", "banded", "latent"])
+def test_a_step_ahead_gives_the_synchronous_engines_tokens(families, name,
+                                                           case, shm_conn):
+    from infinistore_tpu.tpu import TpuKVStore
+
+    family = families(name)
+    sc = {}
+    if case is _ahead_pool_runs_out:
+        # room for two of the three sequences' 30 tokens
+        sc["total_pages"] = 2 * (-(-(family[3] + 33) // 8)) + 1
+    if name == "latent" and case is _ahead_admitted_while_others_decode:
+        sc["admit_piece"] = 16
+    case(_Pair(family, **sc), TpuKVStore(shm_conn))
