@@ -515,7 +515,12 @@ def check_offload_ownership(eng, conn, cfg):
     k_new = jax.random.normal(kk, shape, cfg.jdtype)
     v_new = jax.random.normal(kv, shape, cfg.jdtype)
     ids = eng._alloc(n)[::-1]
-    eng._pool_write(ids, k_new, v_new)
+    at = jax.numpy.asarray(ids)
+    # in place (the pools donated), as an admission's scatter writes
+    write = jax.jit(lambda k, v, k_new, v_new: (
+        k.at[:, at].set(k_new), v.at[:, at].set(v_new)),
+        donate_argnums=(0, 1))
+    eng.k_pages, eng.v_pages = write(eng.k_pages, eng.v_pages, k_new, v_new)
     # page-major (page, layer, k then v): the order of an offload's rows
     want = np.swapaxes(np.stack([np.asarray(k_new), np.asarray(v_new)],
                                 axis=2), 0, 1)
@@ -536,8 +541,9 @@ def check_offload_ownership(eng, conn, cfg):
     try:
         eng._finish(0, slot)
         freed = set(ids) <= set(eng.free_pages)
-        eng._pool_write(ids, jax.numpy.zeros_like(k_new),
-                        jax.numpy.zeros_like(v_new))
+        eng.k_pages, eng.v_pages = write(
+            eng.k_pages, eng.v_pages, jax.numpy.zeros_like(k_new),
+            jax.numpy.zeros_like(v_new))
         jax.block_until_ready((eng.k_pages, eng.v_pages))
         held_back = eng.collect_uploads() == 0 and not eng.outputs
     finally:

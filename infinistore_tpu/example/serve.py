@@ -1,5 +1,5 @@
 """Continuous-batching serving demo: the full engine loop over the
-store — multi-turn prefix caching, chunked prefill, speculative
+store — multi-turn prefix caching, admission in pieces, speculative
 decoding — against a live server.
 
 Run a server first (`python -m infinistore_tpu.server --service-port
@@ -11,7 +11,7 @@ What it shows, in order:
    finished sequences offload their KV pages to the store.
 2. Turn 2: conversations extend their turn-1 prompts — admission HITS
    the cached pages (content-addressed keys), restores them, and
-   prefills only the new tokens, in bounded chunks.
+   prefills only the new tokens, a piece an engine step.
 3. Speculation: a repetitive prompt decodes with prompt-lookup drafts
    accepted several-at-a-time.
 4. With --http-port: the engine goes ONLINE — an HTTP front end
@@ -67,9 +67,10 @@ def run(host, port, http_port=None, http_demo_requests=False):
     )
     print(f"turn 1: {len(out1)} requests through 2 slots; {fmt(eng.stats)}")
 
-    # -- turn 2: prefix-cache HIT + chunked prefill --------------------
+    # -- turn 2: prefix-cache HIT + admission in pieces ----------------
     eng2 = ServingEngine(
-        params, cfg, ServingConfig(max_slots=2, prefill_chunk=8),
+        params, cfg,
+        ServingConfig(max_slots=2, admit_piece=cfg.page_size),
         store=store,
     )
     turn2 = []
@@ -80,7 +81,7 @@ def run(host, port, http_port=None, http_demo_requests=False):
             Request(
                 f"conv{i}",
                 convo[:keep]
-                + [int(t) for t in rng.integers(0, cfg.vocab_size, 6)],
+                + [int(t) for t in rng.integers(0, cfg.vocab_size, 22)],
                 max_new_tokens=8,
             )
         )
@@ -88,10 +89,11 @@ def run(host, port, http_port=None, http_demo_requests=False):
     hits = eng2.stats["prefix_hit_pages"]
     print(
         f"turn 2: {hits} pages/layer-batch restored from the store, "
-        f"only {eng2.stats['prefill_tokens']} tokens prefilled "
-        f"(chunked); {fmt(eng2.stats)}"
+        f"only {eng2.stats['prefill_tokens']} tokens prefilled, in "
+        f"{eng2.stats['admit_pieces']} pieces; {fmt(eng2.stats)}"
     )
     assert hits > 0, "expected turn-2 prefix hits"
+    assert eng2.stats["admit_pieces"] > 0, "expected admission in pieces"
 
     # -- speculation on a repetitive prompt ----------------------------
     block = [int(t) for t in rng.integers(0, cfg.vocab_size, 6)]

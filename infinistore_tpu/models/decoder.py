@@ -871,7 +871,7 @@ def decode_step(block, params, cfg, token, seq_lens, k_pages, v_pages,
 def verify_step(block, params, cfg, tokens, seq_lens, k_pages, v_pages,
                 page_table, valid_len=None):
     """m-token decode over paged KV — speculative decoding's verify
-    step (and the chunked-prefill inner step). Consumes m tokens per
+    step. Consumes m tokens per
     sequence in ONE pass and returns next-token logits at every one of
     the m positions, exactly as if `decode_step` had run m times.
 
@@ -899,8 +899,7 @@ def verify_step(block, params, cfg, tokens, seq_lens, k_pages, v_pages,
     positions >= the accepted seq_len, which later steps overwrite
     before attending (attention is masked by per-token length). A
     recurrent state has no such rollback, so a family with state
-    layers is refused here (speculation and chunked prefill over state
-    are not built).
+    layers is refused here (speculation over state is not built).
     """
     if "mamba" in cfg.layer_kinds:
         raise NotImplementedError(
@@ -914,8 +913,8 @@ def verify_step(block, params, cfg, tokens, seq_lens, k_pages, v_pages,
         raise NotImplementedError("verify_step over packed kv heads")
     if cfg.two_kinds:
         raise NotImplementedError(
-            "verify_step over full and banded layers: speculation and "
-            "chunked prefill over two kinds of page are not built")
+            "verify_step over full and banded layers: speculation over "
+            "two kinds of page is not built")
     b, m = tokens.shape
     x = embed(params, tokens, cfg)  # [b, m, d]
     positions = seq_lens[:, None] + jnp.arange(m)[None, :]

@@ -47,8 +47,7 @@ def scatter_kv_multi(pages, new_kv, page_indices, start_in_page,
                      layer=None):
     """Multi-token variant: write `new_kv` [batch, m, n_kv, hd] at
     (page_indices[b, j], start_in_page[b, j]) — the m tokens of a
-    speculative-verify or chunked-prefill step. Out-of-range page ids
-    are dropped.
+    speculative-verify step. Out-of-range page ids are dropped.
 
     pages: one layer [n_pages, page, n_kv, hd], or with `layer` (static
     int) the whole pool [n_layers, n_pages, page, n_kv, hd]: the rows go
@@ -122,7 +121,7 @@ def prefill_attention(q, k, v, causal=True, window=0):
 def multi_token_paged_attention(q, k_pages, v_pages, page_table, seq_lens,
                                 window=0, layer=None):
     """m-token decode attention over paged KV — the verify step of
-    speculative decoding and the inner op of chunked prefill.
+    speculative decoding.
 
     q:          [batch, m, n_heads, hd] — m new tokens per sequence,
                 whose KV has ALREADY been scattered into the pages at

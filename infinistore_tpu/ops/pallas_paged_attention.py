@@ -622,8 +622,8 @@ def paged_flash_decode_quantized(q, k_q, k_s, v_q, v_s, page_table,
 def _kernel_multi(page_tbl_ref, seq_lens_ref, q_ref, k_ref, v_ref, o_ref,
                   acc_ref, m_ref, l_ref, *, page_size, n_kv, hd, group,
                   m_tok, scale, window=0):
-    """m-token verify attention over paged KV (speculative verify /
-    chunked prefill). Query rows are laid out kv-head-major —
+    """m-token verify attention over paged KV (speculative verify).
+    Query rows are laid out kv-head-major —
     row = h * (m_tok * group) + j * group + g for token j, query head
     h*group+g — so each kv head's dot covers all m tokens' heads in one
     MXU op; the causal limit is per ROW: token j sees positions

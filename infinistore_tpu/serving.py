@@ -190,12 +190,6 @@ class ServingConfig:
     #                              dispatch amortization. Bursts are
     #                              power-of-2 bucketed so the jit cache
     #                              stays O(log host_steps)
-    prefill_chunk: int = 0       # chunked prefill (0 = off): admission
-    #                              consumes the prompt <= chunk tokens
-    #                              per engine step in a MIXED batch with
-    #                              decoding slots, so a long prompt
-    #                              never stalls other sequences' decode
-    #                              (vLLM-style chunked prefill)
     admit_piece: int = 0         # admission in pieces (0 = off): tokens,
     #                              a page multiple. A prompt whose
     #                              uncached part is longer is admitted a
@@ -286,15 +280,12 @@ class _Slot:
     #                           (one sha256 update per page per slot; see
     #                           _slot_digests)
     generated: list = field(default_factory=list)
-    pending: list = field(default_factory=list)  # prompt tokens not yet
-    #                                              prefilled (chunked
-    #                                              prefill phase)
     index: int = -1           # the slot it sits in: its row of the
     #                           state pools (families with state)
     todo: list = field(default_factory=list)  # prompt tokens whose pieces
     #                           are still to run (admission in pieces):
     #                           the slot decodes once this is empty
-    pieces: tuple = (0, 0)    # pieces run, and of how many
+    pieces: int = 0           # pieces run so far
     # A model with full and banded layers (two kinds of page): the
     # banded layers' pool pages, in sequence order from page `wbase`
     # of the sequence on (what lies below left the band), and the page
@@ -896,17 +887,6 @@ def _tick(x):
     return x + 1
 
 
-@partial(jax.jit, donate_argnums=(0, 1))
-def _write_pages(k_pool, v_pool, ids, k_new, v_new):
-    """Scatter per-layer pages into the pool at `ids` ([m] int32; entries
-    == total_pages are out of range and dropped — fixed arity, no
-    recompiles as counts vary). k_new/v_new: [L, m, page, n_kv, hd]."""
-    with jax.named_scope("pool.update"):
-        k_pool = k_pool.at[:, ids].set(k_new, mode="drop")
-        v_pool = v_pool.at[:, ids].set(v_new, mode="drop")
-    return k_pool, v_pool
-
-
 # The most bytes one device-to-host transfer of an offload brings over
 # (and one store batch holds). Measured on a v5e host (PERF.md, PR 27):
 # a transfer lands in a host buffer PJRT allocates anew, and above
@@ -1208,12 +1188,6 @@ class ServingEngine:
             raise ValueError(
                 f"admit_piece {self.sc.admit_piece} is no multiple of the "
                 f"page ({cfg.page_size} tokens)")
-        if self.sc.admit_piece and (self.sc.prefill_chunk or cfg.window
-                                    or self._win_layers or self.state):
-            raise ValueError(
-                "admit_piece runs the prefix program over the slot's own "
-                "pool pages: not built beside prefill_chunk, a sliding "
-                "window, two kinds of attention layer or state layers")
         self._piece_ran = False  # a piece ran in the step under way
         self._snapshot_bytes = 0
         self._snapshot_fields = {}  # what its spans carry beyond pages'
@@ -1296,26 +1270,20 @@ class ServingEngine:
         band go a few at a time and several slots' together
         (`_shed_windows`). Page 0 is scratch, as in
         the full pools. What is not built over two kinds is refused
-        here, not found at the first request."""
+        here, not found at the first request: verify and burst steps,
+        a piece's prefix, the int8 wire and packed rows address ONE
+        page pool."""
         cfg, sc = self.cfg, self.sc
         bands = {w for w, *_ in decoder.attn_layers(cfg) if w}
-        refused = [
-            ("spec_k", sc.spec_k > 0), ("host_steps", sc.host_steps > 1),
-            ("prefill_chunk", sc.prefill_chunk > 0),
-            ("quantized_store", sc.quantized_store),
-            ("kv_pack", cfg.kv_pack > 1),
-            ("state layers", bool(getattr(cfg, "n_state_layers", 0))),
-            ("more than one band", len(bands) > 1),
-            ("a band that is no page multiple",
-             cfg.window_band % cfg.page_size != 0),
-        ]
-        for name, on in refused:
-            if on:
-                raise ValueError(
-                    f"{name} is not supported for a model with full and "
-                    f"banded attention layers ({type(cfg).__name__}): "
-                    f"verify, burst and chunk steps, the int8 wire and "
-                    f"packed rows address ONE page pool")
+        self._refuse("full and banded attention layers", {
+            "spec_k": sc.spec_k > 0, "host_steps": sc.host_steps > 1,
+            "admit_piece": sc.admit_piece > 0,
+            "quantized_store": sc.quantized_store,
+            "kv_pack": cfg.kv_pack > 1,
+            "state layers": bool(getattr(cfg, "n_state_layers", 0)),
+            "more than one band": len(bands) > 1,
+            "a band that is no page multiple":
+                cfg.window_band % cfg.page_size != 0})
         self._band_pages = cfg.window_band // cfg.page_size
         self._wtable_w = -(-(self._band_pages + 2) // 8) * 8
         self._shed_pages = max(
@@ -1342,42 +1310,40 @@ class ServingEngine:
 
     def _check_latent_family(self):
         """What is not built over a latent pool is refused at
-        construction, not found at the first request: verify, burst and
-        chunk steps address K and V pages by head, the int8 wire
-        quantizes a row a head, and packed rows are heads side by
-        side."""
+        construction, not found at the first request: verify and burst
+        steps address K and V pages by head, the int8 wire quantizes a
+        row a head, and packed rows are heads side by side."""
         sc, cfg = self.sc, self.cfg
-        for name, on in (("spec_k", sc.spec_k > 0),
-                         ("host_steps", sc.host_steps > 1),
-                         ("prefill_chunk", sc.prefill_chunk > 0),
-                         ("quantized_store", sc.quantized_store),
-                         ("kv_pack", cfg.kv_pack > 1),
-                         ("window", bool(cfg.window_band)),
-                         ("state layers",
-                          bool(getattr(cfg, "n_state_layers", 0)))):
-            if on:
-                raise ValueError(
-                    f"{name} is not supported for a model with a latent "
-                    f"cache ({type(cfg).__name__})")
+        self._refuse("a latent cache", {
+            "spec_k": sc.spec_k > 0, "host_steps": sc.host_steps > 1,
+            "quantized_store": sc.quantized_store,
+            "kv_pack": cfg.kv_pack > 1, "window": bool(cfg.window_band),
+            "state layers": bool(getattr(cfg, "n_state_layers", 0))})
 
     def _check_state_family(self):
         """What is not built over a recurrent state is refused at
         construction, not found at the first request: a rejected draft
-        or a chunk boundary inside a page cannot be rolled back out of
-        a state, bursts would need the boundary copy inside the scan,
-        and the int8 wire is defined for pages. A sliding window is
-        refused too: its release frees pages by one global band, and a
-        family whose state layers carry the long range has none."""
+        cannot be rolled back out of a state, bursts would need the
+        boundary copy inside the scan, a piece would need the state its
+        predecessor left as a hit needs a snapshot, and the int8 wire is
+        defined for pages. A sliding window is refused too: its release
+        frees pages by one global band, and a family whose state layers
+        carry the long range has none."""
         sc = self.sc
-        for name, on in (("spec_k", sc.spec_k > 0),
-                         ("host_steps", sc.host_steps > 1),
-                         ("prefill_chunk", sc.prefill_chunk > 0),
-                         ("quantized_store", sc.quantized_store),
-                         ("window", bool(self.cfg.window))):
+        self._refuse("state layers", {
+            "spec_k": sc.spec_k > 0, "host_steps": sc.host_steps > 1,
+            "admit_piece": sc.admit_piece > 0,
+            "quantized_store": sc.quantized_store,
+            "window": bool(self.cfg.window)})
+
+    def _refuse(self, over, options):
+        """Raise for the first of `options` (name: whether it is on)
+        that is not built for a model with `over`."""
+        for name, on in options.items():
             if on:
                 raise ValueError(
-                    f"{name} is not supported for a model with state "
-                    f"layers ({type(self.cfg).__name__})")
+                    f"{name} is not supported for a model with {over} "
+                    f"({type(self.cfg).__name__})")
 
     def _to_device(self, host):
         """Host array -> the engine's device (None: jax's default)."""
@@ -1481,8 +1447,7 @@ class ServingEngine:
     def _pad_ids(self, ids, offset=0):
         """Pad a page-id list to the fixed arity max_pages_per_seq with
         the total_pages sentinel (mode=\"drop\" discards those writes) —
-        the ONE place the fixed-arity convention lives (shared by
-        _pool_write and the fused cold-admission path). `offset` places
+        the ONE place the fixed-arity convention lives. `offset` places
         the ids at [offset, offset+len): the windowed cold path drops
         its dead leading pages by leaving [0, offset) at the
         sentinel."""
@@ -1490,18 +1455,6 @@ class ServingEngine:
                         dtype=np.int32)
         ids_p[offset:offset + len(ids)] = ids
         return ids_p
-
-    def _pool_write(self, ids, k_new, v_new):
-        """Write [L, n, page, kv, hd] pages into the pool at `ids`,
-        padding to the fixed arity max_pages_per_seq."""
-        m = self.sc.max_pages_per_seq
-        n = len(ids)
-        ids_p = self._pad_ids(ids)
-        pad = [(0, 0), (0, m - n)] + [(0, 0)] * (k_new.ndim - 2)
-        self.k_pages, self.v_pages = _write_pages(
-            self.k_pages, self.v_pages, self._to_device(ids_p),
-            jnp.pad(k_new, pad), jnp.pad(v_new, pad),
-        )
 
     def _store_failed(self, what, exc):
         """First store failure downgrades to store-less serving: the
@@ -1638,14 +1591,12 @@ class ServingEngine:
             # The probe is cached on work while the request waits under
             # pool pressure, so it can OUTLIVE the store: another slot's
             # store failure latching _store_ok=False between the probe
-            # and this (re)admission would otherwise leave hit > 0 while
-            # skip is computed store-less (skip = p0 != first_live) —
-            # the restore would still run and trip the pool-placement
-            # `assert skip == first_live` (under -O, silently misplace
-            # suffix pages). A dead store chain means a cache MISS, not
-            # a smaller hit.
+            # and this (re)admission would leave hit > 0 while skip is
+            # computed store-less (p0, not first_live), and the restore
+            # would trip the pool-placement `assert skip == first_live`.
+            # A dead store chain means a cache MISS, not a smaller hit.
             hit, digests = 0, []
-        # Windowed admission floors. Three distinct boundaries:
+        # Windowed admission floors. Two distinct boundaries:
         #   first_live — earliest page the SUFFIX PREFILL can attend
         #     (the first suffix query sits at hit*page; its band floor
         #     is hit*page - window + 1), so restore transfers only
@@ -1655,11 +1606,7 @@ class ServingEngine:
         #     allocates pool pages only for [p0, n_pages) — this is
         #     what makes preemption re-admission of an over-pool grown
         #     prompt possible at all: the pool cost is O(window), not
-        #     O(prompt);
-        #   the chunked path allocates from first_live instead (its
-        #     chunk queries attend POOL pages, and its floor rises as
-        #     chunks consume the prompt — _release_windowed frees on
-        #     the way).
+        #     O(prompt).
         first_live = self._first_live(hit)
         p0 = max(0, n_prompt - window) // page if window else 0
         # How many leading pages never get a pool page:
@@ -1668,13 +1615,13 @@ class ServingEngine:
         #     — un-cached sub-floor pages must be materialized once so
         #     release can offload them and keep the prefix chain
         #     gap-free for future hits;
-        #   - store-less (or cache=False), nothing is ever offloaded,
-        #     so every page below the post-admission floor (p0) is
-        #     droppable outright;
-        #   - the chunked path always needs pool pages from first_live
-        #     (its chunk queries attend POOL pages, floor rising as
-        #     chunks consume the prompt).
-        if self.sc.prefill_chunk > 0 or store_chain:
+        #   - an admission in pieces needs pool pages from first_live
+        #     too: a later piece attends POOL pages of an earlier one
+        #     that lie below p0 (its floor rises as pieces consume the
+        #     prompt, and _release_windowed frees on the way);
+        #   - else store-less (or cache=False), nothing is ever
+        #     offloaded, so every page below p0 is droppable outright.
+        if self._in_pieces(n_prompt - hit * page) or store_chain:
             skip = min(first_live, hit)
         else:
             skip = p0
@@ -1829,7 +1776,6 @@ class ServingEngine:
     def _admit_restore_and_prefill(self, slot_idx, work, ids, n_prompt,
                                    n_pages, hit, digests, skip,
                                    first_live, f):
-        cfg = self.cfg
         self._admit_ids_view = ids
         restored = snap = None
         if hit > 0:
@@ -1863,8 +1809,7 @@ class ServingEngine:
                         hit, skip, first_live, restored, snap=None):
         """`restored`, `snap`: what _restore returned for pages
         [first_live, hit), or None on a miss."""
-        cfg = self.cfg
-        page = cfg.page_size
+        page = self.cfg.page_size
         # page_ids[i] for i < skip are dead placeholders (page 0, the
         # scratch page): nothing after admission can attend positions
         # below the band floor, and _release/_offload honor
@@ -1879,30 +1824,8 @@ class ServingEngine:
         row = np.zeros(self.sc.max_pages_per_seq, dtype=np.int32)
         row[skip:n_pages] = ids
         self._pages_rev += 1  # admission rewrites this slot's row
-        if self.sc.prefill_chunk > 0:
-            # Chunked admission: no bulk prefill here — the prompt tail
-            # is consumed <= prefill_chunk tokens per engine step in a
-            # MIXED batch with decoding slots (_unified_step); restored
-            # pages go into the pool to back the cached prefix, and
-            # chunk attention runs straight over the pages.
-            if restored is not None:
-                with self._span("istpu.cache.pool_write", what="restored",
-                                pages=hit - skip):
-                    self._pool_write(
-                        ids[:hit - skip],
-                        *decoder.restored_to_pages(cfg, restored))
-            self.page_table[slot_idx] = row
-            self.slots[slot_idx] = _Slot(
-                work=work, page_ids=full_ids, seq_len=hit * page,
-                cached_pages=hit, released=skip, generated=[],
-                pending=list(work.prompt[hit * page:]),
-            )
-            self._release_windowed(self.slots[slot_idx])
-            return
-
         suffix = work.prompt[hit * page:]
-        piece = self.sc.admit_piece
-        if piece and len(suffix) > piece:
+        if self._in_pieces(len(suffix)):
             # Admission in pieces: the slot holds its pages and the
             # tokens still to admit; `_step_pieces` runs a piece an
             # engine step and the slot decodes once none is left. A
@@ -1911,11 +1834,11 @@ class ServingEngine:
             slot = _Slot(
                 work=work, page_ids=full_ids, seq_len=hit * page,
                 cached_pages=hit, released=skip, index=slot_idx,
-                todo=list(suffix), pieces=(0, -(-len(suffix) // piece)),
-            )
+                todo=suffix)
             self.slots[slot_idx] = slot
             if restored is not None:
                 self._run_piece(slot, restored)
+                self._release_windowed(slot)
             return
         if restored is None:
             # Cold admission (hit == 0). Dead prompt pages [0, skip)
@@ -1925,10 +1848,9 @@ class ServingEngine:
                 suffix, self._pad_ids(ids, offset=skip), slot_idx)
         else:
             # A hit implies skip = first_live <= hit, so every suffix
-            # page has a pool id; sub-floor suffix pages (if any are
-            # below the post-admission floor) are materialized here
-            # and freed by the _release_windowed below, AFTER
-            # offloading — keeping the prefix chain gap-free.
+            # page has a pool id; suffix pages below the post-admission
+            # floor are materialized here and freed by the release
+            # below, AFTER offloading: the prefix chain stays gap-free.
             row_host = self._prefill_hit(
                 suffix, restored, first_live * page,
                 ids[:hit - skip], ids[hit - skip:], snap, slot_idx)
@@ -1945,48 +1867,50 @@ class ServingEngine:
         self.slots[slot_idx] = slot
         # Windowed models: any remaining pages wholly below the band
         # floor go straight back to the pool (with a store, un-cached
-        # ones were materialized so this release can offload them and
-        # keep the prefix chain gap-free; the restore TRANSFER was
-        # already trimmed to [first_live, hit) — only the PROBE's key
-        # list stays O(prompt), it is hash-only).
+        # ones were materialized so this release can offload them).
         self._release_windowed(slot)
 
     # ---- admission in pieces --------------------------------------------
 
     def _run_piece(self, slot, restored=None):
-        """The next piece of `slot`'s prompt (`ServingConfig.
-        admit_piece` tokens, or the tail): ONE program call. A cold
-        prompt's first piece is the cold program; every other piece is
-        the prefix program (`_prefill_hit`, the program a hit runs)
-        over the pages the slot holds so far: `restored`, a hit's pages
-        as its store call returned them (they go into the pool here),
-        or the slot's own pool pages, gathered into that same form.
-        Returns the piece's last logits row (the first token's, once
-        `slot.todo` is empty)."""
-        page = self.cfg.page_size
-        tokens = slot.todo[:self.sc.admit_piece]
+        """The next piece of `slot`'s prompt (`admit_piece` tokens, or
+        the tail): ONE program call. A cold prompt's first piece is the
+        cold program; every other is the prefix program (`_prefill_hit`,
+        the one a hit runs) over the pages the slot holds from its
+        band's floor on (`_first_live`; all of them without a window):
+        `restored`, a hit's pages as its store call returned them (they
+        go into the pool here), or the slot's own pool pages, gathered
+        into that same form. What a window leaves behind is the
+        caller's to release: it owns the slot's pages. Returns the
+        piece's last logits row (the first token's, once `slot.todo` is
+        empty)."""
+        page, piece = self.cfg.page_size, self.sc.admit_piece
+        tokens = slot.todo[:piece]
         held = slot.seq_len // page  # pieces and hits end on page edges
         ids = slot.page_ids[held:held - (-len(tokens) // page)]
-        k, of = slot.pieces
         with self._span("istpu.sched.admit_piece",
                         slot.work.req.request_id, tokens=len(tokens),
-                        prefix_pages=held, piece=k + 1, of=of):
+                        prefix_pages=held, piece=slot.pieces + 1,
+                        of=slot.pieces - (-len(slot.todo) // piece)):
             if restored is None and held == 0:
                 row = self._prefill_cold(tokens, self._pad_ids(ids),
                                          slot.index)
             else:
-                r_ids = slot.page_ids[:held]
+                lo = self._first_live(held)
+                r_ids = slot.page_ids[lo:held]
                 if restored is None:
-                    with self._span("istpu.cache.pool_read", pages=held):
+                    with self._span("istpu.cache.pool_read",
+                                    pages=held - lo):
                         restored = _gather_pages(
                             self.k_pages, self.v_pages, self._to_device(
                                 np.asarray(r_ids, np.int32))
                         ).reshape(-1, *self.cfg.kv_page_shape())
-                    r_ids = [self.sc.total_pages] * held  # they are there
-                row = self._prefill_hit(tokens, restored, 0, r_ids, ids)
+                    r_ids = [self.sc.total_pages] * len(r_ids)  # in place
+                row = self._prefill_hit(tokens, restored, lo * page,
+                                        r_ids, ids)
         slot.todo = slot.todo[len(tokens):]
         slot.seq_len += len(tokens)
-        slot.pieces = (k + 1, of)
+        slot.pieces += 1
         self.stats["admit_pieces"] += 1
         self.stats["prefill_tokens"] += len(tokens)
         self._piece_ran = True
@@ -2003,7 +1927,12 @@ class ServingEngine:
             row = self._run_piece(s)
             if not s.todo:
                 self._emit(s, [self._pick(s.work, row)])
+            self._release_windowed(s)
             self._settle_if_left_idle()
+
+    def _in_pieces(self, n_tokens):
+        """Whether `n_tokens` uncached prompt tokens go in pieces."""
+        return 0 < self.sc.admit_piece < n_tokens
 
     def _active(self):
         """(index, slot) of the slots that decode: occupied and
@@ -2353,8 +2282,7 @@ class ServingEngine:
                 restored, snap = self._restore(hit, digests, first_live)
             except InfiniStoreKeyNotFound:
                 hit = 0  # evicted between probe and restore
-        piece = self.sc.admit_piece
-        if piece and len(prompt) - hit * page > piece:
+        if self._in_pieces(len(prompt) - hit * page):
             # What an admission in pieces runs, on pool pages taken for
             # the call and given back (the engine is idle: they are
             # free, and free again after).
@@ -2362,9 +2290,8 @@ class ServingEngine:
             if ids is None:
                 raise RuntimeError("first_token_logits: the prompt needs "
                                    "more pool pages than are free")
-            tail = prompt[hit * page:]
             slot = _Slot(work=work, page_ids=ids, seq_len=hit * page,
-                         todo=list(tail), pieces=(0, -(-len(tail) // piece)))
+                         todo=prompt[hit * page:])
             try:
                 row = self._run_piece(slot, restored if hit > 0 else None)
                 while slot.todo:
@@ -2443,10 +2370,6 @@ class ServingEngine:
             slot.wpage_ids.extend(ids)
             self._pages_rev += 1
         return True
-
-    def _ensure_page(self, slot_idx, slot):
-        """The KV being appended this step lands at position seq_len."""
-        return self._ensure_pages(slot_idx, slot, slot.seq_len)
 
     def _offload_full_pages(self, slot, hi=None, reason="finish"):
         """Send the slot's NEW full pages [lo, hi) to the store (shared
@@ -2933,11 +2856,6 @@ class ServingEngine:
         if not active:
             return 0
 
-        if any(s.pending for _, s in active):
-            f.update(kind="unified", active=len(active),
-                     k=self.sc.prefill_chunk)
-            return self._unified_step(active)
-
         if self.sc.spec_k > 0:
             proposals = {}
             for i, s in active:
@@ -2974,7 +2892,7 @@ class ServingEngine:
             self._shed_windows(active)
         for i, s in active:
             if not self._ensure_pages(i, s, s.seq_len + k - 1):
-                if k > 1 and self._ensure_page(i, s):
+                if k > 1 and self._ensure_pages(i, s, s.seq_len):
                     # Burst not backable but a single step is: drop the
                     # whole batch to k=1 (pages ensured for other slots
                     # beyond 1 step stay owned and get used later).
@@ -3303,95 +3221,6 @@ class ServingEngine:
                         self.state, self.bstate, self._slot_dev(i))
                 self.stats["boundary_copies"] += 1
 
-    def _verify_batch(self, entries, m):
-        """Shared multi-token verify plumbing: pack {slot_idx: tokens}
-        into the padded [B, m] batch (ragged rows park their padding in
-        the scratch page via valid_len), run verify_step, and return
-        (refreshed active list, per-position argmax [B, m], logits —
-        device-resident; sampling consumers pull rows to host)."""
-        B = self.sc.max_slots
-        token = np.zeros((B, m), dtype=np.int32)
-        seq_lens = np.zeros(B, dtype=np.int32)
-        valid = np.zeros(B, dtype=np.int32)
-        rows = np.zeros_like(self.page_table)
-        for i, toks in entries.items():
-            s = self.slots[i]
-            token[i, : len(toks)] = toks
-            valid[i] = len(toks)
-            seq_lens[i] = s.seq_len
-            rows[i] = self.page_table[i]
-        active = [
-            (i, s) for i, s in enumerate(self.slots)
-            if s is not None and i in entries
-        ]
-        if not active:
-            return [], None, None
-        with self._span("istpu.model.decode", program="verify") as df:
-            logits, self.k_pages, self.v_pages = self.model.verify_step(
-                self.params, self.cfg,
-                self._to_device(token), self._to_device(seq_lens),
-                self.k_pages, self.v_pages, self._to_device(rows),
-                self._to_device(valid),
-            )
-            nxt_dev = jnp.argmax(logits, axis=-1)
-            df["dispatch_ns"] = profiling.elapsed_ns()
-            nxt = np.asarray(nxt_dev)
-        return active, nxt, logits
-
-    def _unified_step(self, active):
-        """Mixed chunked-prefill + decode batch (vLLM-style): slots
-        still prefilling consume up to `prefill_chunk` prompt tokens,
-        decoding slots consume their one token, all in ONE multi-token
-        verify pass — a long prompt admission never stalls the other
-        sequences' decode. m is pinned to the chunk size so the jit
-        compiles once; ragged rows pad via valid_len (scratch-page
-        writes). Decode slots take single tokens here — speculation
-        resumes once no slot is prefilling."""
-        m = self.sc.prefill_chunk
-        self._steady = None  # multi-token advance: device state stale
-        entries = {}
-        for i, s in active:
-            if s.pending:
-                entries[i] = s.pending[: min(m, len(s.pending))]
-                # Pages were preallocated at admission — no ensure.
-            else:
-                if not self._ensure_page(i, s):
-                    # A prefilling slot is always also active here, so
-                    # there is another sequence to yield to.
-                    self._preempt(i, s)
-                    continue
-                entries[i] = [s.generated[-1]]
-        active, nxt, logits = self._verify_batch(entries, m)
-        if not active:
-            return 0
-        lhost = _LazyHost(logits)  # ONE transfer if any slot samples
-        decoded = False
-        for i, s in active:
-            t = len(entries[i])
-            sampler = s.work.req.temperature > 0
-            if s.pending:
-                s.pending = s.pending[t:]
-                s.seq_len += t
-                self._release_windowed(s)
-                self.stats["prefill_tokens"] += t
-                if not s.pending:
-                    # Prompt fully consumed: the last position's logits
-                    # yield the first generated token.
-                    tok = (self._pick(s.work, lhost()[i, t - 1])
-                           if sampler else int(nxt[i, t - 1]))
-                    self._emit(s, [tok])
-            else:
-                tok = (self._pick(s.work, lhost()[i, 0])
-                       if sampler else int(nxt[i, 0]))
-                self._emit(s, [tok])
-                s.seq_len += 1
-                self._release_windowed(s)
-                self.stats["decoded_tokens"] += 1
-                decoded = True
-        if decoded:
-            self.stats["decode_steps"] += 1
-        return len(active)
-
     def _sample_over_draft(self, work, draft, rows):
         """Rejection-sampling acceptance for a sampled request's draft
         (standard speculative sampling, specialized to a DETERMINISTIC
@@ -3442,7 +3271,6 @@ class ServingEngine:
         that bound decode on TPU (HBM-bandwidth-limited)."""
         m = self.sc.spec_k + 1
         self._steady = None  # multi-token advance: device state stale
-        entries = {}
         props = {}
         for i, s in active:
             p = proposals[i]
@@ -3458,11 +3286,31 @@ class ServingEngine:
                         self._finish(i, s)
                     continue
                 p = p[: avail - 1]
-            entries[i] = [s.generated[-1]] + p
             props[i] = p
-        active, nxt, logits = self._verify_batch(entries, m)
-        if not active:
+        if not props:
             return 0
+        # The padded [B, m] batch: drafts differ in length, and a ragged
+        # row parks its padding in the scratch page (valid_len).
+        token = np.zeros((self.sc.max_slots, m), dtype=np.int32)
+        seq_lens, valid = np.zeros((2, self.sc.max_slots), dtype=np.int32)
+        rows = np.zeros_like(self.page_table)
+        active = [(i, s) for i, s in active if i in props]
+        for i, s in active:
+            toks = [s.generated[-1]] + props[i]
+            token[i, : len(toks)] = toks
+            valid[i] = len(toks)
+            seq_lens[i] = s.seq_len
+            rows[i] = self.page_table[i]
+        with self._span("istpu.model.decode", program="verify") as df:
+            logits, self.k_pages, self.v_pages = self.model.verify_step(
+                self.params, self.cfg,
+                self._to_device(token), self._to_device(seq_lens),
+                self.k_pages, self.v_pages, self._to_device(rows),
+                self._to_device(valid),
+            )
+            nxt_dev = jnp.argmax(logits, axis=-1)
+            df["dispatch_ns"] = profiling.elapsed_ns()
+            nxt = np.asarray(nxt_dev)
         lhost = _LazyHost(logits)  # ONE transfer if any slot samples
         for i, s in active:
             p = props[i]

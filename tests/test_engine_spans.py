@@ -122,7 +122,6 @@ def test_miss_span_tree(params, cfg, shm_conn):
         "padded_tokens": 4 * PAGE}
     assert kids[2].fields == {"programs": serving.SETTLE_PROGRAMS}
     # The cold program writes the pool itself: no separate pool write.
-    assert not _named(spans, "istpu.cache.pool_write")
     # Queue wait: recorded after the fact, from the request's arrival
     # to the start of the admission, under the step that admitted it.
     (wait,) = _named(spans, "istpu.sched.queue_wait")
@@ -184,7 +183,6 @@ def test_hit_span_tree(params, cfg, shm_conn):
         "padded_tokens": -(-n_sfx // PAGE) * PAGE, "restored_pages": hit}
     assert not _children(spans, prefill)
     assert not _named(spans, "istpu.cache.to_kv")
-    assert not _named(spans, "istpu.cache.pool_write")
 
 
 def test_a_hit_over_two_offloads_is_two_runs_and_one_copy(params, cfg,
@@ -276,28 +274,44 @@ def test_windowed_hit_restores_from_first_live(cfg, shm_conn):
     assert kids[1].fields["pages"] == hit - first_live
     assert kids[2].fields["restored_pages"] == hit - first_live
     assert kids[2].fields["program"] == "prefix"
-    assert not _named(spans, "istpu.cache.pool_write")
 
 
-def test_chunked_hit_keeps_its_restored_pool_write(params, cfg, shm_conn):
-    """The chunked path (prefill_chunk > 0) attends straight over pool
-    pages and never ran the prefix program: its restored pages still
-    go into the pool through the one eager pool write."""
-    eng = _engine(params, cfg, shm_conn, "spans-hit-chunked",
-                  prefill_chunk=PAGE)
+def test_a_hit_in_pieces_places_its_pages_in_the_first_piece(params, cfg,
+                                                             shm_conn):
+    """A hit admitted in pieces (admit_piece > 0): the admission holds
+    the probe, the store call and the FIRST piece, whose prefix
+    program places the restored pages; every later piece reads the
+    pool (`pool_read`) and places nothing."""
+    eng = _engine(params, cfg, shm_conn, "spans-hit-pieces",
+                  admit_piece=PAGE)
     first = _prompt(6, 4 * PAGE)
     out = eng.run([Request("c0", first, max_new_tokens=PAGE)])["c0"]
-    spans = _run(eng, Request("c1", first + out + _prompt(7, 5),
+    spans = _run(eng, Request("c1", first + out + _prompt(7, 2 * PAGE + 5),
                               max_new_tokens=2))
-    (admit,) = _named(spans, "istpu.sched.admit")
+    (admit,) = _named(spans, "istpu.sched.admit", outcome="admitted")
     hit = admit.fields["hit_pages"]
     assert hit == 4
-    kids = _children(spans, admit)
-    assert [k.name for k in kids] == [
+    pieces = [s for s in _named(spans, "istpu.sched.admit_piece")
+              if s.request == "c1"]
+    assert [(p.fields["piece"], p.fields["of"], p.fields["tokens"],
+             p.fields["prefix_pages"]) for p in pieces] == [
+        (1, 4, PAGE, hit), (2, 4, PAGE, hit + 1), (3, 4, PAGE, hit + 2),
+        (4, 4, 5, hit + 3)]
+    assert [k.name for k in _children(spans, admit)] == [
         "istpu.cache.probe", "istpu.cache.restore",
-        "istpu.cache.pool_write"]
-    assert kids[2].fields == {"what": "restored", "pages": hit}
-    assert not _named(spans, "istpu.model.prefill", program="prefix")
+        "istpu.sched.admit_piece"]
+    assert pieces[0].parent == admit.id
+    (placed,) = _children(spans, pieces[0])
+    assert placed.name == "istpu.model.prefill"
+    assert placed.fields["program"] == "prefix"
+    assert placed.fields["restored_pages"] == hit
+    for i, piece in enumerate(pieces[1:], 1):
+        read, ran = _children(spans, piece)
+        assert (read.name, read.fields) == (
+            "istpu.cache.pool_read", {"pages": hit + i})
+        assert ran.name == "istpu.model.prefill"
+        # the drop sentinel for every page read: they are where they lie
+        assert ran.fields["restored_pages"] == hit + i
 
 
 def _host_calls(trace_dir):
@@ -716,7 +730,7 @@ def test_decode_spans_carry_live_pages_and_counters_sum_them(params, cfg,
         (n_new - 1) * cfg.n_layers * 3 * 12)
 
 
-def test_step_kinds_burst_unified_spec(params, cfg):
+def test_step_kinds_burst_spec_decode(params, cfg):
     def kinds(**sc):
         eng = _engine(params, cfg, None, "spans-kinds", **sc)
         eng.proposer = lambda ctx, k: [1] * k
@@ -725,14 +739,16 @@ def test_step_kinds_burst_unified_spec(params, cfg):
         return ({s.fields["kind"] for s in _named(spans, "istpu.engine.step")}
                 - {"idle"},
                 {s.fields["program"]
-                 for s in _named(spans, "istpu.model.decode")})
+                 for s in _named(spans, "istpu.model.decode")},
+                len(_named(spans, "istpu.sched.admit_piece")))
 
     assert kinds(host_steps=4) == ({"burst", "decode"},
-                                   {"decode_scan", "decode_fused"})
-    # behind the chunks the plain steps run ahead: a dispatch, a wait
-    assert kinds(prefill_chunk=PAGE) == ({"unified", "decode"},
-                                         {"verify", "decode_fused", "land"})
-    k, p = kinds(spec_k=3)
+                                   {"decode_scan", "decode_fused"}, 0)
+    # pieces are no kind of step: each runs in the step that decodes the
+    # others; behind them the plain steps run ahead: a dispatch, a wait
+    assert kinds(admit_piece=PAGE) == ({"decode"},
+                                       {"decode_fused", "land"}, 5)
+    k, p, _ = kinds(spec_k=3)
     assert "spec" in k and "verify" in p
 
 

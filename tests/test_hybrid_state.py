@@ -387,7 +387,7 @@ def test_preempt_and_resume_through_the_store(params, cfg, shm_conn):
 
 
 @pytest.mark.parametrize("option", [
-    {"spec_k": 2}, {"host_steps": 4}, {"prefill_chunk": 16},
+    {"spec_k": 2}, {"host_steps": 4}, {"admit_piece": 16},
     {"quantized_store": True},
 ])
 def test_what_is_not_built_over_state_is_refused(params, cfg, option):

@@ -435,7 +435,6 @@ def test_offload_evict_restore_gives_the_same_logits(cfg, params, shm_conn):
 @pytest.mark.parametrize("name,sc,change", [
     ("spec_k", {"spec_k": 2}, {}),
     ("host_steps", {"host_steps": 4}, {}),
-    ("prefill_chunk", {"prefill_chunk": 16}, {}),
     ("quantized_store", {"quantized_store": True}, {}),
     ("kv_pack", {}, {"kv_pack": 2}),
     ("window", {}, {"window": 32}),
@@ -454,19 +453,17 @@ def test_verify_step_and_a_ragged_piece_are_refused(cfg, params):
             jnp.zeros((1, 4), jnp.int32))
     with pytest.raises(ValueError, match="admit_piece"):
         _engine(params, cfg, admit_piece=12)
-    from infinistore_tpu.models import llama
-    with pytest.raises(ValueError, match="admit_piece"):
-        ServingEngine(llama.init_params(jax.random.PRNGKey(0),
-                                        llama.LlamaConfig(window=32)),
-                      llama.LlamaConfig(window=32),
-                      ServingConfig(admit_piece=16))
 
 
-def test_admission_in_pieces_serves_a_family_with_k_and_v_pages():
+@pytest.mark.parametrize("window", [0, 32])
+def test_admission_in_pieces_serves_a_family_with_k_and_v_pages(window):
     """The pieces are the engine's, not the family's: a dense model's
-    long prompt in pieces gives the tokens of the one program."""
+    long prompt in pieces gives the tokens of the one program, under
+    one sliding window too (a piece attends the slot's pages from its
+    band's floor on, and what fell below is freed between pieces)."""
     from infinistore_tpu.models import llama
-    lcfg = llama.LlamaConfig(dtype="float32", page_size=8, max_seq=512)
+    lcfg = llama.LlamaConfig(dtype="float32", page_size=8, max_seq=512,
+                             window=window)
     lp = llama.init_params(jax.random.PRNGKey(2), lcfg)
     prompt = _prompt(51, 70)
     outs = []

@@ -1052,8 +1052,8 @@ def _page_major(cfg, kvs, first_page):
 def _hit_composed(f, toks, restored, pools, restored_ids, suffix_ids,
                   s_real, pos0):
     """Today's composition, dispatch by dispatch: restore_prefix_pages
-    -> pages_to_kv -> _prefill_px_jit -> kv_to_pages -> _write_pages
-    (twice, each padded to the fixed arity)."""
+    -> pages_to_kv -> _prefill_px_jit -> kv_to_pages -> a pool write
+    (twice, each padded to the fixed arity, the sentinel dropped)."""
     from infinistore_tpu import serving
 
     model, cfg, params = f.model, f.cfg, f.params
@@ -1071,8 +1071,8 @@ def _hit_composed(f, toks, restored, pools, restored_ids, suffix_ids,
         ids_p = np.full(_HIT_ARITY, _HIT_TOTAL_PAGES, np.int32)
         ids_p[:len(ids)] = ids
         pad = [(0, 0), (0, _HIT_ARITY - k_new.shape[1])] + [(0, 0)] * 3
-        return serving._write_pages(*pools, jnp.asarray(ids_p),
-                                    jnp.pad(k_new, pad), jnp.pad(v_new, pad))
+        return tuple(pool.at[:, ids_p].set(jnp.pad(new, pad), mode="drop")
+                     for pool, new in zip(pools, (k_new, v_new)))
 
     pools = write(pools, restored_ids, kp, vp)
     k_sfx, v_sfx = [], []

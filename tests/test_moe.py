@@ -151,10 +151,11 @@ def serve_params(serve_cfg):
     return moe.init_params(jax.random.PRNGKey(3), serve_cfg)
 
 
-@pytest.mark.parametrize("mode", ["spec", "chunk", "burst"])
+@pytest.mark.parametrize("mode", ["spec", "pieces", "burst"])
 def test_moe_serving_modes_token_parity(serve_params, serve_cfg, mode):
-    """Speculation (verify_step), chunked prefill and multi-step bursts
-    all serve the MoE family with the plain-engine token stream."""
+    """Speculation (verify_step), admission in pieces and multi-step
+    bursts all serve the MoE family with the plain-engine token
+    stream."""
     from infinistore_tpu.serving import Request, ServingConfig, ServingEngine
 
     rng = np.random.default_rng(51)
@@ -168,7 +169,7 @@ def test_moe_serving_modes_token_parity(serve_params, serve_cfg, mode):
     )["x"]
     sc = {
         "spec": ServingConfig(spec_k=2),
-        "chunk": ServingConfig(prefill_chunk=4),
+        "pieces": ServingConfig(admit_piece=serve_cfg.page_size),
         "burst": ServingConfig(host_steps=4),
     }[mode]
     eng = ServingEngine(serve_params, serve_cfg, sc, model=moe)
@@ -178,15 +179,16 @@ def test_moe_serving_modes_token_parity(serve_params, serve_cfg, mode):
         assert _steps_of_kind(eng, "burst") > 0
     if mode == "spec":
         assert eng.stats["spec_proposed"] > 0
-    if mode == "chunk":
-        assert _steps_of_kind(eng, "unified") > 0
+    if mode == "pieces":
+        assert eng.stats["admit_pieces"] == 2
 
 
-def test_moe_chunked_parity_at_default_capacity():
-    """The reviewer's failure scenario: chunked prefill at the DEFAULT
-    capacity_factor (1.5) with idle slots — pad/inactive tokens must
-    not evict real tokens from expert capacity (the _route validity
-    mask), so chunked == unchunked exactly."""
+def test_moe_pieces_parity_at_default_capacity():
+    """Admission in pieces at the DEFAULT capacity_factor (1.5): a
+    piece changes the tokens a capacity is reckoned over, and its last
+    one is mostly padding — pad tokens must not evict real tokens from
+    expert capacity (the _route validity mask), so at a size where no
+    real token is dropped pieces == one program exactly."""
     from infinistore_tpu.serving import Request, ServingConfig, ServingEngine
 
     cfg = tiny_cfg(max_seq=128)  # capacity_factor at its default
@@ -198,12 +200,12 @@ def test_moe_chunked_parity_at_default_capacity():
         [Request("x", prompt, max_new_tokens=6)]
     )["x"]
     eng = ServingEngine(
-        params, cfg, ServingConfig(max_slots=8, prefill_chunk=4),
-        model=moe,
+        params, cfg,
+        ServingConfig(max_slots=8, admit_piece=cfg.page_size), model=moe,
     )
     out = eng.run([Request("r", prompt, max_new_tokens=6)])
     assert out["r"] == ref
-    assert _steps_of_kind(eng, "unified") > 0
+    assert eng.stats["admit_pieces"] == 3
 
 
 def test_moe_multiturn_prefix_hit_through_store(serve_params, serve_cfg,

@@ -542,7 +542,11 @@ def test_pages_overwritten_behind_the_gather_reach_the_store_as_they_were(
     assert set(ids) <= set(eng.free_pages) and eng.uploads_pending == 1
     L, shape = eng.cfg.n_layers, eng.cfg.kv_page_shape()
     junk = jnp.full((L, n, *shape), 7, eng.cfg.jdtype)
-    eng._pool_write(ids, junk, -junk)
+    at = jnp.asarray(ids)
+    # in place (the pools donated), as an admission's scatter writes
+    eng.k_pages, eng.v_pages = jax.jit(
+        lambda k, v: (k.at[:, at].set(junk), v.at[:, at].set(-junk)),
+        donate_argnums=(0, 1))(eng.k_pages, eng.v_pages)
     got = _pool_rows(eng, ids)
     assert all((got[li, "k"] == 7).all() and (got[li, "v"] == -7).all()
                for li in range(L))

@@ -197,7 +197,7 @@ def test_quantized_chooser_fallback_gathers_first():
 
 
 # ---------------------------------------------------------------------------
-# Multi-token verify kernel (speculative verify / chunked prefill).
+# Multi-token verify kernel (speculative verify).
 # ---------------------------------------------------------------------------
 
 def _mk_multi(batch, m, n_heads, n_kv, hd, n_pages, page, max_pages,
@@ -267,8 +267,8 @@ def test_verify_kernel_matches_xla(batch, m, n_heads, n_kv, hd, page):
 
 
 def test_verify_kernel_empty_cache_and_page_spanning_chunk():
-    """The chunked-prefill regimes: seq_len = 0 (first chunk — each
-    token attends only to the block's own scattered KV) and an m-token
+    """The verify kernel's edge regimes: seq_len = 0 (each token
+    attends only to the block's own scattered KV) and an m-token
     block spanning several pages (m > page_size)."""
     from infinistore_tpu.ops.paged_attention import (
         multi_token_paged_attention,

@@ -565,47 +565,54 @@ def test_speculative_eos_truncation(params, cfg):
     assert out["r"] == want
 
 
-@pytest.mark.parametrize("chunk", [4, 8, 64])
-def test_chunked_prefill_token_parity(params, cfg, chunk):
-    """Chunked admission must emit exactly the one-shot-prefill tokens,
-    for chunks smaller than a page, page-sized, and bigger than the
-    whole prompt."""
+@pytest.mark.parametrize("pages", [1, 2, 8])
+def test_pieces_token_parity(params, cfg, pages):
+    """An admission in pieces must emit exactly the one-program
+    admission's tokens, for pieces of a page, of two, and longer than
+    the whole prompt (no piece runs: the one program)."""
     rng = np.random.default_rng(14)
     prompt = _prompt(rng, cfg, 21)
     ref = ServingEngine(params, cfg).run(
         [Request("x", prompt, max_new_tokens=7)]
     )
     eng = ServingEngine(
-        params, cfg, ServingConfig(prefill_chunk=chunk)
+        params, cfg, ServingConfig(admit_piece=pages * cfg.page_size)
     )
     out = eng.run([Request("r", prompt, max_new_tokens=7)])
     assert out["r"] == ref["x"]
-    assert _steps_of_kind(eng, "unified") > 0
+    assert eng.stats["admit_pieces"] == {1: 3, 2: 2, 8: 0}[pages]
     assert eng.stats["prefill_tokens"] == 21
 
 
-def test_chunked_prefill_interleaves_with_decode(params, cfg):
-    """While a long prompt is being chunk-prefilled, an already-running
-    sequence must keep decoding in the same steps — and both outputs
-    must match their isolated runs."""
+def test_pieces_interleave_with_decode(params, cfg):
+    """While a long prompt is admitted a piece an engine step, a
+    sequence that already runs lands a token between every two pieces,
+    and both outputs match their isolated runs."""
     rng = np.random.default_rng(15)
     short = _prompt(rng, cfg, 5)
     long_p = _prompt(rng, cfg, 40)
     eng = ServingEngine(
         params, cfg,
-        ServingConfig(max_slots=2, total_pages=32, prefill_chunk=4),
+        ServingConfig(max_slots=2, total_pages=32,
+                      admit_piece=cfg.page_size),
     )
     # Admit the short request, let it produce a couple of tokens, then
-    # submit the long one: its 10 chunk steps overlap short's decode.
+    # submit the long one: its 5 pieces overlap short's decode.
     eng.submit(Request("short", short, max_new_tokens=16))
     eng.step()
     eng.step()
     eng.submit(Request("long", long_p, max_new_tokens=4))
+    seen = []  # (pieces run, short's tokens) after every step
     while eng.queue or any(s is not None for s in eng.slots):
         eng.step()
-    # Mixed steps happened: chunk steps that ALSO decoded.
-    assert _steps_of_kind(eng, "unified") > 0
-    assert eng.stats["decode_steps"] > 0
+        if eng.slots[0] is not None:
+            seen.append((eng.stats["admit_pieces"],
+                         len(eng.slots[0].generated)))
+    # Every step that ran a piece also decoded: one token of short's
+    # between every two pieces.
+    seen = [at for at in seen if at[0]]
+    n0 = seen[0][1]
+    assert seen[:5] == [(k + 1, n0 + k) for k in range(5)]
     for rid, prompt, mx in [("short", short, 16), ("long", long_p, 4)]:
         ref = ServingEngine(params, cfg).run(
             [Request("x", prompt, max_new_tokens=mx)]
@@ -613,10 +620,10 @@ def test_chunked_prefill_interleaves_with_decode(params, cfg):
         assert eng.outputs[rid] == ref["x"], rid
 
 
-def test_chunked_prefill_with_store_hit(params, cfg, shm_conn):
-    """Chunked admission over a cached prefix: restored pages back the
-    chunk attention directly (no contiguous rebuild) with token
-    parity."""
+def test_pieces_with_store_hit(params, cfg, shm_conn):
+    """Admission in pieces over a cached prefix: the restored pages go
+    into the pool with the first piece, and the later pieces attend
+    them there, with token parity."""
     from infinistore_tpu.tpu import TpuKVStore
 
     rng = np.random.default_rng(16)
@@ -627,12 +634,13 @@ def test_chunked_prefill_with_store_hit(params, cfg, shm_conn):
 
     convo = turn1 + out1["t1"]
     turn2 = convo[: (len(convo) // cfg.page_size) * cfg.page_size]
-    turn2 = turn2 + _prompt(rng, cfg, 5)
+    turn2 = turn2 + _prompt(rng, cfg, 13)
     eng2 = ServingEngine(
-        params, cfg, ServingConfig(prefill_chunk=4), store=store
+        params, cfg, ServingConfig(admit_piece=cfg.page_size), store=store
     )
     out2 = eng2.run([Request("t2", turn2, max_new_tokens=6)])
-    assert eng2.stats["prefix_hit_pages"] > 0
+    assert eng2.stats["prefix_hit_pages"] == 2
+    assert eng2.stats["admit_pieces"] == 3
     ref = ServingEngine(params, cfg).run(
         [Request("x", turn2, max_new_tokens=6)]
     )
@@ -692,21 +700,24 @@ def test_sampling_survives_preemption(params, cfg, shm_conn):
         assert out[r.request_id] == ref["x"], r.request_id
 
 
-def test_sampling_rides_chunked_path(params, cfg):
-    """A sampling request through a chunked engine must produce its
-    plain-engine sampled stream (chunk logits feed the sampler, one RNG
-    draw per token). The spec path no longer guarantees STREAM equality
-    for samplers — rejection sampling consumes extra draws — only
-    DISTRIBUTION equality (test_spec_sampling_*)."""
+def test_sampling_rides_pieces(params, cfg):
+    """A sampling request admitted in pieces must produce its
+    plain-engine sampled stream (the last piece's logits row feeds the
+    sampler, one RNG draw per token). The spec path no longer
+    guarantees STREAM equality for samplers — rejection sampling
+    consumes extra draws — only DISTRIBUTION equality
+    (test_spec_sampling_*)."""
     rng = np.random.default_rng(19)
     prompt = _prompt(rng, cfg, 18)
     req = dict(max_new_tokens=10, temperature=0.9, top_k=4, seed=7)
     ref = ServingEngine(params, cfg).run(
         [Request("x", prompt, **req)]
     )["x"]
-    eng = ServingEngine(params, cfg, ServingConfig(prefill_chunk=4))
+    eng = ServingEngine(params, cfg,
+                        ServingConfig(admit_piece=cfg.page_size))
     out = eng.run([Request("r", prompt, **req)])
     assert out["r"] == ref
+    assert eng.stats["admit_pieces"] == 3
 
 
 def test_spec_sampling_accepts_drafts(params, cfg):
@@ -908,8 +919,8 @@ def test_default_model_id_fingerprints_weights(params, cfg, shm_conn):
 
 def test_streaming_on_token_exactly_once_in_order(params, cfg, shm_conn):
     """on_token must deliver every output token exactly once, in order,
-    across plain decode, speculation (multi-token appends), chunked
-    prefill, and preemption/resume."""
+    across plain decode, speculation (multi-token appends), admission
+    in pieces, and preemption/resume."""
     from infinistore_tpu.tpu import TpuKVStore
 
     rng = np.random.default_rng(24)
@@ -932,16 +943,16 @@ def test_streaming_on_token_exactly_once_in_order(params, cfg, shm_conn):
     for rid, toks in out.items():
         assert streamed[rid] == toks, rid
 
-    # Chunked prefill.
+    # Admission in pieces.
     streamed.clear()
     prompt = _prompt(rng, cfg, 21)
     eng2 = ServingEngine(
-        params, cfg, ServingConfig(prefill_chunk=4)
+        params, cfg, ServingConfig(admit_piece=cfg.page_size)
     )
     out2 = eng2.run(
         [Request("c", prompt, max_new_tokens=7, on_token=cb)]
     )
-    assert streamed["c"] == out2["c"]
+    assert streamed["c"] == out2["c"] and eng2.stats["admit_pieces"] == 3
 
     # EOS-truncating speculation: an oracle proposer drives a draft
     # containing the EOS; post-EOS tokens must never reach the stream.
@@ -966,7 +977,7 @@ def test_streaming_on_token_exactly_once_in_order(params, cfg, shm_conn):
 
 @pytest.mark.parametrize("seed", [21, 22, 23])
 def test_engine_config_fuzz_token_parity(params, cfg, seed, shm_conn):
-    """Property test: ANY engine configuration (slots, chunking,
+    """Property test: ANY engine configuration (slots, pieces,
     speculation, store, pool pressure) must emit each request's
     plain-engine token stream. Catches scheduler interactions no
     single-feature test covers."""
@@ -985,7 +996,7 @@ def test_engine_config_fuzz_token_parity(params, cfg, seed, shm_conn):
     sc = ServingConfig(
         max_slots=int(rng.integers(1, 4)),
         total_pages=int(rng.integers(16, 48)),
-        prefill_chunk=int(rng.choice([0, 3, 8])),
+        admit_piece=int(rng.choice([0, 1, 2])) * cfg.page_size,
         spec_k=int(rng.choice([0, 2])),
     )
     store = TpuKVStore(shm_conn) if rng.random() < 0.5 else None
@@ -1175,16 +1186,16 @@ def test_windowed_release_keeps_store_chain(wparams, wcfg, shm_conn):
 
 
 def test_windowed_release_stream_identical_spec_and_chunked(wparams, wcfg):
-    """The speculative-verify and chunked-prefill release sites must be
-    as unobservable as the plain-decode one: stream parity vs a
-    release-disabled engine under spec_k>0 and prefill_chunk>0."""
+    """The speculative-verify and admission-in-pieces release sites
+    must be as unobservable as the plain-decode one: stream parity vs a
+    release-disabled engine under spec_k>0 and admit_piece>0."""
     rng = np.random.default_rng(57)
     prompt = _prompt(rng, wcfg, 20)
     for sc in (
         ServingConfig(max_slots=2, total_pages=64, max_pages_per_seq=16,
                       spec_k=3),
         ServingConfig(max_slots=2, total_pages=64, max_pages_per_seq=16,
-                      prefill_chunk=8),
+                      admit_piece=8),
     ):
         eng = ServingEngine(wparams, wcfg, sc)
         out = eng.run([Request("s", prompt, max_new_tokens=40)])
@@ -1252,39 +1263,124 @@ def test_windowed_release_poisoned_reuse_parity(wparams, wcfg):
     assert eng.outputs["p"] == ref["p"]
 
 
-def test_windowed_release_chunked_with_store(wparams, wcfg, shm_conn):
-    """Chunked-prefill release sites under a store: a prompt much
-    longer than the window frees pages DURING chunk consumption and at
-    chunked admission on the repeat (hit path), with stream parity vs
-    a release-disabled engine and an intact store chain."""
+@pytest.mark.parametrize("pages", [1, 2])
+def test_windowed_pieces_free_pages_between_pieces(wparams, wcfg, pages):
+    """Store-less, one sliding window, a prompt of several windows in
+    pieces: the one-program admission's tokens, and after every piece
+    the slot holds no page that lies wholly below the band's floor."""
+    rng = np.random.default_rng(63)
+    prompt = _prompt(rng, wcfg, 43)  # 6 pages, window 16 = 2 pages
+    page, n_pages = wcfg.page_size, 6
+    sc = dict(max_slots=2, total_pages=64, max_pages_per_seq=16)
+    eng = ServingEngine(wparams, wcfg, ServingConfig(
+        admit_piece=pages * page, **sc))
+    eng.submit(Request("w", prompt, max_new_tokens=12))
+    n_pieces = -(-len(prompt) // (pages * page))
+    for k in range(1, n_pieces):  # the last piece's step decodes too
+        eng.step()
+        assert eng.stats["admit_pieces"] == k
+        seq = k * pages * page
+        assert eng.slots[0].seq_len == seq
+        assert eng.slots[0].released == max(0, seq - wcfg.window) // page
+        assert len(eng.free_pages) == 63 - n_pages + eng.slots[0].released
+    out = eng.run()
+    ref = ServingEngine(wparams, wcfg, ServingConfig(**sc)).run(
+        [Request("w", prompt, max_new_tokens=12)])
+    assert out["w"] == ref["w"]
+    assert sorted(eng.free_pages) == list(range(1, 64))
+
+
+@pytest.mark.parametrize("hit", [False, True])
+def test_first_token_logits_in_pieces_under_a_window(wparams, wcfg, shm_conn,
+                                                     hit):
+    """`first_token_logits` runs what an admission in pieces runs, on
+    pages it takes and gives back: under a window (cold, and over a
+    hit's trimmed restore) the one-program row, nothing released, the
+    free list as it was."""
+    from infinistore_tpu.tpu import TpuKVStore
+
+    rng = np.random.default_rng(67)
+    prompt = _prompt(rng, wcfg, 45)
+    sc = dict(max_slots=2, total_pages=64, max_pages_per_seq=16,
+              model_id="winftl")
+    store = TpuKVStore(shm_conn) if hit else None
+    if hit:  # pages [0, 3) of the prompt, from another engine
+        ServingEngine(wparams, wcfg, ServingConfig(**sc), store=store).run(
+            [Request("seed", prompt[:25], max_new_tokens=1)])
+    one = ServingEngine(wparams, wcfg, ServingConfig(**sc), store=store)
+    eng = ServingEngine(wparams, wcfg, ServingConfig(admit_piece=8, **sc),
+                        store=store)
+    want, hit_one = one.first_token_logits(prompt)
+    got, hit_pieces = eng.first_token_logits(prompt)
+    assert hit_one == hit_pieces == (3 if hit else 0)
+    assert eng.stats["admit_pieces"] == (3 if hit else 6)
+    np.testing.assert_allclose(got, want, atol=1e-4)
+    assert sorted(eng.free_pages) == list(range(1, 64))
+    assert eng.stats["offloaded_pages"] == 0 and eng.uploads_pending == 0
+
+
+def test_windowed_release_in_pieces_with_store(wparams, wcfg, shm_conn):
+    """The release sites of an admission in pieces under a store: a
+    prompt of 5 pages against a window of 2 frees pages BETWEEN pieces
+    (each offloaded first: the store's chain stays gap-free) and on
+    the repeat, a hit whose first piece takes the trimmed restore, with
+    stream parity against a release-disabled engine."""
     from infinistore_tpu.tpu import TpuKVStore
 
     rng = np.random.default_rng(65)
-    prompt = _prompt(rng, wcfg, 40)  # 5 pages, window 16 = 2 pages
+    prompt = _prompt(rng, wcfg, 43)  # 5 pages + 3, window 16 = 2 pages
     store = TpuKVStore(shm_conn)
     sc = ServingConfig(max_slots=2, total_pages=64, max_pages_per_seq=16,
-                       prefill_chunk=8, model_id="winchunk")
+                       admit_piece=8, model_id="winpiece")
     eng = ServingEngine(wparams, wcfg, sc, store=store)
-    out1 = eng.run([Request("k1", prompt, max_new_tokens=24)])
+    eng.submit(Request("k1", prompt, max_new_tokens=24))
+    held = []  # pool pages the slot holds after every piece
+    while eng.stats["admit_pieces"] < 6:
+        eng.step()
+        held.append(sc.total_pages - 1 - len(eng.free_pages))
+    # 6 pages at admission; a piece leaves what its successor attends.
+    assert held == [6, 6, 5, 4, 3, 3], held
+    out1 = eng.run()
+    assert eng.stats["offloaded_pages"] >= 5
 
     ref_eng = ServingEngine(wparams, wcfg, ServingConfig(
-        max_slots=2, total_pages=64, max_pages_per_seq=16,
-        prefill_chunk=8))
+        max_slots=2, total_pages=64, max_pages_per_seq=16, admit_piece=8))
     ref_eng._release_windowed = lambda slot: None
     ref = ref_eng.run([Request("k1", prompt, max_new_tokens=24)])
     assert out1["k1"] == ref["k1"]
+    one = ServingEngine(wparams, wcfg, ServingConfig(
+        max_slots=2, total_pages=64, max_pages_per_seq=16))
+    assert out1["k1"] == one.run(
+        [Request("k1", prompt, max_new_tokens=24)])["k1"]
 
-    # Repeat: chunked admission takes the hit path with trimmed alloc.
+    # The chain is gap-free: every full page of prompt + answer is in
+    # the store, though the pool never held more than 6 of them.
+    told = prompt + out1["k1"]
+    n_full = (len(told) - 1) // wcfg.page_size
+    for layer in range(wcfg.n_layers):
+        for kind in "kv":
+            assert store.cached_prefix_len(content_page_keys(
+                told, wcfg.page_size, n_full, layer, kind,
+                namespace=eng._ns)) == n_full
+
+    # The same document, another question: a hit of 5 pages (of which
+    # the restore brings the 2 its suffix can attend) whose 23 further
+    # tokens go in 3 pieces.
     eng2 = ServingEngine(wparams, wcfg, sc, store=store)
-    out2 = eng2.run([Request("k2", prompt, max_new_tokens=24)])
-    assert eng2.stats["prefix_hit_pages"] > 0
-    assert out2["k2"] == out1["k1"]
+    again = prompt + _prompt(rng, wcfg, 20)
+    out2 = eng2.run([Request("k2", again, max_new_tokens=8)])
+    assert eng2.stats["prefix_hit_pages"] == 5
+    assert eng2.stats["restored_pages"] == 2 * wcfg.n_layers * 2
+    assert eng2.stats["admit_pieces"] == 3
+    assert out2["k2"] == one.run(
+        [Request("k2", again, max_new_tokens=8)])["k2"]
+    assert sorted(eng2.free_pages) == list(range(1, sc.total_pages))
 
 
 @pytest.mark.parametrize("seed", [71, 72, 73, 74])
 def test_engine_config_fuzz_window_and_quantized(cfg, seed, shm_conn):
     """Cross-feature fuzz over the round-5 additions: sliding window x
-    int8 weight quantization x chunking x speculation x store x pool
+    int8 weight quantization x pieces x speculation x store x pool
     pressure. Every configuration must emit each request's token
     stream from a plain engine with the SAME model variant (windowed
     masks and quantized weights change the math, so the oracle shares
@@ -1312,7 +1408,7 @@ def test_engine_config_fuzz_window_and_quantized(cfg, seed, shm_conn):
     sc = ServingConfig(
         max_slots=int(rng.integers(1, 4)),
         total_pages=int(rng.integers(12, 48)),
-        prefill_chunk=int(rng.choice([0, 3, 8])),
+        admit_piece=int(rng.choice([0, 1, 2])) * cfg.page_size,
         spec_k=int(rng.choice([0, 2])),
         host_steps=int(rng.choice([1, 4])),
     )

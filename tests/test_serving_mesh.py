@@ -37,7 +37,7 @@ def _prompt(rng, cfg, n):
     return [int(t) for t in rng.integers(0, cfg.vocab_size, n)]
 
 
-@pytest.mark.parametrize("spec", ["plain", "spec", "chunk"])
+@pytest.mark.parametrize("spec", ["plain", "spec", "pieces"])
 def test_tp_sharded_serving_matches_single_device(params, cfg, spec):
     m = pmesh.make_mesh(pmesh.MeshConfig(dp=1, tp=8))
     sharded = pmesh.shard_params(m, params)
@@ -49,7 +49,7 @@ def test_tp_sharded_serving_matches_single_device(params, cfg, spec):
     sc = {
         "plain": ServingConfig(max_slots=2),
         "spec": ServingConfig(max_slots=2, spec_k=2),
-        "chunk": ServingConfig(max_slots=2, prefill_chunk=4),
+        "pieces": ServingConfig(max_slots=2, admit_piece=cfg.page_size),
     }[spec]
     eng = ServingEngine(sharded, cfg, sc)
     out = eng.run(
@@ -60,6 +60,9 @@ def test_tp_sharded_serving_matches_single_device(params, cfg, spec):
             [Request("x", r.prompt, r.max_new_tokens)]
         )
         assert out[r.request_id] == ref["x"], (spec, r.request_id)
+    # 11 and 19 tokens in pieces of a page of 8: the prefix program
+    # partitions over the mesh as the cold one does
+    assert eng.stats["admit_pieces"] == (5 if spec == "pieces" else 0)
 
 
 def test_tp_decode_kernel_code_path_on_mesh(cfg):

@@ -8,6 +8,8 @@ data paths, spill-only mode never drops data, and eviction mode drops
 only when pool AND disk are full.
 """
 
+import itertools
+import time
 import uuid
 
 import numpy as np
@@ -43,6 +45,20 @@ def make_server(ssd_blocks=64, eviction=False, tmp_path="/tmp"):
     return srv
 
 
+def wait_for_counter(srv, name, poke=None, deadline_s=120.0):
+    """The server's stats once its background work has moved counter
+    `name` off zero: returns as soon as it has, and a loaded box (six
+    test workers on shared cores) has two minutes for it. `poke` runs
+    between polls: what the counter's work is queued by, where one
+    request may be refused under pool pressure and the next not."""
+    deadline = time.monotonic() + deadline_s
+    while srv.stats()[name] == 0 and time.monotonic() < deadline:
+        if poke is not None:
+            poke()
+        time.sleep(0.02)
+    return srv.stats()
+
+
 def connect(srv, ctype=TYPE_SHM):
     c = InfinityConnection(
         ClientConfig(
@@ -73,7 +89,7 @@ def test_spill_and_promote_roundtrip(tmp_path, ctype):
         for i in range(n):
             conn.put_cache(pages[i], [(keys[i], 0)], BLOCK)
             conn.sync()
-        stats = srv.stats()
+        stats = wait_for_counter(srv, "spills")
         assert stats["spills"] > 0, stats
         assert stats["kvmap_len"] == n  # nothing dropped
         # First cold pass: every key intact, served from disk with ZERO
@@ -88,17 +104,19 @@ def test_spill_and_promote_roundtrip(tmp_path, ctype):
         assert stats["promotes"] == 0, stats
         # Second touch on a cold key: the async promote is queued and
         # eventually adopted; the data stays intact throughout.
-        import time
-
-        for i in range(n):
+        def touch(i):
             dst = np.zeros(BLOCK, dtype=np.uint8)
             conn.read_cache(dst, [(keys[i], 0)], BLOCK)
             conn.sync()
             assert np.array_equal(dst, pages[i]), f"key {i} corrupted (2)"
-        deadline = time.time() + 10
-        while time.time() < deadline and srv.stats()["promotes_async"] == 0:
-            time.sleep(0.02)
-        stats = srv.stats()
+
+        for i in range(n):
+            touch(i)
+        # A second touch that meets a pool without headroom queues
+        # nothing (it kicks the reclaimer): the wait touches again.
+        again = itertools.count()
+        stats = wait_for_counter(srv, "promotes_async",
+                                 poke=lambda: touch(next(again) % n))
         assert stats["promotes_async"] > 0, stats
         assert stats["promotes"] >= stats["promotes_async"]
         conn.close()

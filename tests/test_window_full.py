@@ -261,7 +261,7 @@ def test_two_pools_sized_from_the_band(cfg, params):
 
 
 @pytest.mark.parametrize("field,value", [
-    ("spec_k", 2), ("host_steps", 4), ("prefill_chunk", 16),
+    ("spec_k", 2), ("host_steps", 4), ("admit_piece", 16),
     ("quantized_store", True)])
 def test_what_is_not_built_over_two_kinds_is_refused(cfg, params, field,
                                                      value):
