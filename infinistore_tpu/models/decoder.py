@@ -35,6 +35,7 @@ subclass of it. The paging helpers at the bottom turn a model's KV into
 the store's fixed-size pages and back.
 """
 
+import contextlib
 from functools import partial
 
 import jax
@@ -43,6 +44,7 @@ import numpy as np
 
 from ..ops import ssm
 from ..ops.pallas_latent_attention import latent_decode_attention
+from ..ops import sparse_select
 from ..ops.pallas_flash_attention import flash_prefill
 from ..ops.paged_attention import scatter_kv_multi, scatter_kv_to_pages
 from ..ops.pallas_paged_attention import (
@@ -574,12 +576,14 @@ def latent_scale(cfg):
     return (cfg.qk_nope + cfg.qk_rope) ** -0.5 * m * m
 
 
-def latent_project(layer, x, cfg, positions, decode=False):
+def latent_project(layer, x, cfg, positions, decode=False, with_cq=False):
     """(q_nope [b, s, H, nope], q_pe [b, s, H, rope] rotated, the cache
     rows [b, s, latent_width], h the normalised input). `decode`: a
     decode step asks, as of `proj`: Wqb's is the product that goes
     reshaped into the kernel (Wqa's and Wkva's go through a norm, and
-    their weights were never re-laid)."""
+    their weights were never re-laid). `with_cq`: a fifth, the
+    normalised compressed query c_q [b, s, q_lora_rank], which a
+    layer's indexer projects its own queries from."""
     b, s, _ = x.shape
     r = cfg.kv_lora_rank
     with jax.named_scope("attn.qkv"):
@@ -593,13 +597,14 @@ def latent_project(layer, x, cfg, positions, decode=False):
         c = rms_norm(ckv[..., :r], layer["kv_ln"], cfg.norm_eps)
     with jax.named_scope("attn.rope"):
         q_pe = rope(q[..., cfg.qk_nope:], positions, cfg.rope_theta,
-                    yarn=cfg.yarn)
+                    yarn=cfg.yarn, adjacent=cfg.rope_adjacent)
         k_pe = rope(ckv[..., None, r:], positions, cfg.rope_theta,
-                    yarn=cfg.yarn)[..., 0, :]
+                    yarn=cfg.yarn, adjacent=cfg.rope_adjacent)[..., 0, :]
         pad = cfg.latent_width - r - cfg.qk_rope
         rows = jnp.concatenate(
             [c, k_pe, jnp.zeros((b, s, pad), c.dtype)], axis=-1)
-    return q[..., :cfg.qk_nope], q_pe, rows, h
+    out = (q[..., :cfg.qk_nope], q_pe, rows, h)
+    return out + (cq,) if with_cq else out
 
 
 def _wkvb(layer, cfg):
@@ -646,6 +651,136 @@ def latent_decode(layer, cfg, q_nope, q_pe, pool, table, lens, pl):
                                         rank=cfg.kv_lora_rank, layer=pl)
     with jax.named_scope("attn.absorb"):
         out = jnp.einsum("bhr,rhd->bhd", o_lat, w[..., cfg.qk_nope:])
+    return out.reshape(b, -1)
+
+
+# A latent layer under a LEARNED SELECTION (`cfg.index_topk` > 0;
+# DeepSeek-V3.2's lightning indexer, models/glm.py): a query attends
+# the `index_topk` cache rows its layer's indexer scores highest and no
+# other (ops/sparse_select.py). `cfg.indexer_kinds` names, per latent
+# layer, who owns an indexer ("full": its own queries, ONE index key a
+# token, which is cached as a second kind of page) and who borrows
+# ("shared": the selection of the nearest "full" layer below, made in
+# this same program; it computes none and caches none). Where the keys
+# a program can see are `index_topk` or fewer (a shape: an admission's
+# prefix + suffix, a decode step's table width) every row is selected
+# and the layer runs the dense latent path above; the index keys are
+# written all the same, for the longer context that follows.
+
+
+_SELECTION_TAP = None
+
+
+@contextlib.contextmanager
+def selection_tap(into):
+    """While it is open, every selection an owner layer makes in a loop
+    TRACED OR RUN on this thread is appended to the list `into`, in
+    layer order: (positions, taken) as ops/sparse_select.py returns
+    them. For tests and benchmark/tools/selection_agreement.py, which
+    trace a loop inside a jit of their own and return what was
+    tapped; no program of the engine opens it."""
+    global _SELECTION_TAP
+    _SELECTION_TAP = into
+    try:
+        yield into
+    finally:
+        _SELECTION_TAP = None
+
+
+def _tapped(sel):
+    if _SELECTION_TAP is not None:
+        _SELECTION_TAP.append(sel)
+    return sel
+
+
+def owns_indexer(cfg, li):
+    """Whether latent layer `li` owns an indexer (and caches index
+    keys); False for a family without a selection."""
+    return getattr(cfg, "indexer_kinds", ())[li:li + 1] == ("full",)
+
+
+def indexed(cfg, n_keys):
+    """Whether a program whose queries see up to `n_keys` keys runs the
+    selection."""
+    return 0 < getattr(cfg, "index_topk", 0) < n_keys
+
+
+def index_project(layer, cfg, cq, h, positions, keys_only=False):
+    """A "full" layer's indexer: (qI [b, s, Hi, Di], kI [b, s, Di], w
+    [b, s, Hi] float32), qI and kI rotated on their first `qk_rope`
+    lanes. kI = LayerNorm(h Wki) is what the layer caches."""
+    b, s, _ = h.shape
+    hi, di, rl = cfg.index_heads, cfg.index_dim, cfg.qk_rope
+
+    def rotate(x):  # [b, s, heads, di]
+        return jnp.concatenate(
+            [rope(x[..., :rl], positions, cfg.rope_theta,
+                  adjacent=cfg.index_rope_adjacent), x[..., rl:]], axis=-1)
+
+    with jax.named_scope("attn.index"):
+        ki = matmul(h, layer["wki"]).astype(jnp.float32)
+        mean = jnp.mean(ki, axis=-1, keepdims=True)
+        var = jnp.mean(jnp.square(ki - mean), axis=-1, keepdims=True)
+        ki = ((ki - mean) * jax.lax.rsqrt(var + cfg.index_norm_eps)
+              * layer["ki_ln"].astype(jnp.float32)
+              + layer["ki_ln_b"].astype(jnp.float32)).astype(h.dtype)
+        ki = rotate(ki[:, :, None])[:, :, 0]
+        if keys_only:
+            return None, ki, None
+        qi = rotate(matmul(cq, layer["wqi"]).reshape(b, s, hi, di))
+        w = jnp.einsum("bsd,dh->bsh", h, layer["wiw"],
+                       preferred_element_type=jnp.float32) \
+            * (hi ** -0.5 * di ** -0.5)
+    return qi, ki, w
+
+
+def _absorbed_query(layer, cfg, q_nope, q_pe):
+    """[..., H, latent_width]: q_nope through Wkvb's key half, q_pe,
+    zero lanes; scaled."""
+    w = _wkvb(layer, cfg)
+    q_lat = jnp.einsum("...hd,rhd->...hr", q_nope, w[..., :cfg.qk_nope])
+    pad = cfg.latent_width - cfg.kv_lora_rank - cfg.qk_rope
+    return jnp.concatenate(
+        [q_lat, q_pe, jnp.zeros((*q_pe.shape[:-1], pad), q_pe.dtype)],
+        axis=-1) * jnp.asarray(latent_scale(cfg), q_pe.dtype)
+
+
+def latent_selected_prefill(layer, cfg, q_nope, q_pe, rows, sel):
+    """`latent_prefill_attention` under a selection: each of the s
+    queries attends the rows `sel` = (positions [b, s, k], taken
+    [b, s, k]) names, absorbed as a decode step attends (K and V per
+    head are never built; what is read follows the selection):
+    [b, s, H * v_dim]."""
+    b, s = q_nope.shape[:2]
+    w = _wkvb(layer, cfg)
+
+    def absorb(qn, qp):
+        with jax.named_scope("attn.absorb"):
+            return _absorbed_query(layer, cfg, qn, qp)
+
+    o_lat = jax.vmap(
+        lambda qn, qp, r, idx, taken: sparse_select.attend_seq(
+            absorb, (qn, qp), r, idx, taken, cfg.kv_lora_rank)
+    )(q_nope, q_pe, rows, *sel)
+    with jax.named_scope("attn.absorb"):
+        out = jnp.einsum("bshr,rhd->bshd", o_lat, w[..., cfg.qk_nope:])
+    return out.reshape(b, s, -1)
+
+
+def latent_selected_decode(layer, cfg, q_nope, q_pe, pool, table, sel, pl):
+    """`latent_decode` under a selection: the rows `sel` names are
+    gathered through the page table and attended; no other row of the
+    pool is read."""
+    b = q_nope.shape[0]
+    with jax.named_scope("attn.absorb"):
+        q = _absorbed_query(layer, cfg, q_nope, q_pe)
+    idx, taken = sel
+    picked = sparse_select.gather_paged(pool, pl, table, idx)
+    with jax.named_scope("attn.kernel"):
+        o_lat = sparse_select.attend(q, picked, taken, cfg.kv_lora_rank)
+    with jax.named_scope("attn.absorb"):
+        out = jnp.einsum("bhr,rhd->bhd", o_lat,
+                         _wkvb(layer, cfg)[..., cfg.qk_nope:])
     return out.reshape(b, -1)
 
 
@@ -703,14 +838,31 @@ def forward_stack(block, params, cfg, tokens, prefix_kvs=None, pos0=0,
             x = residual(cfg, x, out, mix)
             states.append(st)
         elif kind == "latent":
-            q_nope, q_pe, rows, h_attn = latent_project(layer, x_in, cfg,
-                                                        positions)
+            owner = owns_indexer(cfg, len(kvs))
+            q_nope, q_pe, rows, h_attn, cq = latent_project(
+                layer, x_in, cfg, positions, with_cq=True)
             rows_all = rows if prefix_kvs is None else jnp.concatenate(
                 [prefix_kvs[len(kvs)][0].astype(rows.dtype), rows], axis=1)
-            attn = latent_prefill_attention(layer, cfg, q_nope, q_pe,
-                                            rows_all)
+            selects = indexed(cfg, rows_all.shape[1])
+            ki = None
+            if owner:  # the layer's index keys, cached beside its rows
+                qi, ki, wi = index_project(layer, cfg, cq, h_attn,
+                                           positions,
+                                           keys_only=not selects)
+            if owner and selects:
+                keys = ki if prefix_kvs is None else jnp.concatenate(
+                    [prefix_kvs[len(kvs)][1].astype(ki.dtype), ki], axis=1)
+                sel = _tapped(jax.vmap(partial(
+                    sparse_select.select_seq, k=cfg.index_topk)
+                )(qi, wi, keys, positions - pos0))
+            if selects:
+                attn = latent_selected_prefill(layer, cfg, q_nope, q_pe,
+                                               rows_all, sel)
+            else:
+                attn = latent_prefill_attention(layer, cfg, q_nope, q_pe,
+                                                rows_all)
             x = residual(cfg, x, attn_out(layer, attn), mix)
-            kvs.append((rows, None))
+            kvs.append((rows, ki))
         else:
             band, rotates, pool, _ = spec[len(kvs)]
             q, k, v, h_attn = _qkv(layer, x_in, cfg, positions, rotates)
@@ -765,7 +917,10 @@ def decode_step(block, params, cfg, token, seq_lens, k_pages, v_pages,
                 fetched, summed over the layers (int32; 0 where no
                 block reports any); where the layers hold a share of
                 their experts, int32 [2]: that, and the valid rows'
-                pairs that fell on experts held here.
+                pairs that fell on experts held here. Where layers
+                attend under a learned selection, between the two: the
+                cache rows the valid rows' attention took, summed over
+                those layers (int32 [3]).
 
     Returns (logits [batch, vocab] fp32, k_pages, v_pages): the pools
     it was given with, per attention layer, the new token's K and V
@@ -804,8 +959,8 @@ def decode_step(block, params, cfg, token, seq_lens, k_pages, v_pages,
     valid = (seq_lens > 0)[:, None]  # [b, 1]
 
     spec = attn_layers(cfg)
-    li = mi = 0  # rank among the attention / the state layers
-    hs, convs, experts, pairs = [], [], [], []
+    li = mi = ii = 0  # rank among the attention / state / index layers
+    hs, convs, experts, pairs, taken = [], [], [], [], []
     for layer, kind in zip(params["layers"], cfg.layer_kinds):
         h_attn = None
         x_in, mix = stream_in(cfg, layer, x, "attn")
@@ -817,16 +972,39 @@ def decode_step(block, params, cfg, token, seq_lens, k_pages, v_pages,
             convs.append(conv)
             mi += 1
         elif kind == "latent":
-            # ONE pool (k_pages), no V pool: v_pages is None throughout
-            q_nope, q_pe, rows, h_attn = latent_project(
-                layer, x_in, cfg, positions, decode=True)
+            # ONE pool of rows (k_pages); v_pages is None, or where
+            # some layers own an indexer the pool of their index keys
+            owner = owns_indexer(cfg, li)
+            q_nope, q_pe, rows, h_attn, cq = latent_project(
+                layer, x_in, cfg, positions, decode=True, with_cq=True)
             held = pools["full"]
             _, _, table, lens, target_page, slot = held
+            selects = indexed(cfg, table.shape[1] * cfg.page_size)
             with jax.named_scope("pool.update"):
                 held[0] = held[0].at[li, target_page, slot].set(
                     rows[:, 0], mode="drop")
-            attn = latent_decode(layer, cfg, q_nope[:, 0], q_pe[:, 0],
-                                 held[0], table, lens + 1, li)
+            if owner:
+                # ... and its index key into the second pool (held[1]:
+                # [index layers, pages, page, index_dim]), layer = the
+                # layer's rank among the owners
+                qi, ki, wi = index_project(layer, cfg, cq, h_attn,
+                                           positions, keys_only=not selects)
+                with jax.named_scope("pool.update"):
+                    held[1] = held[1].at[ii, target_page, slot].set(
+                        ki[:, 0], mode="drop")
+                if selects:
+                    sel = _tapped(sparse_select.select_paged(
+                        qi[:, 0], wi[:, 0], held[1], ii, table, lens + 1,
+                        cfg.index_topk))
+                ii += 1
+            if selects:
+                attn = latent_selected_decode(
+                    layer, cfg, q_nope[:, 0], q_pe[:, 0], held[0], table,
+                    sel, li)
+                taken.append(jnp.sum(sel[1] & valid))
+            else:
+                attn = latent_decode(layer, cfg, q_nope[:, 0], q_pe[:, 0],
+                                     held[0], table, lens + 1, li)
             x = residual(cfg, x, attn_out(layer, attn[:, None]), mix)
             li += 1
         else:
@@ -863,8 +1041,10 @@ def decode_step(block, params, cfg, token, seq_lens, k_pages, v_pages,
     if win is not None:
         out += tuple(pools["window"][:2])
     if fetched:
-        count = sum(experts, jnp.int32(0))
-        out += (jnp.stack([count, sum(pairs)]) if pairs else count,)
+        counts = [sum(experts, jnp.int32(0))]
+        counts += [sum(taken)] if taken else []
+        counts += [sum(pairs)] if pairs else []
+        out += (jnp.stack(counts) if len(counts) > 1 else counts[0],)
     return out
 
 
@@ -1011,12 +1191,17 @@ def restored_to_pages(cfg, flat):
     stacks in pool form: (k_pages, v_pages) [n_kv_layers, n, page,
     n_kv, hd]. One transpose; traceable, so the serving engine's hit program
     (serving._admit_fused_px) does it on the device inside the program
-    that also scatters the stacks into the pool."""
-    n = flat.shape[0] // (2 * cfg.n_kv_layers)
+    that also scatters the stacks into the pool. For a family whose
+    kinds of page share ONE shape and ONE set of layers (K and V; a
+    latent family's rows alone): one stack a kind of `cfg.page_kinds`,
+    of that kind's shape (`cfg.page_shape`)."""
+    kinds = cfg.page_kinds
+    n = flat.shape[0] // (len(kinds) * cfg.n_kv_layers)
     both = jnp.moveaxis(
-        flat.reshape(n, cfg.n_kv_layers, 2, *cfg.kv_page_shape()), 0, 2
+        flat.reshape(n, cfg.n_kv_layers, len(kinds),
+                     *cfg.page_shape(kinds[0])), 0, 2
     )
-    return both[:, 0], both[:, 1]
+    return tuple(both[:, i] for i in range(len(kinds)))
 
 
 def restore_prefix_pages(store, cfg, key_fn, n_pages,
@@ -1028,22 +1213,38 @@ def restore_prefix_pages(store, cfg, key_fn, n_pages,
     `getter` overrides the fetch method (e.g.
     store.get_kv_pages_quantized for int8 pages).
 
-    ONE batched store call covers every (layer, kind): 2L small
-    fetches would pay 2L pin/transfer round trips where the batch pays
-    one, and one large DMA beats 2L small ones. The keys go page-major
-    (page, layer, k then v), the order the serving engine's offload
-    allocates them in (serving.content_page_keys_by_page): pages that
-    one offload wrote then lie in the store's pool in the order asked
-    for, and the SHM read is one zero-copy view of the pool and not a
-    view a block plus a stacking copy. The split back into per-layer
-    stacks is one device transpose, then slicing (`restored_to_pages`).
-    Returns (k_pages, v_pages) [n_layers, n_pages, page, n_kv, hd]."""
+    ONE batched store call covers every (layer, kind) of one shape: 2L
+    small fetches would pay 2L pin/transfer round trips where the batch
+    pays one, and one large DMA beats 2L small ones. The keys go
+    page-major (page, layer, k then v), the order the serving engine's
+    offload allocates them in (serving.content_page_keys_by_page):
+    pages that one offload wrote then lie in the store's pool in the
+    order asked for, and the SHM read is one zero-copy view of the pool
+    and not a view a block plus a stacking copy. The split back into
+    per-layer stacks is one device transpose, then slicing
+    (`restored_to_pages`). Returns one stack a kind of
+    `cfg.page_kinds`: (k_pages, v_pages) [n_layers, n_pages, page,
+    n_kv, hd]. A family whose kinds differ in shape or in the layers
+    that keep them (models/glm.py: rows on every layer, index keys on
+    some) makes a call a kind, since a call carries pages of one
+    shape, each page-major over ITS layers (`cfg.page_layers`), and
+    returns each kind's stack [its layers, n_pages, *its shape]."""
     get = getter if getter is not None else store.get_kv_pages
-    per = [key_fn(li, kind) for li in range(cfg.n_kv_layers)
-           for kind in "kv"]
-    keys = [ks[p] for p in range(n_pages) for ks in per]
-    return restored_to_pages(
-        cfg, get(keys, cfg.kv_page_shape(), cfg.jdtype))
+    kinds = cfg.page_kinds
+    first = (cfg.page_shape(kinds[0]), cfg.page_layers(kinds[0]))
+    if all((cfg.page_shape(k), cfg.page_layers(k)) == first for k in kinds):
+        per = [key_fn(li, kind) for li in first[1] for kind in kinds]
+        keys = [ks[p] for p in range(n_pages) for ks in per]
+        return restored_to_pages(cfg, get(keys, first[0], cfg.jdtype))
+    out = []
+    for kind in kinds:
+        shape, layers = cfg.page_shape(kind), cfg.page_layers(kind)
+        per = [key_fn(li, kind) for li in layers]
+        keys = [ks[p] for p in range(n_pages) for ks in per]
+        flat = get(keys, shape, cfg.jdtype)
+        out.append(jnp.moveaxis(
+            flat.reshape(n_pages, len(layers), *shape), 0, 1))
+    return tuple(out)
 
 
 def restore_prefix_kvs(store, cfg, seq_id, n_pages):

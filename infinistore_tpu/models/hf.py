@@ -804,3 +804,122 @@ def cohere_moe_config_from_hf(hf_cfg, page_size=16, dtype="float32"):
         q_init_gain=float(init["query_gain"]) if init else 1.0,
         dtype=dtype,
     )
+
+
+def glm_dsa_config_from_hf(hf_cfg, page_size=16, dtype="float32"):
+    """Map a ``model_type: glm_moe_dsa`` ``config.json`` (zai-org/
+    GLM-5.2; any object with its keys as attributes) onto
+    :class:`models.glm.GlmConfig`: latent attention's ranks and head
+    widths, the indexer's heads, width and ``index_topk``,
+    ``indexer_types`` as the per-layer spec of who owns an indexer and
+    who borrows the nearest one below, ``mlp_layer_types`` as the spec
+    of dense and sparse layers, the sigmoid router with its bias and
+    scale, the shared expert, rotary in adjacent pairs
+    (``rope_interleave``, ``indexer_rope_interleave``) at
+    ``rope_parameters.rope_theta``. One chip's share of the routed
+    experts is the group ``expert_share``, as
+    :func:`cohere_moe_config_from_hf` reads it, and the widths a random
+    checkpoint draws three kinds of matrix at the group ``random_init``
+    (``query_gain``: Wqb; ``attn_out_gain``: Wo; ``ffn_out_gain``: the
+    feed-forwards' down projections; each absent or 1: as every other
+    matrix). The top-level
+    ``head_dim`` equals ``qk_nope_head_dim`` and plays no part.
+    Refused, because models/glm.py does not implement it: expert groups
+    (``n_group`` / ``topk_group`` over 1), ``index_topk_pattern`` not
+    null, an ``indexer_types`` list whose first layer is ``shared``,
+    whose length is not the depth or with an unknown entry, a
+    ``mlp_layer_types`` list likewise or at odds with
+    ``first_k_dense_replace``, a multi-token-prediction module
+    (``num_nextn_predict_layers`` over 0: it is not held), a
+    ``rope_type`` other than default, another score than sigmoid or
+    selection than ``noaux_tc``, gates not normalised on the chosen,
+    an activation other than silu, attention bias, tied embeddings.
+    Config only: no public key names a checkpoint's tensors."""
+    from .glm import GlmConfig
+
+    def refuse(what):
+        raise NotImplementedError(
+            f"glm_moe_dsa: {what} is not implemented by models/glm.py")
+
+    def group(name):
+        v = g(name)
+        return v if v is None or isinstance(v, dict) else vars(v)
+
+    g = lambda k, d=None: getattr(hf_cfg, k, d)  # noqa: E731
+    if g("n_group", 1) != 1 or g("topk_group", 1) != 1:
+        refuse(f"expert groups (n_group {g('n_group')}, topk_group "
+               f"{g('topk_group')})")
+    if g("index_topk_pattern") is not None:
+        refuse(f"index_topk_pattern {g('index_topk_pattern')!r}")
+    if g("num_nextn_predict_layers", 0):
+        refuse("a multi-token-prediction module "
+               f"(num_nextn_predict_layers {g('num_nextn_predict_layers')})")
+    rp = group("rope_parameters") or {}
+    if rp.get("rope_type", "default") != "default":
+        refuse(f"rope_type {rp.get('rope_type')!r}")
+    if g("scoring_func", "sigmoid") != "sigmoid":
+        refuse(f"scoring_func {g('scoring_func')!r}")
+    if g("topk_method", "noaux_tc") != "noaux_tc":
+        refuse(f"topk_method {g('topk_method')!r}")
+    if not g("norm_topk_prob", True):
+        refuse("norm_topk_prob false")
+    if g("hidden_act", "silu") != "silu":
+        refuse(f"hidden_act {g('hidden_act')!r}")
+    if g("attention_bias", False):
+        refuse("attention_bias")
+    if g("tie_word_embeddings", False):
+        refuse("tie_word_embeddings (the head is a leaf of its own)")
+    n = hf_cfg.num_hidden_layers
+    owners = tuple(hf_cfg.indexer_types)
+    if len(owners) != n or set(owners) - {"full", "shared"}:
+        refuse(f"an indexer_types list of {len(owners)} entries "
+               f"{sorted(set(owners))} for {n} layers")
+    if owners[0] != "full":
+        refuse("an indexer_types list whose first layer is shared (it "
+               "has no layer below to borrow from)")
+    lead = g("first_k_dense_replace", 0)
+    mlps = tuple(g("mlp_layer_types") or
+                 ["dense"] * lead + ["sparse"] * (n - lead))
+    if len(mlps) != n or set(mlps) - {"dense", "sparse"}:
+        refuse(f"a mlp_layer_types list of {len(mlps)} entries "
+               f"{sorted(set(mlps))} for {n} layers")
+    if mlps != ("dense",) * lead + ("sparse",) * (n - lead):
+        refuse(f"a mlp_layer_types list at odds with "
+               f"first_k_dense_replace {lead}")
+    share, init = group("expert_share"), group("random_init")
+    return GlmConfig(
+        vocab_size=hf_cfg.vocab_size,
+        d_model=hf_cfg.hidden_size,
+        n_layers=n,
+        n_heads=hf_cfg.num_attention_heads,
+        n_kv_heads=hf_cfg.num_attention_heads,
+        head_dim_override=hf_cfg.qk_nope_head_dim + hf_cfg.qk_rope_head_dim,
+        d_ff=hf_cfg.moe_intermediate_size,
+        ffn_dense=hf_cfg.intermediate_size,
+        dense_layers=tuple(m == "dense" for m in mlps),
+        n_experts=hf_cfg.n_routed_experts,
+        n_routed=int(share["router_width"]) if share else 0,
+        first_expert=int(share["first_expert"]) if share else 0,
+        top_k=hf_cfg.num_experts_per_tok,
+        n_shared=g("n_shared_experts", 0) or 0,
+        route_scale=float(g("routed_scaling_factor", 1.0)),
+        q_lora_rank=hf_cfg.q_lora_rank,
+        kv_lora_rank=hf_cfg.kv_lora_rank,
+        qk_nope=hf_cfg.qk_nope_head_dim,
+        qk_rope=hf_cfg.qk_rope_head_dim,
+        v_dim=hf_cfg.v_head_dim,
+        rope_adjacent=bool(g("rope_interleave", True)),
+        index_heads=hf_cfg.index_n_heads,
+        index_dim=hf_cfg.index_head_dim,
+        index_topk=hf_cfg.index_topk,
+        indexer_kinds=owners,
+        index_rope_adjacent=bool(g("indexer_rope_interleave", True)),
+        q_init_gain=float((init or {}).get("query_gain", 1.0)),
+        o_init_gain=float((init or {}).get("attn_out_gain", 1.0)),
+        down_init_gain=float((init or {}).get("ffn_out_gain", 1.0)),
+        max_seq=hf_cfg.max_position_embeddings,
+        page_size=page_size,
+        rope_theta=float(rp.get("rope_theta", g("rope_theta", 10000.0))),
+        norm_eps=float(hf_cfg.rms_norm_eps),
+        dtype=dtype,
+    )

@@ -164,6 +164,20 @@ class LlamaConfig:
 
         return int(np.prod(self.kv_page_shape())) * self.jdtype.itemsize
 
+    def page_shape(self, kind):
+        """Shape of ONE layer's page of `kind` (a letter of
+        `page_kinds`): `kv_page_shape()`, the first kind's, for every
+        kind here. A family whose kinds differ in width
+        (models/glm.py: latent rows "c", index keys "i") overrides
+        it."""
+        return self.kv_page_shape()
+
+    def page_layers(self, kind):
+        """Which of the layers that keep pages (by their rank among
+        them: the L of a page's store key) keep a page of `kind`: all
+        of them here; models/glm.py's index keys live on some."""
+        return tuple(range(self.n_kv_layers))
+
 
 def init_params(rng, cfg: LlamaConfig):
     """Plain-dict pytree; leaf names match parallel.mesh sharding rules."""

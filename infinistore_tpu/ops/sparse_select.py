@@ -1,0 +1,148 @@
+"""A learned selection of cache rows (DeepSeek-V3.2's lightning indexer,
+GLM-5.2's `index_*` keys): index scores over a sequence's index keys,
+the EXACT top-k of them, and latent attention over the rows the
+selection names and no other. Plain jax.numpy on every backend: the
+gathers are XLA's.
+
+    I(t, s) = sum_j w(t, j) relu(qI_j(t) . kI(s))        s <= t
+    S(t)    = the min(t + 1, k) positions of largest I(t, .)
+    o(t, h) = sum_{s in S(t)} softmax_s(q_h(t) . row_s) row_s[:rank]
+
+The scores' products take their inputs as they are handed over (the
+cached keys in the pool's type) and accumulate in float32; relu, the
+weights and the sum over the index heads are float32. `select` is
+`jax.lax.top_k` (ties go to the lower position) and nothing that may
+return another set. The attention is the absorbed form of
+ops/pallas_latent_attention.py (`latent_decode_xla`'s arithmetic) over
+gathered rows: what it reads follows the selection, not the context.
+
+Two callers (models/decoder.py): a decode step, one query a sequence
+over the paged pools through the page table (`*_paged`), and an
+admission, a block of queries at a time over the contiguous rows of
+prefix + suffix (`*_seq`).
+"""
+
+import jax
+import jax.numpy as jnp
+
+F32 = jnp.float32
+NEG = -1e30
+# Queries an admission scores, selects and attends at a time: the
+# float32 scores of a block are [block, index heads, keys] (64 x 32 x
+# 35,072 x 4 B = 287 MB) and its gathered rows [block, k, width]
+# (64 x 2,048 x 640 x 2 B = 168 MB).
+QUERY_BLOCK = 64
+
+
+def _precision(dtype):
+    return jax.lax.Precision.HIGHEST if dtype == F32 else None
+
+
+def _scores(q, w, keys, eq):
+    """sum_j w_j relu(q_j . k), float32. `eq`: the products' einsum."""
+    dots = jnp.einsum(eq, q, keys.astype(q.dtype),
+                      preferred_element_type=F32,
+                      precision=_precision(q.dtype))
+    return jnp.sum(jax.nn.relu(dots) * w.astype(F32)[..., None], axis=-2)
+
+
+def select(scores, n_live, k):
+    """The exact top-min(n_live, k) of each row of `scores` [n, S]
+    over its first `n_live` [n] positions: (positions [n, k'] int32,
+    taken [n, k'] bool), k' = min(k, S); positions not taken are
+    arbitrary (in range)."""
+    s = scores.shape[-1]
+    k = min(k, s)
+    live = jnp.arange(s)[None] < n_live[:, None]
+    _, idx = jax.lax.top_k(jnp.where(live, scores, -jnp.inf), k)
+    taken = jnp.arange(k)[None] < jnp.minimum(n_live, k)[:, None]
+    return idx.astype(jnp.int32), taken
+
+
+def attend(q, rows, taken, rank):
+    """Absorbed latent attention of each query over ITS gathered rows.
+    q: [n, H, width] (scaled); rows: [n, k, width]; taken: [n, k].
+    Returns o_lat [n, H, rank] in q's type."""
+    precision = _precision(q.dtype)
+    logits = jnp.einsum("nhw,nkw->nhk", q, rows,
+                        preferred_element_type=F32, precision=precision)
+    logits = jnp.where(taken[:, None], logits, NEG)
+    p = jax.nn.softmax(logits, axis=-1)
+    out = jnp.einsum("nhk,nkr->nhr", p.astype(q.dtype), rows[..., :rank],
+                     preferred_element_type=F32, precision=precision)
+    return out.astype(q.dtype)
+
+
+# ---- a decode step: one query a sequence, over the paged pools ---------
+
+
+def select_paged(q, w, ipool, layer, page_table, n_live, k):
+    """One query a sequence over the index keys its page table names.
+    q: [b, Hi, Di]; w: [b, Hi]; ipool: [index layers, pages, page, Di];
+    n_live: [b] keys to score (the new token's included). Returns
+    `select`'s pair, positions counted in the sequence."""
+    with jax.named_scope("attn.index"):
+        keys = ipool.at[(layer, page_table)].get(mode="clip")
+        b, n, page, di = keys.shape
+        scores = _scores(q, w, keys.reshape(b, n * page, di),
+                         "bhd,bsd->bhs")
+    with jax.named_scope("attn.topk"):
+        return select(scores, n_live, k)
+
+
+def gather_paged(pool, layer, page_table, idx):
+    """Rows `idx` [b, k] (positions in the sequence) of layer `layer`
+    of the paged pool [layers, pages, page, width]: [b, k, width]. The
+    pool is addressed where it lies: no layer is sliced out."""
+    page = pool.shape[2]
+    with jax.named_scope("attn.gather"):
+        pages = jnp.take_along_axis(page_table, idx // page, axis=1)
+        return pool.at[(jnp.full_like(pages, layer), pages,
+                        idx % page)].get(mode="clip")
+
+
+# ---- an admission: blocks of queries over contiguous rows --------------
+
+
+def _blocked(fn, n, *arrays):
+    """`fn` over blocks of QUERY_BLOCK of the leading `n` entries of
+    `arrays` (padded up with copies of entry 0), its results' leading
+    axes joined and cut back to n."""
+    block = min(QUERY_BLOCK, n)
+    pad = -n % block
+
+    def cut(a):
+        a = jnp.concatenate([a, jnp.broadcast_to(a[:1], (pad, *a.shape[1:]))])
+        return a.reshape(-1, block, *a.shape[1:])
+
+    out = jax.lax.map(lambda xs: fn(*xs), tuple(cut(a) for a in arrays))
+    return jax.tree_util.tree_map(
+        lambda a: a.reshape(-1, *a.shape[2:])[:n], out)
+
+
+def select_seq(q, w, keys, positions, k):
+    """The queries of one sequence at `positions` [s] (position t sees
+    keys 0 .. t) over its index keys [S, Di]. q: [s, Hi, Di]; w:
+    [s, Hi]. Returns `select`'s pair [s, k']."""
+    def one(qb, wb, pos):
+        with jax.named_scope("attn.index"):
+            scores = _scores(qb, wb, keys, "qhd,sd->qhs")
+        with jax.named_scope("attn.topk"):
+            return select(scores, pos + 1, k)
+
+    return _blocked(one, q.shape[0], q, w, positions)
+
+
+def attend_seq(absorb, q_parts, rows, idx, taken, rank):
+    """Each query over its selected rows of `rows` [S, width]. `absorb`
+    turns a block of `q_parts` (arrays with a leading query axis) into
+    the absorbed, scaled queries [block, H, width]. Returns o_lat
+    [s, H, rank]."""
+    def one(idx_b, taken_b, *parts):
+        q = absorb(*parts)
+        with jax.named_scope("attn.gather"):
+            picked = jnp.take(rows, idx_b, axis=0)
+        with jax.named_scope("attn.kernel"):
+            return attend(q, picked, taken_b, rank)
+
+    return _blocked(one, idx.shape[0], idx, taken, *q_parts)

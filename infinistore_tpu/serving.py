@@ -416,6 +416,17 @@ def _decode_scan(params, cfg, token, seq_lens, k_pages, v_pages, rows,
     return toks.T, lens, kp, vp  # [batch, n_steps]
 
 
+def _index_kind(cfg):
+    """The letter of a latent family's SECOND kind of page (index keys
+    of the layers that own an indexer: models/glm.py, "i"), or None: a
+    kind with a shape and a set of layers of its own
+    (`cfg.page_shape`, `cfg.page_layers`), held in the second pool
+    under the same page ids."""
+    kinds = cfg.page_kinds
+    return kinds[1] if "latent" in cfg.layer_kinds and len(kinds) > 1 \
+        else None
+
+
 def _page_out(cfg, kvs, k_pages, v_pages, ids):
     """A prefill's per-layer (k, v) [1, s_pad, kv, hd], cut into pages
     and scattered into the pools at the first s_pad // page of `ids`
@@ -428,6 +439,14 @@ def _page_out(cfg, kvs, k_pages, v_pages, ids):
         k_sfx = jnp.stack([k[0] for k, _ in kvs]).reshape(shape)
         if v_pages is None:  # a latent family's one pool
             return k_pages.at[:, ids[:m]].set(k_sfx, mode="drop"), None
+        kind = _index_kind(cfg)
+        if kind:
+            # ... and the pool of the index keys of the layers that own
+            # an indexer (the second of their pair), a width of its own
+            i_sfx = jnp.stack([i[0] for _, i in kvs if i is not None])
+            i_sfx = i_sfx.reshape(i_sfx.shape[0], m, *cfg.page_shape(kind))
+            return (k_pages.at[:, ids[:m]].set(k_sfx, mode="drop"),
+                    v_pages.at[:, ids[:m]].set(i_sfx, mode="drop"))
         v_sfx = jnp.stack([v[0] for _, v in kvs]).reshape(shape)
         k_pages = k_pages.at[:, ids[:m]].set(k_sfx, mode="drop")
         v_pages = v_pages.at[:, ids[:m]].set(v_sfx, mode="drop")
@@ -489,6 +508,28 @@ def _place_restored(cfg, restored, k_pages, v_pages, restored_ids):
                                                            mode="drop")
             pfx = jnp.moveaxis(rows, 0, 1).reshape(L, 1, n * page, -1)
         return k_pages, None, [(pfx[li], None) for li in range(L)]
+    kind = _index_kind(cfg)
+    if kind:
+        # ... with index keys on some layers: `restored` is the pair
+        # of the two kinds' store calls, each page-major over ITS
+        # layers; a layer's prefix is (rows, index keys or None).
+        owners = cfg.page_layers(kind)
+        with jax.named_scope("pool.update"):
+            rows = restored[0].reshape(n, L, *cfg.kv_page_shape())
+            keys = restored[1].reshape(n, len(owners),
+                                       *cfg.page_shape(kind))
+            for li in range(L):
+                k_pages = k_pages.at[li, restored_ids].set(rows[:, li],
+                                                           mode="drop")
+            for j in range(len(owners)):
+                v_pages = v_pages.at[j, restored_ids].set(keys[:, j],
+                                                          mode="drop")
+            pfx = jnp.moveaxis(rows, 0, 1).reshape(L, 1, n * page, -1)
+            ipfx = jnp.moveaxis(keys, 0, 1).reshape(
+                len(owners), 1, n * page, -1)
+        return k_pages, v_pages, [
+            (pfx[li], ipfx[owners.index(li)] if li in owners else None)
+            for li in range(L)]
     with jax.named_scope("pool.update"):  # stage names: models/decoder.py
         # Each layer's pages go from the page-major rows straight into
         # that layer of the pool. Scattered as one `[:, ids]` update
@@ -1052,8 +1093,21 @@ class ServingEngine:
         self._latent = "latent" in cfg.layer_kinds
         if self._latent:
             self._check_latent_family()
-        self.v_pages = None if self._latent \
-            else jnp.zeros_like(self.k_pages)
+        # ... unless some of its layers own an indexer: the index keys
+        # they cache are a second kind of page, of a width of its own
+        # on those layers alone, held in the second pool under the
+        # same page ids ([index layers, pages, page, index_dim]).
+        self._index_kind = _index_kind(cfg)
+        self._index_layers = list(cfg.page_layers(self._index_kind)) \
+            if self._index_kind else []
+        if self._index_kind:
+            self.v_pages = jnp.zeros(
+                (len(self._index_layers), self.sc.total_pages,
+                 *cfg.page_shape(self._index_kind)),
+                dtype=cfg.jdtype, device=self.device)
+        else:
+            self.v_pages = None if self._latent \
+                else jnp.zeros_like(self.k_pages)
         self.wk_pages = self.wv_pages = None
         if self._win_layers:
             self._init_window_pools()
@@ -1075,6 +1129,10 @@ class ServingEngine:
         self.page_table = np.zeros(
             (self.sc.max_slots, self.sc.max_pages_per_seq), dtype=np.int32
         )
+        # whether a decode step over this table selects the rows it
+        # attends (a table of index_topk rows or fewer is the dense path)
+        self._selects = bool(self._index_kind) and decoder.indexed(
+            cfg, self.page_table.shape[1] * cfg.page_size)
         self.slots = [None] * self.sc.max_slots
         self.queue = []
         self.outputs = {}
@@ -1144,6 +1202,15 @@ class ServingEngine:
             # wrote to the store and hits restored from it
             "admit_pieces": 0, "latent_pages_written": 0,
             "latent_pages_restored": 0,
+            # a learned selection (models/glm.py), over the decode
+            # steps' active sequences: index keys their layers'
+            # indexers scored, cache rows their attention layers read
+            # and rows that were live there (counted from the lengths
+            # held here); index pages (a sequence page of one owner
+            # layer each) that offloads wrote and hits restored
+            "index_keys_scored": 0, "attn_rows_selected": 0,
+            "attn_rows_live": 0, "index_pages_offloaded": 0,
+            "index_pages_restored": 0,
         }
         # Requests finished: in `outputs`, or held for their offload's
         # acknowledgement. What a driver reads as progress.
@@ -1184,6 +1251,21 @@ class ServingEngine:
         kinds = len(cfg.page_kinds)
         self._page_objects = kinds * self.k_pages.shape[0]
         self._page_bytes = kinds * self.k_pages.nbytes // self.sc.total_pages
+        # The store keys that stand for a page in a hit's probe: one.
+        self._probe_kinds = [(0, cfg.page_kinds[0])]
+        if self._index_kind:
+            # ... but two kinds of different sizes on different layers:
+            # the rows' bytes (`_cpage_bytes`) and the index keys'; a
+            # page is a hit only with both, so the probe asks for the
+            # LAST object an offload writes of each kind.
+            self._cpage_bytes = self.k_pages.nbytes // self.sc.total_pages
+            self._page_objects = self.k_pages.shape[0] \
+                + len(self._index_layers)
+            self._page_bytes = self._cpage_bytes \
+                + self.v_pages.nbytes // self.sc.total_pages
+            self._probe_kinds = [
+                (self.k_pages.shape[0] - 1, cfg.page_kinds[0]),
+                (self._index_layers[-1], self._index_kind)]
         if self.sc.admit_piece % cfg.page_size:
             raise ValueError(
                 f"admit_piece {self.sc.admit_piece} is no multiple of the "
@@ -1237,6 +1319,10 @@ class ServingEngine:
             # ... a latent row is no K page, whatever its bytes
             self._ns += f"/latent{cfg.kv_lora_rank}+{cfg.qk_rope}" \
                 f"w{cfg.latent_width}"
+        if self._index_kind:
+            # ... and which layers keep index keys, how wide
+            self._ns += (f"/index{cfg.index_dim}@"
+                         + ".".join(map(str, self._index_layers)))
         if self._win_layers:
             # ... of every cache kind: which layers are banded, and how
             # widely (a page of a banded layer is not a page of a full
@@ -1319,6 +1405,29 @@ class ServingEngine:
             "quantized_store": sc.quantized_store,
             "kv_pack": cfg.kv_pack > 1, "window": bool(cfg.window_band),
             "state layers": bool(getattr(cfg, "n_state_layers", 0))})
+        if _index_kind(cfg):
+            self._check_index_family()
+
+    def _check_index_family(self):
+        """What is not built over an index pool (a latent family whose
+        layers select the rows they attend, models/glm.py) is refused
+        at construction too. Beside what a latent cache refuses (a
+        verify or burst step would have to make and carry a selection
+        a drafted token, and the int8 wire knows one page shape): a
+        spec that is not one entry a layer or whose first layer
+        borrows a selection (none is made below it; so some layer owns
+        an indexer and the second pool has a layer), and several
+        residual streams (the
+        indexer reads ONE normalised stream; which, no published model
+        says)."""
+        cfg = self.cfg
+        kinds = getattr(cfg, "indexer_kinds", ())
+        self._refuse("an index pool", {
+            "an indexer spec that is not one entry a layer":
+                len(kinds) != cfg.n_layers,
+            "a first layer that borrows its selection":
+                kinds[:1] != ("full",),
+            "hc_mult": cfg.hc_mult > 1})
 
     def _check_state_family(self):
         """What is not built over a recurrent state is refused at
@@ -1479,15 +1588,18 @@ class ServingEngine:
                         pages=cap) as f:
             digests = self._digests(work.prompt, cap)
             try:
-                hit = self.store.cached_prefix_len(
-                    content_page_keys(work.prompt, self.cfg.page_size,
-                                      cap, 0, self.cfg.page_kinds[0],
-                                      digests=digests)
-                )
+                # ONE call over the probed key of every kind of each
+                # page, page-major: a page counts with all of them
+                per = len(self._probe_kinds)
+                found = self.store.cached_prefix_len(
+                    [key for d in digests[:cap]
+                     for layer, kind in self._probe_kinds
+                     for key in content_page_keys_by_page([d], [layer],
+                                                          kind)])
             except Exception as e:
                 self._store_failed("probe", e)
                 return 0, []
-            hit = min(hit, cap)
+            hit = min(found // per, cap)
             if hit > 0 and self.state is not None:
                 hit = self._probe_snapshot(hit, digests)
             f["hit_pages"] = hit
@@ -1541,6 +1653,8 @@ class ServingEngine:
             if self._win_layers:  # what the restore will read, no more
                 keys = self._restore_keys(hit, digests,
                                           self._first_live(hit))
+            elif self._index_kind:
+                keys = sum(self._restore_keys(hit, digests, 0), [])
             else:
                 keys = [key for li in range(cfg.n_kv_layers)
                         for kind in cfg.page_kinds
@@ -1691,8 +1805,15 @@ class ServingEngine:
         with self._span("istpu.cache.restore", pages=n, bytes=nbytes,
                         foreign_pages=foreign, **kinds,
                         **self._snapshot_fields) as f:
-            pages = self._get_pages(keys, self.cfg.kv_page_shape(),
+            if self._index_kind:
+                # a call a kind: a store call carries pages of ONE shape
+                pages = tuple(
+                    self._get_pages(ks, self.cfg.page_shape(kind),
                                     self.cfg.jdtype)
+                    for ks, kind in zip(keys, self.cfg.page_kinds))
+            else:
+                pages = self._get_pages(keys, self.cfg.kv_page_shape(),
+                                        self.cfg.jdtype)
             # How the pages lay in the store's pool: the contiguous runs
             # the read spanned and the bytes it copied on the host (0:
             # one run, transferred from the pool itself). A store that
@@ -1741,9 +1862,13 @@ class ServingEngine:
         self.stats["prefix_hit_pages"] += hit
         self.stats["foreign_hit_pages"] += foreign
         f["foreign_pages"] = foreign
-        self.stats["restored_pages"] += restored.shape[0]
+        rows, *keys = restored if self._index_kind else (restored,)
+        self.stats["restored_pages"] += rows.shape[0]
         if self._latent:
-            self.stats["latent_pages_restored"] += restored.shape[0]
+            self.stats["latent_pages_restored"] += rows.shape[0]
+        for k in keys:
+            self.stats["restored_pages"] += k.shape[0]
+            self.stats["index_pages_restored"] += k.shape[0]
         self.stats["snapshots_restored"] += snap is not None
         if self._win_layers:
             self.stats["restore_trimmed_pages"] += first_live
@@ -1765,6 +1890,13 @@ class ServingEngine:
         full layers' [0, hit) page-major, then the banded layers'
         [first_live, hit) page-major: each kind in the order its
         offloads wrote it, so each reads back in few runs."""
+        if self._index_kind:
+            # A kind of its own shape is a call of its own: [the rows'
+            # keys, the index keys' keys], each page-major over the
+            # layers that keep that kind.
+            return [content_page_keys_by_page(
+                digests[first_live:hit], self.cfg.page_layers(kind), kind)
+                for kind in self.cfg.page_kinds]
         if not self._win_layers:
             return content_page_keys_by_page(digests[first_live:hit],
                                              self.cfg.n_kv_layers,
@@ -1901,10 +2033,18 @@ class ServingEngine:
                 if restored is None:
                     with self._span("istpu.cache.pool_read",
                                     pages=held - lo):
-                        restored = _gather_pages(
-                            self.k_pages, self.v_pages, self._to_device(
-                                np.asarray(r_ids, np.int32))
-                        ).reshape(-1, *self.cfg.kv_page_shape())
+                        at = self._to_device(np.asarray(r_ids, np.int32))
+                        if self._index_kind:
+                            restored = tuple(
+                                _gather_pages(pool, None, at).reshape(
+                                    -1, *self.cfg.page_shape(kind))
+                                for pool, kind in zip(
+                                    (self.k_pages, self.v_pages),
+                                    self.cfg.page_kinds))
+                        else:
+                            restored = _gather_pages(
+                                self.k_pages, self.v_pages, at
+                            ).reshape(-1, *self.cfg.kv_page_shape())
                     r_ids = [self.sc.total_pages] * len(r_ids)  # in place
                 row = self._prefill_hit(tokens, restored, lo * page,
                                         r_ids, ids)
@@ -2431,15 +2571,30 @@ class ServingEngine:
                      counts={"offloaded_pages": n, "snapshots_written":
                              int(self.state is not None),
                              "latent_pages_written":
-                             n * self._page_objects * self._latent})
+                             n * self.k_pages.shape[0] * self._latent,
+                             "index_pages_offloaded":
+                             n * len(self._index_layers)})
         with self._span("istpu.cache.offload", rid, reason=reason, pages=n,
                         bytes=nbytes, padded_pages=0, puts=0,
                         **self._snapshot_fields) as f:
             if not self._upload_room(nbytes):
                 return None
-            self._gather_pool_pages(
-                up, f, self.k_pages, self.v_pages, slot.page_ids[lo:n_full],
-                new_digests, self._full_layers, self._page_bytes)
+            if self._index_kind:
+                # a gather, a transfer and a store batch a kind: the
+                # rows of every layer, then the owners' index keys
+                kc, ki = self.cfg.page_kinds
+                self._gather_pool_pages(
+                    up, f, self.k_pages, None, slot.page_ids[lo:n_full],
+                    new_digests, self._full_layers, self._cpage_bytes, kind=kc)
+                self._gather_pool_pages(
+                    up, f, self.v_pages, None, slot.page_ids[lo:n_full],
+                    new_digests, self._index_layers,
+                    self._page_bytes - self._cpage_bytes, kind=ki)
+            else:
+                self._gather_pool_pages(
+                    up, f, self.k_pages, self.v_pages,
+                    slot.page_ids[lo:n_full], new_digests,
+                    self._full_layers, self._page_bytes)
             if nw:
                 self._gather_pool_pages(
                     up, f, self.wk_pages, self.wv_pages,
@@ -2462,14 +2617,19 @@ class ServingEngine:
                    max(1, OFFLOAD_CHUNK_BYTES // page_bytes))
 
     def _gather_pool_pages(self, up, f, k_pool, v_pool, page_ids, digests,
-                           layers, page_bytes, bucket=_offload_bucket):
+                           layers, page_bytes, bucket=_offload_bucket,
+                           kind=None):
         """Pages `page_ids` of one pair of pools onto the upload `up`,
         page i under `digests[i]`'s keys for `layers` (the pool's
         layers, by their rank among those that keep pages). In chunks
         of at most OFFLOAD_CHUNK_BYTES, each one gather program and
         one device-to-host transfer, all dispatched here and now; each
         is one store batch on the upload thread. `f`: the offload
-        span's fields (`padded_pages`)."""
+        span's fields (`padded_pages`). `kind`: the one kind of page
+        the pool holds (a pool of its own shape: `cfg.page_shape`);
+        None: every kind of the family, of the first kind's shape."""
+        kinds = kind or self.cfg.page_kinds
+        shape = self.cfg.page_shape(kinds[0])
         c = self._chunk_pages(page_bytes)
         for a in range(0, len(page_ids), c):
             # The chunk's ids, padded to a bucket with the scratch
@@ -2484,10 +2644,8 @@ class ServingEngine:
             # quantizes there, so only packed int8 crosses over.
             if not self.sc.quantized_store:
                 flat.copy_to_host_async()
-            up.chunks.append((flat, self.cfg.kv_page_shape(),
-                              content_page_keys_by_page,
-                              (digests[a:a + c], layers,
-                               self.cfg.page_kinds)))
+            up.chunks.append((flat, shape, content_page_keys_by_page,
+                              (digests[a:a + c], layers, kinds)))
 
     def _gather_snapshot_rows(self, up, slot, digest):
         """The slot's boundary copy onto the upload `up`, keyed by
@@ -3110,7 +3268,9 @@ class ServingEngine:
         key, token_dev, lens_dev, rows_dev = self._step_inputs(
             active, greedy, f, more)
         df["live_pages"] = self._count_attn_pages(active, more=more)
-        sparse = self._experts_held > 0
+        if self._index_kind:
+            self._count_selected(active, df, more)
+        sparse = self._experts_held > 0 or self._selects
         pulled = ()  # in place of nxt_dev, where the step counts experts
         if self._win_layers:
             (logits, nxt_dev, lens_next, self.k_pages, self.v_pages,
@@ -3160,6 +3320,11 @@ class ServingEngine:
             df["experts_fetched"] = fetched
             self.stats["moe_experts_fetched"] += fetched
             self.stats["moe_experts_held"] += self._experts_held
+        if flight.counted and self._selects:
+            # the cache rows the step's attention took, as the device
+            # counted them behind its selections (decoder.decode_step)
+            df["rows_selected"] = int(nxt[self.sc.max_slots + 1])
+            self.stats["attn_rows_selected"] += df["rows_selected"]
         if self._share_layers:
             df["pairs_held"] = int(nxt[-1])
             self.stats["moe_pairs_routed"] += (
@@ -3202,6 +3367,30 @@ class ServingEngine:
         self.stats["attn_pages_live"] += k * live
         self.stats["attn_pages_table"] += k * self._attn_table
         return live
+
+    def _count_selected(self, active, df, more=0):
+        """Count what one decode step of `active` under a learned
+        selection reads, from the lengths held here plus `more` (no
+        device work): the rows live in the attention layers
+        (`rows_live` of the step's span) and the index keys the owners'
+        indexers score, which is every entry of every row's page table
+        (ops/sparse_select.py `select_paged` scores the table whole and
+        masks what is not live). The rows the attention TOOK are the
+        device's count and arrive with the step's tokens (`_land`). A
+        table no wider than `index_topk` runs the dense path: every
+        live row is read, no key is scored, and nothing is counted on
+        the device."""
+        live = self.k_pages.shape[0] * sum(
+            s.seq_len + more + 1 for _, s in active)
+        df["rows_live"] = live
+        self.stats["attn_rows_live"] += live
+        if self._selects:
+            self.stats["index_keys_scored"] += (
+                len(self._index_layers) * self.page_table.size
+                * self.cfg.page_size)
+        else:
+            df["rows_selected"] = live
+            self.stats["attn_rows_selected"] += live
 
     def _copy_boundaries(self, active, more=0):
         """Behind a decode step of a family with state (whose lengths

@@ -170,6 +170,17 @@ def benchmark_tests_pinned_before_pr26(request, monkeypatch):
             return
         if _command_a_in_the_pinned_tests(node, name, module, monkeypatch):
             return
+        if _glm_in_the_pinned_tests(node, name, module, monkeypatch):
+            return
+    if module.__name__.endswith("test_bench_command_a") and name == \
+            "test_the_cell_its_configuration_and_its_metrics_are_in_the_manifest":
+        # ... asserts that PR 42's cell, configuration and two metrics
+        # are the LAST: it is shown the manifest without what PR 46
+        # appended
+        load = module.manifest.load
+        monkeypatch.setattr(module.manifest, "load",
+                            lambda *a, **kw: _as_before_pr46(load(*a, **kw)))
+        return
     if module.__name__.endswith("test_bench_manifest") \
             and name == "test_reduced_never_names_a_width":
         # It holds every configuration to mistral7b's widths (4096,
@@ -182,13 +193,16 @@ def benchmark_tests_pinned_before_pr26(request, monkeypatch):
         # leading dense layers, the prediction module);
         # command-a-plus's by tests/benchmark/test_bench_command_a.py
         # (depth, its per-layer list, the experts HELD, the
-        # vocabulary's slice: the chip's share, no width).
+        # vocabulary's slice: the chip's share, no width); glm-5.2's
+        # by tests/benchmark/test_bench_glm.py (depth, the leading
+        # dense layers, its two per-layer lists, the experts HELD, the
+        # vocabulary's slice, the prediction module: no width).
         bench = dict(module.BENCH)
         bench["configs"] = [c for c in bench["configs"]
                             if c["name"] not in ("granite4h-micro",
                                                  "smallthinker21b",
                                                  "xing4-29b",
-                                                 _COMMAND_A)]
+                                                 _COMMAND_A, _GLM)]
         monkeypatch.setattr(module, "BENCH", bench)
         return
     if module.__name__.endswith("test_bench_observations") \
@@ -225,7 +239,7 @@ def benchmark_tests_pinned_before_pr26(request, monkeypatch):
             for m in bench["per_layer"]:
                 for later in ("granite4h-micro-sessions4k",
                               "smallthinker21b-sessions12k",
-                              _XING_CELL, _COMMAND_A_CELL):
+                              _XING_CELL, _COMMAND_A_CELL, _GLM_CELL):
                     if later in m.get("workloads", ()):
                         m["workloads"].remove(later)
             return bench
@@ -262,12 +276,74 @@ def _decode_ahead_in_the_pinned_tests(node, name, module, monkeypatch):
 
 _XING_CELL = "xing4-29b-docs32k"
 _COMMAND_A, _COMMAND_A_CELL = "command-a-plus", "command-a-plus-mixed12k"
+_GLM, _GLM_CELL = "glm-5.2", "glm-5.2-docs32k-answers"
+
+
+def _as_before_pr46(bench):
+    """The manifest without what PR 46 appended: the configuration
+    glm-5.2, its cell, its five per-layer metrics and the cell's name
+    on the older metrics' lists."""
+    bench["workloads"] = [w for w in bench["workloads"]
+                          if w["name"] != _GLM_CELL]
+    bench["configs"] = [c for c in bench["configs"] if c["name"] != _GLM]
+    bench["per_layer"] = [m for m in bench["per_layer"]
+                          if m.get("workloads") != [_GLM_CELL]]
+    for m in bench["per_layer"]:
+        if _GLM_CELL in m.get("workloads", ()):
+            m["workloads"].remove(_GLM_CELL)
+    return bench
+
+
+def _glm_in_the_pinned_tests(node, name, module, monkeypatch):
+    """PR 46 (`model_config`: may add benchmark files, edit none) added
+    the configuration glm-5.2 and five per-layer metrics; as
+    `_xing_in_the_pinned_tests` for PR 40's. Returns True where it
+    dealt with the test: the table test gets the five new metrics'
+    hand-worked numbers from tests/benchmark/glm_by_hand.py, and the
+    configuration's cases of "resolves to today's defaults" are skipped
+    (it names a costs module, tolerances and programs of its own, which
+    tests/benchmark/test_bench_glm.py holds)."""
+    import pytest
+
+    params = getattr(getattr(node, "callspec", None), "params", {})
+    if name == "test_an_accepted_configuration_resolves_to_todays_defaults":
+        if params.get("config") == _GLM:
+            pytest.skip("glm-5.2 brings its own costs and tolerances: "
+                        "test_bench_glm.py")
+        return False
+    if name != "test_reader_gives_the_number_worked_by_hand":
+        return False
+    import glm_by_hand as by_hand
+
+    if params.get("name") not in by_hand.BY_HAND:
+        return False
+    from benchmark.lib import serve
+    from benchmark.metrics import _scoped_ops
+    from infinistore_tpu.utils import profiling
+
+    table, window = module.expected, module.full_window
+
+    def full_window():
+        obs = window()
+        obs.conf = serve.load_config("benchmark/configs/glm-5.2.json")
+        return obs
+
+    monkeypatch.setattr(module, "full_window", full_window)
+    monkeypatch.setattr(module, "expected",
+                        lambda obs: {**table(window()), **by_hand.BY_HAND})
+    monkeypatch.setattr(profiling, "spans", lambda: by_hand.RING)
+    monkeypatch.setattr(
+        _scoped_ops, "seconds",
+        lambda obs, kind, scopes: by_hand.SCOPED[kind, tuple(scopes)])
+    return True
 
 
 def _as_before_pr42(bench):
     """The manifest without what PR 42 appended: the configuration
     command-a-plus, its cell, its two per-layer metrics and the cell's
-    name on the older metrics' lists."""
+    name on the older metrics' lists (nor what PR 46 appended behind
+    them)."""
+    _as_before_pr46(bench)
     bench["workloads"] = [w for w in bench["workloads"]
                           if w["name"] != _COMMAND_A_CELL]
     bench["configs"] = [c for c in bench["configs"]

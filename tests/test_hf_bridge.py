@@ -969,3 +969,109 @@ def test_cohere_config_maps_every_key():
 def test_cohere_bridge_refuses_what_is_not_implemented(changed, match):
     with pytest.raises(NotImplementedError, match=match):
         hf.cohere_moe_config_from_hf(_cohere(**changed))
+
+
+# ---------------------------------------------------------------------------
+# glm_moe_dsa (GLM-5.2): latent attention under a learned selection
+# ---------------------------------------------------------------------------
+
+GLM = dict(
+    attention_bias=False, ep_size=1, first_k_dense_replace=1, head_dim=16,
+    hidden_act="silu", hidden_size=64, index_head_dim=16, index_n_heads=4,
+    index_share_for_mtp_iteration=True, index_skip_topk_offset=3,
+    index_topk=32, index_topk_freq=4, index_topk_pattern=None,
+    indexer_rope_interleave=True,
+    indexer_types=["full", "shared", "shared", "shared", "full"],
+    intermediate_size=128, kv_lora_rank=32, max_position_embeddings=4096,
+    mlp_layer_types=["dense", "sparse", "sparse", "sparse", "sparse"],
+    model_type="glm_moe_dsa", moe_intermediate_size=32, moe_layer_freq=1,
+    n_group=1, n_routed_experts=8, n_shared_experts=1, norm_topk_prob=True,
+    num_attention_heads=4, num_experts_per_tok=2, num_hidden_layers=5,
+    num_key_value_heads=4, num_nextn_predict_layers=0, q_lora_rank=48,
+    qk_head_dim=24, qk_nope_head_dim=16, qk_rope_head_dim=8,
+    rms_norm_eps=1e-5, rope_interleave=True,
+    rope_parameters={"rope_theta": 8000000, "rope_type": "default"},
+    routed_scaling_factor=2.5, scoring_func="sigmoid",
+    tie_word_embeddings=False, topk_group=1, topk_method="noaux_tc",
+    v_head_dim=16, vocab_size=128,
+)
+
+
+def _glm(**changed):
+    from types import SimpleNamespace
+
+    return SimpleNamespace(**{**GLM, **changed})
+
+
+def test_glm_config_maps_every_key():
+    from infinistore_tpu.models.hf import glm_dsa_config_from_hf
+
+    cfg = glm_dsa_config_from_hf(_glm(), page_size=8, dtype="bfloat16")
+    assert (cfg.d_model, cfg.n_layers, cfg.n_heads, cfg.n_kv_heads,
+            cfg.vocab_size, cfg.max_seq, cfg.page_size, cfg.dtype) == (
+        64, 5, 4, 4, 128, 4096, 8, "bfloat16")
+    assert (cfg.q_lora_rank, cfg.kv_lora_rank, cfg.qk_nope, cfg.qk_rope,
+            cfg.v_dim, cfg.head_dim) == (48, 32, 16, 8, 16, 24)
+    assert (cfg.index_heads, cfg.index_dim, cfg.index_topk) == (4, 16, 32)
+    assert cfg.indexer_kinds == ("full", "shared", "shared", "shared",
+                                 "full") and cfg.index_layers == (0, 4)
+    assert cfg.dense_layers == (True, False, False, False, False)
+    assert (cfg.ffn_dense, cfg.d_ff, cfg.n_experts, cfg.top_k, cfg.n_shared,
+            cfg.route_scale, cfg.router) == (128, 32, 8, 2, 1, 2.5,
+                                             "sigmoid")
+    assert (cfg.n_routed, cfg.first_expert) == (0, 0) and not cfg.holds_share
+    assert cfg.rope_theta == 8e6 and cfg.norm_eps == 1e-5
+    assert cfg.rope_adjacent and cfg.index_rope_adjacent and cfg.yarn == ()
+    assert cfg.q_init_gain == 1.0
+    # half-split rotary where the config says so
+    split = glm_dsa_config_from_hf(_glm(rope_interleave=False,
+                                        indexer_rope_interleave=False))
+    assert not split.rope_adjacent and not split.index_rope_adjacent
+    # one chip's share, and the random query projection's width
+    share = glm_dsa_config_from_hf(_glm(
+        n_routed_experts=2,
+        expert_share={"router_width": 8, "first_expert": 4},
+        random_init={"query_gain": 4.0, "attn_out_gain": 0.125}))
+    assert (share.n_experts, share.n_routed, share.first_expert) == (2, 8, 4)
+    assert share.holds_share and share.q_init_gain == 4.0
+    assert (cfg.o_init_gain, cfg.down_init_gain) == (1.0, 1.0)
+    assert (share.o_init_gain, share.down_init_gain) == (0.125, 1.0)
+    assert glm_dsa_config_from_hf(_glm(
+        random_init={"ffn_out_gain": 0.25})).down_init_gain == 0.25
+    # without the list, first_k_dense_replace says which layers are dense
+    lead = glm_dsa_config_from_hf(_glm(mlp_layer_types=None,
+                                       first_k_dense_replace=2))
+    assert lead.dense_layers == (True, True, False, False, False)
+    # the page contract's two kinds
+    assert cfg.page_kinds == "ci" and cfg.page_shape("i") == (8, 16)
+    assert cfg.page_shape("c") == cfg.kv_page_shape() == (8, 128)
+
+
+@pytest.mark.parametrize("changed,match", [
+    (dict(n_group=2), "expert groups"),
+    (dict(topk_group=2), "expert groups"),
+    (dict(index_topk_pattern=[1, 0, 0, 0]), "index_topk_pattern"),
+    (dict(indexer_types=["shared", "full", "shared", "shared", "full"]),
+     "first layer is shared"),
+    (dict(indexer_types=["full", "shared"]), "indexer_types list of 2"),
+    (dict(indexer_types=["full", "none", "shared", "shared", "full"]),
+     "indexer_types list"),
+    (dict(mlp_layer_types=["dense", "sparse"]), "mlp_layer_types list of 2"),
+    (dict(mlp_layer_types=["sparse", "dense", "sparse", "sparse",
+                           "sparse"]), "at odds"),
+    (dict(first_k_dense_replace=3), "at odds"),
+    (dict(num_nextn_predict_layers=1), "multi-token-prediction"),
+    (dict(rope_parameters={"rope_theta": 1e4, "rope_type": "yarn"}),
+     "rope_type"),
+    (dict(scoring_func="softmax"), "scoring_func"),
+    (dict(topk_method="greedy"), "topk_method"),
+    (dict(norm_topk_prob=False), "norm_topk_prob"),
+    (dict(hidden_act="gelu"), "hidden_act"),
+    (dict(attention_bias=True), "attention_bias"),
+    (dict(tie_word_embeddings=True), "tie_word_embeddings"),
+])
+def test_glm_bridge_refuses_what_is_not_implemented(changed, match):
+    from infinistore_tpu.models.hf import glm_dsa_config_from_hf
+
+    with pytest.raises(NotImplementedError, match=match):
+        glm_dsa_config_from_hf(_glm(**changed))

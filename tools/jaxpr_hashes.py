@@ -1,8 +1,9 @@
 #!/usr/bin/env python3
 """Hashes of the jaxprs of the serving engine's fused programs (cold and
 prefix admission, decode step, offload gather) for the five families
-that keep K and V pages (cohere since PR 43) and the one that keeps a
-latent row (xing, since PR 42), at tiny widths. A change that must
+that keep K and V pages (cohere since PR 43), the one that keeps a
+latent row (xing, since PR 42) and the one that keeps index keys
+beside it on some layers (glm, since PR 46), at tiny widths. A change that must
 leave their programs alone is checked by running this in both trees and
 comparing the output (PR 40: the parent unpacked under build/parent):
 
@@ -33,6 +34,10 @@ FAMILIES = {
         n_layers=4, layer_bands=(32, 32, 32, 0),
         layer_rope=(True, True, True, False), n_experts=2, top_k=2,
         n_routed=8, first_expert=2)),
+    "glm": ("glm", "GlmConfig", dict(
+        n_layers=5, dense_layers=(True, False, False, False, False),
+        indexer_kinds=("full", "shared", "shared", "shared", "full"),
+        index_topk=16, n_experts=2, top_k=2, n_routed=8, first_expert=2)),
 }
 
 
@@ -100,6 +105,15 @@ def programs(name):
         (params, toks, eng.k_pages, eng.v_pages, ids, jnp.int32(30)))
     restored = jnp.zeros((2 * L * len(cfg.page_kinds), *cfg.kv_page_shape()),
                          cfg.jdtype)
+    pools = (eng.k_pages, eng.v_pages)
+    if getattr(eng, "_index_kind", None):
+        # a second kind of page of its own shape on some layers: the
+        # restored pages are a pair, a gather reads one pool
+        restored = tuple(
+            jnp.zeros((2 * len(cfg.page_layers(kind)),
+                       *cfg.page_shape(kind)), cfg.jdtype)
+            for kind in cfg.page_kinds)
+        pools = (eng.k_pages, None)
     out["prefix"] = (
         lambda p, t, r, k, v, ri, si, s, p0:
         serving._admit_fused_px.__wrapped__(
@@ -112,7 +126,7 @@ def programs(name):
         (params, slots, slots, eng.k_pages, eng.v_pages, rows))
     out["gather"] = (
         lambda k, v, i: serving._gather_pages.__wrapped__(k, v, i),
-        (eng.k_pages, eng.v_pages, jnp.asarray([1, 2], i32)))
+        (*pools, jnp.asarray([1, 2], i32)))
     return out
 
 
