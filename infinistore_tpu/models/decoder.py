@@ -767,17 +767,22 @@ def latent_selected_prefill(layer, cfg, q_nope, q_pe, rows, sel):
     return out.reshape(b, s, -1)
 
 
-def latent_selected_decode(layer, cfg, q_nope, q_pe, pool, table, sel, pl):
+def latent_selected_decode(layer, cfg, q_nope, q_pe, pool, table, sel, pl,
+                           active):
     """`latent_decode` under a selection: the rows `sel` names are
-    gathered through the page table and attended; no other row of the
-    pool is read."""
+    gathered through the page table and attended, for the decoding
+    slots alone (`active`: sparse_select.active_first's); no other row
+    of the pool is read."""
     b = q_nope.shape[0]
     with jax.named_scope("attn.absorb"):
         q = _absorbed_query(layer, cfg, q_nope, q_pe)
-    idx, taken = sel
-    picked = sparse_select.gather_paged(pool, pl, table, idx)
-    with jax.named_scope("attn.kernel"):
-        o_lat = sparse_select.attend(q, picked, taken, cfg.kv_lora_rank)
+
+    def attend(q, table, idx, taken):
+        picked = sparse_select.gather_paged(pool, pl, table, idx)
+        with jax.named_scope("attn.kernel"):
+            return sparse_select.attend(q, picked, taken, cfg.kv_lora_rank)
+
+    o_lat = sparse_select.over_active(attend, active, q, table, *sel)
     with jax.named_scope("attn.absorb"):
         out = jnp.einsum("bhr,rhd->bhd", o_lat,
                          _wkvb(layer, cfg)[..., cfg.qk_nope:])
@@ -920,7 +925,8 @@ def decode_step(block, params, cfg, token, seq_lens, k_pages, v_pages,
                 pairs that fell on experts held here. Where layers
                 attend under a learned selection, between the two: the
                 cache rows the valid rows' attention took, summed over
-                those layers (int32 [3]).
+                those layers, and the slots the selection's stages ran
+                over (int32 [4]).
 
     Returns (logits [batch, vocab] fp32, k_pages, v_pages): the pools
     it was given with, per attention layer, the new token's K and V
@@ -957,6 +963,9 @@ def decode_step(block, params, cfg, token, seq_lens, k_pages, v_pages,
     # for (best-effort: a previously-active slot's stale row still
     # counts as valid).
     valid = (seq_lens > 0)[:, None]  # [b, 1]
+    # ... and under a learned selection the slots its stages run over
+    active = sparse_select.active_first(valid[:, 0]) if indexed(
+        cfg, page_table.shape[1] * cfg.page_size) else None
 
     spec = attn_layers(cfg)
     li = mi = ii = 0  # rank among the attention / state / index layers
@@ -993,14 +1002,15 @@ def decode_step(block, params, cfg, token, seq_lens, k_pages, v_pages,
                     held[1] = held[1].at[ii, target_page, slot].set(
                         ki[:, 0], mode="drop")
                 if selects:
-                    sel = _tapped(sparse_select.select_paged(
-                        qi[:, 0], wi[:, 0], held[1], ii, table, lens + 1,
-                        cfg.index_topk))
+                    sel = _tapped(sparse_select.over_active(
+                        partial(sparse_select.select_paged, ipool=held[1],
+                                layer=ii, k=cfg.index_topk),
+                        active, qi[:, 0], wi[:, 0], table, lens + 1))
                 ii += 1
             if selects:
                 attn = latent_selected_decode(
                     layer, cfg, q_nope[:, 0], q_pe[:, 0], held[0], table,
-                    sel, li)
+                    sel, li, active)
                 taken.append(jnp.sum(sel[1] & valid))
             else:
                 attn = latent_decode(layer, cfg, q_nope[:, 0], q_pe[:, 0],
@@ -1042,7 +1052,8 @@ def decode_step(block, params, cfg, token, seq_lens, k_pages, v_pages,
         out += tuple(pools["window"][:2])
     if fetched:
         counts = [sum(experts, jnp.int32(0))]
-        counts += [sum(taken)] if taken else []
+        if taken:
+            counts += [sum(taken), sparse_select.slots_run(active)]
         counts += [sum(pairs)] if pairs else []
         out += (jnp.stack(counts) if len(counts) > 1 else counts[0],)
     return out

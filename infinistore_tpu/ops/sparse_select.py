@@ -16,10 +16,11 @@ return another set. The attention is the absorbed form of
 ops/pallas_latent_attention.py (`latent_decode_xla`'s arithmetic) over
 gathered rows: what it reads follows the selection, not the context.
 
-Two callers (models/decoder.py): a decode step, one query a sequence
-over the paged pools through the page table (`*_paged`), and an
-admission, a block of queries at a time over the contiguous rows of
-prefix + suffix (`*_seq`).
+Two callers (models/decoder.py): a decode step, one query a DECODING
+sequence over the paged pools through the page table (`*_paged` under
+`over_active`: the slots that hold no sequence are not scored, sorted
+or gathered for), and an admission, a block of queries at a time over
+the contiguous rows of prefix + suffix (`*_seq`).
 """
 
 import jax
@@ -76,8 +77,63 @@ def attend(q, rows, taken, rank):
 # ---- a decode step: one query a sequence, over the paged pools ---------
 
 
-def select_paged(q, w, ipool, layer, page_table, n_live, k):
-    """One query a sequence over the index keys its page table names.
+def ladder(b):
+    """The batch sizes the selection of a decode step of `b` slots runs
+    at: 1, 2, 4, ... below b, and b."""
+    return [1 << i for i in range((b - 1).bit_length())] + [b]
+
+
+def active_first(valid):
+    """For `over_active`, from a decode step's `valid` [b] (the slots
+    that hold a sequence): (the slots in a stable order with the valid
+    ones first [b] int32, the rung of `ladder(b)` that is the least
+    size to hold them all: a traced index)."""
+    order = jnp.argsort(~valid, stable=True).astype(jnp.int32)
+    count = jnp.sum(valid)
+    return order, sum((count > n).astype(jnp.int32)
+                      for n in ladder(valid.shape[0])[:-1])
+
+
+def slots_run(active):
+    """The slots `over_active` runs over under `active`: the size of
+    its rung (traced int32)."""
+    order, rung = active
+    return jnp.asarray(ladder(order.shape[0]), jnp.int32)[rung]
+
+
+def over_active(fn, active, *arrays):
+    """`fn(*arrays)` of a decode step, run over the decoding slots
+    alone. `arrays` and `fn`'s results are arrays with a leading slot
+    axis [b]; `active` is `active_first`'s pair. ONE program: a branch a
+    rung n of `ladder(b)`, each `fn` as it is over the first n slots of
+    the order (the full batch: over `arrays` as they come), its results
+    put back in slot order. A slot that was not run reads zeros (a
+    selection: nothing taken), and what follows ignores it as it
+    ignored what an empty slot computed. Called OUTSIDE any named scope:
+    the conditional itself belongs to no stage, `fn`'s operations keep
+    theirs."""
+    order, rung = active
+    b = order.shape[0]
+
+    def at(n):
+        def run(order, *xs):
+            with jax.named_scope("attn.active"):
+                rows = order[:n]
+                xs = [x[rows] for x in xs]
+            out = fn(*xs)
+            with jax.named_scope("attn.active"):
+                return jax.tree_util.tree_map(
+                    lambda a: jnp.zeros((b, *a.shape[1:]), a.dtype)
+                    .at[rows].set(a), out)
+
+        return run if n < b else lambda order, *xs: fn(*xs)
+
+    return jax.lax.switch(rung, [at(n) for n in ladder(b)], order, *arrays)
+
+
+def select_paged(q, w, page_table, n_live, ipool, layer, k):
+    """One query a sequence over the index keys its page table names,
+    every entry of the table scored and what is not live masked.
     q: [b, Hi, Di]; w: [b, Hi]; ipool: [index layers, pages, page, Di];
     n_live: [b] keys to score (the new token's included). Returns
     `select`'s pair, positions counted in the sequence."""

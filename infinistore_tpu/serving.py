@@ -1203,12 +1203,15 @@ class ServingEngine:
             "admit_pieces": 0, "latent_pages_written": 0,
             "latent_pages_restored": 0,
             # a learned selection (models/glm.py), over the decode
-            # steps' active sequences: index keys their layers'
-            # indexers scored, cache rows their attention layers read
-            # and rows that were live there (counted from the lengths
-            # held here); index pages (a sequence page of one owner
-            # layer each) that offloads wrote and hits restored
-            "index_keys_scored": 0, "attn_rows_selected": 0,
+            # steps: index keys in every slot's table a layer that
+            # owns an indexer, the slots the selection ran over (the
+            # share of those keys it scored) and those of them that
+            # held a sequence, cache rows the attention layers read and
+            # rows that were live there (counted from the lengths held
+            # here); index pages (a sequence page of one owner layer
+            # each) that offloads wrote and hits restored
+            "index_keys_scored": 0, "select_rows_run": 0,
+            "select_rows_active": 0, "attn_rows_selected": 0,
             "attn_rows_live": 0, "index_pages_offloaded": 0,
             "index_pages_restored": 0,
         }
@@ -3321,10 +3324,16 @@ class ServingEngine:
             self.stats["moe_experts_fetched"] += fetched
             self.stats["moe_experts_held"] += self._experts_held
         if flight.counted and self._selects:
-            # the cache rows the step's attention took, as the device
-            # counted them behind its selections (decoder.decode_step)
+            # the cache rows the step's attention took and the slots
+            # its selections ran over (of which `active` held a
+            # sequence), as the device counted them
+            # (decoder.decode_step)
             df["rows_selected"] = int(nxt[self.sc.max_slots + 1])
+            df["select_rows_run"] = int(nxt[self.sc.max_slots + 2])
+            df["select_rows_active"] = len(active)
             self.stats["attn_rows_selected"] += df["rows_selected"]
+            self.stats["select_rows_run"] += df["select_rows_run"]
+            self.stats["select_rows_active"] += len(active)
         if self._share_layers:
             df["pairs_held"] = int(nxt[-1])
             self.stats["moe_pairs_routed"] += (
@@ -3372,11 +3381,13 @@ class ServingEngine:
         """Count what one decode step of `active` under a learned
         selection reads, from the lengths held here plus `more` (no
         device work): the rows live in the attention layers
-        (`rows_live` of the step's span) and the index keys the owners'
-        indexers score, which is every entry of every row's page table
-        (ops/sparse_select.py `select_paged` scores the table whole and
-        masks what is not live). The rows the attention TOOK are the
-        device's count and arrive with the step's tokens (`_land`). A
+        (`rows_live` of the step's span) and the index keys in the
+        owners' reach, which is every entry of every slot's page table
+        (ops/sparse_select.py `select_paged` scores a slot's table
+        whole and masks what is not live). Of those the program scores
+        the slots its selection runs over, `select_rows_run` of
+        `max_slots`: that count and the rows the attention TOOK are
+        the device's and arrive with the step's tokens (`_land`). A
         table no wider than `index_topk` runs the dense path: every
         live row is read, no key is scored, and nothing is counted on
         the device."""

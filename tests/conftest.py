@@ -172,6 +172,22 @@ def benchmark_tests_pinned_before_pr26(request, monkeypatch):
             return
         if _glm_in_the_pinned_tests(node, name, module, monkeypatch):
             return
+        if _select_in_the_pinned_tests(node, name, module, monkeypatch):
+            return
+    if module.__name__.endswith("test_bench_glm") and name == \
+            "test_the_cell_its_configuration_and_its_metrics_are_in_the_manifest":
+        # ... asserts that PR 46's five metrics are the LAST: it is
+        # shown the manifest without the one PR 47 appended
+        load = module.manifest.load
+
+        def load_as_of_pr46(*a, **kw):
+            bench = load(*a, **kw)
+            bench["per_layer"] = [m for m in bench["per_layer"]
+                                  if m["name"] != "select_active_share"]
+            return bench
+
+        monkeypatch.setattr(module.manifest, "load", load_as_of_pr46)
+        return
     if module.__name__.endswith("test_bench_command_a") and name == \
             "test_the_cell_its_configuration_and_its_metrics_are_in_the_manifest":
         # ... asserts that PR 42's cell, configuration and two metrics
@@ -356,6 +372,28 @@ def _as_before_pr42(bench):
         if _COMMAND_A_CELL in m.get("workloads", ()):
             m["workloads"].remove(_COMMAND_A_CELL)
     return bench
+
+
+def _select_in_the_pinned_tests(node, name, module, monkeypatch):
+    """PR 47 (`perf_opt`: may add benchmark files, edit none) added the
+    per-layer metric select_active_share; as `_glm_in_the_pinned_tests`
+    for PR 46's. Returns True where it dealt with the test: the table
+    test gets the metric's hand-worked number and its ring from
+    tests/benchmark/select_by_hand.py."""
+    params = getattr(getattr(node, "callspec", None), "params", {})
+    if name != "test_reader_gives_the_number_worked_by_hand":
+        return False
+    import select_by_hand as by_hand
+
+    if params.get("name") not in by_hand.BY_HAND:
+        return False
+    from infinistore_tpu.utils import profiling
+
+    table = module.expected
+    monkeypatch.setattr(module, "expected",
+                        lambda obs: {**table(obs), **by_hand.BY_HAND})
+    monkeypatch.setattr(profiling, "spans", lambda: by_hand.RING)
+    return True
 
 
 def _command_a_in_the_pinned_tests(node, name, module, monkeypatch):

@@ -9,7 +9,9 @@ are tests/test_latent.py's.
 """
 
 import dataclasses
+import time
 import types
+from functools import partial
 
 import jax
 import jax.numpy as jnp
@@ -22,6 +24,7 @@ from infinistore_tpu.models import decoder, glm, hf, moe
 from infinistore_tpu.ops import sparse_select
 from infinistore_tpu.serving import Request, ServingConfig, ServingEngine
 from infinistore_tpu.tpu import TpuKVStore
+from infinistore_tpu.utils import profiling
 
 PAGE = 8
 TOPK = 32
@@ -288,17 +291,36 @@ def test_the_references_planted_selections_move_its_rows(cfg, params,
     assert np.array_equal(again, want)
 
 
-def test_selection_is_top_ks_set_on_rows_with_ties():
+@pytest.mark.parametrize("reordered", [False, True])
+def test_selection_is_top_ks_set_on_rows_with_ties(reordered):
     """200 random score rows drawn from FEW values (ties everywhere):
     the set is `jax.lax.top_k`'s, which takes the lower position of
-    equals; a numpy stable sort says the same."""
+    equals; a numpy stable sort says the same. `reordered`: the rows as
+    25 decode batches of 8 slots of which some hold a sequence, selected
+    through `over_active` (the valid slots first, a rung of the ladder,
+    back in slot order): a valid row's set is the same."""
     rng = np.random.default_rng(5)
     scores = rng.integers(0, 6, (200, 96)).astype(np.float32)
     n_live = rng.integers(1, 97, 200).astype(np.int32)
-    idx, taken = sparse_select.select(jnp.asarray(scores),
-                                      jnp.asarray(n_live), TOPK)
+    rows = range(200)
+    if reordered:
+        valid = rng.random((25, 8)) < 0.4
+        valid[0], valid[1] = True, False
+        some = jax.jit(lambda v, s, n: sparse_select.over_active(
+            partial(sparse_select.select, k=TOPK),
+            sparse_select.active_first(v), s, n))
+        idx, taken = (jnp.concatenate(part) for part in zip(*(
+            some(jnp.asarray(v), jnp.asarray(s), jnp.asarray(n))
+            for v, s, n in zip(valid, scores.reshape(25, 8, 96),
+                               n_live.reshape(25, 8)))))
+        rows = np.flatnonzero(valid)
+        # no slot of the second batch is valid: the least rung runs one
+        assert not np.asarray(taken[9:16]).any()
+    else:
+        idx, taken = sparse_select.select(jnp.asarray(scores),
+                                          jnp.asarray(n_live), TOPK)
     assert idx.shape == (200, TOPK) and idx.dtype == jnp.int32
-    for r in range(200):
+    for r in rows:
         live = scores[r, :n_live[r]]
         k = min(TOPK, n_live[r])
         want = np.argsort(-live, kind="stable")[:k]
@@ -376,6 +398,72 @@ def test_decode_through_the_cache_is_the_prefill(cfg, params):
     for (idx, taken), layer in zip(taps, (0, 4)):
         assert theirs[layer][3][0] >= GAP
         assert _sets(idx, taken) == _sets(*theirs[layer][:2])
+
+
+@pytest.mark.parametrize("slots", [
+    (), (0,), (5,), (1, 6), (0, 2, 3), (0, 2, 4, 5, 7), tuple(range(8))])
+def test_a_decode_steps_selection_runs_over_the_decoding_slots(cfg, params,
+                                                               slots):
+    """A decode batch of 8 slots of which `slots` hold a sequence, at a
+    table of 96 keys (3 x `index_topk`): for every valid slot the logits
+    row and each owner layer's tapped (positions, taken) are what the
+    same step gives with every slot valid, which is the parent's
+    arithmetic (the full batch's branch runs `select_paged`, the gather
+    and `attend` over the arrays as they come); the device's counts are
+    the valid slots' rows taken and the least rung of 1, 2, 4, 8 that
+    holds them."""
+    rng = np.random.default_rng(3)
+    pool = jnp.asarray(rng.standard_normal(
+        (5, 97, PAGE, cfg.latent_width)), jnp.float32)
+    ipool = jnp.asarray(rng.standard_normal(
+        (2, 97, PAGE, cfg.index_dim)), jnp.float32)
+    table = 1 + np.arange(8 * 12, dtype=np.int32).reshape(8, 12)
+    lens = np.asarray([40, 95, 20, 64, 33, 71, 88, 50], np.int32)
+    tok = jnp.asarray(rng.integers(0, CONF["vocab_size"], 8), jnp.int32)
+    valid = np.isin(np.arange(8), slots)
+
+    def step(valid):
+        # as the engine hands an empty slot over: length 0, scratch page
+        args = (params, cfg, tok, jnp.asarray(np.where(valid, lens, 0)),
+                pool, ipool, jnp.asarray(np.where(valid[:, None], table, 0)))
+        logits, _, _, counts = glm.decode_step(*args, fetched=True)
+        taps = jax.jit(glm.decode_selections, static_argnums=1)(*args)
+        return np.asarray(logits), taps, np.asarray(counts)
+
+    want, want_taps, _ = step(np.ones(8, bool))
+    got, taps, counts = step(valid)
+    assert len(taps) == 2
+    for i in slots:
+        assert np.abs(got[i] - want[i]).max() < 1e-5
+        for (idx, taken), (widx, wtaken) in zip(taps, want_taps):
+            t = np.asarray(taken[i])
+            assert np.array_equal(t, np.asarray(wtaken[i]))
+            assert t.sum() == min(lens[i] + 1, TOPK)
+            assert np.array_equal(np.asarray(idx[i])[t],
+                                  np.asarray(widx[i])[t])
+    assert np.isfinite(got).all()
+    # [experts fetched, rows taken, slots run, pairs held]
+    assert counts[1] == 5 * sum(min(lens[i] + 1, TOPK) for i in slots)
+    assert counts[2] == next(n for n in (1, 2, 4, 8) if n >= len(slots))
+
+
+def test_the_engine_counts_the_slots_a_steps_selection_ran(cfg, params):
+    """Three requests of 6, 10 and 14 tokens into 4 slots (a table of
+    48 pages, 12 x `index_topk`): the steps run 3, 2 and then 1
+    sequence, their selections over 4, 2 and 1 slots, and the span of a
+    step says so."""
+    eng = _engine(params, cfg, max_slots=4, total_pages=200)
+    eng._proven = lambda active: False     # a span a step, dispatch to land
+    t0 = time.time_ns()
+    eng.run([_req(r, _prompt(s, 40), n)
+             for r, s, n in (("a", 1, 6), ("b", 2, 10), ("c", 3, 14))])
+    steps = [(s.fields["select_rows_active"], s.fields["select_rows_run"])
+             for s in profiling.spans(since_ns=t0)
+             if s.name == "istpu.model.decode" and s.engine == eng.engine_id]
+    assert steps == [(3, 4)] * 5 + [(2, 2)] * 4 + [(1, 1)] * 4
+    assert eng.stats["select_rows_active"] == 15 + 8 + 4
+    assert eng.stats["select_rows_run"] == 20 + 8 + 4
+    assert eng.stats["attn_rows_selected"] == 5 * 27 * TOPK
 
 
 def test_under_index_topk_plus_one_live_tokens_attention_is_dense(cfg,
