@@ -116,10 +116,12 @@ def _block(layer, x, cfg, valid, h_attn=None):
 _forward_stack, decode_step, verify_step = decoder.bind(_block)
 
 
-def prefill(params, cfg: CohereConfig, tokens):
+def prefill(params, cfg: CohereConfig, tokens, keep=None):
     """(logits, per layer (k, v)) and, where the layers hold a share of
-    their experts, the blocks' counts summed over the layers."""
-    logits, kvs, _, *counts = _forward_stack(params, cfg, tokens)
+    their experts, the blocks' counts summed over the layers. `keep`:
+    decoder.forward_stack."""
+    logits, kvs, _, *counts = _forward_stack(params, cfg, tokens,
+                                             keep=keep)
     return (logits, kvs, *counts)
 
 
@@ -127,9 +129,9 @@ forward_dense = prefill
 
 
 def prefill_with_prefix(params, cfg: CohereConfig, tokens, prefix_kvs,
-                        pos0=0):
+                        pos0=0, keep=None):
     """Suffix prefill over a cached prefix; each layer's prefix is what
     that layer may attend (decoder.forward_stack)."""
-    logits, kvs, _, *counts = _forward_stack(params, cfg, tokens,
-                                             prefix_kvs, pos0=pos0)
+    logits, kvs, _, *counts = _forward_stack(
+        params, cfg, tokens, prefix_kvs, pos0=pos0, keep=keep)
     return (logits, kvs, *counts)

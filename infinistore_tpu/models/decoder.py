@@ -790,7 +790,7 @@ def latent_selected_decode(layer, cfg, q_nope, q_pe, pool, table, sel, pl,
 
 
 def forward_stack(block, params, cfg, tokens, prefix_kvs=None, pos0=0,
-                  state=None, s_real=None):
+                  state=None, s_real=None, keep=None):
     """The ONE decoder-stack loop shared by dense forward and
     prefix-cached prefill (the cache-hit identity depends on these two
     paths never diverging). With `prefix_kvs` (per attention layer (k,
@@ -800,12 +800,13 @@ def forward_stack(block, params, cfg, tokens, prefix_kvs=None, pos0=0,
     dense causal forward.
 
     tokens: [batch, seq] int32. Returns (logits [batch, seq, vocab]
-    fp32, per attention layer (k, v) [batch, seq, n_kv, hd] — the KV to
-    page out to the store — and the per-layer list of what `block`
-    returned as its auxiliary loss). A family with state layers gets a
-    fourth element: per state layer what `ssm_mixer_seq` returned; one
-    whose layers hold a share of their experts a last: the blocks'
-    counts (the `block` contract's fourth), summed over the layers.
+    fp32, or [batch, 1, vocab] with `keep`; per attention layer (k, v)
+    [batch, seq, n_kv, hd] — the KV to page out to the store — and the
+    per-layer list of what `block` returned as its auxiliary loss). A
+    family with state layers gets a fourth element: per state layer
+    what `ssm_mixer_seq` returned; one whose layers hold a share of
+    their experts a last: the blocks' counts (the `block` contract's
+    fourth), summed over the layers.
 
     `pos0` shifts every ABSOLUTE rope position (prefix starts at pos0,
     suffix at pos0 + P): a sliding-window engine trims the restored
@@ -819,7 +820,15 @@ def forward_stack(block, params, cfg, tokens, prefix_kvs=None, pos0=0,
     `state`: per state layer (h, conv tail) of the prefix the suffix
     continues (None: position 0); `s_real`: how many of the seq
     positions are real tokens (None: all; the others must lie in the
-    last page and may not advance a recurrence)."""
+    last page and may not advance a recurrence).
+
+    `keep`: the one position (int32 scalar, may be traced) whose logits
+    the caller keeps, as an admission program does of its last real
+    one (None: every position's). Every layer still runs every
+    position (their K/V, rows, index keys, state and counts are what
+    they were); the streams are cut to that position after the last
+    layer, so the final norm and the head run on one row a batch and
+    the head reads its weights once for it."""
     b, s = tokens.shape
     prefix_len = 0 if prefix_kvs is None else max(
         k.shape[1] for k, _ in prefix_kvs)
@@ -891,6 +900,8 @@ def forward_stack(block, params, cfg, tokens, prefix_kvs=None, pos0=0,
         held += more[1:]
         x = residual(cfg, x, out, mix)
         auxes.append(aux)
+    if keep is not None:
+        x = jax.lax.dynamic_slice_in_dim(x, keep, 1, axis=1)
     x = norm(cfg, stream_close(cfg, x), params["final_ln"])
     out = (lm_head(params, x, cfg), kvs, auxes)
     if states:

@@ -218,11 +218,13 @@ def _block(layer, x, cfg, valid, h_attn=None):
 _forward_stack, decode_step, verify_step = decoder.bind(_block)
 
 
-def prefill(params, cfg: GlmConfig, tokens):
+def prefill(params, cfg: GlmConfig, tokens, keep=None):
     """(logits, per layer (rows [b, s, latent_width], index keys
     [b, s, index_dim] or None): what to page out) and, where the
-    layers hold a share of their experts, the blocks' counts."""
-    logits, kvs, _, *counts = _forward_stack(params, cfg, tokens)
+    layers hold a share of their experts, the blocks' counts. `keep`:
+    decoder.forward_stack."""
+    logits, kvs, _, *counts = _forward_stack(params, cfg, tokens,
+                                             keep=keep)
     return (logits, kvs, *counts)
 
 
@@ -230,12 +232,12 @@ forward_dense = prefill
 
 
 def prefill_with_prefix(params, cfg: GlmConfig, tokens, prefix_kvs,
-                        pos0=0):
+                        pos0=0, keep=None):
     """Suffix prefill over cached rows and index keys: `prefix_kvs`
     per layer (rows [b, P, latent_width], index keys [b, P, index_dim]
     or None), as restored or as they lie in the pools."""
-    logits, kvs, _, *counts = _forward_stack(params, cfg, tokens,
-                                             prefix_kvs, pos0=pos0)
+    logits, kvs, _, *counts = _forward_stack(
+        params, cfg, tokens, prefix_kvs, pos0=pos0, keep=keep)
     return (logits, kvs, *counts)
 
 

@@ -139,12 +139,24 @@ def state_pools(cfg: HybridConfig, slots, device=None):
     }
 
 
-def prefill(params, cfg: HybridConfig, tokens, s_real=None):
+def _last(tokens, s_real, last_only):
+    """decoder.forward_stack's `keep` of a caller that keeps the last
+    real position's logits alone: the ONE `s_real` the state layers
+    stop at says which that is."""
+    if not last_only:
+        return None
+    return (tokens.shape[1] if s_real is None else s_real) - 1
+
+
+def prefill(params, cfg: HybridConfig, tokens, s_real=None,
+            last_only=False):
     """(logits, per attention layer (k, v), per state layer the states
     decoder.ssm_mixer_seq returns: after `s_real` tokens and at the
-    last page edge)."""
-    logits, kvs, _, states = _forward_stack(params, cfg, tokens,
-                                            s_real=s_real)
+    last page edge). `last_only`: logits [batch, 1, vocab] of position
+    `s_real - 1` (decoder.forward_stack's `keep`)."""
+    logits, kvs, _, states = _forward_stack(
+        params, cfg, tokens, s_real=s_real,
+        keep=_last(tokens, s_real, last_only))
     return logits, kvs, states
 
 
@@ -154,14 +166,15 @@ def forward_dense(params, cfg: HybridConfig, tokens):
 
 
 def prefill_with_prefix(params, cfg: HybridConfig, tokens, prefix_kvs,
-                        pos0=0, state=None, s_real=None):
+                        pos0=0, state=None, s_real=None, last_only=False):
     """Suffix prefill over a cached prefix: the attention layers attend
     over `prefix_kvs` + the suffix, the state layers continue from
     `state` (per state layer (h, conv tail) at the prefix's end: both
-    or neither; a prefix without its state is no prefix)."""
+    or neither; a prefix without its state is no prefix). `last_only`:
+    as in `prefill`."""
     logits, kvs, _, states = _forward_stack(
         params, cfg, tokens, prefix_kvs, pos0=pos0, state=state,
-        s_real=s_real)
+        s_real=s_real, keep=_last(tokens, s_real, last_only))
     return logits, kvs, states
 
 

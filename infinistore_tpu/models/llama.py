@@ -349,14 +349,17 @@ def forward_dense(params, cfg: LlamaConfig, tokens):
     return logits, kvs
 
 
-def prefill(params, cfg: LlamaConfig, tokens):
+def prefill(params, cfg: LlamaConfig, tokens, keep=None):
     """Prefill: returns (logits, per-layer (k, v) arrays
-    [batch, seq, n_kv, hd]) — the KV to page out to the store."""
-    return forward_dense(params, cfg, tokens)
+    [batch, seq, n_kv, hd]) — the KV to page out to the store. `keep`:
+    the one position whose logits the caller keeps (logits
+    [batch, 1, vocab]: decoder.forward_stack); None: every position's."""
+    logits, kvs, _ = _forward_stack(params, cfg, tokens, keep=keep)
+    return logits, kvs
 
 
 def prefill_with_prefix(params, cfg: LlamaConfig, tokens, prefix_kvs,
-                        pos0=0):
+                        pos0=0, keep=None):
     """Suffix prefill over a cached prefix — the store's cache-HIT path.
 
     This is what a prefix-cache hit buys (reference design.rst:54-63:
@@ -373,14 +376,16 @@ def prefill_with_prefix(params, cfg: LlamaConfig, tokens, prefix_kvs,
                 `pages_to_kv` — positions are absolute, so restored K
                 needs no re-rotation.
 
-    Returns (logits [batch, s_new, vocab] fp32, per-layer suffix (k, v)
-    [batch, s_new, n_kv, hd] — the new pages to put to the store).
+    Returns (logits [batch, s_new, vocab] fp32, or [batch, 1, vocab]
+    of position `keep` of the suffix where the caller keeps one,
+    per-layer suffix (k, v) [batch, s_new, n_kv, hd] — the new pages to
+    put to the store).
     `pos0`: absolute position of the prefix's first token (see
     decoder.forward_stack — used by the windowed engine's trimmed-prefix
     admission).
     """
     logits, kvs, _ = _forward_stack(params, cfg, tokens, prefix_kvs,
-                                    pos0=pos0)
+                                    pos0=pos0, keep=keep)
     return logits, kvs
 
 

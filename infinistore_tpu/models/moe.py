@@ -526,18 +526,18 @@ def forward_dense(params, cfg: MoEConfig, tokens):
     return logits, kvs, sum(auxes, jnp.float32(0))
 
 
-def prefill(params, cfg: MoEConfig, tokens):
-    logits, kvs, _ = _forward_stack(params, cfg, tokens)
+def prefill(params, cfg: MoEConfig, tokens, keep=None):
+    logits, kvs, _ = _forward_stack(params, cfg, tokens, keep=keep)
     return logits, kvs
 
 
 def prefill_with_prefix(params, cfg: MoEConfig, tokens, prefix_kvs,
-                        pos0=0):
+                        pos0=0, keep=None):
     """Suffix prefill over a cached prefix — the cache-HIT path, same
     contract as llama.prefill_with_prefix (the serving engine calls it
     through its model parameter)."""
     logits, kvs, _ = _forward_stack(params, cfg, tokens, prefix_kvs,
-                                    pos0=pos0)
+                                    pos0=pos0, keep=keep)
     return logits, kvs
 
 

@@ -50,8 +50,8 @@ def _block(layer, x, cfg, valid, h_attn=None):
 _forward_stack, decode_step, verify_step = decoder.bind(_block)
 
 
-def prefill(params, cfg: SmallThinkerConfig, tokens):
-    logits, kvs, _ = _forward_stack(params, cfg, tokens)
+def prefill(params, cfg: SmallThinkerConfig, tokens, keep=None):
+    logits, kvs, _ = _forward_stack(params, cfg, tokens, keep=keep)
     return logits, kvs
 
 
@@ -59,10 +59,10 @@ forward_dense = prefill
 
 
 def prefill_with_prefix(params, cfg: SmallThinkerConfig, tokens,
-                        prefix_kvs, pos0=0):
+                        prefix_kvs, pos0=0, keep=None):
     """Suffix prefill over a cached prefix; each layer's prefix is what
     that layer may attend (decoder.forward_stack: a banded layer's may
     be the tail its band needs)."""
     logits, kvs, _ = _forward_stack(params, cfg, tokens, prefix_kvs,
-                                    pos0=pos0)
+                                    pos0=pos0, keep=keep)
     return logits, kvs

@@ -169,10 +169,10 @@ def _block(layer, x, cfg, valid, h_attn=None):
 _forward_stack, decode_step, verify_step = decoder.bind(_block)
 
 
-def prefill(params, cfg: XingConfig, tokens):
+def prefill(params, cfg: XingConfig, tokens, keep=None):
     """(logits, per layer (rows [b, s, latent_width], None)): the
-    latent rows to page out."""
-    logits, kvs, _ = _forward_stack(params, cfg, tokens)
+    latent rows to page out. `keep`: decoder.forward_stack."""
+    logits, kvs, _ = _forward_stack(params, cfg, tokens, keep=keep)
     return logits, kvs
 
 
@@ -180,10 +180,10 @@ forward_dense = prefill
 
 
 def prefill_with_prefix(params, cfg: XingConfig, tokens, prefix_kvs,
-                        pos0=0):
+                        pos0=0, keep=None):
     """Suffix prefill over cached rows: `prefix_kvs` per layer (rows
     [b, P, latent_width], None), as restored or as they lie in the
     pool."""
     logits, kvs, _ = _forward_stack(params, cfg, tokens, prefix_kvs,
-                                    pos0=pos0)
+                                    pos0=pos0, keep=keep)
     return logits, kvs

@@ -454,14 +454,17 @@ def _page_out(cfg, kvs, k_pages, v_pages, ids):
 
 
 def _last_row(logits, s_real, counts):
-    """An admission program's logits row, the last real position's.
+    """An admission program's logits row, the last real position's:
+    `logits` [1, 1, vocab] is that position's alone, because the
+    program asked its model's prefill to keep `s_real - 1` and the
+    head ran on that row (decoder.forward_stack's `keep`).
     Where the model's layers hold a share of the experts their routers
     score (`counts`: what its prefill returned third, else empty), two
     more values ride behind it in the host's one pull: the real rows'
     pairs that fell on experts held here and the rows the experts'
     matmuls ran, summed over the layers (whole numbers, exact in
     float32 below 2 ** 24)."""
-    row = logits[0, s_real - 1]
+    row = logits[0, 0]
     if not counts:
         return row
     c, = counts
@@ -475,8 +478,12 @@ def _last_row(logits, s_real, counts):
 def _admit_fused(params, cfg, tokens, k_pages, v_pages, ids, s_real,
                  model=llama):
     """Cold-prefill admission as ONE device program: prefill + page the
-    suffix KV + scatter it into the (donated) pool at `ids` + slice the
-    last real position's logits row. The unfused path was ~10 dispatches
+    suffix KV + scatter it into the (donated) pool at `ids` + the last
+    real position's logits row, the one row the final norm and the
+    head run on (`keep`: every layer runs every position, the head
+    reads its weights for one; over all `s_pad` it was a sixth of a
+    piece of 8,192 tokens at a vocabulary of 131,072, PERF.md PR 48).
+    The unfused path was ~10 dispatches
     (prefill, per-layer kv_to_pages, stacks, pads, pool write, logits
     indexing) and pulled a full [s,vocab] row source; this is one
     dispatch and one [vocab] row pull. Padded positions beyond s_real
@@ -486,7 +493,8 @@ def _admit_fused(params, cfg, tokens, k_pages, v_pages, ids, s_real,
     are unreachable. `ids` is padded with total_pages (mode=drop);
     the first s_pad // page of them are read.
     tokens: [1, s_pad] (page multiple); ids: [max_pages_per_seq]."""
-    logits, kvs, *counts = model.prefill(params, cfg, tokens)
+    logits, kvs, *counts = model.prefill(params, cfg, tokens,
+                                         keep=s_real - 1)
     k_pages, v_pages = _page_out(cfg, kvs, k_pages, v_pages, ids)
     return _last_row(logits, s_real, counts), k_pages, v_pages
 
@@ -582,7 +590,7 @@ def _admit_fused_px(params, cfg, tokens, restored, k_pages, v_pages,
     k_pages, v_pages, prefix = _place_restored(cfg, restored, k_pages,
                                                v_pages, restored_ids)
     logits, kvs, *counts = model.prefill_with_prefix(
-        params, cfg, tokens, prefix, pos0=pos0)
+        params, cfg, tokens, prefix, pos0=pos0, keep=s_real - 1)
     k_pages, v_pages = _page_out(cfg, kvs, k_pages, v_pages, suffix_ids)
     return _last_row(logits, s_real, counts), k_pages, v_pages
 
@@ -697,10 +705,11 @@ def _admit_fused_st(params, cfg, tokens, k_pages, v_pages, state, bstate,
     advance a recurrence), and the state after `s_real` tokens and the
     one at the last page edge go into row `slot` of the (donated) state
     pools and boundary copies."""
-    logits, kvs, states = model.prefill(params, cfg, tokens, s_real=s_real)
+    logits, kvs, states = model.prefill(params, cfg, tokens, s_real=s_real,
+                                        last_only=True)
     k_pages, v_pages = _page_out(cfg, kvs, k_pages, v_pages, ids)
     state, bstate = _state_in(state, bstate, states, slot)
-    return logits[0, s_real - 1], k_pages, v_pages, state, bstate
+    return logits[0, 0], k_pages, v_pages, state, bstate
 
 
 @partial(jax.jit, static_argnames=("cfg", "model"),
@@ -718,10 +727,10 @@ def _admit_fused_px_st(params, cfg, tokens, restored, snap, k_pages,
                                                v_pages, restored_ids)
     logits, kvs, states = model.prefill_with_prefix(
         params, cfg, tokens, prefix, state=_rows_to_state(cfg, snap),
-        s_real=s_real)
+        s_real=s_real, last_only=True)
     k_pages, v_pages = _page_out(cfg, kvs, k_pages, v_pages, suffix_ids)
     state, bstate = _state_in(state, bstate, states, slot)
-    return logits[0, s_real - 1], k_pages, v_pages, state, bstate
+    return logits[0, 0], k_pages, v_pages, state, bstate
 
 
 @partial(jax.jit, static_argnames=("cfg", "model"),
@@ -827,7 +836,8 @@ def _admit_fused_wf(params, cfg, tokens, k_pages, v_pages, wk, wv, ids,
     at `wids`, and the banded layers' first `n_sub` pages come back as
     `sub` (`_page_out_two`). One program per (s_pad, n_sub); n_sub is a
     function of s_pad in an admission."""
-    logits, kvs, *counts = model.prefill(params, cfg, tokens)
+    logits, kvs, *counts = model.prefill(params, cfg, tokens,
+                                         keep=s_real - 1)
     k_pages, v_pages, wk, wv, sub = _page_out_two(
         cfg, kvs, k_pages, v_pages, wk, wv, ids, wids, n_sub)
     return (_last_row(logits, s_real, counts), k_pages, v_pages, wk, wv,
@@ -880,8 +890,8 @@ def _admit_fused_px_wf(params, cfg, tokens, restored, k_pages, v_pages, wk,
             flat = (1, rows.shape[0] * page, cfg.n_kv_heads, cfg.head_dim)
             prefix.append((rows[:, li, 0].reshape(flat),
                            rows[:, li, 1].reshape(flat)))
-    logits, kvs, *counts = model.prefill_with_prefix(params, cfg, tokens,
-                                                     prefix)
+    logits, kvs, *counts = model.prefill_with_prefix(
+        params, cfg, tokens, prefix, keep=s_real - 1)
     k_pages, v_pages, wk, wv, sub = _page_out_two(
         cfg, kvs, k_pages, v_pages, wk, wv, s_ids, ws_ids, n_sub)
     return (_last_row(logits, s_real, counts), k_pages, v_pages, wk, wv,

@@ -3,7 +3,9 @@
 prefix admission, decode step, offload gather) for the five families
 that keep K and V pages (cohere since PR 43), the one that keeps a
 latent row (xing, since PR 42) and the one that keeps index keys
-beside it on some layers (glm, since PR 46), at tiny widths. A change that must
+beside it on some layers (glm, since PR 46), at tiny widths; the
+prefix admission of the families with state layers or two kinds of
+attention layer since PR 48 (25 programs). A change that must
 leave their programs alone is checked by running this in both trees and
 comparing the output (PR 40: the parent unpacked under build/parent):
 
@@ -41,10 +43,14 @@ FAMILIES = {
 }
 
 
-def programs(name):
+def programs(name, wrap=None, **more):
     """{"cold" | "prefix" | "decode" | "gather": (fn, arguments)} of
     one family: the engine's fused programs unjitted, over the arrays a
-    tiny engine of the family holds (every pool zeros)."""
+    tiny engine of the family holds (every pool zeros). Each `fn`'s
+    parameter names say what its arguments are, so a caller can put
+    its own in by name (tests/test_admit_one_row.py does: tokens,
+    `s_real`, filled pools); `wrap(model)` stands in for the family's module
+    inside the programs, `more` goes to its configuration."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -54,13 +60,16 @@ def programs(name):
 
     module, config, kw = FAMILIES[name]
     model = importlib.import_module("infinistore_tpu.models." + module)
-    cfg = getattr(model, config)(**kw)
+    cfg = getattr(model, config)(**kw, **more)
     i32 = jnp.int32
     params = model.init_params(jax.random.PRNGKey(0), cfg)
     eng = ServingEngine(params, cfg, ServingConfig(
         max_slots=2, total_pages=24, max_pages_per_seq=8), model=model)
+    if wrap:
+        model = wrap(model)
     toks = jnp.zeros((1, 32), i32)
     ids = jnp.asarray(eng._pad_ids([1, 2]))
+    two = jnp.asarray([1, 2], i32)
     slots = jnp.zeros((2,), i32)
     rows = jnp.zeros((2, 8), i32)
     L = eng.k_pages.shape[0]
@@ -71,12 +80,25 @@ def programs(name):
     out = {}
     if eng._win_layers:
         pools = (eng.k_pages, eng.v_pages, eng.wk_pages, eng.wv_pages)
+        wids = jnp.asarray(np.full(
+            eng._wtable_w, eng._wpool_pages, np.int32))[:8]
         out["cold"] = (
-            lambda p, t, k, v, wk, wv, i, wi, s:
-            serving._admit_fused_wf.__wrapped__(
-                p, cfg, t, k, v, wk, wv, i, wi, s, model, 0),
-            (params, toks, *pools, ids, jnp.asarray(np.full(
-                eng._wtable_w, eng._wpool_pages, np.int32))[:8],
+            lambda params, tokens, k_pages, v_pages, wk, wv, ids, wids,
+            s_real: serving._admit_fused_wf.__wrapped__(
+                params, cfg, tokens, k_pages, v_pages, wk, wv, ids, wids,
+                s_real, model, 0),
+            (params, toks, *pools, ids, wids, jnp.int32(30)))
+        # two restored pages of every layer, full and banded alike
+        n_win = eng.wk_pages.shape[0]
+        restored = jnp.zeros((2 * (L + n_win) * 2, *cfg.kv_page_shape()),
+                             cfg.jdtype)
+        out["prefix"] = (
+            lambda params, tokens, restored, k_pages, v_pages, wk, wv,
+            r_ids, wr_ids, s_ids, ws_ids, s_real:
+            serving._admit_fused_px_wf.__wrapped__(
+                params, cfg, tokens, restored, k_pages, v_pages, wk, wv,
+                r_ids, wr_ids, s_ids, ws_ids, s_real, model, 0),
+            (params, toks, restored, *pools, two, two, ids, wids,
              jnp.int32(30)))
         out["decode"] = (
             lambda p, t, s, k, v, wk, wv, r:
@@ -85,13 +107,28 @@ def programs(name):
             (params, slots, slots, *pools,
              (rows, jnp.zeros((2, eng._wtable_w), i32), slots)))
         return out
+    restored = jnp.zeros((2 * L * len(cfg.page_kinds), *cfg.kv_page_shape()),
+                         cfg.jdtype)
     if eng.state is not None:
+        pools = (eng.k_pages, eng.v_pages, eng.state, eng.bstate)
         out["cold"] = (
-            lambda p, t, k, v, st, bst, i, s, sl:
-            serving._admit_fused_st.__wrapped__(
-                p, cfg, t, k, v, st, bst, i, s, sl, model),
-            (params, toks, eng.k_pages, eng.v_pages, eng.state, eng.bstate,
-             ids, jnp.int32(30), jnp.int32(0)))
+            lambda params, tokens, k_pages, v_pages, state, bstate, ids,
+            s_real, slot: serving._admit_fused_st.__wrapped__(
+                params, cfg, tokens, k_pages, v_pages, state, bstate, ids,
+                s_real, slot, model),
+            (params, toks, *pools, ids, jnp.int32(30), jnp.int32(0)))
+        snap = jnp.zeros((cfg.n_state_layers,
+                          serving._snapshot_row_elems(cfg)),
+                         cfg.state_jdtype)
+        out["prefix"] = (
+            lambda params, tokens, restored, snap, k_pages, v_pages, state,
+            bstate, restored_ids, suffix_ids, s_real, slot:
+            serving._admit_fused_px_st.__wrapped__(
+                params, cfg, tokens, restored, snap, k_pages, v_pages,
+                state, bstate, restored_ids, suffix_ids, s_real, slot,
+                model),
+            (params, toks, restored, snap, *pools, two, ids, jnp.int32(30),
+             jnp.int32(0)))
         out["decode"] = (
             lambda p, t, s, k, v, st, r:
             serving._decode_fused_st.__wrapped__(
@@ -100,11 +137,10 @@ def programs(name):
              rows))
         return out
     out["cold"] = (
-        lambda p, t, k, v, i, s: serving._admit_fused.__wrapped__(
-            p, cfg, t, k, v, i, s, model),
+        lambda params, tokens, k_pages, v_pages, ids, s_real:
+        serving._admit_fused.__wrapped__(
+            params, cfg, tokens, k_pages, v_pages, ids, s_real, model),
         (params, toks, eng.k_pages, eng.v_pages, ids, jnp.int32(30)))
-    restored = jnp.zeros((2 * L * len(cfg.page_kinds), *cfg.kv_page_shape()),
-                         cfg.jdtype)
     pools = (eng.k_pages, eng.v_pages)
     if getattr(eng, "_index_kind", None):
         # a second kind of page of its own shape on some layers: the
@@ -115,18 +151,19 @@ def programs(name):
             for kind in cfg.page_kinds)
         pools = (eng.k_pages, None)
     out["prefix"] = (
-        lambda p, t, r, k, v, ri, si, s, p0:
-        serving._admit_fused_px.__wrapped__(
-            p, cfg, t, r, k, v, ri, si, s, p0, model),
-        (params, toks, restored, eng.k_pages, eng.v_pages,
-         jnp.asarray([1, 2], i32), ids, jnp.int32(30), jnp.int32(0)))
+        lambda params, tokens, restored, k_pages, v_pages, restored_ids,
+        suffix_ids, s_real, pos0: serving._admit_fused_px.__wrapped__(
+            params, cfg, tokens, restored, k_pages, v_pages, restored_ids,
+            suffix_ids, s_real, pos0, model),
+        (params, toks, restored, eng.k_pages, eng.v_pages, two, ids,
+         jnp.int32(30), jnp.int32(0)))
     out["decode"] = (
         lambda p, t, s, k, v, r: serving._decode_fused.__wrapped__(
             p, cfg, t, s, k, v, r, model, **counts),
         (params, slots, slots, eng.k_pages, eng.v_pages, rows))
     out["gather"] = (
         lambda k, v, i: serving._gather_pages.__wrapped__(k, v, i),
-        (*pools, jnp.asarray([1, 2], i32)))
+        (*pools, two))
     return out
 
 
