@@ -242,6 +242,11 @@ def _qkv(layer, x, cfg, positions, rotate=None, decode=False):
                  decode)
         v = proj(h, layer, "wv", "bv", (b, s, cfg.n_kv_heads, cfg.head_dim),
                  decode)
+        if "q_norm" in layer:
+            # a per-head RMSNorm on q and k before rotary (Qwen3's;
+            # models/keye.py), a weight of head_dim each
+            q = rms_norm(q, layer["q_norm"], cfg.norm_eps)
+            k = rms_norm(k, layer["k_norm"], cfg.norm_eps)
         if cfg.attn_scale:
             # The kernels scale scores by head_dim ** -0.5; a family
             # with a softmax scale of its own folds the ratio into q.
@@ -707,10 +712,14 @@ def indexed(cfg, n_keys):
 
 def index_project(layer, cfg, cq, h, positions, keys_only=False):
     """A "full" layer's indexer: (qI [b, s, Hi, Di], kI [b, s, Di], w
-    [b, s, Hi] float32), qI and kI rotated on their first `qk_rope`
-    lanes. kI = LayerNorm(h Wki) is what the layer caches."""
+    [b, s, Hi] float32), qI and kI rotated on their first
+    `cfg.index_rope` lanes (and both zero-padded to `cfg.index_width`
+    lanes where the family caches a key wider than Di). kI =
+    LayerNorm(h Wki) is what the layer caches. `cq`: what the queries
+    are projected from (a latent layer's compressed query; an
+    attention layer's normalised input, `h` itself)."""
     b, s, _ = h.shape
-    hi, di, rl = cfg.index_heads, cfg.index_dim, cfg.qk_rope
+    hi, di, rl = cfg.index_heads, cfg.index_dim, cfg.index_rope
 
     def rotate(x):  # [b, s, heads, di]
         return jnp.concatenate(
@@ -725,13 +734,62 @@ def index_project(layer, cfg, cq, h, positions, keys_only=False):
               * layer["ki_ln"].astype(jnp.float32)
               + layer["ki_ln_b"].astype(jnp.float32)).astype(h.dtype)
         ki = rotate(ki[:, :, None])[:, :, 0]
+        # a key is cached in whole tiles of lanes (models/keye.py: 64
+        # of 128); the zero lanes add nothing to a product
+        extra = getattr(cfg, "index_width", di) - di
+        if extra:
+            ki = jnp.pad(ki, ((0, 0), (0, 0), (0, extra)))
         if keys_only:
             return None, ki, None
         qi = rotate(matmul(cq, layer["wqi"]).reshape(b, s, hi, di))
+        if extra:
+            qi = jnp.pad(qi, ((0, 0), (0, 0), (0, 0), (0, extra)))
         w = jnp.einsum("bsd,dh->bsh", h, layer["wiw"],
                        preferred_element_type=jnp.float32) \
             * (hi ** -0.5 * di ** -0.5)
     return qi, ki, w
+
+
+# An ATTENTION layer under the same selection (models/keye.py): the
+# rows a query attends are K and V rows by head group, and the index
+# keys are a third kind of page. Every such layer owns its indexer (a
+# borrowed selection over K and V pages is not built; serving.py
+# refuses it), whose queries come from the layer's normalised input.
+
+
+def kv_selected_prefill(cfg, q, k_all, v_all, qi, wi, keys, positions):
+    """Causal attention of s queries over the K and V rows [b, S, G,
+    hd] of prefix + suffix under the selection their index queries
+    make over `keys` [b, S, Di]: a block of queries at a time, its
+    selection as a mask over the contiguous rows
+    (sparse_select.select_attend_seq). positions: [b, s], counted in
+    the rows. Returns [b, s, H * hd]."""
+    b, s = q.shape[:2]
+    sel, out = jax.vmap(partial(
+        sparse_select.select_attend_seq, k=cfg.index_topk,
+        scale=cfg.head_dim ** -0.5)
+    )(qi, wi, keys, positions, q, k_all, v_all)
+    _tapped(sel)
+    return out.reshape(b, s, -1)
+
+
+def kv_selected_decode(cfg, q, kp, vp, table, sel, pl, active):
+    """One new token a row over the K and V pools under a selection:
+    the rows `sel` names are gathered through the page table from both
+    pools and attended by head group, for the decoding slots alone
+    (`active`: sparse_select.active_first's); no other row of either
+    pool is read. q: [b, H, hd] -> [b, H * hd]."""
+    scale = cfg.head_dim ** -0.5  # `_qkv` folds another into q
+
+    def attend(q, table, idx, taken):
+        k_rows = sparse_select.gather_paged(kp, pl, table, idx)
+        v_rows = sparse_select.gather_paged(vp, pl, table, idx)
+        with jax.named_scope("attn.kernel"):
+            return sparse_select.attend_grouped(q, k_rows, v_rows, taken,
+                                                scale)
+
+    out = sparse_select.over_active(attend, active, q, table, *sel)
+    return out.reshape(q.shape[0], -1)
 
 
 def _absorbed_query(layer, cfg, q_nope, q_pe):
@@ -831,7 +889,7 @@ def forward_stack(block, params, cfg, tokens, prefix_kvs=None, pos0=0,
     the head reads its weights once for it."""
     b, s = tokens.shape
     prefix_len = 0 if prefix_kvs is None else max(
-        k.shape[1] for k, _ in prefix_kvs)
+        k.shape[1] for k, *_ in prefix_kvs)
     spec = attn_layers(cfg)
     x = stream_open(cfg, embed(params, tokens, cfg))
     positions = jnp.broadcast_to(
@@ -880,21 +938,34 @@ def forward_stack(block, params, cfg, tokens, prefix_kvs=None, pos0=0,
         else:
             band, rotates, pool, _ = spec[len(kvs)]
             q, k, v, h_attn = _qkv(layer, x_in, cfg, positions, rotates)
+            owner = owns_indexer(cfg, len(kvs))
             if prefix_kvs is None:
                 k_full, v_full = k, v
             else:
-                pk, pv = prefix_kvs[len(kvs)]
+                pk, pv, *pi = prefix_kvs[len(kvs)]
                 k_full = jnp.concatenate([pk.astype(k.dtype), k], axis=1)
                 v_full = jnp.concatenate([pv.astype(v.dtype), v], axis=1)
-            # Pallas flash kernel on TPU (O(S) memory; speed against the
-            # XLA path not measured), XLA path elsewhere. kv may be
-            # longer than q — the causal diagonal shifts by the prefix.
-            with jax.named_scope(_kernel_scope(cfg, pool)):
-                attn = flash_prefill(q, k_full, v_full, causal=True,
-                                     window=band)
+            selects = owner and indexed(cfg, k_full.shape[1])
+            if owner:  # the layer's index keys, a third page of its own
+                qi, ki, wi = index_project(layer, cfg, h_attn, h_attn,
+                                           positions,
+                                           keys_only=not selects)
+            if selects:
+                keys = ki if prefix_kvs is None else jnp.concatenate(
+                    [pi[0].astype(ki.dtype), ki], axis=1)
+                attn = kv_selected_prefill(cfg, q, k_full, v_full, qi, wi,
+                                           keys, positions - pos0)
+            else:
+                # Pallas flash kernel on TPU (O(S) memory; speed against
+                # the XLA path not measured), XLA path elsewhere. kv may
+                # be longer than q — the causal diagonal shifts by the
+                # prefix.
+                with jax.named_scope(_kernel_scope(cfg, pool)):
+                    attn = flash_prefill(q, k_full, v_full, causal=True,
+                                         window=band)
             x = residual(cfg, x, attn_out(layer, attn.reshape(b, s, -1)),
                          mix)
-            kvs.append((k, v))
+            kvs.append((k, v, ki) if owner else (k, v))
         x_in, mix = stream_in(cfg, layer, x, "ffn")
         out, aux, *more = block(layer, x_in, cfg, None, h_attn)
         held += more[1:]
@@ -917,7 +988,10 @@ def decode_step(block, params, cfg, token, seq_lens, k_pages, v_pages,
 
     token:      [batch] int32 — current input token
     seq_lens:   [batch] int32 — tokens already in cache (excl. current)
-    k_pages/v_pages: [n_kv_layers, n_pages, page, n_kv, hd]
+    k_pages/v_pages: [n_kv_layers, n_pages, page, n_kv, hd]; where the
+                attention layers own an indexer (models/keye.py),
+                `v_pages` is the pair (V pool, index pool [index
+                layers, n_pages, page, index_dim]) and comes back so
     page_table: [batch, max_pages] int32
     state:      for a family with state layers, {"h": [...], "conv":
                 [...]}: per state layer the batch's recurrent state
@@ -1036,16 +1110,38 @@ def decode_step(block, params, cfg, token, seq_lens, k_pages, v_pages,
                 q, k, v = pack_heads(cfg, q, k, v)
             held = pools[pool]
             kp, vp, table, lens, target_page, slot = held
+            owner = owns_indexer(cfg, li)
+            if owner:  # the second of the pair is (V pool, index pool)
+                vp, ip = vp
             with jax.named_scope("pool.update"):
                 kp = scatter_kv_to_pages(kp, k, target_page, slot, layer=pl)
                 vp = scatter_kv_to_pages(vp, v, target_page, slot, layer=pl)
-            held[0], held[1] = kp, vp
-            with jax.named_scope(_kernel_scope(cfg, pool)):
-                attn = paged_decode_attention(
-                    q[:, 0], kp, vp, table, lens + 1, window=band, layer=pl
-                )
-                if cfg.kv_pack > 1:
-                    attn = unpack_heads(cfg, attn)
+            selects = owner and indexed(cfg, table.shape[1] * cfg.page_size)
+            if owner:
+                # ... and its index key into the third pool ([index
+                # layers, pages, page, index_dim])
+                qi, ki, wi = index_project(layer, cfg, h_attn, h_attn,
+                                           positions, keys_only=not selects)
+                with jax.named_scope("pool.update"):
+                    ip = ip.at[ii, target_page, slot].set(ki[:, 0],
+                                                          mode="drop")
+            held[0], held[1] = kp, (vp, ip) if owner else vp
+            if selects:
+                sel = _tapped(sparse_select.over_active(
+                    partial(sparse_select.select_paged, ipool=ip, layer=ii,
+                            k=cfg.index_topk),
+                    active, qi[:, 0], wi[:, 0], table, lens + 1))
+                attn = kv_selected_decode(cfg, q[:, 0], kp, vp, table, sel,
+                                          pl, active)
+                taken.append(jnp.sum(sel[1] & valid))
+            else:
+                with jax.named_scope(_kernel_scope(cfg, pool)):
+                    attn = paged_decode_attention(
+                        q[:, 0], kp, vp, table, lens + 1, window=band,
+                        layer=pl)
+                    if cfg.kv_pack > 1:
+                        attn = unpack_heads(cfg, attn)
+            ii += owner
             x = residual(cfg, x, attn_out(layer, attn.reshape(b, 1, -1)),
                          mix)
             li += 1

@@ -111,6 +111,12 @@ class GlmConfig(moe.MoEConfig):
         return -(-(self.kv_lora_rank + self.qk_rope) // 128) * 128
 
     @property
+    def index_rope(self):
+        """The leading lanes of qI_j and kI that rotate: the shared
+        key's."""
+        return self.qk_rope
+
+    @property
     def index_layers(self):
         """The layers that own an indexer, in order."""
         return tuple(i for i, k in enumerate(self.indexer_kinds)

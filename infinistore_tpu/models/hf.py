@@ -923,3 +923,94 @@ def glm_dsa_config_from_hf(hf_cfg, page_size=16, dtype="float32"):
         norm_eps=float(hf_cfg.rms_norm_eps),
         dtype=dtype,
     )
+
+
+def keye_config_from_hf(hf_cfg, page_size=16, dtype="float32"):
+    """Map the language model's keys of a ``model_type: KeyeVL2``
+    ``config.json`` (Kwai-Keye/Keye-VL-2.0-30B-A3B; any object with its
+    keys as attributes) onto :class:`models.keye.KeyeConfig`: grouped
+    queries over ``num_key_value_heads`` heads of ``head_dim``, the
+    group ``sa_config`` as the indexer's heads, width and ``topk`` on
+    every layer, ``num_experts`` SwiGLU experts ``moe_intermediate_size``
+    wide with ``num_experts_per_tok`` a token under a softmax router
+    whose gates are renormalised over the chosen (``norm_topk_prob``),
+    rotary in the halves layout at ``rope_theta``. Text alone: the
+    three position rows of ``rope_scaling.mrope_section`` are equal for
+    token ids, so the section goes unread; ``intermediate_size`` (no
+    layer is dense), ``max_window_layers``, ``sliding_window`` and
+    ``sa_config``'s ``q_chunk_size`` / ``kv_chunk_size`` (a kernel's
+    tiles) go unread too. The widths a random checkpoint draws three
+    kinds of matrix at are the group ``random_init``
+    (:func:`glm_dsa_config_from_hf`'s keys; ``query_gain``: the q
+    norm's weight).
+    Refused, because models/keye.py does not implement it: a
+    ``vision_config`` (a tower in front of the embedding: inputs are
+    token ids), ``use_sliding_window`` true, ``mlp_only_layers`` not
+    empty, ``decoder_sparse_step`` over 1, ``num_local_experts`` other
+    than ``num_experts``, more than one index key a token
+    (``indexer_num_kv_heads``), a ``rope_type`` other than default,
+    gates not normalised on the chosen, an activation other than silu,
+    attention bias, tied embeddings. Config only: no public key names a
+    checkpoint's tensors."""
+    from .keye import KeyeConfig
+
+    def refuse(what):
+        raise NotImplementedError(
+            f"KeyeVL2: {what} is not implemented by models/keye.py")
+
+    def group(name):
+        v = g(name)
+        return v if v is None or isinstance(v, dict) else vars(v)
+
+    g = lambda k, d=None: getattr(hf_cfg, k, d)  # noqa: E731
+    if g("vision_config") is not None:
+        refuse("a vision_config (a tower in front of the embedding; the "
+               "engine serves token ids)")
+    if g("use_sliding_window", False):
+        refuse("use_sliding_window (a selection over a window)")
+    if g("mlp_only_layers"):
+        refuse(f"mlp_only_layers {g('mlp_only_layers')!r}")
+    if g("decoder_sparse_step", 1) != 1:
+        refuse(f"decoder_sparse_step {g('decoder_sparse_step')}")
+    if g("num_local_experts", hf_cfg.num_experts) != hf_cfg.num_experts:
+        refuse(f"num_local_experts {g('num_local_experts')} of "
+               f"{hf_cfg.num_experts} experts")
+    sa = group("sa_config")
+    if not sa:
+        refuse("a model without sa_config (a dense-attention Qwen3-MoE)")
+    if sa.get("indexer_num_kv_heads", 1) != 1:
+        refuse(f"indexer_num_kv_heads {sa['indexer_num_kv_heads']}")
+    rs = group("rope_scaling") or {}
+    if rs.get("rope_type", rs.get("type", "default")) != "default":
+        refuse(f"rope_type {rs.get('rope_type')!r}")
+    if not g("norm_topk_prob", True):
+        refuse("norm_topk_prob false")
+    if g("hidden_act", "silu") != "silu":
+        refuse(f"hidden_act {g('hidden_act')!r}")
+    if g("attention_bias", False):
+        refuse("attention_bias")
+    if g("tie_word_embeddings", False):
+        refuse("tie_word_embeddings (the head is a leaf of its own)")
+    init = group("random_init") or {}
+    return KeyeConfig(
+        vocab_size=hf_cfg.vocab_size,
+        d_model=hf_cfg.hidden_size,
+        n_layers=hf_cfg.num_hidden_layers,
+        n_heads=hf_cfg.num_attention_heads,
+        n_kv_heads=hf_cfg.num_key_value_heads,
+        head_dim_override=g("head_dim", 0) or 0,
+        d_ff=hf_cfg.moe_intermediate_size,
+        n_experts=hf_cfg.num_experts,
+        top_k=hf_cfg.num_experts_per_tok,
+        index_heads=sa["indexer_num_heads"],
+        index_dim=sa["indexer_head_dim"],
+        index_topk=sa["topk"],
+        q_init_gain=float(init.get("query_gain", 1.0)),
+        o_init_gain=float(init.get("attn_out_gain", 1.0)),
+        down_init_gain=float(init.get("ffn_out_gain", 1.0)),
+        max_seq=hf_cfg.max_position_embeddings,
+        page_size=page_size,
+        rope_theta=float(hf_cfg.rope_theta),
+        norm_eps=float(hf_cfg.rms_norm_eps),
+        dtype=dtype,
+    )

@@ -1,18 +1,21 @@
 """A learned selection of cache rows (DeepSeek-V3.2's lightning indexer,
-GLM-5.2's `index_*` keys): index scores over a sequence's index keys,
-the EXACT top-k of them, and latent attention over the rows the
-selection names and no other. Plain jax.numpy on every backend: the
-gathers are XLA's.
+GLM-5.2's `index_*` keys, Keye-VL-2.0's `sa_config`): index scores
+over a sequence's index keys, the EXACT top-k of them, and attention
+over the rows the selection names and no other. Plain jax.numpy on
+every backend: the gathers are XLA's.
 
     I(t, s) = sum_j w(t, j) relu(qI_j(t) . kI(s))        s <= t
     S(t)    = the min(t + 1, k) positions of largest I(t, .)
+    a latent cache (one row a token):
     o(t, h) = sum_{s in S(t)} softmax_s(q_h(t) . row_s) row_s[:rank]
+    a K and V cache (grouped queries, g(h) the head's kv head):
+    o(t, h) = sum_{s in S(t)} softmax_s(q_h(t) . k_g(h)(s)) v_g(h)(s)
 
 The scores' products take their inputs as they are handed over (the
 cached keys in the pool's type) and accumulate in float32; relu, the
 weights and the sum over the index heads are float32. `select` is
 `jax.lax.top_k` (ties go to the lower position) and nothing that may
-return another set. The attention is the absorbed form of
+return another set. The latent attention is the absorbed form of
 ops/pallas_latent_attention.py (`latent_decode_xla`'s arithmetic) over
 gathered rows: what it reads follows the selection, not the context.
 
@@ -20,7 +23,11 @@ Two callers (models/decoder.py): a decode step, one query a DECODING
 sequence over the paged pools through the page table (`*_paged` under
 `over_active`: the slots that hold no sequence are not scored, sorted
 or gathered for), and an admission, a block of queries at a time over
-the contiguous rows of prefix + suffix (`*_seq`).
+the contiguous rows of prefix + suffix (`*_seq`). An admission over K
+and V rows does not gather: a block's selection becomes a MASK over
+the contiguous rows (`taken_mask`) and the block attends all of them
+under it (`select_attend_seq`): two matmuls over every row are cheaper
+there than two gathers of the selected ones (PERF.md, PR 49).
 """
 
 import jax
@@ -47,17 +54,37 @@ def _scores(q, w, keys, eq):
     return jnp.sum(jax.nn.relu(dots) * w.astype(F32)[..., None], axis=-2)
 
 
-def select(scores, n_live, k):
+def select(scores, n_live, k, with_scores=False):
     """The exact top-min(n_live, k) of each row of `scores` [n, S]
     over its first `n_live` [n] positions: (positions [n, k'] int32,
     taken [n, k'] bool), k' = min(k, S); positions not taken are
-    arbitrary (in range)."""
+    arbitrary (in range). `with_scores`: a third, the scores at those
+    positions [n, k'] (-inf where not taken)."""
     s = scores.shape[-1]
     k = min(k, s)
     live = jnp.arange(s)[None] < n_live[:, None]
-    _, idx = jax.lax.top_k(jnp.where(live, scores, -jnp.inf), k)
+    top, idx = jax.lax.top_k(jnp.where(live, scores, -jnp.inf), k)
     taken = jnp.arange(k)[None] < jnp.minimum(n_live, k)[:, None]
-    return idx.astype(jnp.int32), taken
+    out = (idx.astype(jnp.int32), taken)
+    return out + (top,) if with_scores else out
+
+
+def taken_mask(scores, n_live, sel):
+    """`select`'s set as a mask [n, S] over the positions, from its
+    triple `sel` (positions, taken, their scores) of `scores` [n, S]
+    over their first `n_live` [n] positions, and no scatter: the
+    positions scored above the last one taken, and of those that tie
+    with it the ones up to the highest position
+    taken among them (ties go to the lower position, so what `select`
+    took of a tie is its lowest positions). The set is `select`'s own,
+    ties included (tests/test_keye.py plants them)."""
+    idx, taken, top = sel
+    edge = jnp.min(jnp.where(taken, top, jnp.inf), axis=-1, keepdims=True)
+    last = jnp.max(jnp.where(taken & (top == edge), idx, -1), axis=-1,
+                   keepdims=True)
+    pos = jnp.arange(scores.shape[-1])[None]
+    return (pos < n_live[:, None]) & (
+        (scores > edge) | ((scores == edge) & (pos <= last)))
 
 
 def attend(q, rows, taken, rank):
@@ -72,6 +99,38 @@ def attend(q, rows, taken, rank):
     out = jnp.einsum("nhk,nkr->nhr", p.astype(q.dtype), rows[..., :rank],
                      preferred_element_type=F32, precision=precision)
     return out.astype(q.dtype)
+
+
+def _attend_by_group(q, k_rows, v_rows, keep, scale, rows):
+    """Grouped-query attention, query head h over kv head h // (H //
+    G): q [n, H, hd]; k_rows, v_rows with axes `rows` ("nkgd": each
+    query's own gathered rows; "sgd": one sequence's rows, shared);
+    keep [n, rows] which of them a query attends (at least one);
+    `scale` multiplies the float32 logits. [n, H, hd] in q's type."""
+    n, h, hd = q.shape
+    g = k_rows.shape[-2]
+    precision = _precision(q.dtype)
+    logits = jnp.einsum(f"nghd,{rows}->ngh{rows[-3]}",
+                        q.reshape(n, g, h // g, hd), k_rows.astype(q.dtype),
+                        preferred_element_type=F32, precision=precision)
+    logits = jnp.where(keep[:, None, None], logits * scale, NEG)
+    p = jax.nn.softmax(logits, axis=-1)
+    out = jnp.einsum(f"ngh{rows[-3]},{rows}->nghd", p.astype(q.dtype),
+                     v_rows.astype(q.dtype),
+                     preferred_element_type=F32, precision=precision)
+    return out.reshape(n, h, hd).astype(q.dtype)
+
+
+def attend_grouped(q, k_rows, v_rows, taken, scale):
+    """Each query over ITS gathered K and V rows [n, k, G, hd] where
+    `taken` [n, k] (a decode step's form)."""
+    return _attend_by_group(q, k_rows, v_rows, taken, scale, "nkgd")
+
+
+def attend_masked(q, k_rows, v_rows, mask, scale):
+    """A block of queries over ALL the contiguous rows [S, G, hd] of
+    one sequence under `mask` [n, S] (an admission's form)."""
+    return _attend_by_group(q, k_rows, v_rows, mask, scale, "sgd")
 
 
 # ---- a decode step: one query a sequence, over the paged pools ---------
@@ -148,8 +207,9 @@ def select_paged(q, w, page_table, n_live, ipool, layer, k):
 
 def gather_paged(pool, layer, page_table, idx):
     """Rows `idx` [b, k] (positions in the sequence) of layer `layer`
-    of the paged pool [layers, pages, page, width]: [b, k, width]. The
-    pool is addressed where it lies: no layer is sliced out."""
+    of the paged pool [layers, pages, page, *row]: [b, k, *row] (a
+    latent row [width]; a K or V row [kv heads, hd]). The pool is
+    addressed where it lies: no layer is sliced out."""
     page = pool.shape[2]
     with jax.named_scope("attn.gather"):
         pages = jnp.take_along_axis(page_table, idx // page, axis=1)
@@ -202,3 +262,23 @@ def attend_seq(absorb, q_parts, rows, idx, taken, rank):
             return attend(q, picked, taken_b, rank)
 
     return _blocked(one, idx.shape[0], idx, taken, *q_parts)
+
+
+def select_attend_seq(q, w, keys, positions, qa, k_rows, v_rows, k, scale):
+    """`select_seq` and, in the same block of queries, grouped-query
+    attention over the contiguous K and V rows [S, G, hd] of one
+    sequence under the selection's mask: the scores a block ranks are
+    the scores its mask is read from. qa: [s, H, hd] the attention's
+    queries, `scale` their logits' (`attend_masked`). Returns
+    (`select`'s pair [s, k'], out [s, H, hd])."""
+    def one(qb, wb, pos, qab):
+        with jax.named_scope("attn.index"):
+            scores = _scores(qb, wb, keys, "qhd,sd->qhs")
+        with jax.named_scope("attn.topk"):
+            sel = select(scores, pos + 1, k, with_scores=True)
+        with jax.named_scope("attn.mask"):
+            mask = taken_mask(scores, pos + 1, sel)
+        with jax.named_scope("attn.kernel"):
+            return sel[:2], attend_masked(qab, k_rows, v_rows, mask, scale)
+
+    return _blocked(one, q.shape[0], q, w, positions, qa)

@@ -417,14 +417,33 @@ def _decode_scan(params, cfg, token, seq_lens, k_pages, v_pages, rows,
 
 
 def _index_kind(cfg):
-    """The letter of a latent family's SECOND kind of page (index keys
-    of the layers that own an indexer: models/glm.py, "i"), or None: a
-    kind with a shape and a set of layers of its own
-    (`cfg.page_shape`, `cfg.page_layers`), held in the second pool
-    under the same page ids."""
+    """The letter of the LAST kind of page of a family whose layers
+    select the rows they attend (the index keys of the layers that own
+    an indexer: "i" of models/glm.py's "ci" and of models/keye.py's
+    "kvi"), or None: a kind with a shape and a set of layers of its
+    own (`cfg.page_shape`, `cfg.page_layers`). Such a family's engine
+    holds a pool a KIND under the same page ids (`_kind_pools`), and
+    its offload, its restore and a piece's prefix read make a call a
+    kind."""
     kinds = cfg.page_kinds
-    return kinds[1] if "latent" in cfg.layer_kinds and len(kinds) > 1 \
-        else None
+    return kinds[-1] if getattr(cfg, "indexer_kinds", ()) \
+        and len(kinds) > 1 else None
+
+
+def _kind_pools(k_pages, v_pages):
+    """The pools of a family with `_index_kind`, one a kind in the
+    order of `cfg.page_kinds`, from the pair every program carries:
+    (latent rows, index keys), or (K, (V, index keys)): three pools
+    ride in the pair's second place as a pytree, so the fused programs
+    take, donate and return them as they do an array."""
+    return (k_pages, *v_pages) if isinstance(v_pages, tuple) \
+        else (k_pages, v_pages)
+
+
+def _as_pair(pools):
+    """`_kind_pools`' inverse."""
+    first, *rest = pools
+    return first, rest[0] if len(rest) == 1 else tuple(rest)
 
 
 def _page_out(cfg, kvs, k_pages, v_pages, ids):
@@ -436,17 +455,20 @@ def _page_out(cfg, kvs, k_pages, v_pages, ids):
         page = cfg.page_size
         m = kvs[0][0].shape[1] // page
         shape = (cfg.n_kv_layers, m, *cfg.kv_page_shape())
-        k_sfx = jnp.stack([k[0] for k, _ in kvs]).reshape(shape)
+        k_sfx = jnp.stack([k[0] for k, *_ in kvs]).reshape(shape)
         if v_pages is None:  # a latent family's one pool
             return k_pages.at[:, ids[:m]].set(k_sfx, mode="drop"), None
-        kind = _index_kind(cfg)
-        if kind:
-            # ... and the pool of the index keys of the layers that own
-            # an indexer (the second of their pair), a width of its own
-            i_sfx = jnp.stack([i[0] for _, i in kvs if i is not None])
-            i_sfx = i_sfx.reshape(i_sfx.shape[0], m, *cfg.page_shape(kind))
-            return (k_pages.at[:, ids[:m]].set(k_sfx, mode="drop"),
-                    v_pages.at[:, ids[:m]].set(i_sfx, mode="drop"))
+        if _index_kind(cfg):
+            # ... and a pool a further kind: a layer's entry holds an
+            # array a kind (None where the layer keeps none), each kind
+            # of a shape of its own on the layers that keep it
+            sfx = [k_sfx]
+            for j, kind in enumerate(cfg.page_kinds[1:], 1):
+                a = jnp.stack([kv[j][0] for kv in kvs if kv[j] is not None])
+                sfx.append(a.reshape(a.shape[0], m, *cfg.page_shape(kind)))
+            return _as_pair([
+                pool.at[:, ids[:m]].set(a, mode="drop")
+                for pool, a in zip(_kind_pools(k_pages, v_pages), sfx)])
         v_sfx = jnp.stack([v[0] for _, v in kvs]).reshape(shape)
         k_pages = k_pages.at[:, ids[:m]].set(k_sfx, mode="drop")
         v_pages = v_pages.at[:, ids[:m]].set(v_sfx, mode="drop")
@@ -516,27 +538,26 @@ def _place_restored(cfg, restored, k_pages, v_pages, restored_ids):
                                                            mode="drop")
             pfx = jnp.moveaxis(rows, 0, 1).reshape(L, 1, n * page, -1)
         return k_pages, None, [(pfx[li], None) for li in range(L)]
-    kind = _index_kind(cfg)
-    if kind:
-        # ... with index keys on some layers: `restored` is the pair
-        # of the two kinds' store calls, each page-major over ITS
-        # layers; a layer's prefix is (rows, index keys or None).
-        owners = cfg.page_layers(kind)
+    if _index_kind(cfg):
+        # ... a pool a kind: `restored` holds each kind's store call,
+        # page-major over ITS layers; a layer's prefix is an array a
+        # kind (rows, index keys or None; K, V, index keys).
+        kinds = cfg.page_kinds
+        owners = [cfg.page_layers(kind) for kind in kinds]
+        pools = list(_kind_pools(k_pages, v_pages))
         with jax.named_scope("pool.update"):
-            rows = restored[0].reshape(n, L, *cfg.kv_page_shape())
-            keys = restored[1].reshape(n, len(owners),
-                                       *cfg.page_shape(kind))
-            for li in range(L):
-                k_pages = k_pages.at[li, restored_ids].set(rows[:, li],
-                                                           mode="drop")
-            for j in range(len(owners)):
-                v_pages = v_pages.at[j, restored_ids].set(keys[:, j],
-                                                          mode="drop")
-            pfx = jnp.moveaxis(rows, 0, 1).reshape(L, 1, n * page, -1)
-            ipfx = jnp.moveaxis(keys, 0, 1).reshape(
-                len(owners), 1, n * page, -1)
-        return k_pages, v_pages, [
-            (pfx[li], ipfx[owners.index(li)] if li in owners else None)
+            stacks = [r.reshape(n, len(own), *cfg.page_shape(kind))
+                      for r, own, kind in zip(restored, owners, kinds)]
+            for j, (stack, own) in enumerate(zip(stacks, owners)):
+                for li in range(len(own)):
+                    pools[j] = pools[j].at[li, restored_ids].set(
+                        stack[:, li], mode="drop")
+            pfx = [jnp.moveaxis(stack, 0, 1).reshape(
+                len(own), 1, n * page, *stack.shape[3:])
+                for stack, own in zip(stacks, owners)]
+        return *_as_pair(pools), [
+            tuple(p[own.index(li)] if li in own else None
+                  for p, own in zip(pfx, owners))
             for li in range(L)]
     with jax.named_scope("pool.update"):  # stage names: models/decoder.py
         # Each layer's pages go from the page-major rows straight into
@@ -1104,20 +1125,24 @@ class ServingEngine:
         if self._latent:
             self._check_latent_family()
         # ... unless some of its layers own an indexer: the index keys
-        # they cache are a second kind of page, of a width of its own
-        # on those layers alone, held in the second pool under the
-        # same page ids ([index layers, pages, page, index_dim]).
+        # they cache are a further kind of page, of a width of its own
+        # on those layers alone, held in a pool of its own under the
+        # same page ids ([index layers, pages, page, index width]): a
+        # latent family's second pool (models/glm.py), a K and V
+        # family's third, beside V in the pair's second place
+        # (models/keye.py; `_kind_pools`).
         self._index_kind = _index_kind(cfg)
         self._index_layers = list(cfg.page_layers(self._index_kind)) \
             if self._index_kind else []
+        self.v_pages = None if self._latent else jnp.zeros_like(self.k_pages)
         if self._index_kind:
-            self.v_pages = jnp.zeros(
+            ipool = jnp.zeros(
                 (len(self._index_layers), self.sc.total_pages,
                  *cfg.page_shape(self._index_kind)),
                 dtype=cfg.jdtype, device=self.device)
-        else:
-            self.v_pages = None if self._latent \
-                else jnp.zeros_like(self.k_pages)
+            if not self._latent:
+                self._check_index_family()
+            self.v_pages = ipool if self._latent else (self.v_pages, ipool)
         self.wk_pages = self.wv_pages = None
         if self._win_layers:
             self._init_window_pools()
@@ -1266,19 +1291,24 @@ class ServingEngine:
         self._page_bytes = kinds * self.k_pages.nbytes // self.sc.total_pages
         # The store keys that stand for a page in a hit's probe: one.
         self._probe_kinds = [(0, cfg.page_kinds[0])]
+        self._kinds_field = {}  # what a pool a kind adds to the spans
         if self._index_kind:
-            # ... but two kinds of different sizes on different layers:
-            # the rows' bytes (`_cpage_bytes`) and the index keys'; a
-            # page is a hit only with both, so the probe asks for the
-            # LAST object an offload writes of each kind.
-            self._cpage_bytes = self.k_pages.nbytes // self.sc.total_pages
-            self._page_objects = self.k_pages.shape[0] \
-                + len(self._index_layers)
-            self._page_bytes = self._cpage_bytes \
-                + self.v_pages.nbytes // self.sc.total_pages
-            self._probe_kinds = [
-                (self.k_pages.shape[0] - 1, cfg.page_kinds[0]),
-                (self._index_layers[-1], self._index_kind)]
+            # ... but kinds of different sizes on different layers, a
+            # pool each: one sequence page's bytes by kind
+            # (`_kind_bytes`); a page is a hit only with every kind, so
+            # the probe asks for the LAST object an offload writes of
+            # each kind.
+            self._kind_bytes = [
+                pool.nbytes // self.sc.total_pages
+                for pool in _kind_pools(self.k_pages, self.v_pages)]
+            self._page_objects = sum(
+                len(cfg.page_layers(kind)) for kind in cfg.page_kinds)
+            self._page_bytes = sum(self._kind_bytes)
+            self._probe_kinds = [(cfg.page_layers(kind)[-1], kind)
+                                 for kind in cfg.page_kinds]
+            # `kinds` on istpu.cache.offload and .restore: the calls
+            # (gather, transfer, store batch; get) each makes
+            self._kinds_field = {"kinds": len(cfg.page_kinds)}
         if self.sc.admit_piece % cfg.page_size:
             raise ValueError(
                 f"admit_piece {self.sc.admit_piece} is no multiple of the "
@@ -1336,6 +1366,8 @@ class ServingEngine:
             # ... and which layers keep index keys, how wide
             self._ns += (f"/index{cfg.index_dim}@"
                          + ".".join(map(str, self._index_layers)))
+            if not self._latent:  # ... in how many lanes a key
+                self._ns += f"w{cfg.index_width}"
         if self._win_layers:
             # ... of every cache kind: which layers are banded, and how
             # widely (a page of a banded layer is not a page of a full
@@ -1422,25 +1454,37 @@ class ServingEngine:
             self._check_index_family()
 
     def _check_index_family(self):
-        """What is not built over an index pool (a latent family whose
-        layers select the rows they attend, models/glm.py) is refused
-        at construction too. Beside what a latent cache refuses (a
+        """What is not built over an index pool (a family whose layers
+        select the rows they attend: models/glm.py over a latent
+        cache, models/keye.py over K and V pages) is refused at
+        construction too. Beside what a latent cache refuses (a
         verify or burst step would have to make and carry a selection
         a drafted token, and the int8 wire knows one page shape): a
         spec that is not one entry a layer or whose first layer
         borrows a selection (none is made below it; so some layer owns
-        an indexer and the second pool has a layer), and several
+        an indexer and the index pool has a layer), and several
         residual streams (the
         indexer reads ONE normalised stream; which, no published model
-        says)."""
-        cfg = self.cfg
+        says). Over K and V pages, three pools, the same options are
+        refused by name (speculation, bursts, the int8 wire, packed
+        rows, a window, state layers: none is built over three pools),
+        and a layer that borrows its selection (an attention layer
+        makes its own)."""
+        sc, cfg = self.sc, self.cfg
         kinds = getattr(cfg, "indexer_kinds", ())
+        over_kv = {} if self._latent else {
+            "spec_k": sc.spec_k > 0, "host_steps": sc.host_steps > 1,
+            "quantized_store": sc.quantized_store,
+            "kv_pack": cfg.kv_pack > 1, "window": bool(cfg.window_band),
+            "state layers": bool(getattr(cfg, "n_state_layers", 0)),
+            "a layer that borrows its selection":
+                any(k != "full" for k in kinds)}
         self._refuse("an index pool", {
             "an indexer spec that is not one entry a layer":
                 len(kinds) != cfg.n_layers,
             "a first layer that borrows its selection":
                 kinds[:1] != ("full",),
-            "hc_mult": cfg.hc_mult > 1})
+            "hc_mult": cfg.hc_mult > 1, **over_kv})
 
     def _check_state_family(self):
         """What is not built over a recurrent state is refused at
@@ -1816,7 +1860,7 @@ class ServingEngine:
         # The span times the store calls alone — the interval a span
         # around get_kv_pages from outside times too.
         with self._span("istpu.cache.restore", pages=n, bytes=nbytes,
-                        foreign_pages=foreign, **kinds,
+                        foreign_pages=foreign, **kinds, **self._kinds_field,
                         **self._snapshot_fields) as f:
             if self._index_kind:
                 # a call a kind: a store call carries pages of ONE shape
@@ -1875,13 +1919,14 @@ class ServingEngine:
         self.stats["prefix_hit_pages"] += hit
         self.stats["foreign_hit_pages"] += foreign
         f["foreign_pages"] = foreign
-        rows, *keys = restored if self._index_kind else (restored,)
+        rows, *more = restored if self._index_kind else (restored,)
         self.stats["restored_pages"] += rows.shape[0]
         if self._latent:
             self.stats["latent_pages_restored"] += rows.shape[0]
-        for k in keys:
+        for k in more:  # a call a further kind; the index keys' last
             self.stats["restored_pages"] += k.shape[0]
-            self.stats["index_pages_restored"] += k.shape[0]
+        if more:
+            self.stats["index_pages_restored"] += more[-1].shape[0]
         self.stats["snapshots_restored"] += snap is not None
         if self._win_layers:
             self.stats["restore_trimmed_pages"] += first_live
@@ -2052,7 +2097,8 @@ class ServingEngine:
                                 _gather_pages(pool, None, at).reshape(
                                     -1, *self.cfg.page_shape(kind))
                                 for pool, kind in zip(
-                                    (self.k_pages, self.v_pages),
+                                    _kind_pools(self.k_pages,
+                                                self.v_pages),
                                     self.cfg.page_kinds))
                         else:
                             restored = _gather_pages(
@@ -2589,20 +2635,20 @@ class ServingEngine:
                              n * len(self._index_layers)})
         with self._span("istpu.cache.offload", rid, reason=reason, pages=n,
                         bytes=nbytes, padded_pages=0, puts=0,
-                        **self._snapshot_fields) as f:
+                        **self._kinds_field, **self._snapshot_fields) as f:
             if not self._upload_room(nbytes):
                 return None
             if self._index_kind:
                 # a gather, a transfer and a store batch a kind: the
-                # rows of every layer, then the owners' index keys
-                kc, ki = self.cfg.page_kinds
-                self._gather_pool_pages(
-                    up, f, self.k_pages, None, slot.page_ids[lo:n_full],
-                    new_digests, self._full_layers, self._cpage_bytes, kind=kc)
-                self._gather_pool_pages(
-                    up, f, self.v_pages, None, slot.page_ids[lo:n_full],
-                    new_digests, self._index_layers,
-                    self._page_bytes - self._cpage_bytes, kind=ki)
+                # rows of every layer (or K, then V), then the owners'
+                # index keys
+                for pool, kind, kbytes in zip(
+                        _kind_pools(self.k_pages, self.v_pages),
+                        self.cfg.page_kinds, self._kind_bytes):
+                    self._gather_pool_pages(
+                        up, f, pool, None, slot.page_ids[lo:n_full],
+                        new_digests, self.cfg.page_layers(kind), kbytes,
+                        kind=kind)
             else:
                 self._gather_pool_pages(
                     up, f, self.k_pages, self.v_pages,

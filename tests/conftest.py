@@ -174,14 +174,17 @@ def benchmark_tests_pinned_before_pr26(request, monkeypatch):
             return
         if _select_in_the_pinned_tests(node, name, module, monkeypatch):
             return
+        if _keye_in_the_pinned_tests(node, name, module, monkeypatch):
+            return
     if module.__name__.endswith("test_bench_glm") and name == \
             "test_the_cell_its_configuration_and_its_metrics_are_in_the_manifest":
         # ... asserts that PR 46's five metrics are the LAST: it is
-        # shown the manifest without the one PR 47 appended
+        # shown the manifest without the one PR 47 appended and what
+        # PR 49 appended behind it
         load = module.manifest.load
 
         def load_as_of_pr46(*a, **kw):
-            bench = load(*a, **kw)
+            bench = _as_before_pr49(load(*a, **kw))
             bench["per_layer"] = [m for m in bench["per_layer"]
                                   if m["name"] != "select_active_share"]
             return bench
@@ -212,13 +215,15 @@ def benchmark_tests_pinned_before_pr26(request, monkeypatch):
         # vocabulary's slice: the chip's share, no width); glm-5.2's
         # by tests/benchmark/test_bench_glm.py (depth, the leading
         # dense layers, its two per-layer lists, the experts HELD, the
-        # vocabulary's slice, the prediction module: no width).
+        # vocabulary's slice, the prediction module: no width);
+        # keye-vl2-30b-a3b's by tests/benchmark/test_bench_keye.py
+        # (the depth alone).
         bench = dict(module.BENCH)
         bench["configs"] = [c for c in bench["configs"]
                             if c["name"] not in ("granite4h-micro",
                                                  "smallthinker21b",
                                                  "xing4-29b",
-                                                 _COMMAND_A, _GLM)]
+                                                 _COMMAND_A, _GLM, _KEYE)]
         monkeypatch.setattr(module, "BENCH", bench)
         return
     if module.__name__.endswith("test_bench_observations") \
@@ -255,7 +260,8 @@ def benchmark_tests_pinned_before_pr26(request, monkeypatch):
             for m in bench["per_layer"]:
                 for later in ("granite4h-micro-sessions4k",
                               "smallthinker21b-sessions12k",
-                              _XING_CELL, _COMMAND_A_CELL, _GLM_CELL):
+                              _XING_CELL, _COMMAND_A_CELL, _GLM_CELL,
+                              _KEYE_CELL):
                     if later in m.get("workloads", ()):
                         m["workloads"].remove(later)
             return bench
@@ -293,12 +299,75 @@ def _decode_ahead_in_the_pinned_tests(node, name, module, monkeypatch):
 _XING_CELL = "xing4-29b-docs32k"
 _COMMAND_A, _COMMAND_A_CELL = "command-a-plus", "command-a-plus-mixed12k"
 _GLM, _GLM_CELL = "glm-5.2", "glm-5.2-docs32k-answers"
+_KEYE, _KEYE_CELL = "keye-vl2-30b-a3b", "keye-vl2-30b-a3b-docs32k-answers"
+
+
+def _as_before_pr49(bench):
+    """The manifest without what PR 49 appended: the configuration
+    keye-vl2-30b-a3b, its cell, its per-layer metric and the cell's
+    name on the older metrics' lists."""
+    bench["workloads"] = [w for w in bench["workloads"]
+                          if w["name"] != _KEYE_CELL]
+    bench["configs"] = [c for c in bench["configs"] if c["name"] != _KEYE]
+    bench["per_layer"] = [m for m in bench["per_layer"]
+                          if m.get("workloads") != [_KEYE_CELL]]
+    for m in bench["per_layer"]:
+        if _KEYE_CELL in m.get("workloads", ()):
+            m["workloads"].remove(_KEYE_CELL)
+    return bench
+
+
+def _keye_in_the_pinned_tests(node, name, module, monkeypatch):
+    """PR 49 (`model_config`: may add benchmark files, edit none) added
+    the configuration keye-vl2-30b-a3b and one per-layer metric; as
+    `_glm_in_the_pinned_tests` for PR 46's. Returns True where it dealt
+    with the test: the table test gets the new metric's hand-worked
+    number from tests/benchmark/keye_by_hand.py, and the
+    configuration's cases of "resolves to today's defaults" are skipped
+    (it names a costs module, tolerances and programs of its own, which
+    tests/benchmark/test_bench_keye.py holds)."""
+    import pytest
+
+    params = getattr(getattr(node, "callspec", None), "params", {})
+    if name == "test_an_accepted_configuration_resolves_to_todays_defaults":
+        if params.get("config") == _KEYE:
+            pytest.skip("keye-vl2-30b-a3b brings its own costs and "
+                        "tolerances: test_bench_keye.py")
+        return False
+    if name != "test_reader_gives_the_number_worked_by_hand":
+        return False
+    import keye_by_hand as by_hand
+
+    if params.get("name") not in by_hand.BY_HAND:
+        return False
+    from benchmark.lib import serve
+    from benchmark.metrics import _scoped_ops
+    from infinistore_tpu.utils import profiling
+
+    table, window = module.expected, module.full_window
+
+    def full_window():
+        obs = window()
+        obs.conf = serve.load_config(
+            "benchmark/configs/keye-vl2-30b-a3b.json")
+        return obs
+
+    monkeypatch.setattr(module, "full_window", full_window)
+    monkeypatch.setattr(module, "expected",
+                        lambda obs: {**table(window()), **by_hand.BY_HAND})
+    monkeypatch.setattr(profiling, "spans", lambda: by_hand.RING)
+    monkeypatch.setattr(
+        _scoped_ops, "seconds",
+        lambda obs, kind, scopes: by_hand.SCOPED[kind, tuple(scopes)])
+    return True
 
 
 def _as_before_pr46(bench):
     """The manifest without what PR 46 appended: the configuration
     glm-5.2, its cell, its five per-layer metrics and the cell's name
-    on the older metrics' lists."""
+    on the older metrics' lists (nor what PR 49 appended behind
+    them)."""
+    _as_before_pr49(bench)
     bench["workloads"] = [w for w in bench["workloads"]
                           if w["name"] != _GLM_CELL]
     bench["configs"] = [c for c in bench["configs"] if c["name"] != _GLM]

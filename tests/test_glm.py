@@ -778,6 +778,11 @@ PAGE_CONTRACT = {
     "glm": ({"c": ((0, 1, 2, 3, 4), (16, 128), 4096),
              "i": ((0, 4), (16, 16), 512)},
             ["L0/c", "L1/c", "L2/c", "L3/c", "L4/c", "L0/i", "L4/i"]),
+    "keye": ({"k": ((0, 1, 2), (16, 2, 32), 2048),
+              "v": ((0, 1, 2), (16, 2, 32), 2048),
+              "i": ((0, 1, 2), (16, 128), 4096)},
+             ["L0/k", "L1/k", "L2/k", "L0/v", "L1/v", "L2/v", "L0/i",
+              "L1/i", "L2/i"]),
 }
 
 

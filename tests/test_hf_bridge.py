@@ -1075,3 +1075,95 @@ def test_glm_bridge_refuses_what_is_not_implemented(changed, match):
 
     with pytest.raises(NotImplementedError, match=match):
         glm_dsa_config_from_hf(_glm(**changed))
+
+
+# ---------------------------------------------------------------------------
+# KeyeVL2 (Keye-VL-2.0-30B-A3B), the language model: grouped queries with
+# q and k norms under a learned selection over K and V pages
+# ---------------------------------------------------------------------------
+
+# the catalog row's `config` (model-configs guide, architectures.jsonl),
+# key for key
+_KEYE_ROW = dict(
+    attention_bias=False, decoder_sparse_step=1, head_dim=128,
+    hidden_act="silu", hidden_size=2048, intermediate_size=6144,
+    max_position_embeddings=262144, max_window_layers=48, mlp_only_layers=[],
+    model_type="KeyeVL2", moe_intermediate_size=768, norm_topk_prob=True,
+    num_attention_heads=32, num_experts=128, num_experts_per_tok=8,
+    num_hidden_layers=48, num_key_value_heads=4, num_local_experts=128,
+    rms_norm_eps=1e-06,
+    rope_scaling={"mrope_section": [16, 24, 24], "rope_type": "default",
+                  "type": "default"},
+    rope_theta=10000000,
+    sa_config={"indexer_head_dim": 64, "indexer_num_heads": 16,
+               "indexer_num_kv_heads": 1, "kv_chunk_size": 512,
+               "q_chunk_size": 512, "topk": 2048},
+    sliding_window=None, tie_word_embeddings=False, use_sliding_window=False,
+    vocab_size=151936,
+)
+
+
+def _keye(**changed):
+    from types import SimpleNamespace
+
+    return SimpleNamespace(**{**_KEYE_ROW, **changed})
+
+
+def test_keye_config_maps_the_catalog_rows_keys():
+    from infinistore_tpu.models.hf import keye_config_from_hf
+
+    cfg = keye_config_from_hf(_keye(), page_size=16, dtype="bfloat16")
+    assert type(cfg).__name__ == "KeyeConfig"
+    assert (cfg.vocab_size, cfg.d_model, cfg.n_layers, cfg.n_heads,
+            cfg.n_kv_heads, cfg.head_dim, cfg.page_size, cfg.dtype) == (
+        151936, 2048, 48, 32, 4, 128, 16, "bfloat16")
+    assert (cfg.n_experts, cfg.top_k, cfg.d_ff, cfg.router, cfg.n_shared,
+            cfg.n_routed) == (128, 8, 768, "softmax", 0, 0)
+    assert not cfg.holds_share
+    assert (cfg.index_heads, cfg.index_dim, cfg.index_topk, cfg.index_rope,
+            cfg.index_width) == (16, 64, 2048, 64, 128)
+    assert cfg.indexer_kinds == ("full",) * 48
+    assert cfg.rope_theta == 1e7 and cfg.norm_eps == 1e-6
+    assert not cfg.rope_adjacent and not cfg.index_rope_adjacent
+    assert cfg.rope_scaling == () and cfg.max_seq == 262144
+    assert cfg.layer_kinds == ("attention",) * 48 and not cfg.two_kinds
+    assert (cfg.q_init_gain, cfg.o_init_gain, cfg.down_init_gain) == (
+        1.0, 1.0, 1.0)
+    init = keye_config_from_hf(_keye(random_init={
+        "query_gain": 1.5, "attn_out_gain": 0.03125, "ffn_out_gain": 0.25}))
+    assert (init.q_init_gain, init.o_init_gain, init.down_init_gain) == (
+        1.5, 0.03125, 0.25)
+    # the page contract's three kinds
+    assert cfg.page_kinds == "kvi"
+    assert cfg.page_shape("k") == cfg.page_shape("v") \
+        == cfg.kv_page_shape() == (16, 4, 128)
+    assert cfg.page_shape("i") == (16, 128)
+    assert cfg.page_layers("i") == cfg.page_layers("k") == tuple(range(48))
+    # the groups may come as namespaces, as a loaded config has them
+    from types import SimpleNamespace
+    ns = keye_config_from_hf(_keye(
+        sa_config=SimpleNamespace(**_KEYE_ROW["sa_config"]),
+        rope_scaling=SimpleNamespace(**_KEYE_ROW["rope_scaling"])))
+    assert ns == keye_config_from_hf(_keye())
+
+
+@pytest.mark.parametrize("changed,match", [
+    (dict(vision_config={"depth": 27}), "vision_config"),
+    (dict(use_sliding_window=True), "use_sliding_window"),
+    (dict(mlp_only_layers=[0]), "mlp_only_layers"),
+    (dict(decoder_sparse_step=2), "decoder_sparse_step"),
+    (dict(num_local_experts=16), "num_local_experts"),
+    (dict(sa_config=None), "without sa_config"),
+    (dict(sa_config={**_KEYE_ROW["sa_config"], "indexer_num_kv_heads": 2}),
+     "indexer_num_kv_heads"),
+    (dict(rope_scaling={"rope_type": "yarn", "factor": 4.0}), "rope_type"),
+    (dict(norm_topk_prob=False), "norm_topk_prob"),
+    (dict(hidden_act="gelu"), "hidden_act"),
+    (dict(attention_bias=True), "attention_bias"),
+    (dict(tie_word_embeddings=True), "tie_word_embeddings"),
+])
+def test_keye_bridge_refuses_what_is_not_implemented(changed, match):
+    from infinistore_tpu.models.hf import keye_config_from_hf
+
+    with pytest.raises(NotImplementedError, match=match):
+        keye_config_from_hf(_keye(**changed))

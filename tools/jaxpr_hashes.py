@@ -3,9 +3,10 @@
 prefix admission, decode step, offload gather) for the five families
 that keep K and V pages (cohere since PR 43), the one that keeps a
 latent row (xing, since PR 42) and the one that keeps index keys
-beside it on some layers (glm, since PR 46), at tiny widths; the
-prefix admission of the families with state layers or two kinds of
-attention layer since PR 48 (25 programs). A change that must
+beside it on some layers (glm, since PR 46) and the one that keeps
+index keys beside K and V on every layer (keye, since PR 49), at tiny
+widths; the prefix admission of the families with state layers or two
+kinds of attention layer since PR 48 (29 programs). A change that must
 leave their programs alone is checked by running this in both trees and
 comparing the output (PR 40: the parent unpacked under build/parent):
 
@@ -40,6 +41,8 @@ FAMILIES = {
         n_layers=5, dense_layers=(True, False, False, False, False),
         indexer_kinds=("full", "shared", "shared", "shared", "full"),
         index_topk=16, n_experts=2, top_k=2, n_routed=8, first_expert=2)),
+    "keye": ("keye", "KeyeConfig", dict(
+        n_layers=3, n_kv_heads=2, index_topk=16, n_experts=8, top_k=2)),
 }
 
 
@@ -143,8 +146,8 @@ def programs(name, wrap=None, **more):
         (params, toks, eng.k_pages, eng.v_pages, ids, jnp.int32(30)))
     pools = (eng.k_pages, eng.v_pages)
     if getattr(eng, "_index_kind", None):
-        # a second kind of page of its own shape on some layers: the
-        # restored pages are a pair, a gather reads one pool
+        # a further kind of page of its own shape: the restored pages
+        # are an array a kind, a gather reads one pool
         restored = tuple(
             jnp.zeros((2 * len(cfg.page_layers(kind)),
                        *cfg.page_shape(kind)), cfg.jdtype)
