@@ -767,7 +767,8 @@ def kv_selected_prefill(cfg, q, k_all, v_all, qi, wi, keys, positions):
     b, s = q.shape[:2]
     sel, out = jax.vmap(partial(
         sparse_select.select_attend_seq, k=cfg.index_topk,
-        scale=cfg.head_dim ** -0.5)
+        scale=cfg.head_dim ** -0.5,
+        with_positions=_SELECTION_TAP is not None)
     )(qi, wi, keys, positions, q, k_all, v_all)
     _tapped(sel)
     return out.reshape(b, s, -1)

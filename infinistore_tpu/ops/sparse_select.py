@@ -2,7 +2,8 @@
 GLM-5.2's `index_*` keys, Keye-VL-2.0's `sa_config`): index scores
 over a sequence's index keys, the EXACT top-k of them, and attention
 over the rows the selection names and no other. Plain jax.numpy on
-every backend: the gathers are XLA's.
+every backend (the gathers are XLA's) but for the bisection, which on a
+TPU is one Pallas call.
 
     I(t, s) = sum_j w(t, j) relu(qI_j(t) . kI(s))        s <= t
     S(t)    = the min(t + 1, k) positions of largest I(t, .)
@@ -13,25 +14,36 @@ every backend: the gathers are XLA's.
 
 The scores' products take their inputs as they are handed over (the
 cached keys in the pool's type) and accumulate in float32; relu, the
-weights and the sum over the index heads are float32. `select` is
-`jax.lax.top_k` (ties go to the lower position) and nothing that may
-return another set. The latent attention is the absorbed form of
+weights and the sum over the index heads are float32. S(t) is the set
+`jax.lax.top_k` would take (ties go to the lower position) and nothing
+that may return another, but no sort finds it: nobody reads an order.
+`threshold` maps a row's scores to integer keys and builds the key of
+its k-th largest from the top bit down, each pass one compare-and-count
+over the row (on a TPU all 32 in one kernel, the row held in VMEM);
+`taken_from` is the mask above that key, with the first
+of its equals by position (`taken_mask`: both); `positions_of` turns a
+mask into positions in ASCENDING order where a gather reads them
+(`select`: all three), by running counts and a one-hot product, no
+scatter. The latent attention is the absorbed form of
 ops/pallas_latent_attention.py (`latent_decode_xla`'s arithmetic) over
 gathered rows: what it reads follows the selection, not the context.
 
 Two callers (models/decoder.py): a decode step, one query a DECODING
 sequence over the paged pools through the page table (`*_paged` under
-`over_active`: the slots that hold no sequence are not scored, sorted
+`over_active`: the slots that hold no sequence are not scored, selected
 or gathered for), and an admission, a block of queries at a time over
 the contiguous rows of prefix + suffix (`*_seq`). An admission over K
-and V rows does not gather: a block's selection becomes a MASK over
-the contiguous rows (`taken_mask`) and the block attends all of them
-under it (`select_attend_seq`): two matmuls over every row are cheaper
-there than two gathers of the selected ones (PERF.md, PR 49).
+and V rows does not gather and makes no positions: a block's mask over
+the contiguous rows is what it attends under (`select_attend_seq`): two
+matmuls over every row are cheaper there than two gathers of the
+selected ones (PERF.md, PR 49).
 """
+
+import functools
 
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
 
 F32 = jnp.float32
 NEG = -1e30
@@ -54,37 +66,186 @@ def _scores(q, w, keys, eq):
     return jnp.sum(jax.nn.relu(dots) * w.astype(F32)[..., None], axis=-2)
 
 
-def select(scores, n_live, k, with_scores=False):
+# Bits of the threshold one pass of the XLA bisection settles (a divisor
+# of 32): a pass counts the keys at or above 2 ** _PASS_BITS - 1
+# candidates in ONE read of the row. The chip runs the kernel, whose
+# passes are one bit; the loop's width by tools/time_select_decode.py
+# and tools/time_admit_select.py `--bits` (PERF.md, PR 50).
+_PASS_BITS = 1
+# Positions a chunk: a running count is two levels, within a chunk (a
+# product with a triangle of ones: counts of at most _CHUNK are exact
+# in bfloat16) and over the chunks before it.
+_CHUNK = 128
+_LEAST = jnp.iinfo(jnp.int32).min
+
+
+def _keys(scores, live):
+    """Each float32 score as an int32 key in the scores' order: its
+    magnitude's bits, negated under the sign bit, so both zeros are ONE
+    key (equal keys are equal scores) and a denormal keeps its place;
+    what is not live, the least key, which no score has."""
+    bits = jax.lax.bitcast_convert_type(scores.astype(F32), jnp.int32)
+    return jnp.where(live, jnp.where(bits < 0, _LEAST - bits, bits), _LEAST)
+
+
+def _kth_key_loop(keys, k_row):
+    """`_kth_key` in XLA: one loop of 32 / _PASS_BITS compare-and-counts
+    over the row, a pass 2 ** _PASS_BITS - 1 candidates."""
+    steps = jnp.arange(1, 1 << _PASS_BITS, dtype=jnp.int32)
+
+    def one(i, t):
+        # int32 arithmetic wraps: the least key stands for 0
+        low = 32 - _PASS_BITS * (i + 1)
+        cand = t[:, None] + (steps << low)[None]
+        reached = jnp.sum(keys[:, None, :] >= cand[:, :, None], axis=-1,
+                          dtype=jnp.int32) >= k_row[:, None]
+        return t + (jnp.sum(reached, axis=-1, dtype=jnp.int32) << low)
+
+    return jax.lax.fori_loop(0, 32 // _PASS_BITS, one,
+                             jnp.full(keys.shape[:1], _LEAST))
+
+
+def _kth_key_body(keys_ref, k_ref, edge_ref):
+    """A few rows' keys [r, C, 128] held in VMEM through all 32 one-bit
+    passes; k and the result a row of lanes [r, 1, 128] each."""
+    keys = keys_ref[...]
+    k = k_ref[...].astype(F32)
+
+    def one(i, t):
+        cand = t + (1 << (31 - i))          # wraps: the least key is 0
+        at_or_above = jnp.where(keys >= cand, 1.0, 0.0)
+        count = jnp.sum(jnp.sum(at_or_above, axis=1, keepdims=True), axis=2,
+                        keepdims=True)
+        return jnp.where(count >= k, cand, t)
+
+    edge_ref[...] = jax.lax.fori_loop(0, 32, one,
+                                      jnp.full(k.shape, _LEAST))
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def _kth_key_kernel(keys, k_row, interpret=False):
+    """`_kth_key` as ONE Pallas call: a row's keys (140 KB at 35,072)
+    stay in VMEM through the passes."""
+    n, s = keys.shape
+    c = -(-s // (8 * _CHUNK)) * 8
+    rows = max(r for r in range(1, 9) if n % r == 0)
+
+    def spec(width):
+        return pl.BlockSpec((rows, width, _CHUNK), lambda i: (i, 0, 0))
+
+    edge = pl.pallas_call(
+        _kth_key_body, grid=(n // rows,),
+        in_specs=[spec(c), spec(1)], out_specs=spec(1),
+        out_shape=jax.ShapeDtypeStruct((n, 1, _CHUNK), jnp.int32),
+        interpret=interpret,
+    )(jnp.pad(keys, ((0, 0), (0, c * _CHUNK - s)),
+              constant_values=_LEAST).reshape(n, c, _CHUNK),
+      jnp.broadcast_to(k_row[:, None, None], (n, 1, _CHUNK)))
+    return edge[:, 0, 0]
+
+
+def _kth_key(keys, k_row):
+    """The `k_row` [n]-th largest key of each row of `keys` [n, S] (1 <=
+    k_row <= S), by bisection from the top bit down: the largest t with
+    at least k_row keys >= t; no sort. The kernel on TPU backends (ONE
+    call and no loop in the program: a loop under a decode step's
+    `attn.topk` would be counted beside its children,
+    benchmark/metrics/_scoped_ops.py), the XLA loop elsewhere."""
+    if jax.default_backend() == "tpu":
+        return _kth_key_kernel(keys, k_row)
+    return _kth_key_loop(keys, k_row)
+
+
+def _running_count(mask):
+    """Of `mask` [n, S], in chunks of _CHUNK positions (S padded up):
+    (within [n, C, _CHUNK]: how many are set in a position's chunk up
+    to and including it; before [n, C]: how many in the chunks before).
+    float32 holding integers; two products and no scan."""
+    n, s = mask.shape
+    c = -(-s // _CHUNK)
+    bf = jnp.bfloat16
+    chunks = jnp.pad(mask, ((0, 0), (0, c * _CHUNK - s))).reshape(
+        n, c, _CHUNK)
+    at = jnp.arange(_CHUNK)
+    within = jnp.einsum("ncl,lm->ncm", chunks.astype(bf),
+                        (at[:, None] <= at[None]).astype(bf),
+                        preferred_element_type=F32)
+    at = jnp.arange(c)
+    before = jnp.einsum("nc,cd->nd", within[..., -1].astype(bf),
+                        (at[:, None] < at[None]).astype(bf),
+                        preferred_element_type=F32)
+    return within, before
+
+
+def threshold(scores, n_live, k):
+    """What `taken_from` reads of each row of `scores` [n, S] over its
+    first `n_live` [n] positions (1 <= n_live): (keys [n, S] int32,
+    edge [n] the key of the row's min(n_live, k)-th largest live score,
+    k_row [n] that count)."""
+    s = scores.shape[-1]
+    keys = _keys(scores, jnp.arange(s)[None] < n_live[:, None])
+    k_row = jnp.minimum(n_live, min(k, s)).astype(jnp.int32)
+    return keys, _kth_key(keys, k_row), k_row
+
+
+def taken_from(keys, edge, k_row):
+    """The mask [n, S] of `threshold`'s triple: the positions whose key
+    is above the edge (what is not live lies under every edge), and of
+    those equal to it the first by position that fill the row's count
+    (one running count over the equals, no scatter)."""
+    n, s = keys.shape
+    edge = edge[:, None]
+    above = keys > edge
+    room = (k_row - jnp.sum(above, axis=-1, dtype=jnp.int32)).astype(F32)
+    equal = keys == edge
+    within, before = _running_count(equal)
+    first = (before[..., None] + within <= room[:, None, None]).reshape(
+        n, -1)[:, :s]
+    return above | (equal & first)
+
+
+def taken_mask(scores, n_live, k):
+    """The exact top-min(n_live, k) of each row of `scores` [n, S] over
+    its first `n_live` [n] positions as a mask [n, S]: `jax.lax.top_k`'s
+    set, ties to the lower position, found as a THRESHOLD and not by a
+    sort (tests/test_glm.py and tests/test_keye.py plant the ties)."""
+    return taken_from(*threshold(scores, n_live, k))
+
+
+def positions_of(mask, k):
+    """The positions a `mask` [n, S] of at most k' = min(k, S) a row
+    sets, in ASCENDING position: (positions [n, k'] int32, taken [n,
+    k'] bool: the slots that hold one, the first of each row; the rest
+    arbitrary, in range). No sort and no scatter: slot j finds its
+    chunk by the chunks' running counts, that chunk's running count by
+    a one-hot product (exact: counts of at most _CHUNK) and its place
+    in the chunk by one more compare-and-count."""
+    s = mask.shape[-1]
+    k = min(k, s)
+    within, before = _running_count(mask)
+    slot = jnp.arange(k, dtype=F32)
+    upto = before + within[..., -1]                           # [n, C]
+    passed = upto[:, None, :] <= slot[None, :, None]          # [n, k', C]
+    chunk = jnp.sum(passed, axis=-1, dtype=jnp.int32)
+    ahead = jnp.sum(jnp.where(passed, within[:, None, :, -1], 0), axis=-1)
+    bf = jnp.bfloat16
+    mine = jnp.einsum(
+        "nkc,ncl->nkl",
+        (chunk[..., None] == jnp.arange(upto.shape[-1])).astype(bf),
+        within.astype(bf), preferred_element_type=F32)        # [n, k', L]
+    place = jnp.sum(mine <= (slot[None] - ahead)[..., None], axis=-1,
+                    dtype=jnp.int32)
+    return (jnp.minimum(chunk * _CHUNK + place, s - 1),
+            slot[None] < upto[:, -1:])
+
+
+def select(scores, n_live, k):
     """The exact top-min(n_live, k) of each row of `scores` [n, S]
     over its first `n_live` [n] positions: (positions [n, k'] int32,
-    taken [n, k'] bool), k' = min(k, S); positions not taken are
-    arbitrary (in range). `with_scores`: a third, the scores at those
-    positions [n, k'] (-inf where not taken)."""
-    s = scores.shape[-1]
-    k = min(k, s)
-    live = jnp.arange(s)[None] < n_live[:, None]
-    top, idx = jax.lax.top_k(jnp.where(live, scores, -jnp.inf), k)
-    taken = jnp.arange(k)[None] < jnp.minimum(n_live, k)[:, None]
-    out = (idx.astype(jnp.int32), taken)
-    return out + (top,) if with_scores else out
-
-
-def taken_mask(scores, n_live, sel):
-    """`select`'s set as a mask [n, S] over the positions, from its
-    triple `sel` (positions, taken, their scores) of `scores` [n, S]
-    over their first `n_live` [n] positions, and no scatter: the
-    positions scored above the last one taken, and of those that tie
-    with it the ones up to the highest position
-    taken among them (ties go to the lower position, so what `select`
-    took of a tie is its lowest positions). The set is `select`'s own,
-    ties included (tests/test_keye.py plants them)."""
-    idx, taken, top = sel
-    edge = jnp.min(jnp.where(taken, top, jnp.inf), axis=-1, keepdims=True)
-    last = jnp.max(jnp.where(taken & (top == edge), idx, -1), axis=-1,
-                   keepdims=True)
-    pos = jnp.arange(scores.shape[-1])[None]
-    return (pos < n_live[:, None]) & (
-        (scores > edge) | ((scores == edge) & (pos <= last)))
+    taken [n, k'] bool), k' = min(k, S); `taken` is the first
+    min(n_live, k) slots and positions not taken are arbitrary (in
+    range). `taken_mask`'s set, in ascending position."""
+    return positions_of(taken_mask(scores, n_live, k), k)
 
 
 def attend(q, rows, taken, rank):
@@ -264,21 +425,26 @@ def attend_seq(absorb, q_parts, rows, idx, taken, rank):
     return _blocked(one, idx.shape[0], idx, taken, *q_parts)
 
 
-def select_attend_seq(q, w, keys, positions, qa, k_rows, v_rows, k, scale):
-    """`select_seq` and, in the same block of queries, grouped-query
-    attention over the contiguous K and V rows [S, G, hd] of one
-    sequence under the selection's mask: the scores a block ranks are
-    the scores its mask is read from. qa: [s, H, hd] the attention's
-    queries, `scale` their logits' (`attend_masked`). Returns
-    (`select`'s pair [s, k'], out [s, H, hd])."""
+def select_attend_seq(q, w, keys, positions, qa, k_rows, v_rows, k, scale,
+                      with_positions=False):
+    """`select_seq`'s selection and, in the same block of queries,
+    grouped-query attention over the contiguous K and V rows [S, G, hd]
+    of one sequence under the selection's mask: scores, threshold,
+    mask, attention, and neither a sort nor positions. qa: [s, H, hd]
+    the attention's queries, `scale` their logits' (`attend_masked`).
+    Returns (None, out [s, H, hd]); `with_positions` (a selection
+    somebody reads: decoder.selection_tap): `select`'s pair [s, k']
+    first, of the same mask."""
     def one(qb, wb, pos, qab):
         with jax.named_scope("attn.index"):
             scores = _scores(qb, wb, keys, "qhd,sd->qhs")
         with jax.named_scope("attn.topk"):
-            sel = select(scores, pos + 1, k, with_scores=True)
+            found = threshold(scores, pos + 1, k)
         with jax.named_scope("attn.mask"):
-            mask = taken_mask(scores, pos + 1, sel)
+            mask = taken_from(*found)
+        with jax.named_scope("attn.topk"):
+            sel = positions_of(mask, k) if with_positions else None
         with jax.named_scope("attn.kernel"):
-            return sel[:2], attend_masked(qab, k_rows, v_rows, mask, scale)
+            return sel, attend_masked(qab, k_rows, v_rows, mask, scale)
 
     return _blocked(one, q.shape[0], q, w, positions, qa)

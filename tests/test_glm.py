@@ -291,44 +291,126 @@ def test_the_references_planted_selections_move_its_rows(cfg, params,
     assert np.array_equal(again, want)
 
 
-@pytest.mark.parametrize("reordered", [False, True])
-def test_selection_is_top_ks_set_on_rows_with_ties(reordered):
-    """200 random score rows drawn from FEW values (ties everywhere):
-    the set is `jax.lax.top_k`'s, which takes the lower position of
-    equals; a numpy stable sort says the same. `reordered`: the rows as
-    25 decode batches of 8 slots of which some hold a sequence, selected
-    through `over_active` (the valid slots first, a rung of the ladder,
-    back in slot order): a valid row's set is the same."""
+def _few_values(rng):
+    """200 rows of 96 scores drawn from 6 values: ties everywhere."""
+    return (rng.integers(0, 6, (200, 96)).astype(np.float32),
+            rng.integers(1, 97, 200).astype(np.int32), TOPK)
+
+
+def _ties_at_the_kth(rng):
+    """Rows of 35,072 at k 2,048 whose 2,048th largest value is held by
+    hundreds of positions, on both sides of the cut."""
+    scores = rng.integers(0, 40, (8, 35072)).astype(np.float32)
+    scores[1] = 3.0                                 # one value, all equal
+    scores[2, rng.random(35072) < 0.05] = 50.0      # ~1,750 above the ties
+    scores[3] = -scores[3]
+    return scores, np.array([35072, 35072, 35072, 35072, 17000, 20001,
+                             34000, 2300], np.int32), 2048
+
+
+def _signs_and_specials(rng):
+    """Negative scores, -0.0 beside +0.0 (ONE value: position decides),
+    denormals of both signs, +inf and -inf."""
+    scores = rng.standard_normal((48, 96)).astype(np.float32)
+    scores[:, ::3] = np.round(scores[:, ::3])       # and ties among them
+    for r in range(0, 48, 4):
+        at = rng.permutation(96)
+        scores[r, at[:20]] = -0.0
+        scores[r, at[20:40]] = 0.0
+        scores[r + 1, at[:15]] = 1e-42
+        scores[r + 1, at[15:30]] = -1e-42
+        scores[r + 1, at[30:45]] = 2e-42
+        scores[r + 2, at[:10]] = np.inf
+        scores[r + 2, at[10:20]] = -np.inf
+        scores[r + 3] = -np.abs(scores[r + 3])
+    return scores, rng.integers(1, 97, 48).astype(np.int32), TOPK
+
+
+def _n_live_at_the_edges(rng):
+    """n_live of 1, k - 1, k, k + 1 and S, over few values and many."""
+    edges = np.array([1, TOPK - 1, TOPK, TOPK + 1, 96], np.int32)
+    scores = np.concatenate([
+        rng.integers(0, 3, (20, 96)), rng.standard_normal((20, 96))]
+    ).astype(np.float32)
+    return scores, np.tile(edges, 8), TOPK
+
+
+SELECTION_ROWS = {"few_values": _few_values,
+                  "ties_at_the_kth_of_35072": _ties_at_the_kth,
+                  "signs_zeros_denormals_inf": _signs_and_specials,
+                  "n_live_1_k_and_s": _n_live_at_the_edges}
+
+
+@pytest.mark.parametrize("rows", list(SELECTION_ROWS))
+@pytest.mark.parametrize("reordered", [False, True, 6])
+def test_selection_is_top_ks_set_on_rows_with_ties(reordered, rows):
+    """Score rows with ties everywhere (`rows`): the set is
+    `jax.lax.top_k`'s, which takes the lower position of equals; a
+    numpy stable sort says the same, and the slots `taken` are the
+    first of a row. `reordered`: the rows as decode batches of 8 (or 6)
+    slots of which some hold a sequence, selected through `over_active`
+    (the valid slots first, a rung of the ladder, back in slot order): a
+    valid row's set is the same, at every rung."""
     rng = np.random.default_rng(5)
-    scores = rng.integers(0, 6, (200, 96)).astype(np.float32)
-    n_live = rng.integers(1, 97, 200).astype(np.int32)
-    rows = range(200)
+    scores, n_live, k = SELECTION_ROWS[rows](rng)
+    n, s = scores.shape
+    picked = range(n)
     if reordered:
-        valid = rng.random((25, 8)) < 0.4
+        b = 8 if reordered is True else reordered
+        batches = max(n // b, b + 3)                # the rows, again
+        scores, n_live = (np.resize(a, (batches * b, *a.shape[1:]))
+                          for a in (scores, n_live))
+        valid = rng.random((batches, b)) < 0.4
         valid[0], valid[1] = True, False
+        for count in range(b + 1):              # every rung, every count
+            valid[2 + count] = np.arange(b)[::-1] < count
         some = jax.jit(lambda v, s, n: sparse_select.over_active(
-            partial(sparse_select.select, k=TOPK),
+            partial(sparse_select.select, k=k),
             sparse_select.active_first(v), s, n))
         idx, taken = (jnp.concatenate(part) for part in zip(*(
             some(jnp.asarray(v), jnp.asarray(s), jnp.asarray(n))
-            for v, s, n in zip(valid, scores.reshape(25, 8, 96),
-                               n_live.reshape(25, 8)))))
-        rows = np.flatnonzero(valid)
+            for v, s, n in zip(valid, scores.reshape(batches, b, s),
+                               n_live.reshape(batches, b)))))
+        picked = np.flatnonzero(valid)
         # no slot of the second batch is valid: the least rung runs one
-        assert not np.asarray(taken[9:16]).any()
+        assert not np.asarray(taken[b + 1:2 * b]).any()
+        assert {int(sparse_select.active_first(jnp.asarray(v))[1])
+                for v in valid} == set(range(len(sparse_select.ladder(b))))
     else:
-        idx, taken = sparse_select.select(jnp.asarray(scores),
-                                          jnp.asarray(n_live), TOPK)
-    assert idx.shape == (200, TOPK) and idx.dtype == jnp.int32
-    for r in rows:
+        idx, taken = jax.jit(partial(sparse_select.select, k=k))(
+            jnp.asarray(scores), jnp.asarray(n_live))
+    idx, taken = np.asarray(idx), np.asarray(taken)
+    assert idx.shape[1:] == (min(k, s),) and idx.dtype == np.int32
+    assert idx.min() >= 0 and idx.max() < s
+    for r in picked:
         live = scores[r, :n_live[r]]
-        k = min(TOPK, n_live[r])
-        want = np.argsort(-live, kind="stable")[:k]
-        got = np.asarray(idx[r])[np.asarray(taken[r])]
-        assert int(np.asarray(taken[r]).sum()) == k
-        assert sorted(got.tolist()) == sorted(want.tolist())
-        _, top = jax.lax.top_k(jnp.asarray(live), k)
-        assert sorted(np.asarray(top).tolist()) == sorted(got.tolist())
+        kk = min(k, n_live[r])
+        want = np.argsort(-live, kind="stable")[:kk]
+        assert taken[r, :kk].all() and not taken[r, kk:].any()
+        assert sorted(idx[r][taken[r]].tolist()) == sorted(want.tolist())
+        if rows in ("few_values", "n_live_1_k_and_s"):
+            # (`top_k` on the CPU ranks +0.0 above -0.0: not those rows)
+            _, top = jax.lax.top_k(jnp.asarray(live), kk)
+            assert sorted(np.asarray(top).tolist()) == sorted(want.tolist())
+
+
+@pytest.mark.parametrize("rows", list(SELECTION_ROWS))
+def test_the_chips_bisection_kernel_finds_the_loops_threshold(rows):
+    """`_kth_key_kernel` (what a TPU backend runs, here in interpret
+    mode) and `_kth_key_loop` at one bit a pass and at four: the same
+    key of the kth largest on the rows with ties, and it IS the kth
+    largest."""
+    scores, n_live, k = SELECTION_ROWS[rows](np.random.default_rng(5))
+    scores, n_live = scores[:24], n_live[:24]
+    keys, edge, k_row = jax.jit(partial(sparse_select.threshold, k=k))(
+        jnp.asarray(scores), jnp.asarray(n_live))
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(sparse_select, "_PASS_BITS", 4)
+        assert np.array_equal(edge, sparse_select._kth_key_loop(keys, k_row))
+    assert np.array_equal(edge, sparse_select._kth_key_kernel(
+        keys, k_row, interpret=True))
+    for r, row in enumerate(np.asarray(keys)):
+        assert edge[r] == np.sort(row)[-k_row[r]]
 
 
 def test_the_sum_of_all_shares_is_the_uncut_layer(cfg, params):

@@ -11,11 +11,14 @@ are tests/test_glm.py's.
 import dataclasses
 import time
 import types
+from functools import partial
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+import test_glm
+from test_admit_one_row import _equations
 
 from benchmark.reference import keye_dsa as reference
 from infinistore_tpu import serving
@@ -272,24 +275,72 @@ def test_the_router_is_the_softmax_over_the_chosen_logits():
 
 
 # -- the selection as a mask -------------------------------------------------
-def test_the_mask_is_top_ks_set_on_rows_with_ties():
-    """200 random score rows drawn from FEW values (ties everywhere):
-    `taken_mask` names exactly the positions `select` took, which are
-    a stable sort's (ties to the lower position)."""
+@pytest.mark.parametrize("rows", list(test_glm.SELECTION_ROWS))
+def test_the_mask_is_top_ks_set_on_rows_with_ties(rows):
+    """Score rows with ties everywhere (tests/test_glm.py's `rows`; the
+    few values here under weights of either sign): `taken_mask` names
+    exactly the positions `select` takes of the same scores, which are
+    a stable sort's (ties to the lower position), and `select` hands
+    them over in ascending position."""
     rng = np.random.default_rng(5)
-    scores = rng.integers(0, 6, (200, 96)).astype(np.float32)
-    scores *= rng.choice([-1.0, 1.0], (200, 1))   # a weight can be negative
-    n_live = rng.integers(1, 97, 200).astype(np.int32)
-    sel = sparse_select.select(jnp.asarray(scores), jnp.asarray(n_live),
-                               TOPK, with_scores=True)
-    mask = np.asarray(sparse_select.taken_mask(
-        jnp.asarray(scores), jnp.asarray(n_live), sel))
-    idx, taken = np.asarray(sel[0]), np.asarray(sel[1])
-    for r in range(200):
-        k = min(TOPK, n_live[r])
-        want = np.argsort(-scores[r, :n_live[r]], kind="stable")[:k]
-        assert sorted(np.flatnonzero(mask[r]).tolist()) == sorted(
-            want.tolist()) == sorted(idx[r][taken[r]].tolist())
+    scores, n_live, k = test_glm.SELECTION_ROWS[rows](rng)
+    if rows == "few_values":
+        scores *= rng.choice([-1.0, 1.0], (len(scores), 1))
+    mask, (idx, taken) = jax.jit(lambda s, n: (
+        sparse_select.taken_mask(s, n, k), sparse_select.select(s, n, k))
+    )(jnp.asarray(scores), jnp.asarray(n_live))
+    mask, idx, taken = np.asarray(mask), np.asarray(idx), np.asarray(taken)
+    for r in range(len(scores)):
+        want = np.argsort(-scores[r, :n_live[r]],
+                          kind="stable")[:min(k, n_live[r])]
+        assert np.flatnonzero(mask[r]).tolist() == sorted(
+            want.tolist()) == idx[r][taken[r]].tolist()
+
+
+def test_an_admissions_selection_is_found_without_a_sort(cfg, monkeypatch):
+    """No program of the engine's admissions under a selection over K
+    and V rows holds a `sort` or a `top_k` (nobody reads an order), nor
+    positions; with the tap open the positions are made, of the same
+    mask and still without a sort. A decode step's selection (and no
+    loop under its `attn.topk`: benchmark/metrics/_scoped_ops.py would
+    count a loop's own event beside its children's) and a latent
+    admission's: the same."""
+    rng = np.random.default_rng(3)
+    s, n = 96, 80
+    args = [jnp.asarray(rng.standard_normal(shape), jnp.float32)
+            for shape in [(1, n, 4, 16), (1, n, 4), (1, s, 16),
+                          (1, n, 8, 16), (1, s, 2, 16), (1, s, 2, 16)]]
+    at = jnp.arange(s - n, s)[None]
+
+    def admit(qi, wi, keys, q, k_all, v_all):
+        return decoder.kv_selected_prefill(cfg, q, k_all, v_all, qi, wi,
+                                           keys, at)
+
+    def primitives(jaxpr):
+        return {eqn.primitive.name for eqn in _equations(jaxpr.jaxpr)}
+
+    # (a fresh function a trace: the tap is read while tracing)
+    closed = primitives(jax.make_jaxpr(lambda *a: admit(*a))(*args))
+    assert not closed & {"sort", "top_k", "scatter", "scatter-add",
+                         "cumsum"}
+    with decoder.selection_tap([]) as taps:
+        opened = primitives(jax.make_jaxpr(lambda *a: admit(*a))(*args))
+        (idx, taken), = taps
+    assert idx.shape == taken.shape == (1, n, TOPK)
+    assert not opened & {"sort", "top_k", "scatter", "scatter-add"}
+    for backend in ("cpu", "tpu"):      # the loop's form, the kernel's
+        monkeypatch.setattr(jax, "default_backend", lambda: backend)
+        step = primitives(jax.make_jaxpr(partial(
+            sparse_select.select_paged, layer=0, k=TOPK))(
+                args[0][0], args[1][0], jnp.zeros((n, 12), jnp.int32),
+                jnp.full(n, 90), jnp.zeros((1, 4, PAGE, 16))))
+        assert not step & {"sort", "top_k", "scatter", "scatter-add",
+                           "while"}
+        assert ("pallas_call" in step) == (backend == "tpu")
+    monkeypatch.undo()
+    seq = primitives(jax.make_jaxpr(partial(sparse_select.select_seq, k=TOPK))(
+        args[0][0], args[1][0], args[2][0], at[0]))
+    assert not seq & {"sort", "top_k", "scatter", "scatter-add"}
 
 
 def test_attention_under_the_mask_is_attention_over_the_gathered_rows():
@@ -303,10 +354,10 @@ def test_attention_under_the_mask_is_attention_over_the_gathered_rows():
     v = jnp.asarray(rng.standard_normal((s, g, hd)), jnp.float32)
     scores = jnp.asarray(rng.standard_normal((n, s)), jnp.float32)
     n_live = jnp.asarray(rng.integers(1, s + 1, n), jnp.int32)
-    sel = sparse_select.select(scores, n_live, TOPK, with_scores=True)
+    idx, taken = sparse_select.select(scores, n_live, TOPK)
     a = sparse_select.attend_masked(
-        q, k, v, sparse_select.taken_mask(scores, n_live, sel), 0.25)
-    b = sparse_select.attend_grouped(q, k[sel[0]], v[sel[0]], sel[1], 0.25)
+        q, k, v, sparse_select.taken_mask(scores, n_live, TOPK), 0.25)
+    b = sparse_select.attend_grouped(q, k[idx], v[idx], taken, 0.25)
     assert np.abs(np.asarray(a)).max() > 0.1
     assert np.abs(np.asarray(a) - np.asarray(b)).max() < 1e-5
 

@@ -823,6 +823,27 @@ def test_decode_program_for_the_chip_copies_no_projection_weight(
     assert not large, large
 
 
+@pytest.mark.parametrize("rows", [1, 6, 64])
+def test_selection_for_the_chip_is_one_kernel_and_no_sort(rows, v5e_chip,
+                                                          monkeypatch):
+    """`sparse_select.select` over rows of 35,072 scores at k 2,048,
+    compiled for a described v5e at a decode step's batch (1, 6) and an
+    admission's block (64): the threshold is ONE Pallas call (Mosaic
+    takes the kernel at these shapes), and the program holds neither a
+    sort nor a loop (a loop under a decode program's `attn.topk` would
+    be counted beside its children: benchmark/metrics/_scoped_ops.py)."""
+    from infinistore_tpu.ops import sparse_select
+
+    with _the_chips_branch(monkeypatch):
+        text = jax.jit(lambda s, n: sparse_select.select(s, n, 2048)).lower(
+            jax.ShapeDtypeStruct((rows, 35072), jnp.float32,
+                                 sharding=v5e_chip),
+            jax.ShapeDtypeStruct((rows,), jnp.int32, sharding=v5e_chip),
+        ).compile().as_text()
+    assert text.count("tpu_custom_call") == 1
+    assert " sort(" not in text and " while(" not in text
+
+
 def _tool(name):
     """tools/<name>.py as a module."""
     import importlib.util

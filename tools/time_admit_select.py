@@ -7,14 +7,27 @@ admission under a learned selection over K and V rows
 suffix. The two forms the attention under the selection can take
 (PERF.md, PR 49), and the stages both share:
 
-  index     the block's index scores [64, S]
-  topk      `select` over them (`jax.lax.top_k`)
-  masked    (ii) `taken_mask` from the selection and `attend_masked`
-            over ALL S rows under it: no gather, 17 x the FLOPs at 35k
-  gathered  (i) the selected K and V rows gathered (2 x 64 x 2,048 rows
-            of 1 KB) and `attend_grouped` over them
-  block_*   the whole block by each form (index + topk + attention), as
-            `select_attend_seq` runs (ii)
+  index      the block's index scores [64, S]
+  topk       the selection as it was until PR 50: `jax.lax.top_k` over
+             them, a sort (composed here; not in the tree)
+  threshold  `threshold`: the scores' keys and the kth largest of a row
+             by bisection over the keys' bits, as the tree runs it on
+             this backend (the chip: ONE Pallas call, a row's keys held
+             in VMEM through 32 one-bit passes)
+  kth_loop   the bisection alone over the keys as the XLA loop that
+             runs off the chip (`_kth_key_loop`), `--bits` of the key a
+             pass (a row for each: the table that chose the form)
+  mask       `taken_from`: the mask of a threshold, ties by position
+  compact    `positions_of`: a mask's positions in ascending order
+             (what glm-5.2's admissions and an open tap add)
+  masked     (ii) `taken_mask` of the scores and `attend_masked` over
+             ALL S rows under it: no gather, 17 x the FLOPs at 35k
+  gathered   (i) the selected K and V rows gathered (2 x 64 x 2,048
+             rows of 1 KB) and `attend_grouped` over them
+  block_*    the whole block by each form (index + selection +
+             attention), as `select_attend_seq` runs (ii);
+             `block_masked_sorted`: (ii) as the parent ran it, the mask
+             read from a sort's last score taken
 
 A piece of 4,096 tokens is 64 such blocks in each of 5 layers. The form
 NOT kept in the tree, (i), is composed here from the pieces the decode
@@ -55,6 +68,7 @@ def operands(s):
 
 
 def stages(ss):
+    import jax
     import jax.numpy as jnp
 
     scale = HEAD_DIM ** -0.5
@@ -63,12 +77,39 @@ def stages(ss):
         return ss._scores(qi, w, keys, "qhd,sd->qhs")
 
     def topk(scores, pos):
-        return ss.select(scores, pos + 1, TOPK)[0]
+        live = jnp.arange(scores.shape[-1])[None] <= pos[:, None]
+        return jax.lax.top_k(jnp.where(live, scores, -jnp.inf), TOPK)[1]
+
+    def threshold(scores, pos):
+        return ss.threshold(scores, pos + 1, TOPK)[1]
+
+    def kth_loop(keys, pos):
+        return ss._kth_key_loop(keys, jnp.minimum(pos + 1, TOPK))
+
+    def mask(keys, edge, pos):
+        return ss.taken_from(keys, edge, jnp.minimum(pos + 1, TOPK))
+
+    def compact(taken):
+        return ss.positions_of(taken, TOPK)[0]
 
     def masked(qa, scores, pos, k, v):
-        sel = ss.select(scores, pos + 1, TOPK, with_scores=True)
-        return ss.attend_masked(qa, k, v, ss.taken_mask(scores, pos + 1, sel),
-                                scale)
+        return ss.attend_masked(qa, k, v,
+                                ss.taken_mask(scores, pos + 1, TOPK), scale)
+
+    def masked_sorted(qa, scores, pos, k, v):
+        # the parent's `taken_mask`: above the last score a sort took,
+        # and of its equals up to the highest position taken
+        n_live = pos[:, None] + 1
+        at = jnp.arange(scores.shape[-1])[None]
+        top, idx = jax.lax.top_k(jnp.where(at < n_live, scores, -jnp.inf),
+                                 TOPK)
+        taken = jnp.arange(TOPK)[None] < jnp.minimum(n_live, TOPK)
+        edge = jnp.min(jnp.where(taken, top, jnp.inf), axis=-1,
+                       keepdims=True)
+        last = jnp.max(jnp.where(taken & (top == edge), idx, -1), axis=-1,
+                       keepdims=True)
+        return ss.attend_masked(qa, k, v, (at < n_live) & (
+            (scores > edge) | ((scores == edge) & (at <= last))), scale)
 
     def mask_only(qa, scores, pos, k, v):
         # the mask given, the attention alone (the sort is `topk`'s)
@@ -83,6 +124,9 @@ def stages(ss):
         scores = index(qi, w, keys)
         return masked(qa, scores, pos, k, v)
 
+    def block_masked_sorted(qi, w, pos, qa, keys, k, v):
+        return masked_sorted(qa, index(qi, w, keys), pos, k, v)
+
     def block_gathered(qi, w, pos, qa, keys, k, v):
         idx, taken = ss.select(index(qi, w, keys), pos + 1, TOPK)
         return ss.attend_grouped(qa, jnp.take(k, idx, axis=0),
@@ -91,11 +135,17 @@ def stages(ss):
     return {
         "index": (index, ("qi", "w", "keys")),
         "topk": (topk, ("scores", "pos")),
+        "threshold": (threshold, ("scores", "pos")),
+        "kth_loop": (kth_loop, ("skeys", "pos")),
+        "mask": (mask, ("skeys", "edge", "pos")),
+        "compact": (compact, ("taken",)),
         "attend_all_rows": (mask_only, ("qa", "scores", "pos", "k", "v")),
         "masked": (masked, ("qa", "scores", "pos", "k", "v")),
         "gathered": (gathered, ("qa", "idx", "k", "v")),
         "block_masked": (block_masked,
                          ("qi", "w", "pos", "qa", "keys", "k", "v")),
+        "block_masked_sorted": (block_masked_sorted,
+                                ("qi", "w", "pos", "qa", "keys", "k", "v")),
         "block_gathered": (block_gathered,
                            ("qi", "w", "pos", "qa", "keys", "k", "v")),
     }
@@ -132,6 +182,10 @@ def main():
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--reps", type=int, default=20)
     ap.add_argument("--rounds", type=int, default=3)
+    ap.add_argument("--bits", default="",
+                    help="widths of a bisection pass to time `kth_loop` "
+                    "at, divisors of 32, e.g. 1,2,4 (default: the file's "
+                    "_PASS_BITS)")
     ap.add_argument("--out")
     args = ap.parse_args()
     sys.path.insert(0, os.path.dirname(os.path.dirname(
@@ -144,18 +198,32 @@ def main():
         sys.exit("time_admit_select: no TPU; a CPU time is not a device "
                  "time")
     todo = stages(ss)
+    widths = [int(b) for b in args.bits.split(",") if b] or [ss._PASS_BITS]
     lines = []
     for s in ROWS:
         ops = operands(s)
         ops["scores"] = jax.jit(todo["index"][0])(
             ops["qi"], ops["w"], ops["keys"])
-        ops["idx"] = jax.jit(todo["topk"][0])(ops["scores"], ops["pos"])
+        ops["skeys"], ops["edge"], _ = jax.jit(
+            lambda s, p: ss.threshold(s, p + 1, TOPK))(
+                ops["scores"], ops["pos"])
+        ops["taken"] = jax.jit(todo["mask"][0])(ops["skeys"], ops["edge"],
+                                                ops["pos"])
+        ops["idx"] = jax.jit(todo["compact"][0])(ops["taken"])
         for name, (fn, takes) in todo.items():
-            row = {"stage": name, "rows": s, "call_us": time_chain(
-                fn, [ops[k] for k in takes], args.reps, args.rounds),
-                "device": jax.devices()[0].device_kind}
-            lines.append(row)
-            print(json.dumps(row), flush=True)
+            kept = ss._PASS_BITS
+            swept = name == "kth_loop"
+            for bits in widths if swept else [kept]:
+                ss._PASS_BITS = bits    # read while a stage is traced
+                row = {"stage": name, "rows": s}
+                if swept:
+                    row["pass_bits"] = bits
+                row.update(call_us=time_chain(
+                    fn, [ops[k] for k in takes], args.reps, args.rounds),
+                    device=jax.devices()[0].device_kind)
+                lines.append(row)
+                print(json.dumps(row), flush=True)
+            ss._PASS_BITS = kept
     if args.out:
         os.makedirs(os.path.dirname(os.path.abspath(args.out)),
                     exist_ok=True)

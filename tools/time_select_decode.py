@@ -4,18 +4,28 @@
 a page table of 2,192 pages of 16 (35,072 keys), 32 index heads of 128,
 `index_topk` 2,048, latent rows of 640 bf16 lanes under 64 heads.
 
-  index   `select_paged`'s first half: a slot's index keys through its
-          page table and their scores
-  topk    `select` over those scores (`jax.lax.top_k`)
-  attend  `gather_paged` of the selected rows and `attend` over them
+  index      `select_paged`'s first half: a slot's index keys through
+             its page table and their scores
+  topk       the selection as it was until PR 50: `jax.lax.top_k` over
+             those scores, a sort (composed here; not in the tree)
+  threshold  `threshold`: the scores' keys and the kth largest of a row
+             by bisection over the keys' bits, as the tree runs it on
+             this backend (the chip: ONE Pallas call, a row's keys held
+             in VMEM through 32 one-bit passes)
+  kth_loop   the bisection alone over the keys as the XLA loop that
+             runs off the chip (`_kth_key_loop`), `--bits` of the key a
+             pass (a row for each: the table that chose the form)
+  mask       `taken_from`: the mask of a threshold, ties by position
+  compact    `positions_of`: a mask's positions in ascending order
+  exact      `select` over the scores: threshold, mask and compact
+  select     `select_paged`: index and exact
+  attend     `gather_paged` of the selected rows and `attend` over them
 
 each over the first n = 1, 2, 4, 8 slots (the rungs of
 `sparse_select.ladder(8)`: what a branch of `over_active` runs; a loop
 of one sequence a turn would pay the n = 1 time a sequence), and
-`select` (index + topk) and `attend` through `over_active` with 1, 2,
-3, 5, 8 of the 8 slots valid (the conditional, the reorder and the
-branch together); a stage alone at n = 8 is the stage as the parent ran
-it whatever was valid.
+`select` and `attend` through `over_active` with 1, 2, 3, 5, 8 of the 8
+slots valid (the conditional, the reorder and the branch together).
 
 Time as tools/time_paged_decode.py takes it: R calls chained inside one
 jitted loop, wall time / R, the best of a few repeats. Every operand of
@@ -66,6 +76,7 @@ def operands():
 def stages(ss):
     """name: (fn over arrays with a leading slot axis -> arrays with a
     leading slot axis, the operands it takes, the pool it reads)."""
+    import jax
     import jax.numpy as jnp
 
     def index(qi, w, table, ipool):
@@ -75,6 +86,22 @@ def stages(ss):
                           "bhd,bsd->bhs")
 
     def topk(scores, n_live):
+        live = jnp.arange(scores.shape[-1])[None] < n_live[:, None]
+        return jax.lax.top_k(jnp.where(live, scores, -jnp.inf), TOPK)[1]
+
+    def threshold(scores, n_live):
+        return ss.threshold(scores, n_live, TOPK)[1]
+
+    def kth_loop(keys, n_live):
+        return ss._kth_key_loop(keys, jnp.minimum(n_live, TOPK))
+
+    def mask(keys, edge, n_live):
+        return ss.taken_from(keys, edge, jnp.minimum(n_live, TOPK))
+
+    def compact(taken):
+        return ss.positions_of(taken, TOPK)[0]
+
+    def exact(scores, n_live):
         return ss.select(scores, n_live, TOPK)[0]
 
     def select(qi, w, table, n_live, ipool):
@@ -87,6 +114,11 @@ def stages(ss):
 
     return {"index": (index, ("qi", "w", "table"), "ipool"),
             "topk": (topk, ("scores", "n_live"), None),
+            "threshold": (threshold, ("scores", "n_live"), None),
+            "kth_loop": (kth_loop, ("keys", "n_live"), None),
+            "mask": (mask, ("keys", "edge", "n_live"), None),
+            "compact": (compact, ("taken",), None),
+            "exact": (exact, ("scores", "n_live"), None),
             "select": (select, ("qi", "w", "table", "n_live"), "ipool"),
             "attend": (attend, ("q", "table", "idx"), "pool")}
 
@@ -124,6 +156,10 @@ def main():
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--reps", type=int, default=50)
     ap.add_argument("--rounds", type=int, default=3)
+    ap.add_argument("--bits", default="",
+                    help="widths of a bisection pass to time `kth_loop` "
+                    "at, divisors of 32, e.g. 1,2,4 (default: the file's "
+                    "_PASS_BITS)")
     ap.add_argument("--out")
     args = ap.parse_args()
     sys.path.insert(0, os.path.dirname(os.path.dirname(
@@ -140,7 +176,11 @@ def main():
     todo = stages(ss)
     ops["scores"] = jax.jit(todo["index"][0])(
         ops["qi"], ops["w"], ops["table"], ops["ipool"])
-    ops["idx"] = jax.jit(todo["topk"][0])(ops["scores"], ops["n_live"])
+    ops["keys"], ops["edge"], _ = jax.jit(
+        lambda s, n: ss.threshold(s, n, TOPK))(ops["scores"], ops["n_live"])
+    ops["taken"] = jax.jit(todo["mask"][0])(ops["keys"], ops["edge"],
+                                            ops["n_live"])
+    ops["idx"] = jax.jit(todo["compact"][0])(ops["taken"])
     lines = []
 
     def say(**row):
@@ -148,12 +188,18 @@ def main():
         lines.append(row)
         print(json.dumps(row), flush=True)
 
+    widths = [int(b) for b in args.bits.split(",") if b] or [ss._PASS_BITS]
     for name, (fn, takes, pool) in todo.items():
         pool = None if pool is None else ops[pool]
-        for n in ss.ladder(SLOTS):
-            say(stage=name, form="alone", slots_run=n, call_us=time_chain(
-                fn, [ops[k][:n] for k in takes], pool, args.reps,
-                args.rounds))
+        kept = ss._PASS_BITS
+        for bits in widths if name == "kth_loop" else [kept]:
+            ss._PASS_BITS = bits        # read while a stage is traced
+            for n in ss.ladder(SLOTS):
+                row = {"pass_bits": bits} if name == "kth_loop" else {}
+                say(stage=name, form="alone", slots_run=n, **row,
+                    call_us=time_chain(fn, [ops[k][:n] for k in takes],
+                                       pool, args.reps, args.rounds))
+        ss._PASS_BITS = kept
     for name in ("select", "attend"):
         fn, takes, pool = todo[name]
         pool = ops[pool]
