@@ -63,6 +63,7 @@ out-of-range page ids dropped — no recompilation as counts vary.
 import collections
 import hashlib
 import logging
+import operator
 import queue
 import threading
 import time
@@ -253,6 +254,12 @@ class _Work:
     queued_ns: int = 0    # unix ns it entered the queue (the request's
     #                       arrival; after a preemption, the swap-out)
     queue_len: int = 0    # requests that were ahead of it then
+    gap_at: tuple = None  # where its last token was emitted: (ns on
+    #                       the engine's clock, the five causes' ns
+    #                       then), `ServingEngine._gap_mark`. None until
+    #                       the first; kept across a swap-out, so the gap
+    #                       a resumed sequence's next token closes
+    #                       began before it
 
     def __post_init__(self):
         if self.req.temperature > 0 and self.rng is None:
@@ -338,6 +345,53 @@ class _Flight:
     counted: bool             # ... the experts it fetched among them
     logits: object            # device logits (a sampling slot's row)
     ahead: bool               # dispatched before the step before it landed
+
+
+# What the engine thread did in a gap between two tokens of one
+# request, by the span it did it under: the causes in the order the
+# engine keeps their ns (`ServingEngine._cause_ns`) and the `gap_ns_*`
+# counters name them. An admission is a miss or a hit by the
+# `hit_pages` its span closes with.
+GAP_CAUSES = ("step", "admit_miss", "admit_hit", "admit_piece", "offload")
+_STEP, _ADMIT_MISS, _ADMIT_HIT, _ADMIT_PIECE, _OFFLOAD = range(5)
+_CAUSE_OF_SPAN = {"istpu.model.decode": _STEP,
+                  "istpu.sched.admit": _ADMIT_MISS,
+                  "istpu.sched.admit_piece": _ADMIT_PIECE,
+                  "istpu.cache.offload": _OFFLOAD}
+_GAP_KEYS = tuple(f"gap_ns_{cause}" for cause in GAP_CAUSES)
+
+
+def _cause_of(span):
+    """Index into GAP_CAUSES of a span `_CAUSE_OF_SPAN` lists."""
+    cause = _CAUSE_OF_SPAN[span.name]
+    if cause == _ADMIT_MISS and span.fields["hit_pages"] > 0:
+        return _ADMIT_HIT
+    return cause
+
+
+class _CauseSpan(profiling.span):
+    """A span of the engine thread that `_CAUSE_OF_SPAN` lists. The
+    OUTERMOST one open is the engine's `_cause_open`, and as it closes
+    its duration goes to its cause: a prefill inside an admission, an
+    offload inside either or a settle count once, under the span that
+    holds them, so the five never overlap and never exceed the
+    thread's wall clock."""
+
+    __slots__ = ("owner",)
+
+    def __enter__(self):
+        fields = super().__enter__()
+        if self.owner._cause_open is None:
+            self.owner._cause_open = self
+        return fields
+
+    def __exit__(self, *exc):
+        super().__exit__(*exc)
+        eng = self.owner
+        if eng._cause_open is self:
+            eng._cause_open = None
+            eng._cause_ns[_cause_of(self)] += self.dur_ns
+        return False
 
 
 def prompt_lookup_propose(context, k, ngram=2):
@@ -1249,6 +1303,14 @@ class ServingEngine:
             "select_rows_active": 0, "attn_rows_selected": 0,
             "attn_rows_live": 0, "index_pages_offloaded": 0,
             "index_pages_restored": 0,
+            # the gaps between two tokens of one request, as `_emit`
+            # counts them: how many, their ns on the engine's clock,
+            # what the engine thread spent of them under a span of
+            # each cause (GAP_CAUSES; the rest, `other`, is the loop
+            # around the steps), and the gaps in which a cause other
+            # than `step` ran at all
+            "gap_tokens": 0, "gap_ns": 0, **dict.fromkeys(_GAP_KEYS, 0),
+            "gaps_stalled": 0,
         }
         # Requests finished: in `outputs`, or held for their offload's
         # acknowledgement. What a driver reads as progress.
@@ -1283,6 +1345,16 @@ class ServingEngine:
             "e_gate" in layer for layer in params.get("layers", ())
         ) if getattr(cfg, "holds_share", False) else 0
         self.engine_id = profiling.next_engine_id()
+        # The gaps between tokens. ns the engine thread has spent under
+        # spans of each cause (GAP_CAUSES), as of the last one that
+        # closed, and the outermost such span open now (`_CauseSpan`);
+        # the gaps `_emit` has seen and `_count_gaps` has not counted
+        # yet, {mark they began at: how many}; and the mark at which a
+        # plain decode step last landed its tokens (`_land`).
+        self._cause_ns = [0] * len(GAP_CAUSES)
+        self._cause_open = None
+        self._gaps = {}
+        self._landed_at = None
         self._own_digests = {}  # insertion-ordered, at most OWN_DIGESTS
         # One sequence page over every layer and kind the page pools
         # hold, and one state snapshot, in bytes.
@@ -1517,7 +1589,13 @@ class ServingEngine:
 
     def _span(self, name, request=None, **fields):
         """A span of this engine in the program's ring
-        (utils/profiling.py); docs/serving.md lists the names."""
+        (utils/profiling.py); docs/serving.md lists the names. One
+        that is a cause of a gap between tokens adds its time to it
+        (`_CauseSpan`): those names are the engine thread's alone."""
+        if name in _CAUSE_OF_SPAN:
+            span = _CauseSpan(name, request, self.engine_id, **fields)
+            span.owner = self
+            return span
         return profiling.span(name, request, self.engine_id, **fields)
 
     def _weights_fingerprint(self):
@@ -1731,10 +1809,17 @@ class ServingEngine:
         n_pages = -(-n_prompt // self.cfg.page_size)
         rid = work.req.request_id
         t0_ns = time.time_ns()
+        fresh = work.gap_at is None
         with self._span("istpu.sched.admit", rid, slot=slot_idx,
                         prompt_tokens=n_prompt, hit_pages=0,
                         foreign_pages=0) as f:
             admitted = self._do_admit(slot_idx, work, n_prompt, n_pages, f)
+        if fresh and work.gap_at is not None:
+            # Its first token left inside the span: the causes are
+            # marked anew behind it, so that a request's OWN admission
+            # is in none of its gaps (what is left of the span behind
+            # the token reads as `other`).
+            work.gap_at = (work.gap_at[0], *self._cause_ns)
         if admitted:
             # Known only now that an admission went through: arrival
             # (or swap-out) to the start of that admission.
@@ -2507,16 +2592,77 @@ class ServingEngine:
 
     # ---- decode --------------------------------------------------------
 
-    def _emit(self, slot, tokens):
+    def _emit(self, slot, tokens, at=None):
         """The ONE place generated tokens enter a slot: appends and
         fires the request's streaming callback once per token (callback
-        failures are the caller's bug — they propagate)."""
+        failures are the caller's bug — they propagate). And where the
+        gaps between a request's tokens are seen: from its second token
+        on, each call closes the gap that began at the request's mark
+        (`_Work.gap_at`), and k tokens at once are k gaps, the first
+        the interval and the rest 0, as a client sees them. `at`: the
+        mark of this moment, which a step takes ONCE for all the slots
+        it emits to and hands to `_count_gaps` behind them; None: a
+        token on its own (an admission's first), marked and counted
+        here."""
         slot.generated.extend(tokens)
-        cb = slot.work.req.on_token
+        work = slot.work
+        last, work.gap_at = work.gap_at, at or self._gap_mark()
+        if last is not None:
+            # (no call a token here: under a profiler session every
+            # one, a builtin's too, is an event of its Python tracer)
+            try:
+                self._gaps[last] += 1
+            except KeyError:
+                self._gaps[last] = 1
+            if tokens[1:]:
+                self.stats["gap_tokens"] += len(tokens) - 1
+            if at is None:
+                self._count_gaps(work.gap_at)
+        cb = work.req.on_token
         if cb is not None:
-            rid = slot.work.req.request_id
+            rid = work.req.request_id
             for t in tokens:
                 cb(rid, t)
+
+    def _gap_mark(self):
+        """(now, the five causes' ns as of now): the engine's clock
+        (`perf_counter_ns`, the spans') and `_cause_ns` with what the
+        outermost cause span open now has run so far. Marks are
+        compared by their differences alone."""
+        now = time.perf_counter_ns()
+        span = self._cause_open
+        if span is None:
+            return (now, *self._cause_ns)
+        mark = [now, *self._cause_ns]
+        mark[1 + _cause_of(span)] += now - span._p0
+        return tuple(mark)
+
+    def _count_gaps(self, at, df=None):
+        """The gaps that `_emit` saw end at the mark `at`, into the
+        counters: the slots that emitted together last time share one
+        mark, so one difference serves them all. `df`: the fields of
+        the decode span a step lands under, which gain `waiting` (the
+        slots that emitted here and at the land before) and, where
+        another cause than `step` ran between the two lands, its ns
+        (`stall_ns`) and the largest of them (`stall_cause`)."""
+        st = self.stats
+        for last, n in self._gaps.items():
+            interval, step, *stalls = map(operator.sub, at, last)
+            st["gap_tokens"] += n
+            st["gap_ns"] += n * interval
+            st["gap_ns_step"] += n * step
+            stall_ns = sum(stalls)
+            if stall_ns:
+                st["gaps_stalled"] += n
+                for key, ns in zip(_GAP_KEYS[1:], stalls):
+                    st[key] += n * ns
+            if df is not None and last is self._landed_at:
+                df["waiting"] = n
+                if stall_ns:
+                    df["stall_ns"] = stall_ns
+                    df["stall_cause"] = GAP_CAUSES[
+                        1 + stalls.index(max(stalls))]
+        self._gaps.clear()
 
     @staticmethod
     def _probs(req, row):
@@ -3148,6 +3294,7 @@ class ServingEngine:
                 df["dispatch_ns"] = profiling.elapsed_ns()
                 toks = np.asarray(toks_dev)  # [B, k] — the one D2H
             trimmed = False
+            at = self._gap_mark()
             for i, s in active:
                 burst = [int(t) for t in toks[i]]
                 if self.sc.eos_id >= 0 and self.sc.eos_id in burst:
@@ -3156,10 +3303,11 @@ class ServingEngine:
                     # overwritten by any later occupant of the pages.
                     burst = burst[: burst.index(self.sc.eos_id) + 1]
                     trimmed = True
-                self._emit(s, burst)
+                self._emit(s, burst, at)
                 s.seq_len += len(burst)
                 self._release_windowed(s)
                 self.stats["decoded_tokens"] += len(burst)
+            self._count_gaps(at)
             self.stats["decode_steps"] += k
             # `key` is still valid here: nothing between its
             # computation and this point mutates the active set or
@@ -3287,9 +3435,8 @@ class ServingEngine:
         key = (tuple(i for i, _ in active), self._pages_rev)
         steady = (self._steady is not None and greedy
                   and self._steady[0] == key)
-        # of every step the call dispatched (two, where a run begins)
-        f["steady"] = f.get("steady", True) and steady
-        f["rows_uploaded"] = not f["steady"]
+        # of any step the call dispatched (two, where a run begins)
+        f["rows_uploaded"] = f.get("rows_uploaded", False) or not steady
         if steady:
             return self._steady
         if more:
@@ -3397,6 +3544,7 @@ class ServingEngine:
             self.stats["moe_pairs_held"] += int(nxt[-1])
         lhost = _LazyHost(flight.logits)
         landed = 0
+        at = self._gap_mark()  # the one clock read a landed step
         for i, s in active:
             if self._done(s):
                 self.stats["decode_rows_dropped"] += 1
@@ -3405,10 +3553,13 @@ class ServingEngine:
                 tok = self._pick(s.work, lhost()[i])
             else:
                 tok = int(nxt[i])
-            self._emit(s, [tok])
+            self._emit(s, [tok], at)
             s.seq_len += 1
             self._release_windowed(s)
             landed += 1
+        df["waiting"] = 0
+        self._count_gaps(at, df)
+        self._landed_at = at
         self.stats["decoded_tokens"] += landed
         self.stats["decode_steps"] += 1
         self.stats["decode_steps_ahead"] += flight.ahead
@@ -3568,6 +3719,7 @@ class ServingEngine:
             df["dispatch_ns"] = profiling.elapsed_ns()
             nxt = np.asarray(nxt_dev)
         lhost = _LazyHost(logits)  # ONE transfer if any slot samples
+        at = self._gap_mark()
         for i, s in active:
             p = props[i]
             if s.work.req.temperature > 0:
@@ -3585,7 +3737,7 @@ class ServingEngine:
                 # beyond it hold stale KV that is masked and never
                 # offloaded).
                 appended = appended[: appended.index(self.sc.eos_id) + 1]
-            self._emit(s, appended)
+            self._emit(s, appended, at)
             s.seq_len += len(appended)
             self._release_windowed(s)
             self.stats["spec_proposed"] += len(p)
@@ -3594,6 +3746,7 @@ class ServingEngine:
             # came from the draft).
             self.stats["spec_accepted"] += min(a, len(appended))
             self.stats["decoded_tokens"] += len(appended)
+        self._count_gaps(at)
         self.stats["decode_steps"] += 1
         return len(active)
 

@@ -156,6 +156,8 @@ def benchmark_tests_pinned_before_pr26(request, monkeypatch):
     module = getattr(request, "module", None)
     if module is None:
         return
+    if _gap_by_cause_in_the_pinned_tests(node, name, module, monkeypatch):
+        return
     if _idle_by_span_in_the_pinned_tests(node, name, module, monkeypatch):
         return
     if _decode_ahead_in_the_pinned_tests(node, name, module, monkeypatch):
@@ -283,6 +285,14 @@ def _decode_ahead_in_the_pinned_tests(node, name, module, monkeypatch):
 
     if node.callspec.params.get("name") not in by_hand.BY_HAND:
         return False
+    _counters_and_numbers_by_hand(module, monkeypatch, by_hand)
+    return True
+
+
+def _counters_and_numbers_by_hand(module, monkeypatch, by_hand):
+    """test_bench_observations.py's synthetic window with
+    `by_hand.COUNTERS` among its counters, and its table of expected
+    numbers with `by_hand.BY_HAND`."""
     table, window = module.expected, module.full_window
 
     def full_window():
@@ -293,6 +303,50 @@ def _decode_ahead_in_the_pinned_tests(node, name, module, monkeypatch):
     monkeypatch.setattr(module, "full_window", full_window)
     monkeypatch.setattr(module, "expected",
                         lambda obs: {**table(obs), **by_hand.BY_HAND})
+
+
+def _gap_by_cause_in_the_pinned_tests(node, name, module, monkeypatch):
+    """PR 51 (`tracing`: may add benchmark files, edit none) appended
+    nine per-layer metrics, the mean gap between tokens by its cause;
+    their numbers by hand are in their own test file,
+    tests/benchmark/test_bench_gap_by_cause.py. Returns True where it
+    dealt with the test.
+
+    - test_bench_observations.py's table test gets the nine's window
+      counters, ring and hand-worked numbers from that file;
+    - the tests that hold an earlier PR's entries to be the LAST of
+      BENCHMARK.json's lists (one a configuration's test file, and
+      test_bench_program_spans.py's) are shown the manifest without
+      the nine. That is done FIRST and returns False: the hooks below
+      then take away what lies between their PR and this one."""
+    last = ("test_the_cell_its_configuration_and_its_metrics_are_in_the_manifest",
+            "test_the_cell_its_configuration_and_its_metric_are_in_the_manifest",
+            "test_the_new_metrics_are_in_the_manifest_and_it_is_sound")
+    if name in last and "test_bench_" in module.__name__ \
+            and not module.__name__.endswith("test_bench_gap_by_cause"):
+        import test_bench_gap_by_cause as by_hand
+
+        load = module.manifest.load
+
+        def load_as_of_pr50(*a, **kw):
+            bench = load(*a, **kw)
+            bench["per_layer"] = [m for m in bench["per_layer"]
+                                  if m["name"] not in by_hand.BY_HAND]
+            return bench
+
+        monkeypatch.setattr(module.manifest, "load", load_as_of_pr50)
+        return False
+    if not module.__name__.endswith("test_bench_observations") \
+            or name != "test_reader_gives_the_number_worked_by_hand":
+        return False
+    import test_bench_gap_by_cause as by_hand
+
+    if node.callspec.params.get("name") not in by_hand.BY_HAND:
+        return False
+    from infinistore_tpu.utils import profiling
+
+    _counters_and_numbers_by_hand(module, monkeypatch, by_hand)
+    monkeypatch.setattr(profiling, "spans", lambda: by_hand.RING)
     return True
 
 

@@ -492,14 +492,15 @@ def test_an_engine_left_without_work_records_one_no_work_a_spell(
 
 
 def test_steps_say_whether_their_inputs_were_on_the_device(params, cfg):
-    """`steady` on a plain step that dispatched: false where inputs
-    were rebuilt and uploaded (the first step after an admission; a
-    finish changes the active set too; behind a step in flight the
-    tables alone, where a slot took a new page), true where the device
-    still held them; a call that only lands the step a run ends with
-    dispatched nothing and says nothing; and a decode span's
-    `dispatch_ns`, the start of the span to the return of the dispatch,
-    lies inside it."""
+    """`rows_uploaded` on a plain step that dispatched: true where
+    inputs were rebuilt and uploaded (the first step after an
+    admission; a finish changes the active set too; behind a step in
+    flight the tables alone, where a slot took a new page), false where
+    the device still held them; a call that only lands the step a run
+    ends with dispatched nothing and says nothing; no step carries the
+    `steady` that said the same thing (gone since PR 51); and a decode
+    span's `dispatch_ns`, the start of the span to the return of the
+    dispatch, lies inside it."""
     eng = _engine(params, cfg, None, "spans-steady")
     spans = _run(eng, Request("a", _prompt(51, PAGE + 2), max_new_tokens=6))
     t0 = time.time_ns()
@@ -512,23 +513,22 @@ def test_steps_say_whether_their_inputs_were_on_the_device(params, cfg):
     steps = [s for s in _named(spans, "istpu.engine.step")
              if s.fields["kind"] == "decode"]
     # "a" alone: its first step began a run, the last landed it
-    assert [s.fields.get("steady") for s in steps[:5]] \
-        == [False] + [True] * 3 + [None]
-    for st in steps:
+    assert [s.fields.get("rows_uploaded") for s in steps[:5]] \
+        == [True] + [False] * 3 + [None]
+    for st in _named(spans, "istpu.engine.step"):
         admitted = bool([k for k in _children(spans, st)
                          if k.name == "istpu.sched.admit"])
-        assert not (admitted and st.fields.get("steady"))
-        assert st.fields.get("rows_uploaded") == (
-            None if "steady" not in st.fields else not st.fields["steady"])
+        assert not (admitted and st.fields.get("rows_uploaded") is False)
+        assert "steady" not in st.fields
     # "b" was admitted; "c" was, beside the step in flight, which then
     # landed; a step of both began a run; "c"'s budget ended it; it
     # left; "b" took a second page at its 17th token (the
     # tables alone went up, behind a step in flight); one step held
     # its inputs; "b"'s budget ended the run.
-    later = [s.fields.get("steady") for s in steps[5:]]
-    assert later == [False, None, False, None, False, False, True, None]
-    assert "steady" not in _named(spans, "istpu.engine.step",
-                                  kind="idle")[0].fields
+    later = [s.fields.get("rows_uploaded") for s in steps[5:]]
+    assert later == [True, None, True, None, True, True, False, None]
+    assert "rows_uploaded" not in _named(spans, "istpu.engine.step",
+                                         kind="idle")[0].fields
     decodes = _named(spans, "istpu.model.decode", program="decode_fused")
     assert len(decodes) == len(steps) == eng.stats["decode_steps"]
     for d in decodes:
@@ -1187,7 +1187,9 @@ def test_a_run_ahead_call_is_a_dispatch_then_a_wait(params, cfg):
                               for k in kids))
         for k in kids:
             if k.fields["program"] == "land":
-                assert k.fields == {"program": "land", "dispatch_ns": 0}
+                # ... and how many slots waited for it (PR 51)
+                assert k.fields == {"program": "land", "dispatch_ns": 0,
+                                    "waiting": k.fields["waiting"]}
             else:
                 assert _less_dispatch(k).keys() == {"program", "live_pages"}
                 assert k.fields["program"] == "decode_fused"
@@ -1198,10 +1200,8 @@ def test_a_run_ahead_call_is_a_dispatch_then_a_wait(params, cfg):
         # the step's own host work is what no child covers
         assert sum(k.dur_ns for k in kids) < st.dur_ns + SLACK_NS
         assert bool(st.fields.get("ahead")) == shapes[-1].endswith("dw")
-        if "steady" in st.fields:
-            assert st.fields["rows_uploaded"] == (not st.fields["steady"])
-        else:
-            assert shapes[-1] == "w"
+        # a call that only waits dispatched nothing, and says nothing
+        assert ("rows_uploaded" not in st.fields) == (shapes[-1] == "w")
     # d: a dispatch alone, w: a wait alone
     assert shapes == ["ddw"] + ["dw"] * 4 + ["w"] + ["ddw"] + ["dw"] * 3 + ["w"]
     # b crossed a page edge at its 4th token, a at its 6th: the tables
@@ -1241,7 +1241,7 @@ def test_the_span_readers_over_synchronous_and_run_ahead_steps():
         # [1.0, 4.0) ms, the tokens are there at 4.7
         step(0, 5 * ms, [(300_000, 4_400_000,
                           dict(sent, dispatch_ns=200_000))],
-             steady=False, rows_uploaded=True)
+             rows_uploaded=True)
         # begins a run: its own step's dispatch (the program runs [6.0,
         # 9.0)), the dispatch of the one behind it, then the wait
         + step(5 * ms, 5 * ms, [(300_000, 200_000,
@@ -1249,17 +1249,17 @@ def test_the_span_readers_over_synchronous_and_run_ahead_steps():
                                 (700_000, 200_000,
                                  dict(sent, dispatch_ns=200_000)),
                                 (1 * ms, 3_700_000, land)],
-               steady=False, ahead=True, rows_uploaded=True)
+               ahead=True, rows_uploaded=True)
         # two in the middle of it: programs [9, 12) and [12, 15) start
         # as the one before ends, inside the call's wait
         + step(10 * ms, 3 * ms, [(400_000, 200_000,
                                   dict(sent, dispatch_ns=200_000)),
                                  (700_000, 2 * ms, land)],
-               steady=True, ahead=True, rows_uploaded=False)
+               ahead=True, rows_uploaded=False)
         + step(13 * ms, 3 * ms, [(400_000, 200_000,
                                   dict(sent, dispatch_ns=200_000)),
                                  (700_000, 2 * ms, land)],
-               steady=True, ahead=True, rows_uploaded=False))
+               ahead=True, rows_uploaded=False))
     modules = [("jit__decode_fused", 1 * ms, 3 * ms),
                ("jit__decode_fused", 6 * ms, 3 * ms),
                ("jit__decode_fused", 9 * ms, 3 * ms),
@@ -1274,3 +1274,278 @@ def test_the_span_readers_over_synchronous_and_run_ahead_steps():
 
     # step less ALL its decode children: 0.6, 0.9, 0.8, 0.8 ms
     assert decode_host_p50_ms.value(Obs(), ring) == pytest.approx(0.8)
+
+
+# ---- the gap between tokens, by what the engine thread did in it (PR 51) ----
+
+CAUSES = ["step", "admit_miss", "admit_hit", "admit_piece", "offload"]
+
+
+def _gap_stats(eng):
+    """The eight counters `_emit` keeps, by their short names, and
+    what the five causes leave of the gaps' length: `other`."""
+    g = {c: eng.stats[f"gap_ns_{c}"] for c in CAUSES}
+    g.update(tokens=eng.stats["gap_tokens"], ns=eng.stats["gap_ns"],
+             stalled=eng.stats["gaps_stalled"])
+    g["other"] = g["ns"] - sum(g[c] for c in CAUSES)
+    return g
+
+
+def _heard(*reqs):
+    """`reqs` with a callback that notes when each token was emitted;
+    returns {request id: [perf_counter_ns a token]}."""
+    heard = {r.request_id: [] for r in reqs}
+    for r in reqs:
+        r.on_token = lambda rid, _t: heard[rid].append(
+            time.perf_counter_ns())
+    return heard
+
+
+def _decoding(eng, req, steps=3):
+    """`req` admitted into an idle engine and `steps` steps on; the
+    spans from here on are what happens while it decodes."""
+    eng.submit(req)
+    for _ in range(steps):
+        eng.step()
+    return time.time_ns()
+
+
+def _of(spans, name, rid):
+    return [s for s in _named(spans, name) if s.request == rid]
+
+
+def _lands(spans):
+    """The decode spans under which a plain step landed."""
+    return [s for s in _named(spans, "istpu.model.decode")
+            if "waiting" in s.fields]
+
+
+def test_one_request_alone_has_no_stalled_gap(params, cfg):
+    """Alone, a request's gaps are steps and the loop around them: no
+    other cause runs, the first token is no gap, and their length is
+    what a client's callback sees between tokens."""
+    eng = _engine(params, cfg, None, "gaps-alone")
+    req = Request("a", _prompt(71, PAGE + 2), max_new_tokens=7)
+    heard = _heard(req)
+    spans = _run(eng, req)
+    g = _gap_stats(eng)
+    assert g["tokens"] == 6 and g["stalled"] == 0
+    assert [g[c] for c in CAUSES[1:]] == [0, 0, 0, 0]
+    assert 0 < g["step"] <= g["ns"] and g["other"] >= 0
+    assert abs(g["ns"] - (heard["a"][-1] - heard["a"][0])) < SLACK_NS
+    # the step's part is the decode spans between the first token and
+    # the last: all of them but the tail of the one the last landed in
+    decodes = sum(s.dur_ns for s in _named(spans, "istpu.model.decode"))
+    assert decodes - SLACK_NS < g["step"] <= decodes
+    assert [s.fields["waiting"] for s in _lands(spans)] == [0] + [1] * 5
+    assert not any("stall_ns" in s.fields for s in _lands(spans))
+
+
+def _a_miss(eng, conn):
+    return Request("b", _prompt(73, 2 * PAGE + 3), max_new_tokens=14)
+
+
+def _a_hit(eng, conn):
+    first = _prompt(74, 3 * PAGE)
+    out = eng.run([Request("b0", first, max_new_tokens=PAGE)])["b0"]
+    return Request("b", first + out + _prompt(75, 3), max_new_tokens=14)
+
+
+@pytest.mark.parametrize("cause, second", [
+    ("admit_miss", _a_miss), ("admit_hit", _a_hit)], ids=["miss", "hit"])
+def test_an_admission_is_in_the_gap_of_the_slot_that_waited_and_in_none_of_its_own(
+        params, cfg, shm_conn, cause, second):
+    """`b` is admitted while `a` decodes: the admission's span, whole
+    and once, is in the ONE gap of `a` that held it, under the cause
+    its `hit_pages` name, and in none of `b`'s own; the land span
+    behind it says so on the ring."""
+    eng = _engine(params, cfg, shm_conn, f"gaps-{cause}")
+    b = second(eng, shm_conn)
+    before = _gap_stats(eng)
+    t0 = _decoding(eng, Request("a", _prompt(72, PAGE + 1),
+                                max_new_tokens=10, cache=False))
+    eng.submit(b)
+    out = eng.run()
+    spans = profiling.spans(since_ns=t0)
+    (admit,) = _of(spans, "istpu.sched.admit", "b")
+    assert (admit.fields["hit_pages"] > 0) == (cause == "admit_hit")
+    g = _gap_stats(eng)
+    assert g[cause] - before[cause] == admit.dur_ns
+    assert g["stalled"] - before["stalled"] == 1
+    # nothing else stalled anyone: `a` writes nothing, `b` finishes last
+    assert [g[c] - before[c] for c in CAUSES[1:] if c != cause] == [0, 0, 0]
+    assert g["tokens"] - before["tokens"] == len(out["a"]) + len(out["b"]) - 2
+    assert g["other"] >= 0
+    (stalled,) = [s for s in _lands(spans) if "stall_ns" in s.fields]
+    assert stalled.fields["waiting"] == 1
+    assert stalled.fields["stall_ns"] == admit.dur_ns
+    assert stalled.fields["stall_cause"] == cause
+    assert stalled.t0_ns >= admit.t0_ns + admit.dur_ns - SLACK_NS
+    # `b` emitted at its admission, not at the land before
+    after = [s for s in _lands(spans) if s.t0_ns > stalled.t0_ns]
+    assert after[0].fields["waiting"] == 1
+    assert after[1].fields["waiting"] == 2
+
+
+def test_pieces_are_in_the_gaps_of_the_slot_that_waited(params, cfg):
+    """An admission in pieces while `a` decodes: every piece is in the
+    gap of `a` it ran in, and `b`, whose pieces they are, has none of
+    them in its own."""
+    eng = _engine(params, cfg, None, "gaps-piece", admit_piece=PAGE)
+    t0 = _decoding(eng, Request("a", _prompt(76, PAGE),
+                                max_new_tokens=30))
+    eng.submit(Request("b", _prompt(77, 3 * PAGE + 2), max_new_tokens=5))
+    out = eng.run()
+    spans = profiling.spans(since_ns=t0)
+    pieces = _named(spans, "istpu.sched.admit_piece")
+    assert len(pieces) == 4 == eng.stats["admit_pieces"]
+    (admit,) = _of(spans, "istpu.sched.admit", "b")
+    g = _gap_stats(eng)
+    assert g["admit_piece"] == sum(p.dur_ns for p in pieces)
+    assert g["admit_miss"] == admit.dur_ns and g["admit_hit"] == 0
+    # the admission held no piece (a cold prompt's first runs with the
+    # next step), so the five gaps that stalled are four pieces' and its
+    assert g["stalled"] == 4 + 1
+    assert g["tokens"] == len(out["a"]) + len(out["b"]) - 2
+    stalled = [s.fields for s in _lands(spans) if "stall_ns" in s.fields]
+    assert [f["stall_cause"] for f in stalled].count("admit_piece") == 4
+    assert sum(f["stall_ns"] for f in stalled) \
+        == g["admit_piece"] + g["admit_miss"]
+
+
+def test_a_finishs_offload_is_in_the_gap_of_the_slot_that_goes_on(
+        params, cfg, shm_conn):
+    """`a` finishes while `b` decodes: what the ENGINE thread does of
+    its offload (the span `istpu.cache.offload`; the upload thread's
+    part counts nowhere) is in the one gap of `b` that held it."""
+    eng = _engine(params, cfg, shm_conn, "gaps-offload")
+    t0 = time.time_ns()
+    out = eng.run([Request("a", _prompt(78, 2 * PAGE), max_new_tokens=4),
+                   Request("b", _prompt(79, PAGE + 1), max_new_tokens=12,
+                           cache=False)])
+    spans = profiling.spans(since_ns=t0)
+    (offload,) = _named(spans, "istpu.cache.offload", reason="finish")
+    assert offload.request == "a"
+    (upload,) = _named(spans, "istpu.cache.upload")
+    assert upload.tid != offload.tid
+    g = _gap_stats(eng)
+    assert g["offload"] == offload.dur_ns
+    # `b` was admitted behind `a`'s first token: that gap of `a` too
+    (admit_b,) = _of(spans, "istpu.sched.admit", "b")
+    assert g["admit_miss"] == admit_b.dur_ns
+    assert g["stalled"] == 2
+    assert g["tokens"] == len(out["a"]) + len(out["b"]) - 2 == 14
+    (stalled,) = [s for s in _lands(spans)
+                  if s.fields.get("stall_cause") == "offload"]
+    assert stalled.fields["stall_ns"] == offload.dur_ns
+    assert stalled.fields["waiting"] == 1
+
+
+def test_k_tokens_at_once_are_k_gaps_and_one_interval(params, cfg):
+    """A burst emits k tokens in one call: k gaps, the first the
+    interval since the burst before and the rest 0, as the callback
+    hears them."""
+    eng = _engine(params, cfg, None, "gaps-burst", host_steps=4)
+    req = Request("a", _prompt(80, PAGE + 2), max_new_tokens=9)
+    heard = _heard(req)
+    spans = _run(eng, req)
+    assert {s.fields["kind"] for s in _named(spans, "istpu.engine.step")} \
+        >= {"burst"}
+    g = _gap_stats(eng)
+    assert g["tokens"] == 8 and g["stalled"] == 0
+    assert abs(g["ns"] - (heard["a"][-1] - heard["a"][0])) < SLACK_NS
+    assert 0 < g["step"] <= g["ns"]
+
+
+def test_a_row_dropped_behind_an_eos_is_no_gap(params, cfg):
+    """Under run-ahead the step behind an EOS holds a row nobody sees:
+    it is counted as dropped, and as no gap."""
+    prompts = {"x": _prompt(81, PAGE + 2), "y": _prompt(82, PAGE + 5)}
+    free = _engine(params, cfg, None, "gaps-free", max_slots=3).run(
+        [Request(r, p, max_new_tokens=12) for r, p in prompts.items()])
+    at = next(i for i in range(3, 11) if free["x"][i] not in free["x"][:i]
+              and free["x"][i] not in free["y"])
+    eng = _engine(params, cfg, None, "gaps-eos", max_slots=3,
+                  eos_id=free["x"][at])
+    out = eng.run([Request(r, p, max_new_tokens=12)
+                   for r, p in prompts.items()])
+    assert out["x"] == free["x"][:at + 1] and len(out["y"]) == 12
+    assert eng.stats["decode_rows_dropped"] >= 1
+    g = _gap_stats(eng)
+    assert g["tokens"] == len(out["x"]) + len(out["y"]) - 2
+    assert g["other"] >= 0
+
+
+@pytest.mark.parametrize("ahead", [True, False], ids=["ahead", "sync"])
+def test_the_five_causes_never_exceed_the_gaps(params, cfg, shm_conn,
+                                               ahead):
+    """Arrivals, hits, finishes and their offloads among decoding
+    slots, one step ahead or held synchronous: only the outermost
+    listed span counts, so the five causes sum to no more than the
+    gaps' length, gap by gap and so in the sums."""
+    eng = _engine(params, cfg, shm_conn, f"gaps-sum-{ahead}", max_slots=3)
+    if not ahead:
+        eng._proven = lambda active: False
+    first = _prompt(83, 2 * PAGE)
+    done = eng.run([Request("r0", first, max_new_tokens=PAGE)])["r0"]
+    reqs = [Request("r1", first + done + _prompt(84, 3), max_new_tokens=9),
+            Request("r2", _prompt(85, 3 * PAGE + 1), max_new_tokens=14),
+            Request("r3", _prompt(86, PAGE), max_new_tokens=20),
+            Request("r4", _prompt(87, 2 * PAGE + 5), max_new_tokens=6)]
+    heard = _heard(*reqs)
+    t0 = time.time_ns()
+    eng.submit(reqs[1])
+    eng.step()
+    eng.submit(reqs[0])
+    eng.step()
+    eng.step()
+    for r in reqs[2:]:
+        eng.submit(r)
+    out = eng.run()
+    assert (eng.stats["decode_steps_ahead"] > 0) == ahead
+    g = _gap_stats(eng)
+    assert g["tokens"] == PAGE - 1 + sum(len(out[r.request_id]) - 1
+                                        for r in reqs)
+    assert all(g[c] > 0 for c in CAUSES if c != "admit_piece"), g
+    assert g["other"] >= 0 and g["stalled"] >= 4
+    # the gaps' length is the callbacks' (r0 ran before they listened)
+    spans = profiling.spans(since_ns=t0)
+    mine = sum(t[-1] - t[0] for t in heard.values())
+    assert mine <= g["ns"] and g["ns"] - mine < mine
+    # on the ring: a land's `stall_ns` is what its waiting slots' gaps
+    # hold of the four causes, and never more than the lands are apart
+    lands = _lands(spans)
+    for a, b in zip(lands, lands[1:]):
+        apart = b.t0_ns + b.dur_ns - a.t0_ns - a.dur_ns
+        assert b.fields.get("stall_ns", 0) <= apart + SLACK_NS
+        assert b.fields["waiting"] <= a.fields["waiting"] + 3
+    assert sum(s.fields["waiting"] for s in lands
+               if "stall_ns" in s.fields) <= eng.stats["gaps_stalled"]
+
+
+def test_a_swapped_out_sequence_carries_its_gap_across_the_swap(
+        params, cfg, shm_conn):
+    """A sequence preempted through the store and resumed: the gap
+    between its last token before and its first after is ONE gap,
+    which holds the hit that resumed it."""
+    eng = _engine(params, cfg, shm_conn, "gaps-swap", total_pages=11,
+                  max_pages_per_seq=10)
+    reqs = [Request("a", _prompt(8, 5 * PAGE), max_new_tokens=3 * PAGE),
+            Request("b", _prompt(9, 4 * PAGE), max_new_tokens=PAGE)]
+    heard = _heard(*reqs)
+    t0 = time.time_ns()
+    eng.submit(reqs[0])
+    eng.step()
+    eng.submit(reqs[1])
+    out = eng.run()
+    assert eng.stats["preemptions"] >= 1
+    spans = profiling.spans(since_ns=t0)
+    resumed = [s for s in _named(spans, "istpu.sched.admit",
+                                 outcome="admitted")
+               if s.fields["hit_pages"] > 0]
+    assert resumed
+    g = _gap_stats(eng)
+    assert g["tokens"] == len(out["a"]) + len(out["b"]) - 2
+    assert abs(g["ns"] - sum(t[-1] - t[0] for t in heard.values())) \
+        < SLACK_NS
+    assert g["admit_hit"] > 0 and g["other"] >= 0

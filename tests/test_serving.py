@@ -1732,7 +1732,7 @@ def _ahead_page_edges_on_different_steps(pair, _):
     eng = pair.engines["ahead"]
     steps = _ahead_steps(eng)
     assert any(s.fields["rows_uploaded"] for s in steps)
-    assert any(s.fields["steady"] for s in steps)
+    assert not all(s.fields["rows_uploaded"] for s in steps)
     # all but the first step; the last ends every budget
     assert eng.stats["decode_steps_ahead"] == eng.stats["decode_steps"] - 1
     if eng._win_layers:
