@@ -550,16 +550,19 @@ def ssm_mixer_seq(layer, x, cfg, state, s_real):
     return _ssm_out(layer, y, xs, z, cfg), st
 
 
-def ssm_mixer_step(layer, x, cfg, state):
+def ssm_mixer_step(layer, x, cfg, state, rows):
     """The same mixer for one token a row: x [b, 1, d], `state` = (h
-    [b, H, P, N], conv tail [b, K-1, C]). Returns (out [b, 1, d], new
+    [b, H, P, N], conv tail [b, K-1, C]). `rows` (ssm.decoding's
+    triple): the rows that decode; the state of the others stays as it
+    lies and their output may be anything. Returns (out [b, 1, d], new
     (h, conv tail))."""
     h, conv = state
     z, xbc, dt = _ssm_project(layer, x[:, 0], cfg)
-    xbc, conv = ssm.conv_step(conv, xbc, layer["conv_w"], layer["conv_b"])
+    xbc, conv = ssm.conv_step(conv, xbc, layer["conv_w"], layer["conv_b"],
+                              rows)
     xs, B, C = _ssm_split(xbc, cfg)
     dt, A = _ssm_dt(layer, dt)
-    y, h = ssm.step(h, xs, dt, A, B, C)
+    y, h = ssm.step(h, xs, dt, A, B, C, rows)
     return _ssm_out(layer, y, xs, z, cfg)[:, None], (h, conv)
 
 
@@ -996,7 +999,9 @@ def decode_step(block, params, cfg, token, seq_lens, k_pages, v_pages,
     page_table: [batch, max_pages] int32
     state:      for a family with state layers, {"h": [...], "conv":
                 [...]}: per state layer the batch's recurrent state
-                and convolution tail, row = slot
+                and convolution tail, row = slot. The rows of the
+                slots that decode (seq_lens > 0) are advanced where
+                they lie; no other row is read or written
     win:        for a model with full AND banded attention layers
                 (`cfg.two_kinds`), the banded layers' cache: (k pool,
                 v pool [n_window_layers, n_pages, page, n_kv, hd],
@@ -1052,6 +1057,8 @@ def decode_step(block, params, cfg, token, seq_lens, k_pages, v_pages,
     # ... and under a learned selection the slots its stages run over
     active = sparse_select.active_first(valid[:, 0]) if indexed(
         cfg, page_table.shape[1] * cfg.page_size) else None
+    # ... and over a recurrent state the rows its layers advance
+    rows = None if state is None else ssm.decoding(valid[:, 0])
 
     spec = attn_layers(cfg)
     li = mi = ii = 0  # rank among the attention / state / index layers
@@ -1061,7 +1068,7 @@ def decode_step(block, params, cfg, token, seq_lens, k_pages, v_pages,
         x_in, mix = stream_in(cfg, layer, x, "attn")
         if kind == "mamba":
             out, (h, conv) = ssm_mixer_step(
-                layer, x_in, cfg, (state["h"][mi], state["conv"][mi]))
+                layer, x_in, cfg, (state["h"][mi], state["conv"][mi]), rows)
             x = residual(cfg, x, out, mix)
             hs.append(h)
             convs.append(conv)

@@ -812,10 +812,10 @@ def _admit_fused_px_st(params, cfg, tokens, restored, snap, k_pages,
          donate_argnums=(4, 5, 6))
 def _decode_fused_st(params, cfg, token, seq_lens, k_pages, v_pages,
                      state, rows, model):
-    """`_decode_fused` for a family with state layers: every slot's
-    state is read and written where it lies (one array a layer,
-    donated). An inactive slot's row takes a garbage update; an
-    admission overwrites the whole row."""
+    """`_decode_fused` for a family with state layers: the state of
+    the slots that decode (`seq_lens` > 0) is read and written where it
+    lies (one array a layer, donated); no other slot's row is touched,
+    and an admission overwrites its slot's whole row."""
     logits, k_pages, v_pages, state = model.decode_step(
         params, cfg, token, seq_lens, k_pages, v_pages, rows, state
     )
@@ -1248,6 +1248,9 @@ class ServingEngine:
             # dispatched behind decode steps
             "snapshots_written": 0, "snapshots_restored": 0,
             "snapshot_misses": 0, "boundary_copies": 0,
+            # ... over the decode steps, the slots whose state a step
+            # moved and the sequences it decoded (the same, since PR 52)
+            "state_rows_run": 0, "state_rows_active": 0,
             # hits whose snapshot lay below the pages' matched depth
             "snapshot_walkbacks": 0,
             # two kinds of attention layer: banded layers' pages that
@@ -3521,6 +3524,12 @@ class ServingEngine:
         Returns the tokens landed."""
         nxt = np.asarray(flight.pull)
         active = flight.active
+        if self.state is not None:
+            # the slots whose state the step moved are the decoding
+            # ones, whose count bounds the grid of `ssm.step_kernel`
+            df["state_rows_run"] = df["state_rows_active"] = len(active)
+            self.stats["state_rows_run"] += len(active)
+            self.stats["state_rows_active"] += len(active)
         if flight.counted:
             fetched = int(nxt[self.sc.max_slots])
             df["experts_fetched"] = fetched

@@ -156,6 +156,193 @@ def test_conv_step_continues_conv_seq():
         np.testing.assert_allclose(out, whole[:, t], atol=1e-6)
 
 
+# -- a decode step's update over the rows that decode -----------------------
+def _step_operands(b=16, H=8, P=16, N=128, K=4, seed=0):
+    ks = jax.random.split(jax.random.PRNGKey(seed), 9)
+    f32 = jnp.float32
+    C = H * P + 2 * N
+    return {
+        "h": jax.random.normal(ks[0], (b, H, P, N), f32),
+        "x": jax.random.normal(ks[1], (b, H, P), f32),
+        "dt": jax.nn.softplus(jax.random.normal(ks[2], (b, H), f32)),
+        "A": -jnp.exp(jax.random.normal(ks[3], (H,), f32)),
+        "B": jax.random.normal(ks[4], (b, N), f32),
+        "C": jax.random.normal(ks[5], (b, N), f32),
+        "tail": jax.random.normal(ks[6], (b, K - 1, C), f32),
+        "xbc": jax.random.normal(ks[7], (b, C), f32),
+        "w": jax.random.normal(ks[8], (K, C), f32),
+        "bias": jnp.zeros((C,), f32),
+    }
+
+
+def _scattered(n, b=16):
+    """`n` of `b` slots, scattered: the decoding rows' order is not the
+    identity (unless all decode)."""
+    valid = np.zeros(b, bool)
+    valid[np.random.default_rng(n).permutation(b)[:n]] = True
+    rows = ssm.decoding(jnp.asarray(valid))
+    if 0 < n < b:
+        assert list(np.asarray(rows[1])) != list(range(b))
+    assert int(rows[2][0]) == n
+    assert sorted(np.asarray(rows[1])[:n]) == list(np.flatnonzero(valid))
+    return valid, rows
+
+
+def _step_by(form, o, rows):
+    """(y, h) of the update over `rows` by "xla" (what `ssm.step` runs
+    off the chip) or "kernel.T" (the Pallas call the chip runs, in
+    interpret mode, T heads a block)."""
+    if form == "xla":
+        return ssm.step(o["h"], o["x"], o["dt"], o["A"], o["B"], o["C"],
+                        rows)
+    return ssm.step_kernel(
+        o["h"], jnp.exp(o["dt"] * o["A"]), o["x"] * o["dt"][..., None],
+        o["B"], o["C"], rows[1], rows[2], tile=int(form.split(".")[1]),
+        interpret=True)
+
+
+@pytest.mark.parametrize("form", ["xla", "kernel.8", "kernel.2"])
+@pytest.mark.parametrize("n", [1, 3, 4, 16])
+def test_step_over_the_decoding_rows_is_the_whole_batchs_on_them(n, form):
+    """`ssm.step` with `rows`: a decoding row's y and state are what the
+    update over the whole batch gives it, a row that does not decode
+    keeps its state BIT FOR BIT and reads y = 0; by the form that runs
+    off the chip and by the chip's kernel (interpret mode), whose grid
+    is the n decoding slots x the head tiles."""
+    o = _step_operands()
+    valid, rows = _scattered(n)
+    y_all, h_all = ssm.step(o["h"], o["x"], o["dt"], o["A"], o["B"], o["C"])
+    y, h = _step_by(form, o, rows)
+    if form != "xla":  # the kernel leaves the other rows' y unwritten
+        y = jnp.where(valid[:, None, None], y, 0.0)
+    np.testing.assert_allclose(np.asarray(h)[valid], np.asarray(h_all)[valid],
+                               rtol=1e-6, atol=1e-6)
+    np.testing.assert_allclose(np.asarray(y)[valid], np.asarray(y_all)[valid],
+                               rtol=1e-5, atol=1e-4)
+    assert np.array_equal(np.asarray(h)[~valid].view(np.uint32),
+                          np.asarray(o["h"])[~valid].view(np.uint32))
+    assert not np.asarray(y)[~valid].any()
+
+
+@pytest.mark.parametrize("n", [1, 3, 4, 16])
+def test_conv_step_over_the_decoding_rows_is_the_whole_batchs_on_them(n):
+    o = _step_operands()
+    valid, rows = _scattered(n)
+    out_all, tail_all = ssm.conv_step(o["tail"], o["xbc"], o["w"], o["bias"])
+    out, tail = ssm.conv_step(o["tail"], o["xbc"], o["w"], o["bias"], rows)
+    assert np.array_equal(np.asarray(out), np.asarray(out_all))
+    assert np.array_equal(np.asarray(tail)[valid], np.asarray(tail_all)[valid])
+    assert np.array_equal(np.asarray(tail)[~valid].view(np.uint32),
+                          np.asarray(o["tail"])[~valid].view(np.uint32))
+
+
+@pytest.mark.parametrize("tile", [8, 2])
+@pytest.mark.parametrize("n", [0, 1, 5])
+def test_the_kernels_grid_ends_at_the_count(n, tile):
+    """The kernel's grid is (count, head tiles): were it to run one
+    slot of the order more, a row that does not decode would advance,
+    and were its last step to run again, the LAST decoding row twice
+    (decay^2). Two steps from the same state, each a kernel call, equal
+    the sequential recurrence on every decoding row; with nothing
+    decoding no step runs and the pool comes back bit for bit."""
+    o = _step_operands(seed=n)
+    valid, rows = _scattered(n)
+    want = o["h"]
+    got = dict(o)
+    for _ in range(2):
+        _, want = ssm.step(want, o["x"], o["dt"], o["A"], o["B"], o["C"])
+        _, got["h"] = _step_by(f"kernel.{tile}", got, rows)
+    last = np.asarray(rows[1])[max(n - 1, 0)]
+    np.testing.assert_allclose(np.asarray(got["h"])[last],
+                               np.asarray(want if n else o["h"])[last],
+                               rtol=1e-6, atol=1e-6)
+    np.testing.assert_allclose(np.asarray(got["h"])[valid],
+                               np.asarray(want)[valid], rtol=1e-6, atol=1e-6)
+    assert np.array_equal(np.asarray(got["h"])[~valid].view(np.uint32),
+                          np.asarray(o["h"])[~valid].view(np.uint32))
+
+
+@pytest.mark.parametrize("H,tile", [(64, 32), (48, 16), (40, 8), (12, 12),
+                                    (8, 8), (100, 100)])
+def test_the_kernels_tile_divides_the_heads(H, tile):
+    """Every head lies in exactly one block of the kernel's grid: a
+    family whose heads are no multiple of 32 gets a tile that divides
+    them, a multiple of 8 or all of them (the chip's rule for a block's
+    second-to-last dimension)."""
+    assert ssm.head_tile(H) == tile
+    assert H % tile == 0 and (tile % 8 == 0 or tile == H)
+
+
+@pytest.mark.parametrize("H", [48, 40, 12])
+@pytest.mark.parametrize("n", [2, 4])
+def test_the_kernel_advances_every_head_whatever_their_number(n, H):
+    """The kernel as the PROGRAM calls it (no tile given), in interpret
+    mode, over heads that are no multiple of 32 heads a block: every
+    head of a decoding row is what the update over the whole batch
+    gives it (a grid of H // 32 tiles would leave the heads past the
+    last whole tile as they were and their y unwritten)."""
+    o = _step_operands(b=4, H=H, seed=H)
+    valid, rows = _scattered(n, b=4)
+    y_all, h_all = ssm.step(o["h"], o["x"], o["dt"], o["A"], o["B"], o["C"])
+    y, h = ssm.step_kernel(
+        o["h"], jnp.exp(o["dt"] * o["A"]), o["x"] * o["dt"][..., None],
+        o["B"], o["C"], rows[1], rows[2], interpret=True)
+    np.testing.assert_allclose(np.asarray(h)[valid], np.asarray(h_all)[valid],
+                               rtol=1e-6, atol=1e-6)
+    np.testing.assert_allclose(np.asarray(y)[valid], np.asarray(y_all)[valid],
+                               rtol=1e-5, atol=1e-4)
+    assert not np.array_equal(np.asarray(h)[valid][:, -1],
+                              np.asarray(o["h"])[valid][:, -1])
+    assert np.array_equal(np.asarray(h)[~valid].view(np.uint32),
+                          np.asarray(o["h"])[~valid].view(np.uint32))
+
+
+def test_the_kernel_refuses_a_tile_that_does_not_divide_the_heads():
+    o = _step_operands(b=4, H=12)
+    _, rows = _scattered(2, b=4)
+    with pytest.raises(ValueError, match="does not divide"):
+        ssm.step_kernel(o["h"], o["dt"], o["x"], o["B"], o["C"], rows[1],
+                        rows[2], tile=8, interpret=True)
+
+
+def test_decode_step_leaves_the_rows_that_do_not_decode(params, cfg):
+    """`hybrid.decode_step` over 4 slots of which slots 3 and 1 hold a
+    sequence: their logits and state are what a step over them alone
+    gives, and the two others' state pools' rows are bit for bit what
+    they were."""
+    b = 4
+    state = jax.tree_util.tree_map(
+        lambda a: jax.random.normal(jax.random.PRNGKey(a.size), a.shape,
+                                    a.dtype),
+        hybrid.state_pools(cfg, b))
+    page = cfg.kv_page_shape()
+    kp = jnp.zeros((cfg.n_kv_layers, 16, *page), cfg.jdtype)
+    table = jnp.asarray(np.arange(b * 3).reshape(b, 3) % 15 + 1, jnp.int32)
+    tok = jnp.asarray([5, 9, 2, 77], jnp.int32)
+    lens = jnp.asarray([0, 6, 0, 3], jnp.int32)
+    logits, _, _, new = hybrid.decode_step(params, cfg, tok, lens, kp, kp,
+                                           table, state)
+    held = np.asarray(lens) > 0
+    for kind in state:
+        for old, got in zip(state[kind], new[kind]):
+            assert np.array_equal(np.asarray(got)[~held].view(np.uint32),
+                                  np.asarray(old)[~held].view(np.uint32))
+            assert not np.array_equal(np.asarray(got)[held],
+                                      np.asarray(old)[held])
+    # ... and the two that decode, as in a step where every slot does
+    # (a step before PR 52: every row advanced)
+    every = jnp.where(lens > 0, lens, 1)
+    want, _, _, wnew = hybrid.decode_step(params, cfg, tok, every, kp, kp,
+                                          table, state)
+    np.testing.assert_allclose(np.asarray(logits)[held],
+                               np.asarray(want)[held], rtol=1e-5, atol=1e-5)
+    for kind in state:
+        for w, got in zip(wnew[kind], new[kind]):
+            np.testing.assert_allclose(np.asarray(got)[held],
+                                       np.asarray(w)[held], rtol=1e-6,
+                                       atol=1e-6)
+
+
 # -- the model against the reference --------------------------------------
 def test_dense_forward_matches_the_reference(params, cfg):
     toks = _prompt(0, 50)  # 6 chunks of 8 and a ragged one
@@ -529,3 +716,40 @@ def test_spans_and_counters_of_the_state_cache(params, cfg, shm_conn):
             e2.stats["snapshot_misses"]) == (1, 1, 0)
     # _page_bytes comes from the pools the engine holds.
     assert e1._page_bytes == 2 * 1 * PAGE * 2 * 16 * 4  # packed or not
+
+
+def test_a_decode_steps_span_counts_the_slots_whose_state_it_moved(
+        params, cfg):
+    """Three requests of 3, 6 and 9 tokens into 4 slots: the steps
+    decode 3, 2 and then 1 sequence and move exactly their slots'
+    state (the decoding slots' count bounds the kernel's grid: the
+    engine writes it for both fields); the span a step lands in says
+    so and the counters sum it. A family without state runs the
+    program it ran and its spans carry the fields they carried."""
+    from infinistore_tpu.models import llama
+
+    eng = _engine(params, cfg, max_slots=4)
+    eng._proven = lambda active: False     # a span a step, dispatch to land
+    t0 = profiling.time.time_ns()
+    eng.run([Request(r, _prompt(s, 11), max_new_tokens=n)
+             for r, s, n in (("a", 1, 3), ("b", 2, 6), ("c", 3, 9))])
+    steps = [(s.fields["state_rows_active"], s.fields["state_rows_run"])
+             for s in profiling.spans(since_ns=t0)
+             if s.name == "istpu.model.decode" and s.engine == eng.engine_id]
+    assert steps == [(3, 3)] * 2 + [(2, 2)] * 3 + [(1, 1)] * 3
+    assert eng.stats["state_rows_active"] == eng.stats["state_rows_run"] \
+        == 6 + 6 + 3
+    assert eng.stats["decode_steps"] == len(steps)
+
+    lcfg = llama.LlamaConfig()
+    dense = ServingEngine(llama.init_params(jax.random.PRNGKey(0), lcfg),
+                          lcfg)
+    dense._proven = lambda active: False
+    dense.run([Request("d", [3, 7, 3], max_new_tokens=3)])
+    assert dense.stats["state_rows_run"] == 0
+    fields = [set(s.fields) for s in profiling.spans(since_ns=t0)
+              if s.name == "istpu.model.decode"
+              and s.engine == dense.engine_id]
+    assert fields and all(
+        f == {"program", "live_pages", "dispatch_ns", "waiting"}
+        for f in fields), fields

@@ -729,8 +729,10 @@ def _decode_program(family, slots=16):
     kind = total * int(np.prod(page)) * cfg.jdtype.itemsize
     # a paged-decode kernel a layer and, over routed experts, the
     # gathered expert kernel (ops/pallas_moe_decode.py: one function,
-    # called by every layer)
-    kernels = n_full + n_win + (family in ("smallthinker", "cohere"))
+    # called by every layer); over state layers the state's update
+    # (ops/ssm.py `step_kernel`: one function too)
+    kernels = n_full + n_win + (family in ("smallthinker", "cohere",
+                                           "hybrid"))
     return lower, 2 * (n_full + n_win) * kind, kind, kernels
 
 
@@ -756,6 +758,79 @@ def test_decode_program_for_the_chip_holds_no_layer_of_the_pool(
     assert ma.alias_size_in_bytes >= pool_bytes * 0.99  # donated, aliased
     assert ma.temp_size_in_bytes < one_layer_and_kind // 2, (
         ma.temp_size_in_bytes, one_layer_and_kind)
+
+
+@pytest.mark.parametrize("form", ["rows", "planted"])
+def test_decode_program_for_the_chip_copies_no_state_pool(form, v5e_chip,
+                                                          monkeypatch):
+    """`_decode_fused_st` at granite4h-micro's state widths (h [16, 64,
+    64, 128] float32 a state layer, 33.5 MB), compiled for a described
+    v5e: the program's text holds no `copy`, `copy-start` or scatter of
+    a state pool's shape, and the state pools come back aliased to the
+    donated ones (with the page pools: every byte of both). Each h
+    pool reaches ONE Pallas call as it lies and the call moves the
+    decoding slots' blocks alone. "planted" is the other form PR 52
+    timed, the first slots of the order gathered, advanced and put back
+    with `.at[rows].set` (at another batch, so that no cached trace of
+    the real form answers): XLA scatters into a pool it first moves
+    whole, and both must show, or the case above proves nothing."""
+    import re
+
+    from infinistore_tpu.ops import ssm
+
+    if form == "planted":
+        step = ssm.step
+
+        def gathered(h, x, dt, A, B, C, rows):
+            take = rows[1][:4]
+            y, new = step(h[take], x[take], dt[take], A, B[take], C[take])
+            return (jnp.zeros((h.shape[0], *y.shape[1:]), y.dtype)
+                    .at[take].set(y), h.at[take].set(new))
+
+        monkeypatch.setattr(ssm, "step", gathered)
+    slots = 16 if form == "rows" else 12
+    lower, pool_bytes, _, _ = _decode_program("hybrid", slots=slots)
+    with _the_chips_branch(monkeypatch):
+        compiled = lower(lambda shape, dtype: jax.ShapeDtypeStruct(
+            shape, dtype, sharding=v5e_chip)).compile()
+    text = compiled.as_text()
+    h = f"f32[{slots},64,64,128]"
+    state_bytes = 2 * slots * (64 * 64 * 128 + 3 * 4352) * 4
+    moved = re.findall(r"= \(?" + re.escape(h)
+                       + r"[^=]*? (copy|copy-start|scatter)\(", text)
+    if form == "planted":  # a scatter a state layer, and pools moved
+        assert moved.count("scatter") == 2 and "copy-start" in moved, moved
+        return
+    assert not moved, moved
+    assert text.count("tpu_custom_call") >= 4  # 2 attention, 2 state layers
+    ma = compiled.memory_analysis()
+    assert ma.alias_size_in_bytes >= (pool_bytes + state_bytes) * 0.99
+    assert ma.temp_size_in_bytes < 1 << 24, ma.temp_size_in_bytes
+
+
+@pytest.mark.parametrize("heads", [48, 12])
+def test_state_kernel_compiles_for_the_chip_whatever_the_heads(
+        heads, v5e_chip, monkeypatch):
+    """`ssm.step_kernel` as the program calls it, over heads that are
+    no multiple of 32, compiled for a described v5e: the tile
+    `ssm.head_tile` picks (16 of 48; all 12 of 12) is one the chip's
+    compiler takes, and the pool comes back aliased."""
+    from infinistore_tpu.ops import ssm
+
+    b, P, N = 16, 64, 128
+    f32 = jnp.float32
+
+    def arg(shape, dtype=f32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e_chip)
+
+    with _the_chips_branch(monkeypatch):
+        compiled = jax.jit(ssm.step_kernel, donate_argnums=0).lower(
+            arg((b, heads, P, N)), arg((b, heads)), arg((b, heads, P)),
+            arg((b, N)), arg((b, N)), arg((b,), jnp.int32),
+            arg((1,), jnp.int32)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    assert compiled.memory_analysis().alias_size_in_bytes \
+        >= b * heads * P * N * 4
 
 
 def _relaid(text):
