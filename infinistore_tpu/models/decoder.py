@@ -7,8 +7,9 @@ family (models/llama.py, models/moe.py, models/hybrid.py) supplies its
 config fields, its parameter init and its feed-forward `block`, and
 gets the loops with that block bound in from `bind`; nothing here knows
 which families exist. What a layer's MIXER is the config says per layer
-(`cfg.layer_kinds`): attention over K and V pages, or the Mamba-2 mixer
-over a recurrent state (ops/ssm.py).
+(`cfg.layer_kinds`): attention over K and V pages, the Mamba-2 or the
+Mamba-1 mixer over a recurrent state (ops/ssm.py), or a layer that
+keeps nothing and borrows another layer's pages or scan output.
 
 `block(layer, x, cfg, valid, h_attn)` maps the residual stream [b, s, d]
 to the block's output [b, s, d] and the family's auxiliary loss for that
@@ -61,22 +62,26 @@ def rms_norm(x, w, eps=1e-5, plus_one=False):
     return xn * (1.0 + w) if plus_one else xn * w
 
 
-def layer_norm(x, w, eps=1e-5):
-    """The mean-centred norm (Cohere's LayerNorm): (x - mean) over the
-    standard deviation, a weight and no bias, statistics and product
-    in float32."""
+def layer_norm(x, w, eps=1e-5, b=None):
+    """The mean-centred norm: (x - mean) over the standard deviation,
+    a weight and, where the family has one (`b`; Cohere's has none,
+    models/phi_flash.py's has), a bias; statistics and product in
+    float32."""
     xf = x.astype(jnp.float32)
     xc = xf - jnp.mean(xf, axis=-1, keepdims=True)
     var = jnp.mean(jnp.square(xc), axis=-1, keepdims=True)
-    return (xc * jax.lax.rsqrt(var + eps)
-            * w.astype(jnp.float32)).astype(x.dtype)
+    out = xc * jax.lax.rsqrt(var + eps) * w.astype(jnp.float32)
+    if b is not None:
+        out = out + b.astype(jnp.float32)
+    return out.astype(x.dtype)
 
 
-def norm(cfg, x, w):
+def norm(cfg, x, w, b=None):
     """The family's norm of a layer's input and of the stack's output:
-    `rms_norm`, or `layer_norm` where `cfg.norm_center`."""
+    `rms_norm`, or `layer_norm` where `cfg.norm_center` (with the bias
+    `b` where the parameters hold one)."""
     if cfg.norm_center:
-        return layer_norm(x, w, cfg.norm_eps)
+        return layer_norm(x, w, cfg.norm_eps, b)
     return rms_norm(x, w, cfg.norm_eps, cfg.norm_plus_one)
 
 
@@ -228,20 +233,30 @@ def qkv(layer, x, cfg, positions, rotate=None):
     return _qkv(layer, x, cfg, positions, rotate)[:3]
 
 
-def _qkv(layer, x, cfg, positions, rotate=None, decode=False):
+def _qkv(layer, x, cfg, positions, rotate=None, decode=False, keep=None):
     """... and `h`, the normalised input they were projected from,
     which a family's feed-forward block may read too (a router placed
-    before attention). `decode`: a decode step asks (`proj`)."""
+    before attention). `decode`: a decode step asks (`proj`). A layer
+    without `wk` (a "cross" layer: it attends another layer's K and V)
+    gets k = v = None. `keep`: the one position whose QUERY is
+    projected (`forward_stack` below the rows it cuts); K and V are
+    every position's."""
     b = x.shape[0]
     s = x.shape[1]
     with jax.named_scope("attn.qkv"):
-        h = norm(cfg, x, layer["ln1"])
-        q = proj(h, layer, "wq", "bq", (b, s, cfg.n_heads, cfg.head_dim),
-                 decode)
-        k = proj(h, layer, "wk", "bk", (b, s, cfg.n_kv_heads, cfg.head_dim),
-                 decode)
-        v = proj(h, layer, "wv", "bv", (b, s, cfg.n_kv_heads, cfg.head_dim),
-                 decode)
+        h = norm(cfg, x, layer["ln1"], layer.get("ln1_b"))
+        hq = h
+        if keep is not None:
+            hq = jax.lax.dynamic_slice_in_dim(h, keep, 1, axis=1)
+            qpos = jax.lax.dynamic_slice_in_dim(positions, keep, 1, axis=1)
+        q = proj(hq, layer, "wq", "bq",
+                 (b, hq.shape[1], cfg.n_heads, cfg.head_dim), decode)
+        k = v = None
+        if "wk" in layer:
+            k = proj(h, layer, "wk", "bk",
+                     (b, s, cfg.n_kv_heads, cfg.head_dim), decode)
+            v = proj(h, layer, "wv", "bv",
+                     (b, s, cfg.n_kv_heads, cfg.head_dim), decode)
         if "q_norm" in layer:
             # a per-head RMSNorm on q and k before rotary (Qwen3's;
             # models/keye.py), a weight of head_dim each
@@ -254,8 +269,8 @@ def _qkv(layer, x, cfg, positions, rotate=None, decode=False):
                                 q.dtype)
     if cfg.use_rope if rotate is None else rotate:
         with jax.named_scope("attn.rope"):
-            q = rope(q, positions, cfg.rope_theta, cfg.rope_scaling,
-                     adjacent=cfg.rope_adjacent)
+            q = rope(q, positions if keep is None else qpos, cfg.rope_theta,
+                     cfg.rope_scaling, adjacent=cfg.rope_adjacent)
             k = rope(k, positions, cfg.rope_theta, cfg.rope_scaling,
                      adjacent=cfg.rope_adjacent)
     return q, k, v, h
@@ -271,12 +286,18 @@ def pack_heads(cfg, q, k, v):
     kernels scale by the row width ** -0.5; sqrt(p) puts head_dim **
     -0.5 back. `unpack_heads` picks the same lanes of the output."""
     p = cfg.kv_pack
+    packed = (*k.shape[:2], cfg.n_kv_heads // p, p * k.shape[-1])
+    return pack_queries(cfg, q), k.reshape(packed), v.reshape(packed)
+
+
+def pack_queries(cfg, q):
+    """`pack_heads`' query side alone (a layer that attends another
+    layer's packed rows has queries and no K or V of its own)."""
+    p = cfg.kv_pack
     b, s, n_heads, hd = q.shape
     lanes = _own_lanes(cfg, q.dtype)                       # [heads, p]
     q = (q[..., None, :] * lanes[:, :, None]).reshape(b, s, n_heads, p * hd)
-    q = q * jnp.asarray(p ** 0.5, q.dtype)
-    packed = (b, s, cfg.n_kv_heads // p, p * hd)
-    return q, k.reshape(packed), v.reshape(packed)
+    return q * jnp.asarray(p ** 0.5, q.dtype)
 
 
 def _own_lanes(cfg, dtype):
@@ -430,8 +451,14 @@ def stream_close(cfg, x):
 # pool layer = the layer's rank among the attention layers (the page
 # pools hold those alone). "mamba": the Mamba-2 mixer below over a
 # recurrent state (`h` [b, H, P, N] float32 and the convolution's tail
-# [b, K-1, C]), state index = its rank among the state layers. Every
-# layer ends in the family's feed-forward `block`.
+# [b, K-1, C]), state index = its rank among the state layers;
+# "mamba1": Mamba-1's selective scan over a state of another shape, the
+# same rank. Two kinds keep NOTHING and read what a layer below them
+# left in the same program: "cross" attends the K and V of the last
+# attention layer below it that owns a full pool (so `attn_layers`
+# gives it no pool layer), "gmu" gates the scan output of the last
+# "mamba1" layer below it. Every layer ends in the family's
+# feed-forward `block`.
 #
 # An attention layer also has a band (`cfg.layer_windows`: 0 = full
 # causal attention, w = the last w positions) and may or may not rotate
@@ -514,6 +541,21 @@ def ssm_zero_state(cfg, b):
             jnp.zeros((b, cfg.ssm_conv - 1, c), cfg.state_jdtype))
 
 
+def _states_at_edge(cfg, h1, h2, full, s_real, first):
+    """What a state mixer's scan over a sequence leaves, from the state
+    `h1` at the start of the last page (`first`), `h2` after `s_real`
+    tokens and the convolution's inputs `full`: {"h", "conv": after
+    s_real tokens; "h_b", "conv_b": at the last page edge at or before
+    s_real}."""
+    at_edge = s_real == first + cfg.page_size
+    k = cfg.ssm_conv
+    return {
+        "h": h2, "conv": ssm.conv_tail(full, s_real, k),
+        "h_b": jnp.where(at_edge, h2, h1),
+        "conv_b": ssm.conv_tail(full, jnp.where(at_edge, s_real, first), k),
+    }
+
+
 def ssm_mixer_seq(layer, x, cfg, state, s_real):
     """The Mamba-2 mixer over a sequence [b, s, d], from `state` = (h,
     conv tail) of what came before. Only the first `s_real` positions
@@ -540,13 +582,7 @@ def ssm_mixer_seq(layer, x, cfg, state, s_real):
     y, h2 = ssm.scan(h1, xs[:, first:], dt[:, first:], A, B[:, first:],
                      C[:, first:], cfg.ssm_chunk)
     y = jnp.concatenate(ys + [y], axis=1)
-    at_edge = s_real == first + cfg.page_size
-    k = cfg.ssm_conv
-    st = {
-        "h": h2, "conv": ssm.conv_tail(full, s_real, k),
-        "h_b": jnp.where(at_edge, h2, h1),
-        "conv_b": ssm.conv_tail(full, jnp.where(at_edge, s_real, first), k),
-    }
+    st = _states_at_edge(cfg, h1, h2, full, s_real, first)
     return _ssm_out(layer, y, xs, z, cfg), st
 
 
@@ -564,6 +600,203 @@ def ssm_mixer_step(layer, x, cfg, state, rows):
     dt, A = _ssm_dt(layer, dt)
     y, h = ssm.step(h, xs, dt, A, B, C, rows)
     return _ssm_out(layer, y, xs, z, cfg)[:, None], (h, conv)
+
+
+# A "mamba1" layer (Mamba-1's selective scan, models/phi_flash.py): the
+# same place in the stack as "mamba" and the same two arrays a sequence
+# (`h`, here [N, C]: one decay a channel AND a state element, the
+# channels along the lanes; the convolution's tail [K-1, C]), another
+# recurrence (ops/ssm.py `selective_scan` / `selective_step`) and no
+# norm inside the mixer. Its scan output y (with the D skip, BEFORE the
+# gate) is what the "gmu" layers above read, position by position.
+
+
+def _mamba1_project(layer, x, cfg):
+    """LayerNorm and in_proj: (x' [..., C], z [..., C])."""
+    c = cfg.ssm_inner
+    with jax.named_scope("ssm.in"):
+        u = norm(cfg, x, layer["ln1"], layer.get("ln1_b"))
+        xz = matmul(u, layer["in_proj"])
+    return xz[..., :c], xz[..., c:]
+
+
+def _mamba1_select(layer, xs, cfg):
+    """The input-dependent terms, from the convolved x': (dt [..., C]
+    float32 after softplus, A [N, C], B [..., N], C [..., N])."""
+    f32 = jnp.float32
+    r, n = cfg.dt_rank, cfg.ssm_state
+    with jax.named_scope("ssm.in"):
+        sel = matmul(xs, layer["x_proj"])
+        dt = jax.nn.softplus(
+            matmul(sel[..., :r], layer["dt_proj"]).astype(f32)
+            + layer["dt_bias"].astype(f32))
+        return (dt, -jnp.exp(layer["A_log"].astype(f32)),
+                sel[..., r:r + n], sel[..., r + n:])
+
+
+def _mamba1_out(layer, y, xs, z):
+    """(out_proj((y + D x') silu(z)), the memory y + D x' in z's
+    dtype). y float32 [..., C]."""
+    f32 = jnp.float32
+    with jax.named_scope("ssm.out"):
+        y = y + layer["D"].astype(f32) * xs.astype(f32)
+        out = matmul((y * jax.nn.silu(z.astype(f32))).astype(z.dtype),
+                     layer["out_proj"])
+        return out, y.astype(z.dtype)
+
+
+def mamba1_zero_state(cfg, b):
+    """(h, conv tail) of `b` sequences at position 0."""
+    return (jnp.zeros((b, cfg.ssm_state, cfg.ssm_inner), cfg.state_jdtype),
+            jnp.zeros((b, cfg.ssm_conv - 1, cfg.ssm_inner),
+                      cfg.state_jdtype))
+
+
+def mamba1_mixer_seq(layer, x, cfg, state, s_real):
+    """`ssm_mixer_seq` for a "mamba1" layer: the same contract (the
+    padded positions get dt = 0; the scan is cut at the start of the
+    last page so that the state at the last page edge exists), and a
+    third result, the memory [b, s, C]."""
+    b, s, _ = x.shape
+    h0, conv0 = state
+    xs, z = _mamba1_project(layer, x, cfg)
+    xs, full = ssm.conv_seq(conv0, xs, layer["conv_w"], layer["conv_b"])
+    dt, A, B, C = _mamba1_select(layer, xs, cfg)
+    dt = jnp.where((jnp.arange(s) < s_real)[None, :, None], dt, 0.0)
+    first = (s - 1) // cfg.page_size * cfg.page_size
+    h1, ys = h0, []
+    if first:
+        y, h1 = ssm.selective_scan(h0, xs[:, :first], dt[:, :first], A,
+                                   B[:, :first], C[:, :first])
+        ys.append(y)
+    y, h2 = ssm.selective_scan(h1, xs[:, first:], dt[:, first:], A,
+                               B[:, first:], C[:, first:])
+    y = jnp.concatenate(ys + [y], axis=1)
+    st = _states_at_edge(cfg, h1, h2, full, s_real, first)
+    return (*_mamba1_out(layer, y, xs, z), st)
+
+
+def mamba1_mixer_step(layer, x, cfg, state, rows):
+    """`ssm_mixer_step` for a "mamba1" layer: (out [b, 1, d], the
+    memory [b, 1, C], new (h, conv tail))."""
+    h, conv = state
+    xs, z = _mamba1_project(layer, x[:, 0], cfg)
+    xs, conv = ssm.conv_step(conv, xs, layer["conv_w"], layer["conv_b"],
+                             rows)
+    dt, A, B, C = _mamba1_select(layer, xs, cfg)
+    y, h = ssm.selective_step(h, xs, dt, A, B, C, rows)
+    out, mem = _mamba1_out(layer, y, xs, z)
+    return out[:, None], mem[:, None], (h, conv)
+
+
+def gmu(layer, x, cfg, memory):
+    """A "gmu" layer (Gated Memory Unit, arXiv:2507.06607): the memory
+    of the last "mamba1" layer below, at the SAME position, gated by
+    this layer's own projection of its input and projected back. It
+    keeps no cache and no state. x: [b, s, d]; memory: [b, s, C]."""
+    with jax.named_scope("ssm.gmu"):
+        u = norm(cfg, x, layer["ln1"], layer.get("ln1_b"))
+        gate = jax.nn.silu(matmul(u, layer["gmu_in"]))
+        return matmul(gate * memory, layer["gmu_out"])
+
+
+# Differential attention (arXiv:2410.05258; `cfg.diff_attn`): query
+# head pair j attends with TWO softmax maps, subtracted, over a value
+# of 2 x head_dim lanes. With `kv_pack` 2 the pair of a cache row IS
+# the pair of kv heads the two maps read: row g = [k[2g] | k[2g + 1]],
+# [v[2g] | v[2g + 1]], and of the 4 query heads of its group the first
+# two score against the row's first half (map 1 of pairs 2g, 2g + 1),
+# the last two against its second (map 2 of the same pairs), each
+# widened to the row's lanes with zeros in the other half
+# (`pack_queries`). ONE pass over K and V then gives, per widened
+# query, softmax(.) [v1 | v2] in all 128 lanes: nothing is unpacked,
+# and pair j's output is out[map 1] - lam out[map 2].
+
+
+def diff_lambda(layer, depth):
+    """(lam, lam0) of the attention layer at index `depth` of the
+    stack: lam0 = 0.8 - 0.6 exp(-0.3 depth), lam = exp(lq1 . lk1) -
+    exp(lq2 . lk2) + lam0 (float32 scalars)."""
+    f32 = jnp.float32
+    lam0 = 0.8 - 0.6 * float(np.exp(-0.3 * depth))
+
+    def dot(a, b):
+        return jnp.exp(jnp.sum(layer[a].astype(f32) * layer[b].astype(f32)))
+
+    return dot("lam_q1", "lam_k1") - dot("lam_q2", "lam_k2") + lam0, lam0
+
+
+def diff_combine(layer, cfg, attn, depth):
+    """[..., n_heads, 2 hd] of the widened queries over packed rows ->
+    [..., n_heads * hd]: per pair map 1 less lam times map 2, RMSNorm
+    over the 2 hd lanes (one weight a layer), times 1 - lam0."""
+    f32 = jnp.float32
+    with jax.named_scope("attn.diff"):
+        lam, lam0 = diff_lambda(layer, depth)
+        *lead, n_heads, width = attn.shape
+        maps = attn.reshape(*lead, cfg.n_kv_heads // 2, 2,
+                            n_heads // cfg.n_kv_heads, width).astype(f32)
+        o = maps[..., 0, :, :] - lam * maps[..., 1, :, :]
+        var = jnp.mean(jnp.square(o), axis=-1, keepdims=True)
+        o = o * jax.lax.rsqrt(var + cfg.norm_eps) \
+            * layer["sub_ln"].astype(f32) * (1.0 - lam0)
+        return o.reshape(*lead, -1).astype(attn.dtype)
+
+
+def _attend_row(q, k, v, last):
+    """Causal attention of ONE query a sequence (q [b, 1, H, D]) over
+    the keys [0, `last`] (traced) of k, v [b, S, G, D], in plain XLA:
+    the form an admission takes above the layer that cuts its rows
+    (`forward_stack`), where the flash kernel's diagonal (the queries
+    are the LAST rows) does not say which row this is."""
+    f32 = jnp.float32
+    b, _, n_heads, d = q.shape
+    s, g = k.shape[1:3]
+    qg = q[:, 0].reshape(b, g, n_heads // g, d)
+    sc = jnp.einsum("bgqd,bsgd->bgqs", qg, k,
+                    preferred_element_type=f32) * d ** -0.5
+    sc = jnp.where(jnp.arange(s) <= last, sc, -jnp.inf)
+    p = jax.nn.softmax(sc, axis=-1).astype(v.dtype)
+    out = jnp.einsum("bgqs,bsgd->bgqd", p, v, preferred_element_type=f32)
+    return out.reshape(b, 1, n_heads, d).astype(q.dtype)
+
+
+def _cut_rows(x, memory, keep, before):
+    """The streams from the cut layer up: (x, the scan output the "gmu"
+    layers read if any, each [b, 1, ...] at position `keep`, and that
+    position among the cut layer's keys, `before` of which lie ahead of
+    the sequence: a prefix's)."""
+    last = before + keep
+    x = jax.lax.dynamic_slice_in_dim(x, keep, 1, axis=1)
+    if memory is not None:
+        memory = jax.lax.dynamic_slice_in_dim(memory, keep, 1, axis=1)
+    return x, memory, last
+
+
+def rows_cut(cfg):
+    """The layer of the stack at which an admission that keeps ONE
+    position's logits may cut its streams to that position: the last
+    layer that keeps a cache (pages or a state). Every layer above it
+    ("gmu", "cross") reads what the layers up to it left and keeps
+    nothing, so it runs on that row alone. The last layer of every
+    family whose layers all keep a cache, and wherever that layer is
+    no attention layer (the cut is built inside one, `forward_stack`):
+    there the cut is the one before the final norm."""
+    cut = max(i for i, kind in enumerate(cfg.layer_kinds)
+              if kind not in ("gmu", "cross"))
+    return cut if cfg.layer_kinds[cut] == "attention" else cfg.n_layers - 1
+
+
+def stack_rows(cfg, s):
+    """(token-layer rows an admission of `s` positions runs, of s x
+    layers): below `rows_cut` every row a layer, the cut layer (whose
+    K and V alone are every row's) and the layers above it one; every
+    row where the cut is the last layer."""
+    n = cfg.n_layers
+    cut = rows_cut(cfg)
+    if cut == n - 1:
+        return s * n, s * n
+    return s * cut + (n - cut), s * n
 
 
 # A "latent" layer (multi-head latent attention, DeepSeek-V2/V3): the
@@ -886,11 +1119,15 @@ def forward_stack(block, params, cfg, tokens, prefix_kvs=None, pos0=0,
 
     `keep`: the one position (int32 scalar, may be traced) whose logits
     the caller keeps, as an admission program does of its last real
-    one (None: every position's). Every layer still runs every
-    position (their K/V, rows, index keys, state and counts are what
-    they were); the streams are cut to that position after the last
-    layer, so the final norm and the head run on one row a batch and
-    the head reads its weights once for it."""
+    one (None: every position's). Every layer that keeps a cache still
+    runs every position (their K/V, rows, index keys, state and counts
+    are what they were); the streams are cut to that position at
+    `rows_cut`: after the last layer, so the final norm and the head
+    run on one row a batch and the head reads its weights once for it;
+    where layers above the last cache keep none ("gmu", "cross":
+    models/phi_flash.py), INSIDE that layer: its K and V are every
+    position's, its query, attention, output projection and block and
+    every layer above it run on the one row (`stack_rows`)."""
     b, s = tokens.shape
     prefix_len = 0 if prefix_kvs is None else max(
         k.shape[1] for k, *_ in prefix_kvs)
@@ -903,7 +1140,13 @@ def forward_stack(block, params, cfg, tokens, prefix_kvs=None, pos0=0,
     auxes = []
     states = []
     held = []
-    for layer, kind in zip(params["layers"], cfg.layer_kinds):
+    cut = rows_cut(cfg)
+    if keep is None or cut == cfg.n_layers - 1:
+        cut = None  # no layer runs on the kept row alone
+    diff = getattr(cfg, "diff_attn", False)
+    memory = shared = last = None
+    for depth, (layer, kind) in enumerate(zip(params["layers"],
+                                              cfg.layer_kinds)):
         h_attn = None
         x_in, mix = stream_in(cfg, layer, x, "attn")
         if kind == "mamba":
@@ -913,6 +1156,27 @@ def forward_stack(block, params, cfg, tokens, prefix_kvs=None, pos0=0,
                                     s if s_real is None else s_real)
             x = residual(cfg, x, out, mix)
             states.append(st)
+        elif kind == "mamba1":
+            st = state[len(states)] if state is not None \
+                else mamba1_zero_state(cfg, b)
+            out, memory, st = mamba1_mixer_seq(
+                layer, x_in, cfg, st, s if s_real is None else s_real)
+            x = residual(cfg, x, out, mix)
+            states.append(st)
+        elif kind == "gmu":
+            x = residual(cfg, x, gmu(layer, x_in, cfg, memory), mix)
+        elif kind == "cross":
+            # the K and V of the last layer that owns a full pool, all
+            # its positions; this layer's own queries, lambda and output
+            q, _, _, h_attn = _qkv(layer, x_in, cfg, positions, False)
+            q = pack_queries(cfg, q)
+            with jax.named_scope("attn.kernel.cross"):
+                if last is None:
+                    attn = flash_prefill(q, *shared, causal=True)
+                else:
+                    attn = _attend_row(q, *shared, last)
+            attn = diff_combine(layer, cfg, attn, depth)
+            x = residual(cfg, x, attn_out(layer, attn), mix)
         elif kind == "latent":
             owner = owns_indexer(cfg, len(kvs))
             q_nope, q_pe, rows, h_attn, cq = latent_project(
@@ -941,7 +1205,13 @@ def forward_stack(block, params, cfg, tokens, prefix_kvs=None, pos0=0,
             kvs.append((rows, ki))
         else:
             band, rotates, pool, _ = spec[len(kvs)]
-            q, k, v, h_attn = _qkv(layer, x_in, cfg, positions, rotates)
+            here = depth == cut
+            q, k, v, h_attn = _qkv(layer, x_in, cfg, positions, rotates,
+                                   keep=keep if here else None)
+            if diff:
+                # differential attention: K and V are made, cached and
+                # attended in the cache's row form, nothing is unpacked
+                q, k, v = pack_heads(cfg, q, k, v)
             owner = owns_indexer(cfg, len(kvs))
             if prefix_kvs is None:
                 k_full, v_full = k, v
@@ -949,6 +1219,9 @@ def forward_stack(block, params, cfg, tokens, prefix_kvs=None, pos0=0,
                 pk, pv, *pi = prefix_kvs[len(kvs)]
                 k_full = jnp.concatenate([pk.astype(k.dtype), k], axis=1)
                 v_full = jnp.concatenate([pv.astype(v.dtype), v], axis=1)
+            if here:
+                x, memory, last = _cut_rows(x, memory, keep,
+                                            k_full.shape[1] - s)
             selects = owner and indexed(cfg, k_full.shape[1])
             if owner:  # the layer's index keys, a third page of its own
                 qi, ki, wi = index_project(layer, cfg, h_attn, h_attn,
@@ -965,19 +1238,28 @@ def forward_stack(block, params, cfg, tokens, prefix_kvs=None, pos0=0,
                 # be longer than q — the causal diagonal shifts by the
                 # prefix.
                 with jax.named_scope(_kernel_scope(cfg, pool)):
-                    attn = flash_prefill(q, k_full, v_full, causal=True,
-                                         window=band)
-            x = residual(cfg, x, attn_out(layer, attn.reshape(b, s, -1)),
-                         mix)
+                    if here:
+                        attn = _attend_row(q, k_full, v_full, last)
+                    else:
+                        attn = flash_prefill(q, k_full, v_full, causal=True,
+                                             window=band)
+            if diff:
+                if pool == "full":
+                    shared = (k_full, v_full)
+                attn = diff_combine(layer, cfg, attn, depth)
+            else:
+                attn = attn.reshape(*attn.shape[:2], -1)
+            x = residual(cfg, x, attn_out(layer, attn), mix)
             kvs.append((k, v, ki) if owner else (k, v))
         x_in, mix = stream_in(cfg, layer, x, "ffn")
         out, aux, *more = block(layer, x_in, cfg, None, h_attn)
         held += more[1:]
         x = residual(cfg, x, out, mix)
         auxes.append(aux)
-    if keep is not None:
+    if keep is not None and cut is None:
         x = jax.lax.dynamic_slice_in_dim(x, keep, 1, axis=1)
-    x = norm(cfg, stream_close(cfg, x), params["final_ln"])
+    x = norm(cfg, stream_close(cfg, x), params["final_ln"],
+             params.get("final_ln_b"))
     out = (lm_head(params, x, cfg), kvs, auxes)
     if states:
         out += (states,)
@@ -992,7 +1274,9 @@ def decode_step(block, params, cfg, token, seq_lens, k_pages, v_pages,
 
     token:      [batch] int32 — current input token
     seq_lens:   [batch] int32 — tokens already in cache (excl. current)
-    k_pages/v_pages: [n_kv_layers, n_pages, page, n_kv, hd]; where the
+    k_pages/v_pages: [n_kv_layers, n_pages, page, n_kv, hd] (a family
+                with `cfg.page_rows`: [n_kv_layers, n_pages, page *
+                rows, hd], a page as flat rows); where the
                 attention layers own an indexer (models/keye.py),
                 `v_pages` is the pair (V pool, index pool [index
                 layers, n_pages, page, index_dim]) and comes back so
@@ -1063,7 +1347,13 @@ def decode_step(block, params, cfg, token, seq_lens, k_pages, v_pages,
     spec = attn_layers(cfg)
     li = mi = ii = 0  # rank among the attention / state / index layers
     hs, convs, experts, pairs, taken = [], [], [], [], []
-    for layer, kind in zip(params["layers"], cfg.layer_kinds):
+    diff = getattr(cfg, "diff_attn", False)
+    # kv rows a token, where the pools hold a page as flat rows
+    # (`cfg.page_rows`; 0: the 5-D pool every other family has)
+    flat = getattr(cfg, "page_rows", 0)
+    memory = shared = None  # what "gmu" and "cross" layers read
+    for depth, (layer, kind) in enumerate(zip(params["layers"],
+                                              cfg.layer_kinds)):
         h_attn = None
         x_in, mix = stream_in(cfg, layer, x, "attn")
         if kind == "mamba":
@@ -1073,6 +1363,27 @@ def decode_step(block, params, cfg, token, seq_lens, k_pages, v_pages,
             hs.append(h)
             convs.append(conv)
             mi += 1
+        elif kind == "mamba1":
+            out, memory, (h, conv) = mamba1_mixer_step(
+                layer, x_in, cfg, (state["h"][mi], state["conv"][mi]), rows)
+            x = residual(cfg, x, out, mix)
+            hs.append(h)
+            convs.append(conv)
+            mi += 1
+        elif kind == "gmu":
+            x = residual(cfg, x, gmu(layer, x_in, cfg, memory), mix)
+        elif kind == "cross":
+            # the full pools' layer `shared`, this step's row in it,
+            # through the page table: read here once more, never copied
+            q, _, _, h_attn = _qkv(layer, x_in, cfg, positions, False,
+                                   decode=True)
+            kp, vp, table, lens, _, _ = pools["full"]
+            with jax.named_scope("attn.kernel.cross"):
+                attn = paged_decode_attention(
+                    pack_queries(cfg, q)[:, 0], kp, vp, table, lens + 1,
+                    layer=shared, rows=flat)
+            attn = diff_combine(layer, cfg, attn, depth)
+            x = residual(cfg, x, attn_out(layer, attn[:, None]), mix)
         elif kind == "latent":
             # ONE pool of rows (k_pages); v_pages is None, or where
             # some layers own an indexer the pool of their index keys
@@ -1146,9 +1457,13 @@ def decode_step(block, params, cfg, token, seq_lens, k_pages, v_pages,
                 with jax.named_scope(_kernel_scope(cfg, pool)):
                     attn = paged_decode_attention(
                         q[:, 0], kp, vp, table, lens + 1, window=band,
-                        layer=pl)
-                    if cfg.kv_pack > 1:
+                        layer=pl, rows=flat)
+                    if cfg.kv_pack > 1 and not diff:
                         attn = unpack_heads(cfg, attn)
+                if diff:
+                    attn = diff_combine(layer, cfg, attn, depth)
+                    if pool == "full":
+                        shared = pl
             ii += owner
             x = residual(cfg, x, attn_out(layer, attn.reshape(b, 1, -1)),
                          mix)
@@ -1158,7 +1473,8 @@ def decode_step(block, params, cfg, token, seq_lens, k_pages, v_pages,
         experts += [c for c in n[:1] if c is not None]
         pairs += [jnp.sum(c["pairs_held"]) for c in n[1:]]
         x = residual(cfg, x, out, mix)
-    x = norm(cfg, stream_close(cfg, x), params["final_ln"])
+    x = norm(cfg, stream_close(cfg, x), params["final_ln"],
+             params.get("final_ln_b"))
     logits = lm_head(params, x[:, 0], cfg)
     out = (logits, *pools["full"][:2])
     if state is not None:
@@ -1207,7 +1523,7 @@ def verify_step(block, params, cfg, tokens, seq_lens, k_pages, v_pages,
     recurrent state has no such rollback, so a family with state
     layers is refused here (speculation over state is not built).
     """
-    if "mamba" in cfg.layer_kinds:
+    if {"mamba", "mamba1"} & set(cfg.layer_kinds):
         raise NotImplementedError(
             "verify_step over state layers: a rejected draft cannot be "
             "rolled back out of a recurrent state")

@@ -1014,3 +1014,94 @@ def keye_config_from_hf(hf_cfg, page_size=16, dtype="float32"):
         norm_eps=float(hf_cfg.rms_norm_eps),
         dtype=dtype,
     )
+
+
+def phi4flash_config_from_hf(hf_cfg, page_size=16, dtype="float32"):
+    """Map a Phi-4-mini-flash ``config.json`` (HF `phi4flash`,
+    microsoft/Phi-4-mini-flash-reasoning; any object with its keys as
+    attributes) onto :class:`models.phi_flash.PhiFlashConfig`. The
+    layer layout is derived as the family's modeling code derives it
+    from three keys: a layer l with ``l % mb_per_layer == 0`` is a
+    Mamba-1 layer below ``num_hidden_layers // 2 + 2`` and a Gated
+    Memory Unit from there on; the others are differential attention
+    (a band of the scalar ``sliding_window`` below
+    ``num_hidden_layers // 2``, full causal at ``num_hidden_layers //
+    2 + 1``, whose K and V the cross layers above it attend) and
+    cross-attention. The Mamba widths the catalog's config does not
+    carry default to the family's (`mamba_d_state` 16, `mamba_d_conv`
+    4, `mamba_expand` 2, `mamba_dt_rank` ceil(hidden / 16)).
+    ``random_init`` ({"cross_out_gain"}) is no published key: how a
+    benchmark configuration WITHOUT a checkpoint draws the cross
+    layers' output projections (models/phi_flash.py init_params).
+    Refused,
+    because models/phi_flash.py does not implement it: an untied head,
+    MLP or head biases, a head size that does not pack two to a row of
+    128 lanes, an odd count of kv pairs, a window that is no multiple
+    of the page, dropout. NO loader of published weights exists: one
+    must permute the projections' columns from the published pairing
+    by halves to this family's (models/phi_flash.py's docstring) and
+    check it against the published modeling code."""
+    from .phi_flash import PhiFlashConfig
+
+    def refuse(what):
+        raise NotImplementedError(
+            f"phi4flash: {what} is not implemented by "
+            "models/phi_flash.py")
+
+    if not getattr(hf_cfg, "tie_word_embeddings", True):
+        refuse("tie_word_embeddings false")
+    if getattr(hf_cfg, "mlp_bias", False):
+        refuse("mlp_bias")
+    if getattr(hf_cfg, "lm_head_bias", False):
+        refuse("lm_head_bias")
+    if getattr(hf_cfg, "hidden_act", "silu") not in ("silu", "swish"):
+        refuse(f"hidden_act={hf_cfg.hidden_act!r}")
+    for drop in ("embd_pdrop", "resid_pdrop"):
+        if getattr(hf_cfg, drop, 0):
+            refuse(f"{drop} (inference only)")
+    n, d = hf_cfg.num_hidden_layers, hf_cfg.hidden_size
+    heads, kv = hf_cfg.num_attention_heads, hf_cfg.num_key_value_heads
+    per = int(getattr(hf_cfg, "mb_per_layer", 2))
+    band = int(hf_cfg.sliding_window)
+    if per < 2 or n < 4 or n % 2:
+        refuse(f"mb_per_layer={per} over {n} layers")
+    if d % heads or (d // heads) * 2 > 128 or 128 % (d // heads):
+        refuse(f"a head of {d}/{heads} lanes (two must fill a row of at "
+               "most 128)")
+    if kv % 2 or heads % 2 or (heads // 2) % (kv // 2):
+        refuse(f"{heads} query and {kv} kv heads (pairs of both)")
+    if band <= 0 or band % page_size:
+        refuse(f"sliding_window={band} (a multiple of the page of "
+               f"{page_size})")
+    half = n // 2
+    kinds, bands = [], []
+    for l in range(n):
+        mamba = l % per == 0
+        if l >= half + 2:
+            kinds.append("gmu" if mamba else "cross")
+        else:
+            kinds.append("mamba1" if mamba else "attention")
+        bands.append(band if kinds[-1] == "attention" and l < half else 0)
+    if kinds[half] != "mamba1" or kinds[half + 1] != "attention":
+        refuse(f"a layout whose layer {half} is no Mamba layer or whose "
+               f"layer {half + 1} no attention layer")
+    return PhiFlashConfig(
+        vocab_size=hf_cfg.vocab_size,
+        d_model=d,
+        n_layers=n,
+        n_heads=heads,
+        n_kv_heads=kv,
+        d_ff=hf_cfg.intermediate_size,
+        max_seq=hf_cfg.max_position_embeddings,
+        page_size=page_size,
+        norm_eps=float(hf_cfg.layer_norm_eps),
+        layer_types=tuple(kinds),
+        layer_bands=tuple(bands),
+        ssm_inner=int(getattr(hf_cfg, "mamba_expand", 2)) * d,
+        ssm_state=int(getattr(hf_cfg, "mamba_d_state", 16)),
+        ssm_conv=int(getattr(hf_cfg, "mamba_d_conv", 4)),
+        dt_rank=int(getattr(hf_cfg, "mamba_dt_rank", 0)) or -(-d // 16),
+        cross_out_gain=float((getattr(hf_cfg, "random_init", None)
+                              or {}).get("cross_out_gain", 1.0)),
+        dtype=dtype,
+    )

@@ -331,8 +331,7 @@ def _mlp(layer, x, cfg, valid, h_attn=None):
     a gated MLP, every token on its own, so `valid` is not needed, nor
     the attention block's input; no auxiliary loss."""
     with jax.named_scope("mlp"):
-        h = decoder.rms_norm(x, layer["ln2"], cfg.norm_eps,
-                             cfg.norm_plus_one)
+        h = decoder.norm(cfg, x, layer["ln2"], layer.get("ln2_b"))
         gated = decoder.act(cfg)(decoder.matmul(h, layer["w_gate"])) * (
             decoder.matmul(h, layer["w_up"])
         )

@@ -38,7 +38,19 @@ def scatter_kv_to_pages(pages, new_kv, page_indices, start_in_page,
     Functional update (XLA scatter): returns the new pages array. Batch
     entries may target distinct pages; duplicate targets are undefined
     (callers allocate one page per sequence tail, as vLLM does).
+
+    A whole pool of FOUR dimensions (with `layer`) holds a page as
+    FLAT ROWS [n_layers, n_pages, page * n_kv, hd], row = token * n_kv
+    + kv head (the form of a family whose kv rows a token are no
+    multiple of the 8 a tile holds:
+    pallas_paged_attention.paged_flash_decode); the token's n_kv rows
+    go to rows [start * n_kv, (start + 1) * n_kv) of its page.
     """
+    if layer is not None and pages.ndim == 4:
+        n_kv = new_kv.shape[2]
+        rows = start_in_page[:, None] * n_kv + jnp.arange(n_kv)[None]
+        return pages.at[layer, page_indices[:, None], rows].set(
+            new_kv[:, 0], mode="drop", unique_indices=False)
     return scatter_kv_multi(pages, new_kv[:, 0], page_indices,
                             start_in_page, layer=layer)
 

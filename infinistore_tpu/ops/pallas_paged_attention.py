@@ -442,9 +442,9 @@ def _kv_spec(operand, layer, tok_offset=0):
 
 
 @functools.partial(jax.jit,
-                   static_argnames=("interpret", "window", "layer"))
+                   static_argnames=("interpret", "window", "layer", "rows"))
 def paged_flash_decode(q, k_pages, v_pages, page_table, seq_lens,
-                       interpret=False, window=0, layer=None):
+                       interpret=False, window=0, layer=None, rows=0):
     """Flash-decode attention over paged KV (same contract as
     paged_attention.paged_decode_attention).
 
@@ -453,10 +453,24 @@ def paged_flash_decode(q, k_pages, v_pages, page_table, seq_lens,
     [n_layers, n_pages, page, n_kv, hd] with a static `layer` (see
     `_kv_operand`: the kernel then indexes the layer itself);
     page_table: [batch, max_pages] int32; seq_lens: [batch] int32.
+    `rows` (static; 0: not this form): the whole pool holds a page as
+    FLAT ROWS, [n_layers, n_pages, page * rows, hd] with `rows` kv
+    heads a token: the kernel's own view of a page, which a pool whose
+    kv heads are no multiple of the 8 rows of a tile cannot be merged
+    into without moving every tile (10 packed rows a token: the 5-D
+    pool lies padded to 16 and the merge copied it, once a kind a
+    layer a step). It goes to the kernel as it lies.
     Returns [batch, n_heads, hd].
     """
     batch, n_heads, hd = q.shape
-    page_size, n_kv = k_pages.shape[-3:-1]
+    if rows:
+        if k_pages.ndim != 4 or layer is None or hd % 128:
+            raise ValueError("flat rows: the whole pool [layers, pages, "
+                             "page * rows, hd] with hd a lane multiple")
+        page_size, n_kv = k_pages.shape[-2] // rows, rows
+    else:
+        page_size, n_kv = k_pages.shape[-3:-1]
+    as_lies = bool(rows) or k_pages.ndim == 5  # the pool goes whole
     max_pages = page_table.shape[1]
 
     # Pad to TPU tile boundaries: lanes (last dim) 128; sublane multiple
@@ -467,7 +481,7 @@ def paged_flash_decode(q, k_pages, v_pages, page_table, seq_lens,
     group = n_heads // n_kv
     sublane, n_kv_p = _decode_dims(q.dtype, n_kv, group)
     group_p = group
-    if (n_kv_p != n_kv and k_pages.ndim == 5 and hd % 128 == 0
+    if (n_kv_p != n_kv and as_lies and hd % 128 == 0
             and math.gcd(group, sublane) == 1):
         # A group that shares no factor with the sublane count, so
         # that only `sublane` kv heads make a sublane multiple of
@@ -484,23 +498,44 @@ def paged_flash_decode(q, k_pages, v_pages, page_table, seq_lens,
         q_p = jnp.pad(q_p.reshape(batch, n_kv, group, hd_p),
                       ((0, 0), (0, 0), (0, group_p - group), (0, 0))
                       ).reshape(batch, n_kv * group_p, hd_p)
+    elif (n_kv_p != n_kv and as_lies and hd % 128 == 0
+            and -(-n_heads // sublane) * sublane // n_kv == group):
+        # A group that does share a factor with the sublane count over
+        # kv heads that are no multiple of what is left (4 query rows a
+        # row of 10 packed kv heads: 40 query rows, and 12 kv heads
+        # would make 48), over such a pool: pad the query rows' TAIL
+        # with zero rows (40 -> 48) for the same reason. A tail row's
+        # kv head (row // group: 10, 11) is none the pool has, so every
+        # key is masked for it; its output is finite and dropped below.
+        # Only where the padded rows still divide to the same group (48
+        # // 10 == 4), which is what the kernel derives it from.
+        n_kv_p = n_kv
+        tail = -n_heads % sublane
+        q_p = jnp.pad(q_p, ((0, 0), (0, tail), (0, 0)))
     elif n_kv_p != n_kv:
         q_p = jnp.pad(q_p, ((0, 0), (0, (n_kv_p - n_kv) * group), (0, 0)))
-    n_heads_p = n_kv_p * group_p
+    n_heads_p = q_p.shape[1]
 
     # A page as [page * n_kv, hd] rows: token-major as it lies, so the
     # merge of (page, n_kv) leaves every (8, 128) tile where it is (a
     # bitcast for XLA, on the whole pool too) and a block of pages is
     # one [keys * n_kv, hd] operand of `_attend_rows`.
-    k_f = _kv_aligned(k_pages, layer, n_kv_p)
-    v_f = _kv_aligned(v_pages, layer, n_kv_p)
-    if k_f.ndim != 5:
-        layer = None  # sliced out by `_kv_aligned`
-    rows = (*k_f.shape[:-3], page_size * n_kv_p, hd_p)
-    k_f, v_f = k_f.reshape(rows), v_f.reshape(rows)
+    if rows:
+        if n_kv_p != n_kv:
+            raise ValueError(f"{n_heads} query heads over flat rows of "
+                             f"{n_kv} kv heads: no padding keeps the pool")
+        k_f, v_f = k_pages, v_pages
+    else:
+        k_f = _kv_aligned(k_pages, layer, n_kv_p)
+        v_f = _kv_aligned(v_pages, layer, n_kv_p)
+        if k_f.ndim != 5:
+            layer = None  # sliced out by `_kv_aligned`
+        as_rows = (*k_f.shape[:-3], page_size * n_kv_p, hd_p)
+        k_f, v_f = k_f.reshape(as_rows), v_f.reshape(as_rows)
     n_pages_block = _pages_per_block(
-        page_size, math.prod(rows[-2:]) * k_f.dtype.itemsize, max_pages)
-    buf = pltpu.VMEM((2, n_pages_block, *rows[-2:]), k_f.dtype)
+        page_size, math.prod(k_f.shape[-2:]) * k_f.dtype.itemsize,
+        max_pages)
+    buf = pltpu.VMEM((2, n_pages_block, *k_f.shape[-2:]), k_f.dtype)
     whole = pl.BlockSpec((batch, n_heads_p, hd_p),
                          lambda b, pt, sl: (0, 0, 0))
 
@@ -764,13 +799,20 @@ def verify_attention(q, k_pages, v_pages, page_table, seq_lens, window=0,
 
 
 def decode_attention(q, k_pages, v_pages, page_table, seq_lens, window=0,
-                     layer=None):
+                     layer=None, rows=0):
     """Paged decode attention with automatic backend choice: the pallas
     flash kernel on TPU, the XLA gather path elsewhere.
-    k_pages/v_pages: one layer, or the whole pool plus `layer`."""
+    k_pages/v_pages: one layer, or the whole pool plus `layer`; with
+    `rows`, the whole pool with a page as flat rows
+    (`paged_flash_decode`), which the gather path reads as the 5-D pool
+    it is row for row."""
     if jax.default_backend() == "tpu":
         return paged_flash_decode(q, k_pages, v_pages, page_table, seq_lens,
-                                  window=window, layer=layer)
+                                  window=window, layer=layer, rows=rows)
+    if rows:
+        as_5d = (*k_pages.shape[:2], k_pages.shape[2] // rows, rows,
+                 k_pages.shape[3])
+        k_pages, v_pages = k_pages.reshape(as_5d), v_pages.reshape(as_5d)
     return xla_ref.paged_decode_attention(
         q, k_pages, v_pages, page_table, seq_lens, window=window,
         layer=layer

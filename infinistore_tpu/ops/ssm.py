@@ -23,6 +23,20 @@ y_t = h_t C_t. A position whose dt is 0 leaves the state as it was
 (decay 1, input 0): that is how padded prompt positions are masked.
 The `D x_t` skip, the gate and the norm are the mixer's
 (models/hybrid.py).
+
+A second recurrence, Mamba-1's SELECTIVE scan (models/phi_flash.py),
+whose decay is per channel AND per state element, so the chunked
+matrix form above (a scalar decay a head) does not compute it:
+  x, dt [b, s, C] (dt after softplus)       B, C [b, s, N]
+  A [N, C] (negative)                       h [b, N, C]
+  h_t[n, c] = exp(dt_t[c] A[n, c]) h_{t-1}[n, c] + dt_t[c] x_t[c] B_t[n]
+  y_t[c] = sum_n h_t[n, c] C_t[n]
+The state lies with its CHANNELS along the lanes (5,120 of them at
+Phi-4-mini-flash's widths) and its 16 elements along the sublanes:
+[C, N] would pad 16 lanes to 128. `selective_scan` (an admission) is
+sequential in time, the state in fast memory; `selective_step` (a
+decode step) moves the decoding slots' rows alone, as `step` does. On
+a TPU both are Pallas calls, elsewhere plain `jax.numpy`.
 """
 
 import functools
@@ -246,3 +260,186 @@ def scan(h0, x, dt, A, B, C, chunk):
         y = y + jnp.einsum("bcln,bchpn->bclhp", Cc.astype(F32),
                            h_in) * jnp.exp(a_cum)[..., None]
         return y.reshape(b, c * q, H, P)[:, :s], h_end.astype(h0.dtype)
+
+
+# ---- the selective recurrence (Mamba-1) --------------------------------
+
+# Time steps a grid step of the scan's kernel holds (x, dt and y blocks
+# of _SCAN_CHUNK x C float32 each, B and C as columns) and channels a
+# tile of its inner loop (the tile's state and its A stay in registers
+# over the chunk: 16 x 512 float32 are 8 vregs each).
+_SCAN_CHUNK = 64
+_CHANNEL_TILE = 512
+
+
+def _selective_whole(h, x, dt, A, B, C):
+    """One token of the selective recurrence for every row, in XLA.
+    h: [b, N, C]; x, dt: [b, C]; A: [N, C]; B, C: [b, N]; float32."""
+    new = jnp.exp(dt[:, None, :] * A) * h.astype(F32) \
+        + (dt * x)[:, None, :] * B[:, :, None]
+    return jnp.sum(new * C[:, :, None], axis=1), new.astype(h.dtype)
+
+
+def _selective_step_body(order_ref, h_ref, a_ref, dt_ref, x_ref, b_ref,
+                         c_ref, h_out, y_out):
+    """One slot: h [1, N, C]; A [N, C]; dt, x [1, 1, C]; B, C [1, N, 1]
+    (columns: an element a sublane, broadcast along the lanes)."""
+    dt = dt_ref[0]
+    new = jnp.exp(dt * a_ref[...]) * h_ref[0].astype(F32) \
+        + (dt * x_ref[0]) * b_ref[0]
+    h_out[0] = new.astype(h_out.dtype)
+    y_out[0] = jnp.sum(new * c_ref[0], axis=0, keepdims=True)
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def selective_step_kernel(h, x, dt, A, B, C, order, count, interpret=False):
+    """`selective_step`'s update of the first `count` [1] slots of
+    `order` [b], as ONE Pallas call over the pool `h` [b, N, C], which
+    comes back ALIASED: a grid of `count` steps, step i holding slot
+    order[i] whole (328 KB at 16 x 5,120). Returns (y [b, C] float32,
+    of which the rows of the slots not run are NOT WRITTEN; h)."""
+    b, N, Cw = h.shape
+
+    def slot(*shape):
+        return pl.BlockSpec((1, *shape),
+                            lambda i, order: (order[i], *(0,) * len(shape)))
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,  # order
+        grid=(count[0],),
+        in_specs=[slot(N, Cw), pl.BlockSpec((N, Cw), lambda i, order: (0, 0)),
+                  slot(1, Cw), slot(1, Cw), slot(N, 1), slot(N, 1)],
+        out_specs=[slot(N, Cw), slot(1, Cw)],
+    )
+    h, y = pl.pallas_call(
+        _selective_step_body,
+        out_shape=[jax.ShapeDtypeStruct(h.shape, h.dtype),
+                   jax.ShapeDtypeStruct((b, 1, Cw), F32)],
+        grid_spec=grid_spec,
+        input_output_aliases={1: 0},  # the pool
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=interpret,
+    )(order, h, A, dt[:, None], x[:, None], B[:, :, None], C[:, :, None])
+    return y[:, 0], h
+
+
+def selective_step(h, x, dt, A, B, C, rows=None):
+    """One token of the selective recurrence for every row of the
+    batch, or with `rows` (`decoding`'s triple) for the rows that
+    decode: the others' state stays bit for bit what it was and their
+    y is 0. On a TPU that is `selective_step_kernel`.
+
+    h: [b, N, C] float32; x: [b, C]; dt: [b, C] float32; A: [N, C];
+    B, C: [b, N]. Returns (y [b, C] float32, new h)."""
+    with jax.named_scope("ssm.step"):
+        x, B, C = x.astype(F32), B.astype(F32), C.astype(F32)
+        if rows is None:
+            return _selective_whole(h, x, dt, A, B, C)
+        run = rows[0]
+        if jax.default_backend() == "tpu":
+            y, new = selective_step_kernel(h, x, dt, A, B, C, *rows[1:])
+        else:
+            y, new = _selective_whole(h, x, dt, A, B, C)
+            new = jnp.where(run[:, None, None], new, h)
+        return jnp.where(run[:, None], y, 0.0), new
+
+
+def _selective_scan_body(h0_ref, a_ref, dt_ref, x_ref, b_ref, c_ref, y_ref,
+                         h_ref, *, tile):
+    """One chunk of one sequence: h0, h [1, N, C] (h is the carry: its
+    block stays in fast memory over the chunks); A [N, C]; dt, x, y [1,
+    T, C]; B, C [1, T, N, 1]. A tile of channels at a time, the tile's
+    state in registers over the chunk's T steps, 8 steps (one tile of
+    sublanes of dt, x and y) a turn of the loop."""
+    @pl.when(pl.program_id(1) == 0)
+    def _():
+        h_ref[...] = h0_ref[...]
+
+    T, Cw = dt_ref.shape[1:]
+    for at in range(0, Cw, tile):
+        lanes = slice(at, at + tile)
+        a = a_ref[:, lanes]
+
+        def eight(g, h):
+            r = pl.ds(pl.multiple_of(g * 8, 8), 8)
+            dt8, x8 = dt_ref[0, r, lanes], x_ref[0, r, lanes]
+            ys = []
+            for j in range(8):
+                dt = dt8[j:j + 1]
+                h = jnp.exp(dt * a) * h \
+                    + (dt * x8[j:j + 1]) * b_ref[0, g * 8 + j]
+                ys.append(jnp.sum(h * c_ref[0, g * 8 + j], axis=0,
+                                  keepdims=True))
+            y_ref[0, r, lanes] = jnp.concatenate(ys, axis=0)
+            return h
+
+        h_ref[0, :, lanes] = jax.lax.fori_loop(
+            0, T // 8, eight, h_ref[0, :, lanes].astype(F32)
+        ).astype(h_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=("chunk", "tile", "interpret"))
+def selective_scan_kernel(h0, x, dt, A, B, C, chunk=_SCAN_CHUNK, tile=None,
+                          interpret=False):
+    """`selective_scan` as ONE Pallas call: a grid of (sequences, chunks
+    of `chunk` steps), the state carried from chunk to chunk in its
+    output block. s must be a multiple of `chunk`, `chunk` of 8, and the
+    channels of `tile`. Returns (y [b, s, C] float32, h [b, N, C])."""
+    b, s, Cw = x.shape
+    N = A.shape[0]
+    tile = tile or math.gcd(Cw, _CHANNEL_TILE)
+    if s % chunk or chunk % 8 or Cw % tile:
+        raise ValueError(f"{s} steps in chunks of {chunk}, {Cw} channels "
+                         f"in tiles of {tile}")
+    state = pl.BlockSpec((1, N, Cw), lambda i, j: (i, 0, 0))
+    steps = pl.BlockSpec((1, chunk, Cw), lambda i, j: (i, j, 0))
+    cols = pl.BlockSpec((1, chunk, N, 1), lambda i, j: (i, j, 0, 0))
+    y, h = pl.pallas_call(
+        functools.partial(_selective_scan_body, tile=tile),
+        out_shape=[jax.ShapeDtypeStruct((b, s, Cw), F32),
+                   jax.ShapeDtypeStruct(h0.shape, h0.dtype)],
+        grid=(b, s // chunk),
+        in_specs=[state, pl.BlockSpec((N, Cw), lambda i, j: (0, 0)),
+                  steps, steps, cols, cols],
+        out_specs=[steps, state],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=64 << 20),
+        interpret=interpret,
+    )(h0, A, dt, x, B[..., None], C[..., None])
+    return y, h
+
+
+def selective_scan(h0, x, dt, A, B, C):
+    """The selective recurrence over a sequence from the state `h0`.
+    A position whose dt is 0 leaves the state as it was (decay 1, input
+    0): that is how padded prompt positions are masked. On a TPU
+    `selective_scan_kernel` (s padded to whole chunks with dt = 0),
+    elsewhere a `lax.scan` over the positions.
+
+    h0: [b, N, C] float32; x: [b, s, C]; dt: [b, s, C] float32; A: [N,
+    C]; B, C: [b, s, N]. Returns (y [b, s, C] float32, h after the last
+    position)."""
+    s = x.shape[1]
+    with jax.named_scope("ssm.scan"):
+        x, B, C = x.astype(F32), B.astype(F32), C.astype(F32)
+        if jax.default_backend() == "tpu":
+            chunk = min(_SCAN_CHUNK, -(-s // 8) * 8)
+            pad = -s % chunk
+
+            def steps(a):
+                return jnp.pad(a, ((0, 0), (0, pad))
+                               + ((0, 0),) * (a.ndim - 2))
+
+            y, h = selective_scan_kernel(h0, steps(x), steps(dt), A,
+                                         steps(B), steps(C), chunk=chunk)
+            return y[:, :s], h
+
+        def one(h, inp):
+            y, h = _selective_whole(h, *inp[:2], A, *inp[2:])
+            return h, y
+
+        h, y = jax.lax.scan(one, h0, tuple(
+            jnp.moveaxis(a, 1, 0) for a in (x, dt, B, C)))
+        return jnp.moveaxis(y, 0, 1), h

@@ -919,6 +919,40 @@ def _admit_fused_wf(params, cfg, tokens, k_pages, v_pages, wk, wv, ids,
             sub)
 
 
+def _place_restored_two(cfg, restored, k_pages, v_pages, wk, wv, r_ids,
+                        wr_ids):
+    """`_place_restored` for two kinds: a hit's restored pages
+    (`_admit_fused_px_wf` has the form) into both pairs of pools, and
+    as the per-layer contiguous prefix (k, v) each layer may attend,
+    rows in the cache's form ([1, positions, *a page's row]: packed
+    where the family packs, as its prefill makes and attends them)."""
+    page = cfg.page_size
+    spec = decoder.attn_layers(cfg)
+    n_full = sum(pool == "full" for *_, pool, _ in spec)
+    p, nw = r_ids.shape[0], wr_ids.shape[0]
+    with jax.named_scope("pool.update"):  # stage names: models/decoder.py
+        cut = p * n_full * 2
+        rf = restored[:cut].reshape(p, n_full, 2, *cfg.kv_page_shape())
+        rw = restored[cut:].reshape(nw, len(spec) - n_full, 2,
+                                    *cfg.kv_page_shape())
+        # per layer, straight from the page-major rows, as
+        # `_place_restored` does and for its reason
+        for li in range(n_full):
+            k_pages = k_pages.at[li, r_ids].set(rf[:, li, 0], mode="drop")
+            v_pages = v_pages.at[li, r_ids].set(rf[:, li, 1], mode="drop")
+        for li in range(len(spec) - n_full):
+            wk = wk.at[li, wr_ids].set(rw[:, li, 0], mode="drop")
+            wv = wv.at[li, wr_ids].set(rw[:, li, 1], mode="drop")
+        prefix = []
+        row = (cfg.n_kv_heads // cfg.kv_pack, cfg.head_dim * cfg.kv_pack)
+        for *_, pool, li in spec:
+            rows = rf if pool == "full" else rw
+            flat = (1, rows.shape[0] * page, *row)
+            prefix.append((rows[:, li, 0].reshape(flat),
+                           rows[:, li, 1].reshape(flat)))
+    return k_pages, v_pages, wk, wv, prefix
+
+
 @partial(jax.jit, static_argnames=("cfg", "model", "n_sub"),
          donate_argnums=(4, 5, 6, 7))
 def _admit_fused_px_wf(params, cfg, tokens, restored, k_pages, v_pages, wk,
@@ -942,29 +976,8 @@ def _admit_fused_px_wf(params, cfg, tokens, restored, k_pages, v_pages, wk,
     positions are absolute (keys were rotated before they were cached)
     and the band is relative, so neither needs the other's length
     (decoder.forward_stack). One program per (s_pad, P)."""
-    page = cfg.page_size
-    spec = decoder.attn_layers(cfg)
-    n_full = sum(pool == "full" for *_, pool, _ in spec)
-    p, nw = r_ids.shape[0], wr_ids.shape[0]
-    with jax.named_scope("pool.update"):  # stage names: models/decoder.py
-        cut = p * n_full * 2
-        rf = restored[:cut].reshape(p, n_full, 2, *cfg.kv_page_shape())
-        rw = restored[cut:].reshape(nw, len(spec) - n_full, 2,
-                                    *cfg.kv_page_shape())
-        # per layer, straight from the page-major rows, as
-        # `_place_restored` does and for its reason
-        for li in range(n_full):
-            k_pages = k_pages.at[li, r_ids].set(rf[:, li, 0], mode="drop")
-            v_pages = v_pages.at[li, r_ids].set(rf[:, li, 1], mode="drop")
-        for li in range(len(spec) - n_full):
-            wk = wk.at[li, wr_ids].set(rw[:, li, 0], mode="drop")
-            wv = wv.at[li, wr_ids].set(rw[:, li, 1], mode="drop")
-        prefix = []
-        for *_, pool, li in spec:
-            rows = rf if pool == "full" else rw
-            flat = (1, rows.shape[0] * page, cfg.n_kv_heads, cfg.head_dim)
-            prefix.append((rows[:, li, 0].reshape(flat),
-                           rows[:, li, 1].reshape(flat)))
+    k_pages, v_pages, wk, wv, prefix = _place_restored_two(
+        cfg, restored, k_pages, v_pages, wk, wv, r_ids, wr_ids)
     logits, kvs, *counts = model.prefill_with_prefix(
         params, cfg, tokens, prefix, keep=s_real - 1)
     k_pages, v_pages, wk, wv, sub = _page_out_two(
@@ -990,6 +1003,63 @@ def _decode_fused_wf(params, cfg, token, seq_lens, k_pages, v_pages, wk, wv,
     out = (logits, nxt, seq_lens + (seq_lens > 0), k_pages, v_pages, wk,
            wv)
     return out + (_with_fetched(nxt, n[0]),) if fetched else out
+
+
+# ---- ... and for a model with all three: full pages, banded pages and
+# a recurrent state in one sequence (models/phi_flash.py). Built from
+# the two trios' helpers; names of their own, for the trace's sake.
+
+
+@partial(jax.jit, static_argnames=("cfg", "model", "n_sub"),
+         donate_argnums=(3, 4, 5, 6, 7, 8))
+def _admit_fused_wf_st(params, cfg, tokens, k_pages, v_pages, wk, wv, state,
+                       bstate, ids, wids, s_real, slot, model, n_sub):
+    """`_admit_fused_wf` with `_admit_fused_st`'s state: pages of both
+    kinds go where the first puts them, the state after `s_real` tokens
+    and the one at the last page edge into row `slot` of the state
+    pools and boundary copies."""
+    logits, kvs, states = model.prefill(params, cfg, tokens, s_real=s_real,
+                                        last_only=True)
+    k_pages, v_pages, wk, wv, sub = _page_out_two(
+        cfg, kvs, k_pages, v_pages, wk, wv, ids, wids, n_sub)
+    state, bstate = _state_in(state, bstate, states, slot)
+    return logits[0, 0], k_pages, v_pages, wk, wv, sub, state, bstate
+
+
+@partial(jax.jit, static_argnames=("cfg", "model", "n_sub"),
+         donate_argnums=(5, 6, 7, 8, 9, 10))
+def _admit_fused_px_wf_st(params, cfg, tokens, restored, snap, k_pages,
+                          v_pages, wk, wv, state, bstate, r_ids, wr_ids,
+                          s_ids, ws_ids, s_real, slot, model, n_sub):
+    """`_admit_fused_px_wf` with `_admit_fused_px_st`'s snapshot: a hit
+    of three kinds. `restored` and the ids as there; `snap` ([state
+    layers, row]) is the state at the end of the restored pages, where
+    the state layers continue from."""
+    k_pages, v_pages, wk, wv, prefix = _place_restored_two(
+        cfg, restored, k_pages, v_pages, wk, wv, r_ids, wr_ids)
+    logits, kvs, states = model.prefill_with_prefix(
+        params, cfg, tokens, prefix, state=_rows_to_state(cfg, snap),
+        s_real=s_real, last_only=True)
+    k_pages, v_pages, wk, wv, sub = _page_out_two(
+        cfg, kvs, k_pages, v_pages, wk, wv, s_ids, ws_ids, n_sub)
+    state, bstate = _state_in(state, bstate, states, slot)
+    return logits[0, 0], k_pages, v_pages, wk, wv, sub, state, bstate
+
+
+@partial(jax.jit, static_argnames=("cfg", "model"),
+         donate_argnums=(4, 5, 6, 7, 8))
+def _decode_fused_wf_st(params, cfg, token, seq_lens, k_pages, v_pages, wk,
+                        wv, state, rows, model):
+    """`_decode_fused_wf` with `_decode_fused_st`'s state: `rows` as
+    there; the state of the slots that decode is advanced where it
+    lies."""
+    table, wtable, wbase = rows
+    logits, k_pages, v_pages, state, wk, wv = model.decode_step(
+        params, cfg, token, seq_lens, k_pages, v_pages, table, state,
+        win=(wk, wv, wtable, wbase))
+    nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+    return (logits, nxt, seq_lens + (seq_lens > 0), k_pages, v_pages, wk,
+            wv, state)
 
 
 # Trivial programs dispatched behind a one-shot admission's program
@@ -1251,6 +1321,14 @@ class ServingEngine:
             # ... over the decode steps, the slots whose state a step
             # moved and the sequences it decoded (the same, since PR 52)
             "state_rows_run": 0, "state_rows_active": 0,
+            # the admission programs' token-layer rows: those they ran,
+            # of padded tokens x layers (fewer where the layers above
+            # the last cache run on the kept row alone:
+            # decoder.stack_rows), and over the decode steps the cache
+            # rows of ANOTHER layer's pages that borrowing layers read
+            # (live rows x such layers)
+            "stack_rows_run": 0, "stack_rows_all": 0,
+            "shared_kv_rows_read": 0,
             # hits whose snapshot lay below the pages' matched depth
             "snapshot_walkbacks": 0,
             # two kinds of attention layer: banded layers' pages that
@@ -1332,6 +1410,11 @@ class ServingEngine:
         # of every row's table over every attention layer
         self._attn_kinds = collections.Counter(
             (pool, band) for band, _, pool, _ in spec)
+        # ... and the layers that attend ANOTHER layer's full pages
+        # ("cross": they walk the page table as its owner does)
+        self._borrowers = sum(k == "cross" for k in cfg.layer_kinds)
+        if self._borrowers:
+            self._attn_kinds[("full", 0)] += self._borrowers
         self._attn_table = self.sc.max_slots * sum(
             layers * (self.wtable if pool == "window"
                       else self.page_table).shape[1]
@@ -1364,8 +1447,13 @@ class ServingEngine:
         kinds = len(cfg.page_kinds)
         self._page_objects = kinds * self.k_pages.shape[0]
         self._page_bytes = kinds * self.k_pages.nbytes // self.sc.total_pages
-        # The store keys that stand for a page in a hit's probe: one.
-        self._probe_kinds = [(0, cfg.page_kinds[0])]
+        # The store keys that stand for a page in a hit's probe: one,
+        # attention layer 0's. (Three kinds in a slot: the ONE full
+        # layer's, whose pages a hit needs from page 0 on; attention
+        # layer 0 there is banded, and what it computes below an
+        # admission's band is never written.)
+        self._probe_kinds = [(self._full_layers[0] if self._three_kinds()
+                              else 0, cfg.page_kinds[0])]
         self._kinds_field = {}  # what a pool a kind adds to the spans
         if self._index_kind:
             # ... but kinds of different sizes on different layers, a
@@ -1478,15 +1566,18 @@ class ServingEngine:
         the full pools. What is not built over two kinds is refused
         here, not found at the first request: verify and burst steps,
         a piece's prefix, the int8 wire and packed rows address ONE
-        page pool."""
+        page pool, and state layers beside them are built for ONE
+        combination (`_three_kinds`)."""
         cfg, sc = self.cfg, self.sc
         bands = {w for w, *_ in decoder.attn_layers(cfg) if w}
+        three = self._three_kinds()
         self._refuse("full and banded attention layers", {
             "spec_k": sc.spec_k > 0, "host_steps": sc.host_steps > 1,
             "admit_piece": sc.admit_piece > 0,
             "quantized_store": sc.quantized_store,
-            "kv_pack": cfg.kv_pack > 1,
-            "state layers": bool(getattr(cfg, "n_state_layers", 0)),
+            "kv_pack": cfg.kv_pack > 1 and not three,
+            "state layers": bool(getattr(cfg, "n_state_layers", 0))
+            and not three,
             "more than one band": len(bands) > 1,
             "a band that is no page multiple":
                 cfg.window_band % cfg.page_size != 0})
@@ -1561,15 +1652,37 @@ class ServingEngine:
                 kinds[:1] != ("full",),
             "hc_mult": cfg.hc_mult > 1, **over_kv})
 
+    def _three_kinds(self):
+        """THE rule of what runs over three kinds of cache in one
+        sequence (full pages, banded pages, a recurrent state): state
+        layers beside full and banded attention layers whose cache
+        rows are packed BY PAIR (`cfg.pair_rows`: a row is the two kv
+        heads one differential pair reads, so both pairs of pools take
+        the one `kv_page_shape` and no call unpacks a row). Every
+        other mix of these stays refused by name: packed rows over two
+        kinds for any other family (`unpack_heads` over a second pool
+        is not built), state layers beside two kinds without them, and
+        every option either family refuses."""
+        cfg = self.cfg
+        return bool(cfg.two_kinds and getattr(cfg, "n_state_layers", 0)
+                    and getattr(cfg, "pair_rows", False))
+
     def _check_state_family(self):
         """What is not built over a recurrent state is refused at
         construction, not found at the first request: a rejected draft
         cannot be rolled back out of a state, bursts would need the
         boundary copy inside the scan, a piece would need the state its
         predecessor left as a hit needs a snapshot, and the int8 wire is
-        defined for pages. A sliding window is refused too: its release
-        frees pages by one global band, and a family whose state layers
-        carry the long range has none."""
+        defined for pages. ONE sliding window over every layer is
+        refused too: its release (`_release_windowed`) frees the ONE
+        pool's pages by one global band, and a family whose state layers
+        carry the long range has none. Banded layers BESIDE full ones
+        are no such window: their pages live in pools of their own
+        under a short table (`_init_window_pools`, which refuses state
+        layers for every combination but `_three_kinds`), the full
+        layers' pages stay, and the state's boundary copy and snapshot
+        follow the page edges of the sequence, which both kinds
+        share."""
         sc = self.sc
         self._refuse("state layers", {
             "spec_k": sc.spec_k > 0, "host_steps": sc.host_steps > 1,
@@ -1941,7 +2054,8 @@ class ServingEngine:
             # depth, `bytes` what crosses over.
             kinds = {"full_pages": hit, "window_pages": n,
                      "trimmed_pages": first_live}
-            nbytes = hit * self._page_bytes + n * self._wpage_bytes
+            nbytes = hit * self._page_bytes + n * self._wpage_bytes \
+                + self._snapshot_bytes
             n = hit
         else:
             nbytes = n * self._page_bytes + self._snapshot_bytes
@@ -2265,13 +2379,14 @@ class ServingEngine:
             f["outcome"] = "no_pages"
             return False
         try:
-            restored = None
+            restored = snap = None
             if hit > 0:
-                restored, _, hit = self._try_restore(
+                restored, snap, hit = self._try_restore(
                     hit, digests, self._first_live(hit), f)
             f["hit_pages"] = hit
             row_host, sub = self._prefill_two(work.prompt, hit, restored,
-                                              ids, wids, wbase)
+                                              ids, wids, wbase, snap,
+                                              slot_idx)
         except BaseException:
             self.free_pages.extend(ids)
             self.wfree.extend(wids)
@@ -2296,13 +2411,17 @@ class ServingEngine:
         f["outcome"] = "admitted"
         return True
 
-    def _prefill_two(self, prompt, hit, restored, ids, wids, wbase):
+    def _prefill_two(self, prompt, hit, restored, ids, wids, wbase,
+                     snap=None, slot_idx=None):
         """The admission program of a model with two kinds of attention
         layer: cold (`hit` 0) or prefix (`restored`: what `_restore`
         returned for a hit of `hit` pages). `ids`: the full pools' ids
         of the prompt's pages [0, n_pages); `wids`: the banded pools'
         of [wbase, n_pages); None for either: nothing is written
-        (`first_token_logits`). Returns (the last real position's
+        (`first_token_logits`). With state layers too, `snap` (the
+        restored snapshot) is where they continue from, and the
+        sequence's state and boundary copy land in row `slot_idx`
+        (None: dropped). Returns (the last real position's
         logits row on the host, `sub`: the banded layers' pages [hit,
         wbase) as device chunks, see `_page_out_two`)."""
         cfg, sc = self.cfg, self.sc
@@ -2318,29 +2437,48 @@ class ServingEngine:
             fa[:n_pages] = ids
         if wids is not None:
             wa[wbase:n_pages] = wids
-        n_sub = max(0, wbase - hit)
+        # What the banded layers compute below the band goes to the
+        # store from the program, unless the model has state layers
+        # too: a prefix is used only with the snapshot at its end,
+        # snapshots lie at finishes' page edges alone, and a finish
+        # writes the band it ends in, so no hit can ever read a page
+        # from below an ADMISSION's band (491 MB of them a cold prompt
+        # of 12.4k tokens at phi4-mini-flash's widths, which alone
+        # filled UPLOAD_INFLIGHT_BYTES and made the next finish wait).
+        n_sub = max(0, wbase - hit) if self.state is None else 0
         s_real = self._to_device(np.int32(len(suffix)))
         pools = (self.k_pages, self.v_pages, self.wk_pages, self.wv_pages)
         fields = {"restored_pages": hit} if hit else {}
         with self._span("istpu.model.prefill",
                         program="prefix" if hit else "cold",
                         tokens=len(suffix), padded_tokens=toks.shape[1],
-                        **fields) as f:
+                        **fields, **self._rows_fields(toks.shape[1])) as f:
+            if self.state is None:
+                admit, admit_px, snap_in, where = (
+                    _admit_fused_wf, _admit_fused_px_wf, (), ())
+            else:  # ... and the state pools, their boundary copies, the
+                # snapshot a hit continues from, the slot's row in them
+                pools += (self.state, self.bstate)
+                admit, admit_px, snap_in, where = (
+                    _admit_fused_wf_st, _admit_fused_px_wf_st, (snap,),
+                    (self._slot_dev(slot_idx),))
             if hit:
                 first_live = self._first_live(hit)
-                out = _admit_fused_px_wf(
-                    self.params, cfg, toks, restored, *pools,
+                out = admit_px(
+                    self.params, cfg, toks, restored, *snap_in, *pools,
                     self._to_device(fa[:hit]),
                     self._to_device(wa[first_live:hit]),
                     self._to_device(fa[hit:]), self._to_device(wa[hit:]),
-                    s_real, model=self.model, n_sub=n_sub)
+                    s_real, *where, model=self.model, n_sub=n_sub)
             else:
-                out = _admit_fused_wf(
+                out = admit(
                     self.params, cfg, toks, *pools, self._to_device(fa),
-                    self._to_device(wa), s_real, model=self.model,
+                    self._to_device(wa), s_real, *where, model=self.model,
                     n_sub=n_sub)
             (row_dev, self.k_pages, self.v_pages, self.wk_pages,
-             self.wv_pages, sub) = out
+             self.wv_pages, sub, *states) = out
+            if states:
+                self.state, self.bstate = states
             f["dispatch_ns"] = profiling.elapsed_ns()
             return self._pull_row(row_dev, len(suffix), f), sub
 
@@ -2443,6 +2581,17 @@ class ServingEngine:
             return {}
         return {"chunks": -(-padded_tokens // self.cfg.ssm_chunk)}
 
+    def _rows_fields(self, padded_tokens):
+        """An admission program's token-layer rows, counted as they
+        are read (decoder.stack_rows: the rows the program ran, of
+        padded tokens x layers), and what istpu.model.prefill carries
+        of them where the two differ (a model whose upper layers run on
+        the kept row alone): `rows_run` of `rows_all`."""
+        run, every = decoder.stack_rows(self.cfg, padded_tokens)
+        self.stats["stack_rows_run"] += run
+        self.stats["stack_rows_all"] += every
+        return {"rows_run": run, "rows_all": every} if run < every else {}
+
     def _prefill_cold(self, tokens, ids_padded, slot_idx=None):
         """The cold program: ONE fused device program does prefill +
         page-out + pool scatter at `ids_padded` (_pad_ids form) +
@@ -2453,7 +2602,8 @@ class ServingEngine:
         toks = self._pad_tokens(tokens)
         with self._span("istpu.model.prefill", program="cold",
                         tokens=len(tokens), padded_tokens=toks.shape[1],
-                        **self._scan_fields(toks.shape[1])) as f:
+                        **self._scan_fields(toks.shape[1]),
+                        **self._rows_fields(toks.shape[1])) as f:
             ids = self._to_device(ids_padded)
             s_real = self._to_device(np.int32(len(tokens)))
             if self.state is None:
@@ -2512,7 +2662,8 @@ class ServingEngine:
         with self._span("istpu.model.prefill", program="prefix",
                         tokens=len(suffix), padded_tokens=toks.shape[1],
                         restored_pages=len(restored_ids),
-                        **self._scan_fields(toks.shape[1])) as f:
+                        **self._scan_fields(toks.shape[1]),
+                        **self._rows_fields(toks.shape[1])) as f:
             r_ids = self._to_device(np.asarray(restored_ids, np.int32))
             s_ids = self._to_device(self._pad_ids(suffix_ids))
             s_real = self._to_device(np.int32(len(suffix)))
@@ -2552,16 +2703,16 @@ class ServingEngine:
                      prompt=prompt)
         hit, digests = self._probe_hit(work)
         if self._win_layers:
-            restored = None
+            restored = snap = None
             if hit > 0:
                 try:
-                    restored, _ = self._restore(hit, digests,
-                                                self._first_live(hit))
+                    restored, snap = self._restore(hit, digests,
+                                                   self._first_live(hit))
                 except InfiniStoreKeyNotFound:
                     hit = 0  # evicted between probe and restore
             row, _ = self._prefill_two(
                 prompt, hit, restored, None, None,
-                self._wbase_after(-(-len(prompt) // page)))
+                self._wbase_after(-(-len(prompt) // page)), snap)
             return np.asarray(row, np.float32), hit
         if hit > 0:
             first_live = self._first_live(hit)
@@ -2782,8 +2933,10 @@ class ServingEngine:
                              n * self.k_pages.shape[0] * self._latent,
                              "index_pages_offloaded":
                              n * len(self._index_layers)})
+        two = {"full_pages": n, "window_pages": nw} if self._win_layers \
+            else {}
         with self._span("istpu.cache.offload", rid, reason=reason, pages=n,
-                        bytes=nbytes, padded_pages=0, puts=0,
+                        bytes=nbytes, padded_pages=0, puts=0, **two,
                         **self._kinds_field, **self._snapshot_fields) as f:
             if not self._upload_room(nbytes):
                 return None
@@ -3481,7 +3634,20 @@ class ServingEngine:
             self._count_selected(active, df, more)
         sparse = self._experts_held > 0 or self._selects
         pulled = ()  # in place of nxt_dev, where the step counts experts
-        if self._win_layers:
+        if self._borrowers:
+            # live rows of the shared pages x the layers that borrow them
+            df["shared_rows"] = self._borrowers * sum(
+                s.seq_len + more + 1 for _, s in active)
+            self.stats["shared_kv_rows_read"] += df["shared_rows"]
+        if self._win_layers and self.state is not None:
+            (logits, nxt_dev, lens_next, self.k_pages, self.v_pages,
+             self.wk_pages, self.wv_pages, self.state) = _decode_fused_wf_st(
+                self.params, self.cfg, token_dev, lens_dev,
+                self.k_pages, self.v_pages, self.wk_pages,
+                self.wv_pages, self.state, rows_dev, model=self.model,
+            )
+            self._copy_boundaries(active, more)
+        elif self._win_layers:
             (logits, nxt_dev, lens_next, self.k_pages, self.v_pages,
              self.wk_pages, self.wv_pages, *pulled) = _decode_fused_wf(
                 self.params, self.cfg, token_dev, lens_dev,

@@ -156,6 +156,8 @@ def benchmark_tests_pinned_before_pr26(request, monkeypatch):
     module = getattr(request, "module", None)
     if module is None:
         return
+    if _phi_in_the_pinned_tests(node, name, module, monkeypatch):
+        return
     if _state_in_the_pinned_tests(node, name, module, monkeypatch):
         return
     if _gap_by_cause_in_the_pinned_tests(node, name, module, monkeypatch):
@@ -221,13 +223,15 @@ def benchmark_tests_pinned_before_pr26(request, monkeypatch):
         # dense layers, its two per-layer lists, the experts HELD, the
         # vocabulary's slice, the prediction module: no width);
         # keye-vl2-30b-a3b's by tests/benchmark/test_bench_keye.py
-        # (the depth alone).
+        # (the depth alone); phi4-mini-flash's by
+        # tests/benchmark/test_bench_phi4flash.py (it reduces nothing).
         bench = dict(module.BENCH)
         bench["configs"] = [c for c in bench["configs"]
                             if c["name"] not in ("granite4h-micro",
                                                  "smallthinker21b",
                                                  "xing4-29b",
-                                                 _COMMAND_A, _GLM, _KEYE)]
+                                                 _COMMAND_A, _GLM, _KEYE,
+                                                 _PHI)]
         monkeypatch.setattr(module, "BENCH", bench)
         return
     if module.__name__.endswith("test_bench_observations") \
@@ -310,6 +314,9 @@ def _counters_and_numbers_by_hand(module, monkeypatch, by_hand):
 # The tests that hold their PR's entries to be the LAST of
 # BENCHMARK.json's lists (the fourth is PR 51's own).
 _HELD_TO_BE_LAST = (
+    # (PR 53's own, test_bench_phi4flash.py's "... its_two_metrics ...",
+    # is not among them: it also holds the older readers' lists, which
+    # the hooks below would take away)
     "test_the_cell_its_configuration_and_its_metrics_are_in_the_manifest",
     "test_the_cell_its_configuration_and_its_metric_are_in_the_manifest",
     "test_the_new_metrics_are_in_the_manifest_and_it_is_sound",
@@ -395,6 +402,88 @@ def _gap_by_cause_in_the_pinned_tests(node, name, module, monkeypatch):
 
     _counters_and_numbers_by_hand(module, monkeypatch, by_hand)
     monkeypatch.setattr(profiling, "spans", lambda: by_hand.RING)
+    return True
+
+
+_PHI, _PHI_CELL = "phi4-mini-flash", "phi4-mini-flash-traces12k"
+
+
+def _as_before_pr53(bench):
+    """The manifest without what PR 53 appended: the configuration
+    phi4-mini-flash, its cell, its two per-layer metrics and the cell's
+    name on the older metrics' lists."""
+    bench["workloads"] = [w for w in bench["workloads"]
+                          if w["name"] != _PHI_CELL]
+    bench["configs"] = [c for c in bench["configs"] if c["name"] != _PHI]
+    bench["per_layer"] = [m for m in bench["per_layer"]
+                          if m.get("workloads") != [_PHI_CELL]]
+    for m in bench["per_layer"]:
+        if _PHI_CELL in m.get("workloads", ()):
+            m["workloads"].remove(_PHI_CELL)
+    return bench
+
+
+def _phi_in_the_pinned_tests(node, name, module, monkeypatch):
+    """PR 53 (`model_config`: may add benchmark files, edit none) added
+    the configuration phi4-mini-flash and two per-layer metrics; as
+    `_keye_in_the_pinned_tests` for PR 49's. Returns True where it
+    dealt with the test.
+
+    - the tests that hold an earlier PR's entries to be the LAST of
+      BENCHMARK.json's lists are shown the manifest without what this
+      PR appended. That is done FIRST and returns False: the hooks
+      below then take away what lies between their PR and this one;
+    - test_bench_observations.py's table test gets the two new
+      metrics' hand-worked numbers from tests/benchmark/phi_by_hand.py
+      (one reads the device time under a scope, one the window's
+      counters), and the configuration's cases of "resolves to today's
+      defaults" are skipped (it names a costs module, tolerances and
+      programs of its own, which tests/benchmark/
+      test_bench_phi4flash.py holds)."""
+    import pytest
+
+    pinned = name in _HELD_TO_BE_LAST or (
+        # ... and PR 51's test of the cells each gap metric lists
+        module.__name__.endswith("test_bench_gap_by_cause")
+        and name == "test_the_metric_is_in_the_manifest_on_its_cells")
+    if pinned and "test_bench_" in module.__name__ \
+            and not module.__name__.endswith("test_bench_phi4flash"):
+        load = module.manifest.load
+        monkeypatch.setattr(module.manifest, "load",
+                            lambda *a, **kw: _as_before_pr53(load(*a, **kw)))
+        return False
+    if not module.__name__.endswith("test_bench_observations"):
+        return False
+    params = getattr(getattr(node, "callspec", None), "params", {})
+    if name == "test_an_accepted_configuration_resolves_to_todays_defaults":
+        if params.get("config") == _PHI:
+            pytest.skip("phi4-mini-flash brings its own costs and "
+                        "tolerances: test_bench_phi4flash.py")
+        return False
+    if name != "test_reader_gives_the_number_worked_by_hand":
+        return False
+    import phi_by_hand as by_hand
+
+    if params.get("name") not in by_hand.BY_HAND:
+        return False
+    from benchmark.lib import serve
+    from benchmark.metrics import _scoped_ops
+
+    table, window = module.expected, module.full_window
+
+    def full_window():
+        obs = window()
+        obs.counters.update(by_hand.COUNTERS)
+        obs.conf = serve.load_config(
+            "benchmark/configs/phi4-mini-flash.json")
+        return obs
+
+    monkeypatch.setattr(module, "full_window", full_window)
+    monkeypatch.setattr(module, "expected",
+                        lambda obs: {**table(window()), **by_hand.BY_HAND})
+    monkeypatch.setattr(
+        _scoped_ops, "seconds",
+        lambda obs, kind, scopes: by_hand.SCOPED[kind, tuple(scopes)])
     return True
 
 

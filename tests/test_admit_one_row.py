@@ -1,9 +1,10 @@
 """The admission programs' one-row head (PR 48): every family's cold and
 prefix admission asks its model's prefill to keep ONE position
 (`decoder.forward_stack`'s `keep`), so the final norm and the head run
-on the last real position alone. Held here for all seven families and
-all six programs (`serving._admit_fused`, `_admit_fused_px`, their
-`_st` and `_wf` twins), over the arguments `tools/jaxpr_hashes.py`
+on the last real position alone. Held here for all eight families and
+all eight programs (`serving._admit_fused`, `_admit_fused_px`, their
+`_st`, `_wf` and `_wf_st` twins; models/phi_flash.py cuts its rows
+below its last 2 layers, which the same comparisons hold), over the arguments `tools/jaxpr_hashes.py`
 builds for a tiny engine of each family.
 
 A file of its own: the driver's `--dist loadfile` gives a file to one
@@ -21,7 +22,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-FAMILIES = ["llama", "moe", "smallthinker", "xing", "cohere", "glm", "hybrid"]
+FAMILIES = ["llama", "moe", "smallthinker", "xing", "cohere", "glm", "hybrid",
+            "phi_flash"]
 # (s_real, s_pad): pads in the last page, none, one real token
 S_REAL = {"short_of_the_pad": (30, 32), "whole_pad": (32, 32),
           "one_token": (1, 16)}
@@ -132,11 +134,14 @@ def _prefix_arguments(family, prefix_tokens, tokens, s_real):
                    or n in ("wk", "wv", "state", "bstate")},
           "tokens": tokens, "s_real": jnp.int32(s_real)}
     if "wr_ids" in kw:  # two kinds of attention layer
-        wk, wv, _ = more
+        wk, wv, *more = more
         kw["restored"] = jnp.concatenate([_page_major(two, k, v),
                                           _page_major(two, wk, wv)])
         kw["s_ids"] = kw["s_ids"].at[:2].set(two + 2)
         kw["ws_ids"] = kw["ws_ids"].at[:2].set(two + 2)
+        if "snap" in kw:  # ... and state layers beside them: the
+            # cold program's last output is the boundary copies
+            kw["snap"] = serving._state_rows(cfg, more[-1], 0)
         return kw
     kw["suffix_ids"] = kw["suffix_ids"].at[:2].set(two + 2)
     if "snap" in kw:  # state layers: the state at the prefix's end

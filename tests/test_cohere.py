@@ -424,6 +424,14 @@ def test_cold_admission_and_24_decoded_tokens(cfg, params):
     assert sorted(eng.free_pages) == list(range(1, 96))
 
 
+def test_the_probe_asks_for_layer_zero_though_it_is_banded(cfg, params):
+    """The first attention layers are banded here; the probed key of a
+    page is layer 0's all the same (only a slot of three kinds probes
+    its one full layer: serving.py `_probe_kinds`)."""
+    assert cfg.layer_bands[0] > 0
+    assert _engine(params, cfg)._probe_kinds == [(0, "k")]
+
+
 def test_under_the_band_and_past_it_in_one_engine(cfg, params, shm_conn):
     """Two sessions side by side, one that never leaves the band (its
     window layers keep every page: first_live 0) and one 4 bands long

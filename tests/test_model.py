@@ -760,6 +760,76 @@ def test_decode_program_for_the_chip_holds_no_layer_of_the_pool(
         ma.temp_size_in_bytes, one_layer_and_kind)
 
 
+def test_decode_program_of_three_kinds_for_the_chip_copies_no_pool(
+        v5e_chip, monkeypatch):
+    """`_decode_fused_wf_st` at Phi-4-mini-flash's attention and state
+    widths (40 query heads over 10 packed rows of 128 lanes a token,
+    a page as FLAT ROWS [160, 128]; a state of 16 x 5,120 a layer),
+    compiled for a described v5e. 10 rows a token are no multiple of
+    the 8 a tile holds: as a 5-D pool the page lay padded to 16 rows
+    and every kernel call moved its layer whole (3.8 GB of temporaries
+    in the real program, PERF.md PR 53). Held here: no `copy`,
+    `copy-start` or `pad` of a page pool's shape in the text, one Pallas
+    call an attention layer (banded, full and BORROWING alike: the
+    cross layer reads the full pool where it lies) and one a state
+    layer, the pools aliased, temporaries under a MB a slot."""
+    import re
+
+    from infinistore_tpu import serving
+    from infinistore_tpu.models import hf, phi_flash
+
+    cfg = hf.phi4flash_config_from_hf(SimpleNamespace(
+        vocab_size=256, hidden_size=2560, intermediate_size=256,
+        num_hidden_layers=8, num_attention_heads=40,
+        num_key_value_heads=20, sliding_window=512, mb_per_layer=2,
+        layer_norm_eps=1e-5, tie_word_embeddings=True,
+        max_position_embeddings=16384), page_size=16, dtype="bfloat16")
+    assert cfg.kv_page_shape() == (160, 128) and cfg.page_rows == 10
+    slots, total, table, entries = 16, 4096, 192, 40
+    weights = jax.eval_shape(lambda k: phi_flash.init_params(k, cfg),
+                             jax.ShapeDtypeStruct((2,), jnp.uint32))
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e_chip)
+
+    def described(tree):
+        return jax.tree_util.tree_map(lambda x: sds(x.shape, x.dtype), tree)
+
+    i32 = jnp.int32
+    pool = sds((1, total, 160, 128), cfg.jdtype)
+    wpool = sds((2, slots * entries + 1, 160, 128), cfg.jdtype)
+    state = described(jax.eval_shape(
+        lambda: phi_flash.state_pools(cfg, slots)))
+    lens = sds((slots,), i32)
+    with _the_chips_branch(monkeypatch):
+        lowered = serving._decode_fused_wf_st.lower(
+            described(weights), cfg, lens, lens, pool, pool, wpool, wpool,
+            state, (sds((slots, table), i32), sds((slots, entries), i32),
+                    lens), model=phi_flash)
+        # a function a pool layer: 2 banded, 1 full, which the cross
+        # layer's call IS (the same pool, layer and shapes); the 3 state
+        # layers' one (ops/ssm.py `selective_step_kernel`)
+        assert lowered.as_text().count("tpu_custom_call") == 3 + 1
+        compiled = lowered.compile()
+    text = compiled.as_text()
+    for shape in (f"bf16[1,{total},160,128]", f"bf16[{total},160,128]",
+                  f"bf16[2,{slots * entries + 1},160,128]",
+                  f"f32[{slots},16,5120]"):
+        moved = re.findall(r"= \(?" + re.escape(shape)
+                           + r"[^=]*? (copy|copy-start|pad)\(", text)
+        if shape.startswith("f32"):
+            # a state pool is 5 MB: the compiler may stage it whole in
+            # fast memory around its kernel (copy-start into and out of
+            # memory space 1), which is no second copy in HBM
+            moved = [m for m in moved if m != "copy-start"]
+        assert not moved, (shape, moved)
+    ma = compiled.memory_analysis()
+    pools = 2 * (total + 2 * (slots * entries + 1)) * 160 * 128 * 2
+    states = slots * 3 * (16 + 3) * 5120 * 4
+    assert ma.alias_size_in_bytes >= (pools + states) * 0.99
+    assert ma.temp_size_in_bytes < slots << 20, ma.temp_size_in_bytes
+
+
 @pytest.mark.parametrize("form", ["rows", "planted"])
 def test_decode_program_for_the_chip_copies_no_state_pool(form, v5e_chip,
                                                           monkeypatch):

@@ -253,6 +253,9 @@ def test_two_pools_sized_from_the_band(cfg, params):
     entries = 8                                   # 4 + 2, rounded to 8
     assert eng.wtable.shape == (3, entries)
     assert eng.wk_pages.shape[:2] == (L_WIN, 3 * entries + 1)
+    # a hit's probe asks for layer 0's key of a page, as every family
+    # of one or two kinds of page did before a third kind came
+    assert eng._probe_kinds == [(0, "k")]
     # a model of one kind holds one pool and no short table
     one = ServingEngine(llama.init_params(jax.random.PRNGKey(0),
                                           llama.LlamaConfig()),

@@ -6,7 +6,9 @@ latent row (xing, since PR 42) and the one that keeps index keys
 beside it on some layers (glm, since PR 46) and the one that keeps
 index keys beside K and V on every layer (keye, since PR 49), at tiny
 widths; the prefix admission of the families with state layers or two
-kinds of attention layer since PR 48 (29 programs). A change that must
+kinds of attention layer since PR 48 (29 programs); the one with both
+and layers that borrow another layer's cache (phi_flash, since PR 53:
+32 programs). A change that must
 leave their programs alone is checked by running this in both trees and
 comparing the output (PR 40: the parent unpacked under build/parent):
 
@@ -43,6 +45,10 @@ FAMILIES = {
         index_topk=16, n_experts=2, top_k=2, n_routed=8, first_expert=2)),
     "keye": ("keye", "KeyeConfig", dict(
         n_layers=3, n_kv_heads=2, index_topk=16, n_experts=8, top_k=2)),
+    "phi_flash": ("phi_flash", "PhiFlashConfig", dict(
+        n_layers=6, layer_types=("mamba1", "attention", "mamba1",
+                                 "attention", "gmu", "cross"),
+        layer_bands=(0, 32, 0, 0, 0, 0))),
 }
 
 
@@ -81,6 +87,41 @@ def programs(name, wrap=None, **more):
     # has neither the attribute nor the argument)
     counts = {"fetched": True} if getattr(eng, "_experts_held", 0) else {}
     out = {}
+    if eng._win_layers and eng.state is not None:
+        # three kinds in one slot: both pairs of pools, the state pools
+        # and their boundary copies
+        pools = (eng.k_pages, eng.v_pages, eng.wk_pages, eng.wv_pages,
+                 eng.state, eng.bstate)
+        wids = jnp.asarray(np.full(
+            eng._wtable_w, eng._wpool_pages, np.int32))[:8]
+        out["cold"] = (
+            lambda params, tokens, k_pages, v_pages, wk, wv, state, bstate,
+            ids, wids, s_real, slot: serving._admit_fused_wf_st.__wrapped__(
+                params, cfg, tokens, k_pages, v_pages, wk, wv, state,
+                bstate, ids, wids, s_real, slot, model, 0),
+            (params, toks, *pools, ids, wids, jnp.int32(30), jnp.int32(0)))
+        n_win = eng.wk_pages.shape[0]
+        restored = jnp.zeros((2 * (L + n_win) * 2, *cfg.kv_page_shape()),
+                             cfg.jdtype)
+        snap = jnp.zeros((cfg.n_state_layers,
+                          serving._snapshot_row_elems(cfg)),
+                         cfg.state_jdtype)
+        out["prefix"] = (
+            lambda params, tokens, restored, snap, k_pages, v_pages, wk, wv,
+            state, bstate, r_ids, wr_ids, s_ids, ws_ids, s_real, slot:
+            serving._admit_fused_px_wf_st.__wrapped__(
+                params, cfg, tokens, restored, snap, k_pages, v_pages, wk,
+                wv, state, bstate, r_ids, wr_ids, s_ids, ws_ids, s_real,
+                slot, model, 0),
+            (params, toks, restored, snap, *pools, two, two, ids, wids,
+             jnp.int32(30), jnp.int32(0)))
+        out["decode"] = (
+            lambda p, t, s, k, v, wk, wv, st, r:
+            serving._decode_fused_wf_st.__wrapped__(
+                p, cfg, t, s, k, v, wk, wv, st, r, model),
+            (params, slots, slots, *pools[:5],
+             (rows, jnp.zeros((2, eng._wtable_w), i32), slots)))
+        return out
     if eng._win_layers:
         pools = (eng.k_pages, eng.v_pages, eng.wk_pages, eng.wv_pages)
         wids = jnp.asarray(np.full(

@@ -11,8 +11,11 @@ once more with the benchmark's own `benchmark/metrics/_scoped_ops.py`:
 every device operation inside a run of the configuration's "decode"
 programs, and of its "prefill" (admission) programs, in the traced
 window, filed under the innermost stage name of its `op_name` (`embed`,
-`attn.*`, `mlp`, `moe.*`, `ssm.*`, `hc.*`, `pool.update`, `lm_head`),
-or under "no scope". One `step_by_scope:` JSON line on stderr beside
+`attn.*`, `mlp`, `moe.*`, `ssm.*`, `hc.*`, `pool.update`, `lm_head`;
+since PR 53 `ssm.gmu`, `attn.diff` and `attn.kernel.cross` among them,
+the Gated Memory Units, the differential maps' subtraction and norm,
+and the layers that attend another layer's pages), or under "no
+scope". One `step_by_scope:` JSON line on stderr beside
 the run's own lines, for each of the two kinds: runs, mean ms a run, ms
 a run by stage, and the unscoped operations that took most (a weight's
 `copy` shows there by name, as in the ledger's

@@ -30,6 +30,13 @@ scatter that XLA does not do in place shows there before any timing).
 state needs, over the time. One JSON line a case; `--out` also writes
 them to a file (under chiprun_out/ on the chip).
 
+`--recurrence selective` (PR 53) times the other recurrence the tree
+has, Mamba-1's selective step (ops/ssm.py `selective_step`), at
+phi4-mini-flash's shapes: 36 pools `h` [16, 16, 5120] float32 (5.2 MB
+each, 328 KB a slot), x and dt a channel, by `whole` (every slot in
+XLA) and `kernel` (`selective_step_kernel`: a grid of the decoding
+slots, a slot's state whole a step, the pool aliased).
+
   chiprun -- python3 tools/time_state_step.py --out chiprun_out/state_step.jsonl
 """
 
@@ -43,6 +50,49 @@ import time
 
 SLOTS, HEADS, HEAD_DIM, STATE, LAYERS = 16, 64, 64, 128, 36
 DECODING = (1, 2, 3, 4, 8, 16)
+
+
+SELECTIVE_STATE, SELECTIVE_CHANNELS = 16, 5120
+
+
+def selective_operands():
+    """`operands` for the selective step: h [slots, N, C], x and dt a
+    channel, A [N, C]."""
+    import jax
+    import jax.numpy as jnp
+
+    ks = jax.random.split(jax.random.PRNGKey(0), 6)
+    f32 = jnp.float32
+    n, c = SELECTIVE_STATE, SELECTIVE_CHANNELS
+    return {
+        "h": [jax.random.normal(k, (SLOTS, n, c), f32)
+              for k in jax.random.split(ks[0], LAYERS)],
+        "x": jax.random.normal(ks[1], (SLOTS, c), jnp.bfloat16),
+        "dt": jax.nn.softplus(jax.random.normal(ks[2], (SLOTS, c), f32)),
+        "A": -jnp.exp(jax.random.normal(ks[3], (n, c), f32)),
+        "B": jax.random.normal(ks[4], (SLOTS, n), jnp.bfloat16),
+        "C": jax.random.normal(ks[5], (SLOTS, n), jnp.bfloat16),
+    }
+
+
+def selective_forms(ssm):
+    """name: fn(h, x, dt, A, B, C, rows) -> (y, h), the selective
+    step's."""
+    import jax
+    import jax.numpy as jnp
+
+    def whole(h, x, dt, A, B, C, rows):
+        return ssm.selective_step(h, x, dt, A, B, C)
+
+    def kernel(h, x, dt, A, B, C, rows):
+        f32 = jnp.float32
+        with jax.named_scope("ssm.step"):
+            y, h = ssm.selective_step_kernel(
+                h, x.astype(f32), dt, A, B.astype(f32), C.astype(f32),
+                *rows[1:])
+            return jnp.where(rows[0][:, None], y, 0.0), h
+
+    return {"whole": whole, "kernel": kernel}
 
 
 def operands():
@@ -108,7 +158,7 @@ def pool_copies(fn, ops, rows):
     text = jax.jit(fn, donate_argnums=0).lower(
         ops["h"][0], *(ops[k] for k in ("x", "dt", "A", "B", "C")),
         rows).compile().as_text()
-    shape = "f32[%d,%d,%d,%d]" % (SLOTS, HEADS, HEAD_DIM, STATE)
+    shape = "f32[%s]" % ",".join(map(str, ops["h"][0].shape))
     return len(re.findall(
         r"= \(?" + re.escape(shape) + r"[^=]* copy(-start)?\(", text))
 
@@ -146,6 +196,8 @@ def main():
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--reps", type=int, default=4)
     ap.add_argument("--rounds", type=int, default=3)
+    ap.add_argument("--recurrence", choices=("mamba2", "selective"),
+                    default="mamba2")
     ap.add_argument("--out")
     args = ap.parse_args()
     sys.path.insert(0, os.path.dirname(os.path.dirname(
@@ -159,9 +211,11 @@ def main():
 
     if jax.default_backend() != "tpu":
         sys.exit("time_state_step: no TPU; a CPU time is not a device time")
-    ops = operands()
-    todo = forms(ssm, ss)
-    slot_bytes = HEADS * HEAD_DIM * STATE * 4
+    if args.recurrence == "selective":
+        ops, todo = selective_operands(), selective_forms(ssm)
+    else:
+        ops, todo = operands(), forms(ssm, ss)
+    slot_bytes = ops["h"][0][0].nbytes
     lines = []
     for name, fn in todo.items():
         for n in DECODING:
@@ -176,7 +230,8 @@ def main():
                        "pool_copies": pool_copies(fn, ops, rows)}
             except Exception as e:  # a form the compiler refuses
                 row = {"error": f"{type(e).__name__}: {str(e)[:300]}"}
-            row = {"form": name, "decoding": n, **row,
+            row = {"recurrence": args.recurrence, "form": name,
+                   "decoding": n, **row,
                    "device": jax.devices()[0].device_kind}
             lines.append(row)
             print(json.dumps(row), flush=True)
