@@ -2,8 +2,9 @@
 GLM-5.2's `index_*` keys, Keye-VL-2.0's `sa_config`): index scores
 over a sequence's index keys, the EXACT top-k of them, and attention
 over the rows the selection names and no other. Plain jax.numpy on
-every backend (the gathers are XLA's) but for the bisection, which on a
-TPU is one Pallas call.
+every backend (the gathers are XLA's) but for the bisection and an
+admission's attention under a mask, each of which on a TPU is one
+Pallas call.
 
     I(t, s) = sum_j w(t, j) relu(qI_j(t) . kI(s))        s <= t
     S(t)    = the min(t + 1, k) positions of largest I(t, .)
@@ -34,9 +35,15 @@ sequence over the paged pools through the page table (`*_paged` under
 or gathered for), and an admission, a block of queries at a time over
 the contiguous rows of prefix + suffix (`*_seq`). An admission over K
 and V rows does not gather and makes no positions: a block's mask over
-the contiguous rows is what it attends under (`select_attend_seq`): two
-matmuls over every row are cheaper there than two gathers of the
-selected ones (PERF.md, PR 49).
+the contiguous rows is what it attends under (`select_attend_seq`). On
+a TPU that attention is ONE Pallas call a block, the flash kernel with
+the mask an operand (ops/pallas_masked_attention.py, through
+`block_attention`): a block's logits never leave the chip and the key
+tiles past the block's last position are not run, so a block by two
+products over every live row (17 x the selection's FLOPs at 35k rows,
+at half the peak) costs a quarter of a block by two gathers of the
+selected ones (PERF.md, PRs 49 and 54). Elsewhere `attend_masked`, the
+same sums in XLA, which is also what the kernel is tested against.
 """
 
 import functools
@@ -44,6 +51,8 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from .pallas_masked_attention import by_head, masked_flash_attention
 
 F32 = jnp.float32
 NEG = -1e30
@@ -294,6 +303,23 @@ def attend_masked(q, k_rows, v_rows, mask, scale):
     return _attend_by_group(q, k_rows, v_rows, mask, scale, "sgd")
 
 
+def block_attention(k_rows, v_rows, scale):
+    """`attend_masked` over the rows [S, G, hd] of one sequence for
+    block after block of its queries: fn(q, mask, live_rows), `mask`
+    keeping no row at or past `live_rows` (a traced count: the block's
+    last position + 1). On TPU backends ONE Pallas call a block, the
+    flash form with the mask an operand (ops/pallas_masked_attention.py:
+    no [n, H, S] logits in HBM, no key tile that starts at or past
+    `live_rows`), over rows relaid by kv head ONCE here; `attend_masked`
+    in XLA elsewhere."""
+    if jax.default_backend() == "tpu":
+        k_heads, v_heads = by_head(k_rows), by_head(v_rows)
+        return lambda q, mask, live_rows: masked_flash_attention(
+            q, k_heads, v_heads, mask, live_rows, scale=scale)
+    return lambda q, mask, live_rows: attend_masked(q, k_rows, v_rows, mask,
+                                                    scale)
+
+
 # ---- a decode step: one query a sequence, over the paged pools ---------
 
 
@@ -435,6 +461,9 @@ def select_attend_seq(q, w, keys, positions, qa, k_rows, v_rows, k, scale,
     Returns (None, out [s, H, hd]); `with_positions` (a selection
     somebody reads: decoder.selection_tap): `select`'s pair [s, k']
     first, of the same mask."""
+    with jax.named_scope("attn.kernel"):
+        attend = block_attention(k_rows, v_rows, scale)
+
     def one(qb, wb, pos, qab):
         with jax.named_scope("attn.index"):
             scores = _scores(qb, wb, keys, "qhd,sd->qhs")
@@ -445,6 +474,6 @@ def select_attend_seq(q, w, keys, positions, qa, k_rows, v_rows, k, scale,
         with jax.named_scope("attn.topk"):
             sel = positions_of(mask, k) if with_positions else None
         with jax.named_scope("attn.kernel"):
-            return sel, attend_masked(qab, k_rows, v_rows, mask, scale)
+            return sel, attend(qab, mask, jnp.max(pos) + 1)
 
     return _blocked(one, q.shape[0], q, w, positions, qa)

@@ -23,7 +23,7 @@ from test_admit_one_row import _equations
 from benchmark.reference import keye_dsa as reference
 from infinistore_tpu import serving
 from infinistore_tpu.models import decoder, hf, keye, moe
-from infinistore_tpu.ops import sparse_select
+from infinistore_tpu.ops import pallas_masked_attention, sparse_select
 from infinistore_tpu.serving import Request, ServingConfig, ServingEngine
 from infinistore_tpu.tpu import TpuKVStore
 from infinistore_tpu.utils import profiling
@@ -360,6 +360,153 @@ def test_attention_under_the_mask_is_attention_over_the_gathered_rows():
     b = sparse_select.attend_grouped(q, k[idx], v[idx], taken, 0.25)
     assert np.abs(np.asarray(a)).max() > 0.1
     assert np.abs(np.asarray(a) - np.asarray(b)).max() < 1e-5
+
+
+def _first_tiles_empty(rng):
+    """Scores that rise with the position: a query at position t takes
+    the LAST 32 of its t + 1 live rows. Rows 0-31 stand at 300 or
+    later, so none keeps a key of the first two tiles of 128 (their
+    running maximum is NEG through both); rows 32-63 stand under 128
+    and keep nothing after the first tile."""
+    pos = np.concatenate([rng.integers(300, 512, 32),
+                          rng.integers(0, 128, 32)]).astype(np.int32)
+    return np.tile(np.arange(512, dtype=np.float32), (64, 1)), pos + 1, 128
+
+
+def _rows_not_a_tile(rng):
+    """300 rows at 128 a tile: the third tile lies across the end."""
+    return (rng.standard_normal((64, 300)).astype(np.float32),
+            rng.integers(1, 301, 64).astype(np.int32), 128)
+
+
+def _under_topk(rng):
+    """24 queries (not a multiple of a tile of the mask's sublanes) at
+    positions under `topk`: every live row is kept."""
+    return (rng.standard_normal((24, 200)).astype(np.float32),
+            rng.permutation(TOPK)[:24].astype(np.int32) + 1, 128)
+
+
+MASKED_BLOCKS = {
+    "rows_not_a_multiple_of_the_tile": _rows_not_a_tile,
+    "first_tiles_hold_nothing_selected": _first_tiles_empty,
+    "queries_under_topk_positions": _under_topk,
+    "few_values": lambda rng: (
+        *(a[:64] for a in test_glm.SELECTION_ROWS["few_values"](rng)[:2]),
+        128),
+    "ties_at_the_kth_of_35072": lambda rng: (
+        *test_glm.SELECTION_ROWS["ties_at_the_kth_of_35072"](rng)[:2], 4096),
+}
+
+
+@pytest.mark.parametrize("block", list(MASKED_BLOCKS))
+def test_the_chips_flash_kernel_sums_what_attend_masked_sums(block):
+    """`masked_flash_attention` (what `block_attention` runs on a TPU
+    backend, here in interpret mode) against `attend_masked` in XLA
+    over one block of queries under `taken_mask`'s mask of `block`'s
+    scores: the same rows attended, the same sums, and the key tiles
+    past the block's last position not run."""
+    rng = np.random.default_rng(5)
+    scores, n_live, block_k = MASKED_BLOCKS[block](rng)
+    k_sel = 2048 if scores.shape[1] > 4096 else TOPK
+    n, s = scores.shape
+    h, g, hd = 8, 2, 16
+    q, k, v = (jnp.asarray(rng.standard_normal(shape), jnp.float32)
+               for shape in [(n, h, hd), (s, g, hd), (s, g, hd)])
+    mask = sparse_select.taken_mask(jnp.asarray(scores), jnp.asarray(n_live),
+                                    k_sel)
+    assert np.array_equal(np.asarray(mask).sum(-1),
+                          np.minimum(n_live, k_sel))
+    want = sparse_select.attend_masked(q, k, v, mask, 0.25)
+    by_head = pallas_masked_attention.by_head
+    got = pallas_masked_attention.masked_flash_attention(
+        q, by_head(k), by_head(v), mask, jnp.asarray(n_live.max()),
+        scale=0.25, block_k=block_k, interpret=True)
+    assert got.shape == want.shape and got.dtype == want.dtype
+    assert np.abs(np.asarray(want)).max() > 0.1
+    assert np.abs(np.asarray(got) - np.asarray(want)).max() < 1e-5
+    run, of = pallas_masked_attention.tiles_run(s, int(n_live.max()), block_k)
+    assert run == -(-int(n_live.max()) // block_k) <= of == -(-s // block_k)
+    # rows of V past the last live tile are never read: poison them
+    if run < of:
+        poisoned = v.at[run * block_k:].set(jnp.nan)
+        again = pallas_masked_attention.masked_flash_attention(
+            q, by_head(k), by_head(poisoned), mask,
+            jnp.asarray(n_live.max()), scale=0.25, block_k=block_k,
+            interpret=True)
+        assert np.array_equal(np.asarray(again), np.asarray(got))
+
+
+def test_a_padded_block_through_the_kernel_is_the_xla_admission(
+        cfg, monkeypatch):
+    """`kv_selected_prefill` over 80 queries (a block of 64 and one of
+    16 padded up with copies of query 0, `_blocked`) of which the first
+    stand under `topk`: the program a chip traces, its kernels
+    interpreted, gives what the XLA forms give; the block's count of
+    live rows is its LAST query's position + 1."""
+    rng = np.random.default_rng(3)
+    s, n = 150, 80
+    args = [jnp.asarray(rng.standard_normal(shape), jnp.float32)
+            for shape in [(1, n, 4, 16), (1, n, 4), (1, s, 16),
+                          (1, n, 8, 16), (1, s, 2, 16), (1, s, 2, 16)]]
+    at = jnp.arange(s - 70 - n, s - 70)[None]      # 0 .. 79: under 150
+
+    def admit(qi, wi, keys, q, k_all, v_all):
+        return decoder.kv_selected_prefill(cfg, q, k_all, v_all, qi, wi,
+                                           keys, at)
+
+    want = np.asarray(admit(*args))
+    # the forms a TPU backend traces, their kernels interpreted (steered
+    # here: the program has no option for it)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    for name in ("masked_flash_attention", "_kth_key_kernel"):
+        monkeypatch.setattr(sparse_select, name, partial(
+            getattr(sparse_select, name), interpret=True))
+    got = np.asarray(admit(*args))
+    assert np.abs(want).max() > 0.1
+    assert np.abs(got - want).max() < 1e-5
+
+
+def test_an_admission_on_a_chip_keeps_its_logits_in_the_kernel(
+        cfg, monkeypatch):
+    """The jaxpr of `kv_selected_prefill` as a TPU backend traces it
+    holds the attention's `pallas_call` under `attn.kernel` and, outside
+    it, no float32 array as large as a block's logits [block, H, S]
+    (the index scores [block, Hi, S] are half of that here); off the
+    chip the logits are there and no kernel is."""
+    rng = np.random.default_rng(3)
+    s, n = 384, 128
+    args = [jnp.asarray(rng.standard_normal(shape), jnp.float32)
+            for shape in [(1, n, 4, 16), (1, n, 4), (1, s, 16),
+                          (1, n, 8, 16), (1, s, 2, 16), (1, s, 2, 16)]]
+    at = jnp.arange(s - n, s)[None]
+    logits = sparse_select.QUERY_BLOCK * 8 * s
+
+    def walk(jaxpr, scope, kernels, wide):
+        # (an equation's name stack is counted from its own jaxpr's)
+        for eqn in jaxpr.eqns:
+            here = f"{scope}/{eqn.source_info.name_stack}"
+            if eqn.primitive.name == "pallas_call":
+                kernels.append(here.rstrip("/").rsplit("/", 1)[-1])
+                continue
+            wide += [out.aval.shape for out in eqn.outvars
+                     if getattr(out.aval, "dtype", None) == jnp.float32
+                     and out.aval.size >= logits]
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                walk(sub, here, kernels, wide)
+        return kernels, wide
+
+    def traced():
+        return walk(jax.make_jaxpr(
+            lambda qi, wi, keys, q, k_all, v_all: decoder.kv_selected_prefill(
+                cfg, q, k_all, v_all, qi, wi, keys, at))(*args).jaxpr,
+            "", [], [])
+
+    kernels, wide = traced()
+    assert not kernels and (64, 2, 4, s) in wide
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    kernels, wide = traced()
+    assert not wide
+    assert kernels == ["attn.topk", "attn.kernel"]  # bisection, attention
 
 
 # -- a decode step -----------------------------------------------------------

@@ -989,6 +989,35 @@ def test_selection_for_the_chip_is_one_kernel_and_no_sort(rows, v5e_chip,
     assert " sort(" not in text and " while(" not in text
 
 
+@pytest.mark.parametrize("rows", [4096, 35072])
+def test_an_admissions_attention_for_the_chip_keeps_its_logits_on_it(
+        rows, v5e_chip, monkeypatch):
+    """`sparse_select.select_attend_seq` at keye-vl2-30b-a3b's widths
+    (16 index heads of 128 lanes, 32 query heads over 4 kv heads of
+    128, k 2,048), two blocks of 64 queries over `rows` contiguous rows
+    (35,072: not a multiple of the kernel's key tile), compiled for a
+    described v5e: Mosaic takes the attention's kernel at these shapes,
+    the program's two Pallas calls are the bisection and the attention,
+    and its temporaries are under ONE block's float32 logits [64, 32,
+    rows] (what the XLA form writes and reads back a block)."""
+    from infinistore_tpu.ops import sparse_select
+
+    def sds(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e_chip)
+
+    n = 128
+    with _the_chips_branch(monkeypatch):
+        compiled = jax.jit(lambda *a: sparse_select.select_attend_seq(
+            *a, k=2048, scale=128 ** -0.5)[1]
+        ).lower(sds((n, 16, 128)), sds((n, 16), jnp.float32),
+                sds((rows, 128)), sds((n,), jnp.int32), sds((n, 32, 128)),
+                sds((rows, 4, 128)), sds((rows, 4, 128))).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 2
+    assert " sort(" not in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 * 32 * rows * 4
+
+
 def _tool(name):
     """tools/<name>.py as a module."""
     import importlib.util

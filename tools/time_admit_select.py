@@ -22,12 +22,21 @@ suffix. The two forms the attention under the selection can take
              (what glm-5.2's admissions and an open tap add)
   masked     (ii) `taken_mask` of the scores and `attend_masked` over
              ALL S rows under it: no gather, 17 x the FLOPs at 35k
+  flash      (ii) on the chip since PR 54: `block_attention`'s call a
+             block over a GIVEN mask, ONE Pallas call
+             (ops/pallas_masked_attention.py) over K and V laid by kv
+             head (the relay, once a layer's 64 blocks, is not in the
+             row), `--block-k` keys a tile (a row for each);
+             `flash_half`: the block whose last query stands at S / 2,
+             so half the key tiles are dead (`key_tiles`: run, of the
+             rows')
   gathered   (i) the selected K and V rows gathered (2 x 64 x 2,048
              rows of 1 KB) and `attend_grouped` over them
   block_*    the whole block by each form (index + selection +
-             attention), as `select_attend_seq` runs (ii);
-             `block_masked_sorted`: (ii) as the parent ran it, the mask
-             read from a sort's last score taken
+             attention): `block_flash` as `select_attend_seq` runs (ii)
+             on the chip, `block_masked` as it runs it elsewhere and
+             ran it until PR 54; `block_masked_sorted`: (ii) as PR 49
+             ran it, the mask read from a sort's last score taken
 
 A piece of 4,096 tokens is 64 such blocks in each of 5 layers. The form
 NOT kept in the tree, (i), is composed here from the pieces the decode
@@ -40,6 +49,7 @@ to a file (under chiprun_out/ on the chip).
 """
 
 import argparse
+import functools
 import json
 import os
 import sys
@@ -67,11 +77,19 @@ def operands(s):
     }
 
 
-def stages(ss):
+def stages(ss, pm):
     import jax
     import jax.numpy as jnp
 
     scale = HEAD_DIM ** -0.5
+
+    def flash(qa, taken, pos, kt, vt, block_k=pm.BLOCK_K):
+        return pm.masked_flash_attention(qa, kt, vt, taken, jnp.max(pos) + 1,
+                                         scale=scale, block_k=block_k)
+
+    def block_flash(qi, w, pos, qa, keys, kt, vt):
+        return flash(qa, ss.taken_mask(index(qi, w, keys), pos + 1, TOPK),
+                     pos, kt, vt)
 
     def index(qi, w, keys):
         return ss._scores(qi, w, keys, "qhd,sd->qhs")
@@ -141,9 +159,13 @@ def stages(ss):
         "compact": (compact, ("taken",)),
         "attend_all_rows": (mask_only, ("qa", "scores", "pos", "k", "v")),
         "masked": (masked, ("qa", "scores", "pos", "k", "v")),
+        "flash": (flash, ("qa", "taken", "pos", "kt", "vt")),
+        "flash_half": (flash, ("qa", "taken_half", "pos_half", "kt", "vt")),
         "gathered": (gathered, ("qa", "idx", "k", "v")),
         "block_masked": (block_masked,
                          ("qi", "w", "pos", "qa", "keys", "k", "v")),
+        "block_flash": (block_flash,
+                        ("qi", "w", "pos", "qa", "keys", "kt", "vt")),
         "block_masked_sorted": (block_masked_sorted,
                                 ("qi", "w", "pos", "qa", "keys", "k", "v")),
         "block_gathered": (block_gathered,
@@ -186,19 +208,30 @@ def main():
                     help="widths of a bisection pass to time `kth_loop` "
                     "at, divisors of 32, e.g. 1,2,4 (default: the file's "
                     "_PASS_BITS)")
+    ap.add_argument("--block-k", default="",
+                    help="keys a tile to time `flash` at, e.g. "
+                    "512,1024,2048 (default: the kernel's BLOCK_K)")
+    ap.add_argument("--stages", default="",
+                    help="the stages to time, e.g. masked,flash "
+                    "(default: all)")
     ap.add_argument("--out")
     args = ap.parse_args()
     sys.path.insert(0, os.path.dirname(os.path.dirname(
         os.path.abspath(__file__))))
     import jax
 
+    from infinistore_tpu.ops import pallas_masked_attention as pm
     from infinistore_tpu.ops import sparse_select as ss
 
     if jax.default_backend() != "tpu":
         sys.exit("time_admit_select: no TPU; a CPU time is not a device "
                  "time")
-    todo = stages(ss)
+    todo = stages(ss, pm)
+    wanted = [n for n in args.stages.split(",") if n] or list(todo)
     widths = [int(b) for b in args.bits.split(",") if b] or [ss._PASS_BITS]
+    tiles = [int(b) for b in args.block_k.split(",") if b] or [pm.BLOCK_K]
+    swept = {"kth_loop": ("pass_bits", widths), "flash": ("block_k", tiles),
+             "flash_half": ("block_k", tiles)}
     lines = []
     for s in ROWS:
         ops = operands(s)
@@ -210,14 +243,23 @@ def main():
         ops["taken"] = jax.jit(todo["mask"][0])(ops["skeys"], ops["edge"],
                                                 ops["pos"])
         ops["idx"] = jax.jit(todo["compact"][0])(ops["taken"])
-        for name, (fn, takes) in todo.items():
+        ops["kt"], ops["vt"] = pm.by_head(ops["k"]), pm.by_head(ops["v"])
+        ops["pos_half"] = ops["pos"] - s // 2
+        ops["taken_half"] = ss.taken_mask(ops["scores"],
+                                          ops["pos_half"] + 1, TOPK)
+        for name in wanted:
+            fn, takes = todo[name]
+            key, values = swept.get(name, (None, [None]))
             kept = ss._PASS_BITS
-            swept = name == "kth_loop"
-            for bits in widths if swept else [kept]:
-                ss._PASS_BITS = bits    # read while a stage is traced
+            for value in values:
                 row = {"stage": name, "rows": s}
-                if swept:
-                    row["pass_bits"] = bits
+                if key == "pass_bits":
+                    ss._PASS_BITS = value   # read while a stage is traced
+                    row[key] = value
+                elif key == "block_k":
+                    fn = functools.partial(todo[name][0], block_k=value)
+                    row.update(block_k=value, key_tiles=pm.tiles_run(
+                        s, int(ops[takes[2]][-1]) + 1, value))
                 row.update(call_us=time_chain(
                     fn, [ops[k] for k in takes], args.reps, args.rounds),
                     device=jax.devices()[0].device_kind)
