@@ -82,7 +82,11 @@ def norm(cfg, x, w, b=None):
     `b` where the parameters hold one)."""
     if cfg.norm_center:
         return layer_norm(x, w, cfg.norm_eps, b)
-    return rms_norm(x, w, cfg.norm_eps, cfg.norm_plus_one)
+    out = rms_norm(x, w, cfg.norm_eps, cfg.norm_plus_one)
+    # over a float32 stream (`stream_open`) the sublayers still compute
+    # in the model's type
+    return out.astype(cfg.jdtype) if getattr(cfg, "fp32_stream", False) \
+        else out
 
 
 def _llama3_scale_freqs(freqs, scaling):
@@ -402,7 +406,13 @@ def hc_coef(p, x, cfg):
 
 
 def stream_open(cfg, x):
-    """The embedding as the stack's streams: itself, or n copies."""
+    """The embedding as the stack's streams: itself, or n copies; in
+    float32 where the family adds its residuals there
+    (`cfg.fp32_stream`, models/evabyte.py: every `residual` then adds
+    a sublayer's output to a float32 stream, and `norm` hands the
+    sublayers the model's type)."""
+    if getattr(cfg, "fp32_stream", False):
+        x = x.astype(jnp.float32)
     if cfg.hc_mult == 1:
         return x
     return jnp.broadcast_to(x[:, :, None], (*x.shape[:2], cfg.hc_mult,
@@ -486,6 +496,20 @@ def attn_layers(cfg):
         out.append((band, rotates, pool, n[pool]))
         n[pool] += 1
     return out
+
+
+def cache_rows(cfg, pos):
+    """The cache row position `pos` of a sequence is written at, which
+    is also how many rows the sequence holds below it: `pos` itself
+    for every family whose cache rows are positions. A family whose
+    finished windows FOLD (`cfg.fold_window`, models/evabyte.py: a
+    window of `fold_window` positions leaves one row a chunk of
+    `fold_chunk`) holds, at a position in window w, w windows' summary
+    rows and the window's own exact rows. int or int32 array."""
+    fold = getattr(cfg, "fold_window", 0)
+    if not fold:
+        return pos
+    return pos - (fold - fold // cfg.fold_chunk) * (pos // fold)
 
 
 def _kernel_scope(cfg, pool):
@@ -1273,7 +1297,10 @@ def decode_step(block, params, cfg, token, seq_lens, k_pages, v_pages,
     """One decode step over paged KV.
 
     token:      [batch] int32 — current input token
-    seq_lens:   [batch] int32 — tokens already in cache (excl. current)
+    seq_lens:   [batch] int32 — tokens already in cache (excl. current):
+                the new token's POSITION. Its cache row is
+                `cache_rows(cfg, seq_lens)`: the same number, but for a
+                family whose finished windows fold
     k_pages/v_pages: [n_kv_layers, n_pages, page, n_kv, hd] (a family
                 with `cfg.page_rows`: [n_kv_layers, n_pages, page *
                 rows, hd], a page as flat rows); where the
@@ -1326,7 +1353,10 @@ def decode_step(block, params, cfg, token, seq_lens, k_pages, v_pages,
             table, (lens // cfg.page_size)[:, None], axis=1)[:, 0]
         return table, lens, page, lens % cfg.page_size
 
-    pools = {"full": [k_pages, v_pages, *place(page_table, seq_lens)]}
+    # (a new row is ROTATED at its position, `positions` above, and
+    # WRITTEN at its cache row, which is where the kernel's length ends)
+    pools = {"full": [k_pages, v_pages,
+                      *place(page_table, cache_rows(cfg, seq_lens))]}
     if win is not None:
         wk, wv, wtable, wbase = win
         # inactive rows (seq_lens 0) stay at 0: entry 0 of an empty

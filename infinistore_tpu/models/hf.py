@@ -1105,3 +1105,72 @@ def phi4flash_config_from_hf(hf_cfg, page_size=16, dtype="float32"):
                               or {}).get("cross_out_gain", 1.0)),
         dtype=dtype,
     )
+
+
+def evabyte_config_from_hf(hf_cfg, page_size=16, dtype="float32"):
+    """Map an EvaByte ``config.json`` (HF `evabyte`, EvaByte/EvaByte;
+    any object with its keys as attributes) onto
+    :class:`models.evabyte.EvaByteConfig`: ``window_size`` positions
+    attended exactly, every earlier window as one row a chunk of
+    ``chunk_size``, ``num_pred_heads`` heads of ``vocab_size`` logits
+    (the program's ``vocab_size`` stays the byte vocabulary),
+    ``norm_add_unit_offset`` the (1 + g) norm, ``fp32_skip_add`` the
+    float32 stream, ``fp32_logits`` what `decoder.lm_head` returns
+    anyway. ``random_init`` ({"phi_gain", "mu_gain"}) is no published
+    key: how a benchmark configuration WITHOUT a checkpoint draws the
+    summariser's two vectors (models/evabyte.py init_params).
+    Refused, because models/evabyte.py does not implement it: another
+    ``attention_class`` than "eva", a chunk that is not the page, a
+    window that does not fold into whole pages of summary rows, fewer
+    kv heads than heads, biases, rope scaling, a tied head, another
+    activation than silu. NO loader of published weights exists: one
+    must check the summariser's form (models/evabyte.py `fold`) and the
+    head's layout against the published modeling code."""
+    from .evabyte import EvaByteConfig
+
+    def refuse(what):
+        raise NotImplementedError(
+            f"evabyte: {what} is not implemented by models/evabyte.py")
+
+    g = lambda k, d=None: getattr(hf_cfg, k, d)  # noqa: E731
+    if g("attention_class", "eva") != "eva":
+        refuse(f"attention_class={g('attention_class')!r}")
+    if g("attention_bias", False):
+        refuse("attention_bias")
+    if g("rope_scaling"):
+        refuse("rope_scaling")
+    if g("tie_word_embeddings", False):
+        refuse("tie_word_embeddings")
+    if g("hidden_act", "silu") != "silu":
+        refuse(f"hidden_act={g('hidden_act')!r}")
+    heads = hf_cfg.num_attention_heads
+    if g("num_key_value_heads", heads) != heads:
+        refuse(f"{g('num_key_value_heads')} kv heads under {heads} heads "
+               "(the summariser's vectors are a head's)")
+    chunk, window = int(hf_cfg.chunk_size), int(hf_cfg.window_size)
+    if chunk != page_size:
+        refuse(f"chunk_size={chunk} under pages of {page_size}")
+    if window % (chunk * page_size):
+        refuse(f"window_size={window} (a multiple of {chunk * page_size}: "
+               "whole pages of summary rows)")
+    init = g("random_init") or {}
+    return EvaByteConfig(
+        vocab_size=hf_cfg.vocab_size,
+        d_model=hf_cfg.hidden_size,
+        n_layers=hf_cfg.num_hidden_layers,
+        n_heads=heads,
+        n_kv_heads=heads,
+        d_ff=hf_cfg.intermediate_size,
+        max_seq=hf_cfg.max_position_embeddings,
+        page_size=page_size,
+        rope_theta=float(hf_cfg.rope_theta),
+        norm_eps=float(hf_cfg.rms_norm_eps),
+        norm_plus_one=bool(g("norm_add_unit_offset", True)),
+        fp32_stream=bool(g("fp32_skip_add", True)),
+        fold_window=window,
+        fold_chunk=chunk,
+        n_pred_heads=int(g("num_pred_heads", 1)),
+        phi_gain=float(init.get("phi_gain", 1.0)),
+        mu_gain=float(init.get("mu_gain", 1.0)),
+        dtype=dtype,
+    )

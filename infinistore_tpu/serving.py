@@ -300,6 +300,14 @@ class _Slot:
     wpage_ids: list = field(default_factory=list)
     wbase: int = 0
     wstored: int = 0
+    # A family whose finished windows fold (`cfg.fold_window`): then
+    # `page_ids` is the table of cache ROWS the kernel walks, the
+    # summary pages of the `folded` windows first and the exact pages
+    # of the window the sequence is in behind them, and `seq_len`,
+    # `cached_pages` and the digests still count POSITIONS. The first
+    # `sum_stored` of the summary pages are in the store already.
+    folded: int = 0
+    sum_stored: int = 0
 
     def total_generated(self):
         return len(self.work.done) + len(self.generated)
@@ -703,6 +711,17 @@ def _decode_fused(params, cfg, token, seq_lens, k_pages, v_pages, rows,
     # Live-rows-only advance — see _decode_scan's body comment.
     out = (logits, nxt, seq_lens + (seq_lens > 0), k_pages, v_pages)
     return out + (_with_fetched(nxt, n[0]),) if fetched else out
+
+
+@partial(jax.jit, static_argnames=("cfg", "model"), donate_argnums=(2, 3))
+def _fold_window(params, cfg, k_pages, v_pages, ids, model=None):
+    """ONE finished window of one sequence folded where it lies (a
+    family with `cfg.fold_window`, models/evabyte.py `fold_pages`):
+    every layer's pages `ids` (the window's, in sequence order) are
+    gathered and summarised, and the summary rows written over the
+    first of them, the pools donated. The engine dispatches it behind
+    the program that wrote the window's last row."""
+    return model.fold_pages(params, cfg, k_pages, v_pages, ids)
 
 
 # ---- the same programs for a family with state layers ------------------
@@ -1267,6 +1286,15 @@ class ServingEngine:
             if not self._latent:
                 self._check_index_family()
             self.v_pages = ipool if self._latent else (self.v_pages, ipool)
+        # A family whose finished windows fold into summary rows that
+        # take their pages' place (models/evabyte.py): the window, in
+        # positions (0: rows are positions, every other family), and in
+        # pages what a window holds before and after its fold.
+        self._fold = getattr(cfg, "fold_window", 0)
+        if self._fold:
+            self._check_fold_family()
+            self._fold_in = self._fold // cfg.page_size
+            self._fold_out = self._fold // cfg.fold_chunk // cfg.page_size
         self.wk_pages = self.wv_pages = None
         if self._win_layers:
             self._init_window_pools()
@@ -1372,6 +1400,19 @@ class ServingEngine:
             # wrote to the store and hits restored from it
             "admit_pieces": 0, "latent_pages_written": 0,
             "latent_pages_restored": 0,
+            # a cache whose finished windows fold (models/evabyte.py):
+            # windows folded and the pool pages that freed, summary
+            # pages the folds wrote, those offloads sent to the store
+            # and hits brought back, the exact pages (of the window a
+            # prefix ends in) hits brought back, hits that found their
+            # window's exact pages gone and began at its edge; and over
+            # the decode steps, layers and active sequences, the cache
+            # rows the tables held and the positions they stood for
+            "windows_folded": 0, "fold_pages_freed": 0,
+            "summary_pages_written": 0, "summary_pages_offloaded": 0,
+            "summary_pages_restored": 0, "exact_pages_restored": 0,
+            "hits_cut_to_window_edge": 0, "attn_rows_read": 0,
+            "attn_positions_live": 0,
             # a learned selection (models/glm.py), over the decode
             # steps: index keys in every slot's table a layer that
             # owns an indexer, the slots the selection ran over (the
@@ -1472,6 +1513,11 @@ class ServingEngine:
             # `kinds` on istpu.cache.offload and .restore: the calls
             # (gather, transfer, store batch; get) each makes
             self._kinds_field = {"kinds": len(cfg.page_kinds)}
+        if self._fold:
+            # summary pages and exact pages: two kinds of store key of
+            # one shape ("sk" / "sv" beside "k" / "v"), probed by
+            # attention layer 0's first
+            self._kinds_field = {"kinds": 2}
         if self.sc.admit_piece % cfg.page_size:
             raise ValueError(
                 f"admit_piece {self.sc.admit_piece} is no multiple of the "
@@ -1531,6 +1577,9 @@ class ServingEngine:
                          + ".".join(map(str, self._index_layers)))
             if not self._latent:  # ... in how many lanes a key
                 self._ns += f"w{cfg.index_width}"
+        if self._fold:
+            # ... a row of a folded window is no position's
+            self._ns += f"/fold{self._fold}c{cfg.fold_chunk}"
         if self._win_layers:
             # ... of every cache kind: which layers are banded, and how
             # widely (a page of a banded layer is not a page of a full
@@ -1690,6 +1739,34 @@ class ServingEngine:
             "quantized_store": sc.quantized_store,
             "window": bool(self.cfg.window)})
 
+    def _check_fold_family(self):
+        """What is not built over a cache whose finished windows fold
+        (a table of rows, not positions) is refused at construction,
+        by name: a verify or burst step writes several rows a sequence
+        across what may be a window's edge, the int8 wire and packed
+        rows know one kind of row, a sliding band would release pages
+        by position under a table that is not by position, and a
+        second pool (banded layers', a recurrent state's, a latent or
+        an index pool) would need a fold, or a rule against one, of
+        its own. The window is a whole number of pages and a chunk is
+        the page (a summary page stands for whole pages of positions:
+        models/evabyte.py's config refuses the rest too)."""
+        sc, cfg = self.sc, self.cfg
+        self._refuse("a folded cache", {
+            "spec_k": sc.spec_k > 0, "host_steps": sc.host_steps > 1,
+            "quantized_store": sc.quantized_store,
+            "kv_pack": cfg.kv_pack > 1,
+            "window": bool(cfg.window_band),
+            "state layers": bool(getattr(cfg, "n_state_layers", 0)),
+            "a latent pool": self._latent,
+            "an index pool": bool(self._index_kind),
+            "a fold_chunk that is not the page":
+                cfg.fold_chunk != cfg.page_size,
+            "a fold_window that is no multiple of whole summary pages":
+                self._fold % (cfg.fold_chunk * cfg.page_size) != 0,
+            "an admit_piece longer than the window":
+                sc.admit_piece > self._fold})
+
     def _refuse(self, over, options):
         """Raise for the first of `options` (name: whether it is on)
         that is not built for a model with `over`."""
@@ -1788,15 +1865,32 @@ class ServingEngine:
             # logits; a 0-token budget would still generate (and stream)
             # it, so reject the request up front instead.
             raise ValueError("max_new_tokens must be >= 1")
-        need = -(-(len(req.prompt) + req.max_new_tokens) // self.cfg.page_size)
+        n = len(req.prompt) + req.max_new_tokens
+        need = self._pages_on_the_way(n)
         if need > self.sc.max_pages_per_seq:
+            what = "cache rows" if self._fold else "positions"
             raise ValueError(
-                f"request needs {need} pages > max_pages_per_seq "
-                f"{self.sc.max_pages_per_seq}"
+                f"request needs {need} pages of {what} ({n} positions) "
+                f"> max_pages_per_seq {self.sc.max_pages_per_seq}"
             )
         self.queue.append(_Work(req=req, prompt=list(req.prompt),
                                 queue_len=len(self.queue)))
         self.stats["requests"] += 1
+
+    def _pages_on_the_way(self, n):
+        """The most pool pages a sequence holds on its way to `n`
+        positions: their pages, where rows are positions. Over a
+        folded cache, the summary pages of the windows before the last
+        position's and either that window's pages so far or, just
+        before the fold before it, a whole window's beside one window's
+        summaries fewer."""
+        page = self.cfg.page_size
+        if not self._fold:
+            return -(-n // page)
+        w = (n - 1) // self._fold
+        here = self._fold_out * w - (-(n - w * self._fold) // page)
+        return max(here, self._fold_out * (w - 1) + self._fold_in) \
+            if w else here
 
     def _alloc(self, n):
         if len(self.free_pages) < n:
@@ -1838,6 +1932,16 @@ class ServingEngine:
         with self._span("istpu.cache.probe", work.req.request_id,
                         pages=cap) as f:
             digests = self._digests(work.prompt, cap)
+            if self._fold:
+                try:
+                    hit = self._probe_folded(digests, cap)
+                except Exception as e:
+                    self._store_failed("probe", e)
+                    return 0, []
+                f["hit_pages"] = hit
+                if hit > 0:
+                    self._prefetch_chain(work.prompt, hit, digests[:hit])
+                return hit, digests[:hit]
             try:
                 # ONE call over the probed key of every kind of each
                 # page, page-major: a page counts with all of them
@@ -1857,6 +1961,58 @@ class ServingEngine:
             if hit > 0:
                 self._prefetch_chain(work.prompt, hit, digests[:hit])
         return hit, digests[:hit]
+
+    def _fold_split(self, hit):
+        """(summary pages, exact pages) a folded prefix of `hit` token
+        pages is made of: every window that ends at or below it as its
+        summary pages, the rest exactly."""
+        w = hit // self._fold_in
+        return w * self._fold_out, hit - w * self._fold_in
+
+    def _summary_digests(self, digests, lo, hi):
+        """The digests that key summary pages [lo, hi): a summary page
+        stands for `page_size` chunks = `page_size` token pages, and is
+        keyed by the LAST of them (so by every token it covers and all
+        before it)."""
+        page = self.cfg.page_size
+        return [digests[(j + 1) * page - 1] for j in range(lo, hi)]
+
+    def _probe_folded(self, digests, cap):
+        """The depth, in token pages, a folded cache can restore of the
+        chain `digests[:cap]`: for a prefix of P pages the store must
+        hold the SUMMARY pages of every window that ends at or below P
+        and the EXACT pages from that window's edge to P (what a finish
+        wrote: `_offload_folded`). One call over the summary pages'
+        probe keys of the `cap // window` whole windows and, behind
+        them, the exact pages' of the window `cap` lies in: where a
+        finished turn of the same sequence ended in that window, the
+        common case, it answers both. Where the summaries end earlier
+        (the stored sequence ended a window below), a second call over
+        THAT window's exact pages. Exact pages missing: the hit is cut
+        back to its window's edge, where summaries alone suffice
+        (`cut_to_window_edge` on its admission's span); summaries
+        missing from the first
+        window on: cold. Never a whole window's exact pages: its fold
+        would have to run before the first piece, so such a hit ends a
+        page short."""
+        (layer, kind), = self._probe_kinds
+        per_w, out = self._fold_in, self._fold_out
+
+        def exact_keys(w):
+            return content_page_keys_by_page(
+                digests[w * per_w:min(cap, (w + 1) * per_w)], [layer], kind)
+
+        w_cap = cap // per_w
+        sums = content_page_keys_by_page(
+            self._summary_digests(digests, 0, w_cap * out), [layer],
+            ("s" + kind,))
+        found = self.store.cached_prefix_len(sums + exact_keys(w_cap))
+        if found >= len(sums):
+            w, n_exact = w_cap, found - len(sums)
+        else:
+            w = found // out
+            n_exact = self.store.cached_prefix_len(exact_keys(w))
+        return min(w * per_w + n_exact, (w + 1) * per_w - 1, cap)
 
     def _probe_snapshot(self, hit, digests):
         """The depth a family with state can restore, given `hit`
@@ -1901,7 +2057,8 @@ class ServingEngine:
             return
         cfg = self.cfg
         try:
-            if self._win_layers:  # what the restore will read, no more
+            if self._win_layers or self._fold:
+                # what the restore will read, no more
                 keys = self._restore_keys(hit, digests,
                                           self._first_live(hit))
             elif self._index_kind:
@@ -1959,6 +2116,10 @@ class ServingEngine:
         if self._win_layers:
             return self._do_admit_two(slot_idx, work, n_prompt, n_pages,
                                       hit if store_chain else 0, digests, f)
+        if self._fold:
+            return self._do_admit_folded(slot_idx, work,
+                                         hit if store_chain else 0,
+                                         digests, f)
         if not store_chain and hit:
             # The probe is cached on work while the request waits under
             # pool pressure, so it can OUTLIVE the store: another slot's
@@ -2057,6 +2218,14 @@ class ServingEngine:
             nbytes = hit * self._page_bytes + n * self._wpage_bytes \
                 + self._snapshot_bytes
             n = hit
+        elif self._fold:
+            # A folded prefix: the summary pages of its finished
+            # windows and the exact pages behind them, one shape, ONE
+            # call; `pages` counts what crosses over.
+            n_sum, n_exact = self._fold_split(hit)
+            kinds = {"summary_pages": n_sum, "exact_pages": n_exact}
+            n = n_sum + n_exact
+            nbytes = n * self._page_bytes
         else:
             nbytes = n * self._page_bytes + self._snapshot_bytes
         # The span times the store calls alone — the interval a span
@@ -2132,6 +2301,10 @@ class ServingEngine:
         self.stats["snapshots_restored"] += snap is not None
         if self._win_layers:
             self.stats["restore_trimmed_pages"] += first_live
+        if self._fold:
+            n_sum, n_exact = self._fold_split(hit)
+            self.stats["summary_pages_restored"] += n_sum
+            self.stats["exact_pages_restored"] += n_exact
         return restored, snap, hit
 
     def _first_live(self, hit):
@@ -2157,6 +2330,17 @@ class ServingEngine:
             return [content_page_keys_by_page(
                 digests[first_live:hit], self.cfg.page_layers(kind), kind)
                 for kind in self.cfg.page_kinds]
+        if self._fold:
+            # A folded prefix in the slot's row order: its finished
+            # windows' summary pages ("sk", "sv"), then the exact pages
+            # from the last window's edge on.
+            n_sum, n_exact = self._fold_split(hit)
+            return content_page_keys_by_page(
+                self._summary_digests(digests, 0, n_sum),
+                self.cfg.n_kv_layers, ("sk", "sv")) \
+                + content_page_keys_by_page(digests[hit - n_exact:hit],
+                                            self.cfg.n_kv_layers,
+                                            self.cfg.page_kinds)
         if not self._win_layers:
             return content_page_keys_by_page(digests[first_live:hit],
                                              self.cfg.n_kv_layers,
@@ -2262,11 +2446,172 @@ class ServingEngine:
         # ones were materialized so this release can offload them).
         self._release_windowed(slot)
 
+    # ---- a cache whose finished windows fold ----------------------------
+
+    def _open_folded(self, work, hit, digests, slot_idx=-1, f=None):
+        """A slot over a folded cache with a hit's prefix restored (not
+        yet placed: its first piece does that) and the pool pages of
+        that prefix and of the first piece taken, or None where the
+        pool is out (nothing is held then). Returns (slot, restored or
+        None). A restore that fails makes the admission cold. `f`: an
+        admission span's fields (None: `first_token_logits`, which
+        counts nothing)."""
+        page = self.cfg.page_size
+        restored = None
+        if hit and f is None:
+            try:
+                restored, _ = self._restore(hit, digests)
+            except InfiniStoreKeyNotFound:
+                hit = 0  # evicted between probe and restore
+        elif hit:
+            if len(self.free_pages) < sum(self._fold_split(hit)):
+                return None  # before the store call, as `_do_admit`
+            restored, _, hit = self._try_restore(hit, digests, 0, f)
+        n_sum, n_exact = self._fold_split(hit)
+        slot = _Slot(work=work, page_ids=[], seq_len=hit * page,
+                     cached_pages=hit, index=slot_idx,
+                     todo=work.prompt[hit * page:],
+                     folded=n_sum // self._fold_out, sum_stored=n_sum)
+        first = min(self._piece_len(slot.seq_len), len(slot.todo))
+        if slot_idx >= 0:
+            self.page_table[slot_idx] = 0
+            self._pages_rev += 1
+        if not self._back_rows(slot, n_sum + n_exact - (-first // page)):
+            return None
+        return slot, restored
+
+    def _do_admit_folded(self, slot_idx, work, hit, digests, f):
+        """`_do_admit` for a family whose finished windows fold. The
+        slot's table is of ROWS: a hit's prefix is the summary pages of
+        its finished windows and the exact pages behind them
+        (`_fold_split`), and the pool pages of the prompt are taken a
+        PIECE at a time (`_back_rows`), never for all its positions: a
+        prompt far longer than `max_pages_per_seq` pages of positions
+        is admitted where its rows fit (`submit`). Every such prompt
+        goes in pieces, each inside one window (`_run_piece`), one an
+        engine step; a hit's first piece runs here, with the restore."""
+        opened = self._open_folded(work, hit, digests, slot_idx, f)
+        if opened is None:
+            self.stats["admit_retries"] += 1
+            f["outcome"] = "no_pages"
+            return False
+        slot, restored = opened
+        hit = slot.cached_pages
+        cut = hit > 0 and hit % self._fold_in == 0 \
+            and (len(work.prompt) - 1) // self.cfg.page_size > hit
+        f["hit_pages"], f["cut_to_window_edge"] = hit, cut
+        self.stats["hits_cut_to_window_edge"] += cut
+        self.slots[slot_idx] = slot
+        try:
+            if restored is not None:
+                row = self._run_piece(slot, restored)
+                if not slot.todo:
+                    self._emit(slot, [self._pick(work, row)])
+        except BaseException:
+            self._release(slot_idx, slot)
+            raise
+        work.probe = None  # consumed; a future re-admission re-probes
+        f["outcome"] = "admitted"
+        return True
+
+    def _fold_due(self, active, more=0):
+        """Before a decode step of `active` whose lengths lie `more`
+        beyond the slots' (1: behind the step in flight): every slot
+        whose step would write the first row of a new window has the
+        window before it folded first, behind the program that wrote
+        its last row; the step's table goes up anew."""
+        for _, s in active:
+            if (s.seq_len + more) // self._fold > s.folded:
+                self._fold_slot(s, "decode")
+
+    def _fold_slot(self, slot, during):
+        """The fold: the window `slot.folded` of the sequence (whose
+        last row the last program dispatched wrote) becomes its
+        summary rows. ONE program reads the window's `_fold_in` pool
+        pages of every layer and writes `_fold_out` summary pages over
+        the first of them; the others go back to the free list at
+        once, WITHOUT an offload: nothing at that depth or deeper can
+        attend them again, and a program that takes one is enqueued
+        behind the fold. `during`: "decode" | "piece"."""
+        w, out = slot.folded, self._fold_out
+        lo = w * out
+        ids = slot.page_ids[lo:lo + self._fold_in]
+        assert len(ids) == self._fold_in \
+            and len(slot.page_ids) == lo + self._fold_in, (w, len(ids))
+        with self._span("istpu.cache.fold", slot.work.req.request_id,
+                        slot=slot.index, window=w, pages_in=len(ids),
+                        pages_out=out, during=during,
+                        bytes=(len(ids) + out) * self._page_bytes):
+            self.k_pages, self.v_pages = _fold_window(
+                self.params, self.cfg, self.k_pages, self.v_pages,
+                self._to_device(np.asarray(ids, np.int32)),
+                model=self.model)
+        self.free_pages.extend(ids[out:])
+        del slot.page_ids[lo + out:]
+        slot.folded += 1
+        if slot.index >= 0:
+            self.page_table[slot.index] = 0
+            self.page_table[slot.index, :len(slot.page_ids)] = slot.page_ids
+            self._pages_rev += 1
+        self.stats["windows_folded"] += 1
+        self.stats["fold_pages_freed"] += len(ids) - out
+        self.stats["summary_pages_written"] += out
+
+    def _offload_folded(self, slot, reason):
+        """`_offload_full_pages` for a folded slot: the SUMMARY pages
+        the store lacks (of every window folded here: `sum_stored` on)
+        and the EXACT full pages of the window the sequence ends in,
+        from the deeper of the window's edge and what a hit brought.
+        A folded window's exact pages were freed at its fold and are
+        never written. One offload, a gather-transfer-put a kind."""
+        page = self.cfg.page_size
+        n_sum = slot.folded * self._fold_out
+        base = slot.folded * self._fold_in  # the last window's edge
+        n_full = slot.seq_len // page
+        lo = max(slot.cached_pages, base)
+        n_exact, new_sum = max(0, n_full - lo), n_sum - slot.sum_stored
+        n = new_sum + n_exact
+        if n <= 0:
+            return None
+        nbytes = n * self._page_bytes
+        digests = self._slot_digests(slot, max(n_full, base))
+        rid = slot.work.req.request_id
+        up = _Upload(reason, rid, n, nbytes,
+                     digests=digests[slot.sum_stored * page:],
+                     counts={"offloaded_pages": n_exact,
+                             "summary_pages_offloaded": new_sum})
+        with self._span("istpu.cache.offload", rid, reason=reason, pages=n,
+                        bytes=nbytes, padded_pages=0, puts=0,
+                        summary_pages=new_sum, exact_pages=n_exact,
+                        kinds=2) as f:
+            if not self._upload_room(nbytes):
+                return None
+            if new_sum:
+                self._gather_pool_pages(
+                    up, f, self.k_pages, self.v_pages,
+                    slot.page_ids[slot.sum_stored:n_sum],
+                    self._summary_digests(digests, slot.sum_stored, n_sum),
+                    self._full_layers, self._page_bytes,
+                    kind=("sk", "sv"))
+            if n_exact:
+                self._gather_pool_pages(
+                    up, f, self.k_pages, self.v_pages,
+                    slot.page_ids[n_sum + lo - base:n_sum + n_full - base],
+                    digests[lo:n_full], self._full_layers,
+                    self._page_bytes)
+            f["puts"] = len(up.chunks)
+            self._enqueue_upload(up)
+        slot.sum_stored = n_sum
+        return up
+
     # ---- admission in pieces --------------------------------------------
 
     def _run_piece(self, slot, restored=None):
         """The next piece of `slot`'s prompt (`admit_piece` tokens, or
-        the tail): ONE program call. A cold prompt's first piece is the
+        the tail; over a folded cache it ends no later than the window
+        it begins in, `_piece_len`, and the window folds behind it,
+        `_fold_slot`; None where the pool has no page for it): ONE
+        program call. A cold prompt's first piece is the
         cold program; every other is the prefix program (`_prefill_hit`,
         the one a hit runs) over the pages the slot holds from its
         band's floor on (`_first_live`; all of them without a window):
@@ -2276,14 +2621,19 @@ class ServingEngine:
         caller's to release: it owns the slot's pages. Returns the
         piece's last logits row (the first token's, once `slot.todo` is
         empty)."""
-        page, piece = self.cfg.page_size, self.sc.admit_piece
-        tokens = slot.todo[:piece]
-        held = slot.seq_len // page  # pieces and hits end on page edges
+        page = self.cfg.page_size
+        # pieces and hits end on page edges; over a folded cache the
+        # pages held are of ROWS, and the piece's own are taken here
+        held = decoder.cache_rows(self.cfg, slot.seq_len) // page
+        tokens = slot.todo[:self._piece_len(slot.seq_len)]
+        if self._fold and not self._back_rows(
+                slot, held - (-len(tokens) // page)):
+            return None
         ids = slot.page_ids[held:held - (-len(tokens) // page)]
         with self._span("istpu.sched.admit_piece",
                         slot.work.req.request_id, tokens=len(tokens),
                         prefix_pages=held, piece=slot.pieces + 1,
-                        of=slot.pieces - (-len(slot.todo) // piece)):
+                        of=slot.pieces + self._pieces_left(slot)):
             if restored is None and held == 0:
                 row = self._prefill_cold(tokens, self._pad_ids(ids),
                                          slot.index)
@@ -2307,15 +2657,59 @@ class ServingEngine:
                                 self.k_pages, self.v_pages, at
                             ).reshape(-1, *self.cfg.kv_page_shape())
                     r_ids = [self.sc.total_pages] * len(r_ids)  # in place
-                row = self._prefill_hit(tokens, restored, lo * page,
-                                        r_ids, ids)
+                # the prefix's first row stands at lo * page where rows
+                # are positions; over a folded cache at the piece's
+                # first position less the rows below it
+                row = self._prefill_hit(
+                    tokens, restored, slot.seq_len - (held - lo) * page,
+                    r_ids, ids)
         slot.todo = slot.todo[len(tokens):]
         slot.seq_len += len(tokens)
         slot.pieces += 1
         self.stats["admit_pieces"] += 1
         self.stats["prefill_tokens"] += len(tokens)
         self._piece_ran = True
+        if self._fold and slot.seq_len // self._fold > slot.folded:
+            self._fold_slot(slot, "piece")  # the piece ended its window
         return row
+
+    def _piece_len(self, pos):
+        """How many tokens the piece that begins at position `pos` may
+        take: `admit_piece`; over a folded cache no further than the
+        end of the window `pos` lies in, so that inside a piece every
+        prefix row is visible to every suffix row (and `admit_piece`
+        0 means a window)."""
+        if not self._fold:
+            return self.sc.admit_piece
+        return min(self.sc.admit_piece or self._fold,
+                   self._fold - pos % self._fold)
+
+    def _pieces_left(self, slot):
+        """Pieces `slot.todo` still makes, the next one counted."""
+        if not self._fold:
+            return -(-len(slot.todo) // self.sc.admit_piece)
+        n, pos, left = 0, slot.seq_len, len(slot.todo)
+        while left > 0:
+            step = min(self._piece_len(pos), left)
+            n, pos, left = n + 1, pos + step, left - step
+        return n
+
+    def _back_rows(self, slot, n_pages):
+        """Pool pages under the first `n_pages` entries of a folded
+        slot's table of rows (a piece's own, a decode step's next):
+        taken as they are needed, never for the whole prompt. False,
+        and nothing taken, where the pool is out."""
+        held = len(slot.page_ids)
+        if held >= n_pages:
+            return True
+        ids = self._alloc(n_pages - held)
+        if ids is None:
+            return False
+        if slot.index >= 0:
+            self.page_table[slot.index, held:n_pages] = ids
+            self._pages_rev += 1
+        slot.page_ids.extend(ids)
+        return True
 
     def _step_pieces(self):
         """One piece of the first slot that is still being admitted,
@@ -2326,6 +2720,9 @@ class ServingEngine:
             if s is None or not s.todo or self._piece_ran:
                 continue
             row = self._run_piece(s)
+            if row is None:  # (a folded cache) no pool page for it yet
+                self.stats["admit_retries"] += 1
+                continue
             if not s.todo:
                 self._emit(s, [self._pick(s.work, row)])
             self._release_windowed(s)
@@ -2702,6 +3099,25 @@ class ServingEngine:
         work = _Work(req=Request("first-token-logits", prompt),
                      prompt=prompt)
         hit, digests = self._probe_hit(work)
+        if self._fold:
+            # What an admission over a folded cache runs, piece by
+            # piece with its folds, on pool pages taken for the call
+            # and given back.
+            slot, restored = self._open_folded(work, hit, digests) \
+                or (None, None)
+            row = None
+            try:
+                if slot is not None:
+                    row = self._run_piece(slot, restored)
+                    while slot.todo and row is not None:
+                        row = self._run_piece(slot)
+            finally:
+                if slot is not None:
+                    self.free_pages.extend(slot.page_ids)
+            if row is None:
+                raise RuntimeError("first_token_logits: the prompt needs "
+                                   "more pool pages than are free")
+            return np.asarray(row, np.float32), slot.cached_pages
         if self._win_layers:
             restored = snap = None
             if hit > 0:
@@ -2851,7 +3267,8 @@ class ServingEngine:
         """Allocate pages on demand (vLLM-style growth) so positions up
         to and including `last_pos` are backed. Partial progress is
         kept: pages allocated before a failure stay owned by the slot."""
-        need_idx = last_pos // self.cfg.page_size
+        need_idx = decoder.cache_rows(self.cfg, last_pos) \
+            // self.cfg.page_size
         while len(slot.page_ids) <= need_idx:
             ids = self._alloc(1)
             if ids is None:
@@ -2905,6 +3322,8 @@ class ServingEngine:
         Returns the upload on its way, or None for nothing to send."""
         if not self._store_chain(slot.work):
             return None
+        if self._fold:
+            return self._offload_folded(slot, reason)
         n_full = slot.seq_len // self.cfg.page_size
         if hi is not None:
             n_full = min(n_full, hi)
@@ -3409,6 +3828,8 @@ class ServingEngine:
 
         if self._win_layers:
             self._shed_windows(active)
+        if self._fold:
+            self._fold_due(active)
         for i, s in active:
             if not self._ensure_pages(i, s, s.seq_len + k - 1):
                 if k > 1 and self._ensure_pages(i, s, s.seq_len):
@@ -3552,6 +3973,8 @@ class ServingEngine:
         (the pages taken so far stay the slots')."""
         if self._win_layers:
             self._shed_windows(active, more=1)
+        if self._fold:
+            self._fold_due(active, more=1)
         if not all(self._ensure_pages(i, s, s.seq_len + 1)
                    for i, s in active):
             return
@@ -3630,6 +4053,16 @@ class ServingEngine:
         key, token_dev, lens_dev, rows_dev = self._step_inputs(
             active, greedy, f, more)
         df["live_pages"] = self._count_attn_pages(active, more=more)
+        if self._fold:
+            # the rows the step's tables hold, over its sequences, and
+            # the positions they stand for
+            df["positions"] = sum(s.seq_len + more + 1 for _, s in active)
+            df["cache_rows"] = sum(
+                decoder.cache_rows(self.cfg, s.seq_len + more) + 1
+                for _, s in active)
+            layers = self.k_pages.shape[0]
+            self.stats["attn_rows_read"] += layers * df["cache_rows"]
+            self.stats["attn_positions_live"] += layers * df["positions"]
         if self._index_kind:
             self._count_selected(active, df, more)
         sparse = self._experts_held > 0 or self._selects
@@ -3750,7 +4183,9 @@ class ServingEngine:
         live = 0
         for (pool, band), layers in self._attn_kinds.items():
             for _, s in active:
-                n = s.seq_len + more + 1  # keys the step attends from 0
+                # keys the step attends from 0 (rows, where rows are
+                # not positions)
+                n = decoder.cache_rows(self.cfg, s.seq_len + more) + 1
                 if pool == "window":
                     n -= s.wbase * page
                 first = max(n - band, 0) // page if band else 0

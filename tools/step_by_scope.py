@@ -14,8 +14,9 @@ window, filed under the innermost stage name of its `op_name` (`embed`,
 `attn.*`, `mlp`, `moe.*`, `ssm.*`, `hc.*`, `pool.update`, `lm_head`;
 since PR 53 `ssm.gmu`, `attn.diff` and `attn.kernel.cross` among them,
 the Gated Memory Units, the differential maps' subtraction and norm,
-and the layers that attend another layer's pages), or under "no
-scope". One `step_by_scope:` JSON line on stderr beside
+and the layers that attend another layer's pages; since PR 55
+`attn.fold`, in a third kind of program where the configuration's
+`program.programs` names one, "fold"), or under "no scope". One `step_by_scope:` JSON line on stderr beside
 the run's own lines, for each of the two kinds: runs, mean ms a run, ms
 a run by stage, and the unscoped operations that took most (a weight's
 `copy` shows there by name, as in the ledger's
@@ -42,6 +43,20 @@ def stage_of(where):
     """The innermost stage name in an operation's `op_name` path."""
     path = where.rsplit(" ", 1)[0].split("/")
     return next((p for p in reversed(path) if STAGE.match(p)), "no scope")
+
+
+def folds_traced(obs):
+    """{"decode": n, "piece": m}: the program's istpu.cache.fold spans
+    that started in the traced seconds, by what they ran behind (a
+    cache whose finished windows fold; {} for every other family)."""
+    from infinistore_tpu.utils import profiling
+
+    if obs is None or obs.trace_window is None:
+        return {}
+    t0, t1 = (t * 1e9 for t in obs.trace_window)
+    return dict(collections.Counter(
+        s.fields.get("during") for s in profiling.spans()
+        if s.name == "istpu.cache.fold" and t0 <= s.t0_ns < t1))
 
 
 def by_stage(ops, modules, window, needles):
@@ -104,8 +119,18 @@ def main():
                     "root": args.root, "run": run_args,
                     **{kind: by_stage(ops, modules, window,
                                       serve.program_names(self.conf, kind))
-                       for kind in ("decode", "prefill")}})
+                       for kind in ("decode", "prefill")},
+                    # ... and, where the configuration names one (a
+                    # cache whose finished windows fold, PR 55), the
+                    # fold programs: `attn.fold`
+                    **{kind: by_stage(ops, modules, window, names)
+                       for kind, names in self.conf["program"].get(
+                           "programs", {}).items()
+                       if kind not in ("decode", "prefill")}})
                 print("step_by_scope: " + line, file=sys.stderr, flush=True)
+                print("folds_traced: " + json.dumps(folds_traced(
+                    getattr(self, "traced", None))), file=sys.stderr,
+                    flush=True)
                 if out:
                     os.makedirs(os.path.dirname(out), exist_ok=True)
                     with open(out, "a") as f:
@@ -115,6 +140,13 @@ def main():
                   file=sys.stderr, flush=True)
         return close(self)
 
+    measure = cell.Cell.measure
+
+    def measure_and_keep(self, *a, **kw):
+        self.traced = measure(self, *a, **kw)
+        return self.traced
+
+    cell.Cell.measure = measure_and_keep
     cell.Cell.close = read_then_close
     return run.main(run_args + ["--trace", "1"])
 
