@@ -260,6 +260,11 @@ class _Work:
     #                       the first; kept across a swap-out, so the gap
     #                       a resumed sequence's next token closes
     #                       began before it
+    staged: object = None  # its `_Stage`, from `submit` until an
+    #                       admission has taken it (or let it go): like
+    #                       `probe` it outlives a failed admission, so a
+    #                       request that retries under pool pressure
+    #                       pays ONE store call
 
     def __post_init__(self):
         if self.req.temperature > 0 and self.rng is None:
@@ -339,6 +344,34 @@ class _Upload:
     # it was not tried (an earlier upload had failed).
     error: object = None
     skipped: bool = False
+
+
+@dataclass(eq=False)
+class _Stage:
+    """A queued request's probe and store read on their way into HBM:
+    what `submit` hands the engine's restore thread and the request's
+    admission takes from its `_Work`. The restore thread writes the
+    results and sets the two events, `probed` behind the depth and
+    `done` behind the pages; the engine thread reads a result only
+    behind its event. `nbytes`, `dropped` and the arrays change hands
+    under the engine's `_stage_cv`."""
+    request: object           # request id of the spans
+    prompt: list              # the prompt it was probed for
+    put_ns: int               # perf_counter_ns at the put on the queue
+    probed: object = field(default_factory=threading.Event)
+    done: object = field(default_factory=threading.Event)
+    hit: int = 0              # the probe's answer: pages, their digests,
+    digests: list = ()        # ... and the stats it moves once it is
+    counts: dict = field(default_factory=dict)  # taken (`_probe`)
+    first_live: int = 0       # the pages read are [first_live, hit)
+    restored: object = None   # what `_read_hit` returned: the pages in
+    snap: object = None       # HBM, the snapshot, how the read lay in
+    read: dict = None         # the store's pool. No pages and no error:
+    #                           nothing was read (let go, or no hit)
+    nbytes: int = 0           # what RESTORE_STAGED_BYTES counts of it
+    error: tuple = None       # ("probe" | "restore", the exception)
+    dur_ns: int = 0           # its istpu.cache.stage span's duration
+    dropped: bool = False     # the engine thread let it go
 
 
 @dataclass
@@ -1122,6 +1155,16 @@ OFFLOAD_CHUNK_BYTES = 16 << 20
 # 78 MB snapshot each, 2.4 a second) made the engine thread wait 8-12
 # times in a window of 51 s, at 512 MiB once (PERF.md, PR 39).
 UPLOAD_INFLIGHT_BYTES = 512 << 20
+# The most bytes of hits that may lie staged in HBM, read by the restore
+# thread and not yet taken by their admissions, under the rule above: a
+# hit larger than this alone is read when nothing else is staged. A
+# staged hit is 85 MB in mistral7b-sessions, 127-260 MB in xing4-29b,
+# 193-395 MB in keye-vl2 and 258-666 MB in evabyte, and the v5e cells
+# peak at 11.7-12.95 of 16 GB of HBM. The same array exists without the
+# thread for the length of its admission; new is that it lies beside a
+# decode step's temporaries, and beside the hits of the requests queued
+# behind it while every slot is taken: that is what this bounds.
+RESTORE_STAGED_BYTES = 256 << 20
 # How many pages below the matched depth a hit of a family with state
 # looks for a snapshot, one single-key probe a page (some 0.1 ms
 # each): the next turn of a conversation adds an answer and a message,
@@ -1186,6 +1229,29 @@ def _upload_loop(engine_ref, todo, acked):
         engine._run_upload(up)
         acked.put(up)
         del engine, up
+
+
+def _restore_loop(engine_ref, todo):
+    """An engine's restore thread: `_Stage`s off `todo` in the order
+    their requests arrived, each run by the engine's `_run_stage`; None
+    ends it, as `_upload_loop`."""
+    while True:
+        st = todo.get()
+        engine = None if st is None else engine_ref()
+        if engine is None:
+            return
+        engine._run_stage(st)
+        del engine, st
+
+
+def _join(thread):
+    """Wait for an engine's upload or restore thread to end, its queue
+    closed: `ServingEngine.close`."""
+    thread.join(timeout=60)
+    if thread.is_alive():
+        raise RuntimeError(
+            f"{thread.name} did not stop; the store connection must not "
+            f"be destroyed while it is running")
 
 
 class ServingEngine:
@@ -1390,6 +1456,13 @@ class ServingEngine:
             # finish to its tokens in `outputs`, summed, ms)
             "uploads": 0, "upload_backpressure_waits": 0,
             "done_held_ms": 0.0,
+            # hits whose pages an admission took as the restore thread
+            # had staged them, those of them taken before they were
+            # done with a sequence in a slot (its steps waited: the
+            # scheduler leaves such a head queued, so 0), and what the
+            # engine thread waited for stagings in all, ms
+            "restores_staged": 0, "restores_staged_late": 0,
+            "restore_stage_wait_ms": 0.0,
             # plain decode steps (of decode_steps) whose program was
             # dispatched before the step before it had landed, and the
             # rows of such steps that were dropped unseen: their
@@ -1447,6 +1520,14 @@ class ServingEngine:
         self.uploads_pending = 0
         self._upload_bytes = 0
         self._upload_failed = False
+        # The restore thread (started by the first request staged) and
+        # its queue; the bytes staged and not yet taken, which both
+        # threads move under the condition the restore thread waits
+        # for room on.
+        self._restore_thread = None
+        self._to_stage = queue.SimpleQueue()
+        self._stage_cv = threading.Condition()
+        self._staged_bytes = 0
         # (pool, band) -> attention layers of that kind, and the entries
         # of every row's table over every attention layer
         self._attn_kinds = collections.Counter(
@@ -1873,9 +1954,13 @@ class ServingEngine:
                 f"request needs {need} pages of {what} ({n} positions) "
                 f"> max_pages_per_seq {self.sc.max_pages_per_seq}"
             )
-        self.queue.append(_Work(req=req, prompt=list(req.prompt),
-                                queue_len=len(self.queue)))
+        work = _Work(req=req, prompt=list(req.prompt),
+                     queue_len=len(self.queue))
+        self.queue.append(work)
         self.stats["requests"] += 1
+        # (a prompt within one page has no full page to hit: `_probe`)
+        if self._store_chain(work) and len(work.prompt) > self.cfg.page_size:
+            self._stage(work)
 
     def _pages_on_the_way(self, n):
         """The most pool pages a sequence holds on its way to `n`
@@ -1923,26 +2008,35 @@ class ServingEngine:
     def _probe_hit(self, work):
         """Page-granular prefix hit, capped so at least one prompt token
         remains to prefill (the engine needs its logits). Returns
-        (hit, digests[:hit]) so the restore reuses the hash chain."""
-        if self.store is None or not self._store_ok or not work.req.cache:
+        (hit, digests[:hit]) so the restore reuses the hash chain. ON
+        THE ENGINE THREAD: `_probe`, counted, a store failure taken
+        home."""
+        if not self._store_chain(work):
             return 0, []
-        cap = (len(work.prompt) - 1) // self.cfg.page_size
+        try:
+            hit, digests, counts = self._probe(work.prompt,
+                                               work.req.request_id)
+        except Exception as e:
+            self._store_failed("probe", e)
+            return 0, []
+        for name, n in counts.items():
+            self.stats[name] += n
+        return hit, digests
+
+    def _probe(self, prompt, request):
+        """`_probe_hit` as either thread runs it, the engine's or its
+        restore thread: it touches nothing of the engine that changes
+        and raises what the store raises. Returns (hit, digests[:hit],
+        {stat: what the probe adds to it once it is taken})."""
+        cap = (len(prompt) - 1) // self.cfg.page_size
+        counts = {}
         if cap == 0:
-            return 0, []
-        with self._span("istpu.cache.probe", work.req.request_id,
-                        pages=cap) as f:
-            digests = self._digests(work.prompt, cap)
+            return 0, [], counts
+        with self._span("istpu.cache.probe", request, pages=cap) as f:
+            digests = self._digests(prompt, cap)
             if self._fold:
-                try:
-                    hit = self._probe_folded(digests, cap)
-                except Exception as e:
-                    self._store_failed("probe", e)
-                    return 0, []
-                f["hit_pages"] = hit
-                if hit > 0:
-                    self._prefetch_chain(work.prompt, hit, digests[:hit])
-                return hit, digests[:hit]
-            try:
+                hit = self._probe_folded(digests, cap)
+            else:
                 # ONE call over the probed key of every kind of each
                 # page, page-major: a page counts with all of them
                 per = len(self._probe_kinds)
@@ -1951,16 +2045,14 @@ class ServingEngine:
                      for layer, kind in self._probe_kinds
                      for key in content_page_keys_by_page([d], [layer],
                                                           kind)])
-            except Exception as e:
-                self._store_failed("probe", e)
-                return 0, []
-            hit = min(found // per, cap)
-            if hit > 0 and self.state is not None:
-                hit = self._probe_snapshot(hit, digests)
+                hit = min(found // per, cap)
+                if hit > 0 and self.state is not None:
+                    hit = self._probe_snapshot(hit, digests, counts)
             f["hit_pages"] = hit
             if hit > 0:
-                self._prefetch_chain(work.prompt, hit, digests[:hit])
-        return hit, digests[:hit]
+                counts["prefetched_pages"] = self._prefetch_chain(
+                    prompt, hit, digests[:hit])
+        return hit, digests[:hit], counts
 
     def _fold_split(self, hit):
         """(summary pages, exact pages) a folded prefix of `hit` token
@@ -2014,9 +2106,10 @@ class ServingEngine:
             n_exact = self.store.cached_prefix_len(exact_keys(w))
         return min(w * per_w + n_exact, (w + 1) * per_w - 1, cap)
 
-    def _probe_snapshot(self, hit, digests):
+    def _probe_snapshot(self, hit, digests, counts):
         """The depth a family with state can restore, given `hit`
-        matched pages: the deepest d <= hit at which a snapshot lies in
+        matched pages (`counts`: `_probe`'s, which this adds to): the
+        deepest d <= hit at which a snapshot lies in
         the store, 0 if none within SNAPSHOT_WALK pages. Pages without
         the state at their end are no prefix. A finish writes pages and
         snapshot at ONE depth, so the common case is one probe, of the
@@ -2030,16 +2123,12 @@ class ServingEngine:
         again. Nothing found within the bound (or the snapshot was
         evicted and its pages not): admitted cold, and counted."""
         last = self.cfg.n_state_layers - 1
-        try:
-            for d in range(hit, max(hit - SNAPSHOT_WALK, 0), -1):
-                if self.store.cached_prefix_len(
-                        snapshot_keys(digests[d - 1], last, last + 1)):
-                    self.stats["snapshot_walkbacks"] += d < hit
-                    return d
-        except Exception as e:
-            self._store_failed("probe", e)
-            return 0
-        self.stats["snapshot_misses"] += 1
+        for d in range(hit, max(hit - SNAPSHOT_WALK, 0), -1):
+            if self.store.cached_prefix_len(
+                    snapshot_keys(digests[d - 1], last, last + 1)):
+                counts["snapshot_walkbacks"] = int(d < hit)
+                return d
+        counts["snapshot_misses"] = 1
         return 0
 
     def _prefetch_chain(self, prompt, hit, digests):
@@ -2051,10 +2140,11 @@ class ServingEngine:
         which re-admits through this same probe path) pins the pages
         they are pool-resident and the restore pays zero inline disk
         reads. Purely advisory: failures are swallowed — a broken hint
-        must never fail (or even slow) an admission."""
+        must never fail (or even slow) an admission. Returns the pages
+        it asked for (`_probe` counts them)."""
         fn = getattr(self.store, "prefetch", None)
         if fn is None:
-            return
+            return 0
         cfg = self.cfg
         try:
             if self._win_layers or self._fold:
@@ -2072,10 +2162,9 @@ class ServingEngine:
             if self.state is not None:
                 keys.extend(snapshot_keys(digests[hit - 1], 0,
                                           self.cfg.n_state_layers))
-            if fn(keys):
-                self.stats["prefetched_pages"] += len(keys)
+            return len(keys) if fn(keys) else 0
         except Exception:
-            pass
+            return 0
 
     def _admit(self, slot_idx, work):
         n_prompt = len(work.prompt)
@@ -2085,7 +2174,8 @@ class ServingEngine:
         fresh = work.gap_at is None
         with self._span("istpu.sched.admit", rid, slot=slot_idx,
                         prompt_tokens=n_prompt, hit_pages=0,
-                        foreign_pages=0) as f:
+                        foreign_pages=0, staged_ns=0,
+                        staged_wait_ns=0) as f:
             admitted = self._do_admit(slot_idx, work, n_prompt, n_pages, f)
         if fresh and work.gap_at is not None:
             # Its first token left inside the span: the causes are
@@ -2109,10 +2199,16 @@ class ServingEngine:
         cfg = self.cfg
         page = cfg.page_size
         window = cfg.window
-        if work.probe is None:
-            work.probe = self._probe_hit(work)
-        hit, digests = work.probe
         store_chain = self._store_chain(work)
+        if work.staged is not None:
+            if not store_chain:
+                self.unstage(work)  # the store went while it waited
+            elif not work.staged.done.is_set():
+                self.stats["restores_staged_late"] += self._occupied()
+        if work.probe is None:
+            work.probe = self._staged_probe(work, f) \
+                or self._probe_hit(work)
+        hit, digests = work.probe
         if self._win_layers:
             return self._do_admit_two(slot_idx, work, n_prompt, n_pages,
                                       hit if store_chain else 0, digests, f)
@@ -2194,7 +2290,29 @@ class ServingEngine:
             self.free_pages.extend(self._admit_ids_view)
             raise
 
-    def _restore(self, hit, digests, first_live=0, foreign=0):
+    def _restore_size(self, hit, first_live):
+        """(pages, bytes, the pages by kind) of a hit's restore as its
+        spans count them."""
+        n = hit - first_live
+        if self._win_layers:
+            # Two kinds: the full layers' [0, hit) and the banded
+            # layers' [first_live, hit) in the one call, in the order
+            # `_admit_fused_px_wf` takes them; `pages` stays the hit's
+            # depth, `bytes` what crosses over.
+            return (hit, hit * self._page_bytes + n * self._wpage_bytes
+                    + self._snapshot_bytes,
+                    {"full_pages": hit, "window_pages": n,
+                     "trimmed_pages": first_live})
+        if self._fold:
+            # A folded prefix: the summary pages of its finished
+            # windows and the exact pages behind them, one shape, ONE
+            # call; `pages` counts what crosses over.
+            n_sum, n_exact = self._fold_split(hit)
+            return (n_sum + n_exact, (n_sum + n_exact) * self._page_bytes,
+                    {"summary_pages": n_sum, "exact_pages": n_exact})
+        return n, n * self._page_bytes + self._snapshot_bytes, {}
+
+    def _read_hit(self, hit, digests, first_live):
         """Pages [first_live, hit) of the probed chain `digests`, store ->
         HBM in ONE batched call over every layer and kind, as the store
         call returns them: page-major [(hit - first_live) * L * 2, page,
@@ -2204,63 +2322,60 @@ class ServingEngine:
         layer/kind-independent and come from the probe — the prompt is
         hashed ONCE per admission. For a family with state, a second
         call brings the snapshot taken at the end of page `hit`, [state
-        layers, row]; returns (pages, snapshot or None)."""
-        n = hit - first_live
+        layers, row]. On the thread that calls it, the engine's or its
+        restore thread, and touching nothing of the engine that
+        changes. Returns (pages, snapshot or None, how the pages lay
+        in the store's pool: the contiguous runs the read spanned and
+        the bytes it copied on the host, 0 for one run transferred from
+        the pool itself; None from a store that does not say)."""
         keys = self._restore_keys(hit, digests, first_live)
-        kinds = {}
-        if self._win_layers:
-            # Two kinds: the full layers' [0, hit) and the banded
-            # layers' [first_live, hit) in the one call, in the order
-            # `_admit_fused_px_wf` takes them; `pages` stays the hit's
-            # depth, `bytes` what crosses over.
-            kinds = {"full_pages": hit, "window_pages": n,
-                     "trimmed_pages": first_live}
-            nbytes = hit * self._page_bytes + n * self._wpage_bytes \
-                + self._snapshot_bytes
-            n = hit
-        elif self._fold:
-            # A folded prefix: the summary pages of its finished
-            # windows and the exact pages behind them, one shape, ONE
-            # call; `pages` counts what crosses over.
-            n_sum, n_exact = self._fold_split(hit)
-            kinds = {"summary_pages": n_sum, "exact_pages": n_exact}
-            n = n_sum + n_exact
-            nbytes = n * self._page_bytes
+        if self._index_kind:
+            # a call a kind: a store call carries pages of ONE shape
+            pages = tuple(
+                self._get_pages(ks, self.cfg.page_shape(kind),
+                                self.cfg.jdtype)
+                for ks, kind in zip(keys, self.cfg.page_kinds))
         else:
-            nbytes = n * self._page_bytes + self._snapshot_bytes
-        # The span times the store calls alone — the interval a span
-        # around get_kv_pages from outside times too.
+            pages = self._get_pages(keys, self.cfg.kv_page_shape(),
+                                    self.cfg.jdtype)
+        read = getattr(self.store, "last_read", None)  # this thread's
+        if self.state is None:
+            return pages, None, read
+        # The snapshot's way in: its store call (store -> HBM); it
+        # is placed into the slot inside the hit program.
+        with self._span("istpu.cache.state_in", bytes=self._snapshot_bytes):
+            return pages, self._get_pages(
+                snapshot_keys(digests[hit - 1], 0, self.cfg.n_state_layers),
+                (self._snapshot_row,), self.cfg.state_jdtype), read
+
+    def _restore(self, hit, digests, first_live=0, foreign=0, st=None,
+                 fa=None):
+        """A hit's pages and snapshot in HBM, (pages, snapshot or
+        None), ON THE ENGINE THREAD under istpu.cache.restore, which
+        times what this thread pays for them: with `st`, the request's
+        `_Stage` (and `fa`, its admission's fields), the wait for what
+        the restore thread read, near 0 where it is done; without, or
+        where that thread read nothing, `_read_hit` here."""
+        n, nbytes, kinds = self._restore_size(hit, first_live)
         with self._span("istpu.cache.restore", pages=n, bytes=nbytes,
                         foreign_pages=foreign, **kinds, **self._kinds_field,
                         **self._snapshot_fields) as f:
-            if self._index_kind:
-                # a call a kind: a store call carries pages of ONE shape
-                pages = tuple(
-                    self._get_pages(ks, self.cfg.page_shape(kind),
-                                    self.cfg.jdtype)
-                    for ks, kind in zip(keys, self.cfg.page_kinds))
-            else:
-                pages = self._get_pages(keys, self.cfg.kv_page_shape(),
-                                        self.cfg.jdtype)
-            # How the pages lay in the store's pool: the contiguous runs
-            # the read spanned and the bytes it copied on the host (0:
-            # one run, transferred from the pool itself). A store that
-            # does not say leaves the fields out.
-            read = getattr(self.store, "last_read", None)
+            got = None
+            if st is not None:
+                self._await_stage(st, st.done, fa)
+                if st.error is not None:
+                    raise st.error[1]
+                if st.restored is not None:
+                    got = st.restored, st.snap, st.read
+                    fa["staged_ns"] = st.dur_ns
+                    self.stats["restores_staged"] += 1
+            pages, snap, read = got or self._read_hit(hit, digests,
+                                                      first_live)
             if read is not None:
                 f.update(read)
                 self.stats["restore_runs"] += read["runs"]
                 self.stats["restore_copied_bytes"] += read["copied_bytes"]
-            if self.state is None:
-                return pages, None
-            # The snapshot's way in: its store call (store -> HBM); it
-            # is placed into the slot inside the hit program.
-            with self._span("istpu.cache.state_in",
-                            bytes=self._snapshot_bytes):
-                return pages, self._get_pages(
-                    snapshot_keys(digests[hit - 1], 0,
-                                  self.cfg.n_state_layers),
-                    (self._snapshot_row,), self.cfg.state_jdtype)
+            return pages, snap
 
     def _store_chain(self, work):
         """Whether this request's pages go to and come from the
@@ -2268,23 +2383,31 @@ class ServingEngine:
         return (self.store is not None and self._store_ok
                 and work.req.cache)
 
-    def _try_restore(self, hit, digests, first_live, f):
+    def _try_restore(self, work, hit, digests, first_live, f):
         """`_restore`, counted, with a failure turned into a miss:
         (pages, snapshot, the hit that holds: 0 after a failure). `f`:
-        the admission span's fields."""
+        the admission span's fields. What `work` has staged for this
+        (hit, first_live) is taken; anything else staged is let go and
+        the store call made here."""
         # hit pages this engine did not itself offload
         foreign = sum(d not in self._own_digests for d in digests[:hit])
+        st = work.staged
+        if st is not None and (st.hit, st.first_live) != (hit, first_live):
+            self.unstage(work)
+            st = None
         try:
             restored, snap = self._restore(hit, digests, first_live,
-                                           foreign)
+                                           foreign, st, f)
         except InfiniStoreKeyNotFound:
             # Routine eviction race: the page was LRU-dropped between
             # probe and restore. A cache MISS for this admission only —
             # the store stays in use.
+            self.unstage(work)
             self.stats["restore_misses"] += 1
             return None, None, 0
         except Exception as e:
             # Connection-class failure: downgrade to store-less.
+            self.unstage(work)
             self._store_failed("restore", e)
             return None, None, 0
         self.stats["prefix_hit_pages"] += hit
@@ -2358,7 +2481,7 @@ class ServingEngine:
             # Restore the in-window hit pages once (one HBM array, as
             # the store call returns it; pool placement follows in
             # _do_admit_paged).
-            restored, snap, hit = self._try_restore(hit, digests,
+            restored, snap, hit = self._try_restore(work, hit, digests,
                                                     first_live, f)
             if hit == 0 and skip > 0:
                 # Restore failed after a skip-trimmed allocation: the
@@ -2378,6 +2501,7 @@ class ServingEngine:
             first_live, restored, snap,
         )
         work.probe = None  # consumed; a future re-admission re-probes
+        self.unstage(work)
         f["outcome"] = "admitted"
         return True
 
@@ -2466,7 +2590,7 @@ class ServingEngine:
         elif hit:
             if len(self.free_pages) < sum(self._fold_split(hit)):
                 return None  # before the store call, as `_do_admit`
-            restored, _, hit = self._try_restore(hit, digests, 0, f)
+            restored, _, hit = self._try_restore(work, hit, digests, 0, f)
         n_sum, n_exact = self._fold_split(hit)
         slot = _Slot(work=work, page_ids=[], seq_len=hit * page,
                      cached_pages=hit, index=slot_idx,
@@ -2511,6 +2635,7 @@ class ServingEngine:
             self._release(slot_idx, slot)
             raise
         work.probe = None  # consumed; a future re-admission re-probes
+        self.unstage(work)
         f["outcome"] = "admitted"
         return True
 
@@ -2779,7 +2904,7 @@ class ServingEngine:
             restored = snap = None
             if hit > 0:
                 restored, snap, hit = self._try_restore(
-                    hit, digests, self._first_live(hit), f)
+                    work, hit, digests, self._first_live(hit), f)
             f["hit_pages"] = hit
             row_host, sub = self._prefill_two(work.prompt, hit, restored,
                                               ids, wids, wbase, snap,
@@ -2805,6 +2930,7 @@ class ServingEngine:
         if sub and self._store_chain(work):
             f["subfloor_pages"] = self._put_subfloor(slot, sub, hit, wbase)
         work.probe = None  # consumed; a future re-admission re-probes
+        self.unstage(work)
         f["outcome"] = "admitted"
         return True
 
@@ -3563,6 +3689,153 @@ class ServingEngine:
                     f"the upload thread ended with {self.uploads_pending} "
                     f"uploads unacknowledged")
 
+    # ---- the restore thread and what it stages --------------------------
+
+    def _stage(self, work):
+        """`work`'s probe and store read, handed to the restore thread
+        (which starts with the first, and ends as the upload thread
+        does). Requests are staged as they are submitted and the queue
+        is FIFO, so the head's staging never waits for room behind a
+        request that can only be admitted after it. (A preempted
+        request goes to the queue's FRONT: it is not staged again, and
+        its re-admission makes the store call itself.)"""
+        if self._restore_thread is None:
+            self._restore_thread = threading.Thread(
+                target=_restore_loop,
+                args=(weakref.ref(self), self._to_stage),
+                name=f"istpu-restore-{self.engine_id}", daemon=True)
+            self._stop_restores = weakref.finalize(
+                self, self._to_stage.put, None)
+            self._restore_thread.start()
+        work.staged = _Stage(work.req.request_id, work.prompt,
+                             time.perf_counter_ns())
+        self._to_stage.put(work.staged)
+
+    def _run_stage(self, st):
+        """ON THE RESTORE THREAD, one request at a time in the order
+        they arrived: the digests, the probe and its prefetch hint
+        (`_probe`), then, for a hit and once it has room under
+        RESTORE_STAGED_BYTES, the keys, the store call or calls and the
+        transfer to the engine's device (`_read_hit`), by the same
+        calls under the same span names as the engine thread makes
+        them. Nothing else: no slot, no pool page, no `stats`, no
+        `_own_digests`; what went wrong stays in `st.error` for the
+        engine thread (`_staged_probe`, `_restore`)."""
+        if st.dropped:
+            st.probed.set()
+            st.done.set()
+            return
+        span = self._span("istpu.cache.stage", st.request, hit_pages=0,
+                          pages=0, bytes=0,
+                          queued_ns=time.perf_counter_ns() - st.put_ns)
+        try:
+            with span as f:
+                try:
+                    st.hit, st.digests, st.counts = self._probe(
+                        st.prompt, st.request)
+                except Exception as e:
+                    st.error = ("probe", e)
+                    return
+                finally:
+                    st.probed.set()
+                if not st.hit:
+                    return
+                f["hit_pages"] = st.hit
+                st.first_live = self._first_live(st.hit)
+                pages, nbytes, _ = self._restore_size(st.hit, st.first_live)
+                if not self._stage_room(st, nbytes):
+                    return
+                f.update(pages=pages, bytes=nbytes)
+                try:
+                    got = self._read_hit(st.hit, st.digests, st.first_live)
+                except Exception as e:
+                    st.error = ("restore", e)
+                    got = None
+                with self._stage_cv:
+                    if got is None:
+                        self._let_go(st)
+                    elif not st.dropped:  # (else its bytes went with it)
+                        st.restored, st.snap, st.read = got
+        finally:
+            st.dur_ns = span.dur_ns
+            st.done.set()
+
+    def _stage_room(self, st, nbytes):
+        """On the restore thread, before it reads a hit of `nbytes`:
+        wait while hits are staged and this one would take them past
+        RESTORE_STAGED_BYTES, then count it. False where the engine
+        thread let `st` go meanwhile."""
+        with self._stage_cv:
+            while not st.dropped and self._staged_bytes \
+                    and self._staged_bytes + nbytes > RESTORE_STAGED_BYTES:
+                self._stage_cv.wait()
+            if st.dropped:
+                return False
+            st.nbytes = nbytes
+            self._staged_bytes += nbytes
+            return True
+
+    def _let_go(self, st):
+        """Under `_stage_cv`: what `st` holds staged is no longer."""
+        self._staged_bytes -= st.nbytes
+        st.nbytes = 0
+        st.restored = st.snap = None
+        self._stage_cv.notify_all()
+
+    def unstage(self, work):
+        """On the engine thread: `work` is through with what it had
+        staged (its admission took it, or will do without): the arrays
+        go and their bytes make room. A read still under way is
+        dropped where it ends."""
+        st, work.staged = work.staged, None
+        if st is not None:
+            with self._stage_cv:
+                st.dropped = True
+                self._let_go(st)
+
+    def _await_stage(self, st, event, f):
+        """On the engine thread, inside an admission (`f`: its span's
+        fields): block until the restore thread has set `event` of
+        `st`, and count the wait."""
+        if event.is_set():
+            return
+        t0 = time.perf_counter_ns()
+        while not event.wait(1.0):
+            if not self._restore_thread.is_alive():
+                raise RuntimeError(
+                    f"the restore thread ended with request {st.request} "
+                    f"not staged")
+        waited = time.perf_counter_ns() - t0
+        f["staged_wait_ns"] += waited
+        self.stats["restore_stage_wait_ms"] += waited / 1e6
+
+    def _staged_probe(self, work, f):
+        """The probe `work` has staged, waited for and counted as
+        `_probe_hit` counts its own: (hit, digests), or None where
+        nothing is staged."""
+        st = work.staged
+        if st is None:
+            return None
+        self._await_stage(st, st.probed, f)
+        if st.error is not None and st.error[0] == "probe":
+            self.unstage(work)
+            self._store_failed(*st.error)
+            return 0, []
+        for name, n in st.counts.items():
+            self.stats[name] += n
+        st.counts = {}
+        return st.hit, st.digests
+
+    def _head_ready(self):
+        """Whether what the queue's head has staged is done (or it has
+        nothing staged): its admission then waits for no store call."""
+        st = self.queue[0].staged
+        return st is None or st.done.is_set()
+
+    def _occupied(self):
+        """Whether any slot holds a sequence."""
+        return any(s is not None for s in self.slots)
+
     def drain_uploads(self):
         """Block until every upload is acknowledged and collected:
         what was offloaded is in the store, what was held is in
@@ -3572,22 +3845,25 @@ class ServingEngine:
             self._await_ack()
 
     def close(self):
-        """Drain the uploads and stop the upload thread. Whoever
+        """Drain the uploads and stop the upload thread and the
+        restore thread; what queued requests had staged goes (their
+        admissions make the store call themselves). Whoever
         closes the store's connection calls this first: a native call
         on a closed handle is a use-after-free (`LayerStreamer.close`
-        has the note). The engine stays usable; its next offload
-        starts a thread anew."""
+        has the note). The engine stays usable; its next offload and
+        its next request staged start a thread anew."""
         self.land()
-        if self._upload_thread is None:
-            return
-        self.drain_uploads()
-        self._stop_uploads()
-        self._upload_thread.join(timeout=60)
-        if self._upload_thread.is_alive():
-            raise RuntimeError(
-                "the upload thread did not stop; the store connection "
-                "must not be destroyed while it is running")
-        self._upload_thread = None
+        for work in self.queue:
+            self.unstage(work)
+        if self._restore_thread is not None:
+            self._stop_restores()
+            _join(self._restore_thread)
+            self._restore_thread = None
+        if self._upload_thread is not None:
+            self.drain_uploads()
+            self._stop_uploads()
+            _join(self._upload_thread)
+            self._upload_thread = None
 
     def _shed_windows(self, active, more=0):
         """Before a decode step of a model with two kinds of attention
@@ -3910,10 +4186,18 @@ class ServingEngine:
         return self._land_span(flight)
 
     def _admit_queued(self):
-        """The queue's head into every free slot, while it admits."""
+        """The queue's head into every free slot, while it admits. A
+        head whose staging is not done stays queued while a slot holds
+        a sequence (its steps or pieces go on beside the restore
+        thread's read, and the next call looks again); with every slot
+        free its admission waits for the staging there, so a caller's
+        stall rule never sees a head that is only waiting for its
+        pages."""
         self._piece_ran = False
         for i in range(self.sc.max_slots):
             if self.slots[i] is None and self.queue:
+                if not self._head_ready() and self._occupied():
+                    return
                 if self._admit(i, self.queue[0]):
                     self.queue.pop(0)
 
@@ -3944,9 +4228,12 @@ class ServingEngine:
           slot owns, and no offload reads it (`_land`);
         - the active set is every occupied slot (no admission in
           pieces under way) and no admission this call could make (a
-          queue head and a free slot): the step behind an admission
-          holds the new sequence too, and an arrival never finds two
-          programs queued before its own;
+          queue head whose pages are staged, where it stages any, and
+          a free slot): the step behind an admission holds the new
+          sequence too, and an arrival never finds two programs queued
+          before its own. A head that still waits for its pages is no
+          admission this call could make: the run ahead goes on beside
+          the restore thread's read;
         - every slot greedy (a sampler needs N's logits row) and still
           short of its last token AFTER N's (a count; or an EOS
           landed).
@@ -3957,7 +4244,8 @@ class ServingEngine:
                 or (sc.eos_id >= 0 and self.state is not None):
             return False
         if sum(s is not None for s in self.slots) != len(active) \
-                or (self.queue and len(active) < sc.max_slots):
+                or (self.queue and len(active) < sc.max_slots
+                    and self._head_ready()):
             return False
         return not any(s.work.req.temperature > 0 or self._done(s, 1)
                        for _, s in active)
@@ -4388,6 +4676,7 @@ class ServingEngine:
                     self.queue.pop(0)
                     self.outputs[work.req.request_id] = list(work.done)
                     continue
+                self.unstage(work)
                 self.drain_uploads()
                 raise RuntimeError(
                     f"request {work.req.request_id} needs more pool "

@@ -395,6 +395,7 @@ class ServingHTTPServer:
                     # Fail IT with whatever it produced, keep serving
                     # (run()'s stall rule, without killing the server).
                     work = eng.queue.pop(0)
+                    eng.unstage(work)
                     eng.outputs[work.req.request_id] = list(work.done)
                 progressed = True
                 self._deliver()

